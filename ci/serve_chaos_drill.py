@@ -213,10 +213,10 @@ def scenario_expiry(pred, refs):
     dispatched_tags = []
     real = pred.predict
 
-    def spy(data, key=None):
+    def spy(data, key=None, **kw):
         arr = data["data"] if isinstance(data, dict) else data
         dispatched_tags.extend(np.asarray(arr)[:, 0].tolist())
-        return real(data, key=key)
+        return real(data, key=key, **kw)
 
     pred.predict = spy
     b = DynamicBatcher(pred, max_wait_ms=1, name="expiry")

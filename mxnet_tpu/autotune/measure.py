@@ -11,7 +11,7 @@ it.  The trace supplies identical load to every candidate
 * predictors are cached per ladder — two candidates differing only
   in scalar knobs share warm compiled programs, so a measurement
   prices the CONFIG, not a recompile;
-* the persistent XLA compile cache (``MXNET_COMPILE_CACHE_DIR``)
+* the persistent XLA compile cache (``config.compile_cache_dir()``)
   does the same across tuning processes;
 * ``request_path_compiles`` rides along in every measurement — a
   candidate that compiles in the request path is broken, not slow,

@@ -24,7 +24,8 @@ import os
 
 __all__ = ["register_env", "get_env", "list_env", "describe",
            "tuned_override", "tuned_overrides", "clear_tuned",
-           "resolve_env", "env_is_set", "enable_compile_cache"]
+           "resolve_env", "env_is_set", "compile_cache_dir",
+           "enable_compile_cache"]
 
 _REGISTRY = {}
 
@@ -324,18 +325,6 @@ register_env("MXNET_USE_NATIVE_RECORDIO", bool, True,
 register_env("MXNET_ENGINE_INFO", bool, False,
              "Verbose engine scheduling debug output "
              "(reference: threaded_engine.h:302)")
-register_env("MXNET_COMPILE_CACHE_DIR", str, "",
-             "Directory for jax's persistent XLA compilation cache "
-             "(jax_compilation_cache_dir): cold starts — serving "
-             "fleets, multi-process dist drills, supervisor restarts "
-             "— reload compiled programs from disk instead of paying "
-             "a full compile; empty = off (see docs/serving.md and "
-             "docs/perf_fused_step.md)")
-register_env("MXNET_COMPILE_CACHE_MIN_SECS", float, 0.0,
-             "Minimum compile time (seconds) for a program to be "
-             "written to the persistent compilation cache "
-             "(jax_persistent_cache_min_compile_time_secs); 0 caches "
-             "everything — serving ladders are many small programs")
 register_env("MXNET_SERVE_MAX_WAIT_MS", float, 2.0,
              "How long the serve DynamicBatcher holds a non-full "
              "batch open for more arrivals, measured from the oldest "
@@ -456,32 +445,41 @@ register_env("MXNET_SERVE_DECODE_REBUILDS", int, 2,
              "batcher degrades to unhealthy typed-fail")
 
 
+# Where compiled programs persist when JAX_COMPILATION_CACHE_DIR is not
+# set: one fixed path beside the package.  The directory is part of the
+# cache key, so a path built from a temporary name never hits.
+_DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def compile_cache_dir():
+    """The persistent XLA compilation cache directory of this process
+    and of every child it starts: ``JAX_COMPILATION_CACHE_DIR`` where
+    the environment sets it, else the fixed in-checkout default."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+        _DEFAULT_COMPILE_CACHE
+
+
 def enable_compile_cache():
-    """Apply the ``MXNET_COMPILE_CACHE_DIR`` knob: point jax's
-    persistent compilation cache at the directory (created if
-    missing) so every process sharing it — a serving fleet, the
-    multi-process dist drills, supervisor-restarted jobs — pays each
-    distinct program's compile once, ever.  Returns True when the
-    cache was enabled.  Called at package import; safe to call again
+    """Turn jax's persistent compilation cache on at
+    :func:`compile_cache_dir`, so every process sharing it — a serving
+    fleet, the multi-process dist drills, supervisor-restarted jobs, the
+    next run — pays each distinct program's compile once.  Called at
+    package import; does not initialize a backend.  Safe to call again
     after mutating the environment (tests)."""
-    path = get_env("MXNET_COMPILE_CACHE_DIR")
-    if not path:
-        return False
     import jax
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      get_env("MXNET_COMPILE_CACHE_MIN_SECS"))
-    # tiny programs matter for the serve ladder: do not skip them on
-    # size either
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # jax latches cache initialization on the FIRST compile: enabling
-    # the dir after any jax use in the process (tests, a server that
-    # reads config late) would silently cache nothing.  Drop the
-    # latch so the next compile re-initializes against the new dir.
-    try:
-        from jax._src import compilation_cache as _cc
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+    path = compile_cache_dir()
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+        # jax latches cache initialization on the FIRST compile: drop
+        # the latch so the next compile initializes against this dir
         _cc.reset_cache()
-    except (ImportError, AttributeError):  # layout drift: import-time
-        pass                               # enablement still works
-    return True
+    # serving ladders are many small, fast-compiling programs: cache
+    # them all unless the environment says otherwise
+    for name in ("jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes"):
+        if name.upper() not in os.environ:
+            jax.config.update(name, 0)
+    return path

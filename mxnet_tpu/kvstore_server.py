@@ -19,17 +19,13 @@ def run_server(kv_type="dist_sync", host=None, port=None, num_workers=None,
     # server-side optimizer run on CPU (the reference's ps-lite servers
     # are CPU processes), never on the accelerator.
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception as exc:
-        # backend already initialized on another platform: the server
-        # still works, but say so — a TPU-grabbing server starves the
-        # training processes of the accelerator
-        import logging
-        logging.getLogger(__name__).warning(
-            "kvstore server could not pin the cpu backend (%s: %s); "
-            "continuing on the default platform",
-            type(exc).__name__, exc)
+    jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        # this process initialized an accelerator backend before the
+        # pin: a server that holds the chip takes it from the workers
+        raise RuntimeError(
+            "kvstore server must run on the cpu backend, but this "
+            "process already initialized %r" % jax.default_backend())
     sync = "async" not in kv_type
     # server s of a multi-server group listens at root port + s
     # (tools/launch.py sets DMLC_SERVER_ID; key sharding lives worker-side)

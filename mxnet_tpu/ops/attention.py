@@ -30,11 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# the TPU compiler-params dataclass was renamed across jax releases
-# (TPUCompilerParams -> CompilerParams); accept either spelling
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+from jax.sharding import PartitionSpec as P
 
 from ._precision import matmul_precision
 from .registry import register_op
@@ -42,6 +38,16 @@ from .registry import register_op
 __all__ = ["flash_attention", "attention_reference"]
 
 _NEG_INF = -1e30
+# Per-row softmax state (running max, denominator, logsumexp, delta)
+# rides lane-replicated as (rows, _LANES): Mosaic tiles the last two
+# dims of every block (8, 128), so a (1, blk_q) block over a (bh, sq)
+# array does not lower and 1-D VMEM scratch has no native layout.
+_LANES = 128
+# Default q and k block length.  On v5e 512 and 1024 both compile and
+# land the same distance from the f32 reference; 2048 asks for 25.8 MiB
+# of the 16 MiB scoped VMEM and does not compile (CHANGES.md PR 21).
+# Untuned: no timing chose it.
+_BLK = 1024
 
 
 def attention_reference(q, k, v, causal=False, sm_scale=None):
@@ -185,9 +191,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_and_scratch,
         q = q_ref[0]                               # (blk_q, d)
         k = k_ref[0]                               # (blk_k, d)
         v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32
-                                ) * sm_scale
+        s = _mxu_dot(q, k, ((1,), (1,))) * sm_scale
 
         k_pos = ik * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = k_pos < seq_k
@@ -199,18 +203,17 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_and_scratch,
             mask = mask & (k_pos <= q_pos)
         s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        m_prev = m_ref[...]                        # (blk_q, _LANES)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
+        p = jnp.exp(s - _lanes_to(m_new, blk_k))
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_new
         # p in v's dtype for the second MXU dot (flash convention: the
         # f32 online-softmax state carries the precision; p's entries
         # are probabilities in [0,1] where bf16 relative error is ~2^-8)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = (acc_ref[...] * _lanes_to(alpha, acc_ref.shape[1])
+                        + _mxu_dot(p.astype(v.dtype), v, ((1,), (0,))))
 
     if causal:
         # skip K blocks entirely above the diagonal: their tiles are fully
@@ -228,19 +231,37 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_and_scratch,
         # underflows to 0 for them — zero output, zero gradient, same
         # convention as attention_reference/_chunked_attention
         m = m_ref[...]
-        l = l_ref[...]
-        # Mosaic cannot widen an i1 vector to 2D; reshape the f32 state
-        # first and build the mask at its final rank instead
-        deg2 = m[:, None] <= _NEG_INF * 0.5
-        l_safe2 = jnp.where(deg2, 1.0, l[:, None])
-        o_ref[0] = jnp.where(deg2, 0.0,
-                             acc_ref[...] / l_safe2).astype(o_ref.dtype)
+        degenerate = m <= _NEG_INF * 0.5
+        l_safe = jnp.where(degenerate, 1.0, l_ref[...])
+        # widen the f32 state, then compare: Mosaic does not tile or
+        # reshape i1 vectors
+        dp = acc_ref.shape[1]
+        o_ref[0] = jnp.where(_lanes_to(m, dp) <= _NEG_INF * 0.5, 0.0,
+                             acc_ref[...] / _lanes_to(l_safe, dp)
+                             ).astype(o_ref.dtype)
         if lse_ref is not None:
             # logsumexp residual for the flash backward
-            degenerate = m <= _NEG_INF * 0.5
-            l_safe = jnp.where(degenerate, 1.0, l)
             lse_ref[0] = jnp.where(degenerate, -_NEG_INF,
                                    m + jnp.log(l_safe))
+
+
+def _mxu_dot(a, b, contract):
+    """In-kernel MXU dot, f32 accumulation, at the framework's precision
+    policy: bf16 operands take the full-rate path, f32 operands HIGHEST —
+    Mosaic's default would multiply them as bf16 (the f32 op then
+    disagrees with the CPU at 1e-2; consistency sweep, PR 21)."""
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        precision=matmul_precision(a.dtype, b.dtype),
+        preferred_element_type=jnp.float32)
+
+
+def _lanes_to(x, n):
+    """Widen lane-replicated row state (rows, _LANES) to (rows, n)."""
+    reps, rem = divmod(n, _LANES)
+    if rem:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return jnp.tile(x, (1, reps))
 
 
 def _pad_bh(x, s_pad, d_pad):
@@ -249,7 +270,7 @@ def _pad_bh(x, s_pad, d_pad):
     return xp.reshape(b * h, s + s_pad, d + d_pad)
 
 
-def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=1024, blk_k=1024,
+def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=_BLK, blk_k=_BLK,
                       interpret=False, with_lse=False):
     """Flash forward: grid (B*H, nq, nk); f32 accumulators in VMEM
     scratch.  ``with_lse`` also returns the per-row logsumexp residual
@@ -277,9 +298,9 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=1024, blk_k=1024,
                               lambda bh_, iq, ik: (bh_, iq, 0))]
     out_shape = [jax.ShapeDtypeStruct((bh, sq + sq_pad, dp), q.dtype)]
     if with_lse:  # training: also emit the logsumexp residual
-        out_specs.append(pl.BlockSpec((1, blk_q),
-                                      lambda bh_, iq, ik: (bh_, iq)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, sq + sq_pad),
+        out_specs.append(pl.BlockSpec((1, blk_q, _LANES),
+                                      lambda bh_, iq, ik: (bh_, iq, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, sq + sq_pad, _LANES),
                                               jnp.float32))
     res = pl.pallas_call(
         kernel,
@@ -293,16 +314,16 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=1024, blk_k=1024,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((blk_q, dp), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
+            pltpu.VMEM((blk_q, _LANES), jnp.float32),
+            pltpu.VMEM((blk_q, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)
     out = res[0].reshape(b, h, sq + sq_pad, dp)[:, :, :sq, :d]
     if with_lse:
-        return out, res[1]  # lse stays padded (bh, sqp) for the bwd
+        return out, res[1][..., 0]  # lse stays padded (bh, sqp) for the bwd
     return out
 
 
@@ -320,8 +341,7 @@ def _bwd_p_block(q_ref, k_ref, lse_ref, iq, ik, *, sm_scale, causal,
     accumulates f32 via preferred_element_type."""
     q = q_ref[0]
     k = k_ref[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
+    s = _mxu_dot(q, k, ((1,), (1,))) * sm_scale
     k_pos = ik * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     mask = k_pos < seq_k
     if causal:
@@ -329,7 +349,7 @@ def _bwd_p_block(q_ref, k_ref, lse_ref, iq, ik, *, sm_scale, causal,
                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
         mask = mask & (k_pos <= q_pos)
     s = jnp.where(mask, s, _NEG_INF)
-    return jnp.exp(s - lse_ref[0][:, None])
+    return jnp.exp(s - _lanes_to(lse_ref[0], blk_k))
 
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -354,16 +374,11 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         q = q_ref[0]
         # dv += p^T dO — p cast to the storage dtype for a full-rate
         # MXU dot; accumulators stay f32
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv_acc[...] += _mxu_dot(p.astype(do.dtype), do, ((0,), (0,)))
         # ds = p * (dO v^T - delta) * scale;  dk += ds^T q
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * sm_scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dp = _mxu_dot(do, v, ((1,), (1,)))
+        ds = p * (dp - _lanes_to(delta_ref[0], blk_k)) * sm_scale
+        dk_acc[...] += _mxu_dot(ds.astype(q.dtype), q, ((0,), (0,)))
 
     if causal:
         visible = ik * blk_k <= iq * blk_q + blk_q - 1 + (seq_k - seq_q)
@@ -395,12 +410,9 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         do = do_ref[0]
         v = v_ref[0]
         k = k_ref[0]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * sm_scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dp = _mxu_dot(do, v, ((1,), (1,)))
+        ds = p * (dp - _lanes_to(delta_ref[0], blk_k)) * sm_scale
+        dq_acc[...] += _mxu_dot(ds.astype(k.dtype), k, ((1,), (0,)))
 
     if causal:
         visible = ik * blk_k <= iq * blk_q + blk_q - 1 + (seq_k - seq_q)
@@ -414,7 +426,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
-                      blk_q=1024, blk_k=1024, interpret=False):
+                      blk_q=_BLK, blk_k=_BLK, interpret=False):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     blk_q = min(blk_q, sq)
@@ -433,6 +445,10 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
     # delta_i = rowsum(dO_i * O_i) — zero on padded rows since dO is 0
     delta = jnp.sum(dop.astype(jnp.float32) * outp.astype(jnp.float32),
                     axis=-1)
+    # the per-row residuals enter lane-replicated (see _LANES); the
+    # widened copies live only for this call, the saved lse is (bh, sqp)
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LANES,))
+    delta = jnp.broadcast_to(delta[..., None], delta.shape + (_LANES,))
 
     common = dict(sm_scale=sm_scale, causal=causal, blk_q=blk_q,
                   blk_k=blk_k, seq_q=sq, seq_k=sk)
@@ -440,8 +456,10 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
     q_spec_k = pl.BlockSpec((1, blk_q, dp), lambda bh_, a, b_: (bh_, b_, 0))
     k_spec_q = pl.BlockSpec((1, blk_k, dp), lambda bh_, a, b_: (bh_, b_, 0))
     k_spec_k = pl.BlockSpec((1, blk_k, dp), lambda bh_, a, b_: (bh_, a, 0))
-    r_spec_q = pl.BlockSpec((1, blk_q), lambda bh_, a, b_: (bh_, a))
-    r_spec_k = pl.BlockSpec((1, blk_q), lambda bh_, a, b_: (bh_, b_))
+    r_spec_q = pl.BlockSpec((1, blk_q, _LANES),
+                            lambda bh_, a, b_: (bh_, a, 0))
+    r_spec_k = pl.BlockSpec((1, blk_q, _LANES),
+                            lambda bh_, a, b_: (bh_, b_, 0))
 
     # dk/dv: grid (bh, nk, nq) — k-block resident, q streamed
     dk, dv = pl.pallas_call(
@@ -454,7 +472,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
                    jax.ShapeDtypeStruct((bh, sk + sk_pad, dp), v.dtype)],
         scratch_shapes=[pltpu.VMEM((blk_k, dp), jnp.float32),
                         pltpu.VMEM((blk_k, dp), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp, dop, lse, delta)
@@ -468,7 +486,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
         out_specs=q_spec_q,
         out_shape=jax.ShapeDtypeStruct((bh, sq + sq_pad, dp), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, dp), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp, dop, lse, delta)
@@ -517,31 +535,38 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False,
     def _tpu(q, k, v):
         # the kernels' MXU dots need one operand dtype (f32 q against a
         # bf16 KV cache would raise); promote once here so the uniform
-        # bf16 fast path is untouched.  NOTE platform_dependent traces
-        # BOTH branches on every platform (lax.cond), so the promotion
-        # must stay inside the branch
+        # bf16 fast path is untouched.  platform_dependent traces BOTH
+        # branches on every platform, so the promotion stays inside
         dt = jnp.result_type(q.dtype, k.dtype, v.dtype)
-        return _flash(q.astype(dt), k.astype(dt), v.astype(dt),
-                      causal, float(sm_scale), False).astype(q.dtype)
+
+        def local(q, k, v):
+            return _flash(q.astype(dt), k.astype(dt), v.astype(dt),
+                          causal, float(sm_scale), False).astype(q.dtype)
+
+        from ..parallel.mesh import current_mesh
+        mesh = current_mesh()
+        manual = jax.sharding.get_abstract_mesh().manual_axes
+        if mesh is None or mesh.size == 1 or \
+                set(manual) == set(mesh.axis_names):
+            # one device, or already per shard (a pipeline stage)
+            return local(q, k, v)
+        # XLA does not partition a Mosaic kernel ("wrap the call in a
+        # shard_map"): run it per shard.  Attention is independent over
+        # batch and heads, so dp splits the batch and tp the heads; any
+        # other mesh axis computes replicated.
+        spec = P("dp" if "dp" in mesh.shape else None,
+                 "tp" if "tp" in mesh.shape else None)
+        return jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 3,
+                             out_specs=spec, check_vma=False)(q, k, v)
 
     def _other(q, k, v):
         return _chunked_attention(q, k, v, causal, sm_scale,
                                   int(chunk)).astype(q.dtype)
 
-    # decided at LOWERING time per platform (not by the process-default
-    # backend, which is wrong in a mixed cpu+tpu session).  On this
-    # jax release platform_dependent still LOWERS every branch for the
-    # target platform, and the Mosaic pallas_call has no CPU lowering
-    # rule at all — so in a process with no TPU devices (where the tpu
-    # branch could never be taken anyway) skip straight to the XLA
-    # chunked path instead of tripping "Only interpret mode is
-    # supported on CPU backend" at compile time.
-    try:
-        have_tpu = any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        have_tpu = False
-    if not have_tpu:
-        return _other(q, k, v)
+    # decided at LOWERING time per platform, not by which devices this
+    # process happens to see: only the target platform's branch is
+    # lowered, so the Mosaic kernels are the program on a TPU and never
+    # reach a CPU compile
     return jax.lax.platform_dependent(q, k, v, tpu=_tpu, default=_other)
 
 

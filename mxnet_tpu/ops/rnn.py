@@ -20,6 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ._precision import matmul_precision
 from .registry import register_op
 
 _GATES = {"rnn_relu": 1, "rnn_tanh": 1, "lstm": 4, "gru": 3}
@@ -65,6 +66,13 @@ def _unpack(params, mode, input_size, state_size, num_layers, bidirectional):
     return weights, biases
 
 
+def _proj(x, w):
+    """x @ w.T at the framework's precision policy (f32 operands stay
+    f32-exact on the MXU; an unannotated ``@`` multiplies them as bf16
+    on TPU — consistency sweep, PR 21)."""
+    return jnp.matmul(x, w.T, precision=matmul_precision(x.dtype, w.dtype))
+
+
 def _scan_direction(mode, x_proj, w_h, b_h, h0, c0):
     """Scan one direction. x_proj: (T, B, G*H) input projections."""
     h = h0.shape[-1]
@@ -75,7 +83,7 @@ def _scan_direction(mode, x_proj, w_h, b_h, h0, c0):
 
         def step(carry, xp):
             hy = carry[0]
-            nh = act(xp + hy @ w_h.T + b_h)
+            nh = act(xp + _proj(hy, w_h) + b_h)
             return (nh,), nh
 
         (hT,), out = jax.lax.scan(step, (h0,), x_proj)
@@ -84,7 +92,7 @@ def _scan_direction(mode, x_proj, w_h, b_h, h0, c0):
     if mode == "lstm":
         def step(carry, xp):
             hy, cy = carry
-            pre = xp + hy @ w_h.T + b_h
+            pre = xp + _proj(hy, w_h) + b_h
             i, f, g, o = jnp.split(pre, 4, axis=-1)
             i = jax.nn.sigmoid(i)
             f = jax.nn.sigmoid(f)
@@ -100,7 +108,7 @@ def _scan_direction(mode, x_proj, w_h, b_h, h0, c0):
     if mode == "gru":
         def step(carry, xp):
             hy = carry[0]
-            rec = hy @ w_h.T + b_h
+            rec = _proj(hy, w_h) + b_h
             xr, xz, xn = jnp.split(xp, 3, axis=-1)
             hr, hz, hn = jnp.split(rec, 3, axis=-1)
             r = jax.nn.sigmoid(xr + hr)
@@ -162,7 +170,7 @@ def _rnn(rng, data, parameters, *rest, state_size=0, num_layers=1,
             # one big (T*B, in) @ (in, G*H) matmul outside the scan —
             # keeps the MXU busy with the large GEMM; only the (B, H)
             # recurrent GEMM remains sequential
-            x_proj = xs @ w_x.T + b_x
+            x_proj = _proj(xs, w_x) + b_x
             out, hT, cT = _scan_direction(mode, x_proj, w_h, b_h, h0, c0)
             if d == 1:
                 out = jnp.flip(out, 0)

@@ -15,7 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["allreduce", "allgather", "reduce_scatter", "broadcast",
            "psum", "pmean", "ppermute_ring", "axis_size"]
@@ -51,7 +51,7 @@ def _allreduce_fn(mesh, axis):
         def shard_fn(s):
             return jax.lax.psum(jnp.sum(s, axis=0), axis)
         return shard_map(shard_fn, mesh=mesh, in_specs=P(axis),
-                         out_specs=P(), check_rep=False)(x)
+                         out_specs=P(), check_vma=False)(x)
     return f
 
 
@@ -86,7 +86,7 @@ def _allgather_fn(mesh, axis):
         return shard_map(
             lambda s: jax.lax.all_gather(s, axis, axis=0, tiled=True),
             mesh=mesh, in_specs=P(axis), out_specs=P(),
-            check_rep=False)(x)
+            check_vma=False)(x)
     return f
 
 

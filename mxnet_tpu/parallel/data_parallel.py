@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .mesh import make_mesh
+from .mesh import make_mesh, use_mesh
 from ..ndarray import NDArray
 from ..observability import metrics as _obs_metrics
 
@@ -523,6 +523,17 @@ class ParallelTrainer:
             new_aux.update(auxu)
             return new_params, new_state, new_aux, loss_val
 
+        mesh = self.mesh
+
+        def in_mesh(fn):
+            # the steps trace under the trainer's mesh so ops that XLA
+            # cannot partition by itself (the Mosaic attention kernels)
+            # can shard_map over it
+            def traced(*args):
+                with use_mesh(mesh):
+                    return fn(*args)
+            return traced
+
         repl = NamedSharding(self.mesh, P())
         batch_sh = NamedSharding(self.mesh, P("dp"))
         # frozen args always live replicated, whatever param_specs says
@@ -535,7 +546,7 @@ class ParallelTrainer:
                     for n in self._opt_state}
         aux_sh = {n: repl for n in self._aux}
         self._step_fn = jax.jit(
-            train_step,
+            in_mesh(train_step),
             in_shardings=(param_sh, state_sh, aux_sh,
                           batch_sh, batch_sh, repl, None, None),
             # pin outputs to the input layout so the params/state returned
@@ -561,16 +572,19 @@ class ParallelTrainer:
             return outs[0]
 
         self._eval_fn = jax.jit(
-            eval_step, in_shardings=(param_sh, aux_sh, batch_sh,
+            in_mesh(eval_step), in_shardings=(param_sh, aux_sh, batch_sh,
                                      batch_sh, repl))
         self._predict_fn = jax.jit(
-            predict_step, in_shardings=(param_sh, aux_sh, batch_sh, repl),
+            in_mesh(predict_step),
+            in_shardings=(param_sh, aux_sh, batch_sh, repl),
             out_shardings=batch_sh)
         self._key = jax.random.PRNGKey(0)
 
     def _ensure_built(self, x, y):
         if self._step_fn is None:
-            self.net._ensure_params(NDArray(x))
+            # one row settles the deferred parameter shapes; the whole
+            # global batch would land on the default device first
+            self.net._ensure_params(NDArray(x[:1]))
             self._trace(x, y)
             self._gather_state(data_shape=x.shape, label_shape=y.shape)
             self._build_step()

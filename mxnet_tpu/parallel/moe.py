@@ -16,7 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["moe_apply"]
 
@@ -76,6 +76,6 @@ def moe_apply(expert_fn, expert_params, x, gate_w, axis_name="ep",
         param_specs = jax.tree.map(lambda _: P(axis_name), expert_params)
         return shard_map(shard_fn, mesh=mesh,
                          in_specs=(param_specs, P(axis_name), P()),
-                         out_specs=P(axis_name), check_rep=False)(
+                         out_specs=P(axis_name), check_vma=False)(
             expert_params, x, gate_w)
     return shard_fn(expert_params, x, gate_w)

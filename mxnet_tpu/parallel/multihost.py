@@ -67,28 +67,12 @@ def init_multihost(coordinator=None, num_processes=None,
         process_id = int(pid) if pid is not None else None
     if num_processes in (0, 1):
         return False
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         return True
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id, **kwargs)
     return True
-
-
-def _distributed_initialized():
-    """``jax.distributed.is_initialized`` only exists in newer jax;
-    fall back to the runtime's global coordination-client state."""
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:
-        from jax._src import distributed as _dist
-        return getattr(_dist.global_state, "client", None) is not None
-    except (ImportError, AttributeError):
-        # private-module layout changed again (module gone OR
-        # global_state renamed): treat as uninitialized —
-        # initialize() itself raises loudly if called twice
-        return False
 
 
 def process_index():

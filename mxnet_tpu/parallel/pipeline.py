@@ -16,7 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .collectives import ppermute_ring
 
@@ -94,6 +94,6 @@ def pipeline_apply(stage_fn, stage_params, x_micro, axis_name="pp",
         xs = P() if x_spec is None else x_spec
         return shard_map(shard_fn, mesh=mesh,
                          in_specs=(param_specs, xs),
-                         out_specs=xs, check_rep=False)(
+                         out_specs=xs, check_vma=False)(
             stage_params, x_micro)
     return shard_fn(stage_params, x_micro)

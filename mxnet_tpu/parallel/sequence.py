@@ -20,7 +20,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..ops.attention import (_NEG_INF, _finalize_softmax,
                              _online_softmax_update)
@@ -106,7 +106,7 @@ def sequence_parallel_attention(q, k, v, mesh, axis="sp", causal=False,
                                     sm_scale=sm_scale)
 
     out = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check_rep=False)(q, k, v)
+                    out_specs=spec, check_vma=False)(q, k, v)
     if orig_dev is not None:
         out = jax.device_put(out, orig_dev)
     return out

@@ -67,11 +67,8 @@ class Module(object):
 
     def __init__(self, source, options=(), exports=()):
         import jax.numpy as jnp
-        try:
-            from jax.experimental import pallas as pl
-            from jax.experimental.pallas import tpu as pltpu
-        except ImportError:  # pallas optional on exotic builds
-            pl = pltpu = None
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
         self._namespace = {"jax": jax, "jnp": jnp, "pl": pl,
                            "pltpu": pltpu}
         try:

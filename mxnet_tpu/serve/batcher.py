@@ -700,25 +700,26 @@ class DynamicBatcher:
                     n: np.concatenate([r.data[n] for r in taken], axis=0)
                     if len(taken) > 1 else taken[0].data[n]
                     for n in pred._data_shapes}
-                outs = pred.predict(stacked)
-                # ONE device->host readback per coalesced batch; the
-                # per-caller row splits below are numpy views.  (Lazy
-                # per-request device slices would dispatch — and on
-                # first use COMPILE — a tiny XLA program per distinct
-                # row range; results are leaving the process anyway.)
+                # ONE device->host readback per coalesced batch, of the
+                # padded rung; trimming and the per-caller row splits
+                # below are numpy views.  (Device slices would dispatch
+                # — and on first use COMPILE — a tiny XLA program per
+                # distinct row range; results are leaving the process
+                # anyway.)
+                outs = pred.predict(stacked, trim=False)
                 host = [np.asarray(o._data) for o in outs]
+                padded = pred.ladder.batch_for(rows)
                 # count successful dispatches only, in lockstep with
                 # the serve_batches_total instrument
                 with self._lock:
                     self._batches += 1
                 _BATCHES_TOTAL.inc()
-                _BATCH_OCCUPANCY.observe(
-                    rows / float(pred.ladder.batch_for(rows)))
+                _BATCH_OCCUPANCY.observe(rows / float(padded))
                 lo = 0
                 for req in taken:
                     hi = lo + req.rows
                     req.future._resolve(result=[
-                        h[lo:hi] if h.ndim and h.shape[0] == rows
+                        h[lo:hi] if h.ndim and h.shape[0] == padded
                         else h for h in host])
                     lo = hi
             except Exception as exc:

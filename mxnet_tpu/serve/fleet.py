@@ -7,11 +7,18 @@ ModelRegistry behind the socket RPC surface of replica.py), fronts
 them with a :class:`~mxnet_tpu.serve.router.Router`, and owns the
 operations a real fleet needs:
 
-* **Spawn / replace** — replicas share one persistent XLA compile
-  cache directory (``MXNET_COMPILE_CACHE_DIR``), so every replica
-  after the first warms from disk instead of compiling: scale-out
-  and crash replacement cost seconds, not minutes.  A replica is
-  READY only after every model in its spec is loaded AND warm.
+* **Spawn / replace** — replicas share this process's persistent
+  XLA compile cache directory (``config.compile_cache_dir()``), so
+  every replica after the first warms from disk instead of
+  compiling: scale-out and crash replacement cost seconds, not
+  minutes.  A replica is READY only after every model in its spec is
+  loaded AND warm.
+* **One chip each** — on a TPU host every replica is bound to its own
+  chip (``chips.one_chip_env``); a fleet larger than the host's chip
+  count is refused before anything is spawned.  The fleet's own
+  process must stay off the TPU backend: it only moves bytes, and a
+  parent that has initialized JAX on the TPU holds the chips its
+  replicas need.
 * **Rolling deploy** — :meth:`deploy` cycles replicas one at a time:
   mark draining at the router (new requests route around it) ->
   DRAIN RPC (bounded wait for every accepted request; the
@@ -41,6 +48,8 @@ import tempfile
 import time as _time
 
 from .buckets import ServeError
+from ..chips import host_chips, one_chip_env
+from ..config import compile_cache_dir
 from .replica import MSG_DRAIN, MSG_STATS, MSG_STOP
 from .router import _REPLICAS_READY, Router
 from .. import sanitizer as _san
@@ -86,10 +95,6 @@ class Fleet:
         (see ``serve.replica.main`` for the schema).
     replicas : int
         Fleet size (default 3).
-    compile_cache_dir : str, optional
-        Shared persistent XLA compile cache for every replica
-        (default: ``<workdir>/compile_cache``).  Replicas after the
-        first warm from it.
     workdir : str, optional
         Where spec files / logs live (default: a fresh tempdir).
     max_wait_ms : float, optional
@@ -103,14 +108,21 @@ class Fleet:
         pays real compiles; the rest hit the cache).
     """
 
-    def __init__(self, model_specs, replicas=3, compile_cache_dir=None,
-                 workdir=None, max_wait_ms=None, env=None,
-                 router_kwargs=None, spawn_timeout=300.0):
+    def __init__(self, model_specs, replicas=3, workdir=None,
+                 max_wait_ms=None, env=None, router_kwargs=None,
+                 spawn_timeout=300.0):
         self.model_specs = list(model_specs)
         self.size = int(replicas)
         self.workdir = workdir or tempfile.mkdtemp(prefix="mxnet_fleet_")
-        self.compile_cache_dir = compile_cache_dir or os.path.join(
-            self.workdir, "compile_cache")
+        # the replicas' shared compile cache IS this process's: a
+        # directory of the fleet's own would key every run afresh
+        self.compile_cache_dir = compile_cache_dir()
+        self._free_chips = host_chips()
+        if self._free_chips and self.size > len(self._free_chips):
+            raise ServeError(
+                "fleet of %d replicas needs %d TPU chips (one process "
+                "per chip), this host has %d"
+                % (self.size, self.size, len(self._free_chips)))
         self.max_wait_ms = max_wait_ms
         self._extra_env = dict(env or {})
         self._spawn_timeout = float(spawn_timeout)
@@ -118,7 +130,8 @@ class Fleet:
         self._lock = _san.lock(label="serve.fleet")
         self._procs = {}        # key -> record dict
         self._next_id = 0
-        _san.track(self, ("_procs", "_next_id"), label="serve.fleet")
+        _san.track(self, ("_procs", "_next_id", "_free_chips"),
+                   label="serve.fleet")
 
     # -- spawning ----------------------------------------------------------
     def _write_spec(self, name, model_specs):
@@ -130,6 +143,39 @@ class Fleet:
             json.dump(spec, f)
         return path
 
+    def _take_chip(self):
+        """Reserve a chip for one replica (None off-TPU, where every
+        replica shares the CPU)."""
+        with self._lock:
+            if self._free_chips:
+                return self._free_chips.pop(0)
+            if any(r["chip"] is not None for r in self._procs.values()):
+                raise ServeError(
+                    "no free TPU chip for another replica: %d running, "
+                    "one process per chip" % len(self._procs))
+        return None
+
+    def _release_chip(self, chip):
+        if chip is not None:
+            with self._lock:
+                self._free_chips.append(chip)
+
+    def _replica_env(self, chip=None, extra_env=None):
+        """The environment one replica process starts in."""
+        env = dict(os.environ)
+        env.update(self._extra_env)
+        env.update(extra_env or {})
+        env["JAX_COMPILATION_CACHE_DIR"] = self.compile_cache_dir
+        if chip is not None:
+            env.update(one_chip_env(chip))
+        # make the package importable regardless of the caller's cwd
+        pkg_root = os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__))))
+        env["PYTHONPATH"] = pkg_root + (
+            os.pathsep + env["PYTHONPATH"]
+            if env.get("PYTHONPATH") else "")
+        return env
+
     def _spawn(self, model_specs=None, extra_env=None):
         """Start one replica process, wait for its READY line, and
         register it with the router.  Returns the replica key."""
@@ -139,16 +185,8 @@ class Fleet:
         name = "replica-%d" % rid
         spec_path = self._write_spec(name,
                                      model_specs or self.model_specs)
-        env = dict(os.environ)
-        env.update(self._extra_env)
-        env.update(extra_env or {})
-        env["MXNET_COMPILE_CACHE_DIR"] = self.compile_cache_dir
-        # make the package importable regardless of the caller's cwd
-        pkg_root = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        env["PYTHONPATH"] = pkg_root + (
-            os.pathsep + env["PYTHONPATH"]
-            if env.get("PYTHONPATH") else "")
+        chip = self._take_chip()
+        env = self._replica_env(chip, extra_env)
         # -c instead of -m: runpy would re-execute serve.replica on
         # top of the already-imported package module (RuntimeWarning)
         proc = subprocess.Popen(
@@ -175,14 +213,17 @@ class Fleet:
         if not done.wait(self._spawn_timeout) or "port" not in ready:
             proc.kill()
             proc.wait(timeout=10)
+            self._release_chip(chip)
             raise ServeError(
-                "replica %s did not come up within %.0fs (rc=%s)"
+                "replica %s exited or stayed silent for %.0fs before "
+                "its READY line (rc=%s; its stderr is this process's)"
                 % (name, self._spawn_timeout, proc.poll()))
         handle = self.router.add_replica(
             ("127.0.0.1", ready["port"], ready.get("http", 0)))
         record = {"key": handle.key, "name": name, "proc": proc,
                   "port": ready["port"], "http_port": ready.get("http", 0),
                   "pid": ready.get("pid"), "spec_path": spec_path,
+                  "chip": chip,
                   "models": list(model_specs or self.model_specs)}
         with self._lock:
             self._procs[handle.key] = record
@@ -249,6 +290,7 @@ class Fleet:
             except subprocess.TimeoutExpired:
                 proc.kill()
                 proc.wait(timeout=5.0)
+        self._release_chip(record["chip"])
         _obs_events.emit("fleet", kind="reap", replica=key,
                          rc=proc.returncode)
         return record

@@ -358,7 +358,7 @@ class CompiledPredictor:
         return self._compiles - before
 
     # -- request path ------------------------------------------------------
-    def predict(self, data, key=None):
+    def predict(self, data, key=None, trim=True):
         """Run one padded-bucket dispatch.
 
         *data*: {input name: array} (numpy / NDArray / jax), or a
@@ -366,6 +366,12 @@ class CompiledPredictor:
         missing the batch dim (ndim == example ndim - 1) counts as a
         single example.  Returns the outputs as NDArrays, trimmed to
         the natural batch size.
+
+        ``trim=False`` returns the padded rung's rows instead.  A
+        device-side trim dispatches — and on first use COMPILES — one
+        tiny slice program per distinct (rung, rows) pair, which no
+        warm-up covers; a caller that reads the outputs back to the
+        host anyway (the batcher) trims there.
         """
         from ..ndarray import NDArray
 
@@ -418,7 +424,7 @@ class CompiledPredictor:
             self._dispatches += 1
         trimmed = []
         for o in outs:
-            if bucketed and rows != bucket_rows and \
+            if trim and bucketed and rows != bucket_rows and \
                     getattr(o, "shape", None) and o.shape and \
                     o.shape[0] == bucket_rows:
                 o = o[:rows]

@@ -48,8 +48,8 @@ exact failover path a real replica death exercises.
 
 ``python -m mxnet_tpu.serve.replica --spec spec.json`` is the process
 entry the :class:`~mxnet_tpu.serve.fleet.Fleet` spawns; it loads the
-spec's checkpoints (warming from the shared persistent XLA compile
-cache when ``MXNET_COMPILE_CACHE_DIR`` is set), starts serving, and
+spec's checkpoints (warming from the persistent XLA compile cache it
+shares with its fleet, ``config.compile_cache_dir()``), starts serving, and
 prints one ``REPLICA READY port=.. http=.. pid=..`` line for the
 parent to scrape.
 """
@@ -974,7 +974,7 @@ def main(argv=None):
     wire surface — the fleet chaos drill's streaming workload.
 
     Loads + warms every model (hitting the shared persistent XLA
-    compile cache when ``MXNET_COMPILE_CACHE_DIR`` is set), starts
+    compile cache, ``config.compile_cache_dir()``), starts
     the RPC + probe servers, prints one ``REPLICA READY`` line and
     blocks until a STOP RPC."""
     import argparse

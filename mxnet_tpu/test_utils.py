@@ -146,10 +146,8 @@ def check_numeric_gradient(sym, location, aux_states=None, eps=1e-3,
     The comparison runs in float64 — finite differences in f32 would
     drown real gradient bugs in rounding noise.
     """
-    # jax removed the top-level `jax.enable_x64` alias; the supported
-    # per-scope switch lives in jax.experimental
-    from jax.experimental import enable_x64
-    with enable_x64():
+    import jax
+    with jax.enable_x64():
         location = _as_location(sym, location)
         location = {k: np.asarray(v, np.float64)
                     for k, v in location.items()}

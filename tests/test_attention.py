@@ -279,3 +279,58 @@ def test_pallas_flash_backward_interpret(causal, sq, sk, d, blk):
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(dv), np.asarray(rv),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_lowers_for_tpu_without_a_chip(causal):
+    """The public op, lowered for the TPU platform from this CPU host:
+    the Pallas path is what a TPU program gets, and every block shape
+    passes the Mosaic lowering's tiling rule (a ``(1, blk_q)`` block over
+    a ``(bh, sq)`` array used to be refused here for the gradient)."""
+    aval = jax.ShapeDtypeStruct((2, 4, 2048, 64), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=causal)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    def calls(f):
+        text = jax.jit(f).trace(aval, aval, aval).lower(
+            lowering_platforms=("tpu",)).as_text()
+        # the chunked scan is the other platforms' branch only
+        assert "stablehlo.while" not in text
+        return text.count("tpu_custom_call")
+
+    assert calls(fwd) == 1
+    assert calls(jax.grad(loss, argnums=(0, 1, 2))) == 3   # fwd, dkdv, dq
+
+
+def test_flash_under_a_mesh_runs_per_shard():
+    """XLA does not partition a Mosaic kernel: traced under a trainer's
+    mesh the TPU branch shard_maps over it (dp splits the batch), so a
+    sharded step still lowers — with one kernel call, on local shapes."""
+    from mxnet_tpu.parallel.mesh import use_mesh
+    mesh = make_mesh({"dp": 2}, jax.devices()[:2])
+    aval = jax.ShapeDtypeStruct((4, 2, 256, 64), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        with use_mesh(mesh):
+            return flash_attention(q, k, v, causal=True)
+
+    text = jax.jit(fwd).trace(aval, aval, aval).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "sdy.manual_computation" in text or "shard_map" in text
+
+    # already per shard (a PipelineTrainer stage traces like this): the
+    # kernel is called as is, not shard_mapped a second time
+    from jax.sharding import PartitionSpec as P
+
+    def staged(q, k, v):
+        return jax.shard_map(fwd, mesh=mesh, in_specs=(P("dp"),) * 3,
+                             out_specs=P("dp"), check_vma=False)(q, k, v)
+
+    text = jax.jit(staged).trace(aval, aval, aval).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1

@@ -729,15 +729,10 @@ class TestWarmStart:
         """With the shared persistent XLA compile cache, the second
         replica's load hits disk for every program: zero NEW cache
         entries (the fleet's seconds-not-minutes scale-out claim)."""
-        import jax
         cache_dir = str(tmp_path / "cache")
-        prev = {k: getattr(jax.config, k) for k in
-                ("jax_compilation_cache_dir",
-                 "jax_persistent_cache_min_compile_time_secs",
-                 "jax_persistent_cache_min_entry_size_bytes")}
-        monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", cache_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
         from mxnet_tpu.config import enable_compile_cache
-        assert enable_compile_cache()
+        assert enable_compile_cache() == cache_dir
         try:
             reg1 = ModelRegistry()
             reg1.load("wm", kit["net"], kit["params_v1"],
@@ -754,8 +749,8 @@ class TestWarmStart:
             reg1.close()
             reg2.close()
         finally:
-            for k, v in prev.items():
-                jax.config.update(k, v)
+            monkeypatch.undo()
+            enable_compile_cache()      # back to the session's cache
 
 
 # ---------------------------------------------------------------------------

@@ -153,8 +153,7 @@ def test_rnn_op_gradient_finite_difference():
                     mode="lstm", training=False)
         return jnp.sum(out[0] ** 2)
 
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         g = jax.grad(loss)(jnp.asarray(par))
         eps = 1e-6
         for idx in rs.choice(n, size=8, replace=False):

@@ -400,6 +400,38 @@ class TestDynamicBatcher:
         finally:
             b.close()
 
+    def test_padded_batches_compile_nothing_at_all(self):
+        """Rows that do not fill their rung are trimmed on the HOST: a
+        device-side trim compiled one tiny slice program per distinct
+        (rung, rows) pair on first use — in the request path, unseen
+        by ``compile_count`` (found on the chip, PR 21)."""
+        import jax
+        net, params, aux, pred = _batcher_pred(batches=(1, 2, 4, 8))
+        compiled = []
+        watching = [False]
+
+        def listener(event, secs, **kw):
+            if watching[0] and \
+                    event == "/jax/core/compile/backend_compile_duration":
+                compiled.append(kw.get("fun_name"))
+
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        b = DynamicBatcher(pred, max_wait_ms=0)
+        try:
+            watching[0] = True
+            for rows in (3, 5, 6, 7):       # every one pads its rung
+                x = np.ones((rows, 12), np.float32)
+                out = b.submit(x).result(30)[0]
+                assert out.shape == (rows, 4)
+                ref = _eager(net, params, aux, mx.nd.array(x)).asnumpy()
+                np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
+            watching[0] = False
+        finally:
+            watching[0] = False
+            b.close()
+        assert compiled == [] or all(
+            "dynamic_slice" not in str(n) for n in compiled), compiled
+
     def test_full_batch_dispatches_before_deadline(self):
         _, _, _, pred = _batcher_pred(batches=(1, 2, 4))
         b = DynamicBatcher(pred, max_wait_ms=30000, max_batch=4)
@@ -440,10 +472,10 @@ class TestDynamicBatcher:
             real = pred.predict
             boom = {"armed": True}
 
-            def flaky(data, key=None):
+            def flaky(data, key=None, **kw):
                 if boom.pop("armed", False):
                     raise RuntimeError("injected dispatch failure")
-                return real(data, key=key)
+                return real(data, key=key, **kw)
 
             pred.predict = flaky
             with pytest.raises(RuntimeError, match="injected"):
@@ -460,9 +492,9 @@ class TestDynamicBatcher:
         # saturate: first request dispatches, hold the queue with more
         real = pred.predict
 
-        def slow(data, key=None):
+        def slow(data, key=None, **kw):
             time.sleep(0.2)
-            return real(data, key=key)
+            return real(data, key=key, **kw)
 
         pred.predict = slow
         try:
@@ -812,9 +844,9 @@ class TestCancel:
         real = pred.predict
         release = threading.Event()
 
-        def wedged(data, key=None):
+        def wedged(data, key=None, **kw):
             release.wait(10)
-            return real(data, key=key)
+            return real(data, key=key, **kw)
 
         pred.predict = wedged
         b = DynamicBatcher(pred, max_wait_ms=1)
@@ -892,10 +924,10 @@ class TestDispatcherSupervision:
             real = pred.predict
             boom = {"armed": True}
 
-            def flaky(data, key=None):
+            def flaky(data, key=None, **kw):
                 if boom.pop("armed", False):
                     raise RuntimeError("injected dispatch failure")
-                return real(data, key=key)
+                return real(data, key=key, **kw)
 
             pred.predict = flaky
             with pytest.raises(RuntimeError, match="injected"):
@@ -938,9 +970,9 @@ class TestDrain:
         _, _, _, pred = _batcher_pred()
         real = pred.predict
 
-        def slow(data, key=None):
+        def slow(data, key=None, **kw):
             time.sleep(0.5)
-            return real(data, key=key)
+            return real(data, key=key, **kw)
 
         pred.predict = slow
         b = DynamicBatcher(pred, max_wait_ms=1)
@@ -960,9 +992,9 @@ class TestDrain:
         _, _, _, pred = _batcher_pred()
         real = pred.predict
 
-        def slow(data, key=None):
+        def slow(data, key=None, **kw):
             time.sleep(0.8)
-            return real(data, key=key)
+            return real(data, key=key, **kw)
 
         pred.predict = slow
         b = DynamicBatcher(pred, max_wait_ms=5, max_batch=1)
@@ -998,9 +1030,9 @@ class TestDrain:
         real = pred.predict
         release = threading.Event()
 
-        def wedged(data, key=None):
+        def wedged(data, key=None, **kw):
             release.wait(10)
-            return real(data, key=key)
+            return real(data, key=key, **kw)
 
         pred.predict = wedged
         b = DynamicBatcher(pred, max_wait_ms=1)
@@ -1528,27 +1560,3 @@ class TestCApiBridgeServes:
             handle.close()
 
 
-# ---------------------------------------------------------------------------
-# persistent compilation cache knob
-# ---------------------------------------------------------------------------
-
-class TestCompileCacheKnob:
-    def test_env_knob_applies_and_restores(self, tmp_path, monkeypatch):
-        import jax
-        from mxnet_tpu import config
-        prior_dir = jax.config.jax_compilation_cache_dir
-        prior_min = jax.config.jax_persistent_cache_min_compile_time_secs
-        try:
-            monkeypatch.delenv("MXNET_COMPILE_CACHE_DIR", raising=False)
-            assert config.enable_compile_cache() is False
-            cache_dir = str(tmp_path / "xla-cache")
-            monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", cache_dir)
-            assert config.enable_compile_cache() is True
-            assert jax.config.jax_compilation_cache_dir == cache_dir
-            assert os.path.isdir(cache_dir)
-            assert jax.config.jax_persistent_cache_min_compile_time_secs \
-                == 0.0
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prior_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", prior_min)

@@ -599,8 +599,10 @@ open(os.path.join(os.environ["T_DIR"], "done"), "w").write("ok")
 
 def test_supervisor_detects_hang_dumps_flight_record(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # startup slack is 4x the timeout and the child's package import
+    # alone takes 3.4-3.9 s: 1.0 left no margin under a loaded suite
     s = sup.Supervisor([PY, "-c", _CHILD_HANGS],
-                       workdir=str(tmp_path), timeout=1.0,
+                       workdir=str(tmp_path), timeout=2.0,
                        max_restarts=2,
                        env={"T_DIR": str(tmp_path), "T_REPO": repo},
                        base_delay=0.01, max_delay=0.02,
@@ -611,7 +613,7 @@ def test_supervisor_detects_hang_dumps_flight_record(tmp_path):
     with open(res.flight_records[0]) as f:
         flight = json.load(f)
     assert flight["reason"] == "hang"
-    assert flight["watchdog_timeout_s"] == 1.0
+    assert flight["watchdog_timeout_s"] == 2.0
     # faulthandler stacks were dumped by the hung child
     assert flight["stacks_path"] is not None
     assert os.path.getsize(flight["stacks_path"]) > 0

@@ -39,7 +39,7 @@ class _FakePredictor:
         self.ladder = _Ladder(4)
         self.tuning = None
 
-    def predict(self, feed):
+    def predict(self, feed, trim=True):
         rows = int(feed["data"].shape[0])
         return [_Out(_np.full((rows, 2), 7.0, _np.float32))]
 

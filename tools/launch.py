@@ -8,7 +8,10 @@ processes on one host with ``DMLC_ROLE`` environment variables
 
 TPU-native differences: there is no separate scheduler role — the first
 server process binds the root port and doubles as the rendezvous point —
-and worker ranks are assigned directly by this script.
+and worker ranks are assigned directly by this script.  A chip belongs
+to one process, so on a TPU host each worker is bound to its own chip
+(``mxnet_tpu.chips``) and more workers than chips is refused; servers
+run on the CPU.
 
 Usage:
     python tools/launch.py -n 2 python examples/train_mnist.py \
@@ -24,6 +27,19 @@ import socket
 import subprocess
 import sys
 import time
+
+
+def _load_chips():
+    """``mxnet_tpu/chips.py`` loaded by path: importing the package
+    would pull in jax, which a launcher has no use for."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "_mxnet_tpu_chips", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "mxnet_tpu", "chips.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def _free_port():
@@ -66,6 +82,13 @@ def main(argv=None):
     if command[0] == "--":
         command = command[1:]
 
+    chips_mod = _load_chips()
+    chips = chips_mod.host_chips()
+    if chips and args.num_workers > len(chips):
+        parser.error("%d workers need %d TPU chips (one process per "
+                     "chip), this host has %d"
+                     % (args.num_workers, args.num_workers, len(chips)))
+
     port = args.port or _free_port()
     base_env = dict(os.environ)
     for kv in args.env:
@@ -96,6 +119,8 @@ def main(argv=None):
             env["DMLC_ROLE"] = "worker"
             env["DMLC_WORKER_RANK"] = str(i)
             env["DMLC_WORKER_ID"] = str(i)
+            if chips:
+                env.update(chips_mod.one_chip_env(chips[i]))
             p = subprocess.Popen(command, env=env)
             workers.append(("worker%d" % i, p))
         procs.extend(workers)
