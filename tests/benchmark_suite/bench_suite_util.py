@@ -1,0 +1,53 @@
+"""Shared by the benchmark suite's tests: a temporary benchmark root that
+holds the tiny CPU fixtures, and one run of a cell in this process."""
+
+import json
+import os
+import shutil
+import sys
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
+FIXTURES = os.path.join(HERE, "fixtures")
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+
+def fixture_root(tmp_path, copy_code=False):
+    """A benchmark root under *tmp_path*: the fixture `BENCHMARK.json`,
+    the real traffic mixes and peaks, the fixture configurations.  With
+    *copy_code* the whole of `benchmarks/` is copied, so that files can be
+    added beside the real ones."""
+    root = str(tmp_path / "root")
+    bench = os.path.join(root, "benchmarks")
+    if copy_code:
+        shutil.copytree(os.path.join(REPO, "benchmarks"), bench,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    else:
+        os.makedirs(os.path.join(bench, "configs"))
+        shutil.copytree(os.path.join(REPO, "benchmarks", "traffic"),
+                        os.path.join(bench, "traffic"))
+        shutil.copy(os.path.join(REPO, "benchmarks", "peaks.json"), bench)
+    shutil.copy(os.path.join(FIXTURES, "BENCHMARK.json"), root)
+    for name in ("tiny_lm.json", "tiny_resnet.json"):
+        shutil.copy(os.path.join(FIXTURES, name),
+                    os.path.join(bench, "configs"))
+    shutil.copy(os.path.join(FIXTURES, "fit_prefetch_short.json"),
+                os.path.join(bench, "traffic"))
+    return root
+
+
+def run_cell(root, workload, seed=7, seconds=1.0, trace=0):
+    """One run of a fixture cell on the CPU, past the harness's look for a
+    chip: ``(outcome, last line as a dict)``."""
+    import jax
+    from benchmarks import harness
+
+    cell = harness.Cell(workload, seed, seconds, trace,
+                        time.perf_counter(), root)
+    devices = jax.devices()[:cell.chips]
+    outcome = cell.kind().run(cell, devices)
+    line = json.loads(json.dumps(
+        harness.result_line(cell, outcome, devices)))
+    return outcome, line
