@@ -1,0 +1,13 @@
+"""Device time per step in the Pallas flash-attention dq backward kernel
+(scope `mx.flash.dq`).  Nothing to read where the step calls none."""
+
+from .. import program_spans
+
+LAYER = "kernels"
+UNIT = "ms"
+MOVES = "train_samples_per_s"
+SOURCE = "device_trace"
+
+
+def read(outcome):
+    return program_spans.scope_ms_per_step(outcome, r"/mx\.flash\.dq(/|$)")

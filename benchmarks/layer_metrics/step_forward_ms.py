@@ -1,0 +1,14 @@
+"""Device time per step in the forward pass (under `mx.loss`, no
+`transpose(` in the op_name): the traced blocks' device events, each
+given to a phase by its instruction's op_name in the step's scope map."""
+
+from .. import program_spans
+
+LAYER = "step program"
+UNIT = "ms"
+MOVES = "train_samples_per_s"
+SOURCE = "device_trace"
+
+
+def read(outcome):
+    return program_spans.phase_ms_per_step(outcome, "forward")

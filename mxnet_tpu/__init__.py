@@ -9,6 +9,9 @@ the flash-attention kernels are Pallas.  See SURVEY.md for the full blueprint.
 __version__ = "0.1.0"
 
 import os as _os
+import time as _time
+
+_import_started = _time.perf_counter()   # span mx.import, closed below
 
 # Server-role bootstrap: a process launched with DMLC_ROLE=server never
 # returns to user code — the reference's behavior
@@ -99,3 +102,7 @@ config.enable_compile_cache()
 
 def waitall():
     engine.wait_all()
+
+
+profiler._store("mx.import", "setup", _import_started,
+                _time.perf_counter())

@@ -94,10 +94,25 @@ class Context:
         return None
 
 
+_backend_up = False
+
+
+def _backend_init():
+    """The package's first look at the devices, which is where JAX brings
+    its backends up (seconds on a TPU host): span ``mx.backend_init``."""
+    global _backend_up
+    if not _backend_up:
+        from . import profiler
+        with profiler.scope("mx.backend_init", "setup"):
+            jax.devices()
+        _backend_up = True
+
+
 def _platform_devices(platform):
     """THIS process's devices for a platform.  Under jax.distributed
     (multi-host) ``jax.devices()`` is the global list including peers'
     non-addressable devices; a Context must always name a local one."""
+    _backend_init()
     try:
         return jax.local_devices(backend=platform)
     except RuntimeError:
@@ -106,6 +121,7 @@ def _platform_devices(platform):
 
 def _accelerator_devices():
     """This process's devices of the default (non-cpu) platform, else cpu."""
+    _backend_init()
     devs = jax.local_devices()
     non_cpu = [d for d in devs if d.platform != "cpu"]
     return non_cpu if non_cpu else devs
@@ -127,6 +143,7 @@ def gpu(device_id=0):
 
 
 def num_tpus():
+    _backend_init()
     devs = [d for d in jax.devices() if d.platform != "cpu"]
     return len(devs)
 

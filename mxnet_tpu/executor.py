@@ -77,11 +77,14 @@ def _build_eval(symbol, training):
             params = node.params
             if "training" in op.param_names:
                 params = dict(params, training=training)
-            if op.needs_rng:
-                sub = jax.random.fold_in(key, pos)
-                out = op.fn(sub, *ins, **params)
-            else:
-                out = op.fn(*ins, **params)
+            # a device scope per node ("<op>:<node>" in every instruction's
+            # op_name): metadata only, the program compiles as without it
+            with jax.named_scope("%s:%s" % (op.name, node.name)):
+                if op.needs_rng:
+                    sub = jax.random.fold_in(key, pos)
+                    out = op.fn(sub, *ins, **params)
+                else:
+                    out = op.fn(*ins, **params)
             if not isinstance(out, tuple):
                 out = (out,)
             for i, o in enumerate(out):

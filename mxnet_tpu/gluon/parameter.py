@@ -21,6 +21,7 @@ from ..ndarray import NDArray
 from .. import autograd
 from .. import initializer as init_mod
 from .. import symbol as sym_mod
+from .. import profiler as _prof
 
 __all__ = ["Parameter", "ParameterDict", "Constant",
            "DeferredInitializationError", "tensor_types"]
@@ -117,13 +118,16 @@ class Parameter:
         self._finish_init(init, list(ctx), default_init)
 
     def _finish_init(self, init, ctx_list, default_init):
-        data = nd.zeros(self._shape, dtype=dtype_name(self.dtype),
-                        ctx=ctx_list[0])
-        initializer = init or self.init or default_init
-        if isinstance(initializer, str):
-            initializer = init_mod.create(initializer)
-        initializer(init_mod.InitDesc(self.name), data)
-        self._init_impl(data, ctx_list)
+        # one mx.initialize span per parameter materialised, whichever
+        # way it got here (Block.initialize, its own, deferred)
+        with _prof.scope("mx.initialize", "setup"):
+            data = nd.zeros(self._shape, dtype=dtype_name(self.dtype),
+                            ctx=ctx_list[0])
+            initializer = init or self.init or default_init
+            if isinstance(initializer, str):
+                initializer = init_mod.create(initializer)
+            initializer(init_mod.InitDesc(self.name), data)
+            self._init_impl(data, ctx_list)
 
     def _init_impl(self, data, ctx_list):
         self._data = OrderedDict()

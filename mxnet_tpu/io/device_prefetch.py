@@ -39,6 +39,7 @@ from .io import DataBatch, PrefetchingIter
 from ..ndarray import NDArray
 from ..ndarray.ndarray import _already_placed, _DEVICE_PUT_ELIDED
 from ..observability import metrics as _obs_metrics
+from .. import profiler as _prof
 
 __all__ = ["DevicePrefetcher", "maybe_wrap"]
 
@@ -154,10 +155,12 @@ class DevicePrefetcher(PrefetchingIter):
             else self._device
         label_target = self._label_sharding if self._label_sharding is \
             not None else self._device
-        data = [self._put_array(a, data_target) for a in batch.data] \
-            if batch.data else batch.data
-        label = [self._put_array(a, label_target) for a in batch.label] \
-            if batch.label else batch.label
+        with _prof.scope("mx.prefetch.device_put", "input"):
+            data = [self._put_array(a, data_target) for a in batch.data] \
+                if batch.data else batch.data
+            label = [self._put_array(a, label_target)
+                     for a in batch.label] \
+                if batch.label else batch.label
         out = DataBatch(data=data, label=label, pad=batch.pad,
                         index=batch.index, bucket_key=batch.bucket_key,
                         provide_data=batch.provide_data,

@@ -19,12 +19,12 @@ import os
 import queue
 import struct
 import threading
-import time
 
 import numpy as _np
 
 from ..base import np_dtype
 from .. import ndarray as nd
+from .. import profiler as _prof
 from .. import sanitizer as _san
 from ..ndarray import NDArray
 from ..observability import metrics as _obs_metrics
@@ -527,7 +527,9 @@ class PrefetchingIter(DataIter):
         # replacement epoch's
         while not stop.is_set():
             try:
-                batch = self._transform(self._next_inner())
+                with _prof.scope("mx.prefetch.source_next", "input"):
+                    batch = self._next_inner()
+                batch = self._transform(batch)
             except StopIteration:
                 self._put(q, stop, None)
                 return
@@ -684,13 +686,14 @@ class PrefetchingIter(DataIter):
                 "fresh producer" % type(self).__name__)
         occupancy = self._queue.qsize()
         self._note_occupancy(occupancy)
-        t0 = time.perf_counter()
-        item = self._queue.get()
+        # the span's two clock reads are the wait the instruments see
+        with _prof.scope("mx.prefetch.wait", "input") as wait:
+            item = self._queue.get()
         if item is None:
             raise StopIteration
         if isinstance(item, Exception):
             raise item
-        self._note_delivery(occupancy, time.perf_counter() - t0)
+        self._note_delivery(occupancy, wait.end - wait.start)
         self._consumed += 1
         self.current_batch = item
         return item

@@ -302,25 +302,30 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=_BLK, blk_k=_BLK,
                                       lambda bh_, iq, ik: (bh_, iq, 0)))
         out_shape.append(jax.ShapeDtypeStruct((bh, sq + sq_pad, _LANES),
                                               jnp.float32))
-    res = pl.pallas_call(
-        kernel,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, dp), lambda bh_, iq, ik: (bh_, iq, 0)),
-            pl.BlockSpec((1, blk_k, dp), lambda bh_, iq, ik: (bh_, ik, 0)),
-            pl.BlockSpec((1, blk_k, dp), lambda bh_, iq, ik: (bh_, ik, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, dp), jnp.float32),
-            pltpu.VMEM((blk_q, _LANES), jnp.float32),
-            pltpu.VMEM((blk_q, _LANES), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qp, kp, vp)
+    with jax.named_scope("mx.flash.fwd"):
+        res = pl.pallas_call(
+            kernel,
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, blk_q, dp),
+                             lambda bh_, iq, ik: (bh_, iq, 0)),
+                pl.BlockSpec((1, blk_k, dp),
+                             lambda bh_, iq, ik: (bh_, ik, 0)),
+                pl.BlockSpec((1, blk_k, dp),
+                             lambda bh_, iq, ik: (bh_, ik, 0)),
+            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((blk_q, dp), jnp.float32),
+                pltpu.VMEM((blk_q, _LANES), jnp.float32),
+                pltpu.VMEM((blk_q, _LANES), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="mx_flash_fwd",
+        )(qp, kp, vp)
     out = res[0].reshape(b, h, sq + sq_pad, dp)[:, :, :sq, :d]
     if with_lse:
         return out, res[1][..., 0]  # lse stays padded (bh, sqp) for the bwd
@@ -462,34 +467,39 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
                             lambda bh_, a, b_: (bh_, b_, 0))
 
     # dk/dv: grid (bh, nk, nq) — k-block resident, q streamed
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, **common),
-        grid=(bh, nk, nq),
-        in_specs=[q_spec_k, k_spec_k, k_spec_k, q_spec_k, r_spec_k,
-                  r_spec_k],
-        out_specs=[k_spec_k, k_spec_k],
-        out_shape=[jax.ShapeDtypeStruct((bh, sk + sk_pad, dp), k.dtype),
-                   jax.ShapeDtypeStruct((bh, sk + sk_pad, dp), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk_k, dp), jnp.float32),
-                        pltpu.VMEM((blk_k, dp), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qp, kp, vp, dop, lse, delta)
+    with jax.named_scope("mx.flash.dkdv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_flash_bwd_dkdv_kernel, **common),
+            grid=(bh, nk, nq),
+            in_specs=[q_spec_k, k_spec_k, k_spec_k, q_spec_k, r_spec_k,
+                      r_spec_k],
+            out_specs=[k_spec_k, k_spec_k],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, sk + sk_pad, dp), k.dtype),
+                jax.ShapeDtypeStruct((bh, sk + sk_pad, dp), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((blk_k, dp), jnp.float32),
+                            pltpu.VMEM((blk_k, dp), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="mx_flash_dkdv",
+        )(qp, kp, vp, dop, lse, delta)
 
     # dq: grid (bh, nq, nk) — q-block resident, k streamed
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(bh, nq, nk),
-        in_specs=[q_spec_q, k_spec_q, k_spec_q, q_spec_q, r_spec_q,
-                  r_spec_q],
-        out_specs=q_spec_q,
-        out_shape=jax.ShapeDtypeStruct((bh, sq + sq_pad, dp), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_q, dp), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qp, kp, vp, dop, lse, delta)
+    with jax.named_scope("mx.flash.dq"):
+        dq = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel, **common),
+            grid=(bh, nq, nk),
+            in_specs=[q_spec_q, k_spec_q, k_spec_q, q_spec_q, r_spec_q,
+                      r_spec_q],
+            out_specs=q_spec_q,
+            out_shape=jax.ShapeDtypeStruct((bh, sq + sq_pad, dp), q.dtype),
+            scratch_shapes=[pltpu.VMEM((blk_q, dp), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="mx_flash_dq",
+        )(qp, kp, vp, dop, lse, delta)
 
     dq = dq.reshape(b, h, sq + sq_pad, dp)[:, :, :sq, :d]
     dk = dk.reshape(b, h, sk + sk_pad, dp)[:, :, :sk, :d]

@@ -23,12 +23,14 @@ what Gluon's Trainer uses when constructed with ``kvstore='tpu'``.
 from __future__ import annotations
 
 import re
+import time
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import make_mesh, use_mesh
+from .. import profiler as _prof
 from ..ndarray import NDArray
 from ..observability import metrics as _obs_metrics
 
@@ -149,6 +151,7 @@ class ParallelTrainer:
         # jax.checkpoint policy callable
         self.remat = remat
         self._step_fn = None
+        self._step_called = False
         self._eval_fn = None
         self._params = None          # name -> jax array (device, sharded)
         self._opt_state = None
@@ -458,7 +461,6 @@ class ParallelTrainer:
         def train_step(params, opt_state, aux, x, y, key, lr, t):
             # trace-time only — the compile counter for the sharded step
             # (cached executions bump nothing; see profiler.py counters)
-            from .. import profiler as _prof
             _prof.bump_counter(  # graftlint: disable=JG003
                 "parallel_step_compiles")  # trace-time-only on purpose
 
@@ -471,15 +473,28 @@ class ParallelTrainer:
 
             if remat is not None:
                 loss_of = jax.checkpoint(loss_of, policy=policy)
-            (loss_val, auxu), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(params)
+            # device scopes (docs/observability.md "Spans"): forward and
+            # backward under mx.loss, then mx.grad_clip and mx.optimizer
+            with jax.named_scope("mx.loss"):
+                (loss_val, auxu), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(params)
             if grad_clip is not None:
-                gnorm = jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for n, g in grads.items() if n not in frozen))
-                scale = jnp.minimum(1.0, grad_clip / (gnorm + 1e-8))
-                grads = {k: (g.astype(jnp.float32) * scale).astype(g.dtype)
-                         for k, g in grads.items()}
+                with jax.named_scope("mx.grad_clip"):
+                    gnorm = jnp.sqrt(sum(
+                        jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for n, g in grads.items() if n not in frozen))
+                    scale = jnp.minimum(1.0, grad_clip / (gnorm + 1e-8))
+                    grads = {
+                        k: (g.astype(jnp.float32) * scale).astype(g.dtype)
+                        for k, g in grads.items()}
+            with jax.named_scope("mx.optimizer"):
+                new_params, new_state = apply_updates(
+                    params, grads, opt_state, lr, t)
+            new_aux = dict(aux)
+            new_aux.update(auxu)
+            return new_params, new_state, new_aux, loss_val
+
+        def apply_updates(params, grads, opt_state, lr, t):
             new_params = {}
             new_state = {}
             hp = dict(opt_hp)
@@ -519,19 +534,19 @@ class ParallelTrainer:
                 sp, ss = _apply_small(params, grads, opt_state, lr)
                 new_params.update(sp)
                 new_state.update(ss)
-            new_aux = dict(aux)
-            new_aux.update(auxu)
-            return new_params, new_state, new_aux, loss_val
+            return new_params, new_state
 
         mesh = self.mesh
 
-        def in_mesh(fn):
+        def in_mesh(fn, program):
             # the steps trace under the trainer's mesh so ops that XLA
             # cannot partition by itself (the Mosaic attention kernels)
-            # can shard_map over it
+            # can shard_map over it.  *program* names the compiled module
+            # (jit_<program> in XProf and in the scope map's op_names)
             def traced(*args):
                 with use_mesh(mesh):
                     return fn(*args)
+            traced.__name__ = traced.__qualname__ = program
             return traced
 
         repl = NamedSharding(self.mesh, P())
@@ -546,7 +561,7 @@ class ParallelTrainer:
                     for n in self._opt_state}
         aux_sh = {n: repl for n in self._aux}
         self._step_fn = jax.jit(
-            in_mesh(train_step),
+            in_mesh(train_step, "parallel_step"),
             in_shardings=(param_sh, state_sh, aux_sh,
                           batch_sh, batch_sh, repl, None, None),
             # pin outputs to the input layout so the params/state returned
@@ -572,10 +587,10 @@ class ParallelTrainer:
             return outs[0]
 
         self._eval_fn = jax.jit(
-            in_mesh(eval_step), in_shardings=(param_sh, aux_sh, batch_sh,
-                                     batch_sh, repl))
+            in_mesh(eval_step, "parallel_eval"),
+            in_shardings=(param_sh, aux_sh, batch_sh, batch_sh, repl))
         self._predict_fn = jax.jit(
-            in_mesh(predict_step),
+            in_mesh(predict_step, "parallel_predict"),
             in_shardings=(param_sh, aux_sh, batch_sh, repl),
             out_shardings=batch_sh)
         self._key = jax.random.PRNGKey(0)
@@ -585,9 +600,44 @@ class ParallelTrainer:
             # one row settles the deferred parameter shapes; the whole
             # global batch would land on the default device first
             self.net._ensure_params(NDArray(x[:1]))
-            self._trace(x, y)
-            self._gather_state(data_shape=x.shape, label_shape=y.shape)
-            self._build_step()
+            with _prof.scope("mx.trainer.trace", "setup"):
+                self._trace(x, y)
+            with _prof.scope("mx.trainer.gather_state", "setup"):
+                self._gather_state(data_shape=x.shape,
+                                   label_shape=y.shape)
+            with _prof.scope("mx.trainer.build_step", "setup"):
+                self._build_step()
+
+    def _first_step(self, x, y):
+        """The step program's first call: trace and lower, compile or
+        load from the cache, then hand the profiler the compiled step's
+        scope map.  The lowering made here is the one the call uses, and
+        the executable the call built is the one whose text is read:
+        nothing is lowered or compiled twice.  Span
+        ``mx.step.first_call``; its args say where it went: `lower_s`
+        (tracing and lowering), `call_s` (compile or load, and the
+        dispatch), `scope_map_s` (the text and its parse), and what JAX
+        reported meanwhile under each compile event."""
+        self._step_called = True
+        with _prof.scope("mx.step.first_call", "setup") as span:
+            before = _prof.compile_seconds()
+            args = self._step_args(x, y)
+            lowered = self._step_fn.lower(*args)
+            t_lowered = time.perf_counter()
+            out = self._step_fn(*args)
+            t_called = time.perf_counter()
+            # no new program: `compile()` hands back the executable the
+            # call above built from this same lowering
+            text = lowered.compile().as_text()  # graftlint: disable=JG014
+            _prof.set_scope_map("parallel_step", text)
+            del lowered, text
+            span.args = dict(
+                {k: v - before[k]
+                 for k, v in _prof.compile_seconds().items()},
+                lower_s=t_lowered - span.start,
+                call_s=t_called - t_lowered,
+                scope_map_s=time.perf_counter() - t_called)
+        return out
 
     def _device_batch(self, x):
         if isinstance(x, NDArray):
@@ -693,20 +743,34 @@ class ParallelTrainer:
         if isinstance(y, NDArray):
             y = y._data
         from ..resilience import chaos
-        chaos.on_train_step(self._num_update)
-        self._ensure_built(x, y)
-        self._refresh_frozen(x.shape, y.shape)
+        # span mx.fit_batch: its self time is the trainer's own
+        # bookkeeping.  Its one child holds everything handed to the
+        # device (the batch's cast and placement, the key split, lr and t,
+        # the step): whichever of them comes first waits when the
+        # device's queue is full, and on the chip that was not the step
+        # (PERF.md, PR 24)
+        with _prof.scope("mx.fit_batch", "trainer"):
+            chaos.on_train_step(self._num_update)
+            self._ensure_built(x, y)
+            self._refresh_frozen(x.shape, y.shape)
+            _prof.bump_counter("parallel_step_dispatches")
+            if self._step_called:
+                with _prof.scope("mx.fit_batch.dispatch", "trainer"):
+                    out = self._step_fn(*self._step_args(x, y))
+            else:
+                out = self._first_step(x, y)
+            self._params, self._opt_state, self._aux, loss = out
+            self._num_update += 1
+        return loss
+
+    def _step_args(self, x, y):
         xd = self._device_batch(x)
         yd = self._label_batch(y)
         self._key, sub = jax.random.split(self._key)
         lr = jnp.asarray(self._current_lr(), jnp.float32)
         t = jnp.asarray(self._num_update + 1, jnp.int32)
-        from .. import profiler as _prof
-        _prof.bump_counter("parallel_step_dispatches")
-        self._params, self._opt_state, self._aux, loss = self._step_fn(
-            self._params, self._opt_state, self._aux, xd, yd, sub, lr, t)
-        self._num_update += 1
-        return loss
+        return (self._params, self._opt_state, self._aux, xd, yd, sub,
+                lr, t)
 
     def _current_lr(self):
         sched = self.opt_params.get("lr_scheduler")

@@ -22,6 +22,8 @@ import numpy as _np
 import jax
 from jax.sharding import Mesh, PartitionSpec, NamedSharding
 
+from ..context import _backend_init
+
 __all__ = ["make_mesh", "current_mesh", "use_mesh", "data_parallel_mesh",
            "PartitionSpec", "NamedSharding", "named_sharding"]
 
@@ -35,6 +37,7 @@ def make_mesh(axes=None, devices=None):
     a -1 size is inferred), e.g. {"dp": -1} or {"dp": 2, "tp": 4}.
     """
     if devices is None:
+        _backend_init()
         devices = jax.devices()
     n = len(devices)
     if axes is None:
@@ -57,6 +60,7 @@ def make_mesh(axes=None, devices=None):
 
 
 def data_parallel_mesh(n=None):
+    _backend_init()
     devs = jax.devices()
     if n is not None:
         devs = devs[:n]

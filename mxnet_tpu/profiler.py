@@ -13,14 +13,28 @@ worker thread for the same reason).  Device-side timelines come from the
 XLA profiler: ``set_config(profile_device=True)`` starts a
 ``jax.profiler`` trace whose TensorBoard-loadable output lands next to
 the chrome-trace file.
+
+Spans: :class:`scope` is the one span API and ``_spans`` the one store, a
+bounded ring read with :func:`spans`.  The program's own ``mx.*`` scopes
+(docs/observability.md "Spans") are always on; each is mirrored as a
+``jax.profiler.TraceAnnotation``, so while a device trace is taken it
+also lies on the profiler's host plane, on the device trace's clock.
 """
 
 from __future__ import annotations
 
+import collections
+import gc
+import itertools
 import json
+import logging
 import os
+import re
 import threading
 import time
+
+import jax.monitoring
+from jax.profiler import TraceAnnotation
 
 from . import sanitizer as _san
 from .observability import metrics as _metrics
@@ -28,15 +42,151 @@ from .observability import metrics as _metrics
 __all__ = ["set_config", "set_state", "pause", "resume", "dump", "dumps",
            "profiler_set_config", "profiler_set_state",
            "Domain", "Task", "Frame", "Event", "Counter", "Marker",
-           "scope", "bump_counter", "counter_value", "counters",
+           "scope", "spans", "Span", "scope_map", "set_scope_map",
+           "compile_seconds", "bump_counter", "counter_value", "counters",
            "reset_counters"]
 
 _lock = _san.rlock(label="profiler._lock")
-_events = []            # chrome trace event dicts
+_marks = []             # chrome trace counter ('C') and marker ('i') dicts
 _agg = {}               # name -> [count, total_us, min_us, max_us]
 
+# -- the span store -----------------------------------------------------------
+#: one finished host span: times on `time.perf_counter`, `thread` the
+#: ident of the thread it ran on, `parent` the id of the span that was
+#: open on that thread when it began (None at the top)
+Span = collections.namedtuple(
+    "Span", "id name cat start end thread parent args")
+
+SPAN_RING = 65536       # spans kept; the oldest leave first
+_spans = collections.deque(maxlen=SPAN_RING)
+_ids = itertools.count(1)
+_open = threading.local()   # .stack: ids of the spans open on this thread
+
+
+def _stack():
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
+
+
+def _store(name, cat, start, end, thread=None, parent=None, args=None,
+           span_id=None):
+    """Append one finished span to the ring (lock-free: a deque append is
+    atomic) and, while profiling runs, to the aggregate table."""
+    _spans.append(Span(span_id or next(_ids), name, cat, start, end,
+                       thread or threading.get_ident(), parent, args))
+    if is_running():
+        dur_us = (end - start) * 1e6
+        with _lock:
+            st = _agg.setdefault(name, [0, 0.0, float("inf"), 0.0])
+            st[0] += 1
+            st[1] += dur_us
+            st[2] = min(st[2], dur_us)
+            st[3] = max(st[3], dur_us)
+
+
+def spans(since=None):
+    """The finished spans still in the ring, oldest first; with *since*
+    (a `time.perf_counter` reading) those that ended at or after it."""
+    while True:
+        try:
+            out = list(_spans)
+            break
+        except RuntimeError:    # another thread (or a collection) appended
+            continue
+    if since is not None:
+        out = [s for s in out if s.end >= since]
+    return out
+
+
+# -- device scopes: optimized-HLO instruction -> op_name ----------------------
+# `jax.named_scope` names ("mx.loss", "<op>:<node>", "mx.flash.fwd") reach
+# the compiled program as each instruction's `op_name` metadata; a device
+# trace names its events by instruction, so the map from the one to the
+# other is what lets a reader of the trace say which phase, operator or
+# kernel an event belongs to.  Plain strings only: the map must keep no
+# trainer and no array alive.
+_scope_maps = {}
+_HLO_OP_NAME = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*?\bop_name="([^"]*)"', re.M)
+
+
+def set_scope_map(program, hlo_text):
+    """Keep ``{instruction name: op_name}`` of *program*'s optimized HLO
+    text (`Compiled.as_text()`); the text itself is not kept."""
+    shared = {}
+    _scope_maps[program] = {
+        m.group(1): shared.setdefault(m.group(2), m.group(2))
+        for m in _HLO_OP_NAME.finditer(hlo_text)}
+
+
+def scope_map(program):
+    """``{instruction name: op_name}`` of *program* ("parallel_step": the
+    `ParallelTrainer` step), or None before its first call."""
+    return _scope_maps.get(program)
+
+
+# -- what a program's first call spends, by jax.monitoring event --------------
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        "jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration":
+        "backend_compile_duration",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "cache_retrieval_time_sec",
+}
+_compile_seconds = {
+    short: _metrics.counter(
+        "jax_%s_seconds" % short.replace("_sec", ""),
+        "seconds JAX reported under its monitoring event %s" % event)
+    for event, short in _COMPILE_EVENTS.items()}
+
+
+def _on_duration(event, seconds, **_):
+    short = _COMPILE_EVENTS.get(event)
+    if short is not None and seconds > 0:
+        _compile_seconds[short].inc(seconds)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compile_seconds():
+    """Seconds so far under each of JAX's compile events: tracing to a
+    jaxpr, lowering to MLIR, the backend's compile, and loading from the
+    persistent cache.  A difference of two readings says what a span
+    spent on each."""
+    return {short: c.value for short, c in _compile_seconds.items()}
+
+
+# -- full collections of Python's heap ----------------------------------------
+_gc_started = [None]
+
+
+def _on_gc(phase, info):
+    """`gc.callbacks` entry: one `mx.gc` span per generation-2 collection,
+    on the thread it stopped (a candidate for a stalled step that nothing
+    else records)."""
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _gc_started[0] = time.perf_counter()
+    elif _gc_started[0] is not None:
+        stack = _stack()
+        _store("mx.gc", "gc", _gc_started[0], time.perf_counter(), None,
+               stack[-1] if stack else None,
+               {"collected": info.get("collected", 0)})
+        _gc_started[0] = None
+
+
+gc.callbacks.append(_on_gc)
+
+
 # -- dispatch / compile counters --------------------------------------------
-# Always-on (unlike spans, which need set_state('run')): these are the
+# Always-on, like the `mx.*` spans: these are the
 # observable for the fused-train-step contract — "after warmup, one
 # training step is exactly ONE jitted dispatch and ZERO compiles" —
 # and tests must be able to assert it without turning tracing on.
@@ -176,8 +326,12 @@ def set_state(state="stop", profile_process="worker"):
             try:
                 jax.profiler.start_trace(trace_dir)
                 _state["jax_trace"] = trace_dir
-            except Exception:
+            except Exception as e:
                 _state["jax_trace"] = None
+                logging.getLogger(__name__).warning(
+                    "profile_device=True, but no device trace is being "
+                    "taken: jax.profiler.start_trace(%r) failed: %s: %s",
+                    trace_dir, type(e).__name__, e)
     elif state == "stop" and _state["running"]:
         _state["running"] = False
         if _state["jax_trace"]:
@@ -198,21 +352,12 @@ def resume():
 
 
 def record_span(name, cat, t0_s, t1_s, tid=0, args=None):
-    """Add one complete ('X') event; timestamps in seconds."""
+    """Add one complete span, only while profiling runs; timestamps in
+    seconds on `time.perf_counter`."""
     if not is_running():
         return
-    dur_us = (t1_s - t0_s) * 1e6
-    with _lock:
-        _events.append({
-            "name": name, "cat": cat, "ph": "X",
-            "ts": t0_s * 1e6, "dur": dur_us,
-            "pid": os.getpid(), "tid": tid,
-            **({"args": args} if args else {})})
-        st = _agg.setdefault(name, [0, 0.0, float("inf"), 0.0])
-        st[0] += 1
-        st[1] += dur_us
-        st[2] = min(st[2], dur_us)
-        st[3] = max(st[3], dur_us)
+    stack = _stack()
+    _store(name, cat, t0_s, t1_s, tid, stack[-1] if stack else None, args)
 
 
 def record_counter(name, value):
@@ -223,19 +368,19 @@ def record_counter(name, value):
     if not is_running():
         return
     with _lock:
-        _events.append({"name": name, "ph": "C",
-                        "ts": time.perf_counter() * 1e6,
-                        "pid": os.getpid(), "tid": 0,
-                        "args": {name: value}})
+        _marks.append({"name": name, "ph": "C",
+                       "ts": time.perf_counter() * 1e6,
+                       "pid": os.getpid(), "tid": 0,
+                       "args": {name: value}})
 
 
 def record_marker(name, cat="marker"):
     if not is_running():
         return
     with _lock:
-        _events.append({"name": name, "cat": cat, "ph": "i",
-                        "ts": time.perf_counter() * 1e6,
-                        "pid": os.getpid(), "tid": 0, "s": "p"})
+        _marks.append({"name": name, "cat": cat, "ph": "i",
+                       "ts": time.perf_counter() * 1e6,
+                       "pid": os.getpid(), "tid": 0, "s": "p"})
 
 
 def dump(finished=True, profile_process="worker"):
@@ -264,8 +409,13 @@ def dump(finished=True, profile_process="worker"):
         counter_events.append({"name": "metrics/" + name, "ph": "C",
                                "ts": now_us, "pid": pid, "tid": 0,
                                "args": args})
+    span_events = [
+        {"name": s.name, "cat": s.cat, "ph": "X", "ts": s.start * 1e6,
+         "dur": (s.end - s.start) * 1e6, "pid": pid, "tid": s.thread,
+         **({"args": s.args} if s.args else {})}
+        for s in spans()]
     with _lock:
-        data = {"traceEvents": list(_events) + counter_events,
+        data = {"traceEvents": span_events + list(_marks) + counter_events,
                 "displayTimeUnit": "ms"}
         with open(_config["filename"], "w") as f:
             json.dump(data, f)
@@ -290,7 +440,8 @@ def dumps(reset=False):
 
 def reset():
     with _lock:
-        _events.clear()
+        _spans.clear()
+        _marks.clear()
         _agg.clear()
 
 
@@ -300,18 +451,36 @@ profiler_set_state = set_state
 
 
 class scope:
-    """Context manager timing a named host-side span."""
+    """Context manager timing a named host-side span: always recorded
+    (two clock reads, a ring append, and a `TraceAnnotation` that is a
+    flag test while no device trace runs), closed on an exception too.
+    `start` and `end` are its `time.perf_counter` readings; `args`, set
+    any time before it closes, travels with the span."""
+
+    __slots__ = ("name", "cat", "args", "start", "end", "_id", "_parent",
+                 "_ann")
 
     def __init__(self, name, cat="user"):
         self.name = name
         self.cat = cat
+        self.args = None
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        stack = _stack()
+        self._parent = stack[-1] if stack else None
+        self._id = next(_ids)
+        stack.append(self._id)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        record_span(self.name, self.cat, self._t0, time.perf_counter())
+        self.end = time.perf_counter()
+        self._ann.__exit__(*exc)
+        _stack().pop()
+        _store(self.name, self.cat, self.start, self.end, None,
+               self._parent, self.args, self._id)
 
 
 class Domain:
