@@ -14,7 +14,10 @@ mesh (``mxnet_tpu.parallel.sequence``).  This module provides:
   softmax in f32 scratch (saving the per-row logsumexp), and a custom VJP
   running the standard flash backward as two Pallas kernels
   (``_flash_bwd_dkdv_kernel`` / ``_flash_bwd_dq_kernel``) that recompute
-  p from the saved logsumexp and accumulate blockwise.
+  p from the saved logsumexp and accumulate tile by tile.  All three
+  share one tile plan (``_flash_plan``): resident blocks of Q and K/V
+  a grid step, score sub-tiles walked in loops bounded by the causal
+  limit.
 - ``_contrib_DotProductAttention`` / ``_contrib_div_sqrt_dim`` registered
   operators, so the op is reachable from mx.nd / mx.sym like any other.
 
@@ -23,6 +26,7 @@ Layout is (batch, heads, seq, head_dim) throughout.
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -38,16 +42,24 @@ from .registry import register_op
 __all__ = ["flash_attention", "attention_reference"]
 
 _NEG_INF = -1e30
-# Per-row softmax state (running max, denominator, logsumexp, delta)
-# rides lane-replicated as (rows, _LANES): Mosaic tiles the last two
-# dims of every block (8, 128), so a (1, blk_q) block over a (bh, sq)
-# array does not lower and 1-D VMEM scratch has no native layout.
+# Inside a kernel the per-row softmax state (running max, denominator)
+# rides lane-replicated as (rows, _LANES): 1-D VMEM scratch has no native
+# layout.  In HBM the per-row residuals (logsumexp, delta) are compact
+# rows of a (B*H, 1, seq_q) array, whose (1, 1, n) blocks Mosaic accepts.
 _LANES = 128
-# Default q and k block length.  On v5e 512 and 1024 both compile and
-# land the same distance from the f32 reference; 2048 asks for 25.8 MiB
-# of the 16 MiB scoped VMEM and does not compile (CHANGES.md PR 21).
-# Untuned: no timing chose it.
-_BLK = 1024
+# Score sub-tile (query rows, key columns) of each kernel.  Chosen by
+# tools/flash_sweep.py on the v5e at (b 2, h 32, s 2048, d 64, bf16,
+# causal), the shape of the benchmark's LM cell (docs/PERF_NOTES.md
+# "Flash attention kernel" has the table).
+_SUB_UNROLLED = (256, 256)
+_SUB_LOOPED = {"fwd": (256, 512), "dkdv": (512, 256), "dq": (256, 512)}
+# What the plan lets a kernel's blocks, scratch and tile temporaries take
+# of the 16 MiB of scoped VMEM on the v5e; the rest is Mosaic's own.
+_VMEM_BUDGET = 12 << 20
+# Tiles a loop iteration holds (`_loop`), and the longest static loop
+# that is unrolled whole.
+_UNROLL = 4
+_UNROLL_WHOLE = 8
 
 
 def attention_reference(q, k, v, causal=False, sm_scale=None):
@@ -164,86 +176,271 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512):
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash forward kernel.
+# The tile plan of the three Pallas calls.
 # ---------------------------------------------------------------------------
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_and_scratch,
-                      sm_scale, causal, blk_q, blk_k, seq_q, seq_k):
-    if len(maybe_lse_and_scratch) == 4:
-        lse_ref, acc_ref, m_ref, l_ref = maybe_lse_and_scratch
-    else:  # inference path: no logsumexp output allocated
-        lse_ref = None
-        acc_ref, m_ref, l_ref = maybe_lse_and_scratch
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
-    iq = pl.program_id(1)
+#: one kernel's tiles: a grid step keeps `res_q` query rows and `res_k`
+#: key rows resident in VMEM and walks score tiles of `sub_q` x `sub_k`
+#: inside them, in loops whose bounds come from the causal limit
+_Tiles = collections.namedtuple("_Tiles", "res_q res_k sub_q sub_k")
+#: what `_flash_plan` hands the wrappers: the head dim as the kernels see
+#: it, the padded sequence lengths of the forward and of the backward
+#: pair, and each kernel's tiles
+_Plan = collections.namedtuple(
+    "_Plan", "d_block sq_fwd sk_fwd sq_bwd sk_bwd fwd dkdv dq")
+_KERNELS = ("fwd", "dkdv", "dq")
 
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _compute():
-        # operands stay in their storage dtype: a bf16 x bf16 MXU dot
-        # with f32 accumulation (preferred_element_type) runs at the
-        # full bf16 MXU rate — pre-casting to f32 would halve it
-        q = q_ref[0]                               # (blk_q, d)
-        k = k_ref[0]                               # (blk_k, d)
-        v = v_ref[0]
-        s = _mxu_dot(q, k, ((1,), (1,))) * sm_scale
+def _round_up(n, m):
+    return -(-n // m) * m
 
-        k_pos = ik * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = k_pos < seq_k
-        if causal:
-            # sequence ends aligned (decode-style cross-length causal),
-            # same convention as attention_reference/_chunked_attention
-            q_pos = (iq * blk_q + (seq_k - seq_q)
-                     + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-            mask = mask & (k_pos <= q_pos)
-        s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_ref[...]                        # (blk_q, _LANES)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - _lanes_to(m_new, blk_k))
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        # p in v's dtype for the second MXU dot (flash convention: the
-        # f32 online-softmax state carries the precision; p's entries
-        # are probabilities in [0,1] where bf16 relative error is ~2^-8)
-        acc_ref[...] = (acc_ref[...] * _lanes_to(alpha, acc_ref.shape[1])
-                        + _mxu_dot(p.astype(v.dtype), v, ((1,), (0,))))
+def _d_block(d):
+    """The head dim as the kernels see it.  A block whose last dimension
+    equals the array's is legal for Mosaic, so a multiple of 64 crosses
+    HBM at its own width; anything else is padded to the 128-lane tile."""
+    return d if d % 64 == 0 else _round_up(d, _LANES)
 
+
+def _side_bytes(kernel, d_block, itemsize):
+    """VMEM bytes that one resident query row and one resident key row
+    cost *kernel*: its double-buffered blocks, its f32 accumulators and
+    its row statistics (a `(1, n)` f32 block occupies 8 sublanes).  The
+    lane dimension is padded to 128 in VMEM whatever the array's width."""
+    wide = _round_up(d_block, _LANES)
+    blk = 2 * wide * itemsize           # one array's two buffers
+    acc = wide * 4
+    row = 2 * 8 * 4                     # one statistics row's two buffers
+    return {
+        # q, o, acc, m, l, lse out        k, v
+        "fwd": (2 * blk + acc + 2 * _LANES * 4 + row, 2 * blk),
+        # q, do, lse, delta               k, v, dk, dv, two accumulators
+        "dkdv": (2 * blk + 2 * row, 4 * blk + 2 * acc),
+        # q, do, dq, acc, lse, delta      k, v
+        "dq": (3 * blk + acc + 2 * row, 2 * blk),
+    }[kernel]
+
+
+def _tile_bytes(sub_q, sub_k):
+    """VMEM for one tile's f32 score-sized temporaries (s, p, dp, ds, the
+    mask's iotas, the hoisted statistics)."""
+    return 6 * sub_q * sub_k * 4
+
+
+def _vmem_bytes(kernel, t, d_block, itemsize):
+    """The VMEM *kernel* asks for with tiles *t*, by the plan's model."""
+    per_q, per_k = _side_bytes(kernel, d_block, itemsize)
+    return (per_q * t.res_q + per_k * t.res_k
+            + _tile_bytes(t.sub_q, t.sub_k))
+
+
+def _resident(n_sub, bytes_per_sub, budget):
+    """How many sub-tiles a resident block holds: the largest divisor of
+    *n_sub* that fits *budget*, at least one."""
+    fit = max(1, budget // bytes_per_sub)
+    return max(c for c in range(1, n_sub + 1)
+               if n_sub % c == 0 and c <= fit)
+
+
+def _kernel_tiles(kernels, subs, sq, sk, d_block, itemsize, res_q, res_k):
+    """``(sq_padded, sk_padded, {kernel: _Tiles})`` for *kernels*, which
+    share their padded operands, with *subs* ``{kernel: (sub_q, sub_k)}``
+    cut to the sequence: the resident blocks are the largest
+    `_VMEM_BUDGET` holds, the streamed side first (K/V for the forward
+    and dq, Q/dO for dk/dv)."""
+    sq_p = _round_up(sq, res_q or math.lcm(*(subs[k][0] for k in kernels)))
+    sk_p = _round_up(sk, res_k or math.lcm(*(subs[k][1] for k in kernels)))
+    tiles = {}
+    for kernel in kernels:
+        sub_q, sub_k = subs[kernel]
+        nq, nk = sq_p // sub_q, sk_p // sub_k
+        per_q, per_k = _side_bytes(kernel, d_block, itemsize)
+        per_q, per_k = per_q * sub_q, per_k * sub_k
+        budget = _VMEM_BUDGET - _tile_bytes(sub_q, sub_k)
+        if kernel == "dkdv":
+            cq = _resident(nq, per_q, budget // 2)
+            ck = _resident(nk, per_k, budget - cq * per_q)
+        else:
+            ck = _resident(nk, per_k, budget // 2)
+            cq = _resident(nq, per_q, budget - ck * per_k)
+        tiles[kernel] = _Tiles(res_q or cq * sub_q, res_k or ck * sub_k,
+                               sub_q, sub_k)
+    return sq_p, sk_p, tiles
+
+
+def _unrolls_whole(t, sq_p, sk_p):
+    """Whether a kernel with tiles *t* is one grid step a head whose
+    loops `_loop` unrolls whole: every bound and slice static."""
+    return (t.res_q, t.res_k) == (sq_p, sk_p) and \
+        max(sq_p // t.sub_q, sk_p // t.sub_k) <= _UNROLL_WHOLE
+
+
+def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
+                res_k=None):
+    """Tiles of the three kernels from what the call can see: the
+    lengths, the head dim and the dtype.  One algorithm with different
+    parameters at different shapes.  Where a head fits VMEM and its
+    tiles are few enough to unroll whole (S = 2048 at d = 64), the
+    sub-tile is `_SUB_UNROLLED`, the one closest to the causal triangle;
+    where the loops stay loops it is `_SUB_LOOPED`'s, wider on the
+    streamed side: fewer, larger iterations (both from the sweep).
+    `causal` is not an input: the same tiles serve both, the loops'
+    bounds differ.  *blk_q*, *blk_k* (sub-tile edges) and *res_q*,
+    *res_k* (resident rows; multiples of the sub-tile that divide the
+    padded length) override, for the tests and the sweep."""
+    itemsize = jnp.dtype(dtype).itemsize
+    d_block = _d_block(d)
+
+    def cut(sub):
+        return (min(blk_q or sub[0], _round_up(sq, 1 if blk_q else _LANES)),
+                min(blk_k or sub[1], _round_up(sk, 1 if blk_k else _LANES)))
+
+    plan = {}
+    # the backward pair shares its padded copies of q, k, v and dO
+    for kernels in (("fwd",), ("dkdv", "dq")):
+        found = _kernel_tiles(
+            kernels, {k: cut(_SUB_UNROLLED) for k in kernels}, sq, sk,
+            d_block, itemsize, res_q, res_k)
+        if not all(_unrolls_whole(t, *found[:2])
+                   for t in found[2].values()):
+            found = _kernel_tiles(
+                kernels, {k: cut(_SUB_LOOPED[k]) for k in kernels}, sq, sk,
+                d_block, itemsize, res_q, res_k)
+        plan[kernels[0]] = found
+    (sq_f, sk_f, fwd), (sq_b, sk_b, bwd) = plan["fwd"], plan["dkdv"]
+    return _Plan(d_block, sq_f, sk_f, sq_b, sk_b, **fwd, **bwd)
+
+
+# The loop bounds below run on Python ints (the plan's counts) and on the
+# kernels' traced scalars alike; numerators are clamped at 0 first, so
+# the division is the same truncating one on both.
+def _imax(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
+
+
+def _imin(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.minimum(a, b)
+
+
+def _idiv(a, b):
+    return a // b if isinstance(a, int) else jax.lax.div(a, jnp.int32(b))
+
+
+def _k_tiles(row0, k0, n, t, off, seq_k, causal):
+    """``(n_full, n_vis)`` for the query sub-tile whose first row is
+    *row0*, over the *n* key sub-tiles of the resident block that starts
+    at column *k0*: tiles ``[0, n_vis)`` hold a visible score, and the
+    first ``n_full`` of them nothing else (neither the diagonal nor the
+    padding crosses them).  *off* is ``seq_k - seq_q``: ends aligned."""
+    n_full = n_vis = n
     if causal:
-        # skip K blocks entirely above the diagonal: their tiles are fully
-        # masked and would pay two MXU dots for nothing (~2x on sq == sk)
-        visible = ik * blk_k <= iq * blk_q + blk_q - 1 + (seq_k - seq_q)
-        pl.when(visible)(_compute)
+        n_vis = _imin(n, _idiv(
+            _imax(row0 + t.sub_q + off - k0, 0) + t.sub_k - 1, t.sub_k))
+        n_full = _idiv(_imax(row0 + off + 1 - k0, 0), t.sub_k)
+    n_real = _idiv(_imax(seq_k - k0, 0), t.sub_k)
+    return _imin(_imin(n_full, n_real), n_vis), n_vis
+
+
+def _q_tiles(col0, q0, n, t, off, seq_k, causal):
+    """``(j_first, j_full)`` for the key sub-tile whose first column is
+    *col0*, over the *n* query sub-tiles of the resident block that
+    starts at row *q0*: tiles ``[j_first, n)`` hold a visible score, and
+    from ``j_full`` on nothing else."""
+    j_first = j_full = 0
+    if causal:
+        j_first = _imin(n, _idiv(_imax(col0 - off - q0, 0), t.sub_q))
+        j_full = _imin(n, _idiv(
+            _imax(col0 + t.sub_k - 1 - off - q0, 0) + t.sub_q - 1,
+            t.sub_q))
+    padded = col0 + t.sub_k > seq_k
+    if isinstance(padded, bool):
+        return j_first, (n if padded else j_full)
+    return j_first, jnp.where(padded, n, j_full)
+
+
+def _last_k_block(iq, t, nkr, off):
+    """The last resident key block a causal query block *iq* sees."""
+    return _imin(nkr - 1, _idiv(
+        _imax(iq * t.res_q + t.res_q - 1 + off, 0), t.res_k))
+
+
+def _first_q_block(ik, t, nqr, off):
+    """The first resident query block that sees causal key block *ik*."""
+    return _imin(nqr - 1, _idiv(_imax(ik * t.res_k - off, 0), t.res_q))
+
+
+def _tile_counts(kernel, plan, sq, sk, causal):
+    """What the schedule of *kernel* visits, for one head: score tiles
+    computed, those of them computed under the mask, and the visible
+    scores in tiles (`tiles_ideal`: the causal triangle, or the
+    rectangle).  The same bound functions as the kernels, on ints."""
+    t = getattr(plan, kernel)
+    sq_p, sk_p = (plan.sq_fwd, plan.sk_fwd) if kernel == "fwd" \
+        else (plan.sq_bwd, plan.sk_bwd)
+    off = sk - sq
+    nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
+    visited = masked = 0
+    for q0 in range(0, sq_p, t.res_q):
+        for k0 in range(0, sk_p, t.res_k):
+            if kernel == "dkdv":
+                for jk in range(nks):
+                    first, full = _q_tiles(k0 + jk * t.sub_k, q0, nqs, t,
+                                           off, sk, causal)
+                    visited += nqs - first
+                    masked += full - first
+            else:
+                for jq in range(nqs):
+                    full, vis = _k_tiles(q0 + jq * t.sub_q, k0, nks, t,
+                                         off, sk, causal)
+                    visited += vis
+                    masked += vis - full
+    if causal:
+        lo, hi = max(0, -off), sq            # rows that see a key
+        scores = (hi - lo) * (lo + off + hi + off + 1) // 2 if hi > lo \
+            else 0
     else:
-        _compute()
+        scores = sq * sk
+    return {"tiles_visited": visited, "tiles_masked": masked,
+            "tiles_ideal": round(scores / (t.sub_q * t.sub_k), 3)}
 
-    @pl.when(ik == nk - 1)
-    def _finish():
-        # rows whose running max never rose above the sentinel saw no
-        # visible key (causal with seq_q > seq_k): emit zeros, and a
-        # +1e30 lse so the backward's recomputed p = exp(s - lse)
-        # underflows to 0 for them — zero output, zero gradient, same
-        # convention as attention_reference/_chunked_attention
-        m = m_ref[...]
-        degenerate = m <= _NEG_INF * 0.5
-        l_safe = jnp.where(degenerate, 1.0, l_ref[...])
-        # widen the f32 state, then compare: Mosaic does not tile or
-        # reshape i1 vectors
-        dp = acc_ref.shape[1]
-        o_ref[0] = jnp.where(_lanes_to(m, dp) <= _NEG_INF * 0.5, 0.0,
-                             acc_ref[...] / _lanes_to(l_safe, dp)
-                             ).astype(o_ref.dtype)
-        if lse_ref is not None:
-            # logsumexp residual for the flash backward
-            lse_ref[0] = jnp.where(degenerate, -_NEG_INF,
-                                   m + jnp.log(l_safe))
 
+def _plan_args(plan, sq, sk, d, dtype, causal):
+    """The plan as the `mx.flash.plan` span carries it: static per shape,
+    so recorded where the call is traced, not where it runs."""
+    rec = {"sq": sq, "sk": sk, "d": d, "dtype": jnp.dtype(dtype).name,
+           "causal": bool(causal), "d_block": plan.d_block}
+    for kernel in _KERNELS:
+        t = getattr(plan, kernel)
+        rec[kernel] = dict(
+            _tile_counts(kernel, plan, sq, sk, causal),
+            resident=[t.res_q, t.res_k], sub_tile=[t.sub_q, t.sub_k],
+            vmem_bytes=_vmem_bytes(kernel, t, plan.d_block,
+                                   jnp.dtype(dtype).itemsize))
+    return rec
+
+
+def _record_plan(q, k, causal):
+    """One `mx.flash.plan` span each time the op is traced.  At trace
+    time on purpose: the plan is a fact of the compiled program, not of
+    a step."""
+    from .. import profiler
+    sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
+    with profiler.scope(  # graftlint: disable=JG003
+            "mx.flash.plan", "flash") as span:
+        span.args = _plan_args(_flash_plan(sq, sk, d, q.dtype), sq, sk, d,
+                               q.dtype, causal)
+
+
+# ---------------------------------------------------------------------------
+# Pallas flash kernels.  Shared conventions: operands enter the MXU in
+# their storage dtype and accumulate f32; the softmax state is f32; a
+# grid step holds resident blocks of Q and of K/V and loops over score
+# sub-tiles between bounds that come from the causal limit, so a tile
+# above the diagonal costs neither a grid step, a DMA nor a branch, and
+# only the tiles the diagonal or the padding crosses pay for the mask.
+# ---------------------------------------------------------------------------
 
 def _mxu_dot(a, b, contract):
     """In-kernel MXU dot, f32 accumulation, at the framework's precision
@@ -256,259 +453,483 @@ def _mxu_dot(a, b, contract):
         preferred_element_type=jnp.float32)
 
 
+_NT = ((1,), (1,))      # a @ b.T: contract both operands' last dim
+_NN = ((1,), (0,))      # a @ b
+
+
 def _lanes_to(x, n):
-    """Widen lane-replicated row state (rows, _LANES) to (rows, n)."""
+    """Widen (or cut) lane-replicated row state (rows, _LANES) to
+    (rows, n)."""
+    if n <= _LANES:
+        return x[:, :n]
     reps, rem = divmod(n, _LANES)
     if rem:
         return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
     return jnp.tile(x, (1, reps))
 
 
-def _pad_bh(x, s_pad, d_pad):
+def _sub(j, size, count):
+    """Rows of sub-tile *j* of *count* in a resident block."""
+    if count == 1:
+        return slice(0, size)
+    return pl.ds(pl.multiple_of(j * size, size), size)
+
+
+def _tile_mask(shape, q_axis, row0, col0, off, seq_k, causal, padded_k):
+    """Visibility of the score tile whose first query row is *row0* and
+    first key column *col0*; query rows run along *q_axis*.  Sequence
+    ends aligned (decode-style cross-length causal), the convention of
+    attention_reference and _chunked_attention."""
+    k_pos = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    mask = k_pos < seq_k if padded_k else None
+    if causal:
+        q_pos = row0 + off + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                      q_axis)
+        mask = k_pos <= q_pos if mask is None else mask & (k_pos <= q_pos)
+    return mask
+
+
+def _grid_pos(axis, n):
+    """This step's index along grid *axis* of static size *n*; the int 0
+    where the axis is a single step, so that what follows from it (the
+    loops' bounds, the slices) is static."""
+    return 0 if n == 1 else pl.program_id(axis)
+
+
+def _loop(lo, hi, body):
+    """Run ``body(j)`` for j in [lo, hi), for its effects on refs.
+    Tiles in one basic block let the scheduler run one tile's MXU work
+    under another's VPU work (a `fori_loop` iteration is a block of its
+    own: at 256x256 tiles the forward takes 1.6 times as long that way,
+    tools/flash_sweep.py).  So a short static loop is unrolled whole, and
+    any other runs `_UNROLL` tiles an iteration, the rest one by one."""
+    if isinstance(lo, int) and isinstance(hi, int) and \
+            hi - lo <= _UNROLL_WHOLE:
+        for j in range(lo, hi):
+            body(j)
+        return
+    chunks = _idiv(hi - lo, _UNROLL)
+
+    def chunk(i, carry):
+        for u in range(_UNROLL):
+            body(lo + i * _UNROLL + u)
+        return carry
+
+    def one(j, carry):
+        body(j)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk, 0)
+    jax.lax.fori_loop(lo + chunks * _UNROLL, hi, one, 0)
+
+
+def _two_loops(bounds, tile, any_masked):
+    """Run *tile* over ``[lo, mid)`` and ``[mid, hi)`` with the mask on
+    in the halves that *bounds* ``(lo, mid, hi, masked_first)`` says."""
+    lo, mid, hi, masked_first = bounds
+    for a, b, masked in ((lo, mid, masked_first),
+                         (mid, hi, not masked_first)):
+        if masked and not any_masked:
+            continue            # no diagonal and no padding: never runs
+        _loop(a, b, functools.partial(tile, masked=masked))
+
+
+def _traced_inline(kernel):
+    """Trace *kernel*'s body with `jax.disable_jit`: every `jnp` function
+    and array operator is itself a jitted function, and an unrolled body
+    calls some two thousand of them; traced as nested jits they take
+    several seconds of set-up on the chip's host, inline a fraction."""
+    @functools.wraps(kernel)
+    def wrapped(*refs, **params):
+        with jax.disable_jit():
+            return kernel(*refs, **params)
+    return wrapped
+
+
+@_traced_inline
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_and_scratch,
+                      t, grid, sm_scale, causal, seq_q, seq_k, padded_k):
+    if len(maybe_lse_and_scratch) == 4:
+        lse_ref, acc_ref, m_ref, l_ref = maybe_lse_and_scratch
+    else:  # inference path: no logsumexp output allocated
+        lse_ref = None
+        acc_ref, m_ref, l_ref = maybe_lse_and_scratch
+    nkr = grid[1]
+    iq, ik = _grid_pos(1, grid[0]), _grid_pos(2, nkr)
+    nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
+    off = seq_k - seq_q
+    d = acc_ref.shape[1]
+
+    # a query tile's first and last steps sit in its own loop body, not
+    # at the kernel's ends: unrolled, they run under other tiles' dots
+    def q_tile(jq):
+        qs = _sub(jq, t.sub_q, nqs)
+        row0 = iq * t.res_q + jq * t.sub_q
+        q = q_ref[0, qs, :]
+
+        @pl.when(ik == 0)
+        def _init():
+            acc_ref[qs, :] = jnp.zeros((t.sub_q, d), jnp.float32)
+            m_ref[qs, :] = jnp.full((t.sub_q, _LANES), _NEG_INF)
+            l_ref[qs, :] = jnp.zeros((t.sub_q, _LANES), jnp.float32)
+
+        def tile(jk, masked):
+            ks = _sub(jk, t.sub_k, nks)
+            k = k_ref[0, ks, :]
+            v = v_ref[0, ks, :]
+            s = _mxu_dot(q, k, _NT) * sm_scale      # (sub_q, sub_k)
+            if masked:
+                s = jnp.where(_tile_mask(
+                    s.shape, 0, row0, ik * t.res_k + jk * t.sub_k, off,
+                    seq_k, causal, padded_k), s, _NEG_INF)
+            m_prev = m_ref[qs, :]                   # (sub_q, _LANES)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - _lanes_to(m_new, t.sub_k))
+            l_ref[qs, :] = l_ref[qs, :] * alpha + p.sum(axis=-1,
+                                                        keepdims=True)
+            m_ref[qs, :] = m_new
+            # p in v's dtype for the second MXU dot (flash convention:
+            # the f32 online-softmax state carries the precision; p's
+            # entries are probabilities in [0,1] where bf16 relative
+            # error is ~2^-8)
+            acc_ref[qs, :] = acc_ref[qs, :] * _lanes_to(alpha, d) + \
+                _mxu_dot(p.astype(v.dtype), v, _NN)
+
+        n_full, n_vis = _k_tiles(row0, ik * t.res_k, nks, t, off, seq_k,
+                                 causal)
+        _two_loops((0, n_full, n_vis, False), tile, causal or padded_k)
+
+        @pl.when(ik == nkr - 1)
+        def _finish():
+            # rows whose running max never rose above the sentinel saw no
+            # visible key (causal with seq_q > seq_k): emit zeros, and a
+            # +1e30 lse so the backward's recomputed p = exp(s - lse)
+            # underflows to 0 for them — zero output, zero gradient, same
+            # convention as attention_reference/_chunked_attention
+            m = m_ref[qs, :]
+            degenerate = m <= _NEG_INF * 0.5
+            l_safe = jnp.where(degenerate, 1.0, l_ref[qs, :])
+            # widen the f32 state, then compare: Mosaic does not tile or
+            # reshape i1 vectors
+            o_ref[0, qs, :] = jnp.where(
+                _lanes_to(m, d) <= _NEG_INF * 0.5, 0.0,
+                acc_ref[qs, :] / _lanes_to(l_safe, d)).astype(o_ref.dtype)
+            if lse_ref is not None:
+                # logsumexp residual for the flash backward, compact: the
+                # lane-replicated column state leaves as part of a row
+                lse = jnp.where(degenerate, -_NEG_INF, m + jnp.log(l_safe))
+                lse_ref[0, :, qs] = lse.T[:1]
+
+    _loop(0, nqs, q_tile)
+
+
+def _pad_bh(x, s_to, d_to):
+    """(b, h, s, d) as (b*h, s_to, d_to), zero padded; no copy where the
+    shape already fits."""
     b, h, s, d = x.shape
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, s_pad), (0, d_pad)))
-    return xp.reshape(b * h, s + s_pad, d + d_pad)
+    if s_to != s or d_to != d:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, s_to - s), (0, d_to - d)))
+    return x.reshape(b * h, s_to, d_to)
 
 
-def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=_BLK, blk_k=_BLK,
-                      interpret=False, with_lse=False):
-    """Flash forward: grid (B*H, nq, nk); f32 accumulators in VMEM
-    scratch.  ``with_lse`` also returns the per-row logsumexp residual
-    (the flash backward's recompute anchor)."""
+def _unpad_bh(x, b, h, s, d):
+    """(b*h, s_padded, d_padded) back to (b, h, s, d); no copy where
+    nothing was padded."""
+    s_p, d_p = x.shape[1:]
+    x = x.reshape(b, h, s_p, d_p)
+    return x if (s_p, d_p) == (s, d) else x[:, :, :s, :d]
+
+
+def _k_index(t, nkr, off, causal):
+    """Index map of the key-side blocks on a grid (bh, iq, ik).  Causal,
+    a step above the diagonal is empty (its loops run no tile): its
+    index is clamped to the last block the query block sees, so the
+    empty step refetches nothing."""
+    if causal and nkr > 1:
+        return lambda bh_, iq, ik: jnp.minimum(
+            ik, _last_k_block(iq, t, nkr, off))
+    return lambda bh_, iq, ik: ik
+
+
+def _block_specs(t, d_block, q_index, k_index):
+    """BlockSpecs of a resident query-side block, a key-side block and a
+    query-side statistics row."""
+    return (pl.BlockSpec((1, t.res_q, d_block),
+                         lambda *g: (g[0], q_index(*g), 0)),
+            pl.BlockSpec((1, t.res_k, d_block),
+                         lambda *g: (g[0], k_index(*g), 0)),
+            pl.BlockSpec((1, 1, t.res_q),
+                         lambda *g: (g[0], 0, q_index(*g))))
+
+
+# The wrappers are jitted for the trace cache alone: an unrolled kernel
+# body takes 0.4 to 0.7 s to trace and as long again to lower, and a
+# model calls the same kernel once a layer.  Through the cache the body
+# is traced once per shape and emitted as one function that every layer
+# calls (XLA inlines it; each call site keeps its own op_name).
+_STATIC = ("causal", "sm_scale", "blk_q", "blk_k", "interpret", "res_q",
+           "res_k")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("with_lse",))
+def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
+                      interpret=False, with_lse=False, res_q=None,
+                      res_k=None):
+    """Flash forward: grid (B*H, resident q blocks, resident k blocks),
+    f32 accumulators in VMEM scratch.  ``with_lse`` also returns the
+    per-row logsumexp residual (the flash backward's recompute anchor)
+    as ``(B*H, 1, seq_q)``."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    blk_q = min(blk_q, sq)
-    blk_k = min(blk_k, sk)
-    # pad seq dims to block multiples, head dim to the 128-lane tile
-    d_pad = -d % 128
-    sq_pad = -sq % blk_q
-    sk_pad = -sk % blk_k
-    qp = _pad_bh(q, sq_pad, d_pad)
-    kp = _pad_bh(k, sk_pad, d_pad)
-    vp = _pad_bh(v, sk_pad, d_pad)
+    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k)
+    t, dp = plan.fwd, plan.d_block
+    sq_p, sk_p = plan.sq_fwd, plan.sk_fwd
+    qp = _pad_bh(q, sq_p, dp)
+    kp = _pad_bh(k, sk_p, dp)
+    vp = _pad_bh(v, sk_p, dp)
     bh = b * h
-    dp = d + d_pad
-    nq = (sq + sq_pad) // blk_q
-    nk = (sk + sk_pad) // blk_k
+    nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
 
+    q_spec, k_spec, row_spec = _block_specs(
+        t, dp, lambda bh_, iq, ik: iq, _k_index(t, nkr, sk - sq, causal))
     kernel = functools.partial(
-        _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        blk_q=blk_q, blk_k=blk_k, seq_q=sq, seq_k=sk)
-    out_specs = [pl.BlockSpec((1, blk_q, dp),
-                              lambda bh_, iq, ik: (bh_, iq, 0))]
-    out_shape = [jax.ShapeDtypeStruct((bh, sq + sq_pad, dp), q.dtype)]
+        _flash_fwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
+        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk)
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct((bh, sq_p, dp), q.dtype)]
     if with_lse:  # training: also emit the logsumexp residual
-        out_specs.append(pl.BlockSpec((1, blk_q, _LANES),
-                                      lambda bh_, iq, ik: (bh_, iq, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, sq + sq_pad, _LANES),
-                                              jnp.float32))
+        out_specs.append(row_spec)
+        out_shape.append(jax.ShapeDtypeStruct((bh, 1, sq_p), jnp.float32))
     with jax.named_scope("mx.flash.fwd"):
         res = pl.pallas_call(
             kernel,
-            grid=(bh, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, blk_q, dp),
-                             lambda bh_, iq, ik: (bh_, iq, 0)),
-                pl.BlockSpec((1, blk_k, dp),
-                             lambda bh_, iq, ik: (bh_, ik, 0)),
-                pl.BlockSpec((1, blk_k, dp),
-                             lambda bh_, iq, ik: (bh_, ik, 0)),
-            ],
+            grid=(bh, nqr, nkr),
+            in_specs=[q_spec, k_spec, k_spec],
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[
-                pltpu.VMEM((blk_q, dp), jnp.float32),
-                pltpu.VMEM((blk_q, _LANES), jnp.float32),
-                pltpu.VMEM((blk_q, _LANES), jnp.float32),
+                pltpu.VMEM((t.res_q, dp), jnp.float32),
+                pltpu.VMEM((t.res_q, _LANES), jnp.float32),
+                pltpu.VMEM((t.res_q, _LANES), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
             name="mx_flash_fwd",
         )(qp, kp, vp)
-    out = res[0].reshape(b, h, sq + sq_pad, dp)[:, :, :sq, :d]
+    out = _unpad_bh(res[0], b, h, sq, d)
     if with_lse:
-        return out, res[1][..., 0]  # lse stays padded (bh, sqp) for the bwd
+        return out, res[1][:, :, :sq]
     return out
 
 
 # ---------------------------------------------------------------------------
 # Pallas flash backward kernels (standard flash-attention backward:
-# recompute p from the saved logsumexp, accumulate dq / dk / dv blockwise;
-# delta_i = rowsum(dO_i * O_i) precomputed at the XLA level).
+# recompute p from the saved logsumexp, accumulate dq / dk / dv tile by
+# tile; delta_i = rowsum(dO_i * O_i) precomputed at the XLA level).  lse
+# and delta enter compact, as rows of a (B*H, 1, seq_q) array.
 # ---------------------------------------------------------------------------
 
-def _bwd_p_block(q_ref, k_ref, lse_ref, iq, ik, *, sm_scale, causal,
-                 blk_q, blk_k, seq_q, seq_k):
-    """Recomputed softmax block p = exp(q k^T * scale - lse).
-
-    The dot keeps the storage dtype (bf16 runs at full MXU rate) and
-    accumulates f32 via preferred_element_type."""
-    q = q_ref[0]
-    k = k_ref[0]
-    s = _mxu_dot(q, k, ((1,), (1,))) * sm_scale
-    k_pos = ik * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = k_pos < seq_k
-    if causal:
-        q_pos = (iq * blk_q + (seq_k - seq_q)
-                 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-        mask = mask & (k_pos <= q_pos)
-    s = jnp.where(mask, s, _NEG_INF)
-    return jnp.exp(s - _lanes_to(lse_ref[0], blk_k))
+def _wide(x, width):
+    """A (rows, d) operand of the backward kernels, zero-extended to the
+    accumulators' 128-lane width inside VMEM.  At d = 64 the dots that
+    contract over d or write d columns then run on whole lane tiles
+    (dk/dv 9% and dq 6% faster at S = 2048 than on 64-wide operands,
+    tools/flash_sweep.py; the forward measured flat and is left
+    narrow); HBM holds the 64 columns only."""
+    d = x.shape[1]
+    return x if d == width else jnp.pad(x, ((0, 0), (0, width - d)))
 
 
+@_traced_inline
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                           delta_ref, dk_ref, dv_ref,
-                           dk_acc, dv_acc, *, sm_scale, causal,
-                           blk_q, blk_k, seq_q, seq_k):
-    ik = pl.program_id(1)
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
+                           delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                           t, grid, sm_scale, causal, seq_q, seq_k,
+                           padded_k):
+    """K/V block resident, Q/dO sub-tiles from the first visible row on.
+    The scores are computed transposed, (sub_k, sub_q): the statistics
+    then broadcast along sublanes as the rows they are, and both
+    accumulating dots are plain a @ b (p^T dO, ds^T q) with no transpose
+    of a score tile."""
+    nqr = grid[0]
+    ik, iq = _grid_pos(1, grid[1]), _grid_pos(2, nqr)
+    nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
+    off = seq_k - seq_q
+    d, wide = dk_ref.shape[2], dk_acc.shape[1]
 
-    @pl.when(iq == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+    def k_tile(jk):
+        ks = _sub(jk, t.sub_k, nks)
+        col0 = ik * t.res_k + jk * t.sub_k
+        k = _wide(k_ref[0, ks, :], wide)
+        v = _wide(v_ref[0, ks, :], wide)
 
-    def _compute():
-        p = _bwd_p_block(q_ref, k_ref, lse_ref, iq, ik,
-                         sm_scale=sm_scale, causal=causal, blk_q=blk_q,
-                         blk_k=blk_k, seq_q=seq_q, seq_k=seq_k)
-        do = do_ref[0]
-        v = v_ref[0]
-        q = q_ref[0]
-        # dv += p^T dO — p cast to the storage dtype for a full-rate
-        # MXU dot; accumulators stay f32
-        dv_acc[...] += _mxu_dot(p.astype(do.dtype), do, ((0,), (0,)))
-        # ds = p * (dO v^T - delta) * scale;  dk += ds^T q
-        dp = _mxu_dot(do, v, ((1,), (1,)))
-        ds = p * (dp - _lanes_to(delta_ref[0], blk_k)) * sm_scale
-        dk_acc[...] += _mxu_dot(ds.astype(q.dtype), q, ((0,), (0,)))
+        @pl.when(iq == 0)
+        def _init():
+            dk_acc[ks, :] = jnp.zeros((t.sub_k, wide), jnp.float32)
+            dv_acc[ks, :] = jnp.zeros((t.sub_k, wide), jnp.float32)
 
-    if causal:
-        visible = ik * blk_k <= iq * blk_q + blk_q - 1 + (seq_k - seq_q)
-        pl.when(visible)(_compute)
-    else:
-        _compute()
+        def tile(jq, masked):
+            qs = _sub(jq, t.sub_q, nqs)
+            q = _wide(q_ref[0, qs, :], wide)
+            do = _wide(do_ref[0, qs, :], wide)
+            s = _mxu_dot(k, q, _NT) * sm_scale      # (sub_k, sub_q)
+            if masked:
+                s = jnp.where(_tile_mask(
+                    s.shape, 1, iq * t.res_q + jq * t.sub_q, col0, off,
+                    seq_k, causal, padded_k), s, _NEG_INF)
+            p = jnp.exp(s - lse_ref[0, :, qs])
+            # dv += p^T dO — p cast to the storage dtype for a full-rate
+            # MXU dot; accumulators stay f32
+            dv_acc[ks, :] += _mxu_dot(p.astype(do.dtype), do, _NN)
+            # ds = p * (dO v^T - delta) * scale;  dk += ds^T q
+            ds = p * (_mxu_dot(v, do, _NT) - delta_ref[0, :, qs]) * sm_scale
+            dk_acc[ks, :] += _mxu_dot(ds.astype(q.dtype), q, _NN)
 
-    @pl.when(iq == nq - 1)
-    def _finish():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        j_first, j_full = _q_tiles(col0, iq * t.res_q, nqs, t, off, seq_k,
+                                   causal)
+        _two_loops((j_first, j_full, nqs, True), tile, causal or padded_k)
 
+        @pl.when(iq == nqr - 1)
+        def _finish():
+            dk_ref[0, ks, :] = dk_acc[ks, :d].astype(dk_ref.dtype)
+            dv_ref[0, ks, :] = dv_acc[ks, :d].astype(dv_ref.dtype)
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                         delta_ref, dq_ref, dq_acc, *, sm_scale, causal,
-                         blk_q, blk_k, seq_q, seq_k):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    def _compute():
-        p = _bwd_p_block(q_ref, k_ref, lse_ref, iq, ik,
-                         sm_scale=sm_scale, causal=causal, blk_q=blk_q,
-                         blk_k=blk_k, seq_q=seq_q, seq_k=seq_k)
-        do = do_ref[0]
-        v = v_ref[0]
-        k = k_ref[0]
-        dp = _mxu_dot(do, v, ((1,), (1,)))
-        ds = p * (dp - _lanes_to(delta_ref[0], blk_k)) * sm_scale
-        dq_acc[...] += _mxu_dot(ds.astype(k.dtype), k, ((1,), (0,)))
-
-    if causal:
-        visible = ik * blk_k <= iq * blk_q + blk_q - 1 + (seq_k - seq_q)
-        pl.when(visible)(_compute)
-    else:
-        _compute()
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+    _loop(0, nks, k_tile)
 
 
+@_traced_inline
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         dq_ref, dq_acc, *, t, grid, sm_scale, causal,
+                         seq_q, seq_k, padded_k):
+    """Q/dO block resident, K/V sub-tiles up to the causal limit."""
+    nkr = grid[1]
+    iq, ik = _grid_pos(1, grid[0]), _grid_pos(2, nkr)
+    nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
+    off = seq_k - seq_q
+    d, wide = dq_ref.shape[2], dq_acc.shape[1]
+
+    def q_tile(jq):
+        qs = _sub(jq, t.sub_q, nqs)
+        row0 = iq * t.res_q + jq * t.sub_q
+        q = _wide(q_ref[0, qs, :], wide)
+        do = _wide(do_ref[0, qs, :], wide)
+        # the statistics' rows as columns, widened once per query tile
+        shape = (t.sub_q, t.sub_k)
+        lse = jnp.broadcast_to(lse_ref[0, :, qs].reshape(t.sub_q, 1), shape)
+        delta = jnp.broadcast_to(delta_ref[0, :, qs].reshape(t.sub_q, 1),
+                                 shape)
+
+        @pl.when(ik == 0)
+        def _init():
+            dq_acc[qs, :] = jnp.zeros((t.sub_q, wide), jnp.float32)
+
+        def tile(jk, masked):
+            ks = _sub(jk, t.sub_k, nks)
+            k = _wide(k_ref[0, ks, :], wide)
+            v = _wide(v_ref[0, ks, :], wide)
+            s = _mxu_dot(q, k, _NT) * sm_scale      # (sub_q, sub_k)
+            if masked:
+                s = jnp.where(_tile_mask(
+                    s.shape, 0, row0, ik * t.res_k + jk * t.sub_k, off,
+                    seq_k, causal, padded_k), s, _NEG_INF)
+            p = jnp.exp(s - lse)
+            ds = p * (_mxu_dot(do, v, _NT) - delta) * sm_scale
+            dq_acc[qs, :] += _mxu_dot(ds.astype(k.dtype), k, _NN)
+
+        n_full, n_vis = _k_tiles(row0, ik * t.res_k, nks, t, off, seq_k,
+                                 causal)
+        _two_loops((0, n_full, n_vis, False), tile, causal or padded_k)
+
+        @pl.when(ik == nkr - 1)
+        def _finish():
+            dq_ref[0, qs, :] = dq_acc[qs, :d].astype(dq_ref.dtype)
+
+    _loop(0, nqs, q_tile)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
-                      blk_q=_BLK, blk_k=_BLK, interpret=False):
+                      blk_q=None, blk_k=None, interpret=False, res_q=None,
+                      res_k=None):
+    """dq, dk, dv from the forward's output and its ``(B*H, 1, seq_q)``
+    logsumexp."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    blk_q = min(blk_q, sq)
-    blk_k = min(blk_k, sk)
-    d_pad = -d % 128
-    sq_pad = -sq % blk_q
-    sk_pad = -sk % blk_k
-    qp = _pad_bh(q, sq_pad, d_pad)
-    kp = _pad_bh(k, sk_pad, d_pad)
-    vp = _pad_bh(v, sk_pad, d_pad)
-    dop = _pad_bh(dout, sq_pad, d_pad)
-    outp = _pad_bh(out, sq_pad, d_pad)
-    bh, dp = b * h, d + d_pad
-    nq = (sq + sq_pad) // blk_q
-    nk = (sk + sk_pad) // blk_k
-    # delta_i = rowsum(dO_i * O_i) — zero on padded rows since dO is 0
-    delta = jnp.sum(dop.astype(jnp.float32) * outp.astype(jnp.float32),
-                    axis=-1)
-    # the per-row residuals enter lane-replicated (see _LANES); the
-    # widened copies live only for this call, the saved lse is (bh, sqp)
-    lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LANES,))
-    delta = jnp.broadcast_to(delta[..., None], delta.shape + (_LANES,))
+    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k)
+    dp, sq_p, sk_p = plan.d_block, plan.sq_bwd, plan.sk_bwd
+    wide = _round_up(dp, _LANES)        # the accumulators' lanes
+    qp = _pad_bh(q, sq_p, dp)
+    kp = _pad_bh(k, sk_p, dp)
+    vp = _pad_bh(v, sk_p, dp)
+    dop = _pad_bh(dout, sq_p, dp)
+    bh = b * h
+    # delta_i = rowsum(dO_i * O_i), a row like lse; both zero on padded
+    # rows, where dO is zero too and p = exp(0 - 0) multiplies nothing
+    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(bh, 1, sq)
+    if sq_p != sq:
+        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, sq_p - sq)))
+        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, sq_p - sq)))
+    common = dict(sm_scale=sm_scale, causal=causal, seq_q=sq, seq_k=sk,
+                  padded_k=sk_p != sk)
 
-    common = dict(sm_scale=sm_scale, causal=causal, blk_q=blk_q,
-                  blk_k=blk_k, seq_q=sq, seq_k=sk)
-    q_spec_q = pl.BlockSpec((1, blk_q, dp), lambda bh_, a, b_: (bh_, a, 0))
-    q_spec_k = pl.BlockSpec((1, blk_q, dp), lambda bh_, a, b_: (bh_, b_, 0))
-    k_spec_q = pl.BlockSpec((1, blk_k, dp), lambda bh_, a, b_: (bh_, b_, 0))
-    k_spec_k = pl.BlockSpec((1, blk_k, dp), lambda bh_, a, b_: (bh_, a, 0))
-    r_spec_q = pl.BlockSpec((1, blk_q, _LANES),
-                            lambda bh_, a, b_: (bh_, a, 0))
-    r_spec_k = pl.BlockSpec((1, blk_q, _LANES),
-                            lambda bh_, a, b_: (bh_, b_, 0))
+    # dk/dv: grid (bh, k blocks, q blocks) — k resident, q streamed
+    t = plan.dkdv
+    nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
 
-    # dk/dv: grid (bh, nk, nq) — k-block resident, q streamed
+    def q_index(bh_, ik, iq):
+        # an empty step (before the first visible row) refetches nothing
+        return jnp.maximum(iq, _first_q_block(ik, t, nqr, sk - sq)) \
+            if causal and nqr > 1 else iq
+
+    q_spec, k_spec, row_spec = _block_specs(
+        t, dp, q_index, lambda bh_, ik, iq: ik)
     with jax.named_scope("mx.flash.dkdv"):
         dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkdv_kernel, **common),
-            grid=(bh, nk, nq),
-            in_specs=[q_spec_k, k_spec_k, k_spec_k, q_spec_k, r_spec_k,
-                      r_spec_k],
-            out_specs=[k_spec_k, k_spec_k],
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, sk + sk_pad, dp), k.dtype),
-                jax.ShapeDtypeStruct((bh, sk + sk_pad, dp), v.dtype)],
-            scratch_shapes=[pltpu.VMEM((blk_k, dp), jnp.float32),
-                            pltpu.VMEM((blk_k, dp), jnp.float32)],
+            functools.partial(_flash_bwd_dkdv_kernel, t=t, grid=(nqr, nkr),
+                              **common),
+            grid=(bh, nkr, nqr),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            out_specs=[k_spec, k_spec],
+            out_shape=[jax.ShapeDtypeStruct((bh, sk_p, dp), k.dtype),
+                       jax.ShapeDtypeStruct((bh, sk_p, dp), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((t.res_k, wide), jnp.float32),
+                            pltpu.VMEM((t.res_k, wide), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
             name="mx_flash_dkdv",
         )(qp, kp, vp, dop, lse, delta)
 
-    # dq: grid (bh, nq, nk) — q-block resident, k streamed
+    # dq: grid (bh, q blocks, k blocks) — q resident, k streamed
+    t = plan.dq
+    nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
+
+    q_spec, k_spec, row_spec = _block_specs(
+        t, dp, lambda bh_, iq, ik: iq, _k_index(t, nkr, sk - sq, causal))
     with jax.named_scope("mx.flash.dq"):
         dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, **common),
-            grid=(bh, nq, nk),
-            in_specs=[q_spec_q, k_spec_q, k_spec_q, q_spec_q, r_spec_q,
-                      r_spec_q],
-            out_specs=q_spec_q,
-            out_shape=jax.ShapeDtypeStruct((bh, sq + sq_pad, dp), q.dtype),
-            scratch_shapes=[pltpu.VMEM((blk_q, dp), jnp.float32)],
+            functools.partial(_flash_bwd_dq_kernel, t=t, grid=(nqr, nkr),
+                              **common),
+            grid=(bh, nqr, nkr),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((bh, sq_p, dp), q.dtype),
+            scratch_shapes=[pltpu.VMEM((t.res_q, wide), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
             name="mx_flash_dq",
         )(qp, kp, vp, dop, lse, delta)
 
-    dq = dq.reshape(b, h, sq + sq_pad, dp)[:, :, :sq, :d]
-    dk = dk.reshape(b, h, sk + sk_pad, dp)[:, :, :sk, :d]
-    dv = dv.reshape(b, h, sk + sk_pad, dp)[:, :, :sk, :d]
-    return dq, dk, dv
+    return (_unpad_bh(dq, b, h, sq, d), _unpad_bh(dk, b, h, sk, d),
+            _unpad_bh(dv, b, h, sk, d))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash(q, k, v, causal, sm_scale, interpret):
+    _record_plan(q, k, causal)
     return _flash_fwd_pallas(q, k, v, causal, sm_scale, interpret=interpret)
 
 

@@ -11,6 +11,10 @@ from mxnet_tpu.ops.attention import (attention_reference, _chunked_attention,
                                      _flash_fwd_pallas, flash_attention)
 from mxnet_tpu.parallel import make_mesh, sequence_parallel_attention
 
+# a square sub-tile of edge t visits S^2/2 + S*t/2 scores of the causal
+# triangle's S^2/2: 1.125 at t = 256 and S = 2048
+_TRIANGLE_SLACK = 1.15
+
 
 def _rand_qkv(b=2, h=3, sq=64, sk=64, d=16, seed=0):
     rng = np.random.RandomState(seed)
@@ -251,34 +255,211 @@ def test_symbolic_attention_with_grad():
                                np.asarray(g_ref[0]), rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("causal,sq,sk,d,blk", [
-    (False, 48, 48, 16, 16),
-    (True, 48, 48, 16, 16),
-    (True, 24, 72, 8, 24),    # cross-length causal, uneven blocks
-    (False, 40, 56, 24, 16),  # seq not divisible by block, d not 128
-])
-def test_pallas_flash_backward_interpret(causal, sq, sk, d, blk):
-    from mxnet_tpu.ops.attention import _flash_fwd_pallas, _flash_bwd_pallas
-    q, k, v = _rand_qkv(b=1, h=2, sq=sq, sk=sk, d=d)
+def _check_flash_against_reference(q, k, v, causal, tol_out, tol_grad,
+                                    **tiles):
+    """Both wrappers in interpret mode against the oracle's output and
+    its three gradients."""
+    from mxnet_tpu.ops.attention import _flash_bwd_pallas
+    d = q.shape[-1]
     scale = 1.0 / np.sqrt(d)
-    out, lse = _flash_fwd_pallas(q, k, v, causal, scale, blk_q=blk,
-                                 blk_k=blk, interpret=True, with_lse=True)
+    out, lse = _flash_fwd_pallas(q, k, v, causal, scale, interpret=True,
+                                 with_lse=True, **tiles)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    assert lse.shape == (q.shape[0] * q.shape[1], 1, q.shape[2])
     g = jnp.asarray(np.random.RandomState(9).randn(
-        *out.shape).astype(np.float32))
-    dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, g, causal, scale,
-                                   blk_q=blk, blk_k=blk, interpret=True)
+        *out.shape).astype(np.float32)).astype(q.dtype)
+    grads = _flash_bwd_pallas(q, k, v, out, lse, g, causal, scale,
+                              interpret=True, **tiles)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
     ref, vjp = jax.vjp(
         lambda a, b, c: attention_reference(a, b, c, causal=causal,
-                                            sm_scale=scale), q, k, v)
-    rq, rk, rv = vjp(g)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(dq), np.asarray(rq),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(dk), np.asarray(rk),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(dv), np.asarray(rv),
-                               rtol=2e-4, atol=2e-4)
+                                            sm_scale=scale), *f32)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), rtol=tol_out, atol=tol_out)
+    for got, want, x in zip(grads, vjp(g.astype(jnp.float32)), (q, k, v)):
+        assert got.shape == x.shape and got.dtype == x.dtype
+        assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), rtol=tol_grad,
+                                   atol=tol_grad)
+
+
+# (seq_q, seq_k, tiles): the sub-tile smaller than, equal to and larger
+# than the sequence; rectangular sub-tiles; several resident blocks on
+# either side (the grid's clamped index maps); a sequence that is no
+# multiple of the tile; cross-length, ends aligned, with seq_q > seq_k
+# leaving rows that see no key
+_GEOMETRIES = [
+    (48, 48, dict(blk_q=16, blk_k=16)),
+    (32, 32, dict(blk_q=32, blk_k=32)),
+    (24, 24, dict(blk_q=64, blk_k=64)),
+    (64, 64, dict(blk_q=16, blk_k=32)),
+    (64, 64, dict(blk_q=32, blk_k=8)),
+    (64, 64, dict(blk_q=16, blk_k=16, res_q=32, res_k=32)),
+    (64, 64, dict(blk_q=8, blk_k=16, res_q=16, res_k=16)),
+    (40, 56, dict(blk_q=16, blk_k=16)),
+    (40, 56, dict(blk_q=16, blk_k=16, res_q=16, res_k=32)),
+    (24, 72, dict(blk_q=24, blk_k=24)),
+    (24, 72, dict(blk_q=8, blk_k=24, res_q=8, res_k=24)),
+    (48, 16, dict(blk_q=16, blk_k=16)),
+    (44, 20, dict(blk_q=8, blk_k=8, res_q=16, res_k=8)),
+    # loops too long to unroll whole: static bounds (ten tiles a side) and
+    # traced ones (two query blocks over sixteen key tiles), both run
+    # four tiles an iteration and the rest one by one
+    (160, 160, dict(blk_q=16, blk_k=16)),
+    (128, 128, dict(blk_q=8, blk_k=8, res_q=64, res_k=128)),
+]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("sq,sk,tiles", _GEOMETRIES)
+def test_pallas_flash_backward_interpret(causal, sq, sk, tiles):
+    q, k, v = _rand_qkv(b=1, h=2, sq=sq, sk=sk, d=16)
+    _check_flash_against_reference(q, k, v, causal, 2e-5, 2e-4, **tiles)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("d", [64, 80, 128])
+def test_pallas_flash_head_dims_and_dtypes_interpret(d, dtype, causal):
+    """d 64 crosses HBM unpadded, d 80 is padded to 128 lanes, d 128 is
+    the tile; sm_scale is a power of two at d 64 only (folded into an
+    operand there, on the score tile elsewhere)."""
+    from mxnet_tpu.ops.attention import _flash_plan
+    assert _flash_plan(40, 56, d, dtype).d_block == {64: 64, 80: 128,
+                                                     128: 128}[d]
+    q, k, v = (x.astype(dtype)
+               for x in _rand_qkv(b=1, h=1, sq=40, sk=56, d=d))
+    tol = (2e-5, 2e-4) if dtype == "float32" else (5e-2, 1.5e-1)
+    _check_flash_against_reference(q, k, v, causal, *tol, blk_q=16,
+                                   blk_k=16, res_k=32)
+
+
+def test_pallas_flash_default_plan_short_sequence_interpret():
+    """The plan's own tiles where the sequence is shorter than one."""
+    q, k, v = _rand_qkv(b=1, h=1, sq=24, sk=40, d=64)
+    _check_flash_against_reference(q, k, v, True, 2e-5, 2e-4)
+
+
+def _brute_force_tile_counts(sq, sk, causal, sq_p, sk_p, sub_q, sub_k):
+    """(visited, masked, scores) from the visibility matrix itself."""
+    rows = np.arange(sq_p)[:, None]
+    cols = np.arange(sk_p)[None, :]
+    real = (cols < sk) & (rows >= 0)
+    seen = real & (cols <= rows + (sk - sq)) if causal else real
+    scores = int(seen[:sq].sum())
+    # a padded query row is computed like a real one (its dO is zero):
+    # it never makes a tile masked, the padded key columns do
+    seen = seen.reshape(sq_p // sub_q, sub_q, sk_p // sub_k, sub_k)
+    real_rows = (np.arange(sq_p) < sq).reshape(-1, sub_q)[:, :, None, None]
+    any_ = (seen & real_rows).any(axis=(1, 3))
+    all_ = (seen | ~real_rows).all(axis=(1, 3))
+    return int(any_.sum()), int((any_ & ~all_).sum()), scores
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("sq,sk,tiles", _GEOMETRIES + [
+    (2048, 2048, {}), (1000, 1000, {}), (300, 2048, {}), (2048, 300, {}),
+    (4096, 4096, dict(blk_q=512, blk_k=256, res_q=1024, res_k=2048)),
+])
+def test_flash_plan_visits_what_the_mask_leaves_visible(causal, sq, sk,
+                                                        tiles):
+    """The loops' bounds against the visibility matrix: every tile that
+    holds a visible score is visited and no other (a tile all of whose
+    real rows are hidden costs nothing), and the mask runs exactly on
+    the tiles that the diagonal or the key padding crosses."""
+    from mxnet_tpu.ops.attention import (_KERNELS, _flash_plan,
+                                         _tile_counts)
+    plan = _flash_plan(sq, sk, 64, jnp.bfloat16, **tiles)
+    for kernel in _KERNELS:
+        t = getattr(plan, kernel)
+        sq_p, sk_p = (plan.sq_fwd, plan.sk_fwd) if kernel == "fwd" \
+            else (plan.sq_bwd, plan.sk_bwd)
+        assert sq_p % t.res_q == 0 and t.res_q % t.sub_q == 0
+        assert sk_p % t.res_k == 0 and t.res_k % t.sub_k == 0
+        visited, masked, scores = _brute_force_tile_counts(
+            sq, sk, causal, sq_p, sk_p, t.sub_q, t.sub_k)
+        got = _tile_counts(kernel, plan, sq, sk, causal)
+        assert got["tiles_visited"] == visited, kernel
+        assert got["tiles_masked"] == masked, kernel
+        assert got["tiles_ideal"] == pytest.approx(
+            scores / (t.sub_q * t.sub_k), abs=1e-3)
+
+
+@pytest.mark.parametrize("sq,sk,d,dtype", [
+    (2048, 2048, 64, "bfloat16"),       # the benchmark's LM cell
+    (4096, 4096, 128, "bfloat16"),
+    (8192, 8192, 128, "float32"),
+    (128, 16384, 128, "bfloat16"),
+])
+def test_flash_plan_stays_inside_vmem_and_near_the_triangle(sq, sk, d,
+                                                            dtype):
+    from mxnet_tpu.ops.attention import (_KERNELS, _flash_plan, _plan_args,
+                                         _tile_counts)
+    plan = _flash_plan(sq, sk, d, dtype)
+    rec = _plan_args(plan, sq, sk, d, dtype, True)
+    assert rec["d_block"] == d
+    for kernel in _KERNELS:
+        assert rec[kernel]["vmem_bytes"] < 16 << 20, kernel
+        if sq == sk:
+            assert rec[kernel]["tiles_visited"] <= \
+                _TRIANGLE_SLACK * rec[kernel]["tiles_ideal"], kernel
+            # the diagonal's tiles alone are masked: one for each
+            # sub-tile along the tile's shorter edge
+            assert rec[kernel]["tiles_masked"] == \
+                sq // min(getattr(plan, kernel)[2:]), kernel
+        # the plan is a function of what the call sees, nothing else
+        assert _tile_counts(kernel, _flash_plan(sq, sk, d, dtype), sq, sk,
+                            True) == _tile_counts(kernel, plan, sq, sk,
+                                                  True)
+
+
+def test_flash_plan_is_recorded_once_per_traced_call():
+    """`mx.flash.plan`: one span where the call is traced, none where
+    the compiled program runs."""
+    from mxnet_tpu import profiler
+
+    @jax.jit
+    def f(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True)
+
+    q, k, v = _rand_qkv(b=1, h=1, sq=32, sk=32, d=64)
+    import time
+    t0 = time.perf_counter()
+    f(q, k, v).block_until_ready()
+    first = [s for s in profiler.spans(since=t0)
+             if s.name == "mx.flash.plan"]
+    assert len(first) == 1
+    args = first[0].args
+    assert (args["sq"], args["sk"], args["d"], args["causal"]) == \
+        (32, 32, 64, True)
+    for kernel in ("fwd", "dkdv", "dq"):
+        assert set(args[kernel]) >= {"tiles_visited", "tiles_masked",
+                                     "tiles_ideal", "resident", "sub_tile"}
+    assert args["d_block"] == 64
+    f(q, k, v).block_until_ready()
+    assert len([s for s in profiler.spans(since=t0)
+                if s.name == "mx.flash.plan"]) == 1
+
+
+def test_traced_training_step_holds_exactly_the_three_flash_scopes():
+    import re
+    aval = jax.ShapeDtypeStruct((2, 4, 2048, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+        aval, aval, aval).lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=True)
+    assert set(re.findall(r"mx\.flash\.(\w+)", text)) == \
+        {"fwd", "dkdv", "dq"}
+    assert set(re.findall(r"mx_flash_\w+", text)) == \
+        {"mx_flash_fwd", "mx_flash_dkdv", "mx_flash_dq"}
+    assert text.count("tpu_custom_call") == 3
+    # no head-dim padding and no lane-replicated statistics around them
+    assert "x128xbf16" not in text and "x2048x128xf32" not in text
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -1,12 +1,15 @@
-"""Flash-attention kernel sweep: numerics + TF/s, fwd AND bwd, on the
+"""Flash-attention kernel sweep: numerics + time, fwd AND bwd, on the
 real chip.
 
-One command: per-config numeric checks of the Pallas kernels against
-the einsum oracle (forward and all three gradients), then a block-size
-timing sweep with useful-FLOP throughput for forward, backward, and the
-chunked-XLA baseline.
+One command: numeric checks of the Pallas kernels against the einsum
+oracle (forward and all three gradients; causal and not, seq_q == seq_k
+and not), then a timing sweep of resident block x sub-tile for each of
+the three kernels, each timed alone, with useful-FLOP throughput and the
+plan's tile counts.  The table in docs/PERF_NOTES.md "Flash attention
+kernel" and the values of `ops/attention.py` `_SUB` come from it.
 
-    python tools/flash_sweep.py
+    python tools/flash_sweep.py                  # checks + both shapes
+    python tools/flash_sweep.py --shape 2,32,2048,64 --default-only
 
 Timing discipline: iterations are chained through a data dependency
 inside one jit (scan), timed to a host readback.  Needs the chip to
@@ -21,23 +24,28 @@ import os
 import sys
 import time
 
-import numpy as np
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+#: the shapes swept: the benchmark's LM cell, and a long wide one
+SHAPES = ((2, 32, 2048, 64), (4, 16, 4096, 128))
+#: (query rows, key columns) of a score sub-tile
+SUB_TILES = ((256, 256), (256, 512), (512, 256), (512, 512), (1024, 1024))
 
-def numeric_check(shapes=(1, 2, 256, 64)):
-    """Flash (compiled, on-device) vs oracle: fwd + dq/dk/dv."""
+
+def numeric_check(shapes=(1, 2, 256, 64), seq_k=None):
+    """Flash (compiled, on-device) vs oracle: fwd + dq/dk/dv, causal and
+    not; *seq_k* sets a key length of its own (ends aligned)."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops.attention import (attention_reference,
                                          flash_attention)
     b, h, s, d = shapes
+    sk = seq_k or s
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (b, h, s, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (b, h, s, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, h, s, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, h, sk, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, h, sk, d), jnp.bfloat16)
 
     for causal in (False, True):
         def loss_f(q, k, v):
@@ -62,7 +70,8 @@ def numeric_check(shapes=(1, 2, 256, 64)):
                 for a, b in zip(gf, gr)]
         scale = float(jnp.max(jnp.abs(out_r))) + 1e-6
         gscales = [float(jnp.max(jnp.abs(g))) + 1e-6 for g in gr]
-        print(json.dumps({"check": "numerics", "causal": causal,
+        print(json.dumps({"check": "numerics", "shape": [b, h, s, sk, d],
+                          "causal": causal,
                           "fwd_maxerr": fwd_err,
                           "grad_maxerr": errs,
                           "out_scale": scale,
@@ -78,7 +87,12 @@ def numeric_check(shapes=(1, 2, 256, 64)):
 
 
 def _time_scan(fn, args, iters):
-    """Chained timing: scan fn iters times inside ONE dispatch."""
+    """``(ms, kernel_ms)`` of one call of *fn*.  Chained timing: scan fn
+    iters times inside ONE dispatch (the first argument carries the
+    dependency, the others are constants), timed to a host readback:
+    kernels and whatever the wrappers add around them.  Then one traced
+    dispatch, for the Mosaic kernels' own device time (the `XLA Ops`
+    events named `mx_flash_*`)."""
     import jax
     import jax.numpy as jnp
 
@@ -92,81 +106,186 @@ def _time_scan(fn, args, iters):
 
     j = jax.jit(chained)
     float(j(*args))  # compile + warm
-    t0 = time.perf_counter()
-    float(j(*args))
-    return (time.perf_counter() - t0) / iters
+    best = float("inf")
+    for _ in range(2):
+        t0 = time.perf_counter()
+        float(j(*args))
+        best = min(best, (time.perf_counter() - t0) / iters)
+    return best * 1e3, _kernel_ms(lambda: float(j(*args))) / iters
 
 
-def sweep(b=4, h=16, s=4096, d=128, causal=True, iters=8):
+def _kernel_ms(run):
+    """Device milliseconds under the `mx_flash_*` kernels while *run*
+    runs, from a profiler trace of it."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp)
+        try:
+            run()
+        finally:
+            jax.profiler.stop_trace()
+        path = sorted(glob.glob(os.path.join(
+            tmp, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        ns = 0
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/device:TPU:0"):
+                continue
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    ns += sum(ev.duration_ns for ev in line.events
+                              if "mx_flash_" in ev.name.split(" = ")[0])
+    return ns * 1e-6
+
+
+def _kernels(A, causal, scale, tiles):
+    """The three calls, each alone.  The backward wrapper makes both of
+    its calls; XLA drops the one whose result is not used, so a function
+    that returns dq alone times `mx_flash_dq` (and the delta pass), and
+    one that returns dk + dv times `mx_flash_dkdv`."""
+    def fwd(q, k, v, out, lse, dout):
+        return A._flash_fwd_pallas(q, k, v, causal, scale, with_lse=True,
+                                   **tiles)[0]
+
+    def dq(q, k, v, out, lse, dout):
+        return A._flash_bwd_pallas(q, k, v, out, lse, dout, causal, scale,
+                                   **tiles)[0]
+
+    def dkdv(q, k, v, out, lse, dout):
+        g = A._flash_bwd_pallas(q, k, v, out, lse, dout, causal, scale,
+                                **tiles)
+        return g[1] + g[2]
+
+    return {"fwd": fwd, "dkdv": dkdv, "dq": dq}
+
+
+#: useful FLOPs over the forward's two dots: the recompute and dp, then
+#: one accumulating dot for dq and two for dk/dv
+_DOTS = {"fwd": 2, "dkdv": 4, "dq": 3}
+
+
+def sweep(shape, causal=True, iters=8, default_only=False,
+          kernels=("fwd", "dkdv", "dq"), out=None, residents=True,
+          sub_tiles=SUB_TILES):
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import attention as A
 
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(ks[0], (b, h, s, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (b, h, s, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, h, s, d), jnp.bfloat16)
-    # useful flops: 2 dots of 2*s*s*d per head, halved by causal masking
-    flops = 4.0 * b * h * s * s * d * (0.5 if causal else 1.0)
-    results = []
-    # 2048 does not compile on v5e: its tiles ask for 25.8 MiB of the
-    # 16 MiB scoped VMEM (CHANGES.md PR 21)
-    for blk in (256, 512, 1024):
-        def fwd(q, k, v):
-            return A._flash_fwd_pallas(q, k, v, causal,
-                                       1.0 / (d ** 0.5),
-                                       blk_q=blk, blk_k=blk)
+    b, h, s, d = shape
+    scale = 1.0 / d ** 0.5
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q, k, v, dout = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
+                     for kk in ks)
+    o, lse = jax.jit(lambda q, k, v: A._flash_fwd_pallas(
+        q, k, v, causal, scale, with_lse=True))(q, k, v)
+    args = (q, k, v, o, lse, dout)
+    # useful flops of one dot: 2*s*s*d a head, halved by causal masking
+    dot_flops = 2.0 * b * h * s * s * d * (0.5 if causal else 1.0)
 
-        dt = _time_scan(fwd, (q, k, v), iters)
-        row = {"metric": "flash_fwd", "blk": blk, "ms": dt * 1e3,
-               "tflops": flops / dt / 1e12}
-        results.append(row)
+    variants = [{}]
+    if not default_only:
+        for sub_q, sub_k in sub_tiles:
+            if sub_q > s or sub_k > s:
+                continue
+            variants.append({"blk_q": sub_q, "blk_k": sub_k})
+            if not residents:
+                continue
+            # the same sub-tile through the grid: one tile a grid step
+            variants.append({"blk_q": sub_q, "blk_k": sub_k,
+                             "res_q": sub_q, "res_k": sub_k})
+            if max(sub_q, sub_k) <= s // 2:
+                variants.append({"blk_q": sub_q, "blk_k": sub_k,
+                                 "res_q": s // 2, "res_k": s // 2})
+    rows = []
+    for tiles in variants:
+        fns = _kernels(A, causal, scale, tiles)
+        for name in kernels:
+            row = {"metric": "flash_" + name, "shape": list(shape),
+                   "causal": causal, "tiles": tiles}
+            plan = A._flash_plan(s, s, d, q.dtype, **tiles)
+            row.update(A._plan_args(plan, s, s, d, q.dtype, causal)[name])
+            try:
+                ms, kernel_ms = _time_scan(fns[name], args, iters)
+            except Exception as e:  # a tile VMEM refuses: say so, go on
+                row["error"] = str(e).strip().splitlines()[0][:200]
+            else:
+                row["ms"], row["kernel_ms"] = ms, kernel_ms
+                row["useful_tflops"] = \
+                    _DOTS[name] * dot_flops / kernel_ms / 1e9
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+            if out is not None:
+                out.write(json.dumps(row) + "\n")
+                out.flush()
+
+    print(table(rows), flush=True)
+    if not default_only:
+        def chunked(q, k, v, *_):
+            return A._chunked_attention(q, k, v, causal=causal)
+
+        ms, _ = _time_scan(chunked, args, iters)
+        row = {"metric": "chunked_xla_fwd", "shape": list(shape),
+               "ms": ms, "useful_tflops": 2 * dot_flops / ms / 1e9}
+        rows.append(row)
         print(json.dumps(row), flush=True)
+    return rows
 
-        def bwd(q, k, v):
-            out, lse = A._flash_fwd_pallas(
-                q, k, v, causal, 1.0 / (d ** 0.5), blk_q=blk,
-                blk_k=blk, with_lse=True)
-            dout = jnp.ones_like(out)
-            dq, dk, dv = A._flash_bwd_pallas(
-                q, k, v, out, lse, dout, causal, 1.0 / (d ** 0.5),
-                blk_q=blk, blk_k=blk)
-            # consume dk/dv too: returning dq alone would let XLA
-            # dead-code-eliminate the whole dkdv kernel and inflate
-            # the reported throughput
-            return dq + (jnp.sum(dk.astype(jnp.float32)) +
-                         jnp.sum(dv.astype(jnp.float32))
-                         ).astype(dq.dtype)
 
-        dt = _time_scan(bwd, (q, k, v), iters)
-        # bwd ~ 2.5x fwd flops (recompute + 4 grad dots over 2 fwd dots)
-        row = {"metric": "flash_fwd_plus_bwd", "blk": blk,
-               "ms": dt * 1e3, "tflops": 3.5 * flops / dt / 1e12}
-        results.append(row)
-        print(json.dumps(row), flush=True)
-
-    def chunked(q, k, v):
-        return A._chunked_attention(q, k, v, causal=causal)
-
-    dt = _time_scan(chunked, (q, k, v), iters)
-    row = {"metric": "chunked_xla_fwd", "ms": dt * 1e3,
-           "tflops": flops / dt / 1e12}
-    results.append(row)
-    print(json.dumps(row), flush=True)
-    best = max(r["tflops"] for r in results if r["metric"] == "flash_fwd")
-    print(json.dumps({"metric": "flash_fwd_best_tflops", "value": best}))
-    return results
+def table(rows):
+    """The sweep's rows as the markdown table of docs/PERF_NOTES.md."""
+    out = ["| kernel | sub-tile q x k | resident q, k | tiles visited "
+           "(masked) / ideal | kernel ms | with wrappers ms | useful "
+           "TFLOP/s |", "| --- | --- | --- | --- | --- | --- | --- |"]
+    for r in sorted((r for r in rows if "kernel_ms" in r),
+                    key=lambda r: (r["metric"], r["kernel_ms"])):
+        out.append("| %s%s | %dx%d | %d, %d | %d (%d) / %.1f | %.3f | %.3f "
+                   "| %.1f |" % (
+                       r["metric"][6:], "" if r["tiles"] else " (plan)",
+                       *r["sub_tile"], *r["resident"], r["tiles_visited"],
+                       r["tiles_masked"], r["tiles_ideal"], r["kernel_ms"],
+                       r["ms"], r["useful_tflops"]))
+    return "\n".join(out)
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--skip-sweep", action="store_true")
+    ap.add_argument("--skip-checks", action="store_true")
+    ap.add_argument("--default-only", action="store_true",
+                    help="time the plan's own tiles only")
+    ap.add_argument("--sub-tiles-only", action="store_true",
+                    help="each sub-tile with the plan's resident blocks "
+                    "only")
+    ap.add_argument("--sub", action="append",
+                    help="QxK sub-tile to sweep (repeatable; default: "
+                    "%s)" % " ".join("%dx%d" % t for t in SUB_TILES))
     ap.add_argument("--iters", type=int, default=8)
-    ap.add_argument("--seq", type=int, default=4096)
+    ap.add_argument("--shape", action="append",
+                    help="b,h,s,d (repeatable; default: %s)"
+                    % " and ".join(",".join(map(str, s)) for s in SHAPES))
+    ap.add_argument("--out", default="chiprun_out/flash_sweep.jsonl")
     args = ap.parse_args()
-    numeric_check()
+    if not args.skip_checks:
+        numeric_check()
+        numeric_check((2, 4, 2048, 64))
+        numeric_check((1, 2, 384, 64), seq_k=1000)   # decode-style
+        numeric_check((1, 2, 1000, 128), seq_k=384)  # degenerate rows
     if not args.skip_sweep:
-        sweep(s=args.seq, iters=args.iters)
+        shapes = [tuple(int(x) for x in s.split(","))
+                  for s in args.shape] if args.shape else SHAPES
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "a") as out:
+            for shape in shapes:
+                sweep(shape, iters=args.iters,
+                      default_only=args.default_only, out=out,
+                      residents=not args.sub_tiles_only,
+                      sub_tiles=[tuple(int(x) for x in t.split("x"))
+                                 for t in args.sub] if args.sub
+                      else SUB_TILES)
     return 0
 
 
