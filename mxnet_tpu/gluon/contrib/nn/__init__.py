@@ -1,5 +1,6 @@
 """Contrib layers (reference: gluon/contrib/nn/basic_layers.py)."""
 
-from .basic_layers import (Concurrent, HybridConcurrent, Identity,  # noqa
-                           MoEFFN, MultiHeadAttention, SparseEmbedding,
-                           SyncBatchNorm)
+from .basic_layers import (Concurrent, GatedMLP, GatedShortConv,  # noqa
+                           GroupedQueryAttention, HybridConcurrent, Identity,
+                           MoEFFN, MultiHeadAttention, RoutedExperts,
+                           SparseEmbedding, SyncBatchNorm)

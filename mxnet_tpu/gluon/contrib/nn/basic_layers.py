@@ -174,3 +174,155 @@ class MoEFFN(HybridBlock):
         return F._contrib_MoEFFN(x, gate_weight, expert_w1, expert_w2,
                                  capacity_factor=self._cf,
                                  act_type=self._act)
+
+
+class GatedMLP(HybridBlock):
+    """Gated (SwiGLU) feed-forward ``W2(silu(W1 x) * W3 x)`` without
+    biases, over the ``_contrib_GatedMLP`` op."""
+
+    def __init__(self, units, hidden, weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.w1 = self.params.get("w1_weight", shape=(hidden, units),
+                                      init=weight_initializer)
+            self.w3 = self.params.get("w3_weight", shape=(hidden, units),
+                                      init=weight_initializer)
+            self.w2 = self.params.get("w2_weight", shape=(units, hidden),
+                                      init=weight_initializer)
+
+    def hybrid_forward(self, F, x, w1, w3, w2):
+        return F.contrib.GatedMLP(x, w1, w3, w2)
+
+
+class GatedShortConv(HybridBlock):
+    """The gated short causal convolution operator on (batch, seq,
+    units): an input projection to three streams, a depthwise causal
+    convolution of *kernel* taps over the product of two of them, gated
+    by the third, and an output projection; no biases.  Over the
+    ``_contrib_GatedShortConv`` op."""
+
+    def __init__(self, units, kernel=3, weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.in_weight = self.params.get(
+                "in_weight", shape=(3 * units, units),
+                init=weight_initializer)
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(units, kernel),
+                init=weight_initializer)
+            self.out_weight = self.params.get(
+                "out_weight", shape=(units, units), init=weight_initializer)
+
+    def hybrid_forward(self, F, x, in_weight, conv_weight, out_weight):
+        return F.contrib.GatedShortConv(x, in_weight, conv_weight,
+                                        out_weight)
+
+
+class GroupedQueryAttention(HybridBlock):
+    """Causal self-attention with fewer key/value heads than query
+    heads, RMS norm over each query and key head, and rotary positions;
+    no biases.  The key/value heads are repeated to the query heads in
+    front of ``contrib.DotProductAttention`` (the flash kernels take
+    equal head counts)."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
+                 rope_theta=10000.0, epsilon=1e-5, weight_initializer=None,
+                 **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % num_kv_heads:
+            raise ValueError("num_heads (%d) must be a multiple of "
+                             "num_kv_heads (%d)" % (num_heads, num_kv_heads))
+        head_dim = head_dim or units // num_heads
+        self._units = units
+        self._heads, self._kv_heads = num_heads, num_kv_heads
+        self._head_dim, self._theta = head_dim, float(rope_theta)
+        self._eps = epsilon
+        with self.name_scope():
+            def weight(name, rows, cols):
+                return self.params.get(name, shape=(rows, cols),
+                                       init=weight_initializer)
+            self.q_weight = weight("query_weight", num_heads * head_dim,
+                                   units)
+            self.k_weight = weight("key_weight", num_kv_heads * head_dim,
+                                   units)
+            self.v_weight = weight("value_weight", num_kv_heads * head_dim,
+                                   units)
+            self.out_weight = weight("out_weight", units,
+                                     num_heads * head_dim)
+            self.q_gamma = self.params.get(
+                "query_norm_gamma", shape=(head_dim,), init="ones")
+            self.k_gamma = self.params.get(
+                "key_norm_gamma", shape=(head_dim,), init="ones")
+
+    def hybrid_forward(self, F, x, q_weight, k_weight, v_weight, out_weight,
+                       q_gamma, k_gamma):
+        def heads(w, n, gamma=None):
+            # (B, S, U) -> (B, S, n, d), normed over d, -> (B, n, S, d)
+            h = F.FullyConnected(x, w, no_bias=True, flatten=False,
+                                 num_hidden=n * self._head_dim)
+            h = F.Reshape(h, shape=(0, 0, n, -1))
+            if gamma is not None:
+                h = F.contrib.RMSNorm(h, gamma, eps=self._eps)
+            return F.transpose(h, axes=(0, 2, 1, 3))
+
+        q = F.contrib.RotaryEmbedding(heads(q_weight, self._heads, q_gamma),
+                                      theta=self._theta)
+        k = F.contrib.RotaryEmbedding(
+            heads(k_weight, self._kv_heads, k_gamma), theta=self._theta)
+        v = heads(v_weight, self._kv_heads)
+        group = self._heads // self._kv_heads
+        if group > 1:
+            k = F.repeat(k, repeats=group, axis=1)
+            v = F.repeat(v, repeats=group, axis=1)
+        att = F.contrib.DotProductAttention(
+            q, k, v, causal=True, sm_scale=self._head_dim ** -0.5)
+        att = F.Reshape(F.transpose(att, axes=(0, 2, 1, 3)),
+                        shape=(0, 0, -1))
+        return F.FullyConnected(att, out_weight, no_bias=True,
+                                flatten=False, num_hidden=self._units)
+
+
+class RoutedExperts(HybridBlock):
+    """Dropless top-k routed gated experts, one chip's share of the
+    layer, over the ``_contrib_RoutedExperts`` op: the router scores all
+    *num_experts*, this block holds *experts_held* of them from
+    *first_expert* on and computes their part of the result.
+    *expert_bias* (one number an expert, added to the scores for the
+    choice alone) is an attribute of the layer, not a parameter."""
+
+    def __init__(self, units, hidden, num_experts, num_experts_per_tok,
+                 experts_held=None, first_expert=0, expert_bias=None,
+                 norm_topk_prob=True, routed_scaling_factor=1.0,
+                 weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        held = num_experts if experts_held is None else experts_held
+        if first_expert < 0 or first_expert + held > num_experts:
+            raise ValueError("experts %d..%d are not among the router's %d"
+                             % (first_expert, first_expert + held - 1,
+                                num_experts))
+        if expert_bias is not None and len(expert_bias) != num_experts:
+            raise ValueError("expert_bias needs one number for each of the "
+                             "router's %d experts" % num_experts)
+        self._attrs = {
+            "expert_bias": tuple(float(b) for b in expert_bias or ()),
+            "num_experts_per_tok": int(num_experts_per_tok),
+            "first_expert": int(first_expert),
+            "norm_topk_prob": bool(norm_topk_prob),
+            "routed_scaling_factor": float(routed_scaling_factor)}
+        with self.name_scope():
+            self.router_weight = self.params.get(
+                "router_weight", shape=(num_experts, units),
+                init=weight_initializer)
+            self.w1 = self.params.get(
+                "expert_w1", shape=(held, units, hidden),
+                init=weight_initializer)
+            self.w3 = self.params.get(
+                "expert_w3", shape=(held, units, hidden),
+                init=weight_initializer)
+            self.w2 = self.params.get(
+                "expert_w2", shape=(held, hidden, units),
+                init=weight_initializer)
+
+    def hybrid_forward(self, F, x, router_weight, w1, w3, w2):
+        return F.contrib.RoutedExperts(x, router_weight, w1, w3, w2,
+                                       **self._attrs)

@@ -12,7 +12,8 @@ from ... import ndarray as nd
 from ... import symbol as sym_mod
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "Embedding",
-           "BatchNorm", "InstanceNorm", "LayerNorm", "Flatten", "Lambda",
+           "BatchNorm", "InstanceNorm", "LayerNorm", "RMSNorm", "Flatten",
+           "Lambda",
            "HybridLambda", "Activation", "LeakyReLU", "PReLU", "ELU",
            "SELU", "Swish", "GELU"]
 
@@ -265,6 +266,23 @@ class LayerNorm(HybridBlock):
     def hybrid_forward(self, F, x, gamma, beta):
         return F.LayerNorm(x, gamma, beta, axis=self._axis,
                            eps=self._epsilon)
+
+
+class RMSNorm(HybridBlock):
+    """``x / sqrt(mean(x^2) + epsilon) * gamma`` over the last axis
+    (TPU extension over the ``_contrib_RMSNorm`` op)."""
+
+    def __init__(self, in_channels=0, epsilon=1e-5,
+                 gamma_initializer="ones", **kwargs):
+        super().__init__(**kwargs)
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get(
+                "gamma", shape=(in_channels,), init=gamma_initializer,
+                allow_deferred_init=True)
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.contrib.RMSNorm(x, gamma, eps=self._epsilon)
 
 
 class Flatten(HybridBlock):

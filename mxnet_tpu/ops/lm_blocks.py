@@ -1,0 +1,416 @@
+"""The parts of today's decoder blocks, as operators: RMS norm, rotary
+positions, the gated (SwiGLU) feed-forward, the gated short causal
+convolution, and a dropless top-k routed expert layer that is told which
+experts it holds.
+
+The reference framework predates all of them (SURVEY section 5.7); they
+are TPU extensions beside ``_contrib_DotProductAttention``.  Each is a
+graph node, so its device time lies under ``<op>:<node>`` in the step's
+scope map; the routed layer's phases and the convolution carry scopes of
+their own (docs/observability.md "Spans and device scopes").
+
+Grouped-query attention has no operator: the key/value heads are
+repeated to the query heads (``repeat``) in front of
+``_contrib_DotProductAttention``, after ``_contrib_RMSNorm`` over each
+head and ``_contrib_RotaryEmbedding`` (gluon.contrib.nn
+``GroupedQueryAttention``).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import profiler
+from ._precision import matmul_precision
+from .nn import _fully_connected
+from .registry import register_op
+
+__all__ = ["routed_expert_counts"]
+
+
+def _dot(x, w):
+    """``x`` (..., k) against ``w`` (n, k) as `FullyConnected` computes
+    it: summed in float32, in x's dtype."""
+    return _fully_connected(x, w, no_bias=True, flatten=False)
+
+
+@register_op("_contrib_RMSNorm", aliases=("RMSNorm",))
+def _rms_norm(data, gamma, eps=1e-5):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, the
+    statistics in float32."""
+    x = data.astype(jnp.float32)
+    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+@register_op("_contrib_RotaryEmbedding", aliases=("RotaryEmbedding",))
+def _rotary(data, theta=10000.0):
+    """Rotary positions (rotate-half) on ``(batch, heads, seq, d)``:
+    positions ``0 .. seq - 1``, frequencies ``theta ** (-2i / d)``, the
+    angles in float32."""
+    s, d = data.shape[-2], data.shape[-1]
+    half = d // 2
+    inv = 1.0 / (float(theta) ** (np.arange(half, dtype=np.float64) / half))
+    ang = np.arange(s, dtype=np.float64)[:, None] * inv[None, :]
+    cos = jnp.asarray(np.concatenate([np.cos(ang)] * 2, -1), jnp.float32)
+    sin = jnp.asarray(np.concatenate([np.sin(ang)] * 2, -1), jnp.float32)
+    x = data.astype(jnp.float32)
+    rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
+    return (x * cos + rot * sin).astype(data.dtype)
+
+
+def _silu_mul(h, g):
+    """``silu(h) * g`` computed in float32, in h's dtype."""
+    h32 = h.astype(jnp.float32)
+    return (h32 * jax.nn.sigmoid(h32) * g.astype(jnp.float32)
+            ).astype(h.dtype)
+
+
+@register_op("_contrib_GatedMLP", aliases=("GatedMLP",))
+def _gated_mlp(data, w1, w3, w2):
+    """``W2(silu(W1 x) * W3 x)``; weights as ``FullyConnected`` holds
+    them: w1, w3 ``(hidden, in)``, w2 ``(in, hidden)``."""
+    return _dot(_silu_mul(_dot(data, w1), _dot(data, w3)), w2)
+
+
+@register_op("_contrib_GatedShortConv", aliases=("GatedShortConv",))
+def _gated_short_conv(data, in_weight, conv_weight, out_weight):
+    """The gated short convolution operator on ``(batch, seq, d)``:
+    ``[B, C, X] = split3(x W_in)``, ``u = B * X``, ``c_t = sum_j w_j *
+    u_(t-L+1+j)`` (depthwise, causal, ``u`` zero before the sequence;
+    conv_weight is ``(d, L)``), ``y = (C * c) W_out``.  Weights as
+    ``FullyConnected`` holds them: in_weight ``(3d, d)``, out_weight
+    ``(d, d)``."""
+    with jax.named_scope("mx.shortconv"):
+        d, taps = conv_weight.shape
+        bcx = _dot(data, in_weight)
+        b, c, x = bcx[..., :d], bcx[..., d:2 * d], bcx[..., 2 * d:]
+        u = (b * x).astype(jnp.float32)
+        w = conv_weight.astype(jnp.float32)
+        seq = data.shape[-2]
+        padded = jnp.pad(u, [(0, 0)] * (u.ndim - 2) + [(taps - 1, 0),
+                                                       (0, 0)])
+        conv = sum(padded[..., j:j + seq, :] * w[:, j] for j in range(taps))
+        gated = (c.astype(jnp.float32) * conv).astype(data.dtype)
+        return _dot(gated, out_weight)
+
+
+# ---------------------------------------------------------------------------
+# The routed expert layer.
+# ---------------------------------------------------------------------------
+
+#: the grouped products on the TPU: "megablox" (the installed JAX's Pallas
+#: kernels) or "ragged" (XLA's ragged dot, which every other platform
+#: takes), and the kernels' tiles (m, k, n); from `tools/moe_sweep.py` on
+#: the v5e at 65536 rows of which 19 k are routed, 2048 x 1792 (PERF.md
+#: section 6, PR 26).  Not options: the sweep sets them to compare
+GROUPED_PATH = "megablox"
+GROUPED_TILES = (512, 1024, 1024)
+
+
+def _route(x, router_weight, bias, top_k, norm_topk_prob, scaling):
+    """Sigmoid scores over all the router's experts, the top-k of score +
+    bias, and each chosen expert's weight, all in float32:
+    ``(chosen (N, k) int32, weights (N, k))``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_weight.astype(jnp.float32).T,
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + jnp.asarray(bias, jnp.float32), top_k)
+    weights = jnp.take_along_axis(scores, chosen, axis=1)
+    if norm_topk_prob:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
+    return chosen.astype(jnp.int32), weights * scaling
+
+
+def routed_expert_counts(chosen, num_router_experts, first_expert, held):
+    """What the counters are folded from, as one int32 row: the tokens
+    assigned to each of the router's experts, then the pairs that landed
+    on a held expert, then the tokens with no held expert among their
+    choices."""
+    load = jnp.sum(chosen.reshape(-1, 1) == jnp.arange(num_router_experts),
+                   axis=0, dtype=jnp.int32)
+    local = (chosen >= first_expert) & (chosen < first_expert + held)
+    return jnp.concatenate([
+        load, jnp.sum(local, dtype=jnp.int32)[None],
+        jnp.sum(~jnp.any(local, axis=1), dtype=jnp.int32)[None]])
+
+
+def _fold_expert_counts(rows):
+    """One step's rows (one per routed layer) into the counters; runs on
+    the host, where `profiler.fold_step_stats` is called."""
+    rows = np.asarray(rows).reshape(-1, rows.shape[-1])
+    load = rows[:, :-2].astype(np.float64)
+    profiler.bump_counter("moe_stat_steps_total")
+    profiler.bump_counter("moe_stat_layers_total", len(rows))
+    profiler.bump_counter("moe_assignments_total", int(load.sum()))
+    profiler.bump_counter("moe_local_assignments_total",
+                          int(rows[:, -2].sum()))
+    profiler.bump_counter("moe_tokens_without_local_expert_total",
+                          int(rows[:, -1].sum()))
+    profiler.bump_counter(
+        "moe_expert_load_max_over_mean_sum",
+        float((load.max(axis=1) / np.maximum(load.mean(axis=1), 1e-30)
+               ).sum()))
+
+
+def _ragged(lhs, rhs, sizes, transpose_rhs=False):
+    """Rows of *lhs* in groups of *sizes* against ``rhs[group]``."""
+    if transpose_rhs:
+        rhs = rhs.swapaxes(1, 2)
+    return jax.lax.ragged_dot(
+        lhs, rhs, sizes, precision=matmul_precision(lhs.dtype, rhs.dtype),
+        preferred_element_type=jnp.float32).astype(lhs.dtype)
+
+
+def _ragged_t(lhs, grad, sizes, dtype):
+    """``lhs[group].T @ grad[group]`` for each group: ``(groups, k, n)``."""
+    dims = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    return jax.lax.ragged_dot_general(
+        lhs, grad, sizes, dims,
+        precision=matmul_precision(lhs.dtype, grad.dtype),
+        preferred_element_type=jnp.float32).astype(dtype)
+
+
+def _megablox_backend():
+    # the package's own `gmm` attribute is its custom-vjp function and
+    # hides the module of the two kernels
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _fit(size, cap):
+    """The largest multiple of 128 up to *cap* that divides *size*; the
+    whole of a *size* that has none (the tests' small shapes)."""
+    for tile in range(min(cap, size) // 128 * 128, 0, -128):
+        if size % tile == 0:
+            return tile
+    return size
+
+
+def _tiles(m, k, n):
+    """`GROUPED_TILES` cut to the problem: every tile divides its
+    dimension (1792 = 7 x 256 takes 896 under a cap of 1024)."""
+    return tuple(_fit(size, cap)
+                 for size, cap in zip((m, k, n), GROUPED_TILES))
+
+
+def _megablox(lhs, rhs, sizes, transpose_rhs=False):
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    return _megablox_backend().gmm(
+        lhs, rhs, sizes, lhs.dtype, _tiles(m, k, n),
+        transpose_rhs=transpose_rhs)
+
+
+def _megablox_t(lhs, grad, sizes, dtype):
+    m, k = lhs.shape
+    return _megablox_backend().tgmm(
+        lhs.swapaxes(0, 1), grad, sizes, dtype,
+        _tiles(m, k, grad.shape[1]))
+
+
+def _product(lhs, rhs, sizes, transpose_rhs=False):
+    """Rows of *lhs* in groups of *sizes* against ``rhs[group]`` (against
+    its transpose with *transpose_rhs*).  The kernels exist for the TPU
+    alone; every other platform takes XLA's ragged dot."""
+    if GROUPED_PATH == "ragged":
+        return _ragged(lhs, rhs, sizes, transpose_rhs)
+    return jax.lax.platform_dependent(
+        lhs, rhs, sizes,
+        tpu=functools.partial(_megablox, transpose_rhs=transpose_rhs),
+        default=functools.partial(_ragged, transpose_rhs=transpose_rhs))
+
+
+def _product_t(lhs, grad, sizes, dtype):
+    """``lhs[group].T @ grad[group]`` for each group."""
+    if GROUPED_PATH == "ragged":
+        return _ragged_t(lhs, grad, sizes, dtype)
+    return jax.lax.platform_dependent(
+        lhs, grad, sizes,
+        tpu=functools.partial(_megablox_t, dtype=dtype),
+        default=functools.partial(_ragged_t, dtype=dtype))
+
+
+def _expert_hidden(xs, w1, w3, sizes):
+    h = _product(xs, w1, sizes)
+    g = _product(xs, w3, sizes)
+    return h, g, _silu_mul(h, g)
+
+
+# Rows of the pair buffer beyond the pairs that exist belong to no group.
+# The grouped products neither read nor write them (the kernels leave
+# them uninitialised, XLA's ragged dot zeroes them), so nothing below may
+# use such a row as a number: whatever is gathered back per token is
+# selected, not multiplied, by whether the pair exists.
+
+def _sum_pairs(rows, place, exists, weights=None):
+    """For each token, the sum over its pairs that exist of ``rows[place
+    of the pair]`` (times the pair's weight), in float32.  One row gather
+    a choice: a ``(tokens, k, d)`` array would be laid out with its k
+    padded to a whole sublane tile."""
+    total = 0.0
+    for k in range(place.shape[1]):
+        row = jnp.where(exists[:, k, None],
+                        jnp.take(rows, place[:, k], axis=0), 0
+                        ).astype(jnp.float32)
+        total = total + (row if weights is None
+                         else row * weights[:, k, None])
+    return total
+
+
+def _places(inverse, sizes, top_k):
+    """Where each token's pairs landed ``(tokens, k)``, and which of them
+    exist here (landed among the held experts' rows)."""
+    place = inverse.reshape(-1, top_k)
+    exists = place < jnp.sum(sizes)
+    return jnp.where(exists, place, 0), exists
+
+
+@jax.custom_vjp
+def _experts(x, weights, order, inverse, sizes, w1, w3, w2):
+    """The held experts' part of the result for tokens *x* ``(N, d)``
+    with pair weights *weights* ``(N, k)``: *order* lists the pairs
+    (token * k + choice) sorted by held expert, those on no held expert
+    last; *inverse* is where each pair landed; *sizes* the pairs of each
+    held expert."""
+    return _experts_fwd(x, weights, order, inverse, sizes, w1, w3, w2)[0]
+
+
+def _experts_fwd(x, weights, order, inverse, sizes, w1, w3, w2):
+    top_k = weights.shape[1]
+    with jax.named_scope("mx.moe.dispatch"):
+        xs = jnp.take(x, order // top_k, axis=0)
+    with jax.named_scope("mx.moe.experts"):
+        a = _expert_hidden(xs, w1, w3, sizes)[2]
+        y = _product(a, w2, sizes)
+    with jax.named_scope("mx.moe.combine"):
+        place, exists = _places(inverse, sizes, top_k)
+        out = _sum_pairs(y, place, exists, weights)
+    return out.astype(x.dtype), (x, weights, order, inverse, sizes, w1, w3,
+                                 w2)
+
+
+def _experts_bwd(res, dout):
+    """The experts' hidden states are computed again and not kept: at
+    the worst-case row count they are the layer's largest arrays."""
+    x, weights, order, inverse, sizes, w1, w3, w2 = res
+    top_k = weights.shape[1]
+    w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
+    with jax.named_scope("mx.moe.dispatch"):
+        token = order // top_k
+        xs = jnp.take(x, token, axis=0)
+        dos = jnp.take(dout, token, axis=0)
+    with jax.named_scope("mx.moe.experts"):
+        h, g, a = _expert_hidden(xs, w1, w3, sizes)
+        # d out / d (a W2) without the pair's weight: the weight's own
+        # gradient is its dot with a, the hidden state's is it weighted
+        gu = _product(dos, w2, sizes, True).astype(jnp.float32)
+        dw_sorted = jnp.sum(gu * a.astype(jnp.float32), -1, keepdims=True)
+        dy = (dos.astype(jnp.float32) * w_sorted).astype(dos.dtype)
+        dw2 = _product_t(a, dy, sizes, w2.dtype)
+        da = gu * w_sorted
+        h32, g32 = h.astype(jnp.float32), g.astype(jnp.float32)
+        sig = jax.nn.sigmoid(h32)
+        dh = (da * g32 * sig * (1 + h32 * (1 - sig))).astype(x.dtype)
+        dg = (da * h32 * sig).astype(x.dtype)
+        dw1 = _product_t(xs, dh, sizes, w1.dtype)
+        dw3 = _product_t(xs, dg, sizes, w3.dtype)
+        dxs = (_product(dh, w1, sizes, True).astype(jnp.float32)
+               + _product(dg, w3, sizes, True).astype(jnp.float32)
+               ).astype(x.dtype)
+    with jax.named_scope("mx.moe.combine"):
+        place, exists = _places(inverse, sizes, top_k)
+        dx = _sum_pairs(dxs, place, exists).astype(x.dtype)
+        dweights = jnp.where(exists, jnp.take(dw_sorted[:, 0], place), 0)
+    return (dx, dweights.astype(weights.dtype), None, None, None,
+            dw1, dw3, dw2)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def _sort_pairs(chosen, first_expert, held):
+    """Token-expert pairs by held expert, those on no held expert last:
+    ``(order, inverse, sizes)``.  A counting sort over the held + 1
+    keys: a pair's place is its key's start plus the pairs of that key
+    before it, so neither a sort nor a scatter is needed for *inverse*,
+    and *order* is one stable sort."""
+    key = chosen.reshape(-1) - first_expert
+    key = jnp.where((key >= 0) & (key < held), key, held)
+    onehot = (key[:, None] == jnp.arange(held + 1)).astype(jnp.int32)
+    counts = jnp.sum(onehot, axis=0)
+    starts = jnp.cumsum(counts) - counts
+    before = jnp.cumsum(onehot, axis=0) - onehot
+    inverse = jnp.sum((before + starts) * onehot, axis=1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    return order, inverse, counts[:held]
+
+
+def _record_moe_plan(tokens, router_experts, top_k, held, first_expert,
+                     dtype, hidden, expert_hidden):
+    """One `mx.moe.plan` span each time the op is traced (as
+    `mx.flash.plan`: the plan is a fact of the compiled program)."""
+    with profiler.scope(  # graftlint: disable=JG003
+            "mx.moe.plan", "moe") as span:
+        span.args = {
+            "router_experts": router_experts, "experts_per_token": top_k,
+            "experts_held": held, "first_expert": first_expert,
+            "tokens": tokens, "pair_bound": tokens * top_k,
+            "bound": "worst case: every choice of every token held here",
+            "dtype": jnp.dtype(dtype).name, "path": GROUPED_PATH,
+            "tiles": None if GROUPED_PATH != "megablox" else {
+                "up": _tiles(tokens * top_k, hidden, expert_hidden),
+                "down": _tiles(tokens * top_k, expert_hidden, hidden)}}
+
+
+@register_op("_contrib_RoutedExperts", aliases=("RoutedExperts",))
+def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
+                    num_experts_per_tok=1, first_expert=0,
+                    norm_topk_prob=True, routed_scaling_factor=1.0):
+    """Dropless top-k routed experts, one chip's share.
+
+    data ``(..., d)``; router_weight ``(E, d)`` over ALL the layer's
+    experts; w1, w3 ``(held, d, hidden)`` and w2 ``(held, hidden, d)``
+    are the experts ``first_expert .. first_expert + held - 1`` that
+    live here.  Routing is over all E: ``s = sigmoid(x W_r)``, the
+    chosen are the top-k of ``s + expert_bias`` (a fixed attribute, not
+    trained), a chosen expert's weight is ``s_e / (sum of the chosen s +
+    1e-6)`` (``norm_topk_prob``) times ``routed_scaling_factor``, all in
+    float32.  The result is the held experts' part, ``sum over e chosen
+    and held of w_e * W2_e(silu(W1_e x) * W3_e x)``: what the absent
+    experts would add is left out.  No capacity and no dropped token:
+    token-expert pairs are sorted by expert into a buffer of the worst
+    case (every choice of every token held here), the three products
+    run over the groups that exist, and each token gathers its pairs
+    back.  The counts of `routed_expert_counts` leave through
+    `profiler.emit_step_stat` under ``moe_expert_counts``.
+    """
+    lead, d = data.shape[:-1], data.shape[-1]
+    x = data.reshape(-1, d)
+    held, router_experts = w1.shape[0], router_weight.shape[0]
+    top_k, first = int(num_experts_per_tok), int(first_expert)
+    bias = tuple(expert_bias) or (0.0,) * router_experts
+    _record_moe_plan(x.shape[0], router_experts, top_k, held, first,
+                     data.dtype, d, w1.shape[2])
+    with jax.named_scope("mx.moe.route"):
+        chosen, weights = _route(x, router_weight, bias, top_k,
+                                 bool(norm_topk_prob),
+                                 float(routed_scaling_factor))
+        # at trace time on purpose: it hands the traced counts to the
+        # program that is being traced, which returns them every step
+        profiler.emit_step_stat(  # graftlint: disable=JG003
+            "moe_expert_counts",
+            routed_expert_counts(chosen, router_experts, first, held))
+        order, inverse, sizes = _sort_pairs(chosen, first, held)
+    out = _experts(x, weights, order, inverse, sizes, w1, w3, w2)
+    return out.reshape(*lead, d)
+
+
+profiler.register_step_stat("moe_expert_counts", _fold_expert_counts)

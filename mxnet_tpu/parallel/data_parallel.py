@@ -22,11 +22,13 @@ what Gluon's Trainer uses when constructed with ``kvstore='tpu'``.
 
 from __future__ import annotations
 
+import collections
 import re
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import make_mesh, use_mesh
@@ -158,6 +160,8 @@ class ParallelTrainer:
         self._aux = None
         self._graph = None
         self._num_update = 0
+        # counts the step hands out, not yet folded into counters
+        self._stats_pending = collections.deque()
 
     # -- tracing -----------------------------------------------------------
     def _trace(self, x, y):
@@ -468,15 +472,20 @@ class ParallelTrainer:
                 amap = dict(p)
                 amap["data0"] = x
                 amap["label0"] = y
-                outs, auxu = eval_fn(amap, aux, key)
-                return jnp.mean(outs[0].astype(jnp.float32)), auxu
+                # what the ops count inside the step (routed tokens an
+                # expert) leaves beside the loss: profiler "statistics
+                # that leave a compiled step"
+                with _prof.collect_step_stats() as stats:
+                    outs, auxu = eval_fn(amap, aux, key)
+                stats = {k: jnp.stack(v) for k, v in stats.items()}
+                return jnp.mean(outs[0].astype(jnp.float32)), (auxu, stats)
 
             if remat is not None:
                 loss_of = jax.checkpoint(loss_of, policy=policy)
             # device scopes (docs/observability.md "Spans"): forward and
             # backward under mx.loss, then mx.grad_clip and mx.optimizer
             with jax.named_scope("mx.loss"):
-                (loss_val, auxu), grads = jax.value_and_grad(
+                (loss_val, (auxu, stats)), grads = jax.value_and_grad(
                     loss_of, has_aux=True)(params)
             if grad_clip is not None:
                 with jax.named_scope("mx.grad_clip"):
@@ -492,7 +501,7 @@ class ParallelTrainer:
                     params, grads, opt_state, lr, t)
             new_aux = dict(aux)
             new_aux.update(auxu)
-            return new_params, new_state, new_aux, loss_val
+            return new_params, new_state, new_aux, loss_val, stats
 
         def apply_updates(params, grads, opt_state, lr, t):
             new_params = {}
@@ -567,7 +576,7 @@ class ParallelTrainer:
             # pin outputs to the input layout so the params/state returned
             # by step N are valid inputs for step N+1 (otherwise XLA's
             # sharding propagation may choose a different layout)
-            out_shardings=(param_sh, state_sh, aux_sh, repl),
+            out_shardings=(param_sh, state_sh, aux_sh, repl, repl),
             donate_argnums=(0, 1, 2))
 
         eval_infer = self._eval_infer
@@ -759,9 +768,33 @@ class ParallelTrainer:
                     out = self._step_fn(*self._step_args(x, y))
             else:
                 out = self._first_step(x, y)
-            self._params, self._opt_state, self._aux, loss = out
+            self._params, self._opt_state, self._aux, loss, stats = out
             self._num_update += 1
+            if stats or self._stats_pending:
+                self._keep_step_stats(stats)
         return loss
+
+    def _keep_step_stats(self, stats):
+        """Start *stats* (the step's counts, small device arrays) on
+        their way to the host, and fold into the counters those of
+        earlier steps that have arrived: no wait, no dispatch."""
+        if stats:
+            for a in stats.values():
+                a.copy_to_host_async()
+            self._stats_pending.append(stats)
+        while self._stats_pending and all(
+                a.is_ready() for a in self._stats_pending[0].values()):
+            self._fold_oldest_stats()
+
+    def _fold_oldest_stats(self):
+        _prof.fold_step_stats(
+            {k: np.asarray(a)
+             for k, a in self._stats_pending.popleft().items()})
+
+    def flush_step_stats(self):
+        """Wait for the counts of every step so far and fold them."""
+        while self._stats_pending:
+            self._fold_oldest_stats()
 
     def _step_args(self, x, y):
         xd = self._device_batch(x)

@@ -44,7 +44,8 @@ __all__ = ["set_config", "set_state", "pause", "resume", "dump", "dumps",
            "Domain", "Task", "Frame", "Event", "Counter", "Marker",
            "scope", "spans", "Span", "scope_map", "set_scope_map",
            "compile_seconds", "bump_counter", "counter_value", "counters",
-           "reset_counters"]
+           "reset_counters", "collect_step_stats", "emit_step_stat",
+           "register_step_stat", "fold_step_stats"]
 
 _lock = _san.rlock(label="profiler._lock")
 _marks = []             # chrome trace counter ('C') and marker ('i') dicts
@@ -247,6 +248,56 @@ def counters():
 def reset_counters():
     for name in list(_count_names):
         _instruments[name]._reset()
+
+
+# -- statistics that leave a compiled step ------------------------------------
+# An op that counts something inside a compiled step (tokens routed to
+# each expert) hands the small device array to `emit_step_stat` while it
+# is traced.  The program that traces the step collects them
+# (`collect_step_stats`) and returns them beside its results;
+# `ParallelTrainer` keeps them until they are ready and then folds them
+# into counters on the host (`fold_step_stats`), through the function
+# registered for the name.  Outside a collection the value is dropped:
+# no callback, no readback, no extra dispatch.
+_collecting = threading.local()     # .open: the dicts being collected
+_stat_folds = {}                    # name -> fold(stacked numpy array)
+
+
+class collect_step_stats:
+    """While open on this thread, `emit_step_stat` values land in the
+    dict this yields: ``name -> [arrays, in the order emitted]``."""
+
+    def __enter__(self):
+        self._stats = {}
+        if not hasattr(_collecting, "open"):
+            _collecting.open = []
+        _collecting.open.append(self._stats)
+        return self._stats
+
+    def __exit__(self, *exc):
+        _collecting.open.pop()
+
+
+def emit_step_stat(name, value):
+    """Hand *value* (a small array, traced or not) to the innermost open
+    collection under *name*; dropped where none is open."""
+    open_ = getattr(_collecting, "open", None)
+    if open_:
+        open_[-1].setdefault(name, []).append(value)
+
+
+def register_step_stat(name, fold):
+    """``fold(values)`` turns one step's stacked *name* values (a numpy
+    array, one row per emission) into counters."""
+    _stat_folds[name] = fold
+
+
+def fold_step_stats(stats):
+    """Fold one step's ``name -> numpy array`` into the counters."""
+    for name, values in stats.items():
+        fold = _stat_folds.get(name)
+        if fold is not None:
+            fold(values)
 _config = {
     "filename": "profile.json",
     "profile_all": False,

@@ -1,0 +1,463 @@
+"""The layered decoder (`gluon/model_zoo/decoder.py`) and the operators
+under it (`ops/lm_blocks.py`) against the plain float32 reference
+`benchmarks/reference/lfm2_moe.py`, at a small size on the CPU with
+seeded weights: float32 on both sides, so only the order of the
+arithmetic differs."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmarks import compare  # noqa: E402
+from benchmarks.models import common as models_common  # noqa: E402
+from benchmarks.models import lfm2_moe as family  # noqa: E402
+from benchmarks.reference import common as ref_common  # noqa: E402
+from benchmarks.reference import lfm2_moe as reference  # noqa: E402
+from mxnet_tpu import profiler  # noqa: E402
+from mxnet_tpu.ops import lm_blocks  # noqa: E402
+from mxnet_tpu.ops.registry import get_op  # noqa: E402
+
+SEED = 2 ** 31 + 5
+
+
+def config(layer_types, num_dense_layers, **changes):
+    cfg = {"family": "lfm2_moe", "hidden_size": 64, "intermediate_size": 128,
+           "moe_intermediate_size": 32, "layer_types": list(layer_types),
+           "num_dense_layers": num_dense_layers, "num_experts": 4,
+           "num_routed_experts": 8, "first_expert": 0,
+           "num_experts_per_tok": 2, "num_attention_heads": 4,
+           "num_key_value_heads": 2, "rope_theta": 1000000,
+           "norm_eps": 1e-5, "conv_L_cache": 3, "vocab_size": 96,
+           "norm_topk_prob": True, "routed_scaling_factor": 1,
+           "expert_bias_scale": 0.05, "initializer_range": 0.02,
+           "train": {"optimizer": "sgd", "lr": 0.01, "momentum": 0.9,
+                     "wd": 0.0, "multi_precision": False,
+                     "sequence_length": 32, "per_chip_batch": 4}}
+    cfg.update(changes)
+    return cfg
+
+
+def seeded(cfg, seed=SEED):
+    """``(net, loss, names, reference parameters)`` from one seed."""
+    table = reference.param_table(cfg)
+    net, loss = family.build(cfg)
+    names = models_common.seeded_net(
+        net, table, ref_common.init_params(table, seed))
+    return net, loss, names, ref_common.init_params(table, seed)
+
+
+def highest(fn, *args):
+    """``fn(*args)`` (arrays only) as one jitted program whose float32
+    contractions are exact."""
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(fn)(*args)
+
+
+# one net for each kind of layer, and all of them together (what the
+# benchmark's configuration stacks), each with the tied head
+KINDS = {
+    "dense-conv": (["conv"], 1),
+    "routed-attention": (["full_attention"], 0),
+    "routed-conv": (["conv"], 0),
+    "all": (["conv", "full_attention", "conv"], 1),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_logits_loss_and_every_gradient_agree_with_the_reference(kind):
+    import mxnet_tpu as mx
+    cfg = config(*KINDS[kind])
+    net, loss, names, params = seeded(cfg)
+    (x, y), = family.batches(cfg, SEED, 1, 4)
+    got = net(mx.nd.array(x, dtype="int32")).asnumpy()
+    want = highest(lambda p: reference.logits(p, cfg, x), params)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-4, atol=2e-6)
+
+    # the loss, and each leaf's gradient as the optimizer got it
+    # (|mom_1| = lr * g), through the trainer every cell runs
+    trainer = models_common.make_trainer(net, loss, cfg["train"],
+                                         jax.devices()[:1])
+    got_loss = float(trainer.fit_batch(x, y))
+    value, grads = highest(jax.value_and_grad(
+        lambda p: reference.loss_sum(p, cfg, x, y)), params)
+    assert got_loss == pytest.approx(float(value) / 4, rel=1e-5)
+    assert set(names) == set(grads)                 # one `embed` leaf
+    lr = cfg["train"]["lr"]
+    for ref_name, prog_name in names.items():
+        g = -np.asarray(trainer._opt_state[prog_name][0]) / lr
+        w = np.asarray(grads[ref_name]) / 4
+        scale = max(np.abs(w).max(), 1e-12)
+        assert np.abs(g - w).max() <= 2e-4 * scale, ref_name
+
+
+def test_three_trainer_steps_follow_the_reference():
+    cfg = config(*KINDS["all"])
+    train = cfg["train"]
+    table = reference.param_table(cfg)
+    net, loss, names, params = seeded(cfg)
+    batches = family.batches(cfg, SEED, 3, 4)
+    trainer = models_common.make_trainer(net, loss, train, jax.devices()[:1])
+    to_ref = {prog: ref for ref, prog in names.items()}
+    got = {"losses": []}
+    for i, (x, y) in enumerate(batches):
+        got["losses"].append(float(trainer.fit_batch(x, y)))
+        if i == 0:
+            mom = {n: trainer._opt_state[n][0] for n in trainer.param_names}
+            got["first_update_norms"] = ref_common.leaf_norms(mom)
+            first = {to_ref[n]: np.asarray(a) for n, a in mom.items()}
+    dist = ref_common.distance_from_init(
+        table, SEED, {to_ref[n]: trainer._params[n]
+                      for n in trainer.param_names})
+    got["total_update_norms"] = {names[r]: v for r, v in dist.items()}
+    with jax.default_matmul_precision("highest"):
+        ref = ref_common.follow_steps(
+            lambda p, x, y: reference.loss_sum(p, cfg, x, y), params,
+            batches, {"lr": train["lr"], "momentum": train["momentum"],
+                      "wd": train["wd"]},
+            lambda p: ref_common.distance_from_init(table, SEED, p),
+            rows_per_block=2, first_update=first)
+    for name, (value, detail) in compare.training_numbers(
+            got, ref, names).items():
+        assert value <= 1e-4, (name, value, detail)
+
+
+# -- the routed layer ---------------------------------------------------------
+D, F, E, K = 32, 16, 8, 2
+
+
+def routed_inputs(tokens=48, seed=0):
+    rng = np.random.default_rng(seed)
+    return {"x": jnp.asarray(rng.normal(size=(tokens, D)), jnp.float32),
+            "router": jnp.asarray(0.3 * rng.normal(size=(E, D)), jnp.float32),
+            "expert_w1": jnp.asarray(0.2 * rng.normal(size=(E, D, F)),
+                                     jnp.float32),
+            "expert_w3": jnp.asarray(0.2 * rng.normal(size=(E, D, F)),
+                                     jnp.float32),
+            "expert_w2": jnp.asarray(0.2 * rng.normal(size=(E, F, D)),
+                                     jnp.float32)}
+
+
+def routed_op(v, first, held, bias=(), x=None):
+    sl = slice(first, first + held)
+    return get_op("_contrib_RoutedExperts").fn(
+        v["x"] if x is None else x, v["router"], v["expert_w1"][sl],
+        v["expert_w3"][sl], v["expert_w2"][sl], expert_bias=tuple(bias),
+        num_experts_per_tok=K, first_expert=first)
+
+
+def routed_cfg(scale=0.0):
+    return {"num_routed_experts": E, "num_experts_per_tok": K,
+            "num_experts": E, "first_expert": 0, "expert_bias_scale": scale}
+
+
+def reference_layer(v, cfg, first=0, held=E):
+    p = {"l." + k: a for k, a in v.items() if k != "x"}
+    sl = slice(first, first + held)
+    for k in ("expert_w1", "expert_w3", "expert_w2"):
+        p["l." + k] = p["l." + k][sl]
+    return highest(lambda p, x: reference.routed(p, "l.", cfg, x, False,
+                                                 first, held), p, v["x"])
+
+
+def test_the_four_shares_of_a_routed_layer_add_up_to_the_uncut_layer():
+    """What four chips, each holding a quarter of the experts, compute
+    for the same tokens adds up to the reference's whole layer."""
+    v = routed_inputs()
+    cfg = routed_cfg(0.2)
+    bias = reference.expert_bias(cfg)
+    whole = reference_layer(v, cfg)
+    shares = [routed_op(v, first, E // 4, bias)
+              for first in range(0, E, E // 4)]
+    np.testing.assert_allclose(np.asarray(sum(shares)), np.asarray(whole),
+                               rtol=1e-5, atol=1e-6)
+    # ... and one share is the reference's own share, not a quarter of it
+    np.testing.assert_allclose(
+        np.asarray(shares[1]),
+        np.asarray(reference_layer(v, cfg, E // 4, E // 4)),
+        rtol=1e-5, atol=1e-6)
+    assert np.abs(np.asarray(shares[1] - whole / 4)).max() > 1e-3
+
+
+def test_nothing_is_dropped_when_every_token_goes_to_one_held_expert():
+    """A bias that sends every token's first choice to expert 1 and its
+    second to expert 0: 2 x tokens pairs on two of the four held experts,
+    none dropped; the two experts left without a token get a zero
+    gradient, not a NaN."""
+    v = routed_inputs()
+    bias = [50.0, 100.0] + [0.0] * (E - 2)
+
+    def held_part(w1, w3, w2, x):
+        u = dict(v, expert_w1=w1, expert_w3=w3, expert_w2=w2)
+        return routed_op(u, 0, 4, bias, x=x)
+
+    out = jax.jit(held_part)(v["expert_w1"], v["expert_w3"], v["expert_w2"],
+                             v["x"])
+    # by hand: every token through experts 0 and 1, weighted by its scores
+    s = jax.nn.sigmoid(v["x"] @ v["router"].T)
+    w = s[:, :2] / (s[:, :1] + s[:, 1:2] + 1e-6)
+    want = sum(w[:, e:e + 1] * highest(
+        lambda *a: reference.gated(*a, False), v["x"], v["expert_w1"][e],
+        v["expert_w3"][e], v["expert_w2"][e]) for e in (0, 1))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    assert float(jnp.abs(out).min(axis=1).max()) > 0    # every token served
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(held_part(*a) ** 2),
+                             argnums=(0, 1, 2, 3)))(
+        v["expert_w1"], v["expert_w3"], v["expert_w2"], v["x"])
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+    for g in grads[:3]:
+        assert float(jnp.abs(g[:2]).max()) > 0
+        assert float(jnp.abs(g[2:4]).max()) == 0.0
+
+
+def test_the_choice_is_on_score_plus_bias_and_the_weight_on_the_score():
+    """With a bias that reverses the scores' order the experts chosen are
+    the LOWEST scoring, and their weights are their own scores over the
+    sum of the chosen scores."""
+    v = routed_inputs(tokens=16)
+    scores = np.asarray(jax.nn.sigmoid(v["x"] @ v["router"].T))
+    lowest = np.argsort(scores, axis=1)[:, :K]
+    # a bias so large and ordered that the choice is by bias alone:
+    # experts E-1 and E-2 for every token, whatever their scores
+    bias = 10.0 * np.arange(E)
+    chosen, weights = lm_blocks._route(
+        v["x"], v["router"], tuple(bias), K, True, 1.0)
+    assert (np.sort(np.asarray(chosen), 1) == [E - 2, E - 1]).all()
+    picked = scores[:, [E - 1, E - 2]]
+    np.testing.assert_allclose(
+        np.asarray(weights), picked / (picked.sum(1, keepdims=True) + 1e-6),
+        rtol=1e-6)
+    # ... and without a bias, the highest scoring
+    chosen, _ = lm_blocks._route(v["x"], v["router"], (0.0,) * E, K, True,
+                                 1.0)
+    assert (np.sort(np.asarray(chosen), 1)
+            == np.sort(np.argsort(-scores, axis=1)[:, :K], 1)).all()
+    assert not (np.sort(np.asarray(chosen), 1) == np.sort(lowest, 1)).all()
+
+
+@pytest.mark.parametrize("first", [0, 2, 4])
+def test_the_routed_layer_s_gradients_agree_with_the_reference(first):
+    v = routed_inputs()
+    cfg = routed_cfg(0.2)
+    bias = reference.expert_bias(cfg)
+    names = ("x", "router", "expert_w1", "expert_w3", "expert_w2")
+
+    def program(*a):
+        return jnp.sum(jnp.sin(routed_op(dict(zip(names, a)), first, 4,
+                                         bias)))
+
+    def plain(*a):
+        return jnp.sum(jnp.sin(reference_layer(dict(zip(names, a)), cfg,
+                                               first, 4)))
+
+    args = [v[n] for n in names]
+    got = jax.jit(jax.grad(program, argnums=range(5)))(*args)
+    want = highest(jax.grad(plain, argnums=range(5)), *args)
+    for n, g, w in zip(names, got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-6, err_msg=n)
+    # the experts that are not held get no gradient from this share
+    held = np.zeros(E, bool)
+    held[first:first + 4] = True
+    assert float(jnp.abs(got[2][~held]).max()) == 0.0
+    assert float(jnp.abs(got[2][held]).min(axis=(1, 2)).max()) > 0
+
+
+def test_the_kernel_path_lowers_for_the_tpu_without_a_chip():
+    """The public op lowered for the TPU platform from this CPU host at
+    the cell's widths: the grouped products are the Mosaic kernels there
+    (3 forward; backward 5, the hidden states again among them, and 3
+    transposed ones for the weights' gradients) and no ragged dot is
+    left."""
+    n, d, f, held = 2048, 2048, 1792, 8
+    avals = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
+        (n, d), (32, d), (held, d, f), (held, d, f), (held, f, d))]
+    fn = get_op("_contrib_RoutedExperts").fn
+
+    def fwd(*a):
+        return fn(*a, num_experts_per_tok=4)
+
+    def loss(*a):
+        return jnp.sum(fwd(*a).astype(jnp.float32))
+
+    def calls(f):
+        text = jax.jit(f).trace(*avals).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "ragged_dot" not in text and "tpu_custom_call" in text
+        return text.count("call @gmm"), text.count("call @tgmm")
+
+    assert calls(fwd) == (3, 0)
+    # the loss needs no forward product: its gradient is all that runs
+    assert calls(jax.grad(loss, argnums=(0, 2, 3, 4))) == (5, 3)
+
+
+# -- the other operators ------------------------------------------------------
+def test_the_short_convolution_is_causal_and_agrees_with_the_reference():
+    cfg = config(["conv"], 0)
+    rng = np.random.default_rng(3)
+    d = cfg["hidden_size"]
+    x = jnp.asarray(rng.normal(size=(2, 12, d)), jnp.float32)
+    p = {"l.conv_in": jnp.asarray(0.2 * rng.normal(size=(3 * d, d)),
+                                  jnp.float32),
+         "l.conv_w": jnp.asarray(rng.normal(size=(d, 3)), jnp.float32),
+         "l.conv_out": jnp.asarray(0.2 * rng.normal(size=(d, d)),
+                                   jnp.float32)}
+    fn = get_op("_contrib_GatedShortConv").fn
+
+    def run(x):
+        return fn(x, p["l.conv_in"], p["l.conv_w"], p["l.conv_out"])
+
+    np.testing.assert_allclose(
+        np.asarray(run(x)),
+        np.asarray(highest(
+            lambda p, x: reference.short_conv(p, "l.", cfg, x, False), p,
+            x)),
+        rtol=1e-5, atol=1e-6)
+    # a change at t + 1 moves nothing at or before t, and does move t + 1
+    t = 6
+    moved = np.asarray(run(x.at[:, t + 1].add(1.0)) - run(x))
+    assert np.abs(moved[:, :t + 1]).max() == 0.0
+    assert np.abs(moved[:, t + 1]).max() > 1e-3
+    # by hand at one position: taps t-2, t-1, t
+    bcx = x @ p["l.conv_in"].T
+    u = np.asarray(bcx[..., :d] * bcx[..., 2 * d:])
+    w = np.asarray(p["l.conv_w"])
+    c = u[:, t - 2] * w[:, 0] + u[:, t - 1] * w[:, 1] + u[:, t] * w[:, 2]
+    want = (np.asarray(bcx[:, t, d:2 * d]) * c) @ np.asarray(
+        p["l.conv_out"]).T
+    np.testing.assert_allclose(np.asarray(run(x))[:, t], want, rtol=1e-4,
+                               atol=1e-6)
+
+
+def test_grouped_query_attention_against_the_plain_attention():
+    """Rotary positions, the norm of each q and k head and 4 query heads
+    over 2 key/value heads: the Gluon block against the reference's
+    attention, which repeats nothing."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.contrib.nn import GroupedQueryAttention
+    cfg = config(["full_attention"], 0)
+    d, hd = cfg["hidden_size"], 16
+    rng = np.random.default_rng(4)
+    shapes = {"wq": (4 * hd, d), "wk": (2 * hd, d), "wv": (2 * hd, d),
+              "wo": (d, 4 * hd)}
+    p = {"l." + k: jnp.asarray(0.2 * rng.normal(size=s), jnp.float32)
+         for k, s in shapes.items()}
+    p["l.q_norm"] = jnp.asarray(1 + 0.3 * rng.normal(size=hd), jnp.float32)
+    p["l.k_norm"] = jnp.asarray(1 + 0.3 * rng.normal(size=hd), jnp.float32)
+    x = rng.normal(size=(2, 24, d)).astype(np.float32)
+    blk = GroupedQueryAttention(d, 4, 2, hd, rope_theta=1000000.0)
+    blk.initialize()
+    for param, key in zip(blk.collect_params().values(),
+                          ("wq", "wk", "wv", "wo", "q_norm", "k_norm")):
+        param.set_data(mx.nd.array(np.asarray(p["l." + key])))
+    got = blk(mx.nd.array(x)).asnumpy()
+    want = highest(lambda p, x: reference.attention(p, "l.", cfg, x, False),
+                   p, jnp.asarray(x))
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-4, atol=2e-6)
+    # positions matter: the same token later in the sequence reads
+    # differently (a rotation, not a no-op)
+    rolled = blk(mx.nd.array(np.roll(x, 1, axis=1))).asnumpy()
+    assert np.abs(np.roll(rolled, -1, axis=1)[:, 2:-1]
+                  - got[:, 2:-1]).max() > 1e-4
+
+
+def test_rms_norm_and_the_gated_mlp_by_hand():
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(3, 5, 8)).astype(np.float32)
+    g = rng.normal(size=8).astype(np.float32)
+    got = get_op("_contrib_RMSNorm").fn(jnp.asarray(x), jnp.asarray(g),
+                                        eps=1e-5)
+    want = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-5) * g
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5)
+    w1, w3 = (rng.normal(size=(6, 8)).astype(np.float32) for _ in range(2))
+    w2 = rng.normal(size=(8, 6)).astype(np.float32)
+    got = get_op("_contrib_GatedMLP").fn(jnp.asarray(x), w1, w3, w2)
+    a = x @ w1.T
+    want = ((a / (1 + np.exp(-a))) * (x @ w3.T)) @ w2.T
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+
+
+# -- what leaves the step -----------------------------------------------------
+def test_the_counters_equal_the_reference_s_counts_and_the_plan_is_recorded():
+    cfg = config(*KINDS["all"])
+    net, loss, _, params = seeded(cfg)
+    batches = family.batches(cfg, SEED, 1, 4)
+    trainer = models_common.make_trainer(net, loss, cfg["train"],
+                                         jax.devices()[:1])
+    names = ("moe_stat_steps_total", "moe_stat_layers_total",
+             "moe_assignments_total", "moe_local_assignments_total",
+             "moe_tokens_without_local_expert_total",
+             "moe_expert_load_max_over_mean_sum")
+    before = {n: profiler.counter_value(n) for n in names}
+    since = max([s.id for s in profiler.spans()] or [0])
+    dispatches = profiler.counter_value("parallel_step_dispatches")
+    x, y = batches[0]
+    trainer.fit_batch(x, y)
+    trainer.flush_step_stats()
+    assert profiler.counter_value("parallel_step_dispatches") \
+        == dispatches + 1                       # the counts cost no dispatch
+    got = {n: profiler.counter_value(n) - before[n] for n in names}
+    load = np.asarray(highest(
+        lambda p: reference.expert_counts(p, cfg, x), params))
+    tokens, top_k = x.size, cfg["num_experts_per_tok"]
+    assert load.shape == (2, 8) and (load.sum(1) == tokens * top_k).all()
+    assert got["moe_stat_steps_total"] == 1
+    assert got["moe_stat_layers_total"] == 2
+    assert got["moe_assignments_total"] == load.sum()
+    assert got["moe_local_assignments_total"] == load[:, :4].sum()
+    assert got["moe_expert_load_max_over_mean_sum"] == pytest.approx(
+        (load.max(1) / load.mean(1)).sum())
+    assert 0 <= got["moe_tokens_without_local_expert_total"] <= 2 * tokens
+    plans = [s for s in profiler.spans()
+             if s.name == "mx.moe.plan" and s.id > since]
+    assert plans and plans[0].args["experts_held"] == 4
+    assert plans[0].args["router_experts"] == 8
+    assert plans[0].args["pair_bound"] == tokens * top_k
+    assert plans[0].args["path"] == lm_blocks.GROUPED_PATH
+
+
+def test_counts_fold_only_once_they_are_ready_and_in_order():
+    """`fit_batch` folds the counts of earlier steps that have arrived
+    and never waits; `flush_step_stats` takes the rest."""
+    cfg = config(["conv"], 0)
+    net, loss, _, _ = seeded(cfg)
+    trainer = models_common.make_trainer(net, loss, cfg["train"],
+                                         jax.devices()[:1])
+    before = profiler.counter_value("moe_stat_steps_total")
+    for x, y in family.batches(cfg, SEED, 3, 4):
+        trainer.fit_batch(x, y)
+    folded = profiler.counter_value("moe_stat_steps_total") - before
+    assert 0 <= folded <= 3 and folded + len(trainer._stats_pending) == 3
+    trainer.flush_step_stats()
+    assert profiler.counter_value("moe_stat_steps_total") - before == 3
+    assert not trainer._stats_pending
+
+
+def test_a_value_emitted_outside_a_collection_is_dropped():
+    profiler.emit_step_stat("moe_expert_counts", np.zeros((1, 10)))
+    with profiler.collect_step_stats() as stats:
+        profiler.emit_step_stat("a", 1)
+        with profiler.collect_step_stats() as inner:
+            profiler.emit_step_stat("a", 2)
+        profiler.emit_step_stat("a", 3)
+    assert stats == {"a": [1, 3]} and inner == {"a": [2]}
+
+
+def test_the_expert_bias_is_the_configuration_s_and_not_a_parameter():
+    cfg = config(["conv"], 0, expert_bias_scale=0.25)
+    net, _ = family.build(cfg)
+    bias = reference.expert_bias(cfg)
+    assert bias[0] == 0.25 and bias.min() == pytest.approx(-0.25)
+    attrs = net.layers[0].feed_forward._attrs
+    np.testing.assert_allclose(attrs["expert_bias"], bias)
+    assert len(net.collect_params()) == len(reference.param_table(cfg))
+    assert not any("bias" in n for n in net.collect_params())
