@@ -19,6 +19,7 @@ head and ``_contrib_RotaryEmbedding`` (gluon.contrib.nn
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +112,13 @@ def _gated_short_conv(data, in_weight, conv_weight, out_weight):
 GROUPED_PATH = "megablox"
 GROUPED_TILES = (512, 1024, 1024)
 
+#: rows of the pair buffer over the pairs that uniform routing would land
+#: on the held experts.  The benchmark's cell lands 1.17 to 1.20 times
+#: those in every routed layer (PERF.md section 6, PR 26 and PR 27); a
+#: quarter is to spare, and a step with more pairs than rows runs at the
+#: worst case and drops nothing.  Not an option either
+BUFFER_FACTOR = 1.5
+
 
 def _route(x, router_weight, bias, top_k, norm_topk_prob, scaling):
     """Sigmoid scores over all the router's experts, the top-k of score +
@@ -126,31 +134,41 @@ def _route(x, router_weight, bias, top_k, norm_topk_prob, scaling):
     return chosen.astype(jnp.int32), weights * scaling
 
 
-def routed_expert_counts(chosen, num_router_experts, first_expert, held):
+def routed_expert_counts(chosen, num_router_experts, first_expert, held,
+                         buffer_rows):
     """What the counters are folded from, as one int32 row: the tokens
     assigned to each of the router's experts, then the pairs that landed
-    on a held expert, then the tokens with no held expert among their
-    choices."""
+    on a held expert, the tokens with no held expert among their choices,
+    the rows the pair buffer ran at (*buffer_rows* where the pairs fit
+    them, every choice of every token where not) and whether it was the
+    worst case for want of rows (`_at_buffer` decides on the same count).
+    """
     load = jnp.sum(chosen.reshape(-1, 1) == jnp.arange(num_router_experts),
                    axis=0, dtype=jnp.int32)
     local = (chosen >= first_expert) & (chosen < first_expert + held)
-    return jnp.concatenate([
-        load, jnp.sum(local, dtype=jnp.int32)[None],
-        jnp.sum(~jnp.any(local, axis=1), dtype=jnp.int32)[None]])
+    pairs = jnp.sum(local, dtype=jnp.int32)
+    overflowed = pairs > buffer_rows
+    return jnp.concatenate([load, jnp.stack([
+        pairs, jnp.sum(~jnp.any(local, axis=1), dtype=jnp.int32),
+        jnp.where(overflowed, chosen.size, buffer_rows),
+        overflowed]).astype(jnp.int32)])
 
 
 def _fold_expert_counts(rows):
     """One step's rows (one per routed layer) into the counters; runs on
     the host, where `profiler.fold_step_stats` is called."""
     rows = np.asarray(rows).reshape(-1, rows.shape[-1])
-    load = rows[:, :-2].astype(np.float64)
+    load = rows[:, :-4].astype(np.float64)
+    pairs, without, ran_at, overflowed = rows[:, -4:].sum(axis=0)
     profiler.bump_counter("moe_stat_steps_total")
     profiler.bump_counter("moe_stat_layers_total", len(rows))
     profiler.bump_counter("moe_assignments_total", int(load.sum()))
-    profiler.bump_counter("moe_local_assignments_total",
-                          int(rows[:, -2].sum()))
+    profiler.bump_counter("moe_local_assignments_total", int(pairs))
     profiler.bump_counter("moe_tokens_without_local_expert_total",
-                          int(rows[:, -1].sum()))
+                          int(without))
+    profiler.bump_counter("moe_buffer_rows_total", int(ran_at))
+    profiler.bump_counter("moe_worst_case_buffer_layers_total",
+                          int(overflowed))
     profiler.bump_counter(
         "moe_expert_load_max_over_mean_sum",
         float((load.max(axis=1) / np.maximum(load.mean(axis=1), 1e-30)
@@ -194,53 +212,59 @@ def _fit(size, cap):
     return size
 
 
-def _tiles(m, k, n):
-    """`GROUPED_TILES` cut to the problem: every tile divides its
-    dimension (1792 = 7 x 256 takes 896 under a cap of 1024)."""
-    return tuple(_fit(size, cap)
-                 for size, cap in zip((m, k, n), GROUPED_TILES))
+def _tiles(caps, m, k, n):
+    """The tiles *caps* (`GROUPED_TILES`) cut to the problem: every tile
+    divides its dimension (1792 = 7 x 256 takes 896 under a cap of
+    1024)."""
+    return tuple(_fit(size, cap) for size, cap in zip((m, k, n), caps))
 
 
-def _megablox(lhs, rhs, sizes, transpose_rhs=False):
+def _megablox(lhs, rhs, sizes, caps, transpose_rhs=False):
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     return _megablox_backend().gmm(
-        lhs, rhs, sizes, lhs.dtype, _tiles(m, k, n),
+        lhs, rhs, sizes, lhs.dtype, _tiles(caps, m, k, n),
         transpose_rhs=transpose_rhs)
 
 
-def _megablox_t(lhs, grad, sizes, dtype):
+def _megablox_t(lhs, grad, sizes, caps, dtype):
     m, k = lhs.shape
     return _megablox_backend().tgmm(
         lhs.swapaxes(0, 1), grad, sizes, dtype,
-        _tiles(m, k, grad.shape[1]))
+        _tiles(caps, m, k, grad.shape[1]))
 
 
-def _product(lhs, rhs, sizes, transpose_rhs=False):
+# *grouped* below is ``(path, tiles)``: `GROUPED_PATH` and `GROUPED_TILES`
+# as `_at_buffer` read them, or "ragged" where it says so
+
+def _product(grouped, lhs, rhs, sizes, transpose_rhs=False):
     """Rows of *lhs* in groups of *sizes* against ``rhs[group]`` (against
     its transpose with *transpose_rhs*).  The kernels exist for the TPU
     alone; every other platform takes XLA's ragged dot."""
-    if GROUPED_PATH == "ragged":
+    path, caps = grouped
+    if path == "ragged":
         return _ragged(lhs, rhs, sizes, transpose_rhs)
     return jax.lax.platform_dependent(
         lhs, rhs, sizes,
-        tpu=functools.partial(_megablox, transpose_rhs=transpose_rhs),
+        tpu=functools.partial(_megablox, caps=caps,
+                              transpose_rhs=transpose_rhs),
         default=functools.partial(_ragged, transpose_rhs=transpose_rhs))
 
 
-def _product_t(lhs, grad, sizes, dtype):
+def _product_t(grouped, lhs, grad, sizes, dtype):
     """``lhs[group].T @ grad[group]`` for each group."""
-    if GROUPED_PATH == "ragged":
+    path, caps = grouped
+    if path == "ragged":
         return _ragged_t(lhs, grad, sizes, dtype)
     return jax.lax.platform_dependent(
         lhs, grad, sizes,
-        tpu=functools.partial(_megablox_t, dtype=dtype),
+        tpu=functools.partial(_megablox_t, caps=caps, dtype=dtype),
         default=functools.partial(_ragged_t, dtype=dtype))
 
 
-def _expert_hidden(xs, w1, w3, sizes):
-    h = _product(xs, w1, sizes)
-    g = _product(xs, w3, sizes)
+def _expert_hidden(grouped, xs, w1, w3, sizes):
+    h = _product(grouped, xs, w1, sizes)
+    g = _product(grouped, xs, w3, sizes)
     return h, g, _silu_mul(h, g)
 
 
@@ -273,64 +297,121 @@ def _places(inverse, sizes, top_k):
     return jnp.where(exists, place, 0), exists
 
 
-@jax.custom_vjp
-def _experts(x, weights, order, inverse, sizes, w1, w3, w2):
+def _buffer_rows(tokens, top_k, held, router_experts):
+    """Rows of the pair buffer: `BUFFER_FACTOR` times the pairs uniform
+    routing lands on the held experts, in whole row tiles, and never more
+    than the worst case (every choice of every token held here), which it
+    is where all the router's experts are held or the shape is smaller
+    than a tile."""
+    worst, tile = tokens * top_k, GROUPED_TILES[0]
+    expected = math.ceil(BUFFER_FACTOR * worst * held / router_experts)
+    return min(worst, -(-expected // tile) * tile)
+
+
+def _at_buffer(rows, body, order, sizes, *rest):
+    """``body(n, grouped, order, sizes, *rest)`` at a pair buffer of ``n``
+    = *rows* rows where the pairs that exist fit them, and at the worst
+    case's where they do not: the same pairs in the same groups from row
+    0 either way, so nothing is dropped.  Where *rows* is the worst case
+    there is one path and no `cond`.  Called from inside the custom VJP's
+    two sides, so JAX differentiates neither branch and each keeps its
+    temporaries to itself.
+
+    The worst-case branch of a `cond` takes XLA's ragged dot on every
+    platform: with the kernels in both branches a step holds twice the
+    Mosaic programs, and tracing, lowering and loading the second set
+    cost a set-up 3 s of 31 where no step of the benchmark ever runs
+    them (PERF.md section 6, PR 27).  A step that overflows takes half
+    as long again in this layer and sums in XLA's order, not the
+    kernels'."""
+    worst, grouped = order.shape[0], (GROUPED_PATH, GROUPED_TILES)
+    if rows == worst:
+        return body(worst, grouped, order, sizes, *rest)
+    return jax.lax.cond(
+        jnp.sum(sizes) <= rows, functools.partial(body, rows, grouped),
+        functools.partial(body, worst, ("ragged", None)), order, sizes,
+        *rest)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts(rows, x, weights, order, inverse, sizes, w1, w3, w2):
     """The held experts' part of the result for tokens *x* ``(N, d)``
     with pair weights *weights* ``(N, k)``: *order* lists the pairs
     (token * k + choice) sorted by held expert, those on no held expert
     last; *inverse* is where each pair landed; *sizes* the pairs of each
-    held expert."""
-    return _experts_fwd(x, weights, order, inverse, sizes, w1, w3, w2)[0]
+    held expert; *rows* the pair buffer's rows (`_buffer_rows`)."""
+    return _experts_fwd(rows, x, weights, order, inverse, sizes, w1, w3,
+                        w2)[0]
 
 
-def _experts_fwd(x, weights, order, inverse, sizes, w1, w3, w2):
+# The two bodies are jitted, so a step's routed layers of one shape share
+# a trace of each.  On the chip that left set-up where it was and the step
+# 3.7% faster: XLA places the same operations otherwise (PERF.md section
+# 6, PR 27)
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _forward_at(n, grouped, order, sizes, x, weights, inverse, w1, w3, w2):
     top_k = weights.shape[1]
     with jax.named_scope("mx.moe.dispatch"):
-        xs = jnp.take(x, order // top_k, axis=0)
+        xs = jnp.take(x, order[:n] // top_k, axis=0)
     with jax.named_scope("mx.moe.experts"):
-        a = _expert_hidden(xs, w1, w3, sizes)[2]
-        y = _product(a, w2, sizes)
+        a = _expert_hidden(grouped, xs, w1, w3, sizes)[2]
+        y = _product(grouped, a, w2, sizes)
     with jax.named_scope("mx.moe.combine"):
         place, exists = _places(inverse, sizes, top_k)
         out = _sum_pairs(y, place, exists, weights)
-    return out.astype(x.dtype), (x, weights, order, inverse, sizes, w1, w3,
-                                 w2)
+    return out.astype(x.dtype)
 
 
-def _experts_bwd(res, dout):
-    """The experts' hidden states are computed again and not kept: at
-    the worst-case row count they are the layer's largest arrays."""
-    x, weights, order, inverse, sizes, w1, w3, w2 = res
+def _experts_fwd(rows, x, weights, order, inverse, sizes, w1, w3, w2):
+    out = _at_buffer(rows, _forward_at, order, sizes, x, weights, inverse,
+                     w1, w3, w2)
+    return out, (x, weights, order, inverse, sizes, w1, w3, w2)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _backward_at(n, grouped, order, sizes, x, weights, inverse, w1, w3, w2,
+                 dout):
     top_k = weights.shape[1]
+    order = order[:n]
     w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
     with jax.named_scope("mx.moe.dispatch"):
         token = order // top_k
         xs = jnp.take(x, token, axis=0)
         dos = jnp.take(dout, token, axis=0)
     with jax.named_scope("mx.moe.experts"):
-        h, g, a = _expert_hidden(xs, w1, w3, sizes)
+        h, g, a = _expert_hidden(grouped, xs, w1, w3, sizes)
         # d out / d (a W2) without the pair's weight: the weight's own
         # gradient is its dot with a, the hidden state's is it weighted
-        gu = _product(dos, w2, sizes, True).astype(jnp.float32)
+        gu = _product(grouped, dos, w2, sizes, True).astype(jnp.float32)
         dw_sorted = jnp.sum(gu * a.astype(jnp.float32), -1, keepdims=True)
         dy = (dos.astype(jnp.float32) * w_sorted).astype(dos.dtype)
-        dw2 = _product_t(a, dy, sizes, w2.dtype)
+        dw2 = _product_t(grouped, a, dy, sizes, w2.dtype)
         da = gu * w_sorted
         h32, g32 = h.astype(jnp.float32), g.astype(jnp.float32)
         sig = jax.nn.sigmoid(h32)
         dh = (da * g32 * sig * (1 + h32 * (1 - sig))).astype(x.dtype)
         dg = (da * h32 * sig).astype(x.dtype)
-        dw1 = _product_t(xs, dh, sizes, w1.dtype)
-        dw3 = _product_t(xs, dg, sizes, w3.dtype)
-        dxs = (_product(dh, w1, sizes, True).astype(jnp.float32)
-               + _product(dg, w3, sizes, True).astype(jnp.float32)
+        dw1 = _product_t(grouped, xs, dh, sizes, w1.dtype)
+        dw3 = _product_t(grouped, xs, dg, sizes, w3.dtype)
+        dxs = (_product(grouped, dh, w1, sizes, True).astype(jnp.float32)
+               + _product(grouped, dg, w3, sizes, True).astype(jnp.float32)
                ).astype(x.dtype)
     with jax.named_scope("mx.moe.combine"):
         place, exists = _places(inverse, sizes, top_k)
         dx = _sum_pairs(dxs, place, exists).astype(x.dtype)
         dweights = jnp.where(exists, jnp.take(dw_sorted[:, 0], place), 0)
-    return (dx, dweights.astype(weights.dtype), None, None, None,
-            dw1, dw3, dw2)
+    return dx, dweights.astype(weights.dtype), dw1, dw3, dw2
+
+
+def _experts_bwd(rows, res, dout):
+    """The experts' hidden states are computed again and not kept: they
+    are the layer's largest arrays."""
+    x, weights, order, inverse, sizes, w1, w3, w2 = res
+    dx, dweights, dw1, dw3, dw2 = _at_buffer(
+        rows, _backward_at, order, sizes, x, weights, inverse, w1, w3, w2,
+        dout)
+    return dx, dweights, None, None, None, dw1, dw3, dw2
 
 
 _experts.defvjp(_experts_fwd, _experts_bwd)
@@ -354,7 +435,7 @@ def _sort_pairs(chosen, first_expert, held):
 
 
 def _record_moe_plan(tokens, router_experts, top_k, held, first_expert,
-                     dtype, hidden, expert_hidden):
+                     rows, dtype, hidden, expert_hidden):
     """One `mx.moe.plan` span each time the op is traced (as
     `mx.flash.plan`: the plan is a fact of the compiled program)."""
     with profiler.scope(  # graftlint: disable=JG003
@@ -363,11 +444,18 @@ def _record_moe_plan(tokens, router_experts, top_k, held, first_expert,
             "router_experts": router_experts, "experts_per_token": top_k,
             "experts_held": held, "first_expert": first_expert,
             "tokens": tokens, "pair_bound": tokens * top_k,
-            "bound": "worst case: every choice of every token held here",
+            "buffer_rows": rows,
+            "bound": "worst case: every choice of every token held here"
+            if rows == tokens * top_k else
+            "%g x the pairs of uniform routing (%d x %d x %d / %d), in row "
+            "tiles of %d; a step with more pairs runs at pair_bound, "
+            "through the ragged dot" % (
+                BUFFER_FACTOR, tokens, top_k, held, router_experts,
+                GROUPED_TILES[0]),
             "dtype": jnp.dtype(dtype).name, "path": GROUPED_PATH,
             "tiles": None if GROUPED_PATH != "megablox" else {
-                "up": _tiles(tokens * top_k, hidden, expert_hidden),
-                "down": _tiles(tokens * top_k, expert_hidden, hidden)}}
+                "up": _tiles(GROUPED_TILES, rows, hidden, expert_hidden),
+                "down": _tiles(GROUPED_TILES, rows, expert_hidden, hidden)}}
 
 
 @register_op("_contrib_RoutedExperts", aliases=("RoutedExperts",))
@@ -386,10 +474,12 @@ def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
     float32.  The result is the held experts' part, ``sum over e chosen
     and held of w_e * W2_e(silu(W1_e x) * W3_e x)``: what the absent
     experts would add is left out.  No capacity and no dropped token:
-    token-expert pairs are sorted by expert into a buffer of the worst
-    case (every choice of every token held here), the three products
-    run over the groups that exist, and each token gathers its pairs
-    back.  The counts of `routed_expert_counts` leave through
+    token-expert pairs are sorted by expert into a buffer of
+    `_buffer_rows` rows, the three products run over the groups that
+    exist, and each token gathers its pairs back; a step with more pairs
+    than rows takes the worst case's buffer (every choice of every token
+    held here) through the same code (`_at_buffer`).  The counts of
+    `routed_expert_counts` leave through
     `profiler.emit_step_stat` under ``moe_expert_counts``.
     """
     lead, d = data.shape[:-1], data.shape[-1]
@@ -397,7 +487,8 @@ def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
     held, router_experts = w1.shape[0], router_weight.shape[0]
     top_k, first = int(num_experts_per_tok), int(first_expert)
     bias = tuple(expert_bias) or (0.0,) * router_experts
-    _record_moe_plan(x.shape[0], router_experts, top_k, held, first,
+    rows = _buffer_rows(x.shape[0], top_k, held, router_experts)
+    _record_moe_plan(x.shape[0], router_experts, top_k, held, first, rows,
                      data.dtype, d, w1.shape[2])
     with jax.named_scope("mx.moe.route"):
         chosen, weights = _route(x, router_weight, bias, top_k,
@@ -407,9 +498,9 @@ def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
         # program that is being traced, which returns them every step
         profiler.emit_step_stat(  # graftlint: disable=JG003
             "moe_expert_counts",
-            routed_expert_counts(chosen, router_experts, first, held))
+            routed_expert_counts(chosen, router_experts, first, held, rows))
         order, inverse, sizes = _sort_pairs(chosen, first, held)
-    out = _experts(x, weights, order, inverse, sizes, w1, w3, w2)
+    out = _experts(rows, x, weights, order, inverse, sizes, w1, w3, w2)
     return out.reshape(*lead, d)
 
 

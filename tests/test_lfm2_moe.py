@@ -219,6 +219,118 @@ def test_nothing_is_dropped_when_every_token_goes_to_one_held_expert():
         assert float(jnp.abs(g[2:4]).max()) == 0.0
 
 
+# a shape with two buffers: 2 x 1024 pairs at worst, 1024 rows (two row
+# tiles of 512; uniform routing lands 512 pairs on 2 held experts of 8)
+TOKENS, HELD, ROWS = 1024, 2, 1024
+NAMES = ("x", "router", "expert_w1", "expert_w3", "expert_w2")
+
+
+@pytest.fixture
+def ragged(monkeypatch):
+    """XLA's ragged dot named outright (what this platform takes anyway):
+    the choice by platform and the kernels' own bodies hold `cond`s, and
+    the tests below count the pair buffer's."""
+    monkeypatch.setattr(lm_blocks, "GROUPED_PATH", "ragged")
+    return monkeypatch
+
+
+def out_and_gradients(layer, v):
+    """``layer(*arrays)`` and its gradient by every one of them, as one
+    program: ``(its jaxpr, its values)``."""
+    def both(*a):
+        out, vjp = jax.vjp(layer, *a)
+        return (out,) + vjp(jnp.cos(out))
+    args = [v[n] for n in NAMES]
+    return jax.make_jaxpr(both)(*args), jax.jit(both)(*args)
+
+
+def held_share(bias=()):
+    return lambda *a: routed_op(dict(zip(NAMES, a)), 0, HELD, bias)
+
+
+def test_the_bounded_buffer_gives_the_worst_case_s_numbers_bit_for_bit(
+        ragged):
+    """Where the pairs fit the bounded buffer, the output and the
+    gradients by the tokens and the router are the worst-case body's to
+    the bit: the same pairs in the same groups from row 0, so the same
+    sums in the same order.  The experts' weights' gradients too, but for
+    how XLA's ragged dot on the CPU blocks a sum over the buffer's rows,
+    which follows their number (w2's differs in float32's last places);
+    the kernels' row tiles do not (`tools/moe_sweep.py` reads a gap of
+    0.0 for the output and all three on the chip)."""
+    assert lm_blocks._buffer_rows(TOKENS, K, HELD, E) == ROWS < TOKENS * K
+    v = routed_inputs(tokens=TOKENS)
+    chosen, _ = lm_blocks._route(v["x"], v["router"], (0.0,) * E, K, True,
+                                 1.0)
+    counts = lm_blocks.routed_expert_counts(chosen, E, 0, HELD, ROWS)
+    assert 0 < int(counts[E]) <= ROWS
+    assert [int(n) for n in counts[E + 2:]] == [ROWS, 0]
+    jaxpr, bounded = out_and_gradients(held_share(), v)
+    assert str(jaxpr).count(" cond[") == 2           # forward and backward
+    ragged.setattr(lm_blocks, "_buffer_rows",
+                   lambda tokens, top_k, *_: tokens * top_k)
+    jaxpr, worst = out_and_gradients(held_share(), v)
+    assert " cond[" not in str(jaxpr)
+    for name, got, want in zip(("out",) + NAMES, bounded, worst):
+        assert got.shape == want.shape
+        got, want = np.asarray(got), np.asarray(want)
+        assert np.abs(want).max() > 0, name
+        if name.startswith("expert_w"):
+            np.testing.assert_allclose(
+                got, want, rtol=0, atol=4e-6 * np.abs(want).max(),
+                err_msg=name)
+        else:
+            np.testing.assert_array_equal(got, want, err_msg=name)
+
+
+def test_more_pairs_than_rows_take_the_worst_case_and_nothing_is_dropped(
+        ragged):
+    """A bias that sends every token's two choices to the two held
+    experts, at the shape with two buffers: twice the bounded buffer's
+    rows, so the worst-case branch runs (the bounded one would gather
+    from beyond its rows), and output and gradients are the float32
+    reference's: every token through both experts, by hand."""
+    v = routed_inputs(tokens=TOKENS)
+    bias = [50.0, 100.0] + [0.0] * (E - 2)
+    with profiler.collect_step_stats() as stats:      # eager: real counts
+        eager = held_share(bias)(*[v[n] for n in NAMES])
+    (row,) = stats["moe_expert_counts"]
+    assert [int(n) for n in row[E:]] == [TOKENS * K, 0, TOKENS * K, 1]
+    assert TOKENS * K > ROWS
+
+    def by_hand(x, router, w1, w3, w2):
+        s = jax.nn.sigmoid(x @ router.T)[:, :2]
+        w = s / (jnp.sum(s, -1, keepdims=True) + 1e-6)
+        return sum(w[:, e:e + 1] * reference.gated(x, w1[e], w3[e], w2[e],
+                                                   False) for e in (0, 1))
+
+    jaxpr, got = out_and_gradients(held_share(bias), v)
+    assert str(jaxpr).count(" cond[") == 2
+    with jax.default_matmul_precision("highest"):
+        _, want = out_and_gradients(by_hand, v)
+    np.testing.assert_allclose(np.asarray(eager), np.asarray(want[0]),
+                               rtol=1e-5, atol=1e-6)
+    for name, g, w in zip(("out",) + NAMES, got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-6, err_msg=name)
+    assert float(jnp.abs(got[0]).min(axis=1).max()) > 0  # every token served
+    # the experts that are not held get no gradient from this share
+    assert float(jnp.abs(got[3][HELD:]).max()) == 0.0
+
+
+def test_all_the_router_s_experts_held_leaves_one_path_and_no_cond(ragged):
+    """The choice between one buffer and two is made from shapes: with
+    every expert held here (or a shape under a row tile) the worst case is
+    the only buffer, and the program holds no `cond`."""
+    assert lm_blocks._buffer_rows(TOKENS, K, E, E) == TOKENS * K
+    assert lm_blocks._buffer_rows(48, K, 4, E) == 48 * K
+    v = routed_inputs(tokens=TOKENS)
+    jaxpr, _ = out_and_gradients(
+        lambda *a: routed_op(dict(zip(NAMES, a)), 0, E), v)
+    assert " cond[" not in str(jaxpr)
+    assert str(jaxpr).count("ragged_dot_general[") == 3 + 8
+
+
 def test_the_choice_is_on_score_plus_bias_and_the_weight_on_the_score():
     """With a bias that reverses the scores' order the experts chosen are
     the LOWEST scoring, and their weights are their own scores over the
@@ -272,16 +384,21 @@ def test_the_routed_layer_s_gradients_agree_with_the_reference(first):
     assert float(jnp.abs(got[2][held]).min(axis=(1, 2)).max()) > 0
 
 
-def test_the_kernel_path_lowers_for_the_tpu_without_a_chip():
+@pytest.mark.parametrize("tokens, rows", [(2048, 3072), (16384, 24576)])
+def test_the_kernel_path_lowers_for_the_tpu_without_a_chip(tokens, rows):
     """The public op lowered for the TPU platform from this CPU host at
-    the cell's widths: the grouped products are the Mosaic kernels there
-    (3 forward; backward 5, the hidden states again among them, and 3
-    transposed ones for the weights' gradients) and no ragged dot is
-    left."""
-    n, d, f, held = 2048, 2048, 1792, 8
+    the cell's widths (and, second, its tokens): at the bounded buffer,
+    whose rows divide into the row tile, the grouped products are the
+    Mosaic kernels (3 forward; backward 5, the hidden states again among
+    them, and 3 transposed ones for the weights' gradients); the
+    worst-case branch holds as many ragged dots and no kernel."""
+    d, f, held = 2048, 1792, 8
     avals = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
-        (n, d), (32, d), (held, d, f), (held, d, f), (held, f, d))]
+        (tokens, d), (32, d), (held, d, f), (held, d, f), (held, f, d))]
     fn = get_op("_contrib_RoutedExperts").fn
+    assert lm_blocks._buffer_rows(tokens, 4, held, 32) == rows < tokens * 4
+    assert lm_blocks._tiles(lm_blocks.GROUPED_TILES, rows, d, f) \
+        == (512, 1024, 896) and rows % 512 == 0
 
     def fwd(*a):
         return fn(*a, num_experts_per_tok=4)
@@ -292,12 +409,13 @@ def test_the_kernel_path_lowers_for_the_tpu_without_a_chip():
     def calls(f):
         text = jax.jit(f).trace(*avals).lower(
             lowering_platforms=("tpu",)).as_text()
-        assert "ragged_dot" not in text and "tpu_custom_call" in text
-        return text.count("call @gmm"), text.count("call @tgmm")
+        assert "tpu_custom_call" in text
+        return (text.count("call @gmm"), text.count("call @tgmm"),
+                text.count('"chlo.ragged_dot"('))
 
-    assert calls(fwd) == (3, 0)
+    assert calls(fwd) == (3, 0, 3)
     # the loss needs no forward product: its gradient is all that runs
-    assert calls(jax.grad(loss, argnums=(0, 2, 3, 4))) == (5, 3)
+    assert calls(jax.grad(loss, argnums=(0, 2, 3, 4))) == (5, 3, 8)
 
 
 # -- the other operators ------------------------------------------------------
@@ -396,7 +514,8 @@ def test_the_counters_equal_the_reference_s_counts_and_the_plan_is_recorded():
     names = ("moe_stat_steps_total", "moe_stat_layers_total",
              "moe_assignments_total", "moe_local_assignments_total",
              "moe_tokens_without_local_expert_total",
-             "moe_expert_load_max_over_mean_sum")
+             "moe_expert_load_max_over_mean_sum", "moe_buffer_rows_total",
+             "moe_worst_case_buffer_layers_total")
     before = {n: profiler.counter_value(n) for n in names}
     since = max([s.id for s in profiler.spans()] or [0])
     dispatches = profiler.counter_value("parallel_step_dispatches")
@@ -417,11 +536,16 @@ def test_the_counters_equal_the_reference_s_counts_and_the_plan_is_recorded():
     assert got["moe_expert_load_max_over_mean_sum"] == pytest.approx(
         (load.max(1) / load.mean(1)).sum())
     assert 0 <= got["moe_tokens_without_local_expert_total"] <= 2 * tokens
+    assert got["moe_buffer_rows_total"] == 2 * tokens * top_k
+    assert got["moe_worst_case_buffer_layers_total"] == 0
     plans = [s for s in profiler.spans()
              if s.name == "mx.moe.plan" and s.id > since]
     assert plans and plans[0].args["experts_held"] == 4
     assert plans[0].args["router_experts"] == 8
     assert plans[0].args["pair_bound"] == tokens * top_k
+    # ... which is the only buffer at this size: under one row tile
+    assert plans[0].args["buffer_rows"] == tokens * top_k
+    assert plans[0].args["bound"].startswith("worst case")
     assert plans[0].args["path"] == lm_blocks.GROUPED_PATH
 
 
@@ -443,7 +567,7 @@ def test_counts_fold_only_once_they_are_ready_and_in_order():
 
 
 def test_a_value_emitted_outside_a_collection_is_dropped():
-    profiler.emit_step_stat("moe_expert_counts", np.zeros((1, 10)))
+    profiler.emit_step_stat("moe_expert_counts", np.zeros((1, 12)))
     with profiler.collect_step_stats() as stats:
         profiler.emit_step_stat("a", 1)
         with profiler.collect_step_stats() as inner:
