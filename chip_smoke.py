@@ -146,7 +146,7 @@ def _train(net, loss, optimizer, optimizer_params, x, y, steps, devices,
 
 def phase_train(model="resnet50_v1", classes=1000, per_chip_batch=128,
                 image=224, steps=4, devices=None):
-    """The north-star path: what ``bench.py`` and
+    """The north-star path: what the ``resnet50_train`` cell and
     ``examples/train_imagenet.py --trainer parallel`` build."""
     import jax
     import mxnet_tpu as mx
@@ -259,8 +259,8 @@ def phase_serve(model="resnet50_v1", classes=1000, image=224,
 def phase_lm(vocab=32000, dim=1024, heads=16, layers=12, seq=2048,
              per_chip_batch=8, steps=3, devices=None, kernels_per_layer=3,
              flash_shape=(2, 4, 2048, 64)):
-    """The kernels that must compile: the ``tools/benchmark_lm.py``
-    model inside a real step.  On a TPU the compiled step holds three
+    """The kernels that must compile: the zoo's transformer LM
+    inside a real step.  On a TPU the compiled step holds three
     Mosaic calls per layer (flash forward, dk/dv, dq)."""
     import jax
     import mxnet_tpu as mx

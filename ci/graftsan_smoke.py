@@ -1,6 +1,6 @@
 """Sanitizer-enabled CI smoke train step (ci/run_tests.sh stage).
 
-Runs a short real training loop — fused train step + PrefetchingIter
+Runs a short real training loop — fused train step + DevicePrefetcher
 data path + a local kvstore multi-device trainer — with ALL FOUR
 graftsan components on (the stage exports MXNET_SAN=all), then fails
 on:
@@ -38,7 +38,7 @@ if REPO not in sys.path:
 import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import nd, sym  # noqa: E402
 from mxnet_tpu import profiler  # noqa: E402
-from mxnet_tpu.io import NDArrayIter, PrefetchingIter  # noqa: E402
+from mxnet_tpu.io import DevicePrefetcher, NDArrayIter  # noqa: E402
 import tools.graftsan as graftsan  # noqa: E402
 
 STEPS = 12
@@ -65,10 +65,12 @@ def main():
     y = rng.randint(0, 4, 64).astype(np.float32)
     failures = []
 
-    # threaded data path: PrefetchingIter's producer thread runs under
-    # the instrumented queue/event/thread wrappers
-    it = PrefetchingIter(NDArrayIter(x, y, batch_size=16,
-                                     last_batch_handle="discard"))
+    # threaded data path: DevicePrefetcher (a PrefetchingIter whose
+    # producer thread also does the device_put into a depth-2 ring)
+    # runs under the instrumented queue/event/thread wrappers
+    it = DevicePrefetcher(NDArrayIter(x, y, batch_size=16,
+                                      last_batch_handle="discard"),
+                          depth=2)
 
     # -- phase 1: full-fused path (single device, no kvstore) ---------
     mod = build_module()
@@ -156,6 +158,7 @@ def main():
             failures.append("donation poison hit a LIVE rebound handle")
     finally:
         _registry.supports_donation = real_supports
+        it.close()      # joins the producer: teardown is audited too
     deliberate = [r for r in graftsan.reports()[len(reports):]]
     if [r for r in deliberate if r.component != "donation"]:
         failures.extend(graftsan.format_report(r) for r in deliberate

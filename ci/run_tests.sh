@@ -31,9 +31,10 @@ MXNET_SAN=all python ci/graftir_smoke.py
 python -m tools.graftir --check
 
 echo "== graftsan: sanitizer-enabled smoke train step =="
-# Fused + partial-fused train steps, PrefetchingIter, local kvstore
-# with ALL FOUR runtime sanitizers on (race/lockset + lock-order,
-# recompile-blame, use-after-donate poison, host-transfer guard).
+# Fused + partial-fused train steps, the DevicePrefetcher ring and its
+# producer thread, local kvstore, with ALL FOUR runtime sanitizers on
+# (race/lockset + lock-order, recompile-blame, use-after-donate poison,
+# host-transfer guard).
 # Fails on any sanitizer report or a broken one-program-per-step
 # contract.  Seconds, CPU-only (docs/sanitizers.md).
 MXNET_SAN=all python ci/graftsan_smoke.py
@@ -55,16 +56,6 @@ echo "== observability: telemetry smoke train step =="
 # the registry counters next to its spans.  Seconds, CPU-only; last
 # stdout line is the scrapeable summary ("obs: instruments=.. ...").
 MXNET_OBS=all python ci/obs_smoke.py
-
-echo "== perf: input-pipeline overlap smoke (device prefetch + async guard) =="
-# Host-bound iterator (X ms decode) + real fused steps (Y ms): the
-# DevicePrefetcher ring + MXNET_GUARD_READBACK_LAG async guard
-# accounting must reach a steady state of ~max(X,Y) per step vs the
-# serial path's X+Y (asserted < 0.7x serial), with zero graftsan
-# reports from the ring's threads/locks and the input-wait/stall
-# instruments live.  Seconds, CPU-only (docs/perf_input_pipeline.md).
-# Last stdout line is the scrapeable summary ("inputperf: ... ok").
-MXNET_SAN=all python ci/input_overlap_smoke.py
 
 echo "== serve: compiled-inference smoke (registry + dynamic batcher) =="
 # Two-model registry under concurrent mixed-size traffic through the

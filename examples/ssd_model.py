@@ -5,8 +5,8 @@ built symbolically on this framework's op set.
 Six feature scales (38/19/10/5/3/1 for 300 input), per-scale class +
 offset heads, `MultiBoxPrior` anchors (8732 total at the reference's
 sizes/ratios), and `MultiBoxDetection` (decode + NMS) for inference.
-`tools/benchmark_ssd.py` times it; `build_ssd300_train` attaches the
-MultiBoxTarget + SoftmaxOutput/smooth-L1 training heads the same way
+`build_ssd300_train` attaches the MultiBoxTarget +
+SoftmaxOutput/smooth-L1 training heads the same way
 example/ssd/symbol/symbol_builder.py:training does.
 """
 

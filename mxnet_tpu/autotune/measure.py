@@ -51,8 +51,8 @@ PRIOR_DISPATCH_OVERHEAD_S = 25e-5
 
 
 def percentile(sorted_vals, q):
-    """Nearest-rank percentile of an ascending list (same discipline
-    as bench.py — SLOs quote real request latencies)."""
+    """Nearest-rank percentile of an ascending list (SLOs quote real
+    request latencies, never an interpolated one)."""
     if not sorted_vals:
         return None
     idx = min(len(sorted_vals) - 1,
@@ -61,9 +61,9 @@ def percentile(sorted_vals, q):
 
 
 def fc_model(dim, hidden=64, classes=16, seed=0):
-    """The bench-family 2-layer FC inference model: returns
-    ``(symbol, arg_params, data_shapes)`` for the measurers and the
-    CI smoke (the same shape family bench.py --serve drives)."""
+    """A 2-layer FC inference model: returns ``(symbol, arg_params,
+    data_shapes)`` for the measurers and the CI smoke
+    (ci/autotune_smoke.py)."""
     from .. import nd, sym
     data = sym.var("data")
     net = sym.FullyConnected(data, num_hidden=hidden, name="atfc1")

@@ -182,9 +182,9 @@ class Trace(object):
 
 def synth_serve_trace(rate=150.0, seconds=2.0, dim=64, rows_lo=1,
                       rows_hi=4, seed=0):
-    """A synthetic serve schedule matching bench.py's open loop: a
-    fixed arrival grid at *rate* with mixed request sizes drawn
-    uniformly in ``[rows_lo, rows_hi]``."""
+    """A synthetic open-loop serve schedule: a fixed arrival grid at
+    *rate* with mixed request sizes drawn uniformly in
+    ``[rows_lo, rows_hi]``."""
     rs = _np.random.RandomState(seed)
     n = max(1, int(rate * seconds))
     period = 1.0 / float(rate)
@@ -198,9 +198,8 @@ def synth_serve_trace(rate=150.0, seconds=2.0, dim=64, rows_lo=1,
 
 def synth_decode_trace(rate=12.0, seconds=3.0, vocab=48, prompt_lo=4,
                        prompt_hi=24, new_tokens=24, seed=5):
-    """A synthetic decode-session schedule matching bench.py's
-    ``--serve-decode`` open loop: sessions arrive on a fixed grid,
-    each with a uniformly drawn prompt length."""
+    """A synthetic open-loop decode-session schedule: sessions arrive
+    on a fixed grid, each with a uniformly drawn prompt length."""
     rs = _np.random.RandomState(seed)
     n = max(1, int(rate * seconds))
     period = 1.0 / float(rate)

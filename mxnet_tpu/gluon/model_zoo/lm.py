@@ -1,7 +1,7 @@
 """LSTM language model — the reference's own LM headline shape
 (example/rnn PTB models: Embedding + fused-RNN LSTM stack + head; the
-fused op is `lax.scan` here, ops/rnn.py).  Shared by
-tools/benchmark_lm.py --arch lstm and the trainer tests."""
+fused op is `lax.scan` here, ops/rnn.py).  Driven by the trainer
+tests (tests/test_parallel_modes.py)."""
 
 from __future__ import annotations
 

@@ -19,9 +19,9 @@ Three parts (docs/observability.md):
 
 * :mod:`.costs` — per-op HLO cost attribution: an analytic
   flops/bytes model over a lowered program plus roofline
-  classification against probed peaks, turning a single MFU number
-  into a per-op optimization queue (``bench.py --decompose``,
-  ``tools/mfu_sweep.py --decompose``).
+  classification against the peaks it is given, turning a single MFU
+  number into a per-op optimization queue (``tools/graftir`` prices
+  programs with it).
 
 Import discipline: this package depends only on the stdlib,
 ``..sanitizer`` (lock factories, so graftsan can audit instrument

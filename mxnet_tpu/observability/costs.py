@@ -17,8 +17,8 @@ group against the machine balance point::
 The estimated time share of a group is the roofline time
 ``max(flops/peak_flops, bytes/peak_bw)`` normalized over the program —
 the number that makes an MFU regression attributable to a named op
-(ROADMAP item 3; bench.py --decompose persists it into the BENCH
-json schema).
+(the table is plain JSON; ``tools/graftir`` keeps its totals per
+program in its manifest).
 
 Totals are cross-checked against ``compiled.cost_analysis()`` when
 available: the analytic model counts the UNOPTIMIZED program (before

@@ -16,8 +16,9 @@ the mp_sgd ops' scheme — reference optimizer_op.cc mp_sgd), and
 LARS/LBSGD layer-wise adaptive rates (reference optimizer.py:678) — the
 ResNet-50 north-star configuration.
 
-This is what `bench.py` and `__graft_entry__.dryrun_multichip` run, and
-what Gluon's Trainer uses when constructed with ``kvstore='tpu'``.
+This is what the benchmark's training cells (`benchmarks/run.py`) and
+`__graft_entry__.dryrun_multichip` run, and what Gluon's Trainer uses
+when constructed with ``kvstore='tpu'``.
 """
 
 from __future__ import annotations

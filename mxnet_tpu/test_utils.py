@@ -316,8 +316,8 @@ def check_consistency(sym, location=None, shapes=None, aux_states=None,
 
 def tiny_attention_lm(vocab=32, dim=16, seed=0, dtype="float32"):
     """A single-head attention language model sized for CPU CI — the
-    shared fixture behind the paged-decode tests, ``bench.py
-    --serve-decode`` and ``ci/decode_smoke.py``.
+    shared fixture behind the paged-decode tests and
+    ``ci/decode_smoke.py``.
 
     Returns ``(params, step_fn, prefill_fn, token_spec, input_spec)``
     matching the :class:`mxnet_tpu.serve.DecodeEngine` contract:

@@ -264,27 +264,43 @@ def test_fused_states_serialize_in_legacy_format(tmp_path):
                                        **TOL["float32"])
 
 
+def _third_step_counters(fused):
+    """Dispatch counters of one step after two warm-up steps, and the
+    number of parameters the step updates."""
+    rng = np.random.RandomState(4)
+    init = _mlp_init(rng)
+    batches = _toy_batches(rng)
+    mod = _run_module(fused, _mlp(), init, batches, "sgd",
+                      {"learning_rate": 0.1, "momentum": 0.9}, n_steps=2)
+    os.environ["MXNET_MODULE_FUSED_STEP"] = "1" if fused else "0"
+    try:
+        prof.reset_counters()
+        mod.forward_backward_update(batches[0])
+        return prof.counters(), len(init)
+    finally:
+        os.environ.pop("MXNET_MODULE_FUSED_STEP", None)
+        prof.reset_counters()
+
+
 def test_fused_step_single_dispatch_after_warmup():
     """The tentpole property: after warmup one training step is exactly
     ONE jitted computation — no eager per-parameter dispatches, no
     executor-level dispatch, no recompile."""
-    rng = np.random.RandomState(4)
-    init = _mlp_init(rng)
-    batches = _toy_batches(rng)
-    mod = _run_module(True, _mlp(), init, batches, "sgd",
-                      {"learning_rate": 0.1, "momentum": 0.9}, n_steps=2)
-    os.environ["MXNET_MODULE_FUSED_STEP"] = "1"
-    try:
-        prof.reset_counters()
-        mod.forward_backward_update(batches[0])
-        c = prof.counters()
-    finally:
-        os.environ.pop("MXNET_MODULE_FUSED_STEP", None)
-        prof.reset_counters()
+    c, _ = _third_step_counters(fused=True)
     assert c.get("fused_step_dispatches") == 1, c
     assert c.get("fused_step_compiles", 0) == 0, c
     assert c.get("eager_dispatches", 0) == 0, c
     assert c.get("executor_dispatches", 0) == 0, c
+
+
+def test_legacy_step_dispatches_once_per_parameter():
+    """The other side of the same count: the legacy loop is one
+    executor dispatch plus one eager update per parameter, and no
+    fused program."""
+    c, n_params = _third_step_counters(fused=False)
+    assert c.get("fused_step_dispatches", 0) == 0, c
+    assert c.get("executor_dispatches") == 1, c
+    assert c.get("eager_dispatches") == n_params, c
 
 
 def test_fused_disabled_by_env_falls_back():

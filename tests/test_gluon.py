@@ -409,8 +409,8 @@ def test_resnet_s2d_stem_trains():
 
 def test_model_zoo_transformer_lm():
     """TransformerLM (zoo long-context family): eager == hybridized,
-    (B,S)->(B,S,V), and a ParallelTrainer step runs (the benchmark_lm
-    path)."""
+    (B,S)->(B,S,V), and a ParallelTrainer step runs (the path of the
+    benchmark's LM cells)."""
     from mxnet_tpu.gluon.model_zoo.transformer import get_transformer_lm
     from mxnet_tpu.parallel import make_mesh
     from mxnet_tpu.parallel.data_parallel import ParallelTrainer

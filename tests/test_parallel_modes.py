@@ -266,7 +266,7 @@ def test_parallel_trainer_rnn_frozen_begin_states():
     """Graph args with no backing Parameter (the fused RNN op's
     auto-created begin-state vars) are zero-filled frozen inputs under
     ParallelTrainer — simple_bind's unbound-arg semantics at the
-    compiled-step layer (tools/benchmark_lm.py --arch lstm path)."""
+    compiled-step layer (the zoo's LSTM LM, ``get_lstm_lm``)."""
     import mxnet_tpu as mx
     from mxnet_tpu import gluon
     from mxnet_tpu.gluon.model_zoo.lm import get_lstm_lm

@@ -234,6 +234,35 @@ def test_registry_load_quantized_gate_health_and_unload(net):
     assert reg.health().get("qm") is None
 
 
+@pytest.mark.parametrize("rung", [1, 2, 4])
+def test_int8_rung_moves_fewer_compute_bytes_than_f32_rung(net, rung):
+    """Counted from the lowered text of each rung: the operands and
+    results of the quantized program's dot/convolution ops are fewer
+    bytes than the float32 program's at the same rung."""
+    from mxnet_tpu.observability import costs
+    sym, params, batches, _ = net
+    reg = ModelRegistry()
+    try:
+        compute_bytes = {}
+        for name, quantized in (("f32", {}),
+                                ("int8", {"quantize": "int8",
+                                          "calib_batches": batches})):
+            pred = reg.load(name, sym, params,
+                            data_shapes={"data": (4, 3, 12, 12)},
+                            ladder=BucketLadder(batches=(rung,)),
+                            **quantized)
+            text = pred.lowered_text(pred.rung_shapes(rung))
+            compute_bytes[name] = sum(
+                r["bytes"] for r in costs.parse_hlo_ops(text)
+                if r["op"] in ("dot_general", "dot", "convolution"))
+            if name == "int8":
+                assert hlo_has_int8_compute(text)
+    finally:
+        reg.close()
+    assert 0 < compute_bytes["int8"] < compute_bytes["f32"], \
+        compute_bytes
+
+
 def test_registry_gate_failure_is_typed_and_installs_nothing(net):
     sym, params, batches, _ = net
     reg = ModelRegistry()

@@ -9,13 +9,12 @@ ranking decision a REAL replay of an arrival trace through the real
 serving machinery, with the ``observability.costs`` analytic prior
 pruning dominated candidates before they cost a measurement.
 
-    # record a trace from live-shaped load, then tune against it
-    python bench.py --serve --record-trace /tmp/peak.trace.json
-    python tools/autotune.py --workload serve --model bench \\
+    # tune against a recorded arrival schedule (Trace.save writes one)
+    python tools/autotune.py --workload serve --model resnet \\
         --trace /tmp/peak.trace.json --store /tmp/tuning.json
 
-    # serving processes pick the winner up at load time
-    MXNET_TUNING_STORE=/tmp/tuning.json python bench.py --serve
+    # a serving process started with MXNET_TUNING_STORE=/tmp/tuning.json
+    # picks the winner up when it loads the model of that name
 
 No trace file = a synthetic open-loop trace (--rate/--seconds), good
 for smoke runs; real tuning should replay recorded load.  The winner
@@ -45,8 +44,8 @@ def build_parser():
                    help="store key: the registry/engine name that "
                         "should pick the tuning up at load time")
     p.add_argument("--trace", default=None,
-                   help="recorded trace JSON (bench.py "
-                        "--record-trace); default: synthesize one")
+                   help="recorded trace JSON (autotune.Trace.save); "
+                        "default: synthesize one")
     p.add_argument("--store", default=None,
                    help="TuningStore JSON to create/update with the "
                         "winning entry (default: print only)")

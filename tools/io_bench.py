@@ -9,7 +9,7 @@ decode team for exactly this reason.
 Usage:  python tools/io_bench.py [--images 2048] [--threads 1,4,8,16]
 
 Writes one JSON line per config and a summary to stdout; run it on the
-bench host and paste the table into docs/PERF_NOTES.md.
+chip machine's host and paste the table into docs/PERF_NOTES.md.
 """
 
 from __future__ import annotations
