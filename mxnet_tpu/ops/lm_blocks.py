@@ -9,6 +9,18 @@ graph node, so its device time lies under ``<op>:<node>`` in the step's
 scope map; the routed layer's phases and the convolution carry scopes of
 their own (docs/observability.md "Spans and device scopes").
 
+Which path runs where.  The routed layer's grouped products are the
+installed JAX's megablox kernels where the program is lowered for the TPU
+and XLA's ragged dot on every other platform (`_product`).  The gated short
+convolution's middle (`_gate`: the gates and the depthwise causal taps
+between its two projections) is a pair of Mosaic kernels of this module,
+``mx_shortconv_fwd`` and ``mx_shortconv_bwd``, where the program is lowered
+for the TPU and the shape tiles (`_shortconv_plan`: one device, ``d`` a
+multiple of 128, the sequence in whole tiles), and `_gate_body`, the
+same arithmetic in `jax.numpy`, on every other platform and at every other
+shape.  Both are decided by what the code sees in its input and by the
+platform it is lowered for: no argument, environment variable or switch.
+
 Grouped-query attention has no operator: the key/value heads are
 repeated to the query heads (``repeat``) in front of
 ``_contrib_DotProductAttention``, after ``_contrib_RMSNorm`` over each
@@ -24,6 +36,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import profiler
 from ._precision import matmul_precision
@@ -78,26 +92,316 @@ def _gated_mlp(data, w1, w3, w2):
     return _dot(_silu_mul(_dot(data, w1), _dot(data, w3)), w2)
 
 
+# ---------------------------------------------------------------------------
+# The gated short convolution.  Its middle, everything between the input
+# projection and the output projection, is one function `_gate`: on the TPU
+# a pair of Mosaic kernels that pass over ``bcx`` once each way; everywhere
+# else, and at shapes the kernels do not tile, `_gate_body`.
+# ---------------------------------------------------------------------------
+
+#: rows of the sequence a grid step of each kernel holds, and the most
+#: channels it works on at a time inside the step (a block is whole rows of
+#: ``bcx``, so that ``db``, ``dc`` and ``dx`` leave as one array).  From
+#: `tools/shortconv_sweep.py` on the v5e at (2, 8192, 3 x 2048) bf16 with 3
+#: taps, device ms a call (PERF.md section 6, PR 29): forward 0.398 at 256
+#: rows (82% of 819 GB/s), 0.412 at 128, 0.443 at 64, 512 over the VMEM;
+#: backward 0.753 at 128 rows (76%), 0.802 at 64, 0.910 at 32, 256 over the
+#: VMEM; chunks of 256 to 2048 channels within 2% of each other.  Not
+#: options: the sweep sets them to compare
+SHORTCONV_TILES = {"fwd": 256, "bwd": 128, "channels": 512}
+
+#: what a kernel's blocks, each held twice, may take of the 16 MiB of VMEM
+#: that a Mosaic kernel is given on the v5e; the rest is for one channel
+#: chunk's float32 temporaries
+_SHORTCONV_VMEM = 10 << 20
+
+
+def _gate_body(bcx, conv_weight):
+    """``C * conv(B * X)`` in `jax.numpy`, the middle's definition: ``u``
+    and the taps in float32 (a product of two bf16 numbers is exact there),
+    the result rounded to the input's dtype once."""
+    d, taps = conv_weight.shape
+    b, c, x = bcx[..., :d], bcx[..., d:2 * d], bcx[..., 2 * d:]
+    u = b.astype(jnp.float32) * x.astype(jnp.float32)
+    w = conv_weight.astype(jnp.float32)
+    seq = bcx.shape[-2]
+    padded = jnp.pad(u, [(0, 0)] * (u.ndim - 2) + [(taps - 1, 0), (0, 0)])
+    conv = sum(padded[..., j:j + seq, :] * w[:, j] for j in range(taps))
+    return (c.astype(jnp.float32) * conv).astype(bcx.dtype)
+
+
+def _halo_rows(dtype):
+    """Rows of the block that brings a tile its neighbours: one sublane
+    tile of *dtype* (8 rows of 4 bytes, 16 of 2)."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _shortconv_blocks(kernel, rows, d, dtype):
+    """Bytes of one grid step's blocks of *kernel*, each held twice: the
+    tile's columns of ``d`` (``bcx`` and ``gated``; in the backward kernel
+    ``dgated`` and ``dbcx`` too), its halo blocks, and the taps' 8 float32
+    rows (with their gradient's in the backward kernel)."""
+    wide, halos, taps = {"fwd": (4, 2, 1), "bwd": (7, 4, 2)}[kernel]
+    return 2 * (d * jnp.dtype(dtype).itemsize
+                * (wide * rows + halos * _halo_rows(dtype)) + taps * 8 * d * 4)
+
+
+def _shortconv_plan(bcx, conv_weight):
+    """The tiles `_gate` runs this input at, or None where it runs
+    `_gate_body` and lets JAX differentiate it.  The kernels take ``(batch,
+    seq, 3d)`` on one device: ``d`` a multiple of 128 (the channel chunk is
+    cut to divide it), the sequence in whole tiles of both kernels, the
+    taps within a halo block and the 8 rows their gradient is summed in,
+    blocks within `_SHORTCONV_VMEM`."""
+    from ..parallel.mesh import current_mesh
+    tiles, (d, taps) = SHORTCONV_TILES, conv_weight.shape
+    if bcx.ndim != 3 or jnp.dtype(bcx.dtype).itemsize not in (2, 4) \
+            or d % 128 or not 2 <= taps <= 8 \
+            or any(bcx.shape[1] % tiles[k] for k in ("fwd", "bwd")):
+        return None
+    if any(_shortconv_blocks(k, tiles[k], d, bcx.dtype) > _SHORTCONV_VMEM
+           for k in ("fwd", "bwd")):
+        return None
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1 and set(
+            jax.sharding.get_abstract_mesh().manual_axes) != set(
+                mesh.axis_names):
+        # XLA does not partition a Mosaic kernel, and no cell spans chips
+        return None
+    return dict(tiles, channels=_fit(d, tiles["channels"]))
+
+
+def _record_shortconv_plan(bcx, conv_weight, plan):
+    """One `mx.shortconv.plan` span each time the op is traced (as
+    `mx.flash.plan`: the plan is a fact of the compiled program)."""
+    kept = bcx.size * bcx.dtype.itemsize \
+        + conv_weight.size * conv_weight.dtype.itemsize
+    with profiler.scope(  # graftlint: disable=JG003
+            "mx.shortconv.plan", "shortconv") as span:
+        span.args = {
+            "shape": list(bcx.shape), "dtype": jnp.dtype(bcx.dtype).name,
+            "taps": conv_weight.shape[1],
+            "path": "xla" if plan is None else "kernel",
+            "seq_tile": plan and {k: plan[k] for k in ("fwd", "bwd")},
+            "channel_tile": plan and plan["channels"],
+            "halo_rows": plan and _halo_rows(bcx.dtype),
+            # what `_gate` keeps for the backward pass: bcx and the taps;
+            # on the other path JAX keeps what its derivative of the body
+            # asks for
+            "residual_bytes": plan and kept}
+
+
+def _taps_t(conv_weight):
+    """The taps as the kernels read them: ``(8, d)`` float32, row j the
+    tap of ``u_(t-L+1+j)``."""
+    d, taps = conv_weight.shape
+    return jnp.zeros((8, d), jnp.float32).at[:taps].set(
+        conv_weight.astype(jnp.float32).T)
+
+
+def _f32(ref, cols):
+    return ref[0, :, cols].astype(jnp.float32)
+
+
+def _chunk(bcx_ref, b_before, x_before, w_ref, lo, d, taps, channels):
+    """Channels *lo* .. *lo* + *channels* of a tile, in float32: ``b``,
+    ``c``, ``x``; the taps ``w[j]`` as rows; ``u[j]`` = ``u_(t-L+1+j)``, the
+    tile's ``u = b * x`` as tap j sees it; and ``conv``, summed in
+    `_gate_body`'s order.  ``u`` is zero before the sequence: the rows
+    before the first tile of every batch row are zeroed, so packed rows
+    never see each other.  A shifted ``u`` is a sublane roll of the halo
+    rows and the tile's together and an aligned slice of it: the rows that
+    wrap land in the halo's part and are cut off."""
+    at = slice(lo, lo + channels)
+    b, c, x = (_f32(bcx_ref, slice(k * d + lo, k * d + lo + channels))
+               for k in range(3))
+    before = jnp.where(pl.program_id(1) > 0,
+                       _f32(b_before, at) * _f32(x_before, at), 0.0)
+    halo, own = before.shape[0], b * x
+    ext = jnp.concatenate([before, own], 0)
+    u = [pltpu.roll(ext, back, 0)[halo:] for back in range(taps - 1, 0, -1)]
+    u.append(own)
+    w = [w_ref[j:j + 1, at] for j in range(taps)]
+    return b, c, x, w, u, sum(u[j] * w[j] for j in range(taps))
+
+
+def _shortconv_fwd_kernel(bcx_ref, b_before, x_before, w_ref, out_ref, *,
+                          d, taps, channels):
+    """One ``(rows, 3d)`` tile of ``bcx`` to ``(rows, d)`` of ``gated``, a
+    channel chunk at a time."""
+    for lo in range(0, d, channels):
+        _, c, _, _, _, conv = _chunk(bcx_ref, b_before, x_before, w_ref, lo,
+                                     d, taps, channels)
+        out_ref[0, :, lo:lo + channels] = (c * conv).astype(out_ref.dtype)
+
+
+def _shortconv_bwd_kernel(bcx_ref, b_before, x_before, c_after, dg_ref,
+                          dg_after, w_ref, dbcx_ref, dw_ref, *, d, taps,
+                          channels):
+    """The tile's ``db``, ``dc``, ``dx`` into the three column blocks of
+    ``dbcx``, and its part of the taps' gradient added to *dw_ref*, which
+    stays in VMEM over the whole grid.  ``u`` and ``conv`` are computed
+    again in float32.  ``du_t = sum_j w_j * dconv_(t+L-1-j)`` looks ahead:
+    the rows after the tile come with it, zero past the sequence's end."""
+    tile, rows = pl.program_id(1), dg_ref.shape[1]
+
+    @pl.when((pl.program_id(0) == 0) & (tile == 0))
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    for lo in range(0, d, channels):
+        at = slice(lo, lo + channels)
+        b, c, x, w, u, conv = _chunk(bcx_ref, b_before, x_before, w_ref, lo,
+                                     d, taps, channels)
+        dg = _f32(dg_ref, at)
+        dconv = dg * c
+        for j in range(taps):
+            dw_ref[j:j + 1, at] += jnp.sum(dconv * u[j], 0, keepdims=True)
+        after = jnp.where(tile < pl.num_programs(1) - 1,
+                          _f32(dg_after, at) * _f32(c_after, at), 0.0)
+        ext = jnp.concatenate([dconv, after], 0)
+        du = dconv * w[taps - 1] + sum(
+            pltpu.roll(ext, ext.shape[0] - ahead, 0)[:rows]
+            * w[taps - 1 - ahead] for ahead in range(1, taps))
+        for k, grad in enumerate((du * x, dg * conv, du * b)):
+            dbcx_ref[0, :, slice(k * d + lo, k * d + lo + channels)] = \
+                grad.astype(dbcx_ref.dtype)
+
+
+def _shortconv_specs(bcx, rows):
+    """The index maps both kernels share: a tile, and the halo blocks just
+    before and just after it in column block *col* of ``bcx``'s three (the
+    sequence's ends clamp to a block that is there; the kernels zero it)."""
+    halo = _halo_rows(bcx.dtype)
+    per, last = rows // halo, bcx.shape[1] // halo - 1
+
+    def before(col):
+        return lambda i, s: (i, jnp.maximum(s * per - 1, 0), col)
+
+    def after(col):
+        return lambda i, s: (i, jnp.minimum((s + 1) * per, last), col)
+
+    return halo, (lambda i, s: (i, s, 0)), before, after
+
+
+_SHORTCONV_STATIC = ("rows", "channels", "interpret")
+
+
+# jitted, so a step's four layers of one shape share one trace and one
+# Mosaic program of each kernel (as the flash wrappers since PR 25)
+
+@functools.partial(jax.jit, static_argnames=_SHORTCONV_STATIC)
+def _shortconv_fwd_pallas(bcx, conv_weight, rows, channels, interpret=False):
+    (batch, seq, _), (d, taps) = bcx.shape, conv_weight.shape
+    halo, tile, before, _ = _shortconv_specs(bcx, rows)
+    with jax.named_scope("mx.shortconv.gate"):
+        return pl.pallas_call(
+            functools.partial(_shortconv_fwd_kernel, d=d, taps=taps,
+                              channels=channels),
+            grid=(batch, seq // rows),
+            in_specs=[pl.BlockSpec((1, rows, 3 * d), tile),
+                      pl.BlockSpec((1, halo, d), before(0)),
+                      pl.BlockSpec((1, halo, d), before(2)),
+                      pl.BlockSpec((8, d), lambda i, s: (0, 0))],
+            out_specs=pl.BlockSpec((1, rows, d), tile),
+            out_shape=jax.ShapeDtypeStruct((batch, seq, d), bcx.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            interpret=interpret, name="mx_shortconv_fwd",
+        )(bcx, bcx, bcx, _taps_t(conv_weight))
+
+
+@functools.partial(jax.jit, static_argnames=_SHORTCONV_STATIC)
+def _shortconv_bwd_pallas(bcx, conv_weight, dgated, rows, channels,
+                          interpret=False):
+    (batch, seq, _), (d, taps) = bcx.shape, conv_weight.shape
+    halo, tile, before, after = _shortconv_specs(bcx, rows)
+    with jax.named_scope("mx.shortconv.gate"):
+        dbcx, dw = pl.pallas_call(
+            functools.partial(_shortconv_bwd_kernel, d=d, taps=taps,
+                              channels=channels),
+            grid=(batch, seq // rows),
+            in_specs=[pl.BlockSpec((1, rows, 3 * d), tile),
+                      pl.BlockSpec((1, halo, d), before(0)),
+                      pl.BlockSpec((1, halo, d), before(2)),
+                      pl.BlockSpec((1, halo, d), after(1)),
+                      pl.BlockSpec((1, rows, d), tile),
+                      pl.BlockSpec((1, halo, d), after(0)),
+                      pl.BlockSpec((8, d), lambda i, s: (0, 0))],
+            out_specs=[pl.BlockSpec((1, rows, 3 * d), tile),
+                       pl.BlockSpec((8, d), lambda i, s: (0, 0))],
+            out_shape=[jax.ShapeDtypeStruct(bcx.shape, bcx.dtype),
+                       jax.ShapeDtypeStruct((8, d), jnp.float32)],
+            # the taps' gradient is summed over both axes of the grid
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret, name="mx_shortconv_bwd",
+        )(bcx, bcx, bcx, bcx, dgated, dgated, _taps_t(conv_weight))
+        return dbcx, dw[:taps].T.astype(conv_weight.dtype)
+
+
+def _body_backward(bcx, conv_weight, dgated):
+    return jax.vjp(_gate_body, bcx, conv_weight)[1](dgated)
+
+
+def _gate_forward(bcx, conv_weight):
+    tiles = _shortconv_plan(bcx, conv_weight)
+    return jax.lax.platform_dependent(
+        bcx, conv_weight, default=_gate_body,
+        tpu=functools.partial(_shortconv_fwd_pallas, rows=tiles["fwd"],
+                              channels=tiles["channels"]))
+
+
+def _gate_backward(kept, dgated):
+    tiles = _shortconv_plan(*kept)
+    return jax.lax.platform_dependent(
+        *kept, dgated, default=_body_backward,
+        tpu=functools.partial(_shortconv_bwd_pallas, rows=tiles["bwd"],
+                              channels=tiles["channels"]))
+
+
+@jax.custom_vjp
+def _gate_tiled(bcx, conv_weight):
+    """`_gate` at a shape the kernels tile: they run where the program is
+    lowered for the TPU, `_gate_body` (and JAX's derivative of it, from
+    ``bcx`` again) where it is lowered for anything else."""
+    return _gate_forward(bcx, conv_weight)
+
+
+_gate_tiled.defvjp(lambda *a: (_gate_forward(*a), a), _gate_backward)
+
+
+def _gate(bcx, conv_weight):
+    """``gated = C * conv(B * X)`` from ``bcx = [B, C, X]`` ``(..., seq,
+    3d)`` and the taps ``(d, L)``.  One pass over ``bcx`` forward and one
+    backward where `_shortconv_plan` gives tiles, keeping nothing for the
+    backward pass but its two arguments; `_gate_body` where it gives
+    none."""
+    plan = _shortconv_plan(bcx, conv_weight)
+    _record_shortconv_plan(bcx, conv_weight, plan)
+    if plan is None:
+        return _gate_body(bcx, conv_weight)
+    return _gate_tiled(bcx, conv_weight)
+
+
 @register_op("_contrib_GatedShortConv", aliases=("GatedShortConv",))
 def _gated_short_conv(data, in_weight, conv_weight, out_weight):
     """The gated short convolution operator on ``(batch, seq, d)``:
     ``[B, C, X] = split3(x W_in)``, ``u = B * X``, ``c_t = sum_j w_j *
-    u_(t-L+1+j)`` (depthwise, causal, ``u`` zero before the sequence;
-    conv_weight is ``(d, L)``), ``y = (C * c) W_out``.  Weights as
-    ``FullyConnected`` holds them: in_weight ``(3d, d)``, out_weight
-    ``(d, d)``."""
+    u_(t-L+1+j)`` (depthwise, causal, ``u`` zero before the sequence of
+    every batch row; conv_weight is ``(d, L)``), ``y = (C * c) W_out``.
+    Weights as ``FullyConnected`` holds them: in_weight ``(3d, d)``,
+    out_weight ``(d, d)``.
+
+    The two projections are XLA's; what lies between them is `_gate`.  On
+    the TPU, at ``d`` a multiple of 128 and a sequence in whole tiles
+    (`SHORTCONV_TILES`) on one device, it is the kernels
+    ``mx_shortconv_fwd`` and ``mx_shortconv_bwd`` (`mx.shortconv.plan` says
+    ``path: kernel``), which read ``bcx`` once each way and keep no float32
+    array for the backward pass; on every other platform and at every other
+    shape it is `_gate_body`, the same arithmetic in `jax.numpy`."""
     with jax.named_scope("mx.shortconv"):
-        d, taps = conv_weight.shape
-        bcx = _dot(data, in_weight)
-        b, c, x = bcx[..., :d], bcx[..., d:2 * d], bcx[..., 2 * d:]
-        u = (b * x).astype(jnp.float32)
-        w = conv_weight.astype(jnp.float32)
-        seq = data.shape[-2]
-        padded = jnp.pad(u, [(0, 0)] * (u.ndim - 2) + [(taps - 1, 0),
-                                                       (0, 0)])
-        conv = sum(padded[..., j:j + seq, :] * w[:, j] for j in range(taps))
-        gated = (c.astype(jnp.float32) * conv).astype(data.dtype)
-        return _dot(gated, out_weight)
+        return _dot(_gate(_dot(data, in_weight), conv_weight), out_weight)
 
 
 # ---------------------------------------------------------------------------

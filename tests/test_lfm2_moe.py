@@ -418,6 +418,101 @@ def test_the_kernel_path_lowers_for_the_tpu_without_a_chip(tokens, rows):
     assert calls(jax.grad(loss, argnums=(0, 2, 3, 4))) == (5, 3, 8)
 
 
+def test_the_short_convolution_lowers_for_the_tpu_without_a_chip():
+    """`_contrib_GatedShortConv` lowered for the TPU platform from this
+    CPU host at the cell's shape: the forward is one Mosaic kernel between
+    the two projections; the gradient holds the forward kernel (whose
+    ``gated`` the output projection's own gradient needs) and the backward
+    one, and five products: ``bcx`` again, ``dgated``, and the three
+    gradients of the two projections.  Nothing in float32 of the size of
+    the activations is kept from the forward pass for the backward: ``u``
+    and ``conv`` are computed again from ``bcx``.  At the tests' widths
+    there is no kernel, and `mx.shortconv.plan` says which path it was."""
+    fn = get_op("_contrib_GatedShortConv").fn
+    bf = jnp.bfloat16
+
+    def avals(b, s, d, taps=3):
+        return [jax.ShapeDtypeStruct(shape, bf) for shape in (
+            (b, s, d), (3 * d, d), (d, taps), (d, d))]
+
+    def loss(*a):
+        return jnp.sum(fn(*a).astype(jnp.float32))
+
+    def text(f, at):
+        return jax.jit(f).trace(*at).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    def counts(t):
+        return (t.count("stablehlo.custom_call @tpu_custom_call"),
+                t.count("stablehlo.dot_general"))
+
+    since = max([s.id for s in profiler.spans()] or [0])
+    cell = avals(2, 8192, 2048)
+    forward = text(fn, cell)
+    assert counts(forward) == (1, 2) and "mx_shortconv_fwd" in forward
+    backward = text(jax.grad(loss, argnums=(0, 1, 2, 3)), cell)
+    assert counts(backward) == (2, 5)
+    assert "mx_shortconv_fwd" in backward and "mx_shortconv_bwd" in backward
+    # what `jax.vjp` keeps: its pullback is a pytree of the residuals
+    kept = jax.tree.leaves(jax.eval_shape(
+        lambda *a: jax.vjp(loss, *a)[1], *cell))
+    shapes = [(a.shape, a.dtype) for a in kept]
+    assert ((2, 8192, 3 * 2048), bf) in shapes              # bcx
+    assert ((2, 8192, 2048), bf) in shapes                  # gated
+    assert not [a for a in kept if a.dtype == jnp.float32
+                and a.size >= 2 * 8192 * 2048], shapes
+    small = avals(2, 32, 64)
+    assert counts(text(fn, small))[0] == 0
+    assert counts(text(jax.grad(loss, argnums=(0, 1, 2, 3)), small))[0] == 0
+    plans = [s.args for s in profiler.spans()
+             if s.name == "mx.shortconv.plan" and s.id > since]
+    at_cell = [p for p in plans if p["shape"] == [2, 8192, 3 * 2048]]
+    tiles = lm_blocks.SHORTCONV_TILES
+    assert at_cell and all(p == {
+        "shape": [2, 8192, 6144], "dtype": "bfloat16", "taps": 3,
+        "path": "kernel", "halo_rows": 16, "channel_tile": tiles["channels"],
+        "seq_tile": {"fwd": tiles["fwd"], "bwd": tiles["bwd"]},
+        "residual_bytes": 2 * 8192 * 6144 * 2 + 2048 * 3 * 2}
+        for p in at_cell)
+    at_small = [p for p in plans if p["shape"] == [2, 32, 192]]
+    assert at_small and all(
+        p["path"] == "xla" and p["seq_tile"] is None
+        and p["residual_bytes"] is None for p in at_small)
+
+
+def test_the_kernels_are_left_to_one_device_and_to_shapes_they_tile():
+    """What `_shortconv_plan` reads in its input: a width of whole lane
+    tiles, a sequence in whole tiles, 2 to 8 taps, blocks inside the VMEM
+    budget, and no mesh of several devices around it (XLA does not
+    partition a Mosaic kernel)."""
+    from jax.sharding import Mesh
+    from mxnet_tpu.parallel import mesh as mesh_mod
+    bf = jnp.bfloat16
+
+    def plan(b, s, d, taps=3, dtype=bf):
+        return lm_blocks._shortconv_plan(
+            jax.ShapeDtypeStruct((b, s, 3 * d), dtype),
+            jax.ShapeDtypeStruct((d, taps), dtype))
+
+    assert plan(2, 8192, 2048) == lm_blocks.SHORTCONV_TILES
+    assert plan(1, 256, 512) == lm_blocks.SHORTCONV_TILES
+    # the channel chunk is cut to divide the width: 1536 = 3 x 512, 640 =
+    # 5 x 128; half a lane tile has no chunk
+    assert plan(2, 8192, 1536)["channels"] == 512
+    assert plan(2, 8192, 640)["channels"] == 128
+    assert plan(2, 8192, 2048 + 64) is None
+    assert plan(2, 8192 + 64, 2048) is None         # half a tile
+    assert plan(2, 8192, 2048, taps=1) is None
+    assert plan(2, 8192, 2048, taps=9) is None
+    assert plan(2, 8192, 2048, dtype=jnp.float32) is None   # over the VMEM
+    assert plan(2, 8192, 8192) is None                      # budget
+    assert plan(2, 8192, 1024, dtype=jnp.float32) is not None
+    with mesh_mod.use_mesh(Mesh(np.array(jax.devices()[:2]), ("dp",))):
+        assert plan(2, 8192, 2048) is None
+    with mesh_mod.use_mesh(Mesh(np.array(jax.devices()[:1]), ("dp",))):
+        assert plan(2, 8192, 2048) is not None
+
+
 # -- the other operators ------------------------------------------------------
 def test_the_short_convolution_is_causal_and_agrees_with_the_reference():
     cfg = config(["conv"], 0)
@@ -454,6 +549,158 @@ def test_the_short_convolution_is_causal_and_agrees_with_the_reference():
         p["l.conv_out"]).T
     np.testing.assert_allclose(np.asarray(run(x))[:, t], want, rtol=1e-4,
                                atol=1e-6)
+
+
+# -- the short convolution's kernels, interpreted ----------------------------
+def conv_arguments(batch, seq, d, taps, dtype, seed=5):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(a, jnp.float32).astype(dtype) for a in (
+        rng.normal(size=(batch, seq, d)),
+        0.2 * rng.normal(size=(3 * d, d)), rng.normal(size=(d, taps)),
+        0.2 * rng.normal(size=(d, d))))
+
+
+def with_the_body(x, w_in, w_conv, w_out):
+    """The operator with `_gate_body` under JAX's own differentiation:
+    what the tests' small widths and every platform but the TPU run."""
+    return lm_blocks._dot(
+        lm_blocks._gate_body(lm_blocks._dot(x, w_in), w_conv), w_out)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """Steers the registered operator onto its TPU branch on this CPU
+    host, the two kernels interpreted: ``interpreted(fwd, bwd)`` sets the
+    sequence tiles (channel chunks of 128), small enough for a test."""
+    def take_tpu(*args, tpu, default):
+        return tpu(*args, interpret=True)
+
+    def tiles(fwd, bwd):
+        monkeypatch.setattr(lm_blocks, "SHORTCONV_TILES",
+                            {"fwd": fwd, "bwd": bwd, "channels": 128})
+        monkeypatch.setattr(jax.lax, "platform_dependent", take_tpu)
+        return get_op("_contrib_GatedShortConv").fn
+
+    return tiles
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("seq, fwd, bwd", [(32, 32, 32), (96, 32, 16)],
+                         ids=["one-tile", "several-tiles"])
+@pytest.mark.parametrize("taps", [2, 3, 4])
+def test_the_kernels_give_the_body_s_value_and_all_four_gradients(
+        interpreted, taps, seq, fwd, bwd, batch):
+    """Float32 on both sides, so only the order of a few sums differs
+    (the backward kernel adds the taps' gradient tile by tile)."""
+    args = conv_arguments(batch, seq, 256, taps, jnp.float32)
+    weight = jnp.asarray(np.random.default_rng(7).normal(
+        size=(batch, seq, 256)), jnp.float32)
+    since = max([s.id for s in profiler.spans()] or [0])
+    op = interpreted(fwd, bwd)
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a) * weight)
+
+    def close(g, w, name):
+        # a sum of hundreds of terms that cancel: to a float32 rounding of
+        # its largest, not of each
+        w = np.asarray(w)
+        np.testing.assert_allclose(np.asarray(g), w, rtol=1e-5,
+                                   atol=3e-6 * np.abs(w).max(), err_msg=name)
+
+    close(op(*args), with_the_body(*args), "value")
+    got = jax.grad(loss(op), argnums=(0, 1, 2, 3))(*args)
+    want = jax.grad(loss(with_the_body), argnums=(0, 1, 2, 3))(*args)
+    for name, g, w in zip(("data", "in_weight", "conv_weight", "out_weight"),
+                          got, want):
+        close(g, w, name)
+    plans = [s.args for s in profiler.spans()
+             if s.name == "mx.shortconv.plan" and s.id > since]
+    assert plans and all(p["path"] == "kernel" and p["halo_rows"] == 8
+                         and p["seq_tile"] == {"fwd": fwd, "bwd": bwd}
+                         for p in plans)
+
+
+def test_the_kernel_path_is_causal_and_keeps_batch_rows_apart(interpreted):
+    op = interpreted(32, 16)
+    x, w_in, w_conv, w_out = conv_arguments(2, 96, 256, 3, jnp.float32)
+    base = np.asarray(op(x, w_in, w_conv, w_out))
+    # a change at t + 1 moves nothing at or before t: across a tile's edge
+    # (t + 1 = 32 and 64 open a tile) and inside a tile
+    for t in (31, 40, 63):
+        moved = np.asarray(op(x.at[:, t + 1].add(1.0), w_in, w_conv, w_out)
+                           ) - base
+        assert np.abs(moved[:, :t + 1]).max() == 0.0
+        assert np.abs(moved[:, t + 1]).max() > 1e-3
+        # ... and reaches taps - 1 = 2 rows on, over the edge, no further
+        assert np.abs(moved[:, t + 3]).max() > 1e-3
+        assert np.abs(moved[:, t + 4:]).max() == 0.0
+    # the last rows of batch row 0 move nothing in batch row 1, whose
+    # first rows see zeros before them
+    moved = np.asarray(op(x.at[0, -2:].add(1.0), w_in, w_conv, w_out)) - base
+    assert np.abs(moved[1]).max() == 0.0 and np.abs(moved[0, -2:]).max() > 0
+    # the backward pass: the loss of rows up to t has no gradient after t,
+    # and none in the other batch row
+    t = 47
+
+    def upto(x):
+        return jnp.sum(op(x, w_in, w_conv, w_out)[0, :t + 1] ** 2)
+
+    dx = np.asarray(jax.grad(upto)(x))
+    assert np.abs(dx[0, t + 1:]).max() == 0.0 and np.abs(dx[1]).max() == 0.0
+    assert np.abs(dx[0, t]).max() > 0 and np.abs(dx[0, 0]).max() > 0
+
+
+@pytest.mark.parametrize("taps", [2, 3, 4])
+def test_in_bf16_the_forward_kernel_equals_the_body_to_the_last_bit(taps):
+    """To the last bit, not to a rounding: with bf16 taps every product
+    under a sum (``b * x``, ``w_j * u``) is exact in float32, so only the
+    order of the taps' sum could differ, and it is the body's.  The
+    backward kernel's ``dbcx`` is the body's derivative to one bf16
+    rounding (JAX's sums ``du`` in another order) and the taps' gradient
+    to float32 sums in another order, rounded to bf16."""
+    bf = jnp.bfloat16
+    rng = np.random.default_rng(taps)
+    bcx = jnp.asarray(rng.normal(size=(2, 96, 3 * 256)), bf)
+    w = jnp.asarray(rng.normal(size=(256, taps)), bf)
+    dgated = jnp.asarray(rng.normal(size=(2, 96, 256)), bf)
+    got = lm_blocks._shortconv_fwd_pallas(bcx, w, rows=32, channels=128,
+                                          interpret=True)
+    want = lm_blocks._gate_body(bcx, w)
+    assert got.dtype == want.dtype == bf
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    dbcx, dw = lm_blocks._shortconv_bwd_pallas(bcx, w, dgated, rows=16,
+                                               channels=128, interpret=True)
+    want_dbcx, want_dw = lm_blocks._body_backward(bcx, w, dgated)
+    assert dbcx.dtype == bf and dw.dtype == bf and dw.shape == w.shape
+    np.testing.assert_allclose(np.asarray(dbcx, np.float32),
+                               np.asarray(want_dbcx, np.float32),
+                               rtol=2 ** -7, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(dw, np.float32),
+                               np.asarray(want_dw, np.float32),
+                               rtol=2 ** -7, atol=2 ** -5)
+
+
+def test_at_a_shape_the_kernels_tile_every_other_platform_runs_the_body():
+    """On the CPU `_gate` at a tiled shape is the body, and its backward
+    pass JAX's derivative of the body from ``bcx`` again: the same numbers
+    as the body differentiated in place."""
+    args = conv_arguments(1, 256, 512, 3, jnp.float32)
+    op = get_op("_contrib_GatedShortConv").fn
+    assert lm_blocks._shortconv_plan(
+        jnp.zeros((1, 256, 1536)), args[2]) is not None
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a) ** 2)
+
+    np.testing.assert_array_equal(np.asarray(jax.jit(op)(*args)),
+                                  np.asarray(jax.jit(with_the_body)(*args)))
+    got = jax.jit(jax.grad(loss(op), argnums=(0, 1, 2, 3)))(*args)
+    want = jax.jit(jax.grad(loss(with_the_body), argnums=(0, 1, 2, 3)))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6,
+                                   atol=1e-6)
 
 
 def test_grouped_query_attention_against_the_plain_attention():
