@@ -2,5 +2,6 @@
 
 from .basic_layers import (Concurrent, GatedMLP, GatedShortConv,  # noqa
                            GroupedQueryAttention, HybridConcurrent, Identity,
-                           MoEFFN, MultiHeadAttention, RoutedExperts,
+                           LatentAttention, MoEFFN, MultiHeadAttention,
+                           RoutedExperts, SharedExperts,
                            SparseEmbedding, SyncBatchNorm)

@@ -194,6 +194,15 @@ class GatedMLP(HybridBlock):
         return F.contrib.GatedMLP(x, w1, w3, w2)
 
 
+class SharedExperts(GatedMLP):
+    """The shared experts beside a routed layer: a `GatedMLP` over the
+    ``_contrib_SharedExperts`` op, whose device time reads under scope
+    ``mx.moe.shared``."""
+
+    def hybrid_forward(self, F, x, w1, w3, w2):
+        return F.contrib.SharedExperts(x, w1, w3, w2)
+
+
 class GatedShortConv(HybridBlock):
     """The gated short causal convolution operator on (batch, seq,
     units): an input projection to three streams, a depthwise causal
@@ -280,6 +289,48 @@ class GroupedQueryAttention(HybridBlock):
                         shape=(0, 0, -1))
         return F.FullyConnected(att, out_weight, no_bias=True,
                                 flatten=False, num_hidden=self._units)
+
+
+class LatentAttention(HybridBlock):
+    """Causal multi-head latent attention, the expanded form, over the
+    ``_contrib_LatentAttention`` op: queries of *qk_nope_head_dim* +
+    *qk_rope_head_dim* a head, keys and values expanded from one latent
+    of *kv_lora_rank* (RMS-normed) with one rotary key of
+    *qk_rope_head_dim* for all heads, values *v_head_dim* wide; no
+    biases, no query compression."""
+
+    def __init__(self, units, num_heads, kv_lora_rank, qk_nope_head_dim,
+                 qk_rope_head_dim, v_head_dim, rope_theta=10000.0,
+                 rope_interleave=True, epsilon=1e-6,
+                 weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        self._attrs = {
+            "num_heads": int(num_heads),
+            "qk_nope_head_dim": int(qk_nope_head_dim),
+            "qk_rope_head_dim": int(qk_rope_head_dim),
+            "v_head_dim": int(v_head_dim), "rope_theta": float(rope_theta),
+            "rope_interleave": bool(rope_interleave), "eps": float(epsilon)}
+        qk = qk_nope_head_dim + qk_rope_head_dim
+        with self.name_scope():
+            def weight(name, rows, cols):
+                return self.params.get(name, shape=(rows, cols),
+                                       init=weight_initializer)
+            self.q_weight = weight("query_weight", num_heads * qk, units)
+            self.kv_a_weight = weight("kv_a_weight",
+                                      kv_lora_rank + qk_rope_head_dim, units)
+            self.kv_norm_gamma = self.params.get(
+                "kv_norm_gamma", shape=(kv_lora_rank,), init="ones")
+            self.kv_b_weight = weight(
+                "kv_b_weight", num_heads * (qk_nope_head_dim + v_head_dim),
+                kv_lora_rank)
+            self.out_weight = weight("out_weight", units,
+                                     num_heads * v_head_dim)
+
+    def hybrid_forward(self, F, x, q_weight, kv_a_weight, kv_norm_gamma,
+                       kv_b_weight, out_weight):
+        return F.contrib.LatentAttention(
+            x, q_weight, kv_a_weight, kv_norm_gamma, kv_b_weight, out_weight,
+            **self._attrs)
 
 
 class RoutedExperts(HybridBlock):

@@ -1,15 +1,33 @@
 """A decoder-only language model built from a list of layer kinds: each
-layer has an operator (``"conv"``: a gated short causal convolution, or
-``"full_attention"``: grouped-query attention with per-head RMS norm and
-rotary positions) and a feed-forward (dense gated MLP, or dropless top-k
-routed experts), pre-normed with RMS norm and added to the residual:
+layer has an operator and a feed-forward, pre-normed with RMS norm and
+added to the residual:
 
     h = h + operator(rms(h));  h = h + feed_forward(rms(h))
 
-then a final RMS norm and a head tied to the embedding.  This is the
-shape of LiquidAI's LFM2 mixture-of-experts models (``model_type``
-``lfm2_moe``), whose published ``config.json`` keys the arguments follow;
-`benchmarks/models/lfm2_moe.py` builds one from such a file.
+then a final RMS norm and a head.  The operator is one of three kinds
+(`OPERATOR_KINDS`):
+
+- ``"conv"``: a gated short causal convolution;
+- ``"full_attention"``: grouped-query attention with per-head RMS norm
+  and rotary positions;
+- ``"latent_attention"``: multi-head latent attention, the expanded form:
+  keys and values from one low-rank latent, one rotary key for all heads,
+  keys wider than values (``kv_lora_rank``, ``qk_nope_head_dim``,
+  ``qk_rope_head_dim``, ``v_head_dim``, ``rope_interleave``).
+
+The feed-forward is a dense gated MLP in the first ``num_dense_layers``
+layers and dropless top-k routed experts in the others, to which
+``shared_hidden`` > 0 adds shared experts: one gated MLP of that width
+that every token passes through (and every chip computes alike), under
+device scope ``mx.moe.shared``.  The head is the embedding itself
+(``tied_head``, the default) or a matrix of its own.
+
+These are the shapes of LiquidAI's LFM2 mixture-of-experts models
+(``model_type`` ``lfm2_moe``: conv and full_attention layers, a tied head)
+and of the ``deepseek_v3`` family (latent attention, shared experts, an
+untied head), whose published ``config.json`` keys the arguments follow;
+`benchmarks/models/lfm2_moe.py` and `benchmarks/models/deepseek_v3.py`
+build one from such a file.
 
 The routed layers hold ONE CHIP'S SHARE of their experts
 (`gluon.contrib.nn.RoutedExperts`): ``experts_held`` of ``num_experts``
@@ -28,23 +46,40 @@ from __future__ import annotations
 from ..block import HybridBlock
 from .. import nn
 from ..contrib.nn import (GatedMLP, GatedShortConv, GroupedQueryAttention,
-                          RoutedExperts)
+                          LatentAttention, RoutedExperts, SharedExperts)
 
 __all__ = ["DecoderLayer", "DecoderLM", "get_decoder_lm", "OPERATOR_KINDS"]
 
-OPERATOR_KINDS = ("conv", "full_attention")
+OPERATOR_KINDS = ("conv", "full_attention", "latent_attention")
+
+
+class SharedAndRouted(HybridBlock):
+    """A routed feed-forward with shared experts: ``shared(x) +
+    routed(x)``, the shared part one gated MLP every token passes
+    through."""
+
+    def __init__(self, routed, shared, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.shared = shared()
+            self.routed = routed()
+
+    def hybrid_forward(self, F, x):
+        return self.shared(x) + self.routed(x)
 
 
 class DecoderLayer(HybridBlock):
-    """One pre-norm layer: *operator* is built by kind, *feed_forward*
-    is handed in."""
+    """One pre-norm layer: *operator* is built by kind (*latent* holds
+    `LatentAttention`'s own widths), *feed_forward* is handed in."""
 
     def __init__(self, dim, kind, feed_forward, heads, kv_heads, head_dim,
-                 rope_theta, conv_kernel, eps, init, **kwargs):
+                 rope_theta, conv_kernel, eps, init, latent=None, **kwargs):
         super().__init__(**kwargs)
         if kind not in OPERATOR_KINDS:
-            raise ValueError("layer kind %r is not one of %s"
-                             % (kind, OPERATOR_KINDS))
+            raise ValueError(
+                "layer kind %r is not one of %s (a gated short convolution, "
+                "grouped-query attention, multi-head latent attention)"
+                % (kind, OPERATOR_KINDS))
         with self.name_scope():
             self.operator_norm = nn.RMSNorm(dim, eps,
                                             prefix="operator_norm_")
@@ -52,10 +87,18 @@ class DecoderLayer(HybridBlock):
                 self.operator = GatedShortConv(
                     dim, conv_kernel, weight_initializer=init,
                     prefix="conv_")
-            else:
+            elif kind == "full_attention":
                 self.operator = GroupedQueryAttention(
                     dim, heads, kv_heads, head_dim, rope_theta, eps,
                     weight_initializer=init, prefix="attn_")
+            else:
+                if not latent:
+                    raise ValueError(
+                        "a latent_attention layer needs kv_lora_rank, "
+                        "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+                self.operator = LatentAttention(
+                    dim, heads, rope_theta=rope_theta, epsilon=eps,
+                    weight_initializer=init, prefix="mla_", **latent)
             self.ffn_norm = nn.RMSNorm(dim, eps, prefix="ffn_norm_")
             self.feed_forward = feed_forward()
 
@@ -67,8 +110,10 @@ class DecoderLayer(HybridBlock):
 class DecoderLM(HybridBlock):
     """Token embedding, the layers of *layer_types* (the first
     *num_dense_layers* with a dense gated MLP of width *dense_hidden*,
-    the others with routed experts of width *expert_hidden*), final RMS
-    norm, and logits against the embedding itself (a tied head)."""
+    the others with routed experts of width *expert_hidden* and, with
+    *shared_hidden* > 0, shared experts beside them), final RMS norm, and
+    logits against the embedding itself (*tied_head*) or against a head
+    matrix of its own."""
 
     def __init__(self, vocab, dim, layer_types, num_dense_layers,
                  dense_hidden, expert_hidden, num_experts,
@@ -76,10 +121,18 @@ class DecoderLM(HybridBlock):
                  expert_bias=None, norm_topk_prob=True,
                  routed_scaling_factor=1.0, heads=8, kv_heads=None,
                  head_dim=None, rope_theta=10000.0, conv_kernel=3,
-                 eps=1e-5, weight_initializer="normal", **kwargs):
+                 eps=1e-5, weight_initializer="normal", shared_hidden=0,
+                 tied_head=True, kv_lora_rank=None, qk_nope_head_dim=None,
+                 qk_rope_head_dim=None, v_head_dim=None,
+                 rope_interleave=True, **kwargs):
         super().__init__(**kwargs)
         self._vocab, self._dim = vocab, dim
         init = weight_initializer
+        latent = kv_lora_rank and {
+            "kv_lora_rank": kv_lora_rank,
+            "qk_nope_head_dim": qk_nope_head_dim,
+            "qk_rope_head_dim": qk_rope_head_dim, "v_head_dim": v_head_dim,
+            "rope_interleave": rope_interleave}
 
         def dense():
             return GatedMLP(dim, dense_hidden, weight_initializer=init,
@@ -92,27 +145,38 @@ class DecoderLM(HybridBlock):
                 routed_scaling_factor, weight_initializer=init,
                 prefix="moe_")
 
+        def shared():
+            return SharedExperts(dim, shared_hidden, weight_initializer=init,
+                                 prefix="shared_")
+
+        def shared_and_routed():
+            return SharedAndRouted(routed, shared, prefix="ffn_")
+
+        sparse = shared_and_routed if shared_hidden else routed
         with self.name_scope():
             self.embed_weight = self.params.get(
                 "embed_weight", shape=(vocab, dim), init=init)
             self.layers = []
             for i, kind in enumerate(layer_types):
                 layer = DecoderLayer(
-                    dim, kind, dense if i < num_dense_layers else routed,
+                    dim, kind, dense if i < num_dense_layers else sparse,
                     heads, kv_heads or heads, head_dim, rope_theta,
-                    conv_kernel, eps, init, prefix="l%d_" % i)
+                    conv_kernel, eps, init, latent, prefix="l%d_" % i)
                 setattr(self, "l%d" % i, layer)
                 self.layers.append(layer)
             self.final_norm = nn.RMSNorm(dim, eps, prefix="final_norm_")
+            self.head_weight = None if tied_head else self.params.get(
+                "head_weight", shape=(vocab, dim), init=init)
 
-    def hybrid_forward(self, F, x, embed_weight):
+    def hybrid_forward(self, F, x, embed_weight, head_weight=None):
         h = F.Embedding(x, embed_weight, input_dim=self._vocab,
                         output_dim=self._dim)
         for layer in self.layers:
             h = layer(h)
-        return F.FullyConnected(self.final_norm(h), embed_weight,
-                                no_bias=True, flatten=False,
-                                num_hidden=self._vocab)
+        return F.FullyConnected(
+            self.final_norm(h),
+            embed_weight if head_weight is None else head_weight,
+            no_bias=True, flatten=False, num_hidden=self._vocab)
 
 
 def get_decoder_lm(**kwargs):

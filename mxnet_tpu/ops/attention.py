@@ -21,7 +21,11 @@ mesh (``mxnet_tpu.parallel.sequence``).  This module provides:
 - ``_contrib_DotProductAttention`` / ``_contrib_div_sqrt_dim`` registered
   operators, so the op is reachable from mx.nd / mx.sym like any other.
 
-Layout is (batch, heads, seq, head_dim) throughout.
+Layout is (batch, heads, seq, head_dim) throughout.  Queries and keys
+share one width ``d``; values (and so the output) may have another,
+``d_v`` (a latent-attention block's 192-wide keys beside 128-wide
+values): every path takes it from v's own shape, and nothing is padded
+from the one width to the other.
 """
 
 from __future__ import annotations
@@ -135,7 +139,7 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, d_v = k.shape[2], v.shape[3]
     chunk = min(chunk, sk)
     nchunk = -(-sk // chunk)
     pad = nchunk * chunk - sk
@@ -145,7 +149,7 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512):
     else:
         kp, vp = k, v
     kc = kp.reshape(b, h, nchunk, chunk, d).transpose(2, 0, 1, 3, 4)
-    vc = vp.reshape(b, h, nchunk, chunk, d).transpose(2, 0, 1, 3, 4)
+    vc = vp.reshape(b, h, nchunk, chunk, d_v).transpose(2, 0, 1, 3, 4)
     q_pos = jnp.arange(sq) + (sk - sq)  # align ends for causal cross-length
 
     @jax.checkpoint
@@ -167,7 +171,7 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512):
         o, m, l = _online_softmax_update(o, m, l, s, vb)
         return (o, m, l), None
 
-    o0 = jnp.zeros((b, h, sq, d), jnp.float32)
+    o0 = jnp.zeros((b, h, sq, d_v), jnp.float32)
     m0 = jnp.full((b, h, sq), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     (o, m, l), _ = jax.lax.scan(
@@ -185,9 +189,10 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512):
 _Tiles = collections.namedtuple("_Tiles", "res_q res_k sub_q sub_k")
 #: what `_flash_plan` hands the wrappers: the head dim as the kernels see
 #: it, the padded sequence lengths of the forward and of the backward
-#: pair, and each kernel's tiles
+#: pair, each kernel's tiles, and the values' head dim as the kernels see
+#: it (`d_block` again where v is as wide as q and k)
 _Plan = collections.namedtuple(
-    "_Plan", "d_block sq_fwd sk_fwd sq_bwd sk_bwd fwd dkdv dq")
+    "_Plan", "d_block sq_fwd sk_fwd sq_bwd sk_bwd fwd dkdv dq dv_block")
 _KERNELS = ("fwd", "dkdv", "dq")
 
 
@@ -202,22 +207,24 @@ def _d_block(d):
     return d if d % 64 == 0 else _round_up(d, _LANES)
 
 
-def _side_bytes(kernel, d_block, itemsize):
+def _side_bytes(kernel, d_block, dv_block, itemsize):
     """VMEM bytes that one resident query row and one resident key row
     cost *kernel*: its double-buffered blocks, its f32 accumulators and
     its row statistics (a `(1, n)` f32 block occupies 8 sublanes).  The
-    lane dimension is padded to 128 in VMEM whatever the array's width."""
-    wide = _round_up(d_block, _LANES)
-    blk = 2 * wide * itemsize           # one array's two buffers
-    acc = wide * 4
+    lane dimension is padded to 128 in VMEM whatever the array's width.
+    q, k, dq and dk are *d_block* wide; v, o, dO and dv *dv_block*."""
+    wide, wide_v = _round_up(d_block, _LANES), _round_up(dv_block, _LANES)
+    blk, blk_v = 2 * wide * itemsize, 2 * wide_v * itemsize  # two buffers
+    acc, acc_v = wide * 4, wide_v * 4
     row = 2 * 8 * 4                     # one statistics row's two buffers
     return {
         # q, o, acc, m, l, lse out        k, v
-        "fwd": (2 * blk + acc + 2 * _LANES * 4 + row, 2 * blk),
+        "fwd": (blk + blk_v + acc_v + 2 * _LANES * 4 + row, blk + blk_v),
         # q, do, lse, delta               k, v, dk, dv, two accumulators
-        "dkdv": (2 * blk + 2 * row, 4 * blk + 2 * acc),
+        "dkdv": (blk + blk_v + 2 * row,
+                 2 * blk + 2 * blk_v + acc + acc_v),
         # q, do, dq, acc, lse, delta      k, v
-        "dq": (3 * blk + acc + 2 * row, 2 * blk),
+        "dq": (2 * blk + blk_v + acc + 2 * row, blk + blk_v),
     }[kernel]
 
 
@@ -227,9 +234,9 @@ def _tile_bytes(sub_q, sub_k):
     return 6 * sub_q * sub_k * 4
 
 
-def _vmem_bytes(kernel, t, d_block, itemsize):
+def _vmem_bytes(kernel, t, d_block, dv_block, itemsize):
     """The VMEM *kernel* asks for with tiles *t*, by the plan's model."""
-    per_q, per_k = _side_bytes(kernel, d_block, itemsize)
+    per_q, per_k = _side_bytes(kernel, d_block, dv_block, itemsize)
     return (per_q * t.res_q + per_k * t.res_k
             + _tile_bytes(t.sub_q, t.sub_k))
 
@@ -242,7 +249,8 @@ def _resident(n_sub, bytes_per_sub, budget):
                if n_sub % c == 0 and c <= fit)
 
 
-def _kernel_tiles(kernels, subs, sq, sk, d_block, itemsize, res_q, res_k):
+def _kernel_tiles(kernels, subs, sq, sk, d_block, dv_block, itemsize, res_q,
+                  res_k):
     """``(sq_padded, sk_padded, {kernel: _Tiles})`` for *kernels*, which
     share their padded operands, with *subs* ``{kernel: (sub_q, sub_k)}``
     cut to the sequence: the resident blocks are the largest
@@ -254,7 +262,7 @@ def _kernel_tiles(kernels, subs, sq, sk, d_block, itemsize, res_q, res_k):
     for kernel in kernels:
         sub_q, sub_k = subs[kernel]
         nq, nk = sq_p // sub_q, sk_p // sub_k
-        per_q, per_k = _side_bytes(kernel, d_block, itemsize)
+        per_q, per_k = _side_bytes(kernel, d_block, dv_block, itemsize)
         per_q, per_k = per_q * sub_q, per_k * sub_k
         budget = _VMEM_BUDGET - _tile_bytes(sub_q, sub_k)
         if kernel == "dkdv":
@@ -276,9 +284,10 @@ def _unrolls_whole(t, sq_p, sk_p):
 
 
 def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
-                res_k=None):
+                res_k=None, d_v=None):
     """Tiles of the three kernels from what the call can see: the
-    lengths, the head dim and the dtype.  One algorithm with different
+    lengths, the head dim of q and k, that of v (*d_v*; *d* where not
+    given) and the dtype.  One algorithm with different
     parameters at different shapes.  Where a head fits VMEM and its
     tiles are few enough to unroll whole (S = 2048 at d = 64), the
     sub-tile is `_SUB_UNROLLED`, the one closest to the causal triangle;
@@ -290,6 +299,7 @@ def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
     padded length) override, for the tests and the sweep."""
     itemsize = jnp.dtype(dtype).itemsize
     d_block = _d_block(d)
+    dv_block = d_block if d_v is None else _d_block(d_v)
 
     def cut(sub):
         return (min(blk_q or sub[0], _round_up(sq, 1 if blk_q else _LANES)),
@@ -300,15 +310,16 @@ def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
     for kernels in (("fwd",), ("dkdv", "dq")):
         found = _kernel_tiles(
             kernels, {k: cut(_SUB_UNROLLED) for k in kernels}, sq, sk,
-            d_block, itemsize, res_q, res_k)
+            d_block, dv_block, itemsize, res_q, res_k)
         if not all(_unrolls_whole(t, *found[:2])
                    for t in found[2].values()):
             found = _kernel_tiles(
                 kernels, {k: cut(_SUB_LOOPED[k]) for k in kernels}, sq, sk,
-                d_block, itemsize, res_q, res_k)
+                d_block, dv_block, itemsize, res_q, res_k)
         plan[kernels[0]] = found
     (sq_f, sk_f, fwd), (sq_b, sk_b, bwd) = plan["fwd"], plan["dkdv"]
-    return _Plan(d_block, sq_f, sk_f, sq_b, sk_b, **fwd, **bwd)
+    return _Plan(d_block, sq_f, sk_f, sq_b, sk_b, **fwd, **bwd,
+                 dv_block=dv_block)
 
 
 # The loop bounds below run on Python ints (the plan's counts) and on the
@@ -406,31 +417,32 @@ def _tile_counts(kernel, plan, sq, sk, causal):
             "tiles_ideal": round(scores / (t.sub_q * t.sub_k), 3)}
 
 
-def _plan_args(plan, sq, sk, d, dtype, causal):
+def _plan_args(plan, sq, sk, d, dtype, causal, d_v=None):
     """The plan as the `mx.flash.plan` span carries it: static per shape,
     so recorded where the call is traced, not where it runs."""
     rec = {"sq": sq, "sk": sk, "d": d, "dtype": jnp.dtype(dtype).name,
-           "causal": bool(causal), "d_block": plan.d_block}
+           "causal": bool(causal), "d_block": plan.d_block,
+           "d_v": d if d_v is None else d_v, "dv_block": plan.dv_block}
     for kernel in _KERNELS:
         t = getattr(plan, kernel)
         rec[kernel] = dict(
             _tile_counts(kernel, plan, sq, sk, causal),
             resident=[t.res_q, t.res_k], sub_tile=[t.sub_q, t.sub_k],
-            vmem_bytes=_vmem_bytes(kernel, t, plan.d_block,
+            vmem_bytes=_vmem_bytes(kernel, t, plan.d_block, plan.dv_block,
                                    jnp.dtype(dtype).itemsize))
     return rec
 
 
-def _record_plan(q, k, causal):
+def _record_plan(q, k, v, causal):
     """One `mx.flash.plan` span each time the op is traced.  At trace
     time on purpose: the plan is a fact of the compiled program, not of
     a step."""
     from .. import profiler
-    sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
+    sq, sk, d, d_v = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     with profiler.scope(  # graftlint: disable=JG003
             "mx.flash.plan", "flash") as span:
-        span.args = _plan_args(_flash_plan(sq, sk, d, q.dtype), sq, sk, d,
-                               q.dtype, causal)
+        span.args = _plan_args(_flash_plan(sq, sk, d, q.dtype, d_v=d_v),
+                               sq, sk, d, q.dtype, causal, d_v)
 
 
 # ---------------------------------------------------------------------------
@@ -558,7 +570,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_and_scratch,
     iq, ik = _grid_pos(1, grid[0]), _grid_pos(2, nkr)
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
     off = seq_k - seq_q
-    d = acc_ref.shape[1]
+    d = acc_ref.shape[1]                # the values' width, and the output's
 
     # a query tile's first and last steps sit in its own loop body, not
     # at the kernel's ends: unrolled, they run under other tiles' dots
@@ -652,13 +664,18 @@ def _k_index(t, nkr, off, causal):
     return lambda bh_, iq, ik: ik
 
 
-def _block_specs(t, d_block, q_index, k_index):
-    """BlockSpecs of a resident query-side block, a key-side block and a
-    query-side statistics row."""
-    return (pl.BlockSpec((1, t.res_q, d_block),
-                         lambda *g: (g[0], q_index(*g), 0)),
-            pl.BlockSpec((1, t.res_k, d_block),
-                         lambda *g: (g[0], k_index(*g), 0)),
+def _block_specs(t, d_block, dv_block, q_index, k_index):
+    """BlockSpecs of a resident query-side block and a key-side block at
+    q's and k's width (q, dq; k, dk), of the same two at v's width (o, dO;
+    v, dv), and of a query-side statistics row."""
+    def block(rows, width, index):
+        return pl.BlockSpec((1, rows, width),
+                            lambda *g: (g[0], index(*g), 0))
+
+    return (block(t.res_q, d_block, q_index),
+            block(t.res_k, d_block, k_index),
+            block(t.res_q, dv_block, q_index),
+            block(t.res_k, dv_block, k_index),
             pl.BlockSpec((1, 1, t.res_q),
                          lambda *g: (g[0], 0, q_index(*g))))
 
@@ -681,23 +698,24 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
     per-row logsumexp residual (the flash backward's recompute anchor)
     as ``(B*H, 1, seq_q)``."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
-    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k)
-    t, dp = plan.fwd, plan.d_block
+    sk, d_v = k.shape[2], v.shape[3]
+    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v)
+    t, dp, dvp = plan.fwd, plan.d_block, plan.dv_block
     sq_p, sk_p = plan.sq_fwd, plan.sk_fwd
     qp = _pad_bh(q, sq_p, dp)
     kp = _pad_bh(k, sk_p, dp)
-    vp = _pad_bh(v, sk_p, dp)
+    vp = _pad_bh(v, sk_p, dvp)
     bh = b * h
     nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
 
-    q_spec, k_spec, row_spec = _block_specs(
-        t, dp, lambda bh_, iq, ik: iq, _k_index(t, nkr, sk - sq, causal))
+    q_spec, k_spec, o_spec, v_spec, row_spec = _block_specs(
+        t, dp, dvp, lambda bh_, iq, ik: iq,
+        _k_index(t, nkr, sk - sq, causal))
     kernel = functools.partial(
         _flash_fwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
         causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk)
-    out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct((bh, sq_p, dp), q.dtype)]
+    out_specs = [o_spec]
+    out_shape = [jax.ShapeDtypeStruct((bh, sq_p, dvp), q.dtype)]
     if with_lse:  # training: also emit the logsumexp residual
         out_specs.append(row_spec)
         out_shape.append(jax.ShapeDtypeStruct((bh, 1, sq_p), jnp.float32))
@@ -705,11 +723,11 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
         res = pl.pallas_call(
             kernel,
             grid=(bh, nqr, nkr),
-            in_specs=[q_spec, k_spec, k_spec],
+            in_specs=[q_spec, k_spec, v_spec],
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[
-                pltpu.VMEM((t.res_q, dp), jnp.float32),
+                pltpu.VMEM((t.res_q, dvp), jnp.float32),
                 pltpu.VMEM((t.res_q, _LANES), jnp.float32),
                 pltpu.VMEM((t.res_q, _LANES), jnp.float32),
             ],
@@ -718,7 +736,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
             interpret=interpret,
             name="mx_flash_fwd",
         )(qp, kp, vp)
-    out = _unpad_bh(res[0], b, h, sq, d)
+    out = _unpad_bh(res[0], b, h, sq, d_v)
     if with_lse:
         return out, res[1][:, :, :sq]
     return out
@@ -737,7 +755,10 @@ def _wide(x, width):
     contract over d or write d columns then run on whole lane tiles
     (dk/dv 9% and dq 6% faster at S = 2048 than on 64-wide operands,
     tools/flash_sweep.py; the forward measured flat and is left
-    narrow); HBM holds the 64 columns only."""
+    narrow); HBM holds the 64 columns only.  Each operand is widened to
+    its own accumulator's lanes: q and k to dq's and dk's, v and dO to
+    dv's (at 192 the second lane tile's zero half is written out, the
+    lanes VMEM pads the block to anyway)."""
     d = x.shape[1]
     return x if d == width else jnp.pad(x, ((0, 0), (0, width - d)))
 
@@ -757,22 +778,23 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
     off = seq_k - seq_q
     d, wide = dk_ref.shape[2], dk_acc.shape[1]
+    d_v, wide_v = dv_ref.shape[2], dv_acc.shape[1]
 
     def k_tile(jk):
         ks = _sub(jk, t.sub_k, nks)
         col0 = ik * t.res_k + jk * t.sub_k
         k = _wide(k_ref[0, ks, :], wide)
-        v = _wide(v_ref[0, ks, :], wide)
+        v = _wide(v_ref[0, ks, :], wide_v)
 
         @pl.when(iq == 0)
         def _init():
             dk_acc[ks, :] = jnp.zeros((t.sub_k, wide), jnp.float32)
-            dv_acc[ks, :] = jnp.zeros((t.sub_k, wide), jnp.float32)
+            dv_acc[ks, :] = jnp.zeros((t.sub_k, wide_v), jnp.float32)
 
         def tile(jq, masked):
             qs = _sub(jq, t.sub_q, nqs)
             q = _wide(q_ref[0, qs, :], wide)
-            do = _wide(do_ref[0, qs, :], wide)
+            do = _wide(do_ref[0, qs, :], wide_v)
             s = _mxu_dot(k, q, _NT) * sm_scale      # (sub_k, sub_q)
             if masked:
                 s = jnp.where(_tile_mask(
@@ -793,7 +815,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         @pl.when(iq == nqr - 1)
         def _finish():
             dk_ref[0, ks, :] = dk_acc[ks, :d].astype(dk_ref.dtype)
-            dv_ref[0, ks, :] = dv_acc[ks, :d].astype(dv_ref.dtype)
+            dv_ref[0, ks, :] = dv_acc[ks, :d_v].astype(dv_ref.dtype)
 
     _loop(0, nks, k_tile)
 
@@ -808,12 +830,13 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
     off = seq_k - seq_q
     d, wide = dq_ref.shape[2], dq_acc.shape[1]
+    wide_v = _round_up(v_ref.shape[2], _LANES)
 
     def q_tile(jq):
         qs = _sub(jq, t.sub_q, nqs)
         row0 = iq * t.res_q + jq * t.sub_q
         q = _wide(q_ref[0, qs, :], wide)
-        do = _wide(do_ref[0, qs, :], wide)
+        do = _wide(do_ref[0, qs, :], wide_v)
         # the statistics' rows as columns, widened once per query tile
         shape = (t.sub_q, t.sub_k)
         lse = jnp.broadcast_to(lse_ref[0, :, qs].reshape(t.sub_q, 1), shape)
@@ -827,7 +850,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         def tile(jk, masked):
             ks = _sub(jk, t.sub_k, nks)
             k = _wide(k_ref[0, ks, :], wide)
-            v = _wide(v_ref[0, ks, :], wide)
+            v = _wide(v_ref[0, ks, :], wide_v)
             s = _mxu_dot(q, k, _NT) * sm_scale      # (sub_q, sub_k)
             if masked:
                 s = jnp.where(_tile_mask(
@@ -855,14 +878,16 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
     """dq, dk, dv from the forward's output and its ``(B*H, 1, seq_q)``
     logsumexp."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
-    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k)
-    dp, sq_p, sk_p = plan.d_block, plan.sq_bwd, plan.sk_bwd
-    wide = _round_up(dp, _LANES)        # the accumulators' lanes
+    sk, d_v = k.shape[2], v.shape[3]
+    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v)
+    dp, dvp, sq_p, sk_p = plan.d_block, plan.dv_block, plan.sq_bwd, \
+        plan.sk_bwd
+    # the accumulators' lanes
+    wide, wide_v = _round_up(dp, _LANES), _round_up(dvp, _LANES)
     qp = _pad_bh(q, sq_p, dp)
     kp = _pad_bh(k, sk_p, dp)
-    vp = _pad_bh(v, sk_p, dp)
-    dop = _pad_bh(dout, sq_p, dp)
+    vp = _pad_bh(v, sk_p, dvp)
+    dop = _pad_bh(dout, sq_p, dvp)
     bh = b * h
     # delta_i = rowsum(dO_i * O_i), a row like lse; both zero on padded
     # rows, where dO is zero too and p = exp(0 - 0) multiplies nothing
@@ -883,19 +908,19 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
         return jnp.maximum(iq, _first_q_block(ik, t, nqr, sk - sq)) \
             if causal and nqr > 1 else iq
 
-    q_spec, k_spec, row_spec = _block_specs(
-        t, dp, q_index, lambda bh_, ik, iq: ik)
+    q_spec, k_spec, do_spec, v_spec, row_spec = _block_specs(
+        t, dp, dvp, q_index, lambda bh_, ik, iq: ik)
     with jax.named_scope("mx.flash.dkdv"):
         dk, dv = pl.pallas_call(
             functools.partial(_flash_bwd_dkdv_kernel, t=t, grid=(nqr, nkr),
                               **common),
             grid=(bh, nkr, nqr),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-            out_specs=[k_spec, k_spec],
+            in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+            out_specs=[k_spec, v_spec],
             out_shape=[jax.ShapeDtypeStruct((bh, sk_p, dp), k.dtype),
-                       jax.ShapeDtypeStruct((bh, sk_p, dp), v.dtype)],
+                       jax.ShapeDtypeStruct((bh, sk_p, dvp), v.dtype)],
             scratch_shapes=[pltpu.VMEM((t.res_k, wide), jnp.float32),
-                            pltpu.VMEM((t.res_k, wide), jnp.float32)],
+                            pltpu.VMEM((t.res_k, wide_v), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
@@ -906,14 +931,15 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
     t = plan.dq
     nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
 
-    q_spec, k_spec, row_spec = _block_specs(
-        t, dp, lambda bh_, iq, ik: iq, _k_index(t, nkr, sk - sq, causal))
+    q_spec, k_spec, do_spec, v_spec, row_spec = _block_specs(
+        t, dp, dvp, lambda bh_, iq, ik: iq,
+        _k_index(t, nkr, sk - sq, causal))
     with jax.named_scope("mx.flash.dq"):
         dq = pl.pallas_call(
             functools.partial(_flash_bwd_dq_kernel, t=t, grid=(nqr, nkr),
                               **common),
             grid=(bh, nqr, nkr),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct((bh, sq_p, dp), q.dtype),
             scratch_shapes=[pltpu.VMEM((t.res_q, wide), jnp.float32)],
@@ -924,12 +950,12 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
         )(qp, kp, vp, dop, lse, delta)
 
     return (_unpad_bh(dq, b, h, sq, d), _unpad_bh(dk, b, h, sk, d),
-            _unpad_bh(dv, b, h, sk, d))
+            _unpad_bh(dv, b, h, sk, d_v))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash(q, k, v, causal, sm_scale, interpret):
-    _record_plan(q, k, causal)
+    _record_plan(q, k, v, causal)
     return _flash_fwd_pallas(q, k, v, causal, sm_scale, interpret=interpret)
 
 

@@ -1,7 +1,8 @@
 """The parts of today's decoder blocks, as operators: RMS norm, rotary
 positions, the gated (SwiGLU) feed-forward, the gated short causal
-convolution, and a dropless top-k routed expert layer that is told which
-experts it holds.
+convolution, the latent attention block (low-rank keys and values, one
+rotary key for all heads, keys wider than values), and a dropless top-k
+routed expert layer that is told which experts it holds.
 
 The reference framework predates all of them (SURVEY section 5.7); they
 are TPU extensions beside ``_contrib_DotProductAttention``.  Each is a
@@ -20,6 +21,9 @@ multiple of 128, the sequence in whole tiles), and `_gate_body`, the
 same arithmetic in `jax.numpy`, on every other platform and at every other
 shape.  Both are decided by what the code sees in its input and by the
 platform it is lowered for: no argument, environment variable or switch.
+The latent attention block's core is `ops/attention.py` `flash_attention`
+at two head widths (the Mosaic kernels on the TPU, the chunked scan
+elsewhere); its projections and its assembly of q and k are XLA's.
 
 Grouped-query attention has no operator: the key/value heads are
 repeated to the query heads (``repeat``) in front of
@@ -41,6 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import profiler
 from ._precision import matmul_precision
+from .attention import flash_attention
 from .nn import _fully_connected
 from .registry import register_op
 
@@ -63,18 +68,32 @@ def _rms_norm(data, gamma, eps=1e-5):
 
 
 @register_op("_contrib_RotaryEmbedding", aliases=("RotaryEmbedding",))
-def _rotary(data, theta=10000.0):
-    """Rotary positions (rotate-half) on ``(batch, heads, seq, d)``:
-    positions ``0 .. seq - 1``, frequencies ``theta ** (-2i / d)``, the
-    angles in float32."""
+def _rotary(data, theta=10000.0, interleaved=False):
+    """Rotary positions on ``(batch, heads, seq, d)``: positions ``0 ..
+    seq - 1``, frequencies ``theta ** (-2i / d)``, the angles in float32.
+    Frequency ``i`` turns the pair ``(x[i], x[i + d/2])`` (rotate-half), or
+    with *interleaved* the pair ``(x[2i], x[2i + 1])``.  The interleaved
+    pairs are swapped by a product with a fixed signed permutation (exact:
+    every sum has one term), not by strided slices (a scatter backward)."""
     s, d = data.shape[-2], data.shape[-1]
     half = d // 2
     inv = 1.0 / (float(theta) ** (np.arange(half, dtype=np.float64) / half))
     ang = np.arange(s, dtype=np.float64)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.concatenate([np.cos(ang)] * 2, -1), jnp.float32)
-    sin = jnp.asarray(np.concatenate([np.sin(ang)] * 2, -1), jnp.float32)
     x = data.astype(jnp.float32)
-    rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
+    if interleaved:
+        cos = jnp.asarray(np.repeat(np.cos(ang), 2, -1), jnp.float32)
+        sin = jnp.asarray(np.repeat(np.sin(ang), 2, -1), jnp.float32)
+        swap = np.zeros((d, d), np.float32)
+        even = 2 * np.arange(half)
+        swap[even + 1, even], swap[even, even + 1] = -1.0, 1.0
+        rot = jnp.einsum(
+            "...d,de->...e", data, jnp.asarray(swap, data.dtype),
+            precision=matmul_precision(data.dtype, data.dtype),
+            preferred_element_type=jnp.float32)
+    else:
+        cos = jnp.asarray(np.concatenate([np.cos(ang)] * 2, -1), jnp.float32)
+        sin = jnp.asarray(np.concatenate([np.sin(ang)] * 2, -1), jnp.float32)
+        rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
     return (x * cos + rot * sin).astype(data.dtype)
 
 
@@ -90,6 +109,103 @@ def _gated_mlp(data, w1, w3, w2):
     """``W2(silu(W1 x) * W3 x)``; weights as ``FullyConnected`` holds
     them: w1, w3 ``(hidden, in)``, w2 ``(in, hidden)``."""
     return _dot(_silu_mul(_dot(data, w1), _dot(data, w3)), w2)
+
+
+@register_op("_contrib_SharedExperts", aliases=("SharedExperts",))
+def _shared_experts(data, w1, w3, w2):
+    """The shared experts beside a routed layer: one gated MLP (as
+    ``_contrib_GatedMLP``) that every token passes through and every chip
+    of the layer's group computes alike, under device scope
+    ``mx.moe.shared``."""
+    with jax.named_scope("mx.moe.shared"):
+        return _gated_mlp(data, w1, w3, w2)
+
+
+# ---------------------------------------------------------------------------
+# The latent attention block (multi-head latent attention, expanded form).
+# ---------------------------------------------------------------------------
+
+def _record_mla_plan(data, heads, nope, rope, v_dim, rank):
+    """One `mx.mla.plan` span each time the op is traced (as
+    `mx.flash.plan`: the plan is a fact of the compiled program)."""
+    batch, seq = data.shape[0], data.shape[1]
+    per_width = batch * heads * seq * jnp.dtype(data.dtype).itemsize
+    with profiler.scope(  # graftlint: disable=JG003
+            "mx.mla.plan", "mla") as span:
+        span.args = {
+            "heads": heads, "qk_nope_head_dim": nope,
+            "qk_rope_head_dim": rope, "v_head_dim": v_dim,
+            "kv_lora_rank": rank, "batch": batch, "seq": seq,
+            "dtype": jnp.dtype(data.dtype).name,
+            # what the straightforward assembly writes before the kernels
+            # read it: q and k at heads x (nope + rope), the one rope key
+            # of a position spread over every head
+            "assembled_q_bytes": per_width * (nope + rope),
+            "assembled_k_bytes": per_width * (nope + rope),
+            "rope_key_copies": heads}
+
+
+@register_op("_contrib_LatentAttention", aliases=("LatentAttention",))
+def _latent_attention(data, q_weight, kv_a_weight, kv_norm_gamma,
+                      kv_b_weight, out_weight, num_heads=1,
+                      qk_nope_head_dim=128, qk_rope_head_dim=64,
+                      v_head_dim=128, rope_theta=10000.0,
+                      rope_interleave=True, eps=1e-6):
+    """Causal latent attention on ``(batch, seq, d)``, the expanded form a
+    model is trained in, with H = *num_heads* heads and no biases:
+
+    ``q = x Wq`` -> (H, nope + rope), split ``q_nope | q_rope``;
+    ``x Wkv_a`` -> ``c | k_rope`` (the latent of width ``kv_lora_rank``,
+    and ONE rotary key of width rope for all heads);
+    ``rms(c, gamma) Wkv_b`` -> (H, nope + v), split ``k_nope | v``;
+    rotary positions on ``q_rope`` and ``k_rope`` (pairs ``(x[2i],
+    x[2i+1])`` with *rope_interleave*, rotate-half without);
+    ``q_h = [q_nope_h | q_rope_h]``, ``k_h = [k_nope_h | k_rope]``, scores
+    ``q_h . k_h / sqrt(nope + rope)``, causal softmax, times ``v_h`` (v
+    wide), the heads joined, ``Wo``.
+
+    Weights as ``FullyConnected`` holds them: q_weight ``(H (nope + rope),
+    d)``, kv_a_weight ``(rank + rope, d)``, kv_norm_gamma ``(rank,)``,
+    kv_b_weight ``(H (nope + v), rank)``, out_weight ``(d, H v)``.  The
+    core is `flash_attention` with keys nope + rope wide and values v
+    wide: nothing is padded from the one to the other.  k is materialised
+    at H x (nope + rope) in front of it (`mx.mla.assemble`); the absorbed
+    form, which never builds it, is a decode matter and is not here.
+    """
+    heads, nope, rope, v_dim = (int(num_heads), int(qk_nope_head_dim),
+                                int(qk_rope_head_dim), int(v_head_dim))
+    batch, seq, _ = data.shape
+    rank = kv_norm_gamma.shape[0]
+    theta, interleaved = float(rope_theta), bool(rope_interleave)
+    _record_mla_plan(data, heads, nope, rope, v_dim, rank)
+
+    def by_head(y, width):
+        # (B, S, H * width) -> (B, H, S, width)
+        return y.reshape(batch, seq, heads, width).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("mx.mla"):
+        with jax.named_scope("mx.mla.project"):
+            q = _dot(data, q_weight)
+            ckv = _dot(data, kv_a_weight)
+            kv = _dot(_rms_norm(ckv[..., :rank], kv_norm_gamma, eps),
+                      kv_b_weight)
+        with jax.named_scope("mx.mla.assemble"):
+            q, kv = by_head(q, nope + rope), by_head(kv, nope + v_dim)
+            q = jnp.concatenate(
+                [q[..., :nope], _rotary(q[..., nope:], theta, interleaved)],
+                -1)
+            k_rope = _rotary(ckv[..., rank:][:, None], theta, interleaved)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope, (batch, heads, seq, rope))], -1)
+            v = kv[..., nope:]
+        att = flash_attention(q, k, v, causal=True,
+                              sm_scale=(nope + rope) ** -0.5)
+        with jax.named_scope("mx.mla.assemble"):
+            att = att.transpose(0, 2, 1, 3).reshape(batch, seq,
+                                                    heads * v_dim)
+        with jax.named_scope("mx.mla.out"):
+            return _dot(att, out_weight)
 
 
 # ---------------------------------------------------------------------------
