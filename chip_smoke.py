@@ -12,7 +12,7 @@ user calls, at the full width of models the repo ships:
    up to 32, mixed-size requests from concurrent threads, on device 0;
 3. lm     — the 1024-wide, 16-head transformer LM at sequence 2048
    through ``ParallelTrainer``, so the Pallas flash-attention forward and
-   both backward kernels compile and run inside a real step, plus the
+   backward kernels compile and run inside a real step, plus the
    kernels against ``attention_reference``.
 
 Weights are random, from a seed.  Every phase checks its own result and
@@ -257,11 +257,11 @@ def phase_serve(model="resnet50_v1", classes=1000, image=224,
 
 
 def phase_lm(vocab=32000, dim=1024, heads=16, layers=12, seq=2048,
-             per_chip_batch=8, steps=3, devices=None, kernels_per_layer=3,
+             per_chip_batch=8, steps=3, devices=None, kernels_per_layer=2,
              flash_shape=(2, 4, 2048, 64)):
     """The kernels that must compile: the zoo's transformer LM
-    inside a real step.  On a TPU the compiled step holds three
-    Mosaic calls per layer (flash forward, dk/dv, dq)."""
+    inside a real step.  On a TPU the compiled step holds two
+    Mosaic calls per layer (flash forward and the one backward)."""
     import jax
     import mxnet_tpu as mx
     from mxnet_tpu import gluon

@@ -12,12 +12,16 @@ mesh (``mxnet_tpu.parallel.sequence``).  This module provides:
   every backend (the non-TPU dispatch target).
 - ``flash_attention``: Pallas TPU kernels — MXU-tiled forward with online
   softmax in f32 scratch (saving the per-row logsumexp), and a custom VJP
-  running the standard flash backward as two Pallas kernels
-  (``_flash_bwd_dkdv_kernel`` / ``_flash_bwd_dq_kernel``) that recompute
-  p from the saved logsumexp and accumulate tile by tile.  All three
-  share one tile plan (``_flash_plan``): resident blocks of Q and K/V
-  a grid step, score sub-tiles walked in loops bounded by the causal
-  limit.
+  running the flash backward as one Pallas kernel (``_flash_bwd_kernel``)
+  that recomputes p from the saved logsumexp once a score tile and feeds
+  dv, dk and dq from it.  The scores stay transposed, (key rows, query
+  columns): the K/V block is the resident side, dk and dv accumulate in
+  block-sized scratch and both their dots are plain a @ b; dq, which
+  accumulates over the other axis, lives in an f32 VMEM scratch that
+  spans one head's whole sequence (f32 partials a K/V block in HBM where
+  that would not fit).  Both kernels share one tile plan
+  (``_flash_plan``): resident blocks of Q and K/V a grid step, score
+  sub-tiles walked in loops bounded by the causal limit.
 - ``_contrib_DotProductAttention`` / ``_contrib_div_sqrt_dim`` registered
   operators, so the op is reachable from mx.nd / mx.sym like any other.
 
@@ -51,15 +55,27 @@ _NEG_INF = -1e30
 # layout.  In HBM the per-row residuals (logsumexp, delta) are compact
 # rows of a (B*H, 1, seq_q) array, whose (1, 1, n) blocks Mosaic accepts.
 _LANES = 128
-# Score sub-tile (query rows, key columns) of each kernel.  Chosen by
-# tools/flash_sweep.py on the v5e at (b 2, h 32, s 2048, d 64, bf16,
-# causal), the shape of the benchmark's LM cell (docs/PERF_NOTES.md
-# "Flash attention kernel" has the table).
+# Score sub-tile (query rows, key columns) of both kernels, chosen by
+# tools/flash_sweep.py on the v5e at the benchmark's LM cells' shapes
+# (docs/PERF_NOTES.md "Flash attention kernel" has the tables): where a
+# head's loops unroll whole, and where they stay loops.
 _SUB_UNROLLED = (256, 256)
-_SUB_LOOPED = {"fwd": (256, 512), "dkdv": (512, 256), "dq": (256, 512)}
+_SUB_LOOPED = (256, 512)
 # What the plan lets a kernel's blocks, scratch and tile temporaries take
-# of the 16 MiB of scoped VMEM on the v5e; the rest is Mosaic's own.
+# of the 16 MiB of scoped VMEM that Mosaic grants on the v5e by default;
+# the rest (`_VMEM_MOSAIC`) is Mosaic's own.
 _VMEM_BUDGET = 12 << 20
+_VMEM_MOSAIC = 4 << 20
+# The backward's dq accumulator spans a head's sequence, beside that
+# budget: the call asks Mosaic for what the plan counted
+# (`vmem_limit_bytes`) out of the chip's 128 MiB.  A sequence whose
+# accumulator would pass this much (49152 rows of bf16 at d = 128, where
+# the call asks for 64 MiB: compiled for the v5e at that size, tier-1
+# holds it) leaves dq as f32 partials a K/V block in HBM, and the heads
+# then go through the kernel in groups whose partials stay under
+# `_HBM_DQ`; one head's alone above it is refused.
+_VMEM_DQ = 48 << 20
+_HBM_DQ = 2 << 30
 # Tiles a loop iteration holds (`_loop`), and the longest static loop
 # that is unrolled whole.
 _UNROLL = 4
@@ -180,7 +196,7 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512):
 
 
 # ---------------------------------------------------------------------------
-# The tile plan of the three Pallas calls.
+# The tile plan of the two Pallas calls.
 # ---------------------------------------------------------------------------
 
 #: one kernel's tiles: a grid step keeps `res_q` query rows and `res_k`
@@ -188,12 +204,14 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512):
 #: inside them, in loops whose bounds come from the causal limit
 _Tiles = collections.namedtuple("_Tiles", "res_q res_k sub_q sub_k")
 #: what `_flash_plan` hands the wrappers: the head dim as the kernels see
-#: it, the padded sequence lengths of the forward and of the backward
-#: pair, each kernel's tiles, and the values' head dim as the kernels see
-#: it (`d_block` again where v is as wide as q and k)
+#: it, the padded sequence lengths of the forward and of the backward,
+#: each kernel's tiles, the values' head dim as the kernels see it
+#: (`d_block` again where v is as wide as q and k), and where the
+#: backward accumulates dq (`vmem` or `hbm`)
 _Plan = collections.namedtuple(
-    "_Plan", "d_block sq_fwd sk_fwd sq_bwd sk_bwd fwd dkdv dq dv_block")
-_KERNELS = ("fwd", "dkdv", "dq")
+    "_Plan",
+    "d_block sq_fwd sk_fwd sq_bwd sk_bwd fwd bwd dv_block dq_accumulator")
+_KERNELS = ("fwd", "bwd")
 
 
 def _round_up(n, m):
@@ -221,11 +239,21 @@ def _side_bytes(kernel, d_block, dv_block, itemsize):
         # q, o, acc, m, l, lse out        k, v
         "fwd": (blk + blk_v + acc_v + 2 * _LANES * 4 + row, blk + blk_v),
         # q, do, lse, delta               k, v, dk, dv, two accumulators
-        "dkdv": (blk + blk_v + 2 * row,
-                 2 * blk + 2 * blk_v + acc + acc_v),
-        # q, do, dq, acc, lse, delta      k, v
-        "dq": (2 * blk + blk_v + acc + 2 * row, blk + blk_v),
+        "bwd": (blk + blk_v + 2 * row,
+                2 * blk + 2 * blk_v + acc + acc_v),
     }[kernel]
+
+
+def _dq_bytes(dq_accumulator, rows, d_block, itemsize):
+    """VMEM bytes of the backward's dq side, which its resident blocks do
+    not hold.  `vmem`: the f32 accumulator and dq's double-buffered
+    output block, both over the *rows* of a head's whole (padded)
+    sequence.  `hbm`: the f32 partial's double-buffered block over the
+    *rows* of a resident query block."""
+    wide = _round_up(d_block, _LANES)
+    if dq_accumulator == "vmem":
+        return rows * (wide * 4 + 2 * wide * itemsize)
+    return rows * 2 * wide * 4
 
 
 def _tile_bytes(sub_q, sub_k):
@@ -234,11 +262,30 @@ def _tile_bytes(sub_q, sub_k):
     return 6 * sub_q * sub_k * 4
 
 
-def _vmem_bytes(kernel, t, d_block, dv_block, itemsize):
-    """The VMEM *kernel* asks for with tiles *t*, by the plan's model."""
-    per_q, per_k = _side_bytes(kernel, d_block, dv_block, itemsize)
-    return (per_q * t.res_q + per_k * t.res_k
-            + _tile_bytes(t.sub_q, t.sub_k))
+def _vmem_bytes(kernel, plan, itemsize):
+    """The VMEM *kernel* asks for with *plan*'s tiles, by the plan's
+    model."""
+    t = getattr(plan, kernel)
+    per_q, per_k = _side_bytes(kernel, plan.d_block, plan.dv_block, itemsize)
+    need = per_q * t.res_q + per_k * t.res_k + _tile_bytes(t.sub_q, t.sub_k)
+    if kernel == "bwd":
+        vmem = plan.dq_accumulator == "vmem"
+        need += _dq_bytes(plan.dq_accumulator,
+                          plan.sq_bwd if vmem else t.res_q, plan.d_block,
+                          itemsize)
+    return need
+
+
+def _vmem_limit(plan, itemsize):
+    """What the backward call asks Mosaic for (`vmem_limit_bytes`): the
+    plan's count and Mosaic's own share, never under the default.  That
+    share holds what Mosaic makes of a tile's operands and of the three
+    products before they are added, so it grows with a row's bytes:
+    `_VMEM_MOSAIC` up to 256 lanes of bf16 (512 bytes), and as many
+    times that as a wider row holds 512 bytes."""
+    row = _round_up(max(plan.d_block, plan.dv_block), _LANES) * itemsize
+    return max(_VMEM_BUDGET, _vmem_bytes("bwd", plan, itemsize)) \
+        + _VMEM_MOSAIC * max(1, row // 512)
 
 
 def _resident(n_sub, bytes_per_sub, budget):
@@ -249,31 +296,26 @@ def _resident(n_sub, bytes_per_sub, budget):
                if n_sub % c == 0 and c <= fit)
 
 
-def _kernel_tiles(kernels, subs, sq, sk, d_block, dv_block, itemsize, res_q,
+def _kernel_tiles(kernel, sub, sq, sk, d_block, dv_block, itemsize, res_q,
                   res_k):
-    """``(sq_padded, sk_padded, {kernel: _Tiles})`` for *kernels*, which
-    share their padded operands, with *subs* ``{kernel: (sub_q, sub_k)}``
+    """``(sq_padded, sk_padded, _Tiles)`` of *kernel* with sub-tile *sub*
     cut to the sequence: the resident blocks are the largest
-    `_VMEM_BUDGET` holds, the streamed side first (K/V for the forward
-    and dq, Q/dO for dk/dv)."""
-    sq_p = _round_up(sq, res_q or math.lcm(*(subs[k][0] for k in kernels)))
-    sk_p = _round_up(sk, res_k or math.lcm(*(subs[k][1] for k in kernels)))
-    tiles = {}
-    for kernel in kernels:
-        sub_q, sub_k = subs[kernel]
-        nq, nk = sq_p // sub_q, sk_p // sub_k
-        per_q, per_k = _side_bytes(kernel, d_block, dv_block, itemsize)
-        per_q, per_k = per_q * sub_q, per_k * sub_k
-        budget = _VMEM_BUDGET - _tile_bytes(sub_q, sub_k)
-        if kernel == "dkdv":
-            cq = _resident(nq, per_q, budget // 2)
-            ck = _resident(nk, per_k, budget - cq * per_q)
-        else:
-            ck = _resident(nk, per_k, budget // 2)
-            cq = _resident(nq, per_q, budget - ck * per_k)
-        tiles[kernel] = _Tiles(res_q or cq * sub_q, res_k or ck * sub_k,
-                               sub_q, sub_k)
-    return sq_p, sk_p, tiles
+    `_VMEM_BUDGET` holds, the streamed side first (K/V for the forward,
+    Q/dO for the backward)."""
+    sub_q, sub_k = sub
+    sq_p, sk_p = _round_up(sq, res_q or sub_q), _round_up(sk, res_k or sub_k)
+    nq, nk = sq_p // sub_q, sk_p // sub_k
+    per_q, per_k = _side_bytes(kernel, d_block, dv_block, itemsize)
+    per_q, per_k = per_q * sub_q, per_k * sub_k
+    budget = _VMEM_BUDGET - _tile_bytes(sub_q, sub_k)
+    if kernel == "bwd":
+        cq = _resident(nq, per_q, budget // 2)
+        ck = _resident(nk, per_k, budget - cq * per_q)
+    else:
+        ck = _resident(nk, per_k, budget // 2)
+        cq = _resident(nq, per_q, budget - ck * per_k)
+    return sq_p, sk_p, _Tiles(res_q or cq * sub_q, res_k or ck * sub_k,
+                              sub_q, sub_k)
 
 
 def _unrolls_whole(t, sq_p, sk_p):
@@ -285,18 +327,21 @@ def _unrolls_whole(t, sq_p, sk_p):
 
 def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
                 res_k=None, d_v=None):
-    """Tiles of the three kernels from what the call can see: the
+    """Tiles of the two kernels from what the call can see: the
     lengths, the head dim of q and k, that of v (*d_v*; *d* where not
     given) and the dtype.  One algorithm with different
     parameters at different shapes.  Where a head fits VMEM and its
     tiles are few enough to unroll whole (S = 2048 at d = 64), the
     sub-tile is `_SUB_UNROLLED`, the one closest to the causal triangle;
-    where the loops stay loops it is `_SUB_LOOPED`'s, wider on the
-    streamed side: fewer, larger iterations (both from the sweep).
+    where the loops stay loops it is `_SUB_LOOPED`, wider along the
+    keys: fewer, larger iterations (both from the sweep; the backward's
+    query edge has to stay at 256).
     `causal` is not an input: the same tiles serve both, the loops'
-    bounds differ.  *blk_q*, *blk_k* (sub-tile edges) and *res_q*,
-    *res_k* (resident rows; multiples of the sub-tile that divide the
-    padded length) override, for the tests and the sweep."""
+    bounds differ.  The backward keeps dq's accumulator in VMEM where a
+    head's whole sequence of it stays under `_VMEM_DQ`, else in HBM.
+    *blk_q*, *blk_k* (sub-tile edges) and *res_q*, *res_k* (resident
+    rows; multiples of the sub-tile that divide the padded length)
+    override, for the tests and the sweep."""
     itemsize = jnp.dtype(dtype).itemsize
     d_block = _d_block(d)
     dv_block = d_block if d_v is None else _d_block(d_v)
@@ -305,21 +350,20 @@ def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
         return (min(blk_q or sub[0], _round_up(sq, 1 if blk_q else _LANES)),
                 min(blk_k or sub[1], _round_up(sk, 1 if blk_k else _LANES)))
 
-    plan = {}
-    # the backward pair shares its padded copies of q, k, v and dO
-    for kernels in (("fwd",), ("dkdv", "dq")):
-        found = _kernel_tiles(
-            kernels, {k: cut(_SUB_UNROLLED) for k in kernels}, sq, sk,
-            d_block, dv_block, itemsize, res_q, res_k)
-        if not all(_unrolls_whole(t, *found[:2])
-                   for t in found[2].values()):
-            found = _kernel_tiles(
-                kernels, {k: cut(_SUB_LOOPED[k]) for k in kernels}, sq, sk,
-                d_block, dv_block, itemsize, res_q, res_k)
-        plan[kernels[0]] = found
-    (sq_f, sk_f, fwd), (sq_b, sk_b, bwd) = plan["fwd"], plan["dkdv"]
-    return _Plan(d_block, sq_f, sk_f, sq_b, sk_b, **fwd, **bwd,
-                 dv_block=dv_block)
+    found = {}
+    for kernel in _KERNELS:
+        found[kernel] = _kernel_tiles(
+            kernel, cut(_SUB_UNROLLED), sq, sk, d_block, dv_block, itemsize,
+            res_q, res_k)
+        if not _unrolls_whole(found[kernel][2], *found[kernel][:2]):
+            found[kernel] = _kernel_tiles(
+                kernel, cut(_SUB_LOOPED), sq, sk, d_block, dv_block,
+                itemsize, res_q, res_k)
+    (sq_f, sk_f, fwd), (sq_b, sk_b, bwd) = found["fwd"], found["bwd"]
+    dq_accumulator = "vmem" if _dq_bytes(
+        "vmem", sq_b, d_block, itemsize) <= _VMEM_DQ else "hbm"
+    return _Plan(d_block, sq_f, sk_f, sq_b, sk_b, fwd, bwd, dv_block,
+                 dq_accumulator)
 
 
 # The loop bounds below run on Python ints (the plan's counts) and on the
@@ -395,7 +439,7 @@ def _tile_counts(kernel, plan, sq, sk, causal):
     visited = masked = 0
     for q0 in range(0, sq_p, t.res_q):
         for k0 in range(0, sk_p, t.res_k):
-            if kernel == "dkdv":
+            if kernel == "bwd":
                 for jk in range(nks):
                     first, full = _q_tiles(k0 + jk * t.sub_k, q0, nqs, t,
                                            off, sk, causal)
@@ -423,13 +467,15 @@ def _plan_args(plan, sq, sk, d, dtype, causal, d_v=None):
     rec = {"sq": sq, "sk": sk, "d": d, "dtype": jnp.dtype(dtype).name,
            "causal": bool(causal), "d_block": plan.d_block,
            "d_v": d if d_v is None else d_v, "dv_block": plan.dv_block}
+    itemsize = jnp.dtype(dtype).itemsize
     for kernel in _KERNELS:
         t = getattr(plan, kernel)
         rec[kernel] = dict(
             _tile_counts(kernel, plan, sq, sk, causal),
             resident=[t.res_q, t.res_k], sub_tile=[t.sub_q, t.sub_k],
-            vmem_bytes=_vmem_bytes(kernel, t, plan.d_block, plan.dv_block,
-                                   jnp.dtype(dtype).itemsize))
+            vmem_bytes=_vmem_bytes(kernel, plan, itemsize))
+    rec["bwd"].update(dq_accumulator=plan.dq_accumulator,
+                      vmem_limit_bytes=_vmem_limit(plan, itemsize))
     return rec
 
 
@@ -743,19 +789,36 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash backward kernels (standard flash-attention backward:
-# recompute p from the saved logsumexp, accumulate dq / dk / dv tile by
-# tile; delta_i = rowsum(dO_i * O_i) precomputed at the XLA level).  lse
-# and delta enter compact, as rows of a (B*H, 1, seq_q) array.
+# Pallas flash backward kernel (the flash-attention backward: recompute p
+# from the saved logsumexp, accumulate dq / dk / dv tile by tile; delta_i
+# = rowsum(dO_i * O_i) precomputed at the XLA level).  lse and delta enter
+# compact, as rows of a (B*H, 1, seq_q) array.
+#
+# One kernel forms s, the mask, p, dP and ds once a visible score tile
+# and feeds three accumulating dots from them (two kernels would each
+# recompute the scores' half: 9 dots, two passes of exp and of the f32
+# chain, for 7 and one).  The scores stay transposed, (sub_k, sub_q):
+# the K/V block is the resident side and Q/dO stream past it, the
+# statistics broadcast along sublanes as the rows they are, and dv += p
+# dO and dk += ds q are plain a @ b.  dq accumulates over the other axis,
+# so no block-sized scratch can hold it: its f32 accumulator spans the
+# head's whole sequence in VMEM (`dq_accumulator` `vmem`), zeroed block
+# by block in the steps of the head's first K/V block and written out in
+# those of its last; dq's output block spans the sequence too, its index
+# constant over both inner grid axes.  Its dot is the one that needs a
+# score tile turned, ds^T k: XLU work that rides under the dots.  Where
+# a sequence is too long for that (`_VMEM_DQ`) each K/V block's share of
+# dq leaves as an f32 partial that XLA sums (`hbm`), a group of heads at
+# a time so that the partials in HBM stay bounded (`_HBM_DQ`).
 # ---------------------------------------------------------------------------
 
 def _wide(x, width):
-    """A (rows, d) operand of the backward kernels, zero-extended to the
+    """A (rows, d) operand of the backward kernel, zero-extended to the
     accumulators' 128-lane width inside VMEM.  At d = 64 the dots that
     contract over d or write d columns then run on whole lane tiles
-    (dk/dv 9% and dq 6% faster at S = 2048 than on 64-wide operands,
-    tools/flash_sweep.py; the forward measured flat and is left
-    narrow); HBM holds the 64 columns only.  Each operand is widened to
+    (the pair this kernel replaced: dk/dv 9% and dq 6% faster at S =
+    2048 than on 64-wide operands, tools/flash_sweep.py; the forward
+    measured flat and is left narrow); HBM holds the 64 columns only.  Each operand is widened to
     its own accumulator's lanes: q and k to dq's and dk's, v and dO to
     dv's (at 192 the second lane tile's zero half is written out, the
     lanes VMEM pads the block to anyway)."""
@@ -764,21 +827,35 @@ def _wide(x, width):
 
 
 @_traced_inline
-def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                           delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                           t, grid, sm_scale, causal, seq_q, seq_k,
-                           padded_k):
-    """K/V block resident, Q/dO sub-tiles from the first visible row on.
-    The scores are computed transposed, (sub_k, sub_q): the statistics
-    then broadcast along sublanes as the rows they are, and both
-    accumulating dots are plain a @ b (p^T dO, ds^T q) with no transpose
-    of a score tile."""
-    nqr = grid[0]
-    ik, iq = _grid_pos(1, grid[1]), _grid_pos(2, nqr)
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, dq_acc=None,
+                      *, t, grid, sm_scale, causal, seq_q, seq_k,
+                      padded_k):
+    """K/V block resident, Q/dO sub-tiles from the first visible row on;
+    *dq_acc* spans the head's sequence, and without it this step's share
+    of dq accumulates in its f32 output block."""
+    nqr, nkr = grid
+    ik, iq = _grid_pos(1, nkr), _grid_pos(2, nqr)
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
     off = seq_k - seq_q
     d, wide = dk_ref.shape[2], dk_acc.shape[1]
     d_v, wide_v = dv_ref.shape[2], dv_acc.shape[1]
+
+    if dq_acc is None:
+        dq_ref[0, 0] = jnp.zeros(dq_ref.shape[2:], jnp.float32)
+
+        def dq_add(jq, x):
+            dq_ref[0, 0, _sub(jq, t.sub_q, nqs), :] += x[:, :d]
+    else:
+        # this query block's rows of the head's accumulator
+        rows = _sub(iq, t.res_q, nqr)
+
+        @pl.when(ik == 0)
+        def _init_dq():
+            dq_acc[rows, :] = jnp.zeros((t.res_q, wide), jnp.float32)
+
+        def dq_add(jq, x):
+            dq_acc[_sub(iq * nqs + jq, t.sub_q, nqr * nqs), :] += x
 
     def k_tile(jk):
         ks = _sub(jk, t.sub_k, nks)
@@ -801,12 +878,18 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                     s.shape, 1, iq * t.res_q + jq * t.sub_q, col0, off,
                     seq_k, causal, padded_k), s, _NEG_INF)
             p = jnp.exp(s - lse_ref[0, :, qs])
-            # dv += p^T dO — p cast to the storage dtype for a full-rate
-            # MXU dot; accumulators stay f32
+            # dv += p dO (the tile is p^T as the forward knew it) — p cast
+            # to the storage dtype for a full-rate MXU dot; accumulators
+            # stay f32
             dv_acc[ks, :] += _mxu_dot(p.astype(do.dtype), do, _NN)
-            # ds = p * (dO v^T - delta) * scale;  dk += ds^T q
+            # ds = p * (dO v^T - delta) * scale;  dk += ds q;  dq += ds^T k
             ds = p * (_mxu_dot(v, do, _NT) - delta_ref[0, :, qs]) * sm_scale
             dk_acc[ks, :] += _mxu_dot(ds.astype(q.dtype), q, _NN)
+            # ds is turned in f32 and cast after: the XLU transposes
+            # 32-bit tiles as they are, a bf16 tile it has to unpack (what
+            # Mosaic makes of a dot that contracts ds over its first axis:
+            # 8% slower at S = 2048, tools/flash_sweep.py)
+            dq_add(jq, _mxu_dot(ds.T.astype(k.dtype), k, _NN))
 
         j_first, j_full = _q_tiles(col0, iq * t.res_q, nqs, t, off, seq_k,
                                    causal)
@@ -819,56 +902,24 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     _loop(0, nks, k_tile)
 
-
-@_traced_inline
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_acc, *, t, grid, sm_scale, causal,
-                         seq_q, seq_k, padded_k):
-    """Q/dO block resident, K/V sub-tiles up to the causal limit."""
-    nkr = grid[1]
-    iq, ik = _grid_pos(1, grid[0]), _grid_pos(2, nkr)
-    nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
-    off = seq_k - seq_q
-    d, wide = dq_ref.shape[2], dq_acc.shape[1]
-    wide_v = _round_up(v_ref.shape[2], _LANES)
-
-    def q_tile(jq):
-        qs = _sub(jq, t.sub_q, nqs)
-        row0 = iq * t.res_q + jq * t.sub_q
-        q = _wide(q_ref[0, qs, :], wide)
-        do = _wide(do_ref[0, qs, :], wide_v)
-        # the statistics' rows as columns, widened once per query tile
-        shape = (t.sub_q, t.sub_k)
-        lse = jnp.broadcast_to(lse_ref[0, :, qs].reshape(t.sub_q, 1), shape)
-        delta = jnp.broadcast_to(delta_ref[0, :, qs].reshape(t.sub_q, 1),
-                                 shape)
-
-        @pl.when(ik == 0)
-        def _init():
-            dq_acc[qs, :] = jnp.zeros((t.sub_q, wide), jnp.float32)
-
-        def tile(jk, masked):
-            ks = _sub(jk, t.sub_k, nks)
-            k = _wide(k_ref[0, ks, :], wide)
-            v = _wide(v_ref[0, ks, :], wide_v)
-            s = _mxu_dot(q, k, _NT) * sm_scale      # (sub_q, sub_k)
-            if masked:
-                s = jnp.where(_tile_mask(
-                    s.shape, 0, row0, ik * t.res_k + jk * t.sub_k, off,
-                    seq_k, causal, padded_k), s, _NEG_INF)
-            p = jnp.exp(s - lse)
-            ds = p * (_mxu_dot(do, v, _NT) - delta) * sm_scale
-            dq_acc[qs, :] += _mxu_dot(ds.astype(k.dtype), k, _NN)
-
-        n_full, n_vis = _k_tiles(row0, ik * t.res_k, nks, t, off, seq_k,
-                                 causal)
-        _two_loops((0, n_full, n_vis, False), tile, causal or padded_k)
-
+    if dq_acc is not None:
         @pl.when(ik == nkr - 1)
-        def _finish():
-            dq_ref[0, qs, :] = dq_acc[qs, :d].astype(dq_ref.dtype)
+        def _finish_dq():
+            dq_ref[0, rows, :] = dq_acc[rows, :d].astype(dq_ref.dtype)
 
-    _loop(0, nqs, q_tile)
+
+def _dq_head_groups(bh, partial_bytes, sq):
+    """How many of the *bh* heads go through the backward call together
+    where dq leaves as partials of *partial_bytes* a head: the largest
+    divisor of *bh* whose partials `_HBM_DQ` holds."""
+    if partial_bytes > _HBM_DQ:
+        raise ValueError(
+            "flash attention backward: a query sequence of %d is too long: "
+            "dq's f32 partials of one head would take %.1f GiB of HBM "
+            "(limit %.1f); split the sequence (parallel.sequence)"
+            % (sq, partial_bytes / 2 ** 30, _HBM_DQ / 2 ** 30))
+    return max(g for g in range(1, bh + 1)
+               if bh % g == 0 and g * partial_bytes <= _HBM_DQ)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -876,12 +927,13 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
                       blk_q=None, blk_k=None, interpret=False, res_q=None,
                       res_k=None):
     """dq, dk, dv from the forward's output and its ``(B*H, 1, seq_q)``
-    logsumexp."""
+    logsumexp: grid (B*H, resident k blocks, resident q blocks), K/V
+    resident and Q/dO streamed."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
     plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v)
-    dp, dvp, sq_p, sk_p = plan.d_block, plan.dv_block, plan.sq_bwd, \
-        plan.sk_bwd
+    t, dp, dvp = plan.bwd, plan.d_block, plan.dv_block
+    sq_p, sk_p = plan.sq_bwd, plan.sk_bwd
     # the accumulators' lanes
     wide, wide_v = _round_up(dp, _LANES), _round_up(dvp, _LANES)
     qp = _pad_bh(q, sq_p, dp)
@@ -896,11 +948,6 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
     if sq_p != sq:
         lse = jnp.pad(lse, ((0, 0), (0, 0), (0, sq_p - sq)))
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, sq_p - sq)))
-    common = dict(sm_scale=sm_scale, causal=causal, seq_q=sq, seq_k=sk,
-                  padded_k=sk_p != sk)
-
-    # dk/dv: grid (bh, k blocks, q blocks) — k resident, q streamed
-    t = plan.dkdv
     nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
 
     def q_index(bh_, ik, iq):
@@ -910,45 +957,59 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
 
     q_spec, k_spec, do_spec, v_spec, row_spec = _block_specs(
         t, dp, dvp, q_index, lambda bh_, ik, iq: ik)
-    with jax.named_scope("mx.flash.dkdv"):
-        dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkdv_kernel, t=t, grid=(nqr, nkr),
-                              **common),
-            grid=(bh, nkr, nqr),
-            in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
-            out_specs=[k_spec, v_spec],
-            out_shape=[jax.ShapeDtypeStruct((bh, sk_p, dp), k.dtype),
-                       jax.ShapeDtypeStruct((bh, sk_p, dvp), v.dtype)],
-            scratch_shapes=[pltpu.VMEM((t.res_k, wide), jnp.float32),
-                            pltpu.VMEM((t.res_k, wide_v), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-            name="mx_flash_dkdv",
-        )(qp, kp, vp, dop, lse, delta)
+    scratch = [pltpu.VMEM((t.res_k, wide), jnp.float32),
+               pltpu.VMEM((t.res_k, wide_v), jnp.float32)]
+    in_vmem = plan.dq_accumulator == "vmem"
+    if in_vmem:
+        # the whole head's dq: fetched never, written back once a head
+        heads = bh
+        dq_spec = pl.BlockSpec((1, sq_p, dp), lambda bh_, ik, iq: (bh_, 0, 0))
+        dq_shape = jax.ShapeDtypeStruct((heads, sq_p, dp), q.dtype)
+        scratch.append(pltpu.VMEM((sq_p, wide), jnp.float32))
+    else:
+        # every step writes its block, the empty ones zeros
+        heads = _dq_head_groups(bh, nkr * sq_p * dp * 4, sq)
+        dq_spec = pl.BlockSpec((1, 1, t.res_q, dp),
+                               lambda bh_, ik, iq: (bh_, ik, iq, 0))
+        dq_shape = jax.ShapeDtypeStruct((heads, nkr, sq_p, dp), jnp.float32)
 
-    # dq: grid (bh, q blocks, k blocks) — q resident, k streamed
-    t = plan.dq
-    nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
+    def call(operands):
+        """The kernel over *heads* heads (axis 0 of every operand)."""
+        with jax.named_scope("mx.flash.bwd"):
+            dq, dk, dv = pl.pallas_call(
+                functools.partial(
+                    _flash_bwd_kernel, t=t, grid=(nqr, nkr),
+                    sm_scale=sm_scale, causal=causal, seq_q=sq, seq_k=sk,
+                    padded_k=sk_p != sk),
+                grid=(heads, nkr, nqr),
+                in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec,
+                          row_spec],
+                out_specs=[dq_spec, k_spec, v_spec],
+                out_shape=[dq_shape,
+                           jax.ShapeDtypeStruct((heads, sk_p, dp), k.dtype),
+                           jax.ShapeDtypeStruct((heads, sk_p, dvp),
+                                                v.dtype)],
+                scratch_shapes=scratch,
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel", "arbitrary",
+                                         "arbitrary"),
+                    vmem_limit_bytes=_vmem_limit(plan, q.dtype.itemsize)),
+                interpret=interpret,
+                name="mx_flash_bwd",
+            )(*operands)
+        if not in_vmem:
+            dq = dq.sum(axis=1).astype(q.dtype)
+        return dq, dk, dv
 
-    q_spec, k_spec, do_spec, v_spec, row_spec = _block_specs(
-        t, dp, dvp, lambda bh_, iq, ik: iq,
-        _k_index(t, nkr, sk - sq, causal))
-    with jax.named_scope("mx.flash.dq"):
-        dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, t=t, grid=(nqr, nkr),
-                              **common),
-            grid=(bh, nqr, nkr),
-            in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct((bh, sq_p, dp), q.dtype),
-            scratch_shapes=[pltpu.VMEM((t.res_q, wide), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-            name="mx_flash_dq",
-        )(qp, kp, vp, dop, lse, delta)
-
+    operands = (qp, kp, vp, dop, lse, delta)
+    if heads == bh:
+        dq, dk, dv = call(operands)
+    else:
+        # one group's partials at a time: the loop's buffer is reused
+        dq, dk, dv = (
+            x.reshape((bh,) + x.shape[2:]) for x in jax.lax.map(call, tuple(
+                x.reshape((bh // heads, heads) + x.shape[1:])
+                for x in operands)))
     return (_unpad_bh(dq, b, h, sq, d), _unpad_bh(dk, b, h, sk, d),
             _unpad_bh(dv, b, h, sk, d_v))
 

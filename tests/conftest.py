@@ -75,3 +75,23 @@ def _seed_rng():
     mx.random.seed(0)
     np.random.seed(0)
     yield
+
+
+@pytest.fixture
+def dq_accumulates_in():
+    """``place("hbm")``: the flash backward as a sequence too long for
+    dq's VMEM accumulator gets it (`ops/attention.py` `_VMEM_DQ`), at a
+    test's size; ``place("vmem")`` leaves the plan alone.  The choice is
+    the plan's, from shapes: no argument reaches it."""
+    from mxnet_tpu.ops import attention
+    patch = pytest.MonkeyPatch()
+
+    def place(where):
+        assert where in ("vmem", "hbm")
+        if where == "hbm":
+            patch.setattr(attention, "_VMEM_DQ", 0)
+            attention._flash_bwd_pallas.clear_cache()
+
+    yield place
+    patch.undo()
+    attention._flash_bwd_pallas.clear_cache()

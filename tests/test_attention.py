@@ -318,6 +318,51 @@ def test_pallas_flash_backward_interpret(causal, sq, sk, tiles):
     _check_flash_against_reference(q, k, v, causal, 2e-5, 2e-4, **tiles)
 
 
+# dq accumulates across grid steps: several K/V blocks a head, so that
+# each adds its share to the rows of query blocks it has seen before
+_SEVERAL_KV_BLOCKS = [
+    (64, 64, dict(blk_q=16, blk_k=16, res_q=32, res_k=16)),
+    (64, 64, dict(blk_q=16, blk_k=8, res_q=64, res_k=16)),
+    (40, 56, dict(blk_q=8, blk_k=8, res_q=8, res_k=8)),         # sq < sk
+    (44, 100, dict(blk_q=16, blk_k=16, res_q=16, res_k=32)),    # padded
+    (48, 16, dict(blk_q=16, blk_k=8, res_q=16, res_k=8)),       # sq > sk
+]
+
+
+@pytest.mark.parametrize("dq_accumulator", ["vmem", "hbm"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("sq,sk,tiles", _SEVERAL_KV_BLOCKS)
+def test_flash_backward_dq_in_either_place_interpret(causal, sq, sk, tiles,
+                                                     dq_accumulator,
+                                                     dq_accumulates_in):
+    """dq's accumulator over the head's whole sequence in VMEM, and the
+    fallback of a sequence too long for that, f32 partials a K/V block
+    that XLA sums (the plan's choice at a `_VMEM_DQ` this size would
+    pass): the same three gradients either way."""
+    from mxnet_tpu.ops.attention import _flash_plan
+    assert _flash_plan(sq, sk, 16, jnp.float32, **tiles).dq_accumulator \
+        == "vmem"                               # at the module's constant
+    dq_accumulates_in(dq_accumulator)
+    plan = _flash_plan(sq, sk, 16, jnp.float32, **tiles)
+    assert plan.sk_bwd // plan.bwd.res_k > 1
+    assert plan.dq_accumulator == dq_accumulator
+    q, k, v = _rand_qkv(b=1, h=2, sq=sq, sk=sk, d=16)
+    _check_flash_against_reference(q, k, v, causal, 2e-5, 2e-4, **tiles)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("sq,sk", [(2048, 2048), (300, 2048), (1000, 1000)])
+def test_flash_backward_whole_head_unrolled_d64_interpret(causal, sq, sk):
+    """The plan's own tiles at the LM cell's width: one grid step a head,
+    every loop unrolled; `sq == sk`, `sq < sk`, and a padded length."""
+    from mxnet_tpu.ops.attention import _flash_plan, _unrolls_whole
+    plan = _flash_plan(sq, sk, 64, jnp.bfloat16)
+    assert _unrolls_whole(plan.bwd, plan.sq_bwd, plan.sk_bwd)
+    q, k, v = (x.astype(jnp.bfloat16)
+               for x in _rand_qkv(b=1, h=1, sq=sq, sk=sk, d=64))
+    _check_flash_against_reference(q, k, v, causal, 5e-2, 1.5e-1)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("d", [64, 80, 128])
@@ -399,8 +444,18 @@ def test_flash_plan_stays_inside_vmem_and_near_the_triangle(sq, sk, d,
     plan = _flash_plan(sq, sk, d, dtype)
     rec = _plan_args(plan, sq, sk, d, dtype, True)
     assert rec["d_block"] == d
+    from mxnet_tpu.ops import attention as A
+    # the forward inside Mosaic's default 16 MiB; the backward's blocks
+    # and tile inside the same budget, its dq side inside `_VMEM_DQ`, and
+    # what its call asks for no more than those and Mosaic's share
+    assert rec["fwd"]["vmem_bytes"] <= A._VMEM_BUDGET < 16 << 20
+    bwd, itemsize = rec["bwd"], jnp.dtype(dtype).itemsize
+    dq = A._dq_bytes("vmem", plan.sq_bwd, plan.d_block, itemsize)
+    assert bwd["dq_accumulator"] == "vmem" and dq <= A._VMEM_DQ
+    assert bwd["vmem_bytes"] - dq <= A._VMEM_BUDGET
+    assert bwd["vmem_bytes"] + A._VMEM_MOSAIC <= bwd["vmem_limit_bytes"] \
+        <= A._VMEM_BUDGET + A._VMEM_DQ + A._VMEM_MOSAIC == 64 << 20
     for kernel in _KERNELS:
-        assert rec[kernel]["vmem_bytes"] < 16 << 20, kernel
         if sq == sk:
             assert rec[kernel]["tiles_visited"] <= \
                 _TRIANGLE_SLACK * rec[kernel]["tiles_ideal"], kernel
@@ -433,16 +488,20 @@ def test_flash_plan_is_recorded_once_per_traced_call():
     args = first[0].args
     assert (args["sq"], args["sk"], args["d"], args["causal"]) == \
         (32, 32, 64, True)
-    for kernel in ("fwd", "dkdv", "dq"):
+    for kernel in ("fwd", "bwd"):
         assert set(args[kernel]) >= {"tiles_visited", "tiles_masked",
-                                     "tiles_ideal", "resident", "sub_tile"}
+                                     "tiles_ideal", "resident", "sub_tile",
+                                     "vmem_bytes"}
+    assert not {"dkdv", "dq"} & set(args)
+    assert args["bwd"]["dq_accumulator"] == "vmem"
+    assert args["bwd"]["vmem_limit_bytes"] >= args["bwd"]["vmem_bytes"]
     assert args["d_block"] == 64
     f(q, k, v).block_until_ready()
     assert len([s for s in profiler.spans(since=t0)
                 if s.name == "mx.flash.plan"]) == 1
 
 
-def test_traced_training_step_holds_exactly_the_three_flash_scopes():
+def test_traced_training_step_holds_exactly_the_two_flash_scopes():
     import re
     aval = jax.ShapeDtypeStruct((2, 4, 2048, 64), jnp.bfloat16)
 
@@ -453,13 +512,47 @@ def test_traced_training_step_holds_exactly_the_three_flash_scopes():
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
         aval, aval, aval).lower(lowering_platforms=("tpu",)).as_text(
             debug_info=True)
-    assert set(re.findall(r"mx\.flash\.(\w+)", text)) == \
-        {"fwd", "dkdv", "dq"}
+    assert set(re.findall(r"mx\.flash\.(\w+)", text)) == {"fwd", "bwd"}
     assert set(re.findall(r"mx_flash_\w+", text)) == \
-        {"mx_flash_fwd", "mx_flash_dkdv", "mx_flash_dq"}
-    assert text.count("tpu_custom_call") == 3
+        {"mx_flash_fwd", "mx_flash_bwd"}
+    assert text.count("tpu_custom_call") == 2
     # no head-dim padding and no lane-replicated statistics around them
     assert "x128xbf16" not in text and "x2048x128xf32" not in text
+
+
+@pytest.mark.parametrize("s,d,d_v,sha", [
+    (2048, 64, 64,
+     "c90fe3b230b0e563445ae24e8e0b1cb912c1359ba1d4c947bb5f6fa50e8d19b0"),
+    (8192, 64, 64,
+     "3f340183c39ead4ebe8801fe8e998d129fde443d75ca807321f6cf62b5792909"),
+    (8192, 192, 128,
+     "32fe50cfa89e3417db19a0f7682f539741c0f60d15b8fa25d3d081a85b14a789"),
+])
+def test_the_forward_kernel_s_jaxpr_is_the_one_before_the_fused_backward(
+        s, d, d_v, sha, tmp_path):
+    """The forward call at the three LM cells' shapes, as text (source
+    locations cut), is commit 4c772db's to the letter: what changes the
+    backward leaves `mx_flash_fwd` the program it was.  The hashes are
+    that commit's (PR 31's evidence for its unchanged forward); a change
+    to the forward that an issue asks for, or a JAX that prints jaxprs
+    another way, re-pins them: on a mismatch the text is written out to
+    be set beside `git show 4c772db:mxnet_tpu/ops/attention.py`'s."""
+    import hashlib
+    import re
+    q = jax.ShapeDtypeStruct((1, 2, s, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 2, s, d_v), jnp.bfloat16)
+    text = str(jax.make_jaxpr(lambda q, k, v: _flash_fwd_pallas(
+        q, k, v, True, d ** -0.5, with_lse=True))(q, q, v))
+    assert "mx_flash_fwd" in text
+    text = re.sub(r" at \S+:\d+", "", text)
+    got = hashlib.sha256(text.encode()).hexdigest()
+    if got != sha:
+        path = tmp_path / ("mx_flash_fwd_%d_%d_%d.jaxpr.txt" % (s, d, d_v))
+        path.write_text(text)
+        pytest.fail(
+            "the forward kernel's jaxpr at (%d, %d / %d) is no longer commit "
+            "4c772db's: sha256 %s, %d lines, written to %s (jax %s)"
+            % (s, d, d_v, got, text.count("\n"), path, jax.__version__))
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -484,7 +577,7 @@ def test_flash_lowers_for_tpu_without_a_chip(causal):
         return text.count("tpu_custom_call")
 
     assert calls(fwd) == 1
-    assert calls(jax.grad(loss, argnums=(0, 1, 2))) == 3   # fwd, dkdv, dq
+    assert calls(jax.grad(loss, argnums=(0, 1, 2))) == 2   # fwd, bwd
 
 
 def test_flash_under_a_mesh_runs_per_shard():

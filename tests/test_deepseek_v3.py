@@ -437,20 +437,29 @@ def out_and_grads(fn, q, k, v, w):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("d,d_v,sq,sk,tiles", [
-    (192, 128, 40, 56, dict(blk_q=16, blk_k=16, res_k=32)),
-    (192, 128, 24, 40, {}),                 # the plan's own tiles
-    (24, 16, 40, 56, dict(blk_q=16, blk_k=16)),     # both padded to 128
-    (24, 16, 44, 20, dict(blk_q=8, blk_k=8, res_q=16, res_k=8)),
-    (64, 128, 160, 160, dict(blk_q=16, blk_k=16)),  # v the wider; loops
+@pytest.mark.parametrize("d,d_v,sq,sk,tiles,dq", [
+    (192, 128, 40, 56, dict(blk_q=16, blk_k=16, res_k=32), "vmem"),
+    (192, 128, 24, 40, {}, "vmem"),         # the plan's own tiles
+    (24, 16, 40, 56, dict(blk_q=16, blk_k=16), "vmem"),  # padded to 128
+    (24, 16, 44, 20, dict(blk_q=8, blk_k=8, res_q=16, res_k=8), "vmem"),
+    (64, 128, 160, 160, dict(blk_q=16, blk_k=16), "vmem"),  # v the wider
+    # several K/V blocks: dq accumulates across grid steps, in VMEM ...
+    (192, 128, 64, 64, dict(blk_q=16, blk_k=16, res_q=32, res_k=16), "vmem"),
+    (192, 128, 40, 100, dict(blk_q=8, blk_k=16, res_q=8, res_k=32), "vmem"),
+    # ... and as partials in HBM
+    (192, 128, 64, 64, dict(blk_q=16, blk_k=16, res_q=32, res_k=16), "hbm"),
+    (24, 16, 44, 20, dict(blk_q=8, blk_k=8, res_q=16, res_k=8), "hbm"),
 ])
-def test_flash_kernels_at_two_widths_interpret(d, d_v, sq, sk, tiles,
-                                               causal):
+def test_flash_kernels_at_two_widths_interpret(d, d_v, sq, sk, tiles, dq,
+                                               causal, dq_accumulates_in):
     """Forward and the three gradients of the Pallas kernels (interpret
     mode) with keys *d* wide and values *d_v* wide against
     `attention_reference`."""
     q, k, v, w = qkv(1, 2, sq, sk, d, d_v)
     scale = d ** -0.5
+    dq_accumulates_in(dq)
+    assert attention._flash_plan(sq, sk, d, q.dtype, d_v=d_v,
+                                 **tiles).dq_accumulator == dq
 
     def flash(q, k, v):
         out = attention._flash_fwd_pallas(q, k, v, causal, scale,
@@ -497,27 +506,177 @@ _TILES = attention._Tiles
 
 def test_the_plan_at_one_width_is_the_parent_s_written_out():
     """OPT-1.3B's and LFM2's shapes (d = 64, bf16) plan as they did before
-    the kernels took a second width: the values of commit 889eb0f."""
+    the kernels took a second width: the values of commit 889eb0f, the
+    one backward kernel at the resident blocks dk/dv had (its looped
+    sub-tile is 256 query columns by 512 key rows: the sweep's)."""
     plan = attention._flash_plan(2048, 2048, 64, jnp.bfloat16)
     whole = _TILES(2048, 2048, 256, 256)
-    assert plan[:8] == (64, 2048, 2048, 2048, 2048, whole, whole, whole)
+    assert plan[:7] == (64, 2048, 2048, 2048, 2048, whole, whole)
     plan = attention._flash_plan(8192, 8192, 64, jnp.bfloat16)
-    assert plan[:8] == (64, 8192, 8192, 8192, 8192,
+    assert plan[:7] == (64, 8192, 8192, 8192, 8192,
                         _TILES(1024, 4096, 256, 512),
-                        _TILES(4096, 1024, 512, 256),
-                        _TILES(2048, 4096, 256, 512))
+                        _TILES(4096, 1024, 256, 512))
     for s in (2048, 8192):
         one = attention._flash_plan(s, s, 64, jnp.bfloat16)
-        assert one.dv_block == 64
+        assert (one.dv_block, one.dq_accumulator) == (64, "vmem")
         assert one == attention._flash_plan(s, s, 64, jnp.bfloat16, d_v=64)
-    # ... and so do the bytes the plan's model asks for
-    for kernel, want in (("fwd", (2624, 1024)), ("dkdv", (1152, 3072)),
-                         ("dq", (2176, 1024))):
+    # ... and so do the bytes the plan's model asks for a resident row
+    for kernel, want in (("fwd", (2624, 1024)), ("bwd", (1152, 3072))):
         assert attention._side_bytes(kernel, 64, 64, 2) == want, kernel
     # 192 wide takes two lane tiles in VMEM, like 256
-    for kernel, want in (("fwd", (4160, 2048)), ("dkdv", (2176, 6144)),
-                         ("dq", (4224, 2048))):
+    for kernel, want in (("fwd", (4160, 2048)), ("bwd", (2176, 6144))):
         assert attention._side_bytes(kernel, 192, 192, 2) == want, kernel
+
+
+@pytest.mark.parametrize("s,d,d_v,blocks,dq,limit", [
+    # OPT-1.3B: the whole head, inside Mosaic's default 16 MiB
+    (2048, 64, 64, 8.25, 2, 16),
+    # LFM2: 4096 query rows stream past 1024 resident key rows
+    (8192, 64, 64, 7.5, 8, 22.5),
+    # Kanana: 2048 past 1024, at 192 / 128
+    (8192, 192, 128, 7.75, 16, 30.75),
+])
+def test_the_backward_s_vmem_is_counted_and_asked_for(s, d, d_v, blocks, dq,
+                                                      limit):
+    """The one backward kernel's VMEM by the plan's model, in MiB at the
+    three LM cells' shapes: its resident blocks and a tile's temporaries
+    inside `_VMEM_BUDGET` as ever, dq's f32 accumulator and output block
+    over the head's whole sequence beside them, and the call's
+    `vmem_limit_bytes` that sum and Mosaic's own share."""
+    MiB = 1 << 20
+    plan = attention._flash_plan(s, s, d, jnp.bfloat16, d_v=d_v)
+    t = plan.bwd
+    per_q, per_k = attention._side_bytes("bwd", plan.d_block, plan.dv_block,
+                                         2)
+    tile = attention._tile_bytes(t.sub_q, t.sub_k)
+    assert per_q * t.res_q + per_k * t.res_k == blocks * MiB
+    assert blocks * MiB + tile <= attention._VMEM_BUDGET
+    assert plan.dq_accumulator == "vmem"
+    assert attention._dq_bytes("vmem", s, plan.d_block, 2) == dq * MiB
+    need = attention._vmem_bytes("bwd", plan, 2)
+    assert need == blocks * MiB + tile + dq * MiB
+    rec = attention._plan_args(plan, s, s, d, jnp.bfloat16, True, d_v)
+    assert rec["bwd"]["vmem_bytes"] == need
+    assert rec["bwd"]["vmem_limit_bytes"] == limit * MiB >= need
+    assert rec["bwd"]["dq_accumulator"] == "vmem"
+    # ... and the limit is the one the call hands Mosaic
+    text = str(jax.make_jaxpr(lambda q, k, v, o, lse, do:
+                              attention._flash_bwd_pallas(
+                                  q, k, v, o, lse, do, True, 0.125))(
+        *(jax.ShapeDtypeStruct((1, 1, s, w), jnp.bfloat16)
+          for w in (d, d, d_v, d_v)),
+        jax.ShapeDtypeStruct((1, 1, s), jnp.float32),
+        jax.ShapeDtypeStruct((1, 1, s, d_v), jnp.bfloat16)))
+    assert "vmem_limit_bytes=%d" % (limit * MiB) in text
+
+
+def test_a_sequence_too_long_for_the_accumulator_leaves_dq_in_hbm():
+    """From shapes alone: where dq's accumulator and output block over
+    the whole sequence would pass `_VMEM_DQ`, each K/V block's share
+    leaves as an f32 partial, and the call asks for its blocks alone."""
+    long = attention._flash_plan(65536, 65536, 128, jnp.bfloat16)
+    assert attention._dq_bytes("vmem", 65536, 128, 2) > attention._VMEM_DQ
+    assert long.dq_accumulator == "hbm"
+    rec = attention._plan_args(long, 65536, 65536, 128, jnp.bfloat16, True)
+    # 7.5 MiB of blocks, 3 of a tile's temporaries, 4 of a partial's
+    assert rec["bwd"]["vmem_bytes"] == attention._vmem_bytes(
+        "bwd", long, 2) == 14.5 * (1 << 20)
+    assert rec["bwd"]["vmem_limit_bytes"] == 18.5 * (1 << 20)
+    half = attention._flash_plan(32768, 32768, 128, jnp.bfloat16)
+    assert half.dq_accumulator == "vmem"
+    assert half.bwd == long.bwd         # the same tiles either way
+    # the longest the accumulator holds at this width: 48 MiB exactly
+    most = attention._flash_plan(49152, 49152, 128, jnp.bfloat16)
+    assert most.dq_accumulator == "vmem"
+    assert attention._dq_bytes("vmem", 49152, 128, 2) == attention._VMEM_DQ
+
+
+@pytest.mark.parametrize("s,d,d_v,dtype,limit_mib", [
+    (2048, 64, 64, "bfloat16", 16), (8192, 64, 64, "bfloat16", 22.5),
+    (8192, 192, 128, "bfloat16", 30.75),
+    # the longest sequence whose accumulator `_VMEM_DQ` holds, by width
+    (49152, 128, 128, "bfloat16", 64), (24576, 256, 256, "bfloat16", 62.25),
+    (12288, 512, 512, "bfloat16", 69.125), (32768, 128, 128, "float32", 61.75),
+    (16384, 256, 256, "float32", 68.125), (8192, 512, 512, "float32", 81.0625),
+    # past it
+    (65536, 128, 128, "bfloat16", 18.5), (131072, 64, 64, "bfloat16", 18.5),
+])
+def test_the_backward_never_asks_for_more_vmem_than_its_three_shares(
+        s, d, d_v, dtype, limit_mib):
+    """An absolute cap, whatever the shape: blocks and a tile's
+    temporaries inside `_VMEM_BUDGET` (unless one sub-tile of keys, the
+    least a block holds, is more), dq's accumulator inside `_VMEM_DQ`
+    (or a partial's block), Mosaic's share, which grows with a row's
+    bytes past 256 lanes of bf16; two thirds of the chip's 128 MiB at
+    the widest, half at the cells' widths."""
+    MiB = 1 << 20
+    plan = attention._flash_plan(s, s, d, dtype, d_v=d_v)
+    itemsize = jnp.dtype(dtype).itemsize
+    t = plan.bwd
+    per_q, per_k = attention._side_bytes("bwd", plan.d_block, plan.dv_block,
+                                         itemsize)
+    blocks = per_q * t.res_q + per_k * t.res_k \
+        + attention._tile_bytes(t.sub_q, t.sub_k)
+    if t.res_k > t.sub_k:
+        assert blocks <= attention._VMEM_BUDGET
+    else:       # one sub-tile of keys is the least a block holds
+        assert blocks <= attention._VMEM_BUDGET + per_k * t.sub_k
+    need = attention._vmem_bytes("bwd", plan, itemsize)
+    limit = attention._vmem_limit(plan, itemsize)
+    assert limit == limit_mib * MiB
+    mosaic = attention._VMEM_MOSAIC * max(1, d * itemsize // 512)
+    assert need + mosaic <= limit
+    assert limit <= max(attention._VMEM_BUDGET, blocks) \
+        + attention._VMEM_DQ + mosaic <= 84 * MiB
+    if d * itemsize <= 512:
+        assert limit <= 64 * MiB
+    if plan.dq_accumulator == "hbm":
+        assert limit <= 20 * MiB
+
+
+def test_dq_s_partials_in_hbm_are_bounded_a_group_of_heads_at_a_time():
+    """The fallback's HBM: `nkr` f32 copies of a head's dq, so the heads
+    go through the kernel in the largest groups whose partials `_HBM_DQ`
+    holds, and a sequence whose one head passes it is refused by name."""
+    GiB = 1 << 30
+    assert attention._HBM_DQ == 2 * GiB
+    # S 65536 at d 128: 64 K/V blocks x 32 MiB, one head at a time
+    assert attention._dq_head_groups(32, 2 * GiB, 65536) == 1
+    assert attention._dq_head_groups(32, GiB // 4, 65536) == 8
+    assert attention._dq_head_groups(12, GiB // 2, 65536) == 4
+    assert attention._dq_head_groups(6, 1 << 20, 4096) == 6
+    with pytest.raises(ValueError, match="131072 is too long.*8.0 GiB"):
+        attention._dq_head_groups(32, 8 * GiB, 131072)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("heads_a_call", [1, 2, 6])
+def test_dq_from_partials_is_the_same_whatever_the_groups(
+        heads_a_call, causal, dq_accumulates_in, monkeypatch):
+    """Six heads through the `hbm` fallback one, two and six a call (the
+    first two as a loop over groups): dq, dk, dv against the oracle."""
+    sq = sk = 64
+    tiles = dict(blk_q=16, blk_k=16, res_q=32, res_k=16)
+    dq_accumulates_in("hbm")
+    plan = attention._flash_plan(sq, sk, 24, jnp.float32, d_v=16, **tiles)
+    partial = (plan.sk_bwd // plan.bwd.res_k) * plan.sq_bwd \
+        * plan.d_block * 4
+    monkeypatch.setattr(attention, "_HBM_DQ", heads_a_call * partial)
+    assert attention._dq_head_groups(6, partial, sq) == heads_a_call
+    q, k, v, w = qkv(2, 3, sq, sk, 24, 16)
+    with jax.default_matmul_precision("highest"):
+        _, want = out_and_grads(lambda q, k, v: attention.attention_reference(
+            q, k, v, causal, 0.2), q, k, v, w)
+        out, lse = attention._flash_fwd_pallas(
+            q, k, v, causal, 0.2, interpret=True, with_lse=True, **tiles)
+        got = attention._flash_bwd_pallas(q, k, v, out, lse, w, causal, 0.2,
+                                          interpret=True, **tiles)
+    text = str(jax.make_jaxpr(lambda *a: attention._flash_bwd_pallas(
+        *a, causal, 0.2, interpret=True, **tiles))(q, k, v, out, lse, w))
+    assert ("scan" in text) == (heads_a_call < 6)
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5, err_msg="d" + name)
 
 
 def test_the_plan_at_two_widths_sizes_each_block_at_its_own():
@@ -528,15 +687,16 @@ def test_the_plan_at_two_widths_sizes_each_block_at_its_own():
     one = attention._flash_plan(8192, 8192, 192, jnp.bfloat16)
     assert (two.d_block, two.dv_block) == (192, 128)
     assert (one.d_block, one.dv_block) == (192, 192)
-    assert two.dkdv.res_k > one.dkdv.res_k
+    assert two.bwd.res_k > one.bwd.res_k
     rec = attention._plan_args(two, 8192, 8192, 192, jnp.bfloat16, True, 128)
     assert (rec["d"], rec["d_v"], rec["d_block"], rec["dv_block"]) == \
         (192, 128, 192, 128)
+    assert rec["fwd"]["vmem_bytes"] <= attention._VMEM_BUDGET
+    assert rec["bwd"]["vmem_bytes"] <= rec["bwd"]["vmem_limit_bytes"] \
+        - attention._VMEM_MOSAIC
     for kernel in attention._KERNELS:
-        assert rec[kernel]["vmem_bytes"] <= attention._VMEM_BUDGET, kernel
         # the looped sub-tiles, as at any S = 8192
-        assert rec[kernel]["sub_tile"] == list(
-            attention._SUB_LOOPED[kernel])
+        assert rec[kernel]["sub_tile"] == list(attention._SUB_LOOPED)
     # widths that are no multiple of 64 are padded to the lane tile, each
     # on its own
     ragged = attention._flash_plan(40, 56, 24, jnp.float32, d_v=16)
@@ -558,6 +718,11 @@ def test_the_plan_span_records_the_values_width():
              if s.name == "mx.flash.plan"]
     assert (span.args["d"], span.args["d_v"], span.args["d_block"],
             span.args["dv_block"]) == (192, 128, 192, 128)
+    assert sorted(k for k in span.args if isinstance(span.args[k], dict)) \
+        == ["bwd", "fwd"]
+    assert span.args["bwd"]["dq_accumulator"] == "vmem"
+    assert span.args["bwd"]["vmem_limit_bytes"] >= \
+        span.args["bwd"]["vmem_bytes"]
 
 
 def test_the_two_width_kernels_lower_for_the_tpu_without_a_chip():
@@ -574,5 +739,103 @@ def test_the_two_width_kernels_lower_for_the_tpu_without_a_chip():
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
         q, q, v).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert set(re.findall(r"mx_flash_\w+", text)) == \
-        {"mx_flash_fwd", "mx_flash_dkdv", "mx_flash_dq"}
-    assert text.count("tpu_custom_call") == 3
+        {"mx_flash_fwd", "mx_flash_bwd"}
+    assert text.count("tpu_custom_call") == 2
+
+
+# ---------------------------------------------------------------------------
+# Compiled for a described v5e, without a chip (`benchmarks/rehearse.py`):
+# XLA:TPU and Mosaic for real, nothing runs.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    # a chipless compile is written to the persistent cache but cannot be
+    # read back without a chip
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+_SMALL_LM = {"family": "transformer_lm", "vocab_size": 1024,
+             "hidden_size": 256, "ffn_dim": 1024, "num_attention_heads": 2,
+             "num_hidden_layers": 2, "max_position_embeddings": 1024,
+             "init_std": 0.02,
+             "train": {"optimizer": "sgd", "lr": 0.01, "momentum": 0.9,
+                       "wd": 0.0, "multi_precision": True,
+                       "sequence_length": 1024, "per_chip_batch": 2}}
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_a_training_step_for_the_described_chip_holds_two_mosaic_calls_a_layer(
+        v5e, no_persistent_cache, chips):
+    """The rehearsal's compile of a small LM step: the forward kernel and
+    the one backward kernel of every layer are in the TPU program, the
+    step has arguments, one chip needs no collective and data
+    parallelism over four brings its all-reduces."""
+    from benchmarks import rehearse
+    out = rehearse.step_memory(_SMALL_LM, chips, v5e)
+    assert out["mosaic_calls"] == 2 * _SMALL_LM["num_hidden_layers"]
+    assert out["argument_size_in_bytes"] > 0
+    if chips == 1:
+        assert out["all_gathers"] == out["all_reduces"] == 0
+    else:
+        assert out["all_reduces"] > 0
+
+
+@pytest.mark.parametrize("s,d,d_v,dtype,dq,limit_mib", [
+    # the three LM cells' shapes
+    (2048, 64, 64, "bfloat16", "vmem", 16),
+    (8192, 64, 64, "bfloat16", "vmem", 22.5),
+    (8192, 192, 128, "bfloat16", "vmem", 30.75),
+    # the most the plan may ask Mosaic for: the longest accumulator
+    # `_VMEM_DQ` holds beside full blocks, at three rows' widths
+    (49152, 128, 128, "bfloat16", "vmem", 64),
+    (16384, 384, 384, "bfloat16", "vmem", 62.625),
+    (16384, 256, 256, "float32", "vmem", 68.125),
+    # past it: partials in HBM, one head of the two at a time
+    (65536, 128, 128, "bfloat16", "hbm", 18.5),
+])
+def test_the_backward_compiles_for_the_described_chip_at_what_it_asks_for(
+        v5e, no_persistent_cache, s, d, d_v, dtype, dq, limit_mib):
+    """Mosaic and XLA:TPU accept the backward call with the
+    `vmem_limit_bytes` the plan counted, up to the 64 MiB that
+    `_VMEM_BUDGET + _VMEM_DQ + _VMEM_MOSAIC` allow at the cells' widths
+    and what wider rows add (16384 x 256 of f32 was refused by 212 KiB
+    while Mosaic's share was 4 MiB at every width); the `hbm` fallback's
+    temporaries stay at one group's partials."""
+    from jax.sharding import SingleDeviceSharding
+    plan = attention._flash_plan(s, s, d, dtype, d_v=d_v)
+    assert plan.dq_accumulator == dq
+    assert attention._vmem_limit(plan, jnp.dtype(dtype).itemsize) == \
+        limit_mib * (1 << 20)
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def aval(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    compiled = jax.jit(lambda q, k, v, o, lse, do: attention._flash_bwd_pallas(
+        q, k, v, o, lse, do, True, d ** -0.5)).lower(
+            aval(1, 2, s, d), aval(1, 2, s, d), aval(1, 2, s, d_v),
+            aval(1, 2, s, d_v), aval(2, 1, s, dt=jnp.float32),
+            aval(1, 2, s, d_v)).compile()
+    text = compiled.as_text()
+    assert "mx_flash_bwd" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if dq == "vmem":
+        assert temp < 64 << 20          # delta and lse rows, no partials
+    else:
+        assert (2 << 30) <= temp < (2 << 30) + (256 << 20)

@@ -435,6 +435,27 @@ def test_the_flash_kernels_are_scoped_and_named_in_a_tpu_lowering():
         aval, aval, aval).lower(lowering_platforms=("tpu",)).as_text(
             debug_info=True)
     for scope, kernel in (("mx.flash.fwd", "mx_flash_fwd"),
-                          ("mx.flash.dkdv", "mx_flash_dkdv"),
-                          ("mx.flash.dq", "mx_flash_dq")):
+                          ("mx.flash.bwd", "mx_flash_bwd")):
         assert scope in text and kernel in text, scope
+    # the pair the one backward kernel replaced is in no program
+    assert "mx.flash.dkdv" not in text and "mx.flash.dq" not in text
+
+
+def test_a_trainer_s_first_step_records_the_flash_plan_of_two_kernels():
+    """The LM fixture's set-up holds one `mx.flash.plan` span a traced
+    attention call, and it says of the one backward kernel where dq
+    accumulates and what VMEM its call asks for."""
+    t0 = time.perf_counter()
+    trainer, batches = _fixture_trainer("tiny_lm")
+    trainer.fit_batch(*batches[0])
+    plans = [s for s in profiler.spans(since=t0)
+             if s.name == "mx.flash.plan"]
+    assert plans
+    for span in plans:
+        assert {k for k, v in span.args.items() if isinstance(v, dict)} == \
+            {"fwd", "bwd"}
+        bwd = span.args["bwd"]
+        assert bwd["dq_accumulator"] == "vmem"
+        assert bwd["vmem_limit_bytes"] >= max(bwd["vmem_bytes"], 16 << 20)
+        assert set(span.args["fwd"]) == set(bwd) - {"dq_accumulator",
+                                                     "vmem_limit_bytes"}

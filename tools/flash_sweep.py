@@ -4,12 +4,15 @@ real chip.
 One command: numeric checks of the Pallas kernels against the einsum
 oracle (forward and all three gradients; causal and not, seq_q == seq_k
 and not), then a timing sweep of resident block x sub-tile for each of
-the three kernels, each timed alone, with useful-FLOP throughput and the
-plan's tile counts.  The table in docs/PERF_NOTES.md "Flash attention
-kernel" and the values of `ops/attention.py` `_SUB` come from it.
+the two kernels (the backward also with dq's accumulator in either
+place, and the two places' dq set beside each other), each timed alone, with its throughput by useful work and by the
+MXU passes it executes, and the plan's tile counts.  The table in
+docs/PERF_NOTES.md "Flash attention kernel" and the values of
+`ops/attention.py` `_SUB_*` come from it.
 
     python tools/flash_sweep.py                  # checks + both shapes
     python tools/flash_sweep.py --shape 2,32,2048,64 --default-only
+    python tools/flash_sweep.py --shape 1,32,8192,192,128 --kernel bwd
 
 Timing discipline: iterations are chained through a data dependency
 inside one jit (scan), timed to a host readback.  Needs the chip to
@@ -27,15 +30,17 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-#: the shapes swept: the benchmark's LM cell, and a long wide one
+#: the shapes swept, (b, h, s, d) or (b, h, s, d, d_v): the benchmark's
+#: LM cell, and a long wide one
 SHAPES = ((2, 32, 2048, 64), (4, 16, 4096, 128))
 #: (query rows, key columns) of a score sub-tile
 SUB_TILES = ((256, 256), (256, 512), (512, 256), (512, 512), (1024, 1024))
 
 
-def numeric_check(shapes=(1, 2, 256, 64), seq_k=None):
+def numeric_check(shapes=(1, 2, 256, 64), seq_k=None, d_v=None):
     """Flash (compiled, on-device) vs oracle: fwd + dq/dk/dv, causal and
-    not; *seq_k* sets a key length of its own (ends aligned)."""
+    not; *seq_k* sets a key length of its own (ends aligned), *d_v* a
+    width of the values' own."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops.attention import (attention_reference,
@@ -45,7 +50,7 @@ def numeric_check(shapes=(1, 2, 256, 64), seq_k=None):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (b, h, s, d), jnp.bfloat16)
     k = jax.random.normal(ks[1], (b, h, sk, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, h, sk, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, h, sk, d_v or d), jnp.bfloat16)
 
     for causal in (False, True):
         def loss_f(q, k, v):
@@ -70,7 +75,8 @@ def numeric_check(shapes=(1, 2, 256, 64), seq_k=None):
                 for a, b in zip(gf, gr)]
         scale = float(jnp.max(jnp.abs(out_r))) + 1e-6
         gscales = [float(jnp.max(jnp.abs(g))) + 1e-6 for g in gr]
-        print(json.dumps({"check": "numerics", "shape": [b, h, s, sk, d],
+        print(json.dumps({"check": "numerics",
+                          "shape": [b, h, s, sk, d, d_v or d],
                           "causal": causal,
                           "fwd_maxerr": fwd_err,
                           "grad_maxerr": errs,
@@ -99,8 +105,9 @@ def _time_scan(fn, args, iters):
     def chained(*args):
         def body(c, _):
             out = fn(*((c,) + args[1:]))
-            # feed a scaled output back as q to chain the iterations
-            return (c * 0 + out).astype(args[0].dtype), None
+            # feed a scaled output back as q to chain the iterations (one
+            # column of it: the output may be narrower than q)
+            return (c * 0 + out[..., :1]).astype(args[0].dtype), None
         c, _ = jax.lax.scan(body, args[0], None, length=iters)
         return jnp.sum(c.astype(jnp.float32))
 
@@ -143,48 +150,58 @@ def _kernel_ms(run):
 
 
 def _kernels(A, causal, scale, tiles):
-    """The three calls, each alone.  The backward wrapper makes both of
-    its calls; XLA drops the one whose result is not used, so a function
-    that returns dq alone times `mx_flash_dq` (and the delta pass), and
-    one that returns dk + dv times `mx_flash_dkdv`."""
+    """The two calls, each alone.  The backward's three results are all
+    used, so that nothing around the kernel (the sum of dq's partials)
+    is dropped."""
     def fwd(q, k, v, out, lse, dout):
         return A._flash_fwd_pallas(q, k, v, causal, scale, with_lse=True,
                                    **tiles)[0]
 
-    def dq(q, k, v, out, lse, dout):
-        return A._flash_bwd_pallas(q, k, v, out, lse, dout, causal, scale,
-                                   **tiles)[0]
+    def bwd(q, k, v, out, lse, dout):
+        dq, dk, dv = A._flash_bwd_pallas(q, k, v, out, lse, dout, causal,
+                                         scale, **tiles)
+        return dq + dk + dv[..., :1]
 
-    def dkdv(q, k, v, out, lse, dout):
-        g = A._flash_bwd_pallas(q, k, v, out, lse, dout, causal, scale,
-                                **tiles)
-        return g[1] + g[2]
-
-    return {"fwd": fwd, "dkdv": dkdv, "dq": dq}
+    return {"fwd": fwd, "bwd": bwd}
 
 
-#: useful FLOPs over the forward's two dots: the recompute and dp, then
-#: one accumulating dot for dq and two for dk/dv
-_DOTS = {"fwd": 2, "dkdv": 4, "dq": 3}
+def _work(A, name, row, bh, s, d, d_v, causal):
+    """``(useful, executed)`` FLOPs of one call.  Useful: the visible
+    scores (the causal triangle) through the forward's two dots, or the
+    backward's five (the recomputed scores, dP, dv, dk, dq), each at its
+    own width.  Executed: the tiles the plan visits, whole, through the
+    128-lane MXU passes the kernel makes of them (the backward's operands
+    are widened to whole lane tiles; 192 takes two passes, 64 one)."""
+    scores = bh * s * s * (0.5 if causal else 1.0)
+    wide = -(-A._d_block(d) // 128)
+    wide_v = -(-A._d_block(d_v) // 128)
+    if name == "fwd":
+        dots, passes = d + d_v, wide + wide_v
+    else:
+        dots, passes = 3 * d + 2 * d_v, 3 * wide + 2 * wide_v
+    tile = row["sub_tile"][0] * row["sub_tile"][1]
+    return 2.0 * scores * dots, \
+        2.0 * bh * row["tiles_visited"] * tile * 128 * passes
 
 
 def sweep(shape, causal=True, iters=8, default_only=False,
-          kernels=("fwd", "dkdv", "dq"), out=None, residents=True,
+          kernels=("fwd", "bwd"), out=None, residents=True,
           sub_tiles=SUB_TILES):
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import attention as A
 
-    b, h, s, d = shape
+    b, h, s, d = shape[:4]
+    d_v = shape[4] if len(shape) > 4 else d
     scale = 1.0 / d ** 0.5
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
-    q, k, v, dout = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
-                     for kk in ks)
+    q, k = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
+            for kk in ks[:2])
+    v, dout = (jax.random.normal(kk, (b, h, s, d_v), jnp.bfloat16)
+               for kk in ks[2:])
     o, lse = jax.jit(lambda q, k, v: A._flash_fwd_pallas(
         q, k, v, causal, scale, with_lse=True))(q, k, v)
     args = (q, k, v, o, lse, dout)
-    # useful flops of one dot: 2*s*s*d a head, halved by causal masking
-    dot_flops = 2.0 * b * h * s * s * d * (0.5 if causal else 1.0)
 
     variants = [{}]
     if not default_only:
@@ -201,35 +218,66 @@ def sweep(shape, causal=True, iters=8, default_only=False,
                 variants.append({"blk_q": sub_q, "blk_k": sub_k,
                                  "res_q": s // 2, "res_k": s // 2})
     rows = []
+    vmem_dq = A._VMEM_DQ
     for tiles in variants:
-        fns = _kernels(A, causal, scale, tiles)
         for name in kernels:
-            row = {"metric": "flash_" + name, "shape": list(shape),
-                   "causal": causal, "tiles": tiles}
-            plan = A._flash_plan(s, s, d, q.dtype, **tiles)
-            row.update(A._plan_args(plan, s, s, d, q.dtype, causal)[name])
-            try:
-                ms, kernel_ms = _time_scan(fns[name], args, iters)
-            except Exception as e:  # a tile VMEM refuses: say so, go on
-                row["error"] = str(e).strip().splitlines()[0][:200]
-            else:
-                row["ms"], row["kernel_ms"] = ms, kernel_ms
-                row["useful_tflops"] = \
-                    _DOTS[name] * dot_flops / kernel_ms / 1e9
-            rows.append(row)
-            print(json.dumps(row), flush=True)
-            if out is not None:
-                out.write(json.dumps(row) + "\n")
-                out.flush()
+            # the backward with dq's accumulator where the plan puts it,
+            # then (at the plan's own resident blocks) in the other
+            # place: the plan as a `_VMEM_DQ` that holds every sequence,
+            # or none, makes it (no argument chooses)
+            places = (None, "vmem", "hbm") \
+                if name == "bwd" and "res_q" not in tiles else (None,)
+            for place in places:
+                A._VMEM_DQ = {None: vmem_dq, "vmem": 1 << 40, "hbm": 0}[place]
+                A._flash_bwd_pallas.clear_cache()
+                plan = A._flash_plan(s, s, d, q.dtype, d_v=d_v, **tiles)
+                if place is None:
+                    own_place = plan.dq_accumulator
+                elif place == own_place:
+                    continue            # the plan's own: timed already
+                row = {"metric": "flash_" + name, "shape": list(shape),
+                       "causal": causal, "tiles": tiles}
+                row.update(A._plan_args(plan, s, s, d, q.dtype, causal,
+                                        d_v)[name])
+                try:
+                    ms, kernel_ms = _time_scan(
+                        _kernels(A, causal, scale, tiles)[name], args, iters)
+                except Exception as e:  # a tile VMEM refuses: say so, go on
+                    row["error"] = str(e).strip().splitlines()[0][:200]
+                else:
+                    useful, executed = _work(A, name, row, b * h, s, d, d_v,
+                                             causal)
+                    row["ms"], row["kernel_ms"] = ms, kernel_ms
+                    row["useful_tflops"] = useful / kernel_ms / 1e9
+                    row["executed_tflops"] = executed / kernel_ms / 1e9
+                    if name == "bwd" and len(places) > 1:
+                        # the other place's dq beside the plan's own
+                        dq = jax.jit(lambda *a: A._flash_bwd_pallas(
+                            *a, causal, scale, **tiles)[0])(*args).astype(
+                                jnp.float32)
+                        if place is None:
+                            dq_own = dq
+                        else:
+                            row["dq_maxdiff_from_plans"] = float(
+                                jnp.max(jnp.abs(dq - dq_own)))
+                            row["dq_scale"] = float(jnp.max(jnp.abs(dq_own)))
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+                if out is not None:
+                    out.write(json.dumps(row) + "\n")
+                    out.flush()
+    A._VMEM_DQ = vmem_dq
+    A._flash_bwd_pallas.clear_cache()
 
     print(table(rows), flush=True)
-    if not default_only:
+    if not default_only and d_v == d:
         def chunked(q, k, v, *_):
             return A._chunked_attention(q, k, v, causal=causal)
 
         ms, _ = _time_scan(chunked, args, iters)
         row = {"metric": "chunked_xla_fwd", "shape": list(shape),
-               "ms": ms, "useful_tflops": 2 * dot_flops / ms / 1e9}
+               "ms": ms, "useful_tflops": 4.0 * b * h * s * s * d
+               * (0.5 if causal else 1.0) / ms / 1e9}
         rows.append(row)
         print(json.dumps(row), flush=True)
     return rows
@@ -237,17 +285,19 @@ def sweep(shape, causal=True, iters=8, default_only=False,
 
 def table(rows):
     """The sweep's rows as the markdown table of docs/PERF_NOTES.md."""
-    out = ["| kernel | sub-tile q x k | resident q, k | tiles visited "
-           "(masked) / ideal | kernel ms | with wrappers ms | useful "
-           "TFLOP/s |", "| --- | --- | --- | --- | --- | --- | --- |"]
+    out = ["| kernel | sub-tile q x k | resident q, k | dq accumulates in | "
+           "tiles visited (masked) / ideal | kernel ms | with wrappers ms | "
+           "TFLOP/s useful | executed |",
+           "| --- | --- | --- | --- | --- | --- | --- | --- | --- |"]
     for r in sorted((r for r in rows if "kernel_ms" in r),
                     key=lambda r: (r["metric"], r["kernel_ms"])):
-        out.append("| %s%s | %dx%d | %d, %d | %d (%d) / %.1f | %.3f | %.3f "
-                   "| %.1f |" % (
+        out.append("| %s%s | %dx%d | %d, %d | %s | %d (%d) / %.1f | %.3f | "
+                   "%.3f | %.1f | %.1f |" % (
                        r["metric"][6:], "" if r["tiles"] else " (plan)",
-                       *r["sub_tile"], *r["resident"], r["tiles_visited"],
+                       *r["sub_tile"], *r["resident"],
+                       r.get("dq_accumulator", ""), r["tiles_visited"],
                        r["tiles_masked"], r["tiles_ideal"], r["kernel_ms"],
-                       r["ms"], r["useful_tflops"]))
+                       r["ms"], r["useful_tflops"], r["executed_tflops"]))
     return "\n".join(out)
 
 
@@ -265,8 +315,10 @@ def main():
                     "%s)" % " ".join("%dx%d" % t for t in SUB_TILES))
     ap.add_argument("--iters", type=int, default=8)
     ap.add_argument("--shape", action="append",
-                    help="b,h,s,d (repeatable; default: %s)"
+                    help="b,h,s,d or b,h,s,d,d_v (repeatable; default: %s)"
                     % " and ".join(",".join(map(str, s)) for s in SHAPES))
+    ap.add_argument("--kernel", action="append", choices=("fwd", "bwd"),
+                    help="the kernel to time (repeatable; default: both)")
     ap.add_argument("--out", default="chiprun_out/flash_sweep.jsonl")
     args = ap.parse_args()
     if not args.skip_checks:
@@ -274,6 +326,7 @@ def main():
         numeric_check((2, 4, 2048, 64))
         numeric_check((1, 2, 384, 64), seq_k=1000)   # decode-style
         numeric_check((1, 2, 1000, 128), seq_k=384)  # degenerate rows
+        numeric_check((1, 2, 8192, 192), d_v=128)    # looped, two widths
     if not args.skip_sweep:
         shapes = [tuple(int(x) for x in s.split(","))
                   for s in args.shape] if args.shape else SHAPES
@@ -282,6 +335,7 @@ def main():
             for shape in shapes:
                 sweep(shape, iters=args.iters,
                       default_only=args.default_only, out=out,
+                      kernels=args.kernel or ("fwd", "bwd"),
                       residents=not args.sub_tiles_only,
                       sub_tiles=[tuple(int(x) for x in t.split("x"))
                                  for t in args.sub] if args.sub
