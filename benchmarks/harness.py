@@ -9,6 +9,13 @@ and edits nothing that is here.
     models/<family>.py             the program's model of a family
     reference/<family>.py          its plain float32 reference
     layer_metrics/<name>.py        read(outcome) -> value or None
+
+A PR that adds a cell appends a configuration, a workload and its
+per-layer entries (last in `per_layer`, each with a `workloads` list), and
+adds `configs/<config>.json`, `models/` + `reference/<family>.py` for a new
+family, a counts file beside `moe_counts.py`, and a `layer_metrics/<name>.py`
+an entry (`LAYER`, `UNIT`, `MOVES`, `BETTER`, `SOURCE` as the entry says).
+`tests/benchmark_suite/` finds entries by name: nothing that exists is edited.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
 """Device time per step under the graph's `_contrib_DotProductAttention:*`
-nodes, forward and backward: the three flash kernels and whatever the
+nodes, forward and backward: the two flash kernels and whatever the
 wrappers around them cost (padding, slicing, the backward's delta pass,
 copies XLA adds to feed them).  Prints the kernels' tile plan beside it,
 as the `mx.flash.plan` spans carry it (one per traced call; the plan is
@@ -13,6 +13,7 @@ from .. import program_spans
 LAYER = "kernels"
 UNIT = "ms"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "device_trace"
 
 

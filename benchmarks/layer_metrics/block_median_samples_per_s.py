@@ -5,6 +5,7 @@ one stalled block moves by nothing and a stall in every block does."""
 LAYER = "the whole loop"
 UNIT = "samples/s"
 MOVES = "train_samples_per_s"
+BETTER = "higher"
 SOURCE = "host_clock"
 
 

@@ -3,6 +3,7 @@
 LAYER = "device"
 UNIT = "%"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "device_trace"
 
 

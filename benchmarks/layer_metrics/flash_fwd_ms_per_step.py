@@ -6,6 +6,7 @@ from .. import program_spans
 LAYER = "kernels"
 UNIT = "ms"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "device_trace"
 
 

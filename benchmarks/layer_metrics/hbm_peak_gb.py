@@ -4,6 +4,7 @@ window closes (before the reference runs), in GB."""
 LAYER = "device"
 UNIT = "GB"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "program_counter"
 
 

@@ -4,6 +4,7 @@
 LAYER = "input"
 UNIT = "ms"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "program_span"
 
 

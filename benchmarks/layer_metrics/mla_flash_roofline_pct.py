@@ -1,13 +1,14 @@
-"""The three flash kernels' share of their roofline where they run inside
+"""The two flash kernels' share of their roofline where they run inside
 a latent attention block (keys wider than values): the time their useful
 causal work takes at the chip's peak (the larger of FLOPs over the bf16
 peak and bytes over the HBM peak, `benchmarks/peaks.json`) over the device
-time measured under `mx.flash.fwd`, `mx.flash.dkdv` and `mx.flash.dq`
-inside the `_contrib_LatentAttention:*` nodes.  The work is counted from
-the configuration's shapes by `benchmarks/mla_counts.py`: the scores on
-and under the diagonal at the two widths, forward and the backward's four
-contractions; what the two backward kernels compute twice is not useful
-work.  Nothing to read where the step holds no such kernel."""
+time measured under `mx.flash.fwd` and `mx.flash.bwd` inside the
+`_contrib_LatentAttention:*` nodes.  The work is counted from the
+configuration's shapes by `benchmarks/mla_counts.py`: the scores on and
+under the diagonal at the two widths, forward and the backward's four
+contractions; the scores the backward kernel forms again from q and k,
+and the tiles it visits above the diagonal, are not useful work.  Nothing
+to read where the step holds no such kernel."""
 
 from .. import mla_counts, moe_counts, program_spans
 
@@ -17,7 +18,7 @@ MOVES = "train_samples_per_s"
 BETTER = "higher"
 SOURCE = "device_trace"
 
-KERNELS = r"_contrib_LatentAttention:.*/mx\.flash\.(fwd|dkdv|dq)(/|$)"
+KERNELS = r"_contrib_LatentAttention:.*/mx\.flash\.(fwd|bwd)(/|$)"
 
 
 def read(outcome):

@@ -2,7 +2,7 @@
 nodes, forward and backward: the three input projections and the latent's
 norm (`mx.mla.project`), rotary positions, the one rope key spread over
 the heads, the concatenations and layout moves (`mx.mla.assemble`), the
-three flash kernels at keys wider than values, the output projection
+two flash kernels at keys wider than values, the output projection
 (`mx.mla.out`).  Prints the block's plan beside it, as the `mx.mla.plan`
 spans carry it (one per traced call), and the phases.  Nothing to read
 where the step holds no such node."""
@@ -18,7 +18,7 @@ BETTER = "lower"
 SOURCE = "device_trace"
 
 PHASES = ("mx.mla.project", "mx.mla.assemble", "mx.flash.fwd",
-          "mx.flash.dkdv", "mx.flash.dq", "mx.mla.out")
+          "mx.flash.bwd", "mx.mla.out")
 
 
 def read(outcome):

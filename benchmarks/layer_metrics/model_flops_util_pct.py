@@ -7,6 +7,7 @@ peak of the device kind (`benchmarks/peaks.json`)."""
 LAYER = "step program"
 UNIT = "%"
 MOVES = "train_samples_per_s"
+BETTER = "higher"
 SOURCE = "host_clock"
 
 

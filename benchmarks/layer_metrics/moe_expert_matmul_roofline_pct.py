@@ -4,9 +4,12 @@ bytes over the HBM peak, `benchmarks/peaks.json`) over the device time
 measured under `mx.moe.experts`.  The work is counted, not expected: the
 token-expert pairs on held experts a step are the program's counters
 `moe_local_assignments_total / moe_stat_steps_total`, put through
-`benchmarks/moe_counts.py` at the configuration's widths.  Padding rows
-and the backward pass's recomputed hidden states are not useful work.
-Nothing to read without the counters (a program from before them)."""
+`benchmarks/moe_counts.py` at the configuration's widths; how many layers
+are routed and how many experts are held, the cell's family says
+(`routed_layers_and_experts_held` in `benchmarks/models/<family>.py`).
+Padding rows and the backward pass's recomputed hidden states are not
+useful work.  Nothing to read without the counters (a program from before
+them)."""
 
 from .. import moe_counts, program_spans
 from . import moe_expert_matmul_ms_per_step
@@ -27,12 +30,12 @@ def read(outcome):
     cfg, cell = outcome.cell.config, outcome.cell
     pairs = count["moe_local_assignments_total"] \
         / count["moe_stat_steps_total"]
-    layers = len(cfg["layer_types"]) - cfg["num_dense_layers"]
+    layers, held = cell.family().routed_layers_and_experts_held(cfg)
     flops = moe_counts.expert_matmul_flops(
         pairs, cfg["hidden_size"], cfg["moe_intermediate_size"])
     moved = moe_counts.expert_matmul_bytes(
         pairs, cfg["hidden_size"], cfg["moe_intermediate_size"],
-        cfg["num_experts"], layers)
+        held, layers)
     kind = outcome.facts["device_kind"]
     least, bound = moe_counts.roofline_seconds(
         flops, moved, cell.peak(kind, "bf16_flops_per_s"),

@@ -7,6 +7,7 @@ from .. import program_spans
 LAYER = "input"
 UNIT = "ms"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "program_span"
 
 

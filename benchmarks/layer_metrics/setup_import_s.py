@@ -9,6 +9,7 @@ from .. import program_spans
 LAYER = "process and platform set-up"
 UNIT = "s"
 MOVES = "setup_s"
+BETTER = "lower"
 SOURCE = "program_span"
 
 

@@ -8,6 +8,7 @@ from .. import program_spans
 LAYER = "trainers"
 UNIT = "s"
 MOVES = "setup_s"
+BETTER = "lower"
 SOURCE = "program_span"
 
 

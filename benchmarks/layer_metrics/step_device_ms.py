@@ -4,6 +4,7 @@ in the traced blocks, averaged over the chips, over the traced steps."""
 LAYER = "step program"
 UNIT = "ms"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "device_trace"
 
 

@@ -7,6 +7,7 @@ from .. import program_spans
 LAYER = "step program"
 UNIT = "ms"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "device_trace"
 
 

@@ -7,6 +7,7 @@ from . import host_feed_ms_per_step
 LAYER = "trainers"
 UNIT = "ms"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "program_span"
 
 

@@ -13,6 +13,7 @@ from .. import program_spans
 LAYER = "trainers"
 UNIT = "ms"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "program_span"
 
 

@@ -4,6 +4,7 @@
 LAYER = "process and platform set-up"
 UNIT = "count"
 MOVES = "setup_s"
+BETTER = "lower"
 SOURCE = "program_counter"
 
 

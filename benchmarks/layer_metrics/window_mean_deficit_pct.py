@@ -6,6 +6,7 @@ that they were stalls and not a slower step."""
 LAYER = "the whole loop"
 UNIT = "%"
 MOVES = "train_samples_per_s"
+BETTER = "lower"
 SOURCE = "host_clock"
 
 

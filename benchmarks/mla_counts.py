@@ -1,8 +1,8 @@
 """Operations and bytes of the attention core at two head widths (queries
 and keys *d* wide, values and the output *d_v* wide), from its shapes: the
 only place these counts live.  Useful work only: the scores above a causal
-diagonal, and the scores and ``dP`` that the two backward kernels each
-compute again, are not counted.
+diagonal, and the scores that the backward kernel forms again from q and
+k, are not counted.
 """
 
 from __future__ import annotations

@@ -77,6 +77,14 @@ def forward_macs_per_token(cfg):
     return macs
 
 
+def routed_layers_and_experts_held(cfg):
+    """How many of the cell's layers are routed, and how many experts of
+    each this chip holds (the source's `n_routed_experts` counts what is
+    held here; the router's width is `router_experts`)."""
+    return cfg["num_hidden_layers"] - cfg["first_k_dense_replace"], \
+        cfg["n_routed_experts"]
+
+
 def flops_per_sample(cfg):
     """Training FLOPs of one sequence: 2 a multiply-add, backward twice
     the forward; normalisations, activations, the softmax, rotary

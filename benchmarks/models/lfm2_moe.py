@@ -75,6 +75,14 @@ def forward_macs_per_token(cfg):
     return macs
 
 
+def routed_layers_and_experts_held(cfg):
+    """How many of the cell's layers are routed, and how many experts of
+    each this chip holds: what a reader of the routed layers' work asks
+    its family, whose keys it does not know."""
+    return len(cfg["layer_types"]) - cfg["num_dense_layers"], \
+        cfg["num_experts"]
+
+
 def flops_per_sample(cfg):
     """Training FLOPs of one sequence: 2 a multiply-add, backward twice
     the forward; normalisations, activations, the softmax, the depthwise

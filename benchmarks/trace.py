@@ -21,8 +21,8 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench."
 # operations that only wrap others on the ops line: their time is their
-# children's
-_WRAPPERS = re.compile(r"^(while|conditional|call)([.\d]|$)")
+# children's (a `lax.cond` is on the ops line as `cond.N` or `cond.N.clone`)
+_WRAPPERS = re.compile(r"^(while|conditional|cond|call)([.\d]|$)")
 
 
 def find_xplane(trace_dir):

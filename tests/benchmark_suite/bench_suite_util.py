@@ -14,6 +14,13 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 
+def named(entries, name):
+    """The one entry of a list of `BENCHMARK.json` called *name*: entries
+    are found by name, never by their place in the list."""
+    entry, = [e for e in entries if e["name"] == name]
+    return entry
+
+
 def fixture_root(tmp_path, copy_code=False):
     """A benchmark root under *tmp_path*: the fixture `BENCHMARK.json`,
     the real traffic mixes and peaks, the fixture configurations.  With

@@ -11,8 +11,7 @@ import pytest
 import bench_suite_util as util
 from benchmarks import trace
 from benchmarks.layer_metrics import (attention_ms_per_step,
-                                      flash_dkdv_ms_per_step,
-                                      flash_dq_ms_per_step,
+                                      flash_bwd_ms_per_step,
                                       flash_fwd_ms_per_step)
 
 Span = collections.namedtuple(
@@ -31,8 +30,8 @@ class Outcome:
 
 def _step(with_attention=True):
     """One traced step of two layers: per layer a matmul, then (in an
-    attention node) the wrapper's copy, the kernel; backward the same
-    with both backward kernels and the delta pass."""
+    attention node) the wrapper's copy, the kernel; backward the delta
+    pass and the one backward kernel."""
     events = [{"plane": "/host:CPU", "line": "python",
                "name": "bench.fit_batch", "start_ns": 0,
                "dur_ns": 100000}]
@@ -62,10 +61,8 @@ def _step(with_attention=True):
         op("mx_flash_fwd.%d" % layer,
            fwd + "/mx.flash.fwd/mx_flash_fwd/pallas_call", 300, "fwd")
         op("reduce.%d" % layer, bwd + "/reduce_sum", 20, "wrapper")
-        op("mx_flash_dkdv.%d" % layer,
-           bwd + "/mx.flash.dkdv/mx_flash_dkdv/pallas_call", 500, "dkdv")
-        op("mx_flash_dq.%d" % layer,
-           bwd + "/mx.flash.dq/mx_flash_dq/pallas_call", 400, "dq")
+        op("mx_flash_bwd.%d" % layer,
+           bwd + "/mx.flash.bwd/mx_flash_bwd/pallas_call", 900, "bwd")
     return events, scope_map, want
 
 
@@ -82,10 +79,9 @@ def test_attention_is_the_kernels_and_their_wrappers(capsys):
     got = attention_ms_per_step.read(out)
     assert got == pytest.approx(want["attention"] * 1e-6)
     kernels = [m.read(out) for m in (flash_fwd_ms_per_step,
-                                     flash_dkdv_ms_per_step,
-                                     flash_dq_ms_per_step)]
+                                     flash_bwd_ms_per_step)]
     assert kernels == [pytest.approx(want[k] * 1e-6)
-                       for k in ("fwd", "dkdv", "dq")]
+                       for k in ("fwd", "bwd")]
     assert got == pytest.approx(sum(kernels) + want["wrapper"] * 1e-6)
     said = capsys.readouterr().out
     assert said.count("bench: mx.flash.plan (2 traced calls)") == 1
@@ -108,12 +104,58 @@ def test_attention_reads_nothing_where_there_is_none(capsys):
     assert "mx.flash.plan" not in capsys.readouterr().out
 
 
-def test_attention_metric_is_declared_for_the_lm_cell_alone():
+@pytest.mark.parametrize("node", [
+    "transpose(jvp(_contrib_DotProductAttention:opt_l0_att))",
+    "transpose(jvp(_contrib_LatentAttention:contrib_latentattention0))"
+    "/mx.mla"],
+    ids=["in_an_attention_node", "in_a_latent_attention_block"])
+def test_the_backward_kernel_is_read_wherever_it_runs(node):
+    """One kernel, one scope (`mx.flash.bwd`), under whichever operator
+    calls it; the retired pair's scopes and a scope that only starts
+    alike are not it."""
+    events = [{"plane": "/host:CPU", "line": "python",
+               "name": "bench.fit_batch", "start_ns": 0, "dur_ns": 10000}]
+    scope_map = {}
+    bwd = "jit(parallel_step)/mx.loss/" + node
+    for i, (name, scope, dur) in enumerate((
+            ("mx_flash_bwd.1", "/mx.flash.bwd/mx_flash_bwd/pallas_call",
+             700),
+            ("mx_flash_bwd.2", "/mx.flash.bwd", 200),
+            ("mx_flash_dq.3", "/mx.flash.dq/mx_flash_dq/pallas_call", 400),
+            ("fusion.4", "/mx.flash.bwd_delta/reduce_sum", 50),
+            ("mx_flash_fwd.5", "/mx.flash.fwd/mx_flash_fwd/pallas_call",
+             300))):
+        events.append({"plane": "/device:TPU:0", "line": "XLA Ops",
+                       "name": "%" + name, "start_ns": 10 + 1000 * i,
+                       "dur_ns": dur})
+        scope_map[name] = bwd + scope
+    out = Outcome([], scope_map, events, traced_blocks=1,
+                  steps_per_block=3)
+    assert flash_bwd_ms_per_step.read(out) == pytest.approx(900e-6 / 3)
+    assert flash_fwd_ms_per_step.read(out) == pytest.approx(300e-6 / 3)
+
+
+def test_attention_metric_is_declared_for_the_cells_with_attention_nodes():
     with open(os.path.join(util.REPO, "BENCHMARK.json")) as f:
         spec = json.load(f)
-    m = spec["per_layer"][-1]
-    r = attention_ms_per_step
-    assert m == {"name": "attention_ms_per_step", "unit": r.UNIT,
-                 "better": "lower", "source": r.SOURCE, "layer": r.LAYER,
-                 "moves": r.MOVES, "workloads": ["opt-1.3b_train_1chip"]}
-    assert m["layer"] in {x["layer"] for x in spec["per_layer"][:-1]}
+    for name, r in (("attention_ms_per_step", attention_ms_per_step),
+                    ("flash_bwd_ms_per_step", flash_bwd_ms_per_step)):
+        m = util.named(spec["per_layer"], name)
+        assert m == {"name": name, "unit": r.UNIT, "better": r.BETTER,
+                     "source": r.SOURCE, "layer": r.LAYER, "moves": r.MOVES,
+                     "workloads": m["workloads"]}
+        assert "opt-1.3b_train_1chip" in m["workloads"]
+        # ResNet-50 traces no attention
+        assert "resnet50_train" not in m["workloads"]
+    # the latent block is a node of its own (`_contrib_LatentAttention`):
+    # its kernels are read, its node is `mla_ms_per_step`'s
+    attention, latent, backward = (
+        set(util.named(spec["per_layer"], name)["workloads"])
+        for name in ("attention_ms_per_step", "mla_ms_per_step",
+                     "flash_bwd_ms_per_step"))
+    assert latent.isdisjoint(attention) and latent <= backward
+    # the pair the one kernel replaced is gone, files and entries
+    for gone in ("flash_dkdv_ms_per_step", "flash_dq_ms_per_step"):
+        assert gone not in {m["name"] for m in spec["per_layer"]}
+        assert not os.path.exists(os.path.join(
+            util.REPO, "benchmarks", "layer_metrics", gone + ".py"))

@@ -1,6 +1,5 @@
 """The `kanana-2-30b-a3b_train_ep8share` cell's own pieces: its four
-per-layer readers (in the tree, not yet declared in BENCHMARK.json: PERF.md
-section 7 row 19) on made-up outcomes, `benchmarks/mla_counts.py` against
+per-layer readers on made-up outcomes, `benchmarks/mla_counts.py` against
 counts by hand, the family's FLOPs against the table the cell was sized
 with, the configuration's published keys, its entries in BENCHMARK.json,
 and the family through the `train_fit` loop at a tiny size on the CPU (a
@@ -79,7 +78,7 @@ class Outcome:
 def _step(latent=True):
     """One traced step: a dense matmul and, with *latent*, two layers of a
     latent attention node (projections, assembly, the kernels, the output
-    projection; backward the same with both backward kernels), a shared
+    projection; backward the same with the one backward kernel), a shared
     expert node and a routed node each."""
     events = [{"plane": "/host:CPU", "line": "python",
                "name": "bench.fit_batch", "start_ns": 0, "dur_ns": 100000}]
@@ -119,11 +118,8 @@ def _step(latent=True):
                    "kernels")
             else:
                 op("delta.%d" % layer, node + "/reduce_sum", 20, "mla")
-                op("mx_flash_dkdv.%d" % layer, node
-                   + "/mx.flash.dkdv/mx_flash_dkdv/pallas_call", 500, "mla",
-                   "kernels")
-                op("mx_flash_dq.%d" % layer, node
-                   + "/mx.flash.dq/mx_flash_dq/pallas_call", 400, "mla",
+                op("mx_flash_bwd.%d" % layer, node
+                   + "/mx.flash.bwd/mx_flash_bwd/pallas_call", 900, "mla",
                    "kernels")
             op("out_%s.%d" % (way, layer), node + "/mx.mla.out/dot_general",
                90, "mla")
@@ -158,7 +154,8 @@ def test_the_device_readers_sum_their_nodes_and_scopes(capsys):
     assert '"assembled_k_bytes": 100663296' in said
     assert "bench: latent attention mx.mla.assemble %.3f ms a step" % (
         want["assemble"] * 1e-6) in said
-    assert "latent attention mx.flash.dkdv %.3f" % (2 * 500e-6) in said
+    assert "latent attention mx.flash.bwd %.3f" % (2 * 900e-6) in said
+    assert "mx.flash.dkdv" not in said and "mx.flash.dq" not in said
     mla_ms_per_step.read(out)               # said once
     assert "mx.mla.plan" not in capsys.readouterr().out
 
@@ -200,18 +197,27 @@ def test_a_reader_reads_nothing_where_there_is_nothing(name):
 
 
 def test_the_routed_layer_s_roofline_reader_finds_its_keys_here(cfg, capsys):
-    """`moe_expert_matmul_roofline_pct` was written beside `lfm2_moe` and
-    looks up that family's key names: the file says them again."""
-    assert cfg["layer_types"] == ["latent_attention"] * \
-        cfg["num_hidden_layers"]
-    assert cfg["num_dense_layers"] == cfg["first_k_dense_replace"]
-    assert cfg["num_experts"] == cfg["n_routed_experts"]
+    """`moe_expert_matmul_roofline_pct` was written beside `lfm2_moe`: it
+    asks the cell's family how many layers are routed and how many experts
+    are held, and the file says nothing twice for it."""
+    for key in ("layer_types", "num_dense_layers", "num_experts",
+                "reader_keys"):
+        assert key not in cfg, key
+    assert family.routed_layers_and_experts_held(cfg) == (4, 16)
+    assert family.routed_layers_and_experts_held(
+        dict(cfg, num_hidden_layers=9, n_routed_experts=32)) == (8, 32)
     events, scope_map, want = _step()
     names = ("moe_local_assignments_total", "moe_stat_steps_total")
     out = Outcome([], scope_map, events,
                   {names: dict(zip(names, (6500 * 4 * 10, 10)))},
                   traced_blocks=1, steps_per_block=1)
-    assert moe_expert_matmul_roofline_pct.read(out) > 0
+    pairs, d, f = 26000, cfg["hidden_size"], cfg["moe_intermediate_size"]
+    moved = 3 * 3 * 2 * (pairs * (d + f) + 4 * 16 * d * f)
+    flops = 3 * 3 * 2 * pairs * d * f
+    least = max(flops / 197e12, moved / 819e9)
+    assert least == moved / 819e9          # few pairs: the weights' bytes
+    assert moe_expert_matmul_roofline_pct.read(out) == pytest.approx(
+        100.0 * 1e3 * least / (want["moe"] * 1e-6))
     assert "26000.0 local pairs a step over 4 layers" in \
         capsys.readouterr().out
 
@@ -304,14 +310,13 @@ def test_every_unreduced_key_is_the_published_one(cfg):
         "first_gradient_norm_rms", "update_norm_gap", "update_norm_rms"}
 
 
-def test_the_cell_is_declared_and_the_old_lists_are_as_they_were(spec, cfg):
-    assert spec["configs"][-1]["name"] == CONFIG
-    entry = spec["configs"][-1]
+def test_the_cell_is_declared_and_its_readers_list_it(spec, cfg):
+    entry = util.named(spec["configs"], CONFIG)
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
     assert entry["reduced"] == cfg["reduced"]
     assert entry["source"] == cfg["source"] and len(entry["source"]) <= 200
     assert entry["file"] == "benchmarks/configs/%s.json" % CONFIG
-    cell = spec["workloads"][-1]
+    cell = util.named(spec["workloads"], CELL)
     assert cell == {"name": CELL, "config": CONFIG,
                     "traffic": "fit_prefetch", "chips": 1,
                     "why": cell["why"]}
@@ -320,39 +325,61 @@ def test_the_cell_is_declared_and_the_old_lists_are_as_they_were(spec, cfg):
                  "an eighth of their load"):
         assert said in cell["why"], said
     assert "every %d" % cfg["train"]["steps_per_block"] in cell["why"]
-    assert [w["name"] for w in spec["workloads"][:-1]] == [
-        "resnet50_train", "opt-1.3b_train_1chip",
-        "lfm2-8b-a1b_train_ep4share"]
-    # the cell is read by the nine metrics without a list, and is on no
-    # list: a new per-layer entry may only go last, where
-    # test_bench_attention_metric.py pins `attention_ms_per_step`, so the
-    # four readers wait undeclared beside LFM2's five (PERF.md section 7)
-    assert not [m["name"] for m in spec["per_layer"]
-                if CELL in m.get("workloads", [])]
-    assert len([m for m in spec["per_layer"] if "workloads" not in m]) == 9
-    assert not set(READERS) & {m["name"] for m in spec["per_layer"]}
-    assert spec["per_layer"][-1]["name"] == "attention_ms_per_step"
+    # its own four readers are declared for it, and for no cell without a
+    # latent block or shared experts; the routed layer's readers, the
+    # kernels' and the phases' list it beside the cells they read already
+    for name in READERS:
+        assert util.named(spec["per_layer"], name)["workloads"] == [CELL], name
+    for name in ("moe_ms_per_step", "moe_expert_matmul_ms_per_step",
+                 "moe_expert_matmul_roofline_pct",
+                 "moe_expert_load_max_over_mean",
+                 "moe_worst_case_layers_pct", "flash_fwd_ms_per_step",
+                 "flash_bwd_ms_per_step", "mosaic_time_share_pct",
+                 "step_forward_ms", "step_backward_ms", "step_optimizer_ms",
+                 "step_unscoped_pct", "setup_program_s"):
+        assert CELL in util.named(spec["per_layer"], name)["workloads"], name
+    # the latent block is no `_contrib_DotProductAttention` node, and
+    # the cell holds no short convolution and no batch norm
+    for name in ("attention_ms_per_step", "short_conv_ms_per_step",
+                 "batchnorm_ms_per_step"):
+        assert CELL not in util.named(
+            spec["per_layer"], name)["workloads"], name
 
 
-def test_the_readers_are_read_once_they_are_declared():
-    """What the benchmark PR has to add: four entries from the readers'
-    own constants, after which the harness reads all four."""
+def test_the_declared_readers_are_read_through_the_harness(spec):
+    """The four entries are the readers' own constants, and the harness
+    reads all four for this cell."""
     events, scope_map, _ = _step()
     out = Outcome([], scope_map, events, traced_blocks=1, steps_per_block=1)
-    layers = {m["layer"] for m in out.cell.spec["per_layer"]}
-    out.cell.spec["per_layer"] = [
-        {"name": name, "unit": r.UNIT, "better": r.BETTER,
-         "source": r.SOURCE, "layer": r.LAYER, "moves": r.MOVES,
-         "workloads": [CELL]} for name, r in sorted(READERS.items())]
-    for m in out.cell.spec["per_layer"]:
+    layers = {m["layer"] for m in spec["per_layer"]
+              if m["name"] not in READERS}
+    declared = [util.named(spec["per_layer"], name)
+                for name in sorted(READERS)]
+    for m, (name, r) in zip(declared, sorted(READERS.items())):
+        assert m == {"name": name, "unit": r.UNIT, "better": r.BETTER,
+                     "source": r.SOURCE, "layer": r.LAYER, "moves": r.MOVES,
+                     "workloads": [CELL]}
         assert m["layer"] in layers and m["moves"] == "train_samples_per_s"
         assert m["source"] == "device_trace"
-    assert out.cell.spec["per_layer"][1]["name"] == "mla_flash_roofline_pct"
-    assert out.cell.spec["per_layer"][1]["unit"] == "%"
+    assert util.named(declared, "mla_flash_roofline_pct")["unit"] == "%"
+    out.cell.spec["per_layer"] = declared
     after = harness.per_layer_metrics(out.cell, out)
     assert set(after) == set(READERS)
     assert {after[n]["unit"] for n in READERS} == {"ms", "%"}
-    assert after["mla_flash_roofline_pct"]["value"] > 0
+    assert 0 < after["mla_flash_roofline_pct"]["value"]
+    # ... and among the tree's entries for the step program and its
+    # kernels that list the cell, each that finds something in this
+    # made-up step
+    out = Outcome([], scope_map, events, traced_blocks=1, steps_per_block=1)
+    out.cell.spec["per_layer"] = [
+        m for m in spec["per_layer"] if CELL in m.get("workloads", ())
+        and m["layer"] in ("step program", "kernels")
+        and m["source"] == "device_trace"]
+    assert set(READERS) | {
+        "moe_ms_per_step", "moe_expert_matmul_ms_per_step",
+        "flash_fwd_ms_per_step", "flash_bwd_ms_per_step",
+        "step_forward_ms", "step_backward_ms"} <= set(
+        harness.per_layer_metrics(out.cell, out))
 
 
 def test_the_bias_is_the_configuration_s_in_the_program_and_the_reference(

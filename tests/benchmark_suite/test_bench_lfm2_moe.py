@@ -1,7 +1,8 @@
 """The `lfm2-8b-a1b_train_ep4share` cell's own pieces: its five per-layer
-readers (not yet declared in BENCHMARK.json) on made-up outcomes, `benchmarks/moe_counts.py` against counts by
-hand, the family's FLOPs against the table the cell was sized with, the
-configuration's published widths, its entries in BENCHMARK.json, and the
+readers and the pair buffer's miss rate on made-up outcomes,
+`benchmarks/moe_counts.py` against counts by hand, the family's FLOPs
+against the table the cell was sized with, the configuration's published
+widths, its entries in BENCHMARK.json, and the
 family through the `train_fit` loop at a tiny size on the CPU (a fixture
 root of its own) with its fp8 control."""
 
@@ -17,6 +18,7 @@ from benchmarks.layer_metrics import (moe_expert_load_max_over_mean,
                                       moe_expert_matmul_ms_per_step,
                                       moe_expert_matmul_roofline_pct,
                                       moe_ms_per_step,
+                                      moe_worst_case_layers_pct,
                                       short_conv_ms_per_step)
 from benchmarks.models import lfm2_moe as family
 
@@ -27,6 +29,7 @@ READERS = {"moe_ms_per_step": moe_ms_per_step,
            "moe_expert_matmul_ms_per_step": moe_expert_matmul_ms_per_step,
            "moe_expert_matmul_roofline_pct": moe_expert_matmul_roofline_pct,
            "moe_expert_load_max_over_mean": moe_expert_load_max_over_mean,
+           "moe_worst_case_layers_pct": moe_worst_case_layers_pct,
            "short_conv_ms_per_step": short_conv_ms_per_step}
 
 
@@ -165,13 +168,37 @@ def test_the_load_reader_averages_over_layers_and_steps(capsys):
         100.0 * 900000 / 2621440) in said
 
 
+@pytest.mark.parametrize("overflowed, layers, share", [
+    (0, 1200, 0.0), (8, 2952, 100.0 * 8 / 2952), (4, 4, 100.0)])
+def test_the_pair_buffer_s_miss_rate_is_a_share_of_layer_steps(
+        capsys, overflowed, layers, share):
+    """Counters `moe_worst_case_buffer_layers_total / moe_stat_layers_total`
+    (PR 30 read 8 of 2952); no overflow is a count of none, 0, and not
+    nothing to read."""
+    names = moe_worst_case_layers_pct.NAMES
+    # rows the layers ran at: the buffer's 24576 where the pairs fit it,
+    # every choice of every token (65536) where not
+    ran_at = (layers - overflowed) * 24576 + overflowed * 65536
+    counted = dict(zip(names, (overflowed, layers, ran_at,
+                               int(0.8 * 24576) * layers)))
+    out = Outcome([], {}, None, {names: counted})
+    got = moe_worst_case_layers_pct.read(out)
+    assert got == pytest.approx(share) and isinstance(got, float)
+    said = capsys.readouterr().out
+    assert "%d of %d routed layer-steps took the worst-case branch" % (
+        overflowed, layers) in said
+    assert "%.4f of the rows" % (int(0.8 * 24576) * layers / ran_at) in said
+
+
 @pytest.mark.parametrize("name", sorted(READERS))
 def test_a_reader_reads_nothing_where_there_is_nothing(name):
     reader = READERS[name]
     events, scope_map, _ = _step(routed=False)
     no_counts = {n: 0 for n in moe_expert_load_max_over_mean.NAMES}
     zeros = {ROOFLINE_NAMES: {n: 0 for n in ROOFLINE_NAMES},
-             moe_expert_load_max_over_mean.NAMES: no_counts}
+             moe_expert_load_max_over_mean.NAMES: no_counts,
+             moe_worst_case_layers_pct.NAMES: {
+                 n: 0 for n in moe_worst_case_layers_pct.NAMES}}
     for out in (
             # a step without a routed layer, counters at zero
             Outcome([], scope_map, events, zeros, traced_blocks=1,
@@ -269,48 +296,63 @@ def test_published_widths_are_not_cut(cfg):
     assert "four chips share each layer" in cfg["deployment"]
 
 
-def test_the_cell_is_declared_and_the_old_lists_are_as_they_were(spec, cfg):
-    entry, = [c for c in spec["configs"]
-              if c["name"] == "lfm2-8b-a1b-ep4share"]
+def test_the_cell_is_declared_and_its_readers_list_it(spec, cfg):
+    entry = util.named(spec["configs"], "lfm2-8b-a1b-ep4share")
     assert entry["reduced"] == cfg["reduced"]
     assert entry["source"] == cfg["source"] and len(entry["source"]) <= 200
-    cell, = [w for w in spec["workloads"] if w["name"] == CELL]
+    cell = util.named(spec["workloads"], CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lfm2-8b-a1b-ep4share", "fit_prefetch", 1)
     assert len(cell["why"]) <= 200 and "4x their share" in cell["why"]
     assert "%d packed" % cfg["train"]["per_chip_batch"] in cell["why"]
     assert "every %d" % cfg["train"]["steps_per_block"] in cell["why"]
-    # the cell is read by the nine metrics without a list, and is on no
-    # list: a new per-layer entry may only go last, where
-    # test_bench_attention_metric.py pins `attention_ms_per_step`, so the
-    # five readers wait undeclared for a benchmark PR (PERF.md section 7)
-    assert not [m["name"] for m in spec["per_layer"]
-                if CELL in m.get("workloads", [])]
-    assert len([m for m in spec["per_layer"] if "workloads" not in m]) == 9
-    assert not set(READERS) & {m["name"] for m in spec["per_layer"]}
+    # its own readers are declared for it: the short convolutions for it
+    # alone, the routed layer's beside the other expert cell; the kernels'
+    # and the phases' metrics list it beside the cells they read already
+    assert util.named(spec["per_layer"], "short_conv_ms_per_step")[
+        "workloads"] == [CELL]
+    for name in set(READERS) | {
+            "flash_fwd_ms_per_step", "flash_bwd_ms_per_step",
+            "attention_ms_per_step", "mosaic_time_share_pct",
+            "step_forward_ms", "step_backward_ms", "step_optimizer_ms",
+            "step_unscoped_pct", "setup_program_s"}:
+        assert CELL in util.named(spec["per_layer"], name)["workloads"], name
+    # no latent block, no shared experts, no batch norm here
+    for name in ("mla_ms_per_step", "moe_shared_ms_per_step",
+                 "batchnorm_ms_per_step"):
+        assert CELL not in util.named(
+            spec["per_layer"], name)["workloads"], name
 
 
-def test_the_readers_are_read_once_they_are_declared(cfg):
-    """What the benchmark PR has to add: five entries from the readers'
-    own constants, after which the harness reads all five."""
+def test_the_declared_readers_are_read_through_the_harness(spec):
+    """The entries are the readers' own constants, and the harness reads
+    all of them for this cell."""
     events, scope_map, _ = _step()
     counted = {"moe_local_assignments_total": 720000,
                "moe_stat_steps_total": 40}
     names = moe_expert_load_max_over_mean.NAMES
     load = dict(zip(names, (336.0, 160, 40, 10485760, 3000000, 900000)))
+    buffer = dict(zip(moe_worst_case_layers_pct.NAMES,
+                      (0, 160, 160 * 24576, 3000000)))
     out = Outcome([], scope_map, events,
-                  {ROOFLINE_NAMES: counted, names: load},
+                  {ROOFLINE_NAMES: counted, names: load,
+                   moe_worst_case_layers_pct.NAMES: buffer},
                   traced_blocks=1, steps_per_block=1)
-    layers = {m["layer"] for m in out.cell.spec["per_layer"]}
-    out.cell.spec["per_layer"] = [
-        {"name": name, "unit": r.UNIT, "better": r.BETTER,
-         "source": r.SOURCE, "layer": r.LAYER, "moves": r.MOVES,
-         "workloads": [CELL]} for name, r in sorted(READERS.items())]
-    for m in out.cell.spec["per_layer"]:
+    layers = {m["layer"] for m in spec["per_layer"]
+              if m["name"] not in READERS}
+    declared = [util.named(spec["per_layer"], name)
+                for name in sorted(READERS)]
+    for m, (name, r) in zip(declared, sorted(READERS.items())):
+        assert m == {"name": name, "unit": r.UNIT, "better": r.BETTER,
+                     "source": r.SOURCE, "layer": r.LAYER, "moves": r.MOVES,
+                     "workloads": m["workloads"]}
         assert m["layer"] in layers and m["moves"] == "train_samples_per_s"
+    out.cell.spec["per_layer"] = declared
     after = harness.per_layer_metrics(out.cell, out)
     assert set(after) == set(READERS)
     assert {after[n]["unit"] for n in READERS} == {"ms", "%", "ratio"}
+    # no layer-step overflowed: the miss rate is reported, as 0
+    assert after["moe_worst_case_layers_pct"]["value"] == 0.0
 
 
 def test_the_bias_is_the_configuration_s_in_the_program_and_the_reference(

@@ -18,14 +18,18 @@ Span = collections.namedtuple(
     "Span", "id name cat start end thread parent args")
 MAIN, PRODUCER = 11, 22
 
-NEW = {
-    "setup_import_s": 2, "setup_state_s": 2, "setup_program_s": 2,
-    "trainer_python_ms_per_step": 2, "prefetch_wait_ms_per_step": 2,
-    "prefetch_put_ms_per_batch": 2, "gc_pause_ms_per_block": 2,
-    "step_forward_ms": 2, "step_backward_ms": 2, "step_optimizer_ms": 2,
-    "step_unscoped_pct": 2, "batchnorm_ms_per_step": 1,
-    "flash_fwd_ms_per_step": 1, "flash_dkdv_ms_per_step": 1,
-    "flash_dq_ms_per_step": 1, "idle_outside_program_pct": 2}
+#: the readers that read nothing but the program's spans and scopes; how
+#: many cells list each is BENCHMARK.json's to say, not this file's
+READERS = (
+    "setup_import_s", "setup_state_s", "setup_program_s",
+    "trainer_python_ms_per_step", "prefetch_wait_ms_per_step",
+    "prefetch_put_ms_per_batch", "gc_pause_ms_per_block",
+    "step_forward_ms", "step_backward_ms", "step_optimizer_ms",
+    "step_unscoped_pct", "batchnorm_ms_per_step", "flash_fwd_ms_per_step",
+    "flash_bwd_ms_per_step", "idle_outside_program_pct",
+    "attention_ms_per_step", "moe_ms_per_step",
+    "moe_expert_matmul_ms_per_step", "short_conv_ms_per_step",
+    "mla_ms_per_step", "mla_assemble_ms_per_step", "moe_shared_ms_per_step")
 
 
 def reader(name):
@@ -136,7 +140,7 @@ def test_host_readers_count_the_untraced_blocks_only(name, value):
         assert value == pytest.approx(1e3 * own / 6)
 
 
-@pytest.mark.parametrize("name", sorted(NEW))
+@pytest.mark.parametrize("name", sorted(READERS))
 def test_a_program_without_spans_or_scopes_leaves_every_metric_out(name):
     """The parent commit: `profiler.spans` and `scope_map` do not exist;
     the readers return None and do not raise."""
@@ -259,14 +263,14 @@ def test_batch_norm_and_the_flash_kernels_are_read_by_scope(clip):
     assert 0 < norm < reader("step_forward_ms").read(out) + \
         reader("step_backward_ms").read(out)
     # ResNet-50 calls no flash kernel: nothing to read ...
-    for k in ("fwd", "dkdv", "dq"):
+    for k in ("fwd", "bwd"):
         assert reader("flash_%s_ms_per_step" % k).read(out) is None
     # ... a step that does is read kernel by kernel
     events = [e for e in clip["events"] if e["plane"] == "/host:CPU"]
     lo = min(e["start_ns"] for e in events)
     scope_map = {}
-    for i, (k, dur) in enumerate((("fwd", 300), ("dkdv", 500),
-                                  ("dq", 200), ("fwd", 100))):
+    for i, (k, dur) in enumerate((("fwd", 300), ("bwd", 500),
+                                  ("bwd", 200), ("fwd", 100))):
         name = "mx_flash_%s.%d" % (k, i)
         events.append({"plane": "/device:TPU:0", "line": "XLA Ops",
                        "name": "%" + name, "start_ns": lo + 1000 * i,
@@ -279,9 +283,8 @@ def test_batch_norm_and_the_flash_kernels_are_read_by_scope(clip):
     out = Outcome([], scope_map, traced_blocks=1, steps_per_block=2)
     out.trace = trace.Trace(events)
     assert [reader("flash_%s_ms_per_step" % k).read(out)
-            for k in ("fwd", "dkdv", "dq")] == [
-        pytest.approx(400e-6 / 2), pytest.approx(500e-6 / 2),
-        pytest.approx(200e-6 / 2)]
+            for k in ("fwd", "bwd")] == [
+        pytest.approx(400e-6 / 2), pytest.approx(700e-6 / 2)]
     assert reader("step_backward_ms").read(out) == pytest.approx(700e-6 / 2)
 
 
@@ -344,21 +347,24 @@ def test_idle_gaps_are_given_to_the_program_span_at_their_middle(
 
 # -- BENCHMARK.json -----------------------------------------------------------
 def test_every_new_per_layer_entry_has_its_file_and_its_cells():
+    """Each reader this file tests is declared, from its own constants,
+    for cells that exist (the rules for every entry, whoever tests its
+    reader, are `test_bench_data_driven.py`'s)."""
     with open(os.path.join(util.REPO, "BENCHMARK.json")) as f:
         spec = json.load(f)
     entries = {m["name"]: m for m in spec["per_layer"]}
-    cells = [w["name"] for w in spec["workloads"]]
-    layers = {m["layer"] for m in spec["per_layer"][:10]}
-    assert [m["name"] for m in spec["per_layer"][10:]] == list(NEW) \
-        or set(NEW) <= set(entries)
-    for name, n_cells in NEW.items():
+    cells = {w["name"] for w in spec["workloads"]}
+    for name in READERS:
         m, r = entries[name], reader(name)
-        assert (r.UNIT, r.LAYER, r.MOVES, r.SOURCE) == \
-            (m["unit"], m["layer"], m["moves"], m["source"]), name
-        assert m["layer"] in layers, name     # a layer PERF.md §3 has
-        assert len(m["workloads"]) == n_cells and \
-            set(m["workloads"]) <= set(cells), name
+        assert (r.UNIT, r.BETTER, r.LAYER, r.MOVES, r.SOURCE) == \
+            (m["unit"], m["better"], m["layer"], m["moves"], m["source"]), \
+            name
+        # each says which cells it reads
+        assert m["workloads"] and set(m["workloads"]) <= cells, name
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert os.path.exists(os.path.join(
             util.REPO, "benchmarks", "layer_metrics", name + ".py"))
+    # batch norm is ResNet-50's alone; the transformers trace none
+    assert entries["batchnorm_ms_per_step"]["workloads"] == [
+        "resnet50_train"]

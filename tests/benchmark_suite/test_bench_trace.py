@@ -90,6 +90,65 @@ def test_busy_seconds_are_averaged_over_the_devices_used():
         (300 + 200 + 1000) / 2 * 1e-9)
 
 
+@pytest.mark.parametrize("wrapper", [
+    "%cond.52.clone = (bf16[24576,2048]) conditional(%p, %a, %b)",
+    "%cond.7", "%conditional.3", "%while.4", "%call.1"])
+def test_a_wrapper_s_time_is_its_children_s(wrapper):
+    """A `lax.cond` (the routed layer's bounded branch) is on the ops
+    line as `cond.N.clone` around the operations of the branch taken:
+    counted beside them it would count their time twice."""
+    events = [
+        _ev("/host:CPU", "bench.fit_batch", 0, 2000, "python"),
+        _ev(DEV, "%fusion.1", 0, 100),
+        _ev(DEV, wrapper, 200, 1000),
+        _ev(DEV, "%gmm.2 = bf16[] custom-call()", 210, 600),
+        _ev(DEV, "%fusion.3", 850, 300),
+    ]
+    t = trace.Trace(events)
+    assert t.busy_s == pytest.approx((100 + 600 + 300) * 1e-9)
+    assert dict(t.top_ops()) == {
+        "fusion": pytest.approx(400e-9), "gmm": pytest.approx(600e-9)}
+    assert t.seconds_where(lambda name: True) == pytest.approx(1000e-9)
+    # an operation that only starts like one is an operation
+    t = trace.Trace(events + [_ev(DEV, "%condense_fusion.9", 1500, 50),
+                              _ev(DEV, "%callback.2", 1600, 50)])
+    assert {"condense_fusion", "callback"} <= set(dict(t.top_ops()))
+
+
+def test_mosaic_time_is_the_custom_calls_not_what_reads_them():
+    """Event names as the chip recorded them (PR 32, LFM2): the kernels
+    say `custom_call_target="tpu_custom_call"`; a fusion and a copy that
+    take a kernel's output as an operand name `%pallas_call.N` and are
+    not kernels."""
+    from benchmarks.layer_metrics import mosaic_time_share_pct
+
+    class Outcome:
+        trace = trace.Trace([
+            _ev("/host:CPU", "bench.fit_batch", 0, 2000, "python"),
+            _ev(DEV, "%gmm.17 = bf16[24576,1792]{1,0:T(8,128)(2,1)} "
+                "custom-call(s32[]{:T(128)} %get-tuple-element.2120, "
+                "bf16[24576,2048]{1,0} %broadcast_select_fusion.44), "
+                'custom_call_target="tpu_custom_call"', 0, 300),
+            _ev(DEV, "%branch_0_fun.3 = bf16[64,8192,64]{2,1,0} custom-call("
+                'bf16[64,8192,64]{2,1,0} %bitcast.875), custom_call_target='
+                '"tpu_custom_call", operand_layout_constraints={}', 300, 100),
+            _ev(DEV, "%fusion.1917 = (bf16[2048,3]{0,1}) fusion(f32[2048,3]"
+                "{0,1} %copy-done.563, f32[8,2048]{1,0} %pallas_call.23), "
+                "kind=kLoop, calls=%fused_computation.2801", 400, 500),
+            _ev(DEV, "%copy-start.67 = (bf16[32,8192,128]{2,1,0}) "
+                "copy-start(bf16[32,8192,128]{2,1,0} %pallas_call.8)",
+                900, 100),
+        ])
+    assert mosaic_time_share_pct.read(Outcome) == pytest.approx(
+        100.0 * 400 / 1000)
+    Outcome.trace = trace.Trace([
+        _ev("/host:CPU", "bench.fit_batch", 0, 2000, "python"),
+        _ev(DEV, "%fusion.1 = f32[] fusion(f32[] %pallas_call.2)", 0, 10)])
+    assert mosaic_time_share_pct.read(Outcome) is None
+    Outcome.trace = None
+    assert mosaic_time_share_pct.read(Outcome) is None
+
+
 def test_intervals():
     assert trace.union([(5, 7), (0, 2), (1, 3), (7, 7)]) == [(0, 3), (5, 7)]
     assert trace.subtract([(0, 10)], [(2, 3), (5, 12)]) == [(0, 2), (3, 5)]
