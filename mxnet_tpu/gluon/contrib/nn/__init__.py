@@ -3,5 +3,5 @@
 from .basic_layers import (Concurrent, GatedMLP, GatedShortConv,  # noqa
                            GroupedQueryAttention, HybridConcurrent, Identity,
                            LatentAttention, MoEFFN, MultiHeadAttention,
-                           RoutedExperts, SharedExperts,
+                           RoutedExperts, SharedExperts, SparseAttention,
                            SparseEmbedding, SyncBatchNorm)

@@ -291,6 +291,76 @@ class GroupedQueryAttention(HybridBlock):
                                 flatten=False, num_hidden=self._units)
 
 
+class SparseAttention(HybridBlock):
+    """Causal grouped-query attention over the keys a learned indexer
+    chooses for each query (learned sparse attention, as DeepSeek-V3.2-Exp
+    publishes it), over the ``_contrib_SparseAttention`` op: *num_heads*
+    query heads over *num_kv_heads* key/value heads with RMS norm over each
+    query and key head and rotary positions, as `GroupedQueryAttention`;
+    an indexer of *index_heads* heads of *index_head_dim* over one key
+    head scores every causal key, a query sees the *topk* best (all of
+    them where there are no more), and the indexer's three matrices are
+    trained by an alignment term alone.  Returns ``(output, term)``: the
+    term, shape ``(1,)``, is for the objective to add
+    (`gluon.model_zoo.decoder` `AlignedLoss`); it is the only path to the
+    indexer, and the output's gradient reaches everything else.
+    *mrope_section* deals the rotary frequencies to the axes of a
+    ``positions`` input ``(axes, batch, seq)``; without that input the
+    positions are a text's.  No biases."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
+                 index_heads=16, index_head_dim=64, topk=2048,
+                 rope_theta=10000.0, mrope_section=(), epsilon=1e-6,
+                 weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % num_kv_heads:
+            raise ValueError("num_heads (%d) must be a multiple of "
+                             "num_kv_heads (%d)" % (num_heads, num_kv_heads))
+        head_dim = head_dim or units // num_heads
+        self._attrs = {
+            "num_heads": int(num_heads), "num_kv_heads": int(num_kv_heads),
+            "index_heads": int(index_heads), "topk": int(topk),
+            "rope_theta": float(rope_theta),
+            "mrope_section": tuple(int(n) for n in mrope_section),
+            "eps": float(epsilon)}
+        with self.name_scope():
+            def weight(name, rows, cols):
+                return self.params.get(name, shape=(rows, cols),
+                                       init=weight_initializer)
+            self.q_weight = weight("query_weight", num_heads * head_dim,
+                                   units)
+            self.k_weight = weight("key_weight", num_kv_heads * head_dim,
+                                   units)
+            self.v_weight = weight("value_weight", num_kv_heads * head_dim,
+                                   units)
+            self.out_weight = weight("out_weight", units,
+                                     num_heads * head_dim)
+            self.q_gamma = self.params.get(
+                "query_norm_gamma", shape=(head_dim,), init="ones")
+            self.k_gamma = self.params.get(
+                "key_norm_gamma", shape=(head_dim,), init="ones")
+            self.index_q_weight = weight(
+                "index_query_weight", index_heads * index_head_dim, units)
+            self.index_k_weight = weight("index_key_weight", index_head_dim,
+                                         units)
+            self.index_w_weight = weight("index_head_weight", index_heads,
+                                         units)
+
+    def hybrid_forward(self, F, x, positions=None, q_weight=None,
+                       k_weight=None, v_weight=None, out_weight=None,
+                       q_gamma=None, k_gamma=None, index_q_weight=None,
+                       index_k_weight=None, index_w_weight=None):
+        weights = (q_weight, k_weight, v_weight, out_weight, q_gamma,
+                   k_gamma, index_q_weight, index_k_weight, index_w_weight)
+        if positions is None:
+            out = F.contrib.SparseAttention(x, *weights, **self._attrs)
+        else:
+            out = F.contrib.SparseAttention(x, *weights, positions,
+                                            use_positions=True,
+                                            **self._attrs)
+        return out[0], out[1]
+
+
 class LatentAttention(HybridBlock):
     """Causal multi-head latent attention, the expanded form, over the
     ``_contrib_LatentAttention`` op: queries of *qk_nope_head_dim* +
@@ -344,7 +414,7 @@ class RoutedExperts(HybridBlock):
     def __init__(self, units, hidden, num_experts, num_experts_per_tok,
                  experts_held=None, first_expert=0, expert_bias=None,
                  norm_topk_prob=True, routed_scaling_factor=1.0,
-                 weight_initializer=None, **kwargs):
+                 weight_initializer=None, scoring_func="sigmoid", **kwargs):
         super().__init__(**kwargs)
         held = num_experts if experts_held is None else experts_held
         if first_expert < 0 or first_expert + held > num_experts:
@@ -360,6 +430,10 @@ class RoutedExperts(HybridBlock):
             "first_expert": int(first_expert),
             "norm_topk_prob": bool(norm_topk_prob),
             "routed_scaling_factor": float(routed_scaling_factor)}
+        if scoring_func != "sigmoid":
+            # ``softmax``: a softmax over all the experts, its top-k
+            # renormalised, no bias and no scaling
+            self._attrs["scoring_func"] = str(scoring_func)
         with self.name_scope():
             self.router_weight = self.params.get(
                 "router_weight", shape=(num_experts, units),

@@ -4,7 +4,7 @@ added to the residual:
 
     h = h + operator(rms(h));  h = h + feed_forward(rms(h))
 
-then a final RMS norm and a head.  The operator is one of three kinds
+then a final RMS norm and a head.  The operator is one of four kinds
 (`OPERATOR_KINDS`):
 
 - ``"conv"``: a gated short causal convolution;
@@ -13,21 +13,33 @@ then a final RMS norm and a head.  The operator is one of three kinds
 - ``"latent_attention"``: multi-head latent attention, the expanded form:
   keys and values from one low-rank latent, one rotary key for all heads,
   keys wider than values (``kv_lora_rank``, ``qk_nope_head_dim``,
-  ``qk_rope_head_dim``, ``v_head_dim``, ``rope_interleave``).
+  ``qk_rope_head_dim``, ``v_head_dim``, ``rope_interleave``);
+- ``"sparse_attention"``: grouped-query attention over the keys a learned
+  indexer chooses for each query (``index_heads`` heads of
+  ``index_head_dim`` over one key head, the ``index_topk`` best causal keys
+  a query), the indexer trained by an alignment term alone; rotary
+  frequencies dealt to the axes of an optional ``positions`` input by
+  ``mrope_section``.  A net with such layers returns ``(logits, term)``:
+  ``alignment_weight`` times the mean of the layers' terms, which
+  `AlignedLoss` adds to the objective beside a loss of the logits.
 
 The feed-forward is a dense gated MLP in the first ``num_dense_layers``
 layers and dropless top-k routed experts in the others, to which
 ``shared_hidden`` > 0 adds shared experts: one gated MLP of that width
 that every token passes through (and every chip computes alike), under
-device scope ``mx.moe.shared``.  The head is the embedding itself
+device scope ``mx.moe.shared``.  The router scores with a sigmoid, or with
+``scoring_func`` ``softmax`` with a softmax over all the experts whose
+top-k is renormalised.  The head is the embedding itself
 (``tied_head``, the default) or a matrix of its own.
 
 These are the shapes of LiquidAI's LFM2 mixture-of-experts models
 (``model_type`` ``lfm2_moe``: conv and full_attention layers, a tied head)
 and of the ``deepseek_v3`` family (latent attention, shared experts, an
-untied head), whose published ``config.json`` keys the arguments follow;
-`benchmarks/models/lfm2_moe.py` and `benchmarks/models/deepseek_v3.py`
-build one from such a file.
+untied head), and of Kwai-Keye's ``KeyeVL2`` language model (sparse
+attention, a softmax router, three-axis rotary positions), whose published
+``config.json`` keys the arguments follow; `benchmarks/models/lfm2_moe.py`,
+`benchmarks/models/deepseek_v3.py` and `benchmarks/models/keye_vl2.py` build
+one from such a file.
 
 The routed layers hold ONE CHIP'S SHARE of their experts
 (`gluon.contrib.nn.RoutedExperts`): ``experts_held`` of ``num_experts``
@@ -46,11 +58,14 @@ from __future__ import annotations
 from ..block import HybridBlock
 from .. import nn
 from ..contrib.nn import (GatedMLP, GatedShortConv, GroupedQueryAttention,
-                          LatentAttention, RoutedExperts, SharedExperts)
+                          LatentAttention, RoutedExperts, SharedExperts,
+                          SparseAttention)
 
-__all__ = ["DecoderLayer", "DecoderLM", "get_decoder_lm", "OPERATOR_KINDS"]
+__all__ = ["AlignedLoss", "DecoderLayer", "DecoderLM", "get_decoder_lm",
+           "OPERATOR_KINDS"]
 
-OPERATOR_KINDS = ("conv", "full_attention", "latent_attention")
+OPERATOR_KINDS = ("conv", "full_attention", "latent_attention",
+                  "sparse_attention")
 
 
 class SharedAndRouted(HybridBlock):
@@ -70,16 +85,21 @@ class SharedAndRouted(HybridBlock):
 
 class DecoderLayer(HybridBlock):
     """One pre-norm layer: *operator* is built by kind (*latent* holds
-    `LatentAttention`'s own widths), *feed_forward* is handed in."""
+    `LatentAttention`'s own widths, *sparse* `SparseAttention`'s),
+    *feed_forward* is handed in.  A ``positions`` input goes to a
+    sparse_attention operator and to no other; a layer of that kind returns
+    ``(output, alignment term)``."""
 
     def __init__(self, dim, kind, feed_forward, heads, kv_heads, head_dim,
-                 rope_theta, conv_kernel, eps, init, latent=None, **kwargs):
+                 rope_theta, conv_kernel, eps, init, latent=None,
+                 sparse=None, **kwargs):
         super().__init__(**kwargs)
         if kind not in OPERATOR_KINDS:
             raise ValueError(
                 "layer kind %r is not one of %s (a gated short convolution, "
-                "grouped-query attention, multi-head latent attention)"
-                % (kind, OPERATOR_KINDS))
+                "grouped-query attention, multi-head latent attention, "
+                "learned sparse attention)" % (kind, OPERATOR_KINDS))
+        self._takes_positions = kind == "sparse_attention"
         with self.name_scope():
             self.operator_norm = nn.RMSNorm(dim, eps,
                                             prefix="operator_norm_")
@@ -91,6 +111,15 @@ class DecoderLayer(HybridBlock):
                 self.operator = GroupedQueryAttention(
                     dim, heads, kv_heads, head_dim, rope_theta, eps,
                     weight_initializer=init, prefix="attn_")
+            elif kind == "sparse_attention":
+                if not sparse:
+                    raise ValueError(
+                        "a sparse_attention layer needs index_heads, "
+                        "index_head_dim and index_topk")
+                self.operator = SparseAttention(
+                    dim, heads, kv_heads, head_dim, rope_theta=rope_theta,
+                    epsilon=eps, weight_initializer=init, prefix="dsa_",
+                    **sparse)
             else:
                 if not latent:
                     raise ValueError(
@@ -102,9 +131,15 @@ class DecoderLayer(HybridBlock):
             self.ffn_norm = nn.RMSNorm(dim, eps, prefix="ffn_norm_")
             self.feed_forward = feed_forward()
 
-    def hybrid_forward(self, F, x):
-        x = x + self.operator(self.operator_norm(x))
-        return x + self.feed_forward(self.ffn_norm(x))
+    def hybrid_forward(self, F, x, positions=None):
+        if not self._takes_positions:
+            x = x + self.operator(self.operator_norm(x))
+            return x + self.feed_forward(self.ffn_norm(x))
+        h = self.operator_norm(x)
+        out, term = self.operator(h) if positions is None \
+            else self.operator(h, positions)
+        x = x + out
+        return x + self.feed_forward(self.ffn_norm(x)), term
 
 
 class DecoderLM(HybridBlock):
@@ -113,7 +148,10 @@ class DecoderLM(HybridBlock):
     the others with routed experts of width *expert_hidden* and, with
     *shared_hidden* > 0, shared experts beside them), final RMS norm, and
     logits against the embedding itself (*tied_head*) or against a head
-    matrix of its own."""
+    matrix of its own.  A second input, ``positions`` ``(axes, batch,
+    seq)``, reaches the sparse_attention layers' rotary positions; with
+    such layers the net returns ``(logits, term)``, *alignment_weight*
+    times the mean of their alignment terms, shape ``(1,)``."""
 
     def __init__(self, vocab, dim, layer_types, num_dense_layers,
                  dense_hidden, expert_hidden, num_experts,
@@ -124,7 +162,9 @@ class DecoderLM(HybridBlock):
                  eps=1e-5, weight_initializer="normal", shared_hidden=0,
                  tied_head=True, kv_lora_rank=None, qk_nope_head_dim=None,
                  qk_rope_head_dim=None, v_head_dim=None,
-                 rope_interleave=True, **kwargs):
+                 rope_interleave=True, scoring_func="sigmoid",
+                 index_heads=None, index_head_dim=None, index_topk=None,
+                 mrope_section=(), alignment_weight=1.0, **kwargs):
         super().__init__(**kwargs)
         self._vocab, self._dim = vocab, dim
         init = weight_initializer
@@ -133,6 +173,12 @@ class DecoderLM(HybridBlock):
             "qk_nope_head_dim": qk_nope_head_dim,
             "qk_rope_head_dim": qk_rope_head_dim, "v_head_dim": v_head_dim,
             "rope_interleave": rope_interleave}
+        indexer = index_heads and {
+            "index_heads": index_heads, "index_head_dim": index_head_dim,
+            "topk": index_topk, "mrope_section": mrope_section}
+        # the objective adds the MEAN of the sparse layers' alignment terms
+        self._term_scale = float(alignment_weight) / max(
+            1, list(layer_types).count("sparse_attention"))
 
         def dense():
             return GatedMLP(dim, dense_hidden, weight_initializer=init,
@@ -143,7 +189,7 @@ class DecoderLM(HybridBlock):
                 dim, expert_hidden, num_experts, num_experts_per_tok,
                 experts_held, first_expert, expert_bias, norm_topk_prob,
                 routed_scaling_factor, weight_initializer=init,
-                prefix="moe_")
+                scoring_func=scoring_func, prefix="moe_")
 
         def shared():
             return SharedExperts(dim, shared_hidden, weight_initializer=init,
@@ -161,22 +207,56 @@ class DecoderLM(HybridBlock):
                 layer = DecoderLayer(
                     dim, kind, dense if i < num_dense_layers else sparse,
                     heads, kv_heads or heads, head_dim, rope_theta,
-                    conv_kernel, eps, init, latent, prefix="l%d_" % i)
+                    conv_kernel, eps, init, latent, indexer,
+                    prefix="l%d_" % i)
                 setattr(self, "l%d" % i, layer)
                 self.layers.append(layer)
             self.final_norm = nn.RMSNorm(dim, eps, prefix="final_norm_")
             self.head_weight = None if tied_head else self.params.get(
                 "head_weight", shape=(vocab, dim), init=init)
 
-    def hybrid_forward(self, F, x, embed_weight, head_weight=None):
+    def hybrid_forward(self, F, x, positions=None, embed_weight=None,
+                       head_weight=None):
         h = F.Embedding(x, embed_weight, input_dim=self._vocab,
                         output_dim=self._dim)
+        terms = []
         for layer in self.layers:
-            h = layer(h)
-        return F.FullyConnected(
+            h = layer(h) if positions is None else layer(h, positions)
+            if isinstance(h, tuple):
+                h, term = h
+                terms.append(term)
+        logits = F.FullyConnected(
             self.final_norm(h),
             embed_weight if head_weight is None else head_weight,
             no_bias=True, flatten=False, num_hidden=self._vocab)
+        if not terms:
+            return logits
+        total = terms[0]
+        for term in terms[1:]:
+            total = total + term
+        return logits, total * self._term_scale
+
+
+class AlignedLoss(HybridBlock):
+    """*loss* of a net's first output, with the net's second output (a
+    `DecoderLM`'s alignment term) a part of the objective and not of the
+    value: each row of the result is *loss*'s row plus ``term -
+    stop_gradient(term)``, which is zero.  So what a step reports stays
+    *loss*, and the term's gradient is scaled as every other gradient is, by
+    however the rows are reduced and scaled afterwards (a trainer's mean, a
+    plain ``backward()``'s sum, a loss scale)."""
+
+    def __init__(self, loss, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.loss = loss
+
+    def forward(self, outputs, label, *args):
+        from ... import ndarray, symbol
+        out, term = outputs
+        F = symbol if isinstance(out, symbol.Symbol) else ndarray
+        rows = self.loss(out, label, *args)
+        return F.broadcast_add(rows, term - F.BlockGrad(term))
 
 
 def get_decoder_lm(**kwargs):

@@ -288,6 +288,58 @@ def _vmem_limit(plan, itemsize):
         + _VMEM_MOSAIC * max(1, row // 512)
 
 
+#: queries (forward) or keys (backward) a word of the selection operand
+#: holds, one bit each
+_SEL_BITS = 32
+
+
+def _selection_bytes(t):
+    """VMEM bytes of a kernel's selection block, held twice, and of the
+    two more score-sized temporaries a tile takes to unpack it."""
+    return 2 * (t.res_q * t.res_k // _SEL_BITS) * 4 \
+        + 2 * t.sub_q * t.sub_k * 4
+
+
+def _selected_vmem_limit(kernel, plan, itemsize):
+    """What a call with a selection operand asks Mosaic for: the plan's
+    count (the backward's limit as it stands), the selection's block and
+    temporaries, and for the forward Mosaic's own share."""
+    extra = _selection_bytes(getattr(plan, kernel))
+    if kernel == "bwd":
+        return _vmem_limit(plan, itemsize) + extra
+    return max(_VMEM_BUDGET, _vmem_bytes("fwd", plan, itemsize)) + extra \
+        + _VMEM_MOSAIC
+
+
+def pack_selection(mask):
+    """A ``(batch, rows, cols)`` bool mask as the selection operand the
+    kernels read: ``(batch, ceil(rows / 32), cols)`` int32, bit ``r % 32``
+    of word ``[r // 32, c]`` saying whether ``mask[r, c]``.  One bit a
+    pair: a sequence of 16384 takes 33.5 MB where the mask itself would
+    take 268."""
+    b, r, c = mask.shape
+    m = jnp.pad(mask, ((0, 0), (0, -r % _SEL_BITS), (0, 0)))
+    m = m.reshape(b, -1, _SEL_BITS, c).astype(jnp.uint32)
+    words = jnp.sum(m << jnp.arange(_SEL_BITS, dtype=jnp.uint32)[:, None],
+                    axis=2, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def unpack_selection(words, rows=None):
+    """`pack_selection`'s inverse on ``(..., groups, cols)`` words: a bool
+    ``(..., groups * 32, cols)`` (cut to *rows*).  The same arithmetic
+    inside the kernels and outside them."""
+    groups, cols = words.shape[-2:]
+    lead = words.shape[:-2]
+    spread = jnp.broadcast_to(
+        words[..., None, :], lead + (groups, _SEL_BITS, cols)
+    ).reshape(lead + (groups * _SEL_BITS, cols))
+    bit = jax.lax.broadcasted_iota(jnp.int32, spread.shape,
+                                   spread.ndim - 2) & (_SEL_BITS - 1)
+    mask = ((spread >> bit) & 1) != 0
+    return mask if rows is None else mask[..., :rows, :]
+
+
 def _resident(n_sub, bytes_per_sub, budget):
     """How many sub-tiles a resident block holds: the largest divisor of
     *n_sub* that fits *budget*, at least one."""
@@ -479,16 +531,28 @@ def _plan_args(plan, sq, sk, d, dtype, causal, d_v=None):
     return rec
 
 
-def _record_plan(q, k, v, causal):
+def _record_plan(q, k, v, causal, selected=False):
     """One `mx.flash.plan` span each time the op is traced.  At trace
     time on purpose: the plan is a fact of the compiled program, not of
-    a step."""
+    a step.  With a selection operand (*selected*) the span says so: every
+    visited tile is then computed under the mask, and the forward asks
+    Mosaic for its own `vmem_limit_bytes` too."""
     from .. import profiler
     sq, sk, d, d_v = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     with profiler.scope(  # graftlint: disable=JG003
             "mx.flash.plan", "flash") as span:
-        span.args = _plan_args(_flash_plan(sq, sk, d, q.dtype, d_v=d_v),
-                               sq, sk, d, q.dtype, causal, d_v)
+        plan = _flash_plan(sq, sk, d, q.dtype, d_v=d_v)
+        span.args = _plan_args(plan, sq, sk, d, q.dtype, causal, d_v)
+        if selected:
+            itemsize = jnp.dtype(q.dtype).itemsize
+            span.args["selection"] = "bits"
+            for kernel in _KERNELS:
+                rec = span.args[kernel]
+                rec["tiles_masked"] = rec["tiles_visited"]
+                rec["selection_block_bytes"] = _selection_bytes(
+                    getattr(plan, kernel))
+                rec["vmem_limit_bytes"] = _selected_vmem_limit(
+                    kernel, plan, itemsize)
 
 
 # ---------------------------------------------------------------------------
@@ -545,6 +609,10 @@ def _tile_mask(shape, q_axis, row0, col0, off, seq_k, causal, padded_k):
                                                       q_axis)
         mask = k_pos <= q_pos if mask is None else mask & (k_pos <= q_pos)
     return mask
+
+
+def _and(mask, other):
+    return other if mask is None else mask & other
 
 
 def _grid_pos(axis, n):
@@ -605,8 +673,13 @@ def _traced_inline(kernel):
 
 
 @_traced_inline
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_and_scratch,
-                      t, grid, sm_scale, causal, seq_q, seq_k, padded_k):
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
+                      seq_q, seq_k, padded_k, selected=False):
+    # with *selected* a selection block follows v: (1, res_q / 32, res_k)
+    # words, bit r % 32 of word r // 32 saying whether query row r of the
+    # block sees the key; every visited tile is then a masked one
+    sel_ref = rest[0] if selected else None
+    o_ref, *maybe_lse_and_scratch = rest[1:] if selected else rest
     if len(maybe_lse_and_scratch) == 4:
         lse_ref, acc_ref, m_ref, l_ref = maybe_lse_and_scratch
     else:  # inference path: no logsumexp output allocated
@@ -637,9 +710,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_and_scratch,
             v = v_ref[0, ks, :]
             s = _mxu_dot(q, k, _NT) * sm_scale      # (sub_q, sub_k)
             if masked:
-                s = jnp.where(_tile_mask(
+                mask = _tile_mask(
                     s.shape, 0, row0, ik * t.res_k + jk * t.sub_k, off,
-                    seq_k, causal, padded_k), s, _NEG_INF)
+                    seq_k, causal, padded_k)
+                if selected:
+                    mask = _and(mask, unpack_selection(sel_ref[
+                        0, _sub(jq, t.sub_q // _SEL_BITS, nqs), ks]))
+                s = jnp.where(mask, s, _NEG_INF)
             m_prev = m_ref[qs, :]                   # (sub_q, _LANES)
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -656,7 +733,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_and_scratch,
 
         n_full, n_vis = _k_tiles(row0, ik * t.res_k, nks, t, off, seq_k,
                                  causal)
-        _two_loops((0, n_full, n_vis, False), tile, causal or padded_k)
+        if selected:
+            n_full = 0
+        _two_loops((0, n_full, n_vis, False), tile,
+                   causal or padded_k or selected)
 
         @pl.when(ik == nkr - 1)
         def _finish():
@@ -689,6 +769,17 @@ def _pad_bh(x, s_to, d_to):
     if s_to != s or d_to != d:
         x = jnp.pad(x, ((0, 0), (0, 0), (0, s_to - s), (0, d_to - d)))
     return x.reshape(b * h, s_to, d_to)
+
+
+def _pad_selection(sel, rows, cols):
+    """A selection operand zero padded to *rows* packed rows and *cols*
+    columns: a padded row sees nothing and a padded column is seen by
+    nobody."""
+    groups = rows // _SEL_BITS
+    if sel.shape[1:] == (groups, cols):
+        return sel
+    return jnp.pad(sel, ((0, 0), (0, groups - sel.shape[1]),
+                         (0, cols - sel.shape[2])))
 
 
 def _unpad_bh(x, b, h, s, d):
@@ -738,11 +829,14 @@ _STATIC = ("causal", "sm_scale", "blk_q", "blk_k", "interpret", "res_q",
 @functools.partial(jax.jit, static_argnames=_STATIC + ("with_lse",))
 def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
                       interpret=False, with_lse=False, res_q=None,
-                      res_k=None):
+                      res_k=None, sel=None):
     """Flash forward: grid (B*H, resident q blocks, resident k blocks),
     f32 accumulators in VMEM scratch.  ``with_lse`` also returns the
     per-row logsumexp residual (the flash backward's recompute anchor)
-    as ``(B*H, 1, seq_q)``."""
+    as ``(B*H, 1, seq_q)``.  *sel* is a selection operand
+    (`pack_selection` of a ``(B, seq_q, seq_k)`` mask, one for all the
+    heads of a batch row): a query then sees a key only where its bit is
+    set, besides `causal` and the padding."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
     plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v)
@@ -760,6 +854,16 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
     kernel = functools.partial(
         _flash_fwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
         causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk)
+    in_specs, operands, params = [q_spec, k_spec, v_spec], (qp, kp, vp), {}
+    if sel is not None:
+        k_index = _k_index(t, nkr, sk - sq, causal)
+        kernel = functools.partial(kernel, selected=True)
+        in_specs.append(pl.BlockSpec(
+            (1, t.res_q // _SEL_BITS, t.res_k),
+            lambda bh_, iq, ik: (bh_ // h, iq, k_index(bh_, iq, ik))))
+        operands += (_pad_selection(sel, sq_p, sk_p),)
+        params["vmem_limit_bytes"] = _selected_vmem_limit(
+            "fwd", plan, q.dtype.itemsize)
     out_specs = [o_spec]
     out_shape = [jax.ShapeDtypeStruct((bh, sq_p, dvp), q.dtype)]
     if with_lse:  # training: also emit the logsumexp residual
@@ -769,7 +873,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
         res = pl.pallas_call(
             kernel,
             grid=(bh, nqr, nkr),
-            in_specs=[q_spec, k_spec, v_spec],
+            in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[
@@ -778,10 +882,11 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
                 pltpu.VMEM((t.res_q, _LANES), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                **params),
             interpret=interpret,
             name="mx_flash_fwd",
-        )(qp, kp, vp)
+        )(*operands)
     out = _unpad_bh(res[0], b, h, sq, d_v)
     if with_lse:
         return out, res[1][:, :, :sq]
@@ -828,12 +933,18 @@ def _wide(x, width):
 
 @_traced_inline
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, dq_acc=None,
-                      *, t, grid, sm_scale, causal, seq_q, seq_k,
-                      padded_k):
+                      *rest, t, grid, sm_scale, causal, seq_q, seq_k,
+                      padded_k, selected=False):
     """K/V block resident, Q/dO sub-tiles from the first visible row on;
     *dq_acc* spans the head's sequence, and without it this step's share
-    of dq accumulates in its f32 output block."""
+    of dq accumulates in its f32 output block.  With *selected* a
+    selection block follows delta, the scores' way round: (1, res_k / 32,
+    res_q) words, bit c % 32 of word c // 32 saying whether the query sees
+    key row c of the block."""
+    sel_ref = rest[0] if selected else None
+    dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *maybe_dq_acc = \
+        rest[1:] if selected else rest
+    dq_acc = maybe_dq_acc[0] if maybe_dq_acc else None
     nqr, nkr = grid
     ik, iq = _grid_pos(1, nkr), _grid_pos(2, nqr)
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
@@ -874,9 +985,13 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do = _wide(do_ref[0, qs, :], wide_v)
             s = _mxu_dot(k, q, _NT) * sm_scale      # (sub_k, sub_q)
             if masked:
-                s = jnp.where(_tile_mask(
+                mask = _tile_mask(
                     s.shape, 1, iq * t.res_q + jq * t.sub_q, col0, off,
-                    seq_k, causal, padded_k), s, _NEG_INF)
+                    seq_k, causal, padded_k)
+                if selected:
+                    mask = _and(mask, unpack_selection(sel_ref[
+                        0, _sub(jk, t.sub_k // _SEL_BITS, nks), qs]))
+                s = jnp.where(mask, s, _NEG_INF)
             p = jnp.exp(s - lse_ref[0, :, qs])
             # dv += p dO (the tile is p^T as the forward knew it) — p cast
             # to the storage dtype for a full-rate MXU dot; accumulators
@@ -893,7 +1008,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         j_first, j_full = _q_tiles(col0, iq * t.res_q, nqs, t, off, seq_k,
                                    causal)
-        _two_loops((j_first, j_full, nqs, True), tile, causal or padded_k)
+        if selected:
+            j_full = nqs
+        _two_loops((j_first, j_full, nqs, True), tile,
+                   causal or padded_k or selected)
 
         @pl.when(iq == nqr - 1)
         def _finish():
@@ -925,10 +1043,12 @@ def _dq_head_groups(bh, partial_bytes, sq):
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
                       blk_q=None, blk_k=None, interpret=False, res_q=None,
-                      res_k=None):
+                      res_k=None, sel=None):
     """dq, dk, dv from the forward's output and its ``(B*H, 1, seq_q)``
     logsumexp: grid (B*H, resident k blocks, resident q blocks), K/V
-    resident and Q/dO streamed."""
+    resident and Q/dO streamed.  *sel* is the forward's selection the
+    scores' way round here: `pack_selection` of the ``(B, seq_k, seq_q)``
+    transposed mask."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
     plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v)
@@ -973,17 +1093,29 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
                                lambda bh_, ik, iq: (bh_, ik, iq, 0))
         dq_shape = jax.ShapeDtypeStruct((heads, nkr, sq_p, dp), jnp.float32)
 
+    kernel = functools.partial(
+        _flash_bwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
+        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk)
+    in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
+    limit = _vmem_limit(plan, q.dtype.itemsize)
+    if sel is not None:
+        if heads != bh:
+            raise ValueError(
+                "flash attention backward: a selection operand with dq's "
+                "partials in HBM (a query sequence of %d) is not built" % sq)
+        kernel = functools.partial(kernel, selected=True)
+        in_specs.append(pl.BlockSpec(
+            (1, t.res_k // _SEL_BITS, t.res_q),
+            lambda bh_, ik, iq: (bh_ // h, ik, q_index(bh_, ik, iq))))
+        limit = _selected_vmem_limit("bwd", plan, q.dtype.itemsize)
+
     def call(operands):
         """The kernel over *heads* heads (axis 0 of every operand)."""
         with jax.named_scope("mx.flash.bwd"):
             dq, dk, dv = pl.pallas_call(
-                functools.partial(
-                    _flash_bwd_kernel, t=t, grid=(nqr, nkr),
-                    sm_scale=sm_scale, causal=causal, seq_q=sq, seq_k=sk,
-                    padded_k=sk_p != sk),
+                kernel,
                 grid=(heads, nkr, nqr),
-                in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec,
-                          row_spec],
+                in_specs=in_specs,
                 out_specs=[dq_spec, k_spec, v_spec],
                 out_shape=[dq_shape,
                            jax.ShapeDtypeStruct((heads, sk_p, dp), k.dtype),
@@ -993,7 +1125,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
                 compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel", "arbitrary",
                                          "arbitrary"),
-                    vmem_limit_bytes=_vmem_limit(plan, q.dtype.itemsize)),
+                    vmem_limit_bytes=limit),
                 interpret=interpret,
                 name="mx_flash_bwd",
             )(*operands)
@@ -1002,6 +1134,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
         return dq, dk, dv
 
     operands = (qp, kp, vp, dop, lse, delta)
+    if sel is not None:
+        operands += (_pad_selection(sel, sk_p, sq_p),)
     if heads == bh:
         dq, dk, dv = call(operands)
     else:
@@ -1086,6 +1220,124 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False,
     # lowered, so the Mosaic kernels are the program on a TPU and never
     # reach a CPU compile
     return jax.lax.platform_dependent(q, k, v, tpu=_tpu, default=_other)
+
+
+# ---------------------------------------------------------------------------
+# Causal attention over the keys a selection names (learned sparse
+# attention: `ops/sparse_attention.py` makes the selection).  The same two
+# kernels with a selection operand, one bit a query-key pair, shared by the
+# heads of a batch row; the blockwise `jax.numpy` body on other platforms.
+# ---------------------------------------------------------------------------
+
+#: query rows a step of the `jax.numpy` body holds scores for
+_SELECTED_BLOCK = 256
+
+
+def _selected_rows(q, k, v, sel_q, sm_scale):
+    """The `jax.numpy` body: blocks of query rows, one after another, each
+    against every key under its rows' bits and the diagonal; JAX
+    differentiates it, computing a block's scores again.  ``(out, lse (B,
+    H, S))``."""
+    b, h, s, d = q.shape
+    blk = _SELECTED_BLOCK if s % _SELECTED_BLOCK == 0 else s
+    n, groups = s // blk, -(-blk // _SEL_BITS)
+    cols = jnp.arange(s)
+
+    @jax.checkpoint
+    def rows(qb, words, row0):
+        att = jnp.einsum("bhqd,bhkd->bhqk", qb, k,
+                         precision=matmul_precision(qb.dtype, k.dtype),
+                         preferred_element_type=jnp.float32) * sm_scale
+        seen = unpack_selection(words, blk) \
+            & (cols[None, :] <= row0 + jnp.arange(blk)[:, None])
+        att = jnp.where(seen[:, None], att, _NEG_INF)
+        m = att.max(-1)
+        p = jnp.exp(att - m[..., None])
+        l = p.sum(-1)
+        o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                       precision=matmul_precision(v.dtype, v.dtype),
+                       preferred_element_type=jnp.float32)
+        return (o / l[..., None]).astype(q.dtype), m + jnp.log(l)
+
+    words = _pad_selection(sel_q, n * groups * _SEL_BITS, s)
+    out, lse = jax.lax.map(
+        lambda at: rows(*at),
+        (q.reshape(b, h, n, blk, d).transpose(2, 0, 1, 3, 4),
+         words.reshape(b, n, groups, s).transpose(1, 0, 2, 3),
+         jnp.arange(0, s, blk)))
+    return (out.transpose(1, 2, 0, 3, 4).reshape(b, h, s, v.shape[3]),
+            lse.transpose(1, 2, 0, 3).reshape(b, h, s))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_selected(q, k, v, sel_q, sel_k, sm_scale, interpret):
+    _record_plan(q, k, v, True, selected=True)
+    out, lse = _flash_fwd_pallas(q, k, v, True, sm_scale,
+                                 interpret=interpret, with_lse=True,
+                                 sel=sel_q)
+    return out, lse.reshape(q.shape[:3])
+
+
+def _flash_selected_fwd(q, k, v, sel_q, sel_k, sm_scale, interpret):
+    out, lse = _flash_selected(q, k, v, sel_q, sel_k, sm_scale, interpret)
+    return (out, lse), (q, k, v, out, lse, sel_k)
+
+
+def _flash_selected_bwd(sm_scale, interpret, res, g):
+    # the logsumexp leaves for the alignment term, which holds it fixed:
+    # its cotangent is not read
+    q, k, v, out, lse, sel_k = res
+    b, h, s, _ = q.shape
+    return _flash_bwd_pallas(q, k, v, out, lse.reshape(b * h, 1, s), g[0],
+                             True, sm_scale, interpret=interpret,
+                             sel=sel_k) + (None, None)
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+
+def mosaic_runs_here():
+    """Whether a Mosaic call traced here reaches one device: no mesh, a
+    mesh of one, or inside a `shard_map` over every mesh axis.  (XLA does
+    not partition a Mosaic kernel.)"""
+    from ..parallel.mesh import current_mesh
+    mesh = current_mesh()
+    return mesh is None or mesh.size == 1 or set(
+        jax.sharding.get_abstract_mesh().manual_axes) == set(mesh.axis_names)
+
+
+def selected_attention(q, k, v, sel_q, sel_k, sm_scale=None,
+                       interpret=False):
+    """Causal attention in which query ``t`` of a batch row sees key ``s``
+    only where the selection says so, (B, H, S, D) layout, equal head
+    counts: ``(out, lse)`` with ``lse`` (B, H, S) the logsumexp of each
+    row's visible scores (float32, carrying no gradient).
+
+    *sel_q* is `pack_selection` of the ``(B, S, S)`` mask (queries by
+    keys) and *sel_k* of its transpose, one selection for all the heads of
+    a batch row; a query has to see at least one key.  The flash kernels
+    with the selection as an operand where the program is lowered for the
+    TPU (one device, or inside a `shard_map` over every mesh axis), the
+    blockwise `jax.numpy` body elsewhere."""
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    dt = jnp.result_type(q.dtype, k.dtype, v.dtype)
+    q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
+    if interpret:
+        return _flash_selected(q, k, v, sel_q, sel_k, float(sm_scale), True)
+
+    def _tpu(q, k, v, sel_q, sel_k):
+        if not mosaic_runs_here():
+            # no cell spans chips with a selection
+            return _selected_rows(q, k, v, sel_q, float(sm_scale))
+        return _flash_selected(q, k, v, sel_q, sel_k, float(sm_scale),
+                               False)
+
+    def _other(q, k, v, sel_q, sel_k):
+        return _selected_rows(q, k, v, sel_q, float(sm_scale))
+
+    return jax.lax.platform_dependent(q, k, v, sel_q, sel_k, tpu=_tpu,
+                                      default=_other)
 
 
 # ---------------------------------------------------------------------------

@@ -67,19 +67,48 @@ def _rms_norm(data, gamma, eps=1e-5):
     return (y * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
-@register_op("_contrib_RotaryEmbedding", aliases=("RotaryEmbedding",))
-def _rotary(data, theta=10000.0, interleaved=False):
+def _rotary(data, theta=10000.0, interleaved=False, positions=None,
+            mrope_section=()):
     """Rotary positions on ``(batch, heads, seq, d)``: positions ``0 ..
     seq - 1``, frequencies ``theta ** (-2i / d)``, the angles in float32.
     Frequency ``i`` turns the pair ``(x[i], x[i + d/2])`` (rotate-half), or
     with *interleaved* the pair ``(x[2i], x[2i + 1])``.  The interleaved
     pairs are swapped by a product with a fixed signed permutation (exact:
-    every sum has one term), not by strided slices (a scatter backward)."""
+    every sum has one term), not by strided slices (a scatter backward).
+
+    With *positions* ``(axes, batch, seq)`` the positions are an operand
+    and have several axes (a text, height and width axis): the ``d / 2``
+    frequencies are dealt to the axes in chunks of *mrope_section* (``[16,
+    24, 24]``: the first 16 frequencies turn by axis 0's position, the next
+    24 by axis 1's, the last 24 by axis 2's), rotate-half.  Plain text has
+    all its axes equal to ``0 .. seq - 1``, which is what no *positions*
+    means: the one-axis arithmetic, to the letter."""
     s, d = data.shape[-2], data.shape[-1]
     half = d // 2
+    sections = tuple(int(n) for n in mrope_section) or (half,)
+    if sum(sections) != half:
+        raise ValueError("mrope_section %r has to cover the %d frequencies "
+                         "of a %d-wide head" % (sections, half, d))
     inv = 1.0 / (float(theta) ** (np.arange(half, dtype=np.float64) / half))
-    ang = np.arange(s, dtype=np.float64)[:, None] * inv[None, :]
     x = data.astype(jnp.float32)
+    if positions is not None:
+        if interleaved:
+            raise ValueError("positions as an operand turn rotate-half "
+                             "pairs; the interleaved form is not built")
+        if positions.shape[0] != len(sections):
+            raise ValueError("positions have %d axes, mrope_section %d"
+                             % (positions.shape[0], len(sections)))
+        pos = positions.astype(jnp.float32)
+        # (batch, seq, half): each frequency beside its own axis's position
+        ang = jnp.concatenate(
+            [jnp.broadcast_to(pos[a][..., None], pos.shape[1:] + (n,))
+             for a, n in enumerate(sections)], -1) \
+            * jnp.asarray(inv, jnp.float32)
+        cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, None]
+        sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, None]
+        rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
+        return (x * cos + rot * sin).astype(data.dtype)
+    ang = np.arange(s, dtype=np.float64)[:, None] * inv[None, :]
     if interleaved:
         cos = jnp.asarray(np.repeat(np.cos(ang), 2, -1), jnp.float32)
         sin = jnp.asarray(np.repeat(np.sin(ang), 2, -1), jnp.float32)
@@ -95,6 +124,23 @@ def _rotary(data, theta=10000.0, interleaved=False):
         sin = jnp.asarray(np.concatenate([np.sin(ang)] * 2, -1), jnp.float32)
         rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
     return (x * cos + rot * sin).astype(data.dtype)
+
+
+@register_op("_contrib_RotaryEmbedding", aliases=("RotaryEmbedding",),
+             input_names=("data", "positions"))
+def _rotary_embedding(data, *positions, theta=10000.0, interleaved=False,
+                      mrope_section=(), use_positions=False):
+    """`_rotary` as an operator: *positions* is an input with
+    ``use_positions``, and is not one without."""
+    return _rotary(data, theta, interleaved,
+                   positions[0] if positions else None, mrope_section)
+
+
+def _with_positions(*names):
+    def active(params):
+        return names + (("positions",) if params.get("use_positions")
+                        else ())
+    return active
 
 
 def _silu_mul(h, g):
@@ -206,6 +252,144 @@ def _latent_attention(data, q_weight, kv_a_weight, kv_norm_gamma,
                                                     heads * v_dim)
         with jax.named_scope("mx.mla.out"):
             return _dot(att, out_weight)
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention: grouped-query attention over the keys an indexer
+# chose, the indexer trained by an alignment term.
+# ---------------------------------------------------------------------------
+
+def _record_dsa_plan(data, heads, kv_heads, head_dim, index_heads,
+                     index_width, topk):
+    """One `mx.dsa.plan` span each time the op is traced (as
+    `mx.flash.plan`, which the attention's own call records beside it)."""
+    from . import sparse_attention
+    batch, seq = data.shape[0], data.shape[1]
+    with profiler.scope(  # graftlint: disable=JG003
+            "mx.dsa.plan", "dsa") as span:
+        span.args = dict(
+            sparse_attention.select_plan(seq, index_heads, index_width,
+                                         data.dtype),
+            batch=batch, tokens=seq, topk=min(topk, seq), heads=heads,
+            kv_heads=kv_heads, head_dim=head_dim, index_heads=index_heads,
+            index_head_dim=index_width, dtype=jnp.dtype(data.dtype).name,
+            # every causal tile is visited under the selection's bits: no
+            # tile is skipped and no key is gathered
+            form="masked flash: a selection operand, one bit a pair",
+            selection_bytes=2 * batch * -(-seq // 32) * seq * 4)
+
+
+def _fold_dsa_counts(rows):
+    rows = np.asarray(rows).reshape(-1, 2)
+    profiler.bump_counter("dsa_selected_keys_total", int(rows[:, 0].sum()))
+    profiler.bump_counter("dsa_visible_keys_total", int(rows[:, 1].sum()))
+
+
+def _fold_dsa_alignment(values):
+    from ..observability import metrics
+    metrics.gauge(
+        "dsa_alignment_loss", "the indexer's alignment term, the mean over "
+        "the last step's sparse attention layers").set(
+            float(np.mean(np.asarray(values, np.float64))))
+
+
+@register_op("_contrib_SparseAttention", aliases=("SparseAttention",),
+             num_outputs=2,
+             input_names=("data", "q_weight", "k_weight", "v_weight",
+                          "out_weight", "q_gamma", "k_gamma",
+                          "index_q_weight", "index_k_weight",
+                          "index_w_weight", "positions"))
+def _sparse_attention(data, q_weight, k_weight, v_weight, out_weight,
+                      q_gamma, k_gamma, index_q_weight, index_k_weight,
+                      index_w_weight, *positions, num_heads=1,
+                      num_kv_heads=1, index_heads=1, topk=2048,
+                      rope_theta=10000.0, mrope_section=(), eps=1e-6,
+                      use_positions=False):
+    """Causal grouped-query attention on ``(batch, seq, d)`` over the keys a
+    learned indexer chose (DeepSeek-V3.2-Exp's sparse attention), H =
+    *num_heads* query heads over *num_kv_heads* key/value heads, J =
+    *index_heads* indexer heads over one indexer key, no biases:
+
+    ``q = rope(rms_head(x Wq))``, ``k = rope(rms_head(x Wk))``, ``v = x Wv``
+    (RMS norm over each head, then rotary positions: `_rotary`, over
+    *positions* ``(axes, batch, seq)`` dealt by *mrope_section* with
+    ``use_positions``, text positions without);
+    on ``xd = stop_gradient(x)``: ``qI = xd WqI`` (J x di), ``kI = xd WkI``
+    (di), ``w = (xd Ww) * J^-1/2 * di^-1/2``; ``I[t, s] = sum_j w[t, j] *
+    relu(qI[t, j] . kI[s])``;
+    query ``t`` sees the causal keys whose ``I[t, s]`` is at least the
+    *topk*-th largest of its row (ties with it included; all of them where
+    there are no more than *topk*): a choice, which carries no gradient;
+    softmax attention over those keys, the heads joined, ``Wo``.
+
+    The indexer is trained by the alignment term ``L = mean_t KL(p[t, .] ||
+    softmax_{S_t} I[t, .])``, ``p`` the heads' mean probabilities held
+    fixed.  ``L`` is the op's SECOND output, shape ``(1,)`` in float32, for
+    the net to hand to the objective (`gluon.model_zoo.decoder`
+    `AlignedLoss`), so its gradient takes the loss's own scale: it is the
+    ONLY path to WqI, WkI and Ww, and it reaches nothing else; the first
+    output reaches every other weight and none of those three.  ``L`` also
+    leaves as gauge ``dsa_alignment_loss``, the chosen and the causal pairs
+    as counters ``dsa_selected_keys_total`` and ``dsa_visible_keys_total``
+    (from the bits the kernels were handed, so ties show).
+
+    Weights as ``FullyConnected`` holds them.  The selection is
+    `ops/sparse_attention.py` `index_select` (bits, one a pair; no ``S x
+    S`` array is written), the attention `ops/attention.py`
+    `selected_attention` (the flash kernels with the selection as an
+    operand, on the TPU), the term `alignment_term`."""
+    from . import sparse_attention
+    from .attention import selected_attention
+    heads, kv_heads, j = int(num_heads), int(num_kv_heads), int(index_heads)
+    batch, seq, _ = data.shape
+    head_dim = q_gamma.shape[0]
+    width = index_k_weight.shape[0]
+    pos = positions[0] if positions else None
+    _record_dsa_plan(data, heads, kv_heads, head_dim, j, width, int(topk))
+
+    def by_head(y, n, gamma=None):
+        # (B, S, n * hd) -> (B, n, S, hd), normed over hd and turned
+        y = y.reshape(batch, seq, n, head_dim)
+        if gamma is None:
+            return y.transpose(0, 2, 1, 3)
+        return _rotary(_rms_norm(y, gamma, eps).transpose(0, 2, 1, 3),
+                       float(rope_theta), False, pos, mrope_section)
+
+    with jax.named_scope("mx.dsa"):
+        with jax.named_scope("mx.dsa.project"):
+            q = by_head(_dot(data, q_weight), heads, q_gamma)
+            k = by_head(_dot(data, k_weight), kv_heads, k_gamma)
+            v = by_head(_dot(data, v_weight), kv_heads)
+            group = heads // kv_heads
+            k_all = jnp.repeat(k, group, axis=1) if group > 1 else k
+            v_all = jnp.repeat(v, group, axis=1) if group > 1 else v
+        with jax.named_scope("mx.dsa.index"):
+            xd = jax.lax.stop_gradient(data)
+            qi = _dot(xd, index_q_weight)
+            ki = _dot(xd, index_k_weight)
+            w = _dot(xd, index_w_weight).astype(jnp.float32) \
+                * (j ** -0.5 * width ** -0.5)
+        sel_q, sel_k, lse_i = sparse_attention.index_select(qi, ki, w,
+                                                            int(topk))
+        scale = head_dim ** -0.5
+        att, lse = selected_attention(q, k_all, v_all, sel_q, sel_k, scale)
+        term = sparse_attention.alignment_term(qi, ki, w, q, k, lse, lse_i,
+                                               sel_q, scale)
+        with jax.named_scope("mx.dsa.out"):
+            out = _dot(att.transpose(0, 2, 1, 3).reshape(
+                batch, seq, heads * head_dim), out_weight)
+        # at trace time on purpose (as the routed op's counts)
+        profiler.emit_step_stat(  # graftlint: disable=JG003
+            "dsa_key_counts", jnp.stack([
+                jnp.sum(jax.lax.population_count(sel_q), dtype=jnp.int32),
+                jnp.int32(batch * seq * (seq + 1) // 2)]))
+        profiler.emit_step_stat(  # graftlint: disable=JG003
+            "dsa_alignment_loss", term)
+        return out, term.reshape(1)
+
+
+profiler.register_step_stat("dsa_key_counts", _fold_dsa_counts)
+profiler.register_step_stat("dsa_alignment_loss", _fold_dsa_alignment)
 
 
 # ---------------------------------------------------------------------------
@@ -540,13 +724,29 @@ GROUPED_TILES = (512, 1024, 1024)
 BUFFER_FACTOR = 1.5
 
 
-def _route(x, router_weight, bias, top_k, norm_topk_prob, scaling):
-    """Sigmoid scores over all the router's experts, the top-k of score +
-    bias, and each chosen expert's weight, all in float32:
-    ``(chosen (N, k) int32, weights (N, k))``."""
-    scores = jax.nn.sigmoid(jnp.dot(
+def _route(x, router_weight, bias, top_k, norm_topk_prob, scaling,
+           scoring_func="sigmoid"):
+    """Scores over all the router's experts, the top-k, and each chosen
+    expert's weight, all in float32: ``(chosen (N, k) int32, weights (N,
+    k))``.  ``sigmoid``: the top-k of score + bias, a chosen score over the
+    chosen scores' sum + 1e-6, times *scaling*.  ``softmax``: a softmax over
+    all the experts, its top-k, a chosen gate over the chosen gates' sum;
+    no bias and no scaling."""
+    logits = jnp.dot(
         x.astype(jnp.float32), router_weight.astype(jnp.float32).T,
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    if scoring_func == "softmax":
+        if any(bias) or scaling != 1.0:
+            raise ValueError("a softmax router takes no expert_bias and no "
+                             "routed_scaling_factor")
+        weights, chosen = jax.lax.top_k(jax.nn.softmax(logits, -1), top_k)
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, -1, keepdims=True)
+        return chosen.astype(jnp.int32), weights
+    if scoring_func != "sigmoid":
+        raise ValueError("scoring_func %r is not sigmoid or softmax"
+                         % (scoring_func,))
+    scores = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(scores + jnp.asarray(bias, jnp.float32), top_k)
     weights = jnp.take_along_axis(scores, chosen, axis=1)
     if norm_topk_prob:
@@ -881,7 +1081,8 @@ def _record_moe_plan(tokens, router_experts, top_k, held, first_expert,
 @register_op("_contrib_RoutedExperts", aliases=("RoutedExperts",))
 def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
                     num_experts_per_tok=1, first_expert=0,
-                    norm_topk_prob=True, routed_scaling_factor=1.0):
+                    norm_topk_prob=True, routed_scaling_factor=1.0,
+                    scoring_func="sigmoid"):
     """Dropless top-k routed experts, one chip's share.
 
     data ``(..., d)``; router_weight ``(E, d)`` over ALL the layer's
@@ -891,8 +1092,11 @@ def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
     chosen are the top-k of ``s + expert_bias`` (a fixed attribute, not
     trained), a chosen expert's weight is ``s_e / (sum of the chosen s +
     1e-6)`` (``norm_topk_prob``) times ``routed_scaling_factor``, all in
-    float32.  The result is the held experts' part, ``sum over e chosen
-    and held of w_e * W2_e(silu(W1_e x) * W3_e x)``: what the absent
+    float32; with *scoring_func* ``softmax``, ``g = softmax(x W_r)``, the
+    chosen are the top-k of ``g`` and a weight is ``g_e / (sum of the
+    chosen g)``, with no bias and no scaling.  The result is the held
+    experts' part, ``sum over e chosen and held of w_e * W2_e(silu(W1_e x)
+    * W3_e x)``: what the absent
     experts would add is left out.  No capacity and no dropped token:
     token-expert pairs are sorted by expert into a buffer of
     `_buffer_rows` rows, the three products run over the groups that
@@ -913,7 +1117,8 @@ def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
     with jax.named_scope("mx.moe.route"):
         chosen, weights = _route(x, router_weight, bias, top_k,
                                  bool(norm_topk_prob),
-                                 float(routed_scaling_factor))
+                                 float(routed_scaling_factor),
+                                 str(scoring_func))
         # at trace time on purpose: it hands the traced counts to the
         # program that is being traced, which returns them every step
         profiler.emit_step_stat(  # graftlint: disable=JG003
@@ -925,3 +1130,11 @@ def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
 
 
 profiler.register_step_stat("moe_expert_counts", _fold_expert_counts)
+
+
+from .registry import get_op as _get_op  # noqa: E402
+
+_get_op("_contrib_RotaryEmbedding").active_inputs = _with_positions("data")
+_get_op("_contrib_SparseAttention").active_inputs = _with_positions(
+    "data", "q_weight", "k_weight", "v_weight", "out_weight", "q_gamma",
+    "k_gamma", "index_q_weight", "index_k_weight", "index_w_weight")

@@ -296,7 +296,12 @@ def test_interleaved_rotary_is_the_pairs_and_gives_the_source_s_scores():
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(2, 3, 40, 8)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(2, 1, 40, 8)), jnp.float32)
-    rot = get_op("_contrib_RotaryEmbedding").fn
+    def rot(x, theta, interleaved=False):
+        # attributes by keyword: what follows the data positionally is an
+        # input (the positions, since PR 33)
+        return get_op("_contrib_RotaryEmbedding").fn(
+            x, theta=theta, interleaved=interleaved)
+
     # the pairs by hand: (x[2i], x[2i+1]) turned by pos * theta^(-2i/d)
     pos = np.arange(40)[:, None]
     ang = pos * (1e6 ** (-np.arange(0, 8, 2) / 8))[None, :]
