@@ -19,8 +19,14 @@ between its two projections) is a pair of Mosaic kernels of this module,
 for the TPU and the shape tiles (`_shortconv_plan`: one device, ``d`` a
 multiple of 128, the sequence in whole tiles), and `_gate_body`, the
 same arithmetic in `jax.numpy`, on every other platform and at every other
-shape.  Both are decided by what the code sees in its input and by the
-platform it is lowered for: no argument, environment variable or switch.
+shape.  The sparse attention block's per-head norm of q and k, their
+rotary positions and their move to the head-major layout are
+`_head_norm_rotary`, the Mosaic pair ``mx_headrope_fwd`` and
+``mx_headrope_bwd``, on the same terms (`_headrope_plan`: one device, heads
+of whole 128-lane tiles, the sequence in whole tiles), and `_rotary` over
+`_rms_norm` at every other shape.  All are decided by what the code sees in
+its input and by the platform it is lowered for: no argument, environment
+variable or switch.
 The latent attention block's core is `ops/attention.py` `flash_attention`
 at two head widths (the Mosaic kernels on the TPU, the chunked scan
 elsewhere); its projections and its assembly of q and k are XLA's.
@@ -313,7 +319,9 @@ def _sparse_attention(data, q_weight, k_weight, v_weight, out_weight,
     ``q = rope(rms_head(x Wq))``, ``k = rope(rms_head(x Wk))``, ``v = x Wv``
     (RMS norm over each head, then rotary positions: `_rotary`, over
     *positions* ``(axes, batch, seq)`` dealt by *mrope_section* with
-    ``use_positions``, text positions without);
+    ``use_positions``, text positions without; where `_headrope_plan` gives
+    tiles, `_head_norm_rotary`: the same in one pass each way, rounded
+    once, and `mx.headrope.plan` says ``path: kernel``);
     on ``xd = stop_gradient(x)``: ``qI = xd WqI`` (J x di), ``kI = xd WkI``
     (di), ``w = (xd Ww) * J^-1/2 * di^-1/2``; ``I[t, s] = sum_j w[t, j] *
     relu(qI[t, j] . kI[s])``;
@@ -347,12 +355,22 @@ def _sparse_attention(data, q_weight, k_weight, v_weight, out_weight,
     pos = positions[0] if positions else None
     _record_dsa_plan(data, heads, kv_heads, head_dim, j, width, int(topk))
 
+    tables = []
+
     def by_head(y, n, gamma=None):
         # (B, S, n * hd) -> (B, n, S, hd), normed over hd and turned
-        y = y.reshape(batch, seq, n, head_dim)
         if gamma is None:
-            return y.transpose(0, 2, 1, 3)
-        return _rotary(_rms_norm(y, gamma, eps).transpose(0, 2, 1, 3),
+            return y.reshape(batch, seq, n, head_dim).transpose(0, 2, 1, 3)
+        plan, why = _headrope_plan(y, n, pos, mrope_section)
+        if plan and not tables:
+            # once an op: q and k turn by the same angles
+            tables.extend(jax.lax.stop_gradient(t) for t in _rotary_tables(
+                seq, head_dim, rope_theta, pos, mrope_section))
+        _record_headrope_plan(y, n, plan, why, tables)
+        if plan:
+            return _head_norm_rotary(y, gamma, *tables, n, float(eps))
+        return _rotary(_rms_norm(y.reshape(batch, seq, n, head_dim), gamma,
+                                 eps).transpose(0, 2, 1, 3),
                        float(rope_theta), False, pos, mrope_section)
 
     with jax.named_scope("mx.dsa"):
@@ -453,7 +471,6 @@ def _shortconv_plan(bcx, conv_weight):
     cut to divide it), the sequence in whole tiles of both kernels, the
     taps within a halo block and the 8 rows their gradient is summed in,
     blocks within `_SHORTCONV_VMEM`."""
-    from ..parallel.mesh import current_mesh
     tiles, (d, taps) = SHORTCONV_TILES, conv_weight.shape
     if bcx.ndim != 3 or jnp.dtype(bcx.dtype).itemsize not in (2, 4) \
             or d % 128 or not 2 <= taps <= 8 \
@@ -462,10 +479,7 @@ def _shortconv_plan(bcx, conv_weight):
     if any(_shortconv_blocks(k, tiles[k], d, bcx.dtype) > _SHORTCONV_VMEM
            for k in ("fwd", "bwd")):
         return None
-    mesh = current_mesh()
-    if mesh is not None and mesh.size > 1 and set(
-            jax.sharding.get_abstract_mesh().manual_axes) != set(
-                mesh.axis_names):
+    if not _one_device():
         # XLA does not partition a Mosaic kernel, and no cell spans chips
         return None
     return dict(tiles, channels=_fit(d, tiles["channels"]))
@@ -702,6 +716,288 @@ def _gated_short_conv(data, in_weight, conv_weight, out_weight):
     shape it is `_gate_body`, the same arithmetic in `jax.numpy`."""
     with jax.named_scope("mx.shortconv"):
         return _dot(_gate(_dot(data, in_weight), conv_weight), out_weight)
+
+
+# ---------------------------------------------------------------------------
+# A projection's output to normed, turned heads: the RMS norm over each
+# head, rotate-half positions and the move from ``(batch, seq, heads x d)``
+# to ``(batch, heads, seq, d)`` in one pass over the data each way.  On the
+# TPU a pair of Mosaic kernels; everywhere else `_headrope_body`.
+# ---------------------------------------------------------------------------
+
+#: rows of the sequence a grid step of each kernel holds, and the most heads
+#: it takes at a time (a block of the flat projection is ``heads`` column
+#: blocks of ``d``; the head-major array's block is their transpose, which
+#: the two index maps make and no copy).  From `tools/headrope_sweep.py` on
+#: the v5e at (1, 16384, 32 x 128) bf16, device ms a call (PERF.md section
+#: 6, PR 34; 268 MB move forward, 403 backward): whole rows win, forward
+#: 0.435 at 256 rows x 32 heads (75% of 819 GB/s), 0.452 at 512 x 8, 0.463
+#: at 1024 x 4, 0.486 at 256 x 16, 0.502 at 256 x 8, 0.539 at 2048 x 1,
+#: 0.802 at 512 x 1; backward 0.655 at 128 x 32, 0.662 at 256 x 32 (74%),
+#: 0.678 at 1024 x 4, 0.696 at 512 x 8, 0.736 at 256 x 8, 0.947 at 512 x
+#: 1; 512 x 32 is over the VMEM.  At k's 4 heads 256 rows take 0.088 and
+#: 0.112 where 1024 take 0.076 and 0.099: 0.1 ms a step, left.  Not
+#: options: the sweep sets them to compare
+HEADROPE_TILES = {"fwd": 256, "bwd": 256, "heads": 32}
+
+#: what a kernel's blocks and temporaries (`_headrope_blocks`) may take of
+#: the 16 MiB of VMEM that a Mosaic kernel is given on the v5e: the backward
+#: kernel's at 256 rows x 32 heads of 128 in bf16 count 13.75
+_HEADROPE_VMEM = 15 << 20
+
+
+def _rotary_tables(seq, d, theta, positions, sections):
+    """``(cos, sin)`` ``(1 or batch, seq, d)`` in float32 from `_rotary`'s
+    own arithmetic for rotate-half pairs (float64 angles of ``0 .. seq - 1``
+    rounded once; with *positions* ``(axes, batch, seq)`` the operand's, in
+    float32, each frequency beside its own axis's position), the sign of
+    ``rot = [-x2, x1]`` folded into the sine's first half: ``x * cos +
+    roll(x, d / 2) * sin`` is `_rotary`'s ``x * cos + rot * sin`` bit for
+    bit."""
+    half = d // 2
+    sections = tuple(int(n) for n in sections) or (half,)
+    inv = 1.0 / (float(theta) ** (np.arange(half, dtype=np.float64) / half))
+    if positions is None:
+        ang = np.arange(seq, dtype=np.float64)[:, None] * inv[None, :]
+        cos, sin = np.cos(ang), np.sin(ang)
+        return (jnp.asarray(np.concatenate([cos, cos], -1)[None], jnp.float32),
+                jnp.asarray(np.concatenate([-sin, sin], -1)[None], jnp.float32))
+    pos = positions.astype(jnp.float32)
+    ang = jnp.concatenate(
+        [jnp.broadcast_to(pos[a][..., None], pos.shape[1:] + (n,))
+         for a, n in enumerate(sections)], -1) * jnp.asarray(inv, jnp.float32)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return (jnp.concatenate([cos, cos], -1),
+            jnp.concatenate([-sin, sin], -1))
+
+
+def _headrope_body(y, gamma, cos, sin, heads, eps):
+    """``_rotary(_rms_norm(y by head, gamma).transpose(0, 2, 1, 3))`` from
+    the tables, to the bit: the norm rounded to the input's dtype, then the
+    rotation rounded again."""
+    batch, seq, _ = y.shape
+    x = _rms_norm(y.reshape(batch, seq, heads, -1), gamma, eps).transpose(
+        0, 2, 1, 3).astype(jnp.float32)
+    rolled = jnp.roll(x, x.shape[-1] // 2, -1)
+    return (x * cos[:, None] + rolled * sin[:, None]).astype(y.dtype)
+
+
+def _one_device():
+    """Whether XLA would have nothing to partition here: no mesh of several
+    devices around the op, or every axis of it manual (a `shard_map`)."""
+    from ..parallel.mesh import current_mesh
+    mesh = current_mesh()
+    return mesh is None or mesh.size == 1 or set(
+        jax.sharding.get_abstract_mesh().manual_axes) == set(mesh.axis_names)
+
+
+def _headrope_blocks(kernel, rows, heads, d, dtype):
+    """Bytes of VMEM one grid step of *kernel* takes: its blocks, each held
+    twice (the flat and the head-major one, in the backward kernel ``dy``
+    too, and the two tables' rows), and a head's float32 temporaries (ten
+    of them live in the backward kernel)."""
+    wide = {"fwd": 2, "bwd": 3}[kernel]
+    return rows * d * (2 * (wide * heads * jnp.dtype(dtype).itemsize + 2 * 4)
+                       + 10 * 4)
+
+
+def _headrope_plan(y, heads, positions=None, sections=()):
+    """``(tiles, None)`` where `_head_norm_rotary` takes this projection,
+    ``(None, why not)`` where the caller keeps `_rotary` over `_rms_norm`
+    (which also says what is wrong with positions or sections it cannot
+    turn by).  The kernels take ``(batch, seq, heads x d)`` on one device:
+    ``d`` whole 128-lane tiles, the sequence in whole tiles of both
+    kernels, a grid step within `_HEADROPE_VMEM`."""
+    tiles = HEADROPE_TILES
+    d = y.shape[-1] // heads
+    n = len(sections) or 1
+    if y.ndim != 3 or jnp.dtype(y.dtype).itemsize not in (2, 4):
+        return None, "not (batch, seq, width) in 2 or 4 bytes"
+    if d % 128:
+        return None, "a head of %d is not whole 128-lane tiles" % d
+    if any(y.shape[1] % tiles[k] for k in ("fwd", "bwd")):
+        return None, "a sequence of %d is not whole tiles of %d and %d" % (
+            y.shape[1], tiles["fwd"], tiles["bwd"])
+    if sum(int(s) for s in sections or (d // 2,)) != d // 2 or (
+            positions is not None and positions.shape != (n,) + y.shape[:2]):
+        return None, "positions or sections `_rotary` refuses"
+    # the most heads a grid step can take: a divisor of them, within the
+    # sweep's cap and, with both kernels' blocks, within the VMEM budget
+    at_once = next((h for h in range(min(heads, tiles["heads"]), 0, -1)
+                    if heads % h == 0 and all(
+                        _headrope_blocks(k, tiles[k], h, d, y.dtype)
+                        <= _HEADROPE_VMEM for k in ("fwd", "bwd"))), None)
+    if at_once is None:
+        return None, "blocks over the VMEM budget"
+    at = dict(tiles, heads=at_once)
+    if not _one_device():
+        # XLA does not partition a Mosaic kernel, and no cell spans chips
+        return None, "a mesh of several devices"
+    return at, None
+
+
+def _record_headrope_plan(y, heads, plan, why, tables):
+    """One `mx.headrope.plan` span each time a projection is handed over
+    (as `mx.flash.plan`: the plan is a fact of the compiled program)."""
+    with profiler.scope(  # graftlint: disable=JG003
+            "mx.headrope.plan", "headrope") as span:
+        span.args = {
+            "shape": list(y.shape), "dtype": jnp.dtype(y.dtype).name,
+            "heads": heads, "head_dim": y.shape[-1] // heads,
+            "path": "xla" if plan is None else "kernel", "why": why,
+            "seq_tile": plan and {k: plan[k] for k in ("fwd", "bwd")},
+            "head_tile": plan and plan["heads"],
+            "table_bytes": plan and sum(t.size * t.dtype.itemsize
+                                        for t in tables),
+            # what `_head_norm_rotary` keeps for the backward pass: the
+            # projection as the product wrote it, and the norm's scale
+            "residual_bytes": plan and y.size * y.dtype.itemsize
+            + y.shape[-1] // heads * 4}
+
+
+def _headrope_fwd_kernel(y_ref, g_ref, cos_ref, sin_ref, out_ref, *, d, eps):
+    """A ``(rows, heads x d)`` block of the projection to ``(heads, rows,
+    d)`` of the head-major array, a head at a time: all of it in float32,
+    rounded once."""
+    gamma, cos, sin = g_ref[...], cos_ref[0], sin_ref[0]
+    for h in range(out_ref.shape[1]):
+        x = y_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+        n = x * jax.lax.rsqrt(
+            jnp.sum(x * x, -1, keepdims=True) * (1.0 / d) + eps) * gamma
+        out_ref[0, h] = (n * cos + pltpu.roll(n, d // 2, 1) * sin).astype(
+            out_ref.dtype)
+
+
+def _headrope_bwd_kernel(y_ref, g_ref, cos_ref, sin_ref, dout_ref, dy_ref,
+                         dg_ref, *, d, eps):
+    """The block's ``dy`` into the flat layout and its part of the scale's
+    gradient.  The rotation is orthogonal, so the cotangent turns back by
+    the transposed roll (a roll by ``d / 2`` again); the head's ``rsqrt``
+    is computed again from the kept projection."""
+    gamma, cos, sin = g_ref[...], cos_ref[0], sin_ref[0]
+    dgamma = jnp.zeros_like(gamma)
+    for h in range(dout_ref.shape[1]):
+        x = y_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+        dout = dout_ref[0, h].astype(jnp.float32)
+        r = jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) * (1.0 / d) + eps)
+        xh = x * r
+        dn = dout * cos + pltpu.roll(dout * sin, d // 2, 1)
+        dgamma += jnp.sum(dn * xh, 0, keepdims=True)
+        dxh = dn * gamma
+        dy_ref[0, :, h * d:(h + 1) * d] = (r * (dxh - xh * (
+            jnp.sum(dxh * xh, -1, keepdims=True) * (1.0 / d)))).astype(
+                dy_ref.dtype)
+    dg_ref[0] = dgamma
+
+
+def _headrope_specs(y, cos, heads, rows, at_once):
+    """The grid (batch, sequence tile, group of heads, the heads innermost
+    so that a tile's rows of the tables are fetched once) and the block of
+    each operand: flat, head-major, the scale, a table."""
+    batch, seq, width = y.shape
+    d = width // heads
+    per_row = cos.shape[0] > 1
+    return (batch, seq // rows, heads // at_once), d, (
+        pl.BlockSpec((1, rows, at_once * d), lambda b, s, h: (b, s, h)),
+        pl.BlockSpec((1, at_once, rows, d), lambda b, s, h: (b, h, s, 0)),
+        pl.BlockSpec((1, d), lambda b, s, h: (0, 0)),
+        pl.BlockSpec((1, rows, d),
+                     lambda b, s, h: (b if per_row else 0, s, 0)))
+
+
+_HEADROPE_STATIC = ("heads", "eps", "rows", "at_once", "interpret")
+
+
+# jitted, so q's and k's calls of a step's four layers share two traces
+# and two Mosaic programs of each kernel
+
+@functools.partial(jax.jit, static_argnames=_HEADROPE_STATIC)
+def _headrope_fwd_pallas(y, gamma, cos, sin, heads, eps, rows, at_once,
+                         interpret=False):
+    grid, d, (flat, by_head, scale, table) = _headrope_specs(
+        y, cos, heads, rows, at_once)
+    with jax.named_scope("mx.dsa.project.headrope"):
+        return pl.pallas_call(
+            functools.partial(_headrope_fwd_kernel, d=d, eps=eps),
+            grid=grid, in_specs=[flat, scale, table, table],
+            out_specs=by_head,
+            out_shape=jax.ShapeDtypeStruct(
+                (y.shape[0], heads, y.shape[1], d), y.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",) * 3),
+            interpret=interpret, name="mx_headrope_fwd",
+        )(y, gamma.astype(jnp.float32).reshape(1, d), cos, sin)
+
+
+@functools.partial(jax.jit, static_argnames=_HEADROPE_STATIC)
+def _headrope_bwd_pallas(y, gamma, cos, sin, dout, heads, eps, rows, at_once,
+                         interpret=False):
+    grid, d, (flat, by_head, scale, table) = _headrope_specs(
+        y, cos, heads, rows, at_once)
+    with jax.named_scope("mx.dsa.project.headrope"):
+        dy, dgamma = pl.pallas_call(
+            functools.partial(_headrope_bwd_kernel, d=d, eps=eps),
+            grid=grid, in_specs=[flat, scale, table, table, by_head],
+            out_specs=[flat, pl.BlockSpec(
+                (1, 1, d), lambda b, s, h: (
+                    (b * grid[1] + s) * grid[2] + h, 0, 0))],
+            out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
+                       jax.ShapeDtypeStruct((math.prod(grid), 1, d),
+                                            jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",) * 3),
+            interpret=interpret, name="mx_headrope_bwd",
+        )(y, gamma.astype(jnp.float32).reshape(1, d), cos, sin, dout)
+        return dy, jnp.sum(dgamma, (0, 1)).astype(gamma.dtype)
+
+
+def _headrope_body_backward(y, gamma, cos, sin, dout, heads, eps):
+    return jax.vjp(functools.partial(_headrope_body, heads=heads, eps=eps),
+                   y, gamma, cos, sin)[1](dout)[:2]
+
+
+def _headrope_forward(y, gamma, cos, sin, heads, eps):
+    tiles, _ = _headrope_plan(y, heads)
+    return jax.lax.platform_dependent(
+        y, gamma, cos, sin,
+        default=functools.partial(_headrope_body, heads=heads, eps=eps),
+        tpu=functools.partial(_headrope_fwd_pallas, heads=heads, eps=eps,
+                              rows=tiles["fwd"], at_once=tiles["heads"]))
+
+
+def _headrope_backward(heads, eps, kept, dout):
+    y, gamma, cos, sin = kept
+    tiles, _ = _headrope_plan(y, heads)
+    dy, dgamma = jax.lax.platform_dependent(
+        y, gamma, cos, sin, dout,
+        default=functools.partial(_headrope_body_backward, heads=heads,
+                                  eps=eps),
+        tpu=functools.partial(_headrope_bwd_pallas, heads=heads, eps=eps,
+                              rows=tiles["bwd"], at_once=tiles["heads"]))
+    # the tables are positions, which are data: nothing flows back to them
+    return dy, dgamma, jnp.zeros_like(cos), jnp.zeros_like(sin)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _head_norm_rotary(y, gamma, cos, sin, heads, eps):
+    """A projection's output ``(batch, seq, heads x d)`` as the product
+    wrote it to ``(batch, heads, seq, d)``, each head normed (RMS over
+    ``d``, scaled by *gamma*) and turned by the tables of `_rotary_tables`,
+    at a shape `_headrope_plan` gives tiles for.  Where the program is
+    lowered for the TPU the kernels ``mx_headrope_fwd`` and
+    ``mx_headrope_bwd`` read every element once and write it once each way:
+    nothing in float32 leaves the chip, the result is rounded once, and the
+    projection alone is kept for the backward pass.  Lowered for anything
+    else it is `_headrope_body` and JAX's derivative of it (from ``y``
+    again)."""
+    return _headrope_forward(y, gamma, cos, sin, heads, eps)
+
+
+_head_norm_rotary.defvjp(
+    lambda y, gamma, cos, sin, heads, eps: (
+        _headrope_forward(y, gamma, cos, sin, heads, eps),
+        (y, gamma, cos, sin)),
+    _headrope_backward)
 
 
 # ---------------------------------------------------------------------------

@@ -680,23 +680,13 @@ def v5e():
         pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
 
 
-@pytest.fixture()
-def no_persistent_cache():
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", True)
-    cc.reset_cache()
-
-
-def test_a_layer_compiles_for_the_described_chip_with_no_square_array(
-        v5e, no_persistent_cache):
+@pytest.fixture(scope="module")
+def compiled_layer(v5e):
     """The operator's forward and backward at the cell's size (16384 tokens,
-    32 / 4 heads of 128, 16 indexer heads of 64, 2048 keys a query): Mosaic
-    and XLA:TPU take the four kernels with what they ask for, and no array
-    in the compiled program has two axes of 16384 (no S x S scores, mask or
-    probabilities; the selection is (1, 512, 16384) words)."""
+    32 / 4 heads of 128, 16 indexer heads of 64, 2048 keys a query),
+    compiled once for the described chip with the persistent cache off
+    (such a compile is written to it and cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.sharding import SingleDeviceSharding
     one = SingleDeviceSharding(v5e.devices[0])
     s, d = 16384, 2048
@@ -715,16 +705,70 @@ def test_a_layer_compiles_for_the_described_chip_with_no_square_array(
             return jnp.sum(out.astype(jnp.float32) * dout) + term[0]
         return jax.grad(objective, argnums=(0, 1))(x, weights)
 
-    compiled = jax.jit(step).lower(
-        aval(1, s, d), [aval(*shape) for shape in shapes],
-        aval(1, s, d, dt=jnp.float32)).compile()
-    text = compiled.as_text()
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return jax.jit(step).lower(
+            aval(1, s, d), [aval(*shape) for shape in shapes],
+            aval(1, s, d, dt=jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+
+
+def test_a_layer_compiles_for_the_described_chip_with_no_square_array(
+        compiled_layer):
+    """Mosaic and XLA:TPU take the four kernels with what they ask for, and
+    no array in the compiled program has two axes of 16384 (no S x S scores,
+    mask or probabilities; the selection is (1, 512, 16384) words)."""
+    text = compiled_layer.as_text()
     for kernel in ("mx_dsa_select", "mx_flash_fwd", "mx_flash_bwd",
                    "mx_dsa_align"):
         assert kernel in text, kernel
     assert not re.search(r"\[[0-9,]*16384,[0-9,]*16384", text)
     assert "s32[1,512,16384]" in text
-    # all the temporaries together (q, the repeated k and v, their float32
-    # forms under the norms and the rotation, the gradients of all of them)
-    # are 1.47 GB: what ONE S x S array of float32 a head would be 32 times
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.6 * (1 << 30)
+    # all the temporaries together (q, the repeated k and v, the gradients
+    # of all of them) are 1.2 GB: what ONE S x S array of float32 a head
+    # would be 32 times
+    assert compiled_layer.memory_analysis().temp_size_in_bytes \
+        < 1.6 * (1 << 30)
+
+
+def written_arrays(hlo_text):
+    """``[(opcode, result type)]`` of the instructions whose results a
+    compiled program writes to memory: all but the ones inside fused
+    computations, and the parameters."""
+    fused = set(re.findall(r"fusion\(.*?calls=%([\w.\-]+)", hlo_text))
+    out, inside = [], False
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            inside = head.group(1) in fused
+            continue
+        m = re.match(r"^\s+(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if m and not inside and m.group(2) != "parameter":
+            out.append((m.group(2), m.group(1)))
+    return out
+
+
+def test_the_compiled_layer_writes_no_float32_array_of_q_s_size(
+        compiled_layer):
+    """The per-head norms, the rotary positions and the move to the
+    head-major layout are the two kernels `mx_headrope_fwd` and
+    `mx_headrope_bwd`, for q and for k: the program writes no float32 array
+    of q's 67.1 M elements, in any order of its axes, nor one of its halves
+    (before PR 34 it wrote ``f32[1,32,16384,128]`` twice and the rotation's
+    ``f32[1,32,16384,64]`` eight times), and each kernel lies under
+    `mx.dsa.project` and the pair's own scope inside it."""
+    text = compiled_layer.as_text()
+    half = 32 * 16384 * 128 // 2
+    large = [(op, shape) for op, result in written_arrays(text)
+             for shape, dims in re.findall(r"(f32\[([0-9,]+)\])", result)
+             if np.prod([int(n) for n in dims.split(",")]) >= half]
+    assert not large, large
+    assert len(written_arrays(text)) > 100      # the parse found the program
+    for kernel, calls in (("mx_headrope_fwd", 2), ("mx_headrope_bwd", 2)):
+        found = re.findall(
+            r'custom-call\(.*op_name="[^"]*/mx\.dsa\.project/[^"]*/'
+            r'mx\.dsa\.project\.headrope/%s/' % kernel, text)
+        assert len(found) == calls, (kernel, len(found))

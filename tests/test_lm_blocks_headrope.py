@@ -1,0 +1,261 @@
+"""`ops/lm_blocks.py` `_head_norm_rotary`: a projection's output to normed,
+turned heads in head-major layout, the Mosaic pair `mx_headrope_fwd` and
+`mx_headrope_bwd` (interpreted here) against what the sparse attention
+operator ran before it, `_rotary(_rms_norm(y by head, gamma).transpose(0, 2,
+1, 3))`, and the path `_contrib_SparseAttention` chooses for an input."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mxnet_tpu import profiler
+from mxnet_tpu.ops import lm_blocks
+from mxnet_tpu.ops.registry import get_op
+
+D, SEQ, THETA, SECTIONS, EPS = 128, 64, 1e7, (16, 24, 24), 1e-6
+
+
+def today(y, gamma, heads, positions=None):
+    batch, seq, _ = y.shape
+    return lm_blocks._rotary(
+        lm_blocks._rms_norm(y.reshape(batch, seq, heads, -1), gamma,
+                            EPS).transpose(0, 2, 1, 3),
+        THETA, False, positions, SECTIONS)
+
+
+def arguments(heads, dtype, batch=2, seed=0):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(batch, SEQ, heads * D)), dtype),
+            jnp.asarray(1 + 0.3 * rng.normal(size=D), dtype),
+            jnp.asarray(rng.normal(size=(batch, heads, SEQ, D)), dtype))
+
+
+def three_axes(batch=2):
+    """Positions as a vision tower's tokens have them: the three axes
+    differ, and so do the batch rows."""
+    rng = np.random.default_rng(1)
+    return jnp.asarray(rng.integers(0, 300, size=(3, batch, SEQ)),
+                       jnp.float32)
+
+
+POSITIONS = {"text": lambda: None, "three-axis": three_axes}
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """Steers `_head_norm_rotary` onto its TPU branch on this CPU host, the
+    two kernels interpreted, at tiles small enough for a test; every other
+    choice by platform (the attention's, the selection's) stays the
+    CPU's."""
+    real = jax.lax.platform_dependent
+    mine = (lm_blocks._headrope_fwd_pallas, lm_blocks._headrope_bwd_pallas)
+
+    def choose(*args, tpu, default):
+        if getattr(tpu, "func", None) in mine:
+            return tpu(*args, interpret=True)
+        return real(*args, tpu=tpu, default=default)
+
+    monkeypatch.setattr(lm_blocks, "HEADROPE_TILES",
+                        {"fwd": 32, "bwd": 16, "heads": 8})
+    monkeypatch.setattr(jax.lax, "platform_dependent", choose)
+
+
+def close(got, want, name):
+    # sums of a hundred and more terms in another order: to a float32
+    # rounding of the largest, not of each
+    want = np.asarray(want)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
+                               atol=2e-6 * np.abs(want).max(), err_msg=name)
+
+
+@pytest.mark.parametrize("positions", sorted(POSITIONS))
+@pytest.mark.parametrize("heads", [32, 4])
+def test_the_kernels_give_today_s_value_and_gradients(interpreted, heads,
+                                                      positions):
+    """Float32 on both sides, so only the order of a few sums differs (and
+    the one rounding the kernels leave out rounds nothing)."""
+    y, gamma, dout = arguments(heads, jnp.float32)
+    pos = POSITIONS[positions]()
+    cos, sin = lm_blocks._rotary_tables(SEQ, D, THETA, pos, SECTIONS)
+    assert cos.shape == sin.shape == (1 if pos is None else 2, SEQ, D)
+    assert lm_blocks._headrope_plan(y, heads, pos, SECTIONS)[0] == {
+        "fwd": 32, "bwd": 16, "heads": min(heads, 8)}
+    got, back = jax.vjp(lambda y, g: lm_blocks._head_norm_rotary(
+        y, g, cos, sin, heads, EPS), y, gamma)
+    want, want_back = jax.vjp(lambda y, g: today(y, g, heads, pos), y, gamma)
+    assert got.shape == (2, heads, SEQ, D)
+    close(got, want, "value")
+    for name, g, w in zip(("y", "gamma"), back(dout), want_back(dout)):
+        close(g, w, "d" + name)
+
+
+@pytest.mark.parametrize("positions", sorted(POSITIONS))
+def test_the_gradients_reach_the_weights_through_the_operator(
+        monkeypatch, interpreted, positions):
+    """`_contrib_SparseAttention` at the cell's 32 query and 4 key/value
+    heads of 128 with the pair in q's and k's place, against the same
+    operator as every platform but the TPU runs it: both outputs and the
+    gradient on the input, the projections' weights and the norms'
+    scales."""
+    op = get_op("_contrib_SparseAttention").fn
+    rng = np.random.default_rng(3)
+    width, shapes = 64, [(32 * D, 64), (4 * D, 64), (4 * D, 64), (64, 32 * D),
+                         (D,), (D,), (16, 64), (8, 64), (2, 64)]
+    weights = [jnp.asarray(1 + 0.3 * rng.normal(size=s) if len(s) == 1
+                           else 0.2 * rng.normal(size=s), jnp.float32)
+               for s in shapes]
+    x = jnp.asarray(rng.normal(size=(2, SEQ, width)), jnp.float32)
+    weight = jnp.asarray(rng.normal(size=(2, SEQ, width)), jnp.float32)
+    pos = POSITIONS[positions]()
+    attrs = dict(num_heads=32, num_kv_heads=4, index_heads=2, topk=8,
+                 rope_theta=THETA, mrope_section=SECTIONS, eps=EPS,
+                 use_positions=pos is not None)
+
+    def objective(x, weights):
+        out, term = op(x, *weights, *(() if pos is None else (pos,)), **attrs)
+        return jnp.sum(out * weight) + term[0], (out, term)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(
+                objective, argnums=(0, 1), has_aux=True))(x, weights)
+
+    since = max([s.id for s in profiler.spans()] or [0])
+    (_, (out, term)), (dx, dw) = run()
+    plans = [s.args for s in profiler.spans()
+             if s.name == "mx.headrope.plan" and s.id > since]
+    assert [(p["path"], p["heads"]) for p in plans] == [("kernel", 32),
+                                                        ("kernel", 4)]
+    monkeypatch.undo()
+    (_, (want_out, want_term)), (want_dx, want_dw) = run()
+    close(out, want_out, "out")
+    close(term, want_term, "term")
+    close(dx, want_dx, "d data")
+    for i in (0, 1, 2, 3, 4, 5):
+        close(dw[i], want_dw[i], "d weight %d" % i)
+        assert np.abs(np.asarray(want_dw[i])).max() > 0
+
+
+@pytest.mark.parametrize("heads", [32, 4])
+def test_in_bf16_the_result_is_one_rounding_from_float32(heads):
+    """From bf16 operands the kernels round once, at the output: every
+    element lies within half a bf16 step of today's arithmetic carried out
+    in float32 (today's own result, rounded after the norm and again after
+    the rotation, lies within one and a half).  The backward kernel's ``dy``
+    is the float32 derivative's to one rounding, the scale's gradient a
+    float32 sum rounded once."""
+    bf = jnp.bfloat16
+    y, gamma, dout = arguments(heads, bf)
+    cos, sin = lm_blocks._rotary_tables(SEQ, D, THETA, None, SECTIONS)
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+    exact, back = jax.vjp(lambda y, g: today(y, g, heads), f32(y), f32(gamma))
+    kw = dict(heads=heads, eps=EPS, at_once=min(heads, 8), interpret=True)
+    got = lm_blocks._headrope_fwd_pallas(y, gamma, cos, sin, rows=32, **kw)
+    dy, dgamma = lm_blocks._headrope_bwd_pallas(y, gamma, cos, sin, dout,
+                                                rows=16, **kw)
+    assert got.dtype == dy.dtype == dgamma.dtype == bf
+    assert dy.shape == y.shape and dgamma.shape == gamma.shape
+    step = 2.0 ** -8        # half a step of bf16's 8 bits, relative
+    for name, g, w in zip(("value", "dy", "dgamma"), (got, dy, dgamma),
+                          (exact,) + back(f32(dout))):
+        w = np.asarray(w)
+        assert np.all(np.abs(np.asarray(g, np.float32) - w)
+                      <= step * np.abs(w) + 1e-6), name
+    # and the body, which every other platform runs at a tiled shape, is
+    # today's arithmetic to the bit
+    np.testing.assert_array_equal(
+        np.asarray(lm_blocks._headrope_body(y, gamma, cos, sin, heads, EPS),
+                   np.float32),
+        np.asarray(today(y, gamma, heads), np.float32))
+
+
+CASES = {
+    # (seq, head width, devices of the mesh) -> path, and why not
+    "the-cell-s-shape": ((16384, 128, 1), "kernel", None),
+    "a-64-wide-head": ((16384, 64, 1), "xla", "a head of 64"),
+    "not-whole-tiles": ((16384 + 64, 128, 1), "xla", "a sequence of 16448"),
+    "a-mesh-of-two": ((16384, 128, 2), "xla", "a mesh of several devices"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_path_is_chosen_from_the_input(case):
+    """`mx.headrope.plan`, one span for q and one for k each time the
+    operator is traced: the kernels at the cell's shape on one device,
+    `_rotary` over `_rms_norm` as before at a head that is not whole lane
+    tiles, a sequence that is not whole tiles, or under a mesh of several
+    devices (XLA does not partition a Mosaic kernel)."""
+    from jax.sharding import Mesh
+    from mxnet_tpu.parallel import mesh as mesh_mod
+    (seq, d, devices), path, why = CASES[case]
+    bf, width = jnp.bfloat16, 2048
+    shapes = [(32 * d, width), (4 * d, width), (4 * d, width),
+              (width, 32 * d), (d,), (d,), (1024, width), (64, width),
+              (16, width)]
+    avals = [jax.ShapeDtypeStruct(s, bf) for s in [(1, seq, width)] + shapes]
+    op = functools.partial(
+        get_op("_contrib_SparseAttention").fn, num_heads=32, num_kv_heads=4,
+        index_heads=16, topk=2048, rope_theta=THETA,
+        mrope_section=(d // 8, 3 * d // 16, 3 * d // 16))
+    since = max([s.id for s in profiler.spans()] or [0])
+    with mesh_mod.use_mesh(Mesh(np.array(jax.devices()[:devices]), ("dp",))):
+        jax.eval_shape(op, *avals)
+    plans = [s.args for s in profiler.spans()
+             if s.name == "mx.headrope.plan" and s.id > since]
+    assert [p["heads"] for p in plans] == [32, 4]
+    tiles = lm_blocks.HEADROPE_TILES
+    for p in plans:
+        assert p["path"] == path and p["head_dim"] == d
+        assert p["shape"] == [1, seq, p["heads"] * d]
+        if path == "xla":
+            assert p["why"].startswith(why)
+            assert p["seq_tile"] is p["table_bytes"] is p["residual_bytes"] \
+                is None
+            continue
+        assert p["why"] is None
+        assert p["seq_tile"] == {"fwd": tiles["fwd"], "bwd": tiles["bwd"]}
+        assert p["head_tile"] == min(p["heads"], tiles["heads"])
+        # cos and sin over the sequence in float32, once an op; kept: the
+        # projection as the product wrote it, and the scale
+        assert p["table_bytes"] == 2 * seq * d * 4
+        assert p["residual_bytes"] == seq * p["heads"] * d * 2 + d * 4
+
+
+def test_the_pair_lowers_for_the_tpu_under_the_projection_s_scope():
+    """Lowered for the TPU from this CPU host at the cell's two widths: one
+    Mosaic call each way a width, named, each under the scope
+    `mx.dsa.project.headrope` where the caller's `mx.dsa.project` calls it
+    (the compiled program joins the two: `tests/test_keye_vl2.py` reads it
+    there), and nothing of the activations' size kept for the backward pass
+    but the projection itself and the tables."""
+    bf = jnp.bfloat16
+    cos, sin = lm_blocks._rotary_tables(16384, D, THETA, None, SECTIONS)
+
+    def loss(y, gamma, heads):
+        with jax.named_scope("mx.dsa.project"):
+            return jnp.sum(lm_blocks._head_norm_rotary(
+                y, gamma, cos, sin, heads, EPS).astype(jnp.float32))
+
+    for heads in (32, 4):
+        avals = (jax.ShapeDtypeStruct((1, 16384, heads * D), bf),
+                 jax.ShapeDtypeStruct((D,), bf))
+        f = functools.partial(loss, heads=heads)
+        text = jax.jit(jax.value_and_grad(f, argnums=(0, 1))).trace(
+            *avals).lower(lowering_platforms=("tpu",)).as_text(
+                debug_info=True)
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+        for way in ("fwd", "bwd"):
+            assert '"mx.dsa.project.headrope/mx_headrope_%s/pallas_call"' \
+                % way in text
+            assert "mx.dsa.project))/cond/branch_0_fun/jit(_headrope_%s_" \
+                "pallas)" % way in text.replace("jvp(mx.dsa.project)/",
+                                                "jvp(mx.dsa.project))/")
+        kept = jax.tree.leaves(jax.eval_shape(
+            lambda *a: jax.vjp(f, *a)[1], *avals))
+        big = [(a.shape, a.dtype) for a in kept if a.size >= 16384 * D]
+        assert sorted(big, key=str) == sorted(
+            [((1, 16384, heads * D), bf), ((1, 16384, D), jnp.float32),
+             ((1, 16384, D), jnp.float32)], key=str), big

@@ -41,6 +41,34 @@ BWD = ((128, 512), (128, 256), (128, 1024), (128, 2048), (64, 512),
        (64, 2048), (32, 512))
 
 
+def device_ms(fn, *a, iters=ITERS):
+    """Device busy time of one call of jitted *fn*, in ms (shared with
+    `tools/headrope_sweep.py`)."""
+    import jax
+    from benchmarks import trace
+    jax.block_until_ready(fn(*a))
+    where = tempfile.mkdtemp(prefix="sweep")
+    try:
+        with jax.profiler.trace(where):
+            for _ in range(iters):
+                jax.block_until_ready(fn(*a))
+        events = [e for e in trace.load_events(trace.find_xplane(where))
+                  if e["line"] == trace.OPS_LINE]
+    finally:
+        shutil.rmtree(where, ignore_errors=True)
+    busy = trace.total(trace.union(
+        [(e["start_ns"], e["start_ns"] + e["dur_ns"]) for e in events]))
+    return busy / iters / 1e6
+
+
+def gap(a, b):
+    """``(largest difference, relative difference of the norms)``."""
+    import jax.numpy as jnp
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.max(jnp.abs(a - b))), float(
+        jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30))
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--default-only", action="store_true",
@@ -49,7 +77,6 @@ def main(argv=None):
 
     import jax
     import jax.numpy as jnp
-    from benchmarks import trace
     from mxnet_tpu.ops import lm_blocks
     from mxnet_tpu.ops.registry import get_op
 
@@ -70,27 +97,6 @@ def main(argv=None):
     dgated = jax.random.normal(ks[5], (n, s, d), bf)
     item = 2
     moved = {"fwd": 4 * n * s * d * item, "bwd": 7 * n * s * d * item}
-
-    def device_ms(fn, *a):
-        """Device busy time of one call of jitted *fn*, in ms."""
-        jax.block_until_ready(fn(*a))
-        where = tempfile.mkdtemp(prefix="shortconv_sweep")
-        try:
-            with jax.profiler.trace(where):
-                for _ in range(ITERS):
-                    jax.block_until_ready(fn(*a))
-            events = [e for e in trace.load_events(trace.find_xplane(where))
-                      if e["line"] == trace.OPS_LINE]
-        finally:
-            shutil.rmtree(where, ignore_errors=True)
-        busy = trace.total(trace.union(
-            [(e["start_ns"], e["start_ns"] + e["dur_ns"]) for e in events]))
-        return busy / ITERS / 1e6
-
-    def gap(a, b):
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-        return float(jnp.max(jnp.abs(a - b))), float(
-            jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30))
 
     body = jax.jit(lm_blocks._gate_body)
     body_bwd = jax.jit(lm_blocks._body_backward)
