@@ -621,13 +621,15 @@ class ParallelTrainer:
     def _first_step(self, x, y):
         """The step program's first call: trace and lower, compile or
         load from the cache, then hand the profiler the compiled step's
-        scope map.  The lowering made here is the one the call uses, and
-        the executable the call built is the one whose text is read:
-        nothing is lowered or compiled twice.  Span
+        text (its scope map and its cost map, parsed when first asked
+        for) and XLA's own cost analysis.  The lowering made here is the
+        one the call uses, and the executable the call built is the one
+        whose text is read: nothing is lowered or compiled twice.  Span
         ``mx.step.first_call``; its args say where it went: `lower_s`
         (tracing and lowering), `call_s` (compile or load, and the
-        dispatch), `scope_map_s` (the text and its parse), and what JAX
-        reported meanwhile under each compile event."""
+        dispatch), `scope_map_s` (the text, its packing and the cost
+        analysis), and what JAX reported meanwhile under each compile
+        event."""
         self._step_called = True
         with _prof.scope("mx.step.first_call", "setup") as span:
             before = _prof.compile_seconds()
@@ -638,9 +640,10 @@ class ParallelTrainer:
             t_called = time.perf_counter()
             # no new program: `compile()` hands back the executable the
             # call above built from this same lowering
-            text = lowered.compile().as_text()  # graftlint: disable=JG014
-            _prof.set_scope_map("parallel_step", text)
-            del lowered, text
+            compiled = lowered.compile()  # graftlint: disable=JG014
+            _prof.set_scope_map("parallel_step", compiled.as_text(),
+                                compiled.cost_analysis())
+            del lowered, compiled
             span.args = dict(
                 {k: v - before[k]
                  for k, v in _prof.compile_seconds().items()},

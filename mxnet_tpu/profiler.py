@@ -29,20 +29,22 @@ import itertools
 import json
 import logging
 import os
-import re
 import threading
 import time
+import zlib
 
 import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 from . import sanitizer as _san
+from .observability import costs as _costs
 from .observability import metrics as _metrics
 
 __all__ = ["set_config", "set_state", "pause", "resume", "dump", "dumps",
            "profiler_set_config", "profiler_set_state",
            "Domain", "Task", "Frame", "Event", "Counter", "Marker",
            "scope", "spans", "Span", "scope_map", "set_scope_map",
+           "cost_map", "cost_totals",
            "compile_seconds", "bump_counter", "counter_value", "counters",
            "reset_counters", "collect_step_stats", "emit_step_stat",
            "register_step_stat", "fold_step_stats"]
@@ -102,31 +104,109 @@ def spans(since=None):
     return out
 
 
-# -- device scopes: optimized-HLO instruction -> op_name ----------------------
+# -- the compiled program: optimized-HLO instruction -> op_name, and its cost --
 # `jax.named_scope` names ("mx.loss", "<op>:<node>", "mx.flash.fwd") reach
 # the compiled program as each instruction's `op_name` metadata; a device
 # trace names its events by instruction, so the map from the one to the
 # other is what lets a reader of the trace say which phase, operator or
-# kernel an event belongs to.  Plain strings only: the map must keep no
-# trainer and no array alive.
-_scope_maps = {}
-_HLO_OP_NAME = re.compile(
-    r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*?\bop_name="([^"]*)"', re.M)
+# kernel an event belongs to.  The same text prints every instruction's
+# shapes, so it also says what each moves and multiplies: `cost_map`.
+# What is kept is the text, packed, until a map is first asked for: one
+# parse (`observability/costs.py`) gives the scope map and is then priced
+# when a cost is first asked for.  Bytes, plain strings and numbers only,
+# so that no trainer and no array is kept alive; nothing is parsed in a
+# run that asks for no map, and the scope map depends on the parse alone:
+# a text that the pricing cannot read leaves the costs None, as a program
+# from before the cost map has none.
+class _CompiledText:
+    __slots__ = ("packed", "xla", "parsed", "scopes", "costs", "totals")
+
+    def __init__(self, hlo_text):
+        self.packed = zlib.compress(hlo_text.encode(), 1)
+        self.xla = self.parsed = self.scopes = None
+        self.costs = self.totals = None
+
+    def parse(self):
+        """The text parsed, once: the work is done outside the
+        profiler's lock (a span on another thread does not wait for it)
+        and only the result is put in place under it."""
+        packed = self.packed
+        if packed is not None:
+            parsed = _costs.parse_optimized_hlo(
+                zlib.decompress(packed).decode())
+            scopes = _costs.hlo_op_names(parsed)
+            with _lock:
+                if self.packed is not None:
+                    self.parsed, self.scopes, self.packed = \
+                        parsed, scopes, None
+        return self
+
+    def price(self):
+        """The parse priced, once, and then let go of."""
+        parsed = self.parse().parsed
+        if parsed is not None:
+            try:
+                costs, totals = _costs.price_optimized_hlo(parsed)
+            except Exception:   # a text some other XLA prints differently
+                logging.getLogger(__name__).warning(
+                    "the compiled text could not be priced", exc_info=True)
+                costs = totals = None
+            with _lock:
+                if self.parsed is not None:
+                    self.costs, self.totals, self.parsed = \
+                        costs, totals, None
+        return self
 
 
-def set_scope_map(program, hlo_text):
-    """Keep ``{instruction name: op_name}`` of *program*'s optimized HLO
-    text (`Compiled.as_text()`); the text itself is not kept."""
-    shared = {}
-    _scope_maps[program] = {
-        m.group(1): shared.setdefault(m.group(2), m.group(2))
-        for m in _HLO_OP_NAME.finditer(hlo_text)}
+_compiled = {}
+
+
+def set_scope_map(program, hlo_text, cost_analysis=None):
+    """Keep *program*'s optimized HLO text (`Compiled.as_text()`), packed,
+    for `scope_map`, `cost_map` and `cost_totals`, with XLA's own
+    `Compiled.cost_analysis()` where the caller has it at hand: its
+    `bytes accessed` and `flops` are the two numbers kept of it."""
+    _compiled[program] = kept = _CompiledText(hlo_text)
+    if isinstance(cost_analysis, (list, tuple)):
+        cost_analysis = cost_analysis[0] if cost_analysis else None
+    if cost_analysis:
+        kept.xla = {"bytes_accessed": float(
+                        cost_analysis.get("bytes accessed", 0.0)),
+                    "flops": float(cost_analysis.get("flops", 0.0))}
 
 
 def scope_map(program):
     """``{instruction name: op_name}`` of *program* ("parallel_step": the
     `ParallelTrainer` step), or None before its first call."""
-    return _scope_maps.get(program)
+    kept = _compiled.get(program)
+    return None if kept is None else kept.parse().scopes
+
+
+def cost_map(program):
+    """``{instruction name: record}`` for every instruction of *program*'s
+    optimized HLO that can run as a device event, or None before its
+    first call.  A record says what the instruction is (`op_name`,
+    `opcode`, the `computation` it stands in, a fusion's `kind`, a custom
+    call's `target` and `kernel`),
+    the logical bytes it reads and writes (`bytes_read`, `bytes_written`;
+    by memory `hbm_bytes_*` and `onchip_bytes_*`), its `mxu_flops` (None
+    for a kernel that states none) and `bytes_by_scope`, its HBM bytes by
+    the op_name they belong to.  Priced on the first request;
+    docs/observability.md "What the compiled step costs"."""
+    kept = _compiled.get(program)
+    return None if kept is None else kept.price().costs
+
+
+def cost_totals(program):
+    """The cost map's sums over *program* as XLA adds a module up (a
+    loop's body once, a conditional's dearest branch), the ENTRY
+    computation's name under ``entry`` and, under ``xla``, the
+    `bytes_accessed` and `flops` of XLA's own `cost_analysis()` (None
+    where the caller of `set_scope_map` had none); None before the
+    program's first call."""
+    kept = _compiled.get(program)
+    totals = None if kept is None else kept.price().totals
+    return None if totals is None else dict(totals, xla=kept.xla)
 
 
 # -- what a program's first call spends, by jax.monitoring event --------------

@@ -844,3 +844,72 @@ def test_the_backward_compiles_for_the_described_chip_at_what_it_asks_for(
         assert temp < 64 << 20          # delta and lse rows, no partials
     else:
         assert (2 << 30) <= temp < (2 << 30) + (256 << 20)
+
+
+def test_a_latent_attention_layer_at_published_widths_prices_itself(
+        v5e, no_persistent_cache):
+    """The compiled layer's text says what every instruction moves and
+    multiplies (`profiler.cost_map`): Kanana's latent attention at its
+    published widths (8192 tokens, 32 heads of 128 + 64 / 128 over a
+    512-wide latent), forward and backward, compiled for the described
+    chip.  Every instruction of the ENTRY computation has a record, the
+    two Mosaic kernels are found by name with no FLOPs of their own, the
+    MXU work is the projections' (the attention core is the kernels'), and
+    the map's sum is within a tenth of XLA's own `bytes accessed` (an
+    async pair, which the map counts once, counted at both ends as XLA
+    counts it)."""
+    from jax.sharding import SingleDeviceSharding
+    from mxnet_tpu.observability import costs
+    one = SingleDeviceSharding(v5e.devices[0])
+    s, d, heads, latent, nope, rope, vd = 8192, 2048, 32, 512, 128, 64, 128
+
+    def aval(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    shapes = [(heads * (nope + rope), d), (latent + rope, d), (latent,),
+              (heads * (nope + vd), latent), (d, heads * vd)]
+
+    def step(x, weights, dout):
+        def objective(x, w):
+            out = get_op("_contrib_LatentAttention").fn(
+                x, *w, num_heads=heads, qk_nope_head_dim=nope,
+                qk_rope_head_dim=rope, v_head_dim=vd, rope_theta=1e6,
+                rope_interleave=True, eps=1e-6)
+            return jnp.sum(out.astype(jnp.float32) * dout)
+        return jax.grad(objective, argnums=(0, 1))(x, weights)
+
+    compiled = jax.jit(step).lower(
+        aval(1, s, d), [aval(*shape) for shape in shapes],
+        aval(1, s, d, dt=jnp.float32)).compile()
+    text = compiled.as_text()
+    profiler.set_scope_map("a-latent-attention-layer", text,
+                           compiled.cost_analysis())
+    try:
+        records = profiler.cost_map("a-latent-attention-layer")
+        totals = profiler.cost_totals("a-latent-attention-layer")
+    finally:
+        profiler._compiled.pop("a-latent-attention-layer")
+    entry, comps = costs.parse_optimized_hlo(text)
+    assert len(comps[entry]) > 50
+    assert {i.name for i in comps[entry]} <= set(records)
+    kernels = {r["kernel"]: r for r in records.values()
+               if r.get("target") == "tpu_custom_call"}
+    assert set(kernels) == {"mx_flash_fwd", "mx_flash_bwd"}
+    assert all(r["mxu_flops"] is None and r["bytes_read"] > 0
+               for r in kernels.values())
+    # forward, input gradient and weight gradient of the four projections
+    # (the objective is linear in the output, so the output projection's
+    # forward product is not in the program)
+    macs = s * (3 * (d * heads * (nope + rope) + d * (latent + rope)
+                     + latent * heads * (nope + vd)) + 2 * heads * vd * d)
+    assert totals["mxu_flops"] == pytest.approx(2 * macs, rel=0.02)
+    assert totals["mxu_flops"] == pytest.approx(totals["xla"]["flops"],
+                                                rel=0.05)
+    moved = totals["bytes_read"] + totals["bytes_written"]
+    pairs = sum(r["bytes_read"] + r["bytes_written"]
+                for r in records.values() if r["opcode"].endswith("-start"))
+    assert moved + pairs == pytest.approx(totals["xla"]["bytes_accessed"],
+                                          rel=0.1)
+    assert moved > 0.75 * totals["xla"]["bytes_accessed"]
+    # the on-chip memory is read from the layouts' memory-space marks
+    assert totals["onchip_bytes_read"] > 0 and totals["hbm_bytes_read"] > 0

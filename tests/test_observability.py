@@ -462,6 +462,24 @@ def test_parse_hlo_dot_and_conv_flops():
         4 * (16 * 32 + 32 * 64 + 16 * 64)
 
 
+def test_parse_hlo_grouped_conv_divides_by_its_groups_once():
+    """A grouped convolution's kernel already holds the input's features
+    of ONE group (`HWIO` with I = C / groups): the FLOPs are
+    2 * out * window * I, not that over the groups again."""
+    import jax
+    import jax.numpy as jnp
+
+    def f(c, k):
+        return jax.lax.conv_general_dilated(
+            c, k, (1, 1), "SAME", feature_group_count=4,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+    low = jax.jit(f).lower(jnp.ones((2, 8, 8, 16)), jnp.ones((3, 3, 4, 32)))
+    conv, = [r for r in costs.parse_hlo_ops(low.as_text())
+             if r["op"] == "convolution"]
+    assert conv["flops"] == 2 * (2 * 8 * 8 * 32) * 9 * 4
+
+
 def test_parse_hlo_scan_counts_trip_count_times():
     """Ops inside a lax.scan body (lowered to stablehlo.while calling
     an outlined private function) must be charged trip_count x, not

@@ -225,9 +225,9 @@ def built(request):
     texts = []
     keep = profiler.set_scope_map
 
-    def capture(program, text):
-        texts.append((program, text))
-        keep(program, text)
+    def capture(program, text, *xla):
+        texts.append((program, text, xla))
+        keep(program, text, *xla)
     profiler.set_scope_map = capture
     t0 = time.perf_counter()
     try:
@@ -235,8 +235,9 @@ def built(request):
         trainer.fit_batch(*batches[0])
     finally:
         profiler.set_scope_map = keep
-    assert [p for p, _ in texts] == ["parallel_step"]
+    assert [t[0] for t in texts] == ["parallel_step"]
     return {"trainer": trainer, "batches": batches, "text": texts[0][1],
+            "xla": texts[0][2],
             "map": dict(profiler.scope_map("parallel_step")),
             "setup": profiler.spans(since=t0)}
 
@@ -419,6 +420,55 @@ def test_the_scope_map_holds_the_trainer_by_no_reference():
     scope_of = profiler.scope_map("parallel_step")
     assert all(type(k) is str and type(v) is str
                for k, v in scope_of.items())
+
+
+def test_the_cost_map_holds_the_trainer_by_no_reference():
+    """The twin of the test above: what the profiler keeps for the cost
+    map is the packed text, then plain records; the map is priced after
+    the trainer is gone, on the first request."""
+    trainer, batches = _fixture_trainer("tiny_lm")
+    trainer.fit_batch(*batches[0])
+    ref = weakref.ref(trainer)
+    leaf = weakref.ref(next(iter(trainer._params.values())))
+    kept = profiler._compiled["parallel_step"]
+    assert kept.packed is not None and kept.costs is None
+    del trainer
+    gc.collect()
+    assert ref() is None and leaf() is None
+    costs_of = profiler.cost_map("parallel_step")
+    assert kept.packed is None and costs_of
+    for name, rec in costs_of.items():
+        assert type(name) is str and all(
+            type(v) in (str, int, float, dict, type(None))
+            for v in rec.values()), name
+        assert all(type(k) is str and type(v) is float
+                   for k, v in rec["bytes_by_scope"].items()), name
+
+
+def test_the_step_s_cost_map_prices_its_entry_and_agrees_with_xla(built):
+    """Every instruction of the compiled step's ENTRY has a record, the
+    scope map is what the old regular expression made of the text, and
+    the map's sum is within a tenth of XLA's own `bytes accessed`."""
+    from mxnet_tpu.observability import costs
+    profiler.set_scope_map("parallel_step", built["text"], *built["xla"])
+    old = {m.group(1): m.group(2) for m in re.finditer(
+        r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*?\bop_name="([^"]*)"',
+        built["text"], re.M)}
+    assert built["map"] == old == profiler.scope_map("parallel_step")
+    entry, comps = costs.parse_optimized_hlo(built["text"])
+    records = profiler.cost_map("parallel_step")
+    assert {i.name for i in comps[entry]} <= set(records)
+    totals = profiler.cost_totals("parallel_step")
+    assert totals["xla"]["flops"] > 0
+    assert totals["bytes_read"] + totals["bytes_written"] == pytest.approx(
+        totals["xla"]["bytes_accessed"], rel=0.1)
+    # the update is under its scope, in whatever fusion it rides
+    update = sum(b for rec in records.values()
+                 for op, b in rec["bytes_by_scope"].items()
+                 if "/mx.optimizer" in op)
+    n = sum(int(v.size) for v in built["trainer"]._params.values())
+    per_parameter = 18 if built["trainer"].multi_precision else 20
+    assert update >= per_parameter * n
 
 
 def test_the_flash_kernels_are_scoped_and_named_in_a_tpu_lowering():
