@@ -276,6 +276,9 @@ def _record_dsa_plan(data, heads, kv_heads, head_dim, index_heads,
         span.args = dict(
             sparse_attention.select_plan(seq, index_heads, index_width,
                                          data.dtype),
+            **sparse_attention.align_plan(seq, index_heads, index_width,
+                                          heads, kv_heads, head_dim,
+                                          data.dtype),
             batch=batch, tokens=seq, topk=min(topk, seq), heads=heads,
             kv_heads=kv_heads, head_dim=head_dim, index_heads=index_heads,
             index_head_dim=index_width, dtype=jnp.dtype(data.dtype).name,

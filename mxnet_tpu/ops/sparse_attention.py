@@ -368,23 +368,42 @@ def _align_body(qi, ki, w, q, k, lse, lse_i, sel_q, sm_scale):
     return (loss,) + grads
 
 
+def _fold_rows(x):
+    """(n * 8, cols) summed over its sublane tiles: (8, cols)."""
+    return x.reshape(-1, 8, x.shape[1]).sum(axis=0)
+
+
 @_traced_inline
-def _align_kernel(qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, lsei_ref,
-                  sel_ref, dqi_ref, dki_ref, dw_ref, kl_ref, dqi_acc, dw_acc,
-                  kl_acc, *, sm_scale, cols, grid):
-    """One tile of query rows by key columns, every head of it: the heads'
+def _align_kernel(row_ref, col_ref, qit_ref, ki_ref, kit_ref, w_ref, q_ref,
+                  k_ref, lse_ref, lsei_ref, sel_ref, dqi_ref, dki_ref, dw_ref,
+                  kl_ref, dqi_acc, dw_acc, kl_acc, kept_ref, *, sm_scale,
+                  cols):
+    """One tile of key columns by query rows, every head of it: the heads'
     mean probability from q, k and the kept logsumexps, the indexer's
     scores and their softmax from the kept logsumexp, the divergence's
-    part, and ``dI = pi - p`` carried back to qI, kI and w.  kI's gradient
-    accumulates in its output block, which spans the sequence; the rows'
-    own in scratch over the row's tiles."""
-    nq, nk = grid
-    iq, ik = pl.program_id(1), pl.program_id(2)
-    heads, rows, width = qi_ref.shape[1:]
-    h, kv = q_ref.shape[1], k_ref.shape[1]
-    last = jnp.minimum(nk - 1, (iq * rows + rows - 1) // cols)
+    part, and ``dI = pi - p`` carried back to qI, kI and w.  A grid step is
+    a tile at or under the diagonal, *row_ref* and *col_ref* saying which:
+    a row of tiles after another.
 
-    @pl.when((iq == 0) & (ik == 0))
+    The tile is held keys down, queries along the lanes, as the flash
+    backward holds its own: what belongs to a query (its logsumexps, its
+    head weights) is a row laid down the tile, every product is a plain
+    one or contracts both operands' last axis, and nothing is turned but
+    the selection's bits, once a tile.  qI and kI arrive turned (width by
+    tokens; kI as it is too), and their gradients leave so: ``g_j`` is the
+    latched operand of both its products, the width's 64 rows stream
+    against it, and the accumulators are whole lanes.  Each product is
+    formed once: the indexer's ``relu(a_j)`` stay on chip in *kept_ref*
+    (float32: `dw` sums the numbers the score was made of) for the
+    gradient's loop.  kI's gradient accumulates in its output block, which
+    spans the sequence; the rows' own in scratch over the row's tiles."""
+    tile = pl.program_id(1)
+    iq, ik = row_ref[tile], col_ref[tile]
+    heads, _, rows = qit_ref.shape[1:]
+    h, kv = q_ref.shape[1], k_ref.shape[1]
+    shape = (cols, rows)
+
+    @pl.when(tile == 0)
     def _zero_keys():
         dki_ref[...] = jnp.zeros(dki_ref.shape, jnp.float32)
 
@@ -394,60 +413,66 @@ def _align_kernel(qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, lsei_ref,
         dw_acc[...] = jnp.zeros(dw_acc.shape, jnp.float32)
         kl_acc[...] = jnp.zeros(kl_acc.shape, jnp.float32)
 
-    @pl.when(ik <= last)
-    def _tile():
-        shape = (rows, cols)
-        row = iq * rows + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        col = ik * cols + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        seen = unpack_selection(sel_ref[0]) & (col <= row)
-        target = jnp.zeros(shape, jnp.float32)
-        for i in range(h):
-            s = _mxu_dot(q_ref[0, i], k_ref[0, i // (h // kv)], _NT) \
+    col = ik * cols + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    row = iq * rows + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    # the bits are the forward's, a word 32 query rows: turned as float32,
+    # which the XLU turns as it is
+    seen = (unpack_selection(sel_ref[0]).astype(jnp.float32).T > 0) \
+        & (col <= row)
+    # the indexer's products first: their stores, and the score's sums
+    # below, ride under the heads' products, which leave those slots idle
+    ki = ki_ref[0]
+    for j in range(heads):
+        kept_ref[j] = jnp.maximum(_mxu_dot(ki, qit_ref[0, j], _NN), 0.0)
+    # one select a pair, after the heads are summed: an unseen pair's terms
+    # may overflow to inf, never to nan (none is negative)
+    total = jnp.zeros(shape, jnp.float32)
+    score = jnp.zeros(shape, jnp.float32)
+    for j in range(heads):
+        for i in range(j * h // heads, (j + 1) * h // heads):
+            s = _mxu_dot(k_ref[0, i // (h // kv)], q_ref[0, i], _NT) \
                 * sm_scale
-            target = target + jnp.exp(jnp.where(seen, s, _NEG_INF)
-                                      - lse_ref[0, :, i:i + 1])
-        target = target * (1.0 / h)
-        ki = ki_ref[0]
-        score = jnp.zeros(shape, jnp.float32)
-        for j in range(heads):
-            a = _mxu_dot(qi_ref[0, j], ki, _NT)
-            score = score + w_ref[0, :, j:j + 1] * jnp.maximum(a, 0.0)
-        logp = score + 0.0 - lsei_ref[0]
-        kl_acc[...] += _fold(jnp.where(
-            seen & (target > 0),
-            target * (jnp.log(jnp.where(target > 0, target, 1.0)) - logp),
-            0.0))
-        d_score = jnp.where(seen, jnp.exp(logp), 0.0) - target
-        at = pl.ds(pl.multiple_of(ik * cols, cols), cols)
-        for j in range(heads):
-            a = _mxu_dot(qi_ref[0, j], ki, _NT)
-            dw_acc[j] += _fold(d_score * jnp.maximum(a, 0.0))
-            g = jnp.where(a > 0, d_score * w_ref[0, :, j:j + 1], 0.0
-                          ).astype(ki.dtype)
-            dqi_acc[j] += _mxu_dot(g, ki, _NN)
-            dki_ref[0, at, :] += jax.lax.dot_general(
-                g, qi_ref[0, j], (((0,), (0,)), ((), ())),
-                precision=matmul_precision(g.dtype, ki.dtype),
-                preferred_element_type=jnp.float32)
+            total = total + jnp.exp(s - lse_ref[0, i:i + 1, :])
+        score = score + w_ref[0, j:j + 1, :] * kept_ref[j]
+    target = jnp.where(seen, total, 0.0) * (1.0 / h)
+    logp = score + 0.0 - lsei_ref[0]
+    kl_acc[...] += _fold_rows(jnp.where(
+        seen & (target > 0),
+        target * (jnp.log(jnp.where(target > 0, target, 1.0)) - logp), 0.0))
+    d_score = jnp.where(seen, jnp.exp(logp), 0.0) - target
+    at = pl.ds(pl.multiple_of(ik * cols, cols), cols)
+    for j in range(heads):
+        kept = kept_ref[j]
+        dw_acc[j] += _fold_rows(d_score * kept)
+        g = jnp.where(kept > 0, d_score * w_ref[0, j:j + 1, :], 0.0
+                      ).astype(ki.dtype)
+        dqi_acc[j] += _mxu_dot(kit_ref[0], g, _NN)
+        dki_ref[0, :, at] += _mxu_dot(qit_ref[0, j], g, _NT)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(ik == (iq * rows + rows - 1) // cols)
     def _finish():
         dqi_ref[0] = dqi_acc[...].astype(dqi_ref.dtype)
         for j in range(heads):
-            dw_ref[0, j:j + 1, :] = jnp.sum(dw_acc[j], axis=1,
-                                            keepdims=True).T
-        kl_ref[0] = jnp.sum(kl_acc[...], axis=1, keepdims=True).T
+            dw_ref[0, j:j + 1, :] = jnp.sum(dw_acc[j], axis=0, keepdims=True)
+        kl_ref[0] = jnp.sum(kl_acc[...], axis=0, keepdims=True)
+
+
+def _align_kept_bytes(heads):
+    """`mx_dsa_align`'s ``relu(a_j)`` of a tile, float32."""
+    return heads * ALIGN_COLS * ROWS * 4
 
 
 def _align_vmem(seq, heads, width, h, kv, d, itemsize):
+    """What `mx_dsa_align` holds: its blocks (kI's gradient spans the
+    sequence), each twice, the rows' accumulators and the kept
+    ``relu(a_j)``."""
     lanes, wide = -(-width // _LANES) * _LANES, -(-d // _LANES) * _LANES
-    blocks = (heads * ROWS * lanes + ALIGN_COLS * lanes
+    blocks = (2 * heads * width * ROWS + ALIGN_COLS * (lanes + width)
               + h * ROWS * wide + kv * ALIGN_COLS * wide) * itemsize \
-        + 3 * ROWS * _LANES * 4 + (ROWS // _SEL_BITS) * ALIGN_COLS * 4 \
-        + heads * ROWS * lanes * itemsize + seq * lanes * 4 \
-        + 2 * 8 * ROWS * 4 * 2
-    return 2 * blocks + heads * ROWS * (lanes + _LANES) * 4 \
-        + ROWS * _LANES * 4
+        + (2 * heads + h + 2 * 8) * ROWS * 4 \
+        + (ROWS // _SEL_BITS) * ALIGN_COLS * 4 + seq * width * 4
+    return 2 * blocks + (heads * (width + 8) + 8) * ROWS * 4 \
+        + _align_kept_bytes(heads)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
@@ -455,58 +480,88 @@ def _align_pallas(qi, ki, w, q, k, lse, lse_i, sel_q, sm_scale,
                   interpret=False):
     b, s, heads = w.shape
     width, (h, kv, d) = ki.shape[-1], (q.shape[1], k.shape[1], q.shape[3])
-    qh = qi.reshape(b, s, heads, width).transpose(0, 2, 1, 3)
-    nq, nk = s // ROWS, s // ALIGN_COLS
-
-    def key_block(i, r, c):
-        # a tile above the diagonal computes nothing and refetches nothing
-        return jnp.minimum(c, (r * ROWS + ROWS - 1) // ALIGN_COLS)
+    qit = qi.reshape(b, s, heads, width).transpose(0, 2, 3, 1)
+    # the tiles at or under the diagonal, a row of them after another
+    tiles = [(r, c) for r in range(s // ROWS)
+             for c in range((r * ROWS + ROWS - 1) // ALIGN_COLS + 1)]
+    row_of, col_of = (jnp.asarray(x, jnp.int32) for x in zip(*tiles))
 
     dqi, dki, dw, kl = pl.pallas_call(
-        functools.partial(_align_kernel, sm_scale=sm_scale,
-                          cols=ALIGN_COLS, grid=(nq, nk)),
-        grid=(b, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, heads, ROWS, width),
-                         lambda i, r, c: (i, 0, r, 0)),
-            pl.BlockSpec((1, ALIGN_COLS, width),
-                         lambda i, r, c: (i, key_block(i, r, c), 0)),
-            pl.BlockSpec((1, ROWS, heads), lambda i, r, c: (i, r, 0)),
-            pl.BlockSpec((1, h, ROWS, d), lambda i, r, c: (i, 0, r, 0)),
-            pl.BlockSpec((1, kv, ALIGN_COLS, d),
-                         lambda i, r, c: (i, 0, key_block(i, r, c), 0)),
-            pl.BlockSpec((1, ROWS, h), lambda i, r, c: (i, r, 0)),
-            pl.BlockSpec((1, ROWS, 1), lambda i, r, c: (i, r, 0)),
-            pl.BlockSpec((1, ROWS // _SEL_BITS, ALIGN_COLS),
-                         lambda i, r, c: (i, r, key_block(i, r, c)))],
-        out_specs=[
-            pl.BlockSpec((1, heads, ROWS, width),
-                         lambda i, r, c: (i, 0, r, 0)),
-            pl.BlockSpec((1, s, width), lambda i, r, c: (i, 0, 0)),
-            pl.BlockSpec((1, heads, ROWS), lambda i, r, c: (i, 0, r)),
-            pl.BlockSpec((1, 1, ROWS), lambda i, r, c: (i, 0, r))],
+        functools.partial(_align_kernel, sm_scale=sm_scale, cols=ALIGN_COLS),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, len(tiles)),
+            in_specs=[
+                pl.BlockSpec((1, heads, width, ROWS),
+                             lambda i, t, row, col: (i, 0, 0, row[t])),
+                pl.BlockSpec((1, ALIGN_COLS, width),
+                             lambda i, t, row, col: (i, col[t], 0)),
+                pl.BlockSpec((1, width, ALIGN_COLS),
+                             lambda i, t, row, col: (i, 0, col[t])),
+                pl.BlockSpec((1, heads, ROWS),
+                             lambda i, t, row, col: (i, 0, row[t])),
+                pl.BlockSpec((1, h, ROWS, d),
+                             lambda i, t, row, col: (i, 0, row[t], 0)),
+                pl.BlockSpec((1, kv, ALIGN_COLS, d),
+                             lambda i, t, row, col: (i, 0, col[t], 0)),
+                pl.BlockSpec((1, h, ROWS),
+                             lambda i, t, row, col: (i, 0, row[t])),
+                pl.BlockSpec((1, 1, ROWS),
+                             lambda i, t, row, col: (i, 0, row[t])),
+                pl.BlockSpec((1, ROWS // _SEL_BITS, ALIGN_COLS),
+                             lambda i, t, row, col: (i, row[t], col[t]))],
+            out_specs=[
+                pl.BlockSpec((1, heads, width, ROWS),
+                             lambda i, t, row, col: (i, 0, 0, row[t])),
+                pl.BlockSpec((1, width, s), lambda i, t, row, col: (i, 0, 0)),
+                pl.BlockSpec((1, heads, ROWS),
+                             lambda i, t, row, col: (i, 0, row[t])),
+                pl.BlockSpec((1, 1, ROWS),
+                             lambda i, t, row, col: (i, 0, row[t]))],
+            scratch_shapes=[
+                pltpu.VMEM((heads, width, ROWS), jnp.float32),
+                pltpu.VMEM((heads, 8, ROWS), jnp.float32),
+                pltpu.VMEM((8, ROWS), jnp.float32),
+                pltpu.VMEM((heads, ALIGN_COLS, ROWS), jnp.float32)]),
         out_shape=[
-            jax.ShapeDtypeStruct((b, heads, s, width), qi.dtype),
-            jax.ShapeDtypeStruct((b, s, width), jnp.float32),
+            jax.ShapeDtypeStruct((b, heads, width, s), qi.dtype),
+            jax.ShapeDtypeStruct((b, width, s), jnp.float32),
             jax.ShapeDtypeStruct((b, heads, s), jnp.float32),
             jax.ShapeDtypeStruct((b, 1, s), jnp.float32)],
-        scratch_shapes=[
-            pltpu.VMEM((heads, ROWS, width), jnp.float32),
-            pltpu.VMEM((heads, ROWS, _LANES), jnp.float32),
-            pltpu.VMEM((ROWS, _LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_align_vmem(
                 s, heads, width, h, kv, d, qi.dtype.itemsize)
             + _VMEM_SPARE),
         interpret=interpret, name="mx_dsa_align",
-    )(qh, ki, w, q, k, lse.transpose(0, 2, 1), lse_i[..., None], sel_q)
+    )(row_of, col_of, qit, ki, ki.transpose(0, 2, 1), w.transpose(0, 2, 1),
+      q, k, lse, lse_i[:, None], sel_q)
     scale = 1.0 / (b * s)
     return (jnp.sum(kl) * scale,
-            (dqi.astype(jnp.float32) * scale).transpose(0, 2, 1, 3).reshape(
+            (dqi.astype(jnp.float32) * scale).transpose(0, 3, 1, 2).reshape(
                 qi.shape).astype(qi.dtype),
-            (dki * scale).astype(ki.dtype),
+            (dki * scale).transpose(0, 2, 1).astype(ki.dtype),
             (dw * scale).transpose(0, 2, 1).astype(w.dtype))
+
+
+def align_plan(seq, heads, width, h, kv, d, dtype):
+    """The form `alignment_term` runs a sequence in, for the `mx.dsa.plan`
+    span (`select_plan`'s sibling): the kernel's tile, the MXU products it
+    issues a tile (the heads' scores, and the indexer's pre-activations and
+    the two gradients they carry a head), what it keeps on chip between
+    its loops and what it asks Mosaic for, or the `jax.numpy` body's
+    block."""
+    if _kernels_tile(seq):
+        return {"align": "kernel", "align_rows_on_chip": ROWS,
+                "align_cols": ALIGN_COLS,
+                "align_products_a_tile": h + 3 * heads,
+                "align_kept": "relu(a_j) of the tile, float32: %d bytes"
+                % _align_kept_bytes(heads),
+                "align_vmem_limit_bytes": _align_vmem(
+                    seq, heads, width, h, kv, d, jnp.dtype(dtype).itemsize)
+                + _VMEM_SPARE}
+    return {"align": "xla", "align_rows_on_chip": None,
+            "align_rows_a_block": _blocks(seq)[0],
+            "align_vmem_limit_bytes": None}
 
 
 def _align_value_and_grads(qi, ki, w, q, k, lse, lse_i, sel_q, sm_scale,

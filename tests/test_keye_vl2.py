@@ -7,6 +7,7 @@ float32 reference `benchmarks/reference/keye_vl2.py`, at a small size on the
 CPU with seeded weights: float32 on both sides, so only the order of the
 arithmetic differs; the Mosaic kernels interpreted."""
 
+import functools
 import hashlib
 import os
 import re
@@ -180,6 +181,8 @@ def test_the_counters_and_the_gauge_say_what_a_step_chose():
     plans = [s for s in profiler.spans() if s.name == "mx.dsa.plan"]
     assert plans and plans[-1].args["topk"] == 8
     assert plans[-1].args["select"] == "xla"        # 48 rows: no whole block
+    assert plans[-1].args["align"] == "xla"
+    assert plans[-1].args["align_rows_a_block"] == 48
     assert "masked flash" in plans[-1].args["form"]
 
 
@@ -445,25 +448,54 @@ def test_the_plan_span_says_a_selection_is_an_operand():
     assert "vmem_limit_bytes" not in plain.args["fwd"]
 
 
-@pytest.mark.parametrize("interpret", [False, True], ids=["body", "kernel"])
-def test_the_alignment_term_and_its_gradient(interpret):
+#: sequence, keys a query, heads, key/value heads, what marks the case; the
+#: first is the `jax.numpy` body's, the others `mx_dsa_align`'s
+ALIGN_CASES = {
+    "body": (48, 8, 4, 2, None),
+    "kernel": (512, 64, 4, 2, None),
+    # several row blocks a column tile, every tile under the diagonal whole
+    "kernel-1024": (1024, 64, 4, 2, None),
+    # rows 1024 to 1279 end in the middle of the columns' third tile
+    "kernel-1536": (1536, 96, 4, 2, None),
+    # the cell's eight query heads a key/value head
+    "kernel-grouped-8": (512, 64, 8, 1, None),
+    # pairs of equal keys: rows that hold more than `topk` keys
+    "kernel-ties": (512, 63, 4, 2, "ties"),
+    # no row has `topk` causal keys: every causal key is chosen
+    "kernel-every-causal-key": (512, 2048, 4, 2, None),
+    # queries so long that a key left out scores e^89 times what the
+    # chosen ones do: float32's exponential of it is inf, and discarded
+    "kernel-unseen-overflows": (512, 64, 4, 2, "long queries")}
+
+
+@pytest.mark.parametrize("case", list(ALIGN_CASES))
+def test_the_alignment_term_and_its_gradient(case):
     """`alignment_term` (the `jax.numpy` body, and `mx_dsa_align`
     interpreted) against the term written out densely: its value, and its
     gradient on the indexer's queries, key and head weights."""
-    b, s, h, kv, d = 2, 512 if interpret else 48, 4, 2, 16
-    topk = 64 if interpret else 8
+    s, topk, h, kv, mark = ALIGN_CASES[case]
+    interpret, b, d = case != "body", 2, 16
     qi, ki, w = indexer(s=s)
-    q, k, v = rand(70, b, h, s, d), rand(71, b, kv, s, d), \
-        rand(72, b, kv, s, d)
+    if mark == "ties":
+        ki = ki.at[:, 1::2].set(ki[:, 0::2])
+    q, k, v = rand(70, b, h, s, d, scale=100 if mark == "long queries" else 1
+                   ), rand(71, b, kv, s, d), rand(72, b, kv, s, d)
     with jax.default_matmul_precision("highest"):
         sel_q, sel_k, lse_i = sparse_attention.index_select(qi, ki, w, topk)
         mask = attention.unpack_selection(sel_q, s)
-        k_all, v_all = jnp.repeat(k, 2, 1), jnp.repeat(v, 2, 1)
+        if mark == "ties":
+            kept = np.asarray(mask).sum(-1)
+            assert (kept > topk).sum() > kept.size // 4
+        k_all, v_all = jnp.repeat(k, h // kv, 1), jnp.repeat(v, h // kv, 1)
         _, lse = attention.selected_attention(q, k_all, v_all, sel_q, sel_k,
                                               0.25)
-        target = jnp.mean(jax.nn.softmax(jnp.where(
-            mask[:, None], jnp.einsum("bhqd,bhkd->bhqk", q, k_all) * 0.25,
-            -jnp.inf), -1), 1)
+        att = jnp.einsum("bhqd,bhkd->bhqk", q, k_all) * 0.25
+        target = jnp.mean(jax.nn.softmax(jnp.where(mask[:, None], att,
+                                                   -jnp.inf), -1), 1)
+        if mark == "long queries":
+            left_out = jnp.tril(jnp.ones((s, s), bool)) & ~mask
+            assert float(jnp.where(left_out[:, None], att - lse[..., None],
+                                   0.0).max()) > 89
 
         def dense(qi, ki, w):
             scores = reference.index_scores(qi.reshape(b, s, 2, 8), ki, w)
@@ -483,6 +515,65 @@ def test_the_alignment_term_and_its_gradient(interpret):
     for name, a, c in zip(("qi", "ki", "w"), got[1], want[1]):
         scale = float(jnp.abs(c).max())
         assert float(jnp.abs(a - c).max()) <= 2e-5 * scale, name
+
+
+def kernel_dots(jaxpr):
+    """Every `dot_general` of a jaxpr, the ones inside its kernels, loops
+    and branches among them."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) \
+                    else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from kernel_dots(inner)
+
+
+def test_the_alignment_kernel_forms_each_product_of_a_tile_once(monkeypatch):
+    """`mx_dsa_align` at the cell's head counts: 32 products for the heads'
+    scores, 16 for the indexer's pre-activations and 32 that carry ``dI``
+    back to qI and kI (the pre-activations stay on chip for them), each
+    contracting its left operand's last axis: none turns a tile to
+    contract its first.  The plan says so where the kernels run."""
+    s, h, kv, d, j, di = 512, 32, 4, 128, 16, 64
+    bf = jnp.bfloat16
+    jaxpr = jax.make_jaxpr(functools.partial(
+        sparse_attention._align_pallas, sm_scale=d ** -0.5))(
+        jnp.zeros((1, s, j * di), bf), jnp.zeros((1, s, di), bf),
+        jnp.zeros((1, s, j)), jnp.zeros((1, h, s, d), bf),
+        jnp.zeros((1, kv, s, d), bf), jnp.zeros((1, h, s)),
+        jnp.zeros((1, s)), jnp.zeros((1, s // 32, s), jnp.int32))
+    dots = list(kernel_dots(jaxpr.jaxpr))
+    assert len(dots) == 32 + 16 + 32
+    shapes = {}
+    for eqn in dots:
+        (lhs, rhs), batch = eqn.params["dimension_numbers"]
+        assert lhs == (1,) and rhs in ((0,), (1,)) and batch == ((), ())
+        assert {v.aval.dtype for v in eqn.invars} == {jnp.dtype(bf)}
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+        key = tuple(v.aval.shape for v in eqn.invars)
+        shapes[key] = shapes.get(key, 0) + 1
+    cols, rows = sparse_attention.ALIGN_COLS, sparse_attention.ROWS
+    # the tile is keys by queries; g_j, (cols, rows), is the latched
+    # operand of both its products
+    assert shapes == {((cols, d), (rows, d)): h,        # k . q^T
+                      ((cols, di), (di, rows)): j,      # kI . qI_j^T
+                      ((di, cols), (cols, rows)): j,    # dqI_j^T = kI^T . g_j
+                      ((di, rows), (cols, rows)): j}    # dkI^T = qI_j^T . g_j^T
+    monkeypatch.setattr(sparse_attention, "mosaic_runs_here", lambda: True)
+    plan = sparse_attention.align_plan(16384, j, di, h, kv, d, bf)
+    assert plan["align"] == "kernel"
+    assert plan["align_products_a_tile"] == len(dots) == 80
+    assert (plan["align_rows_on_chip"], plan["align_cols"]) == (rows, cols)
+    kept = j * rows * cols * 4
+    assert plan["align_kept"].endswith("float32: %d bytes" % kept)
+    # what the kernel asks Mosaic for counts what it keeps and kI's
+    # gradient, which spans the sequence, twice
+    assert plan["align_vmem_limit_bytes"] > kept + 2 * 16384 * di * 4
+    assert sparse_attention.align_plan(48, j, di, h, kv, d, bf)["align"] \
+        == "xla"
 
 
 # -- the router ---------------------------------------------------------------
@@ -725,6 +816,11 @@ def test_a_layer_compiles_for_the_described_chip_with_no_square_array(
     for kernel in ("mx_dsa_select", "mx_flash_fwd", "mx_flash_bwd",
                    "mx_dsa_align"):
         assert kernel in text, kernel
+    # `mx_dsa_align` keeps its tile's relu(a_j) on chip: Mosaic placed that
+    # within what the kernel asks for, and the ask is half the chip's VMEM
+    assert sparse_attention._align_kept_bytes(16) == 8 << 20
+    assert sparse_attention._align_vmem(16384, 16, 64, 32, 4, 128, 2) \
+        + sparse_attention._VMEM_SPARE < 64 << 20
     assert not re.search(r"\[[0-9,]*16384,[0-9,]*16384", text)
     assert "s32[1,512,16384]" in text
     # all the temporaries together (q, the repeated k and v, the gradients
