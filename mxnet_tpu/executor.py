@@ -78,8 +78,13 @@ def _build_eval(symbol, training):
             if "training" in op.param_names:
                 params = dict(params, training=training)
             # a device scope per node ("<op>:<node>" in every instruction's
-            # op_name): metadata only, the program compiles as without it
-            with jax.named_scope("%s:%s" % (op.name, node.name)):
+            # op_name): metadata only, the program compiles as without it;
+            # under the scope a block named for a group of its nodes, if it
+            # did (`AttrScope(__scope__=...)`)
+            scope = "%s:%s" % (op.name, node.name)
+            if node.attrs.get("__scope__"):
+                scope = "%s/%s" % (node.attrs["__scope__"], scope)
+            with jax.named_scope(scope):
                 if op.needs_rng:
                     sub = jax.random.fold_in(key, pos)
                     out = op.fn(sub, *ins, **params)

@@ -232,11 +232,20 @@ class GroupedQueryAttention(HybridBlock):
     heads, RMS norm over each query and key head, and rotary positions;
     no biases.  The key/value heads are repeated to the query heads in
     front of ``contrib.DotProductAttention`` (the flash kernels take
-    equal head counts)."""
+    equal head counts).
+
+    With *diffusion_block* the attention is not causal: the sequence is a
+    clean copy then a noised copy of half its length each, and the mask
+    is the block-diffusion one in blocks of that many positions
+    (`ops/attention.py` `BlockDiffusion`).  An optional second input,
+    ``positions`` ``(1, batch, seq)``, gives the rotary positions (both
+    copies of a token stand at the same one); without it they are ``0 ..
+    seq - 1``.  In a compiled graph the projections then lie under device
+    scope ``mx.bd.project`` and the attention under ``mx.bd.attention``."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
                  rope_theta=10000.0, epsilon=1e-5, weight_initializer=None,
-                 **kwargs):
+                 diffusion_block=None, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("num_heads (%d) must be a multiple of "
@@ -246,6 +255,11 @@ class GroupedQueryAttention(HybridBlock):
         self._heads, self._kv_heads = num_heads, num_kv_heads
         self._head_dim, self._theta = head_dim, float(rope_theta)
         self._eps = epsilon
+        self._mask = {"causal": True} if not diffusion_block else {
+            "mask": "block_diffusion", "mask_block": int(diffusion_block)}
+        # the projections' nodes as one named group of the compiled graph
+        self._group = {"__scope__": "mx.bd.project"} if diffusion_block \
+            else {}
         with self.name_scope():
             def weight(name, rows, cols):
                 return self.params.get(name, shape=(rows, cols),
@@ -263,28 +277,35 @@ class GroupedQueryAttention(HybridBlock):
             self.k_gamma = self.params.get(
                 "key_norm_gamma", shape=(head_dim,), init="ones")
 
-    def hybrid_forward(self, F, x, q_weight, k_weight, v_weight, out_weight,
-                       q_gamma, k_gamma):
+    def hybrid_forward(self, F, x, positions=None, q_weight=None,
+                       k_weight=None, v_weight=None, out_weight=None,
+                       q_gamma=None, k_gamma=None):
         def heads(w, n, gamma=None):
-            # (B, S, U) -> (B, S, n, d), normed over d, -> (B, n, S, d)
+            # (B, S, U) -> (B, S, n, d), normed over d, turned,
+            # -> (B, n, S, d)
             h = F.FullyConnected(x, w, no_bias=True, flatten=False,
                                  num_hidden=n * self._head_dim)
             h = F.Reshape(h, shape=(0, 0, n, -1))
-            if gamma is not None:
-                h = F.contrib.RMSNorm(h, gamma, eps=self._eps)
-            return F.transpose(h, axes=(0, 2, 1, 3))
+            if gamma is None:
+                return F.transpose(h, axes=(0, 2, 1, 3))
+            h = F.transpose(F.contrib.RMSNorm(h, gamma, eps=self._eps),
+                            axes=(0, 2, 1, 3))
+            if positions is None:
+                return F.contrib.RotaryEmbedding(h, theta=self._theta)
+            return F.contrib.RotaryEmbedding(h, positions, theta=self._theta,
+                                             use_positions=True)
 
-        q = F.contrib.RotaryEmbedding(heads(q_weight, self._heads, q_gamma),
-                                      theta=self._theta)
-        k = F.contrib.RotaryEmbedding(
-            heads(k_weight, self._kv_heads, k_gamma), theta=self._theta)
-        v = heads(v_weight, self._kv_heads)
-        group = self._heads // self._kv_heads
-        if group > 1:
-            k = F.repeat(k, repeats=group, axis=1)
-            v = F.repeat(v, repeats=group, axis=1)
+        from .... import symbol
+        with symbol.AttrScope(**self._group):
+            q = heads(q_weight, self._heads, q_gamma)
+            k = heads(k_weight, self._kv_heads, k_gamma)
+            v = heads(v_weight, self._kv_heads)
+            group = self._heads // self._kv_heads
+            if group > 1:
+                k = F.repeat(k, repeats=group, axis=1)
+                v = F.repeat(v, repeats=group, axis=1)
         att = F.contrib.DotProductAttention(
-            q, k, v, causal=True, sm_scale=self._head_dim ** -0.5)
+            q, k, v, sm_scale=self._head_dim ** -0.5, **self._mask)
         att = F.Reshape(F.transpose(att, axes=(0, 2, 1, 3)),
                         shape=(0, 0, -1))
         return F.FullyConnected(att, out_weight, no_bias=True,

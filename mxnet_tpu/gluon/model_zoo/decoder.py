@@ -4,7 +4,7 @@ added to the residual:
 
     h = h + operator(rms(h));  h = h + feed_forward(rms(h))
 
-then a final RMS norm and a head.  The operator is one of four kinds
+then a final RMS norm and a head.  The operator is one of five kinds
 (`OPERATOR_KINDS`):
 
 - ``"conv"``: a gated short causal convolution;
@@ -21,7 +21,20 @@ then a final RMS norm and a head.  The operator is one of four kinds
   frequencies dealt to the axes of an optional ``positions`` input by
   ``mrope_section``.  A net with such layers returns ``(logits, term)``:
   ``alignment_weight`` times the mean of the layers' terms, which
-  `AlignedLoss` adds to the objective beside a loss of the logits.
+  `AlignedLoss` adds to the objective beside a loss of the logits;
+- ``"block_diffusion_attention"``: grouped-query attention as
+  ``"full_attention"`` has it, under the block-diffusion mask in blocks of
+  ``diffusion_block`` positions (`ops/attention.py` `BlockDiffusion`,
+  BD3-LM, arXiv:2503.09573): the net's input is ``(B, 2L)`` ids, a clean
+  copy of each sequence then a noised copy; both copies of token ``i`` stand
+  at rotary position ``i``; a clean query sees the clean keys of its own and
+  of earlier blocks, a noised one the clean keys of earlier blocks and the
+  noised keys of its own block, forward and backward.  The final norm and
+  the head read the noised half alone, so the net returns ``(B, L, vocab)``
+  logits, and `BlockDiffusionLoss` is its objective: the cross-entropy of
+  the clean token at each masked position (no shift), weighted by the
+  label's second plane.  Device scopes ``mx.bd.project`` and
+  ``mx.bd.attention``.
 
 The feed-forward is a dense gated MLP in the first ``num_dense_layers``
 layers and dropless top-k routed experts in the others, to which
@@ -36,10 +49,12 @@ These are the shapes of LiquidAI's LFM2 mixture-of-experts models
 (``model_type`` ``lfm2_moe``: conv and full_attention layers, a tied head)
 and of the ``deepseek_v3`` family (latent attention, shared experts, an
 untied head), and of Kwai-Keye's ``KeyeVL2`` language model (sparse
-attention, a softmax router, three-axis rotary positions), whose published
-``config.json`` keys the arguments follow; `benchmarks/models/lfm2_moe.py`,
-`benchmarks/models/deepseek_v3.py` and `benchmarks/models/keye_vl2.py` build
-one from such a file.
+attention, a softmax router, three-axis rotary positions), and of JetLM's
+``sdar_moe`` models (block_diffusion_attention layers, a softmax router, an
+untied head), whose published ``config.json`` keys the arguments follow;
+`benchmarks/models/lfm2_moe.py`, `benchmarks/models/deepseek_v3.py`,
+`benchmarks/models/keye_vl2.py` and `benchmarks/models/sdar_moe.py` build one
+from such a file.
 
 The routed layers hold ONE CHIP'S SHARE of their experts
 (`gluon.contrib.nn.RoutedExperts`): ``experts_held`` of ``num_experts``
@@ -61,11 +76,11 @@ from ..contrib.nn import (GatedMLP, GatedShortConv, GroupedQueryAttention,
                           LatentAttention, RoutedExperts, SharedExperts,
                           SparseAttention)
 
-__all__ = ["AlignedLoss", "DecoderLayer", "DecoderLM", "get_decoder_lm",
-           "OPERATOR_KINDS"]
+__all__ = ["AlignedLoss", "BlockDiffusionLoss", "DecoderLayer", "DecoderLM",
+           "get_decoder_lm", "OPERATOR_KINDS"]
 
 OPERATOR_KINDS = ("conv", "full_attention", "latent_attention",
-                  "sparse_attention")
+                  "sparse_attention", "block_diffusion_attention")
 
 
 class SharedAndRouted(HybridBlock):
@@ -87,19 +102,22 @@ class DecoderLayer(HybridBlock):
     """One pre-norm layer: *operator* is built by kind (*latent* holds
     `LatentAttention`'s own widths, *sparse* `SparseAttention`'s),
     *feed_forward* is handed in.  A ``positions`` input goes to a
-    sparse_attention operator and to no other; a layer of that kind returns
-    ``(output, alignment term)``."""
+    sparse_attention or block_diffusion_attention operator and to no other;
+    a sparse_attention layer returns ``(output, alignment term)``."""
 
     def __init__(self, dim, kind, feed_forward, heads, kv_heads, head_dim,
                  rope_theta, conv_kernel, eps, init, latent=None,
-                 sparse=None, **kwargs):
+                 sparse=None, diffusion_block=None, **kwargs):
         super().__init__(**kwargs)
         if kind not in OPERATOR_KINDS:
             raise ValueError(
                 "layer kind %r is not one of %s (a gated short convolution, "
                 "grouped-query attention, multi-head latent attention, "
-                "learned sparse attention)" % (kind, OPERATOR_KINDS))
-        self._takes_positions = kind == "sparse_attention"
+                "learned sparse attention, grouped-query attention under "
+                "the block-diffusion mask)" % (kind, OPERATOR_KINDS))
+        self._takes_positions = kind in ("sparse_attention",
+                                         "block_diffusion_attention")
+        self._returns_term = kind == "sparse_attention"
         with self.name_scope():
             self.operator_norm = nn.RMSNorm(dim, eps,
                                             prefix="operator_norm_")
@@ -111,6 +129,14 @@ class DecoderLayer(HybridBlock):
                 self.operator = GroupedQueryAttention(
                     dim, heads, kv_heads, head_dim, rope_theta, eps,
                     weight_initializer=init, prefix="attn_")
+            elif kind == "block_diffusion_attention":
+                if not diffusion_block:
+                    raise ValueError("a block_diffusion_attention layer "
+                                     "needs diffusion_block")
+                self.operator = GroupedQueryAttention(
+                    dim, heads, kv_heads, head_dim, rope_theta, eps,
+                    weight_initializer=init, prefix="attn_",
+                    diffusion_block=diffusion_block)
             elif kind == "sparse_attention":
                 if not sparse:
                     raise ValueError(
@@ -132,14 +158,15 @@ class DecoderLayer(HybridBlock):
             self.feed_forward = feed_forward()
 
     def hybrid_forward(self, F, x, positions=None):
-        if not self._takes_positions:
-            x = x + self.operator(self.operator_norm(x))
-            return x + self.feed_forward(self.ffn_norm(x))
         h = self.operator_norm(x)
-        out, term = self.operator(h) if positions is None \
-            else self.operator(h, positions)
+        out = self.operator(h, positions) \
+            if self._takes_positions and positions is not None \
+            else self.operator(h)
+        if self._returns_term:
+            out, term = out
         x = x + out
-        return x + self.feed_forward(self.ffn_norm(x)), term
+        x = x + self.feed_forward(self.ffn_norm(x))
+        return (x, term) if self._returns_term else x
 
 
 class DecoderLM(HybridBlock):
@@ -151,7 +178,11 @@ class DecoderLM(HybridBlock):
     matrix of its own.  A second input, ``positions`` ``(axes, batch,
     seq)``, reaches the sparse_attention layers' rotary positions; with
     such layers the net returns ``(logits, term)``, *alignment_weight*
-    times the mean of their alignment terms, shape ``(1,)``."""
+    times the mean of their alignment terms, shape ``(1,)``.  With
+    block_diffusion_attention layers (blocks of *diffusion_block*) the input
+    is ``(batch, 2 * seq)``, a clean copy then a noised copy, the positions
+    are ``j mod seq`` where none are given, and the logits are the noised
+    half's: ``(batch, seq, vocab)``."""
 
     def __init__(self, vocab, dim, layer_types, num_dense_layers,
                  dense_hidden, expert_hidden, num_experts,
@@ -164,9 +195,11 @@ class DecoderLM(HybridBlock):
                  qk_rope_head_dim=None, v_head_dim=None,
                  rope_interleave=True, scoring_func="sigmoid",
                  index_heads=None, index_head_dim=None, index_topk=None,
-                 mrope_section=(), alignment_weight=1.0, **kwargs):
+                 mrope_section=(), alignment_weight=1.0,
+                 diffusion_block=None, **kwargs):
         super().__init__(**kwargs)
         self._vocab, self._dim = vocab, dim
+        self._two_copies = "block_diffusion_attention" in layer_types
         init = weight_initializer
         latent = kv_lora_rank and {
             "kv_lora_rank": kv_lora_rank,
@@ -208,7 +241,7 @@ class DecoderLM(HybridBlock):
                     dim, kind, dense if i < num_dense_layers else sparse,
                     heads, kv_heads or heads, head_dim, rope_theta,
                     conv_kernel, eps, init, latent, indexer,
-                    prefix="l%d_" % i)
+                    diffusion_block, prefix="l%d_" % i)
                 setattr(self, "l%d" % i, layer)
                 self.layers.append(layer)
             self.final_norm = nn.RMSNorm(dim, eps, prefix="final_norm_")
@@ -219,12 +252,17 @@ class DecoderLM(HybridBlock):
                        head_weight=None):
         h = F.Embedding(x, embed_weight, input_dim=self._vocab,
                         output_dim=self._dim)
+        if self._two_copies and positions is None:
+            positions = F.contrib.BlockDiffusionPositions(x)
         terms = []
         for layer in self.layers:
             h = layer(h) if positions is None else layer(h, positions)
             if isinstance(h, tuple):
                 h, term = h
                 terms.append(term)
+        if self._two_copies:
+            # the clean half is context only: no norm, no head, no logits
+            h = F.split(h, num_outputs=2, axis=1)[1]
         logits = F.FullyConnected(
             self.final_norm(h),
             embed_weight if head_weight is None else head_weight,
@@ -257,6 +295,22 @@ class AlignedLoss(HybridBlock):
         F = symbol if isinstance(out, symbol.Symbol) else ndarray
         rows = self.loss(out, label, *args)
         return F.broadcast_add(rows, term - F.BlockGrad(term))
+
+
+class BlockDiffusionLoss(HybridBlock):
+    """The masked-diffusion objective of a net with
+    block_diffusion_attention layers, one value a row: *pred* ``(batch, L,
+    vocab)`` logits of the noised copy, *label* ``(batch, 2, L)`` float32,
+    its planes the clean ids and the weights (``1 / t`` of the position's
+    block where it was masked, else 0):
+
+        ``(1 / L) * sum_i w_i * -log softmax(pred_i)[x_i]``
+
+    summed and divided in float32 (`_contrib_BlockDiffusionLoss`, which
+    also counts the positions that carry loss)."""
+
+    def hybrid_forward(self, F, pred, label):
+        return F.contrib.BlockDiffusionLoss(pred, label)
 
 
 def get_decoder_lm(**kwargs):

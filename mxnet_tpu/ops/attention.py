@@ -47,7 +47,8 @@ from jax.sharding import PartitionSpec as P
 from ._precision import matmul_precision
 from .registry import register_op
 
-__all__ = ["flash_attention", "attention_reference"]
+__all__ = ["flash_attention", "attention_reference", "BlockDiffusion",
+           "block_diffusion_visible"]
 
 _NEG_INF = -1e30
 # Inside a kernel the per-row softmax state (running max, denominator)
@@ -82,8 +83,48 @@ _UNROLL = 4
 _UNROLL_WHOLE = 8
 
 
-def attention_reference(q, k, v, causal=False, sm_scale=None):
+#: the block-diffusion mask, a static description: the sequence is two
+#: copies of *half* positions, a clean one then a noised one, in blocks of
+#: *block*.  With ``n(j) = j >= half`` and ``b(j) = (j mod half) // block``
+#: query ``j`` sees key ``s`` where both are clean and ``b(s) <= b(j)``,
+#: or the query is noised and the key clean and ``b(s) < b(j)``, or both
+#: are noised and ``b(s) == b(j)`` (BD3-LM, arXiv:2503.09573, section 5).
+#: Every query sees a key; ``half * (half + block)`` pairs are visible.
+BlockDiffusion = collections.namedtuple("BlockDiffusion", "block half")
+
+
+def _checked_mask(mask, causal, sq, sk):
+    """*mask* as a `BlockDiffusion` of ints, or None; raises where it does
+    not describe these sequences."""
+    if mask is None:
+        return None
+    mask = BlockDiffusion(int(mask[0]), int(mask[1]))
+    if causal:
+        raise ValueError("a block-diffusion mask is not causal: pass one "
+                         "or the other")
+    if sq != sk or sq != 2 * mask.half or mask.block < 1 \
+            or mask.half % mask.block:
+        raise ValueError(
+            "a block-diffusion mask of two halves of %d in blocks of %d "
+            "does not describe %d queries and %d keys"
+            % (mask.half, mask.block, sq, sk))
+    return mask
+
+
+def block_diffusion_visible(q_pos, k_pos, mask):
+    """Whether query position *q_pos* sees key position *k_pos* under
+    *mask* (a `BlockDiffusion`), from its definition; the two broadcast."""
+    block, half = mask
+    qn, kn = q_pos >= half, k_pos >= half
+    qb = (q_pos - qn * half) // block
+    kb = (k_pos - kn * half) // block
+    return (~kn & ~qn & (kb <= qb)) | (~kn & qn & (kb < qb)) \
+        | (kn & qn & (kb == qb))
+
+
+def attention_reference(q, k, v, causal=False, sm_scale=None, mask=None):
     """O(S^2)-memory einsum attention — the numeric oracle for tests.
+    *mask* is a `BlockDiffusion` (every row then sees a key).
 
     Degenerate-row convention (shared by all paths in this module): a
     causal query row that can see NO keys (seq_q > seq_k under the
@@ -97,7 +138,12 @@ def attention_reference(q, k, v, causal=False, sm_scale=None):
                    k.astype(jnp.float32),
                    precision=matmul_precision(q.dtype, k.dtype)) \
         * sm_scale
-    if causal:
+    mask = _checked_mask(mask, causal, s.shape[-2], s.shape[-1])
+    if mask is not None:
+        seen = block_diffusion_visible(jnp.arange(s.shape[-2])[:, None],
+                                       jnp.arange(s.shape[-1])[None, :], mask)
+        p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
+    elif causal:
         qlen, klen = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((qlen, klen), bool), klen - qlen)
         s = jnp.where(mask, s, _NEG_INF)
@@ -145,8 +191,10 @@ def _finalize_softmax(o, m, l):
     l_safe = jnp.where(degenerate, 1.0, l)
     return jnp.where(degenerate[..., None], 0.0, o / l_safe[..., None])
 
-def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512):
-    """Blockwise attention with online softmax over K chunks.
+def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512,
+                       mask=None):
+    """Blockwise attention with online softmax over K chunks; *mask* a
+    `BlockDiffusion` in `causal`'s place.
 
     Memory is O(S_q * chunk) instead of O(S_q * S_k); the scan body is
     rematerialized on backward (jax.checkpoint), which is exactly the
@@ -179,7 +227,11 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512):
                        preferred_element_type=jnp.float32) * sm_scale
         k_pos = ci * chunk + jnp.arange(chunk)
         valid = k_pos < sk
-        if causal:
+        if mask is not None:
+            valid = valid[None, :] & block_diffusion_visible(
+                q_pos[:, None], k_pos[None, :], mask)
+            s = jnp.where(valid[None, None], s, _NEG_INF)
+        elif causal:
             valid = valid[None, :] & (k_pos[None, :] <= q_pos[:, None])
             s = jnp.where(valid[None, None], s, _NEG_INF)
         else:
@@ -349,14 +401,19 @@ def _resident(n_sub, bytes_per_sub, budget):
 
 
 def _kernel_tiles(kernel, sub, sq, sk, d_block, dv_block, itemsize, res_q,
-                  res_k):
+                  res_k, halves=1):
     """``(sq_padded, sk_padded, _Tiles)`` of *kernel* with sub-tile *sub*
     cut to the sequence: the resident blocks are the largest
     `_VMEM_BUDGET` holds, the streamed side first (K/V for the forward,
-    Q/dO for the backward)."""
+    Q/dO for the backward).  A sequence of *halves* equal parts (a
+    block-diffusion mask's two copies) is padded a part at a time and a
+    resident block lies inside one part, so that no tile crosses from one
+    into the next."""
     sub_q, sub_k = sub
-    sq_p, sk_p = _round_up(sq, res_q or sub_q), _round_up(sk, res_k or sub_k)
+    sq_p = _round_up(sq // halves, res_q or sub_q)
+    sk_p = _round_up(sk // halves, res_k or sub_k)
     nq, nk = sq_p // sub_q, sk_p // sub_k
+    sq_p, sk_p = halves * sq_p, halves * sk_p
     per_q, per_k = _side_bytes(kernel, d_block, dv_block, itemsize)
     per_q, per_k = per_q * sub_q, per_k * sub_k
     budget = _VMEM_BUDGET - _tile_bytes(sub_q, sub_k)
@@ -378,7 +435,7 @@ def _unrolls_whole(t, sq_p, sk_p):
 
 
 def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
-                res_k=None, d_v=None):
+                res_k=None, d_v=None, halves=1):
     """Tiles of the two kernels from what the call can see: the
     lengths, the head dim of q and k, that of v (*d_v*; *d* where not
     given) and the dtype.  One algorithm with different
@@ -389,7 +446,10 @@ def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
     keys: fewer, larger iterations (both from the sweep; the backward's
     query edge has to stay at 256).
     `causal` is not an input: the same tiles serve both, the loops'
-    bounds differ.  The backward keeps dq's accumulator in VMEM where a
+    bounds differ.  Under a block-diffusion mask the sequence is *halves*
+    = 2 copies: the tiles are cut to one copy and never cross into the
+    other (`_kernel_tiles`), so a head is never one grid step and the
+    sub-tile is the looped one.  The backward keeps dq's accumulator in VMEM where a
     head's whole sequence of it stays under `_VMEM_DQ`, else in HBM.
     *blk_q*, *blk_k* (sub-tile edges) and *res_q*, *res_k* (resident
     rows; multiples of the sub-tile that divide the padded length)
@@ -399,18 +459,20 @@ def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
     dv_block = d_block if d_v is None else _d_block(d_v)
 
     def cut(sub):
-        return (min(blk_q or sub[0], _round_up(sq, 1 if blk_q else _LANES)),
-                min(blk_k or sub[1], _round_up(sk, 1 if blk_k else _LANES)))
+        return (min(blk_q or sub[0],
+                    _round_up(sq // halves, 1 if blk_q else _LANES)),
+                min(blk_k or sub[1],
+                    _round_up(sk // halves, 1 if blk_k else _LANES)))
 
     found = {}
     for kernel in _KERNELS:
         found[kernel] = _kernel_tiles(
             kernel, cut(_SUB_UNROLLED), sq, sk, d_block, dv_block, itemsize,
-            res_q, res_k)
+            res_q, res_k, halves)
         if not _unrolls_whole(found[kernel][2], *found[kernel][:2]):
             found[kernel] = _kernel_tiles(
                 kernel, cut(_SUB_LOOPED), sq, sk, d_block, dv_block,
-                itemsize, res_q, res_k)
+                itemsize, res_q, res_k, halves)
     (sq_f, sk_f, fwd), (sq_b, sk_b, bwd) = found["fwd"], found["bwd"]
     dq_accumulator = "vmem" if _dq_bytes(
         "vmem", sq_b, d_block, itemsize) <= _VMEM_DQ else "hbm"
@@ -467,6 +529,139 @@ def _q_tiles(col0, q0, n, t, off, seq_k, causal):
     return j_first, jnp.where(padded, n, j_full)
 
 
+# Under a block-diffusion mask (`BlockDiffusion`) both kernels see the
+# sequence as two halves, each padded to whole resident blocks: `_Halves`
+# holds the block length, a half's real length and its padded lengths
+# along the queries and along the keys.  Positions below are LOCAL to
+# their half.  A real query never sees a padded key, so the padding needs
+# no term of its own; a padded query sees what the last real one does.
+_Halves = collections.namedtuple("_Halves", "block real pad_q pad_k")
+#: a tile's mask body: the tile's keys are clean ones (*noised* 0), visible
+#: below the query's block start plus *extra* (the block length for a clean
+#: query, 0 for a noised one), or noised ones (*noised* 1), visible inside
+#: the query's block.  Ints, or traced where one loop runs tiles of several
+#: kinds (`_run_segments`)
+_BdMask = collections.namedtuple("_BdMask", "noised extra")
+
+
+def _sel(which, a, b):
+    """*a* where *which* is 1, *b* where it is 0 (ints or traced)."""
+    return which * a + (1 - which) * b
+
+
+def _below(a, b):
+    """1 where *a* < *b*, else 0 (ints or traced)."""
+    if isinstance(a, int) and isinstance(b, int):
+        return int(a < b)
+    return (a < b).astype(jnp.int32)
+
+
+def _bd_start(x, block):
+    """First position of the *block*-long block that holds position *x*."""
+    if block & (block - 1) == 0:
+        return x & -block
+    return _idiv(x, block) * block
+
+
+def _bd_blocks(x0, n, hv):
+    """Block starts of the first and of the last real position among the
+    *n* from *x0* on (of the last real one of the half where all *n* are
+    padding)."""
+    return (_bd_start(_imin(x0, hv.real - 1), hv.block),
+            _bd_start(_imin(x0 + n, hv.real) - 1, hv.block))
+
+
+def _bd_k_segments(row0, k0, n, t, hv):
+    """The forward's loops for the query sub-tile whose first row is
+    *row0* over the *n* key sub-tiles of the resident block at column
+    *k0*, as ``(lo, hi, mask)``: the clean keys every row of the tile
+    sees, those a block boundary crosses, and (a noised tile) the noised
+    keys of its own blocks."""
+    qh = _idiv(row0, hv.pad_q)          # 0: clean queries, 1: noised ones
+    first, last = _bd_blocks(row0 - qh * hv.pad_q, t.sub_q, hv)
+    live = _below(row0 - qh * hv.pad_q, hv.real)    # 0: a tile of padding
+    extra = hv.block * (1 - qh)
+    base, half = _idiv(k0, t.sub_k), hv.pad_k // t.sub_k
+
+    def local(g):
+        return _imin(_imax(g - base, 0), n)
+
+    def up(x):
+        return _idiv(x + t.sub_k - 1, t.sub_k)
+
+    full = live * local(_idiv(first + extra, t.sub_k))
+    vis = live * local(up(last + extra))
+    lo = local(half + _idiv(first, t.sub_k))
+    hi = _sel(qh * live, local(half + up(last + hv.block)), lo)
+    return ((0, full, None), (full, vis, _BdMask(0, extra)),
+            (lo, hi, _BdMask(1, 0)))
+
+
+def _bd_q_segments(col0, q0, n, t, hv):
+    """The backward's loops for the key sub-tile whose first column is
+    *col0* over the *n* query sub-tiles of the resident block at row *q0*.
+    Clean keys: the clean rows from the keys' first block on and the
+    noised rows from the block after, each masked until the keys' last
+    block is passed; noised keys: the noised rows of their own blocks."""
+    kh = _idiv(col0, hv.pad_k)          # 0: clean keys, 1: noised ones
+    first, last = _bd_blocks(col0 - kh * hv.pad_k, t.sub_k, hv)
+    live = _below(col0 - kh * hv.pad_k, hv.real)    # 0: a tile of padding
+    base, half = _idiv(q0, t.sub_q), hv.pad_q // t.sub_q
+    rows = -(-hv.real // t.sub_q)       # a half's tiles that hold a real row
+
+    def local(g):
+        return _imin(_imax(g - base, 0), n)
+
+    def up(x):
+        return _imin(_idiv(x + t.sub_q - 1, t.sub_q), rows)
+
+    clean, noised = (1 - kh) * live, kh * live
+    a_lo, a_full = _idiv(first, t.sub_q), up(last)
+    # (the keys' first block may be the half's last: no noised row then)
+    b_lo = _sel(_below(first + hv.block, hv.real),
+                _idiv(first + hv.block, t.sub_q), rows)
+    b_full = _imax(up(last + hv.block), b_lo)
+    # on noised keys the first four are empty, on clean keys the last
+    return ((local(_sel(clean, a_lo, rows)), local(_sel(clean, a_full, rows)),
+             _BdMask(0, hv.block)),
+            (local(_sel(clean, a_full, rows)), local(rows), None),
+            (local(half + _sel(clean, b_lo, rows)),
+             local(half + _sel(clean, b_full, rows)), _BdMask(0, 0)),
+            (local(half + _sel(clean, b_full, rows)), local(half + rows),
+             None),
+            (local(half + _sel(noised, a_lo, rows)),
+             local(half + _sel(noised, b_full, rows)), _BdMask(1, 0)))
+
+
+def _bd_k_blocks(iq, t, hv):
+    """Resident key blocks a query block *iq* visits under the mask:
+    ``(last clean one, first noised one, last noised one)``; a clean
+    query block's noised pair is its last clean block twice."""
+    qh = _idiv(iq * t.res_q, hv.pad_q)
+    first, last = _bd_blocks(iq * t.res_q - qh * hv.pad_q, t.res_q, hv)
+    c_last = _idiv(_imax(last + hv.block * (1 - qh) - 1, 0), t.res_k)
+    half = hv.pad_k // t.res_k
+    return (c_last, _sel(qh, half + _idiv(first, t.res_k), c_last),
+            _sel(qh, half + _idiv(last + hv.block - 1, t.res_k), c_last))
+
+
+def _bd_q_blocks(ik, t, hv):
+    """Resident query blocks a key block *ik* is seen by: ``(first clean
+    one, first noised one, last noised one)``."""
+    kh = _idiv(ik * t.res_k, hv.pad_k)
+    first, last = _bd_blocks(ik * t.res_k - kh * hv.pad_k, t.res_k, hv)
+    half = hv.pad_q // t.res_q
+    a_lo = _idiv(first, t.res_q)
+    b_lo = _imin(_idiv(first + hv.block, t.res_q), half - 1)
+    return (_sel(kh, half, a_lo), half + _sel(kh, a_lo, b_lo),
+            half + _sel(kh, _idiv(last + hv.block - 1, t.res_q), half - 1))
+
+
+def _halves_of(mask, sq_p, sk_p):
+    return None if mask is None else _Halves(mask.block, mask.half,
+                                             sq_p // 2, sk_p // 2)
+
+
 def _last_k_block(iq, t, nkr, off):
     """The last resident key block a causal query block *iq* sees."""
     return _imin(nkr - 1, _idiv(
@@ -478,20 +673,31 @@ def _first_q_block(ik, t, nqr, off):
     return _imin(nqr - 1, _idiv(_imax(ik * t.res_k - off, 0), t.res_q))
 
 
-def _tile_counts(kernel, plan, sq, sk, causal):
+def _tile_counts(kernel, plan, sq, sk, causal, mask=None):
     """What the schedule of *kernel* visits, for one head: score tiles
     computed, those of them computed under the mask, and the visible
-    scores in tiles (`tiles_ideal`: the causal triangle, or the
-    rectangle).  The same bound functions as the kernels, on ints."""
+    scores in tiles (`tiles_ideal`: the causal triangle, the rectangle, or
+    what a block-diffusion *mask* leaves).  The same bound functions as the
+    kernels, on ints."""
     t = getattr(plan, kernel)
     sq_p, sk_p = (plan.sq_fwd, plan.sk_fwd) if kernel == "fwd" \
         else (plan.sq_bwd, plan.sk_bwd)
     off = sk - sq
+    hv = _halves_of(mask, sq_p, sk_p)
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
     visited = masked = 0
     for q0 in range(0, sq_p, t.res_q):
         for k0 in range(0, sk_p, t.res_k):
-            if kernel == "bwd":
+            if hv is not None:
+                segments = [
+                    seg for j in range(nks if kernel == "bwd" else nqs)
+                    for seg in (
+                        _bd_q_segments(k0 + j * t.sub_k, q0, nqs, t, hv)
+                        if kernel == "bwd" else
+                        _bd_k_segments(q0 + j * t.sub_q, k0, nks, t, hv))]
+                visited += sum(hi - lo for lo, hi, _ in segments)
+                masked += sum(hi - lo for lo, hi, m in segments if m)
+            elif kernel == "bwd":
                 for jk in range(nks):
                     first, full = _q_tiles(k0 + jk * t.sub_k, q0, nqs, t,
                                            off, sk, causal)
@@ -503,7 +709,9 @@ def _tile_counts(kernel, plan, sq, sk, causal):
                                          off, sk, causal)
                     visited += vis
                     masked += vis - full
-    if causal:
+    if mask is not None:
+        scores = mask.half * (mask.half + mask.block)
+    elif causal:
         lo, hi = max(0, -off), sq            # rows that see a key
         scores = (hi - lo) * (lo + off + hi + off + 1) // 2 if hi > lo \
             else 0
@@ -513,17 +721,19 @@ def _tile_counts(kernel, plan, sq, sk, causal):
             "tiles_ideal": round(scores / (t.sub_q * t.sub_k), 3)}
 
 
-def _plan_args(plan, sq, sk, d, dtype, causal, d_v=None):
+def _plan_args(plan, sq, sk, d, dtype, causal, d_v=None, mask=None):
     """The plan as the `mx.flash.plan` span carries it: static per shape,
     so recorded where the call is traced, not where it runs."""
     rec = {"sq": sq, "sk": sk, "d": d, "dtype": jnp.dtype(dtype).name,
            "causal": bool(causal), "d_block": plan.d_block,
            "d_v": d if d_v is None else d_v, "dv_block": plan.dv_block}
+    if mask is not None:
+        rec.update(mask="block_diffusion", block=mask.block, half=mask.half)
     itemsize = jnp.dtype(dtype).itemsize
     for kernel in _KERNELS:
         t = getattr(plan, kernel)
         rec[kernel] = dict(
-            _tile_counts(kernel, plan, sq, sk, causal),
+            _tile_counts(kernel, plan, sq, sk, causal, mask),
             resident=[t.res_q, t.res_k], sub_tile=[t.sub_q, t.sub_k],
             vmem_bytes=_vmem_bytes(kernel, plan, itemsize))
     rec["bwd"].update(dq_accumulator=plan.dq_accumulator,
@@ -531,18 +741,20 @@ def _plan_args(plan, sq, sk, d, dtype, causal, d_v=None):
     return rec
 
 
-def _record_plan(q, k, v, causal, selected=False):
+def _record_plan(q, k, v, causal, selected=False, mask=None):
     """One `mx.flash.plan` span each time the op is traced.  At trace
     time on purpose: the plan is a fact of the compiled program, not of
     a step.  With a selection operand (*selected*) the span says so: every
     visited tile is then computed under the mask, and the forward asks
-    Mosaic for its own `vmem_limit_bytes` too."""
+    Mosaic for its own `vmem_limit_bytes` too.  Under a block-diffusion
+    *mask* it carries the mask's kind, block and half."""
     from .. import profiler
     sq, sk, d, d_v = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     with profiler.scope(  # graftlint: disable=JG003
             "mx.flash.plan", "flash") as span:
-        plan = _flash_plan(sq, sk, d, q.dtype, d_v=d_v)
-        span.args = _plan_args(plan, sq, sk, d, q.dtype, causal, d_v)
+        plan = _flash_plan(sq, sk, d, q.dtype, d_v=d_v,
+                           halves=1 if mask is None else 2)
+        span.args = _plan_args(plan, sq, sk, d, q.dtype, causal, d_v, mask)
         if selected:
             itemsize = jnp.dtype(q.dtype).itemsize
             span.args["selection"] = "bits"
@@ -611,6 +823,22 @@ def _tile_mask(shape, q_axis, row0, col0, off, seq_k, causal, padded_k):
     return mask
 
 
+def _bd_tile_mask(shape, q_axis, row0, col0, hv, m):
+    """Visibility under a block-diffusion mask of the score tile at query
+    row *row0* and key column *col0* (whole-sequence positions; query rows
+    along *q_axis*), its keys of the half and with the bound *m* (a
+    `_BdMask`) names: from iotas, no operand."""
+    q_loc = row0 - _idiv(row0, hv.pad_q) * hv.pad_q \
+        + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_loc = col0 - m.noised * hv.pad_k \
+        + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    start = _bd_start(q_loc, hv.block)
+    # clean keys: below the start plus `extra`; noised keys: from the
+    # start on, one block long
+    return (k_loc >= m.noised * start) \
+        & (k_loc < start + m.extra + m.noised * hv.block)
+
+
 def _and(mask, other):
     return other if mask is None else mask & other
 
@@ -660,6 +888,35 @@ def _two_loops(bounds, tile, any_masked):
         _loop(a, b, functools.partial(tile, masked=masked))
 
 
+def _run_segments(segments, tile):
+    """Run *tile* over each ``(lo, hi, mask)`` of *segments*, in two loops:
+    the tiles that need no mask body, then those that do, each loop over
+    its segments laid end to end with the tile's index and the mask's
+    bounds chosen by where the count stands.  A loop a segment is five
+    copies of the backward's tile body in each of ten loops a key
+    sub-tile, 71085 bundles where the causal kernel has 29182, and a tile
+    then took 3.6 times as long on the chip (PERF.md section 6, PR 39)."""
+    for masked in (False, True):
+        group = [(lo, _imax(hi, lo), m) for lo, hi, m in segments
+                 if (m is not None) == masked]
+        if not group:
+            continue
+
+        def body(j, group=group, masked=masked):
+            lo, end, m = group[0]
+            at, end = lo + j, end - lo      # `end`: tiles up to here
+            for lo2, hi2, m2 in group[1:]:
+                later = _below(end - 1, j)
+                at = _sel(later, lo2 + j - end, at)
+                if masked:
+                    m = _BdMask(_sel(later, m2.noised, m.noised),
+                                _sel(later, m2.extra, m.extra))
+                end = end + hi2 - lo2
+            tile(at, masked=m)
+
+        _loop(0, sum(hi - lo for lo, hi, _ in group), body)
+
+
 def _traced_inline(kernel):
     """Trace *kernel*'s body with `jax.disable_jit`: every `jnp` function
     and array operator is itself a jitted function, and an unrolled body
@@ -674,7 +931,9 @@ def _traced_inline(kernel):
 
 @_traced_inline
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
-                      seq_q, seq_k, padded_k, selected=False):
+                      seq_q, seq_k, padded_k, selected=False, hv=None):
+    # with *hv* (`_Halves`) the mask is the block-diffusion one: the loops'
+    # bounds and the mask bodies come from its geometry, not from `causal`
     # with *selected* a selection block follows v: (1, res_q / 32, res_k)
     # words, bit r % 32 of word r // 32 saying whether query row r of the
     # block sees the key; every visited tile is then a masked one
@@ -710,9 +969,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
             v = v_ref[0, ks, :]
             s = _mxu_dot(q, k, _NT) * sm_scale      # (sub_q, sub_k)
             if masked:
+                col0 = ik * t.res_k + jk * t.sub_k
                 mask = _tile_mask(
-                    s.shape, 0, row0, ik * t.res_k + jk * t.sub_k, off,
-                    seq_k, causal, padded_k)
+                    s.shape, 0, row0, col0, off, seq_k, causal, padded_k) \
+                    if hv is None else _bd_tile_mask(
+                        s.shape, 0, row0, col0, hv, masked)
                 if selected:
                     mask = _and(mask, unpack_selection(sel_ref[
                         0, _sub(jq, t.sub_q // _SEL_BITS, nqs), ks]))
@@ -731,12 +992,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
             acc_ref[qs, :] = acc_ref[qs, :] * _lanes_to(alpha, d) + \
                 _mxu_dot(p.astype(v.dtype), v, _NN)
 
-        n_full, n_vis = _k_tiles(row0, ik * t.res_k, nks, t, off, seq_k,
-                                 causal)
-        if selected:
-            n_full = 0
-        _two_loops((0, n_full, n_vis, False), tile,
-                   causal or padded_k or selected)
+        if hv is not None:
+            _run_segments(_bd_k_segments(row0, ik * t.res_k, nks, t, hv),
+                          tile)
+        else:
+            n_full, n_vis = _k_tiles(row0, ik * t.res_k, nks, t, off, seq_k,
+                                     causal)
+            if selected:
+                n_full = 0
+            _two_loops((0, n_full, n_vis, False), tile,
+                       causal or padded_k or selected)
 
         @pl.when(ik == nkr - 1)
         def _finish():
@@ -762,13 +1027,27 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
     _loop(0, nqs, q_tile)
 
 
-def _pad_bh(x, s_to, d_to):
-    """(b, h, s, d) as (b*h, s_to, d_to), zero padded; no copy where the
-    shape already fits."""
+def _pad_bh(x, s_to, d_to, halves=1):
+    """(b, h, s, d) as (b*h, s_to, d_to), zero padded (each of *halves*
+    equal parts of the sequence to its own share of *s_to*); no copy where
+    the shape already fits."""
     b, h, s, d = x.shape
     if s_to != s or d_to != d:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, s_to - s), (0, d_to - d)))
+        x = jnp.pad(x.reshape(b, h, halves, s // halves, d), (
+            (0, 0), (0, 0), (0, 0), (0, (s_to - s) // halves),
+            (0, d_to - d)))
     return x.reshape(b * h, s_to, d_to)
+
+
+def _pad_rows(x, s_to, halves=1):
+    """A (bh, 1, s) row statistic zero padded to *s_to*, a part at a
+    time."""
+    bh, _, s = x.shape
+    if s_to == s:
+        return x
+    return jnp.pad(x.reshape(bh, 1, halves, s // halves), (
+        (0, 0), (0, 0), (0, 0), (0, (s_to - s) // halves))
+    ).reshape(bh, 1, s_to)
 
 
 def _pad_selection(sel, rows, cols):
@@ -782,19 +1061,37 @@ def _pad_selection(sel, rows, cols):
                          (0, cols - sel.shape[2])))
 
 
-def _unpad_bh(x, b, h, s, d):
-    """(b*h, s_padded, d_padded) back to (b, h, s, d); no copy where
-    nothing was padded."""
+def _unpad_bh(x, b, h, s, d, halves=1):
+    """(b*h, s_padded, d_padded) back to (b, h, s, d), each of *halves*
+    parts cut to its own rows; no copy where nothing was padded."""
     s_p, d_p = x.shape[1:]
-    x = x.reshape(b, h, s_p, d_p)
-    return x if (s_p, d_p) == (s, d) else x[:, :, :s, :d]
+    if (s_p, d_p) == (s, d):
+        return x.reshape(b, h, s, d)
+    x = x.reshape(b, h, halves, s_p // halves, d_p)
+    return x[:, :, :, :s // halves, :d].reshape(b, h, s, d)
 
 
-def _k_index(t, nkr, off, causal):
+def _unpad_rows(x, s, halves=1):
+    """`_pad_rows` undone."""
+    bh, _, s_p = x.shape
+    if s_p == s:
+        return x
+    return x.reshape(bh, 1, halves, s_p // halves)[
+        ..., :s // halves].reshape(bh, 1, s)
+
+
+def _k_index(t, nkr, off, causal, hv=None):
     """Index map of the key-side blocks on a grid (bh, iq, ik).  Causal,
     a step above the diagonal is empty (its loops run no tile): its
     index is clamped to the last block the query block sees, so the
-    empty step refetches nothing."""
+    empty step refetches nothing.  Under a block-diffusion mask (*hv*) a
+    step past the query block's last clean block takes the index of the
+    nearest noised block it visits (a clean query block's: none)."""
+    if hv is not None:
+        def index(bh_, iq, ik):
+            c_last, n_first, n_last = _bd_k_blocks(iq, t, hv)
+            return jnp.where(ik <= c_last, ik, jnp.clip(ik, n_first, n_last))
+        return index
     if causal and nkr > 1:
         return lambda bh_, iq, ik: jnp.minimum(
             ik, _last_k_block(iq, t, nkr, off))
@@ -823,37 +1120,41 @@ def _block_specs(t, d_block, dv_block, q_index, k_index):
 # is traced once per shape and emitted as one function that every layer
 # calls (XLA inlines it; each call site keeps its own op_name).
 _STATIC = ("causal", "sm_scale", "blk_q", "blk_k", "interpret", "res_q",
-           "res_k")
+           "res_k", "mask")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("with_lse",))
 def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
                       interpret=False, with_lse=False, res_q=None,
-                      res_k=None, sel=None):
+                      res_k=None, sel=None, mask=None):
     """Flash forward: grid (B*H, resident q blocks, resident k blocks),
     f32 accumulators in VMEM scratch.  ``with_lse`` also returns the
     per-row logsumexp residual (the flash backward's recompute anchor)
     as ``(B*H, 1, seq_q)``.  *sel* is a selection operand
     (`pack_selection` of a ``(B, seq_q, seq_k)`` mask, one for all the
     heads of a batch row): a query then sees a key only where its bit is
-    set, besides `causal` and the padding."""
+    set, besides `causal` and the padding.  *mask* is a `BlockDiffusion`
+    in `causal`'s place."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
-    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v)
+    halves = 1 if mask is None else 2
+    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v,
+                       halves)
     t, dp, dvp = plan.fwd, plan.d_block, plan.dv_block
     sq_p, sk_p = plan.sq_fwd, plan.sk_fwd
-    qp = _pad_bh(q, sq_p, dp)
-    kp = _pad_bh(k, sk_p, dp)
-    vp = _pad_bh(v, sk_p, dvp)
+    hv = _halves_of(mask, sq_p, sk_p)
+    qp = _pad_bh(q, sq_p, dp, halves)
+    kp = _pad_bh(k, sk_p, dp, halves)
+    vp = _pad_bh(v, sk_p, dvp, halves)
     bh = b * h
     nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
 
     q_spec, k_spec, o_spec, v_spec, row_spec = _block_specs(
         t, dp, dvp, lambda bh_, iq, ik: iq,
-        _k_index(t, nkr, sk - sq, causal))
+        _k_index(t, nkr, sk - sq, causal, hv))
     kernel = functools.partial(
         _flash_fwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
-        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk)
+        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk, hv=hv)
     in_specs, operands, params = [q_spec, k_spec, v_spec], (qp, kp, vp), {}
     if sel is not None:
         k_index = _k_index(t, nkr, sk - sq, causal)
@@ -887,9 +1188,9 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
             interpret=interpret,
             name="mx_flash_fwd",
         )(*operands)
-    out = _unpad_bh(res[0], b, h, sq, d_v)
+    out = _unpad_bh(res[0], b, h, sq, d_v, halves)
     if with_lse:
-        return out, res[1][:, :, :sq]
+        return out, _unpad_rows(res[1], sq, halves)
     return out
 
 
@@ -934,7 +1235,7 @@ def _wide(x, width):
 @_traced_inline
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *rest, t, grid, sm_scale, causal, seq_q, seq_k,
-                      padded_k, selected=False):
+                      padded_k, selected=False, hv=None):
     """K/V block resident, Q/dO sub-tiles from the first visible row on;
     *dq_acc* spans the head's sequence, and without it this step's share
     of dq accumulates in its f32 output block.  With *selected* a
@@ -985,9 +1286,11 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do = _wide(do_ref[0, qs, :], wide_v)
             s = _mxu_dot(k, q, _NT) * sm_scale      # (sub_k, sub_q)
             if masked:
+                row0 = iq * t.res_q + jq * t.sub_q
                 mask = _tile_mask(
-                    s.shape, 1, iq * t.res_q + jq * t.sub_q, col0, off,
-                    seq_k, causal, padded_k)
+                    s.shape, 1, row0, col0, off, seq_k, causal, padded_k) \
+                    if hv is None else _bd_tile_mask(
+                        s.shape, 1, row0, col0, hv, masked)
                 if selected:
                     mask = _and(mask, unpack_selection(sel_ref[
                         0, _sub(jk, t.sub_k // _SEL_BITS, nks), qs]))
@@ -1006,12 +1309,16 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             # 8% slower at S = 2048, tools/flash_sweep.py)
             dq_add(jq, _mxu_dot(ds.T.astype(k.dtype), k, _NN))
 
-        j_first, j_full = _q_tiles(col0, iq * t.res_q, nqs, t, off, seq_k,
-                                   causal)
-        if selected:
-            j_full = nqs
-        _two_loops((j_first, j_full, nqs, True), tile,
-                   causal or padded_k or selected)
+        if hv is not None:
+            _run_segments(_bd_q_segments(col0, iq * t.res_q, nqs, t, hv),
+                          tile)
+        else:
+            j_first, j_full = _q_tiles(col0, iq * t.res_q, nqs, t, off,
+                                       seq_k, causal)
+            if selected:
+                j_full = nqs
+            _two_loops((j_first, j_full, nqs, True), tile,
+                       causal or padded_k or selected)
 
         @pl.when(iq == nqr - 1)
         def _finish():
@@ -1043,35 +1350,43 @@ def _dq_head_groups(bh, partial_bytes, sq):
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
                       blk_q=None, blk_k=None, interpret=False, res_q=None,
-                      res_k=None, sel=None):
+                      res_k=None, sel=None, mask=None):
     """dq, dk, dv from the forward's output and its ``(B*H, 1, seq_q)``
     logsumexp: grid (B*H, resident k blocks, resident q blocks), K/V
     resident and Q/dO streamed.  *sel* is the forward's selection the
     scores' way round here: `pack_selection` of the ``(B, seq_k, seq_q)``
-    transposed mask."""
+    transposed mask.  *mask* is a `BlockDiffusion` in `causal`'s place."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
-    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v)
+    halves = 1 if mask is None else 2
+    plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v,
+                       halves)
     t, dp, dvp = plan.bwd, plan.d_block, plan.dv_block
     sq_p, sk_p = plan.sq_bwd, plan.sk_bwd
+    hv = _halves_of(mask, sq_p, sk_p)
     # the accumulators' lanes
     wide, wide_v = _round_up(dp, _LANES), _round_up(dvp, _LANES)
-    qp = _pad_bh(q, sq_p, dp)
-    kp = _pad_bh(k, sk_p, dp)
-    vp = _pad_bh(v, sk_p, dvp)
-    dop = _pad_bh(dout, sq_p, dvp)
+    qp = _pad_bh(q, sq_p, dp, halves)
+    kp = _pad_bh(k, sk_p, dp, halves)
+    vp = _pad_bh(v, sk_p, dvp, halves)
+    dop = _pad_bh(dout, sq_p, dvp, halves)
     bh = b * h
     # delta_i = rowsum(dO_i * O_i), a row like lse; both zero on padded
     # rows, where dO is zero too and p = exp(0 - 0) multiplies nothing
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, sq)
-    if sq_p != sq:
-        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, sq_p - sq)))
-        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, sq_p - sq)))
+    lse, delta = _pad_rows(lse, sq_p, halves), _pad_rows(delta, sq_p, halves)
     nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
 
     def q_index(bh_, ik, iq):
-        # an empty step (before the first visible row) refetches nothing
+        # an empty step (before the first visible row; under a
+        # block-diffusion mask, outside the rows that see the key block)
+        # refetches nothing
+        if hv is not None:
+            a_first, n_first, n_last = _bd_q_blocks(ik, t, hv)
+            return jnp.where((iq < nqr // 2) & (a_first < nqr // 2),
+                             jnp.maximum(iq, a_first),
+                             jnp.clip(iq, n_first, n_last))
         return jnp.maximum(iq, _first_q_block(ik, t, nqr, sk - sq)) \
             if causal and nqr > 1 else iq
 
@@ -1095,7 +1410,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
 
     kernel = functools.partial(
         _flash_bwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
-        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk)
+        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk, hv=hv)
     in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
     limit = _vmem_limit(plan, q.dtype.itemsize)
     if sel is not None:
@@ -1144,45 +1459,52 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
             x.reshape((bh,) + x.shape[2:]) for x in jax.lax.map(call, tuple(
                 x.reshape((bh // heads, heads) + x.shape[1:])
                 for x in operands)))
-    return (_unpad_bh(dq, b, h, sq, d), _unpad_bh(dk, b, h, sk, d),
-            _unpad_bh(dv, b, h, sk, d_v))
+    return (_unpad_bh(dq, b, h, sq, d, halves),
+            _unpad_bh(dk, b, h, sk, d, halves),
+            _unpad_bh(dv, b, h, sk, d_v, halves))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, sm_scale, interpret):
-    _record_plan(q, k, v, causal)
-    return _flash_fwd_pallas(q, k, v, causal, sm_scale, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, interpret, mask=None):
+    _record_plan(q, k, v, causal, mask=mask)
+    return _flash_fwd_pallas(q, k, v, causal, sm_scale, interpret=interpret,
+                             mask=mask)
 
 
-def _flash_vjp_fwd(q, k, v, causal, sm_scale, interpret):
+def _flash_vjp_fwd(q, k, v, causal, sm_scale, interpret, mask):
     out, lse = _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                                 interpret=interpret, with_lse=True)
+                                 interpret=interpret, with_lse=True,
+                                 mask=mask)
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, sm_scale, interpret, res, g):
+def _flash_vjp_bwd(causal, sm_scale, interpret, mask, res, g):
     q, k, v, out, lse = res
     return _flash_bwd_pallas(q, k, v, out, lse, g, causal, sm_scale,
-                             interpret=interpret)
+                             interpret=interpret, mask=mask)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False,
-                    chunk=512):
+                    chunk=512, mask=None):
     """Blockwise (flash) attention, (B, H, S, D) layout.
 
     Pallas MXU kernel on TPU; chunked-scan XLA path elsewhere (*chunk*
     is its block length).  Both have O(S * block) activation memory;
-    grads flow through either.
+    grads flow through either.  *mask* is a static description of a mask
+    that is not causal, a `BlockDiffusion` ``(block, half)``: the kernels'
+    loops then visit the tiles that hold a visible pair and no other, and
+    no mask is an operand.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    mask = _checked_mask(mask, causal, q.shape[2], k.shape[2])
     if interpret:
         dt = jnp.result_type(q.dtype, k.dtype, v.dtype)
         return _flash(q.astype(dt), k.astype(dt), v.astype(dt),
-                      causal, float(sm_scale), True).astype(q.dtype)
+                      causal, float(sm_scale), True, mask).astype(q.dtype)
 
     def _tpu(q, k, v):
         # the kernels' MXU dots need one operand dtype (f32 q against a
@@ -1193,7 +1515,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False,
 
         def local(q, k, v):
             return _flash(q.astype(dt), k.astype(dt), v.astype(dt),
-                          causal, float(sm_scale), False).astype(q.dtype)
+                          causal, float(sm_scale), False,
+                          mask).astype(q.dtype)
 
         from ..parallel.mesh import current_mesh
         mesh = current_mesh()
@@ -1212,8 +1535,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False,
                              out_specs=spec, check_vma=False)(q, k, v)
 
     def _other(q, k, v):
-        return _chunked_attention(q, k, v, causal, sm_scale,
-                                  int(chunk)).astype(q.dtype)
+        return _chunked_attention(q, k, v, causal, sm_scale, int(chunk),
+                                  mask).astype(q.dtype)
 
     # decided at LOWERING time per platform, not by which devices this
     # process happens to see: only the target platform's branch is
@@ -1347,8 +1670,28 @@ def selected_attention(q, k, v, sel_q, sel_k, sm_scale=None,
 @register_op("_contrib_DotProductAttention",
              input_names=("query", "key", "value"))
 def _dot_product_attention(query, key, value, causal=False, sm_scale=None,
-                           chunk=512):
+                           chunk=512, mask=None, mask_block=1):
     """Fused scaled-dot-product attention (TPU-native; no reference
-    counterpart — the reference predates Transformers, SURVEY §5.7)."""
-    return flash_attention(query, key, value, causal=bool(causal),
-                           sm_scale=sm_scale, chunk=chunk)
+    counterpart — the reference predates Transformers, SURVEY §5.7).
+    *mask* ``"block_diffusion"`` with *mask_block* puts the sequence under
+    the block-diffusion mask (`ops/attention.py` `BlockDiffusion`: a clean
+    copy then a noised copy of half the sequence each), under device scope
+    ``mx.bd.attention``."""
+    if mask is None:
+        return flash_attention(query, key, value, causal=bool(causal),
+                               sm_scale=sm_scale, chunk=chunk)
+    if mask != "block_diffusion":
+        raise ValueError("mask %r is not built (block_diffusion is)"
+                         % (mask,))
+    from .. import profiler
+    half = query.shape[2] // 2
+    # at trace time on purpose (as the routed op's counts); a float: a
+    # batch's pairs pass 2^31
+    profiler.emit_step_stat(  # graftlint: disable=JG003
+        "bd_visible_pairs", jnp.float32(
+            query.shape[0] * half * (half + int(mask_block))))
+    with jax.named_scope("mx.bd.attention"):
+        return flash_attention(
+            query, key, value, causal=bool(causal), sm_scale=sm_scale,
+            chunk=chunk,
+            mask=BlockDiffusion(int(mask_block), half))

@@ -414,6 +414,79 @@ profiler.register_step_stat("dsa_alignment_loss", _fold_dsa_alignment)
 
 
 # ---------------------------------------------------------------------------
+# Training by diffusion over blocks (BD3-LM, arXiv:2503.09573): the net's
+# input is a clean copy of each sequence then a noised copy, attention runs
+# under `ops/attention.py` `BlockDiffusion`; here the positions both copies
+# share and the weighted cross-entropy over the noised copy.
+# ---------------------------------------------------------------------------
+
+@register_op("_contrib_BlockDiffusionPositions",
+             aliases=("BlockDiffusionPositions",))
+def _block_diffusion_positions(data):
+    """Rotary positions of ``(batch, 2L)`` token ids that are a clean copy
+    then a noised copy of ``L`` tokens: ``(1, batch, 2L)`` int32, position
+    ``j mod L`` (both copies of token ``i`` stand at ``i``), as
+    `_contrib_RotaryEmbedding` takes them with ``use_positions``."""
+    batch, seq = data.shape[0], data.shape[1]
+    return jnp.broadcast_to(
+        jnp.arange(seq, dtype=jnp.int32) % (seq // 2), (1, batch, seq))
+
+
+def _fold_bd_counts(rows):
+    rows = np.asarray(rows).reshape(-1, 2)
+    profiler.bump_counter("bd_masked_positions_total", int(rows[:, 0].sum()))
+    profiler.bump_counter("bd_positions_total", int(rows[:, 1].sum()))
+
+
+def _fold_bd_loss(values):
+    from ..observability import metrics
+    metrics.gauge(
+        "bd_loss", "the block-diffusion objective of the last step, the "
+        "mean over its rows").set(
+            float(np.mean(np.asarray(values, np.float64))))
+
+
+def _fold_bd_pairs(values):
+    profiler.bump_counter("bd_visible_pairs_total",
+                          int(np.asarray(values, np.int64).sum()))
+
+
+@register_op("_contrib_BlockDiffusionLoss", aliases=("BlockDiffusionLoss",))
+def _block_diffusion_loss(data, label):
+    """The masked-diffusion objective of one step, a value a row: *data*
+    ``(batch, L, vocab)`` logits of the noised copy, *label* ``(batch, 2,
+    L)`` float32 holding the clean ids ``x`` and the weights ``w`` (``1 /
+    t`` of its block where the position was masked, else 0):
+
+        ``loss = (1 / L) * sum_i w_i * -log softmax(logits_i)[x_i]``
+
+    with the logsumexp, the sum and the division in float32.  No shift:
+    the token predicted is the one at the masked position itself.  The
+    positions that carry loss and all positions leave as counters
+    ``bd_masked_positions_total`` and ``bd_positions_total``, the value as
+    gauge ``bd_loss``."""
+    x = data.astype(jnp.float32)
+    ids = label[:, 0].astype(jnp.int32)
+    w = label[:, 1].astype(jnp.float32)
+    # the target's logit by a select: a gather's gradient is a scatter
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, 2) == ids[..., None]
+    nll = jax.nn.logsumexp(x, -1) - jnp.sum(jnp.where(hit, x, 0.0), -1)
+    rows = jnp.sum(w * nll, -1) / x.shape[1]
+    # at trace time on purpose (as the routed op's counts)
+    profiler.emit_step_stat(  # graftlint: disable=JG003
+        "bd_position_counts", jnp.stack([
+            jnp.sum(w > 0, dtype=jnp.int32), jnp.int32(w.size)]))
+    profiler.emit_step_stat(  # graftlint: disable=JG003
+        "bd_loss", jnp.mean(rows))
+    return rows
+
+
+profiler.register_step_stat("bd_position_counts", _fold_bd_counts)
+profiler.register_step_stat("bd_loss", _fold_bd_loss)
+profiler.register_step_stat("bd_visible_pairs", _fold_bd_pairs)
+
+
+# ---------------------------------------------------------------------------
 # The gated short convolution.  Its middle, everything between the input
 # projection and the output projection, is one function `_gate`: on the TPU
 # a pair of Mosaic kernels that pass over ``bcx`` once each way; everywhere

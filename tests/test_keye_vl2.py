@@ -748,8 +748,8 @@ def test_the_eight_shares_parts_of_a_routed_layer_add_up_to_the_uncut_one():
 def test_a_decoder_without_the_kind_s_widths_says_what_is_missing():
     from mxnet_tpu.gluon.model_zoo.decoder import (OPERATOR_KINDS,
                                                    get_decoder_lm)
-    assert OPERATOR_KINDS == ("conv", "full_attention", "latent_attention",
-                              "sparse_attention")
+    assert OPERATOR_KINDS[:4] == ("conv", "full_attention",
+                                  "latent_attention", "sparse_attention")
     with pytest.raises(ValueError, match="index_heads"):
         get_decoder_lm(vocab=32, dim=64, layer_types=["sparse_attention"],
                        num_dense_layers=1, dense_hidden=64, expert_hidden=32,
