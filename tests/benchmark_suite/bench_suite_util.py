@@ -14,6 +14,25 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 
+#: the accepted per-layer entries whose readers find something to read in
+#: an expert cell built from the decoder's routed layer and flash kernels
+#: (PERF.md section 7 row 31; my chip runs, PR 42, call A: each read a number
+#: in `keye-vl-2.0-30b-a3b_train_ep8share` and in
+#: `sdar-30b-a3b-chat_train_ep8share`), on whose lists PR 42 put both
+EXPERT_CELL_LISTS = (
+    "mosaic_time_share_pct", "setup_import_s", "setup_state_s",
+    "setup_program_s", "trainer_python_ms_per_step",
+    "prefetch_wait_ms_per_step", "prefetch_put_ms_per_batch",
+    "gc_pause_ms_per_block", "step_forward_ms", "step_backward_ms",
+    "step_optimizer_ms", "step_unscoped_pct", "flash_fwd_ms_per_step",
+    "flash_bwd_ms_per_step", "idle_outside_program_pct", "moe_ms_per_step",
+    "moe_expert_matmul_ms_per_step", "moe_expert_matmul_roofline_pct",
+    "moe_expert_load_max_over_mean", "moe_worst_case_layers_pct")
+#: PR 37's four, which name every cell
+COST_LISTS = ("step_hbm_gb", "step_floor_ms", "step_memory_bound_ms",
+              "step_optimizer_hbm_gb")
+
+
 def named(entries, name):
     """The one entry of a list of `BENCHMARK.json` called *name*: entries
     are found by name, never by their place in the list."""

@@ -463,7 +463,7 @@ def test_the_tiny_cell_runs_and_is_correct(root, capsys):
     from mxnet_tpu import profiler
     steps0 = profiler.counter_value("moe_stat_steps_total")
     outcome, line = util.run_cell(root, "tiny_kanana_train",
-                                  seed=2 ** 31 + 13, seconds=0.5)
+                                  seed=2 ** 31 + 13, seconds=3.0)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 24 and line["metrics"] == {}
     folded = profiler.counter_value("moe_stat_steps_total") - steps0

@@ -340,12 +340,10 @@ KEYE_READERS = ("dsa_ms_per_step", "dsa_index_ms_per_step",
 def test_keye_s_declaration_with_every_entry_found_by_name(spec):
     """What `test_bench_keye_vl2.py::test_the_cell_is_declared_and_its_
     readers_list_it` holds the declaration to, with the entries found by
-    `name`.  That test looks for Keye's five entries by their place
-    (`per_layer[-5:]`) and allows the cell on no other list, so the four
-    entries this file's readers are declared by, appended after them with
-    all five cells on their lists as ISSUE 37 asks, fail it; a file under
-    `tests/benchmark_suite/` that exists is a `benchmark` PR's to edit
-    (PERF.md section 7 row 35).  Everything else of it is held here."""
+    `name`.  Until PR 42 that test looked for Keye's five entries by their
+    place (`per_layer[-5:]`), and the four entries this file's readers are
+    declared by, appended after them as ISSUE 37 asks, failed it (PERF.md
+    section 7 row 35); it finds them by name now, and this copy stays."""
     with open(os.path.join(util.REPO, "benchmarks", "configs",
                            KEYE_CONFIG + ".json")) as f:
         cfg = json.load(f)
@@ -372,12 +370,12 @@ def test_keye_s_declaration_with_every_entry_found_by_name(spec):
     for name in KEYE_READERS:
         assert util.named(spec["per_layer"], name)["workloads"] \
             == [KEYE_CELL], name
-    # the entries accepted before them are not edited: the cell is on none
-    # of their lists; those after them may name it, as this PR's four do
-    for m in spec["per_layer"][:first]:
-        assert KEYE_CELL not in m.get("workloads", ()), m["name"]
-    assert set(READERS) <= {m["name"] for m in spec["per_layer"][first + 5:]
-                            if KEYE_CELL in m.get("workloads", ())}
+    # this file's four name it, as do (since PR 42: PERF.md section 7 row
+    # 31) the twenty accepted entries whose readers find something in it
+    listed = {m["name"] for m in spec["per_layer"]
+              if KEYE_CELL in m.get("workloads", ())}
+    assert set(READERS) | set(KEYE_READERS) \
+        | set(util.EXPERT_CELL_LISTS) <= listed
     # ... and reports the ones without a list
     unlisted = [m["name"] for m in spec["per_layer"] if "workloads" not in m]
     assert len(unlisted) == 9 and "model_flops_util_pct" in unlisted

@@ -400,6 +400,63 @@ def test_every_unreduced_key_is_the_published_one(cfg):
         "first_gradient_norm_rms", "update_norm_gap", "update_norm_rms"}
 
 
+@pytest.fixture(scope="module")
+def readings():
+    with open(os.path.join(util.FIXTURES, "sdar_check_readings.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("number", [
+    "first_update_difference", "loss_gap", "first_gradient_norm_gap",
+    "first_gradient_norm_rms", "update_norm_gap", "update_norm_rms"])
+def test_a_limit_stands_off_the_sound_runs_and_the_controls_it_decides(
+        cfg, readings, number):
+    """The chip's readings of the cell's check, one row a run
+    (`fixtures/sdar_check_readings.json`: the accepted program, PRs 40 and
+    41's fused projection as a sound variant, the three controls), hold the
+    file's limits: every sound run under its limit, the accepted program's
+    1.5 times under, every run of a control that the number is said to
+    decide 1.5 times over; and `_limits_from` says what the rows say."""
+    limit = cfg["check"]["limits"][number]
+    rows = readings["rows"]
+
+    def of(tree):
+        return [r[number] for r in rows if r["tree"] == tree]
+
+    accepted, variant = of("accepted"), of("variant_headrope")
+    seeds = {r["seed"] for r in rows if r["tree"] == "accepted"}
+    assert len(seeds) >= 47 and {4015, 2147486113} <= seeds
+    assert len(variant) == 47
+    assert max(accepted + variant) < limit
+    assert 1.5 * max(accepted) <= limit
+    decides = readings["decides"][number]
+    for control in ("fp8", "causal", "block_diagonal"):
+        runs = of(control)
+        assert len(runs) >= 3 and 2147486113 in {
+            r["seed"] for r in rows if r["tree"] == control}
+        if control in decides:
+            assert min(runs) >= 1.5 * limit, control
+    # every control is some number's to fail, on every seed it ran on
+    if number == "first_update_difference":
+        assert set(decides) == {"fp8", "causal", "block_diagonal"}
+    else:
+        assert "fp8" not in decides
+    # `_limits_from` is written from these rows: per number the limit, the
+    # largest sound reading of either tree, the nearest control it decides
+    # and both distances
+    said = cfg["check"]["_limits_from"]
+    assert "%d runs on %d seeds" % (len(accepted), len(seeds)) in said
+    line = "%s %.4g: " % (number, limit)
+    assert line in said
+    text = said[said.index(line):].split(";")[0]
+    assert "%.4g" % max(accepted) in text and "%.4g" % max(variant) in text
+    if decides:
+        nearest = min(decides, key=lambda c: min(of(c)))
+        assert "%s %.4g" % (nearest, min(of(nearest))) in text
+        assert "%.2f times under" % (min(of(nearest)) / limit) in text
+    assert "%.2f times over" % (limit / max(accepted + variant)) in text
+
+
 def test_the_cell_is_declared_and_its_readers_list_it(spec, cfg):
     entry = util.named(spec["configs"], CONFIG)
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
@@ -420,17 +477,20 @@ def test_the_cell_is_declared_and_its_readers_list_it(spec, cfg):
     # its own four readers are declared for it alone, wherever they stand
     for name in READERS:
         assert util.named(spec["per_layer"], name)["workloads"] == [CELL], name
-    # no accepted entry that carries a list was edited to take the cell
-    # (PERF.md section 7 asks the next benchmark PR for that)
-    for m in spec["per_layer"]:
-        if m["name"] not in READERS:
-            assert CELL not in m.get("workloads", ()), m["name"]
+    # since PR 42 the cell stands on the 25 accepted lists whose readers find
+    # something to read in it (PERF.md section 7 row 31): the expert cells'
+    # twenty, PR 37's four, and `attention_ms_per_step`, which reads its
+    # `_contrib_DotProductAttention` nodes as `bd_attention_ms_per_step` does
+    listed = {m["name"] for m in spec["per_layer"]
+              if CELL in m.get("workloads", ())}
+    assert set(READERS) | set(util.EXPERT_CELL_LISTS) \
+        | set(util.COST_LISTS) | {"attention_ms_per_step"} <= listed
     # ... and the cell reports the ones without a list
     unlisted = [m["name"] for m in spec["per_layer"] if "workloads" not in m]
     assert len(unlisted) == 9 and "model_flops_util_pct" in unlisted
     loaded = harness.Cell(CELL, 1, 1, 1, 0.0, util.REPO)
     assert {m["name"] for m in loaded.metric_names("per_layer")} \
-        == set(unlisted) | set(READERS)
+        == set(unlisted) | listed
 
 
 def test_the_declared_readers_are_read_through_the_harness(spec):
