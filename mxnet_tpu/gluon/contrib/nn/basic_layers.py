@@ -230,9 +230,16 @@ class GatedShortConv(HybridBlock):
 class GroupedQueryAttention(HybridBlock):
     """Causal self-attention with fewer key/value heads than query
     heads, RMS norm over each query and key head, and rotary positions;
-    no biases.  The key/value heads are repeated to the query heads in
-    front of ``contrib.DotProductAttention`` (the flash kernels take
-    equal head counts).
+    no biases.  The q and the k product each go through
+    ``contrib.HeadNormRotary``: the norm, the rotation and the move to
+    ``(batch, heads, seq, d)`` in one pass over the data each way (the
+    kernels ``mx_headrope_fwd`` and ``mx_headrope_bwd``) where the
+    program is lowered for the TPU at heads of whole 128-lane tiles and a
+    sequence of whole tiles of 256 on one device, and ``contrib.RMSNorm``
+    then ``contrib.RotaryEmbedding``'s arithmetic at every other input;
+    span ``mx.headrope.plan`` says which.  The key/value heads are repeated
+    to the query heads in front of ``contrib.DotProductAttention`` (the
+    flash kernels take equal head counts).
 
     With *diffusion_block* the attention is not causal: the sequence is a
     clean copy then a noised copy of half its length each, and the mask
@@ -281,19 +288,17 @@ class GroupedQueryAttention(HybridBlock):
                        k_weight=None, v_weight=None, out_weight=None,
                        q_gamma=None, k_gamma=None):
         def heads(w, n, gamma=None):
-            # (B, S, U) -> (B, S, n, d), normed over d, turned,
-            # -> (B, n, S, d)
+            # (B, S, U) -> (B, S, n * d) -> (B, n, S, d); q and k normed
+            # over d and turned on the way
             h = F.FullyConnected(x, w, no_bias=True, flatten=False,
                                  num_hidden=n * self._head_dim)
-            h = F.Reshape(h, shape=(0, 0, n, -1))
             if gamma is None:
-                return F.transpose(h, axes=(0, 2, 1, 3))
-            h = F.transpose(F.contrib.RMSNorm(h, gamma, eps=self._eps),
-                            axes=(0, 2, 1, 3))
-            if positions is None:
-                return F.contrib.RotaryEmbedding(h, theta=self._theta)
-            return F.contrib.RotaryEmbedding(h, positions, theta=self._theta,
-                                             use_positions=True)
+                return F.transpose(F.Reshape(h, shape=(0, 0, n, -1)),
+                                   axes=(0, 2, 1, 3))
+            where = () if positions is None else (positions,)
+            return F.contrib.HeadNormRotary(
+                h, gamma, *where, num_heads=n, theta=self._theta,
+                eps=self._eps, use_positions=bool(where))
 
         from .... import symbol
         with symbol.AttrScope(**self._group):

@@ -19,8 +19,10 @@ between its two projections) is a pair of Mosaic kernels of this module,
 for the TPU and the shape tiles (`_shortconv_plan`: one device, ``d`` a
 multiple of 128, the sequence in whole tiles), and `_gate_body`, the
 same arithmetic in `jax.numpy`, on every other platform and at every other
-shape.  The sparse attention block's per-head norm of q and k, their
-rotary positions and their move to the head-major layout are
+shape.  The per-head norm of q and k, their rotary positions and their
+move to the head-major layout (`_norm_turn_by_head`: the sparse attention
+block's, and the operator ``_contrib_HeadNormRotary`` that gluon.contrib.nn
+``GroupedQueryAttention`` puts behind its q and k products) are
 `_head_norm_rotary`, the Mosaic pair ``mx_headrope_fwd`` and
 ``mx_headrope_bwd``, on the same terms (`_headrope_plan`: one device, heads
 of whole 128-lane tiles, the sequence in whole tiles), and `_rotary` over
@@ -31,11 +33,9 @@ The latent attention block's core is `ops/attention.py` `flash_attention`
 at two head widths (the Mosaic kernels on the TPU, the chunked scan
 elsewhere); its projections and its assembly of q and k are XLA's.
 
-Grouped-query attention has no operator: the key/value heads are
-repeated to the query heads (``repeat``) in front of
-``_contrib_DotProductAttention``, after ``_contrib_RMSNorm`` over each
-head and ``_contrib_RotaryEmbedding`` (gluon.contrib.nn
-``GroupedQueryAttention``).
+Grouped-query attention has no operator of its own: behind
+``_contrib_HeadNormRotary`` the key/value heads are repeated to the query
+heads (``repeat``) in front of ``_contrib_DotProductAttention``.
 """
 
 from __future__ import annotations
@@ -364,17 +364,9 @@ def _sparse_attention(data, q_weight, k_weight, v_weight, out_weight,
         # (B, S, n * hd) -> (B, n, S, hd), normed over hd and turned
         if gamma is None:
             return y.reshape(batch, seq, n, head_dim).transpose(0, 2, 1, 3)
-        plan, why = _headrope_plan(y, n, pos, mrope_section)
-        if plan and not tables:
-            # once an op: q and k turn by the same angles
-            tables.extend(jax.lax.stop_gradient(t) for t in _rotary_tables(
-                seq, head_dim, rope_theta, pos, mrope_section))
-        _record_headrope_plan(y, n, plan, why, tables)
-        if plan:
-            return _head_norm_rotary(y, gamma, *tables, n, float(eps))
-        return _rotary(_rms_norm(y.reshape(batch, seq, n, head_dim), gamma,
-                                 eps).transpose(0, 2, 1, 3),
-                       float(rope_theta), False, pos, mrope_section)
+        # one pair of tables an op: q and k turn by the same angles
+        return _norm_turn_by_head(y, n, gamma, pos, mrope_section,
+                                  rope_theta, eps, tables)
 
     with jax.named_scope("mx.dsa"):
         with jax.named_scope("mx.dsa.project"):
@@ -992,7 +984,7 @@ def _headrope_fwd_pallas(y, gamma, cos, sin, heads, eps, rows, at_once,
                          interpret=False):
     grid, d, (flat, by_head, scale, table) = _headrope_specs(
         y, cos, heads, rows, at_once)
-    with jax.named_scope("mx.dsa.project.headrope"):
+    with jax.named_scope("mx.headrope"):
         return pl.pallas_call(
             functools.partial(_headrope_fwd_kernel, d=d, eps=eps),
             grid=grid, in_specs=[flat, scale, table, table],
@@ -1010,7 +1002,7 @@ def _headrope_bwd_pallas(y, gamma, cos, sin, dout, heads, eps, rows, at_once,
                          interpret=False):
     grid, d, (flat, by_head, scale, table) = _headrope_specs(
         y, cos, heads, rows, at_once)
-    with jax.named_scope("mx.dsa.project.headrope"):
+    with jax.named_scope("mx.headrope"):
         dy, dgamma = pl.pallas_call(
             functools.partial(_headrope_bwd_kernel, d=d, eps=eps),
             grid=grid, in_specs=[flat, scale, table, table, by_head],
@@ -1074,6 +1066,46 @@ _head_norm_rotary.defvjp(
         _headrope_forward(y, gamma, cos, sin, heads, eps),
         (y, gamma, cos, sin)),
     _headrope_backward)
+
+
+def _norm_turn_by_head(y, heads, gamma, positions, sections, theta, eps,
+                       tables=None):
+    """A projection's output ``(batch, seq, heads x d)`` to ``(batch,
+    heads, seq, d)``, each head normed by *gamma* and turned by *positions*
+    (``(axes, batch, seq)`` dealt by *sections*; a text's where None): where
+    `_headrope_plan` gives tiles `_head_norm_rotary`, at every other input
+    `_rotary` over `_rms_norm`; `mx.headrope.plan` says which, and why.  A
+    caller that turns several projections by the same angles (an op's q and
+    k) hands each call the same list *tables*: the first that takes the
+    kernels builds the pair into it."""
+    batch, seq, _ = y.shape
+    d = y.shape[-1] // heads
+    tables = [] if tables is None else tables
+    plan, why = _headrope_plan(y, heads, positions, sections)
+    if plan and not tables:
+        tables.extend(jax.lax.stop_gradient(t) for t in _rotary_tables(
+            seq, d, theta, positions, sections))
+    _record_headrope_plan(y, heads, plan, why, tables)
+    if plan:
+        return _head_norm_rotary(y, gamma, *tables, heads, float(eps))
+    return _rotary(_rms_norm(y.reshape(batch, seq, heads, d), gamma,
+                             eps).transpose(0, 2, 1, 3),
+                   float(theta), False, positions, sections)
+
+
+@register_op("_contrib_HeadNormRotary", aliases=("HeadNormRotary",),
+             input_names=("data", "gamma", "positions"))
+def _head_norm_rotary_op(data, gamma, *positions, num_heads=1,
+                         theta=10000.0, eps=1e-5, use_positions=False):
+    """A q or k projection's output ``(batch, seq, num_heads x d)`` to the
+    attention's ``(batch, num_heads, seq, d)``: ``_contrib_RMSNorm`` over
+    each head by *gamma* ``(d,)``, then ``_contrib_RotaryEmbedding``
+    (rotate-half; *positions* ``(1, batch, seq)`` is an input with
+    ``use_positions``, and the positions are ``0 .. seq - 1`` without), as
+    `_norm_turn_by_head` computes them."""
+    return _norm_turn_by_head(data, int(num_heads), gamma,
+                              positions[0] if positions else None, (),
+                              theta, eps)
 
 
 # ---------------------------------------------------------------------------
@@ -1507,6 +1539,8 @@ profiler.register_step_stat("moe_expert_counts", _fold_expert_counts)
 from .registry import get_op as _get_op  # noqa: E402
 
 _get_op("_contrib_RotaryEmbedding").active_inputs = _with_positions("data")
+_get_op("_contrib_HeadNormRotary").active_inputs = _with_positions(
+    "data", "gamma")
 _get_op("_contrib_SparseAttention").active_inputs = _with_positions(
     "data", "q_weight", "k_weight", "v_weight", "out_weight", "q_gamma",
     "k_gamma", "index_q_weight", "index_k_weight", "index_w_weight")

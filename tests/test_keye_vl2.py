@@ -866,5 +866,5 @@ def test_the_compiled_layer_writes_no_float32_array_of_q_s_size(
     for kernel, calls in (("mx_headrope_fwd", 2), ("mx_headrope_bwd", 2)):
         found = re.findall(
             r'custom-call\(.*op_name="[^"]*/mx\.dsa\.project/[^"]*/'
-            r'mx\.dsa\.project\.headrope/%s/' % kernel, text)
+            r'mx\.headrope/%s/' % kernel, text)
         assert len(found) == calls, (kernel, len(found))

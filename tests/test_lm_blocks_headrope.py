@@ -2,7 +2,9 @@
 turned heads in head-major layout, the Mosaic pair `mx_headrope_fwd` and
 `mx_headrope_bwd` (interpreted here) against what the sparse attention
 operator ran before it, `_rotary(_rms_norm(y by head, gamma).transpose(0, 2,
-1, 3))`, and the path `_contrib_SparseAttention` chooses for an input."""
+1, 3))`, the path `_contrib_SparseAttention` chooses for an input, and the
+operator `_contrib_HeadNormRotary` as `GroupedQueryAttention` reaches the
+same pair."""
 
 import functools
 
@@ -61,6 +63,20 @@ def interpreted(monkeypatch):
     monkeypatch.setattr(lm_blocks, "HEADROPE_TILES",
                         {"fwd": 32, "bwd": 16, "heads": 8})
     monkeypatch.setattr(jax.lax, "platform_dependent", choose)
+    # an operator traced before is not traced again, and one traced here
+    # must not serve a later test
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def plans_since(since):
+    return [s.args for s in profiler.spans()
+            if s.name == "mx.headrope.plan" and s.id > since]
+
+
+def last_span():
+    return max([s.id for s in profiler.spans()] or [0])
 
 
 def close(got, want, name):
@@ -123,10 +139,9 @@ def test_the_gradients_reach_the_weights_through_the_operator(
             return jax.jit(jax.value_and_grad(
                 objective, argnums=(0, 1), has_aux=True))(x, weights)
 
-    since = max([s.id for s in profiler.spans()] or [0])
+    since = last_span()
     (_, (out, term)), (dx, dw) = run()
-    plans = [s.args for s in profiler.spans()
-             if s.name == "mx.headrope.plan" and s.id > since]
+    plans = plans_since(since)
     assert [(p["path"], p["heads"]) for p in plans] == [("kernel", 32),
                                                         ("kernel", 4)]
     monkeypatch.undo()
@@ -200,11 +215,10 @@ def test_the_path_is_chosen_from_the_input(case):
         get_op("_contrib_SparseAttention").fn, num_heads=32, num_kv_heads=4,
         index_heads=16, topk=2048, rope_theta=THETA,
         mrope_section=(d // 8, 3 * d // 16, 3 * d // 16))
-    since = max([s.id for s in profiler.spans()] or [0])
+    since = last_span()
     with mesh_mod.use_mesh(Mesh(np.array(jax.devices()[:devices]), ("dp",))):
         jax.eval_shape(op, *avals)
-    plans = [s.args for s in profiler.spans()
-             if s.name == "mx.headrope.plan" and s.id > since]
+    plans = plans_since(since)
     assert [p["heads"] for p in plans] == [32, 4]
     tiles = lm_blocks.HEADROPE_TILES
     for p in plans:
@@ -226,11 +240,12 @@ def test_the_path_is_chosen_from_the_input(case):
 
 def test_the_pair_lowers_for_the_tpu_under_the_projection_s_scope():
     """Lowered for the TPU from this CPU host at the cell's two widths: one
-    Mosaic call each way a width, named, each under the scope
-    `mx.dsa.project.headrope` where the caller's `mx.dsa.project` calls it
-    (the compiled program joins the two: `tests/test_keye_vl2.py` reads it
-    there), and nothing of the activations' size kept for the backward pass
-    but the projection itself and the tables."""
+    Mosaic call each way a width, named, each under the pair's own scope
+    `mx.headrope`, nested in whatever scope the caller stands in (here the
+    sparse block's `mx.dsa.project`; the compiled program joins the two:
+    `tests/test_keye_vl2.py` reads it there), and nothing of the
+    activations' size kept for the backward pass but the projection itself
+    and the tables."""
     bf = jnp.bfloat16
     cos, sin = lm_blocks._rotary_tables(16384, D, THETA, None, SECTIONS)
 
@@ -248,8 +263,7 @@ def test_the_pair_lowers_for_the_tpu_under_the_projection_s_scope():
                 debug_info=True)
         assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
         for way in ("fwd", "bwd"):
-            assert '"mx.dsa.project.headrope/mx_headrope_%s/pallas_call"' \
-                % way in text
+            assert '"mx.headrope/mx_headrope_%s/pallas_call"' % way in text
             assert "mx.dsa.project))/cond/branch_0_fun/jit(_headrope_%s_" \
                 "pallas)" % way in text.replace("jvp(mx.dsa.project)/",
                                                 "jvp(mx.dsa.project))/")
@@ -259,3 +273,188 @@ def test_the_pair_lowers_for_the_tpu_under_the_projection_s_scope():
         assert sorted(big, key=str) == sorted(
             [((1, 16384, heads * D), bf), ((1, 16384, D), jnp.float32),
              ((1, 16384, D), jnp.float32)], key=str), big
+
+
+# ---------------------------------------------------------------------------
+# `_contrib_HeadNormRotary`, and `GroupedQueryAttention` over it.
+# ---------------------------------------------------------------------------
+
+def written_out(F, x, positions, weights, heads, kv_heads, d, theta, eps,
+                mask):
+    """`GroupedQueryAttention` as it was before the operator: `RMSNorm`
+    over each head of the reshaped product, `transpose`, then
+    `RotaryEmbedding`."""
+    def by_head(w, n, gamma=None):
+        h = F.FullyConnected(x, w, no_bias=True, flatten=False,
+                             num_hidden=n * d)
+        h = F.Reshape(h, shape=(0, 0, n, -1))
+        if gamma is None:
+            return F.transpose(h, axes=(0, 2, 1, 3))
+        h = F.transpose(F.contrib.RMSNorm(h, gamma, eps=eps),
+                        axes=(0, 2, 1, 3))
+        if positions is None:
+            return F.contrib.RotaryEmbedding(h, theta=theta)
+        return F.contrib.RotaryEmbedding(h, positions, theta=theta,
+                                         use_positions=True)
+
+    wq, wk, wv, wo, q_gamma, k_gamma = weights
+    q, k, v = by_head(wq, heads, q_gamma), by_head(wk, kv_heads, k_gamma), \
+        by_head(wv, kv_heads)
+    if heads // kv_heads > 1:
+        k = F.repeat(k, repeats=heads // kv_heads, axis=1)
+        v = F.repeat(v, repeats=heads // kv_heads, axis=1)
+    att = F.contrib.DotProductAttention(q, k, v, sm_scale=d ** -0.5, **mask)
+    att = F.Reshape(F.transpose(att, axes=(0, 2, 1, 3)), shape=(0, 0, -1))
+    return F.FullyConnected(att, wo, no_bias=True, flatten=False,
+                            num_hidden=x.shape[-1])
+
+
+def block_and_composition(d, seq, dtype, positions, diffusion_block):
+    """``(value, every parameter's gradient)`` of the block and of
+    `written_out` on the same weights, input and cotangent; 4 query heads
+    over 2 key/value heads of *d*."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import autograd
+    from mxnet_tpu.gluon.contrib.nn import GroupedQueryAttention
+    units, heads, kv_heads, theta, eps = 64, 4, 2, 1e6, 1e-6
+    rng = np.random.default_rng(7)
+    block = GroupedQueryAttention(units, heads, kv_heads, d, theta, eps,
+                                  diffusion_block=diffusion_block)
+    block.initialize()
+    block.cast(dtype)
+    shapes = [(heads * d, units), (kv_heads * d, units),
+              (kv_heads * d, units), (units, heads * d), (d,), (d,)]
+    values = [mx.nd.array(1 + 0.3 * rng.normal(size=s) if len(s) == 1
+                          else 0.2 * rng.normal(size=s), dtype=dtype)
+              for s in shapes]
+    for param, value in zip(block.collect_params().values(), values):
+        param.set_data(value)
+    x = mx.nd.array(rng.normal(size=(2, seq, units)), dtype=dtype)
+    dout = mx.nd.array(rng.normal(size=(2, seq, units)), dtype=dtype)
+    pos = None if positions == "counted" else mx.nd.array(
+        np.broadcast_to(np.arange(seq) % (seq // 2), (1, 2, seq)),
+        dtype="int32")
+    with autograd.record():
+        out = block(x) if pos is None else block(x, pos)
+    out.backward(dout)
+    got = [out] + [p.grad() for p in block.collect_params().values()]
+    weights = [v.copy() for v in values]
+    for w in weights:
+        w.attach_grad()
+    mask = {"mask": "block_diffusion", "mask_block": diffusion_block} \
+        if diffusion_block else {"causal": True}
+    with autograd.record():
+        want = written_out(mx.nd, x, pos, weights, heads, kv_heads, d,
+                           theta, eps, mask)
+    want.backward(dout)
+    return ([a.asnumpy().astype(np.float32) for a in got],
+            [a.asnumpy().astype(np.float32)
+             for a in [want] + [w.grad for w in weights]])
+
+
+LEAVES = ("value", "query_weight", "key_weight", "value_weight",
+          "out_weight", "query_norm_gamma", "key_norm_gamma")
+
+LAYER_SHAPES = {
+    # (head width, sequence) -> what the plan says
+    "heads-of-128": ((128, 256), "kernel", None),
+    "heads-of-64": ((64, 256), "xla", "a head of 64"),
+    "heads-of-16": ((16, 256), "xla", "a head of 16"),
+    "a-sequence-of-200": ((128, 200), "xla", "a sequence of 200"),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("diffusion_block", [None, 4])
+@pytest.mark.parametrize("positions", ["counted", "an-input"])
+@pytest.mark.parametrize("shape", sorted(LAYER_SHAPES))
+def test_grouped_query_attention_is_the_written_out_composition_to_the_bit(
+        shape, positions, diffusion_block, dtype):
+    """On the CPU, where the pair is `_headrope_body` at a shape the plan
+    takes and `_rotary` over `_rms_norm` at one it refuses: the block's
+    value and the gradient of each of its six parameters are the
+    composition's, bit for bit.  Primitive by primitive
+    (`jax.disable_jit`), since XLA's CPU backend fuses one operator's
+    arithmetic otherwise than four operators' and contracts other
+    multiply-adds."""
+    (d, seq), path, why = LAYER_SHAPES[shape]
+    since = last_span()
+    with jax.disable_jit():
+        got, want = block_and_composition(d, seq, dtype, positions,
+                                          diffusion_block)
+    for name, g, w in zip(LEAVES, got, want):
+        assert np.abs(w).max() > 0, name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+    plans = [p for p in plans_since(since) if p["dtype"] == dtype]
+    assert plans and {p["heads"] for p in plans} == {4, 2}
+    for p in plans:
+        assert p["path"] == path and p["head_dim"] == d
+        assert p["why"] is None if why is None else p["why"].startswith(why)
+
+
+@pytest.mark.parametrize("diffusion_block", [None, 4])
+@pytest.mark.parametrize("positions", ["counted", "an-input"])
+def test_grouped_query_attention_through_the_kernels(
+        interpreted, positions, diffusion_block):
+    """The same comparison with the two kernels in the block's path
+    (interpreted), float32 on both sides: the order of a few sums
+    differs."""
+    since = last_span()
+    with jax.default_matmul_precision("highest"):
+        got, want = block_and_composition(128, 64, "float32", positions,
+                                          diffusion_block)
+    assert {(p["path"], p["heads"], p["head_tile"])
+            for p in plans_since(since)} == {("kernel", 4, 4),
+                                             ("kernel", 2, 2)}
+    for name, g, w in zip(LEAVES, got, want):
+        close(g, w, name)
+
+
+@pytest.mark.parametrize("positions", ["counted", "an-input"])
+@pytest.mark.parametrize("d,seq", [(128, 256), (64, 48)])
+def test_the_operator_gives_one_value_through_nd_sym_and_a_hybridized_block(
+        d, seq, positions):
+    """`_contrib_HeadNormRotary` as `mx.nd.contrib.HeadNormRotary`, as a
+    symbol bound and run, and inside a hybridized block: one value, which
+    is `RMSNorm`, `transpose`, `RotaryEmbedding` composed."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon import HybridBlock
+    heads, theta, eps = 3, 1e6, 1e-6
+    rng = np.random.default_rng(11)
+    y = mx.nd.array(rng.normal(size=(2, seq, heads * d)))
+    gamma = mx.nd.array(1 + 0.3 * rng.normal(size=d))
+    pos = None if positions == "counted" else mx.nd.array(
+        rng.integers(0, 500, size=(1, 2, seq)), dtype="int32")
+    attrs = dict(num_heads=heads, theta=theta, eps=eps,
+                 use_positions=pos is not None)
+    inputs = [y, gamma] + ([] if pos is None else [pos])
+
+    through_nd = mx.nd.contrib.HeadNormRotary(*inputs, **attrs).asnumpy()
+    assert through_nd.shape == (2, heads, seq, d)
+
+    names = ["data", "gamma", "positions"][:len(inputs)]
+    sym = mx.sym.contrib.HeadNormRotary(*[mx.sym.var(n) for n in names],
+                                        **attrs)
+    assert sym.list_arguments() == names
+    assert sym.infer_shape(**{n: a.shape for n, a in zip(names, inputs)})[1] \
+        == [(2, heads, seq, d)]
+    through_sym = sym.bind(mx.cpu(), dict(zip(names, inputs))).forward()[
+        0].asnumpy()
+
+    class Turn(HybridBlock):
+        def hybrid_forward(self, F, *inputs):
+            return F.contrib.HeadNormRotary(*inputs, **attrs)
+
+    block = Turn()
+    block.hybridize()
+    through_block = block(*inputs).asnumpy()
+
+    np.testing.assert_array_equal(through_sym, through_nd)
+    np.testing.assert_array_equal(through_block, through_nd)
+    h = mx.nd.transpose(mx.nd.contrib.RMSNorm(
+        mx.nd.Reshape(y, shape=(0, 0, heads, -1)), gamma, eps=eps),
+        axes=(0, 2, 1, 3))
+    want = mx.nd.contrib.RotaryEmbedding(
+        h, *inputs[2:], theta=theta, use_positions=pos is not None)
+    np.testing.assert_allclose(through_nd, want.asnumpy(), rtol=1e-6,
+                               atol=1e-6)

@@ -380,6 +380,80 @@ def test_grouped_query_attention_without_a_mask_is_what_it_was():
     assert "_contrib_DotProductAttention" not in scoped
 
 
+LAYERS = {
+    # the cell's: 2 x 8192 positions as an input, 32 heads over 4 of 128
+    "sdar": (dict(units=2048, num_heads=32, num_kv_heads=4, head_dim=128,
+                  diffusion_block=4), 16384, True),
+    # LFM2's `full_attention`: 8192 positions counted, 32 heads over 8 of 64
+    "lfm2": (dict(units=2048, num_heads=32, num_kv_heads=8, head_dim=64),
+             8192, False),
+}
+
+
+@pytest.mark.parametrize("layer", sorted(LAYERS))
+def test_the_layer_lowered_for_the_tpu_holds_the_pair_where_the_plan_gives_it(
+        layer):
+    """One `GroupedQueryAttention` layer's forward and backward as the
+    executor evaluates its graph, in bf16, lowered for the TPU from this
+    CPU host.  At the cell's shape q's and k's `_contrib_HeadNormRotary`
+    nodes are the kernels `mx_headrope_fwd` and `mx_headrope_bwd`, one each
+    a node, under the group's scope `mx.bd.project` and the pair's own
+    `mx.headrope` inside it, and `mx.headrope.plan` says `kernel` for the
+    32 and the 4 heads.  At LFM2's 64-wide heads the plan says `xla` and
+    why, and the program holds neither kernel."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import executor
+    from mxnet_tpu.gluon.contrib.nn import GroupedQueryAttention
+    kwargs, seq, with_positions = LAYERS[layer]
+    block = GroupedQueryAttention(rope_theta=1e6, epsilon=1e-6, **kwargs)
+    inputs = [mx.sym.var("x")] + (
+        [mx.sym.var("pos")] if with_positions else [])
+    graph = executor._build_eval(block(*inputs), True)
+    bf = jnp.bfloat16
+    avals = {p.name: jax.ShapeDtypeStruct(p.shape, bf)
+             for p in block.collect_params().values()}
+    avals["x"] = jax.ShapeDtypeStruct((1, seq, kwargs["units"]), bf)
+
+    def step(args, positions, dout):
+        def objective(args):
+            out, = graph(dict(args, **positions), {}, None)[0]
+            return jnp.sum(out.astype(jnp.float32) * dout)
+        return jax.grad(objective)(args)
+
+    positions = {"pos": jax.ShapeDtypeStruct((1, 1, seq), jnp.int32)} \
+        if with_positions else {}
+    since = max([s.id for s in profiler.spans()] or [0])
+    text = jax.jit(step).trace(
+        avals, positions, jax.ShapeDtypeStruct(avals["x"].shape, jnp.float32)
+    ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    plans = [s.args for s in profiler.spans()
+             if s.name == "mx.headrope.plan" and s.id > since]
+    heads = [kwargs["num_heads"], kwargs["num_kv_heads"]]
+    assert [p["heads"] for p in plans] == heads
+    assert all(p["shape"] == [1, seq, p["heads"] * kwargs["head_dim"]]
+               and p["dtype"] == "bfloat16" for p in plans)
+    for kernel in ("mx_flash_fwd", "mx_flash_bwd"):
+        assert kernel in text, kernel
+    if layer == "lfm2":
+        assert all(p["path"] == "xla" and p["why"].startswith(
+            "a head of 64 is not whole 128-lane tiles") for p in plans)
+        assert "mx_headrope" not in text and "mx.headrope" not in text
+        return
+    assert all(p["path"] == "kernel" and p["why"] is None for p in plans)
+    assert [p["head_tile"] for p in plans] == heads
+    # one pair of tables a node, from the positions operand: cos and sin
+    # over 16384 positions of 128 in float32
+    assert all(p["table_bytes"] == 2 * seq * 128 * 4 for p in plans)
+    for way in ("fwd", "bwd"):
+        # the kernel under the pair's scope, called from q's node and from
+        # k's under the group's (the compiled program joins the two names)
+        assert '"mx.headrope/mx_headrope_%s/pallas_call"' % way in text
+        nodes = set(re.findall(
+            r'mx\.bd\.project/_contrib_HeadNormRotary:(\w+)\)+/cond/'
+            r'branch_0_fun/jit\(_headrope_%s_pallas\)"' % way, text))
+        assert len(nodes) == 2, (way, nodes)
+
+
 def test_a_decoder_layer_of_the_kind_needs_its_block_length():
     from mxnet_tpu.gluon.model_zoo.decoder import (OPERATOR_KINDS,
                                                    get_decoder_lm)

@@ -1,7 +1,12 @@
 """Per-head norm + rotary + head-major layout sweep on the real chip:
-numbers and device time of `_head_norm_rotary` (ops/lm_blocks.py) at the
-sparse-attention cell's two widths, q's 32 heads and k's 4 of 128 over
-16384 tokens.
+numbers and device time of `_head_norm_rotary` (ops/lm_blocks.py) at its
+two callers' shapes, each q's 32 heads and k's 4 of 128 over 16384
+positions: the sparse-attention cell's (``--caller keye``: a text's
+positions, three-axis sections, tables that are constants of the program)
+and the block-diffusion cell's (``--caller sdar``: `GroupedQueryAttention`
+through `_contrib_HeadNormRotary`, the positions of a clean and a noised
+copy as an operand, so the tables are computed on the device; their build
+is timed beside the kernels).
 
 One command: the two Mosaic kernels (`mx_headrope_fwd`, `mx_headrope_bwd`)
 at several tilings, each checked against `_headrope_body` (today's `_rotary`
@@ -10,7 +15,7 @@ timed; then that body itself, forward and backward alone, as XLA compiles
 it.  `HEADROPE_TILES` in ops/lm_blocks.py, and the table in PERF.md section
 6 (PR 34), come from it.
 
-    python tools/headrope_sweep.py [--default-only]
+    python tools/headrope_sweep.py [--default-only] [--caller keye|sdar]
 
 Timing is `tools/shortconv_sweep.py`'s: the device's busy time a call
 under the profiler.  Needs the chip to itself: one process per chip.
@@ -25,8 +30,12 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-#: the cell's ``(batch, seq)``, head width, rotary base, sections and eps
-BATCH, SEQ, D, THETA, SECTIONS, EPS = 1, 16384, 128, 1e7, (16, 24, 24), 1e-6
+#: both cells' ``(batch, seq)``, head width and eps
+BATCH, SEQ, D, EPS = 1, 16384, 128, 1e-6
+
+#: a caller's rotary base and sections, and whether its positions are an
+#: operand (then ``j mod seq / 2``: `_contrib_BlockDiffusionPositions`)
+CALLERS = {"keye": (1e7, (16, 24, 24), False), "sdar": (1e6, (), True)}
 
 #: (rows of a grid step, heads of a grid step)
 TILINGS = ((256, 8), (512, 1), (1024, 1), (2048, 1), (256, 4), (512, 4),
@@ -38,6 +47,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--default-only", action="store_true",
                     help="HEADROPE_TILES as they stand and no other tiling")
+    ap.add_argument("--caller", choices=sorted(CALLERS), default="keye")
     args = ap.parse_args(argv)
 
     import jax
@@ -52,7 +62,17 @@ def main(argv=None):
         sys.exit("headrope_sweep: no TPU: a device time comes only from "
                  "the chip")
     bf = jnp.bfloat16
-    cos, sin = lm_blocks._rotary_tables(SEQ, D, THETA, None, SECTIONS)
+    theta, sections, as_operand = CALLERS[args.caller]
+    if as_operand:
+        ids = jnp.zeros((BATCH, SEQ), jnp.int32)
+        positions = lm_blocks._block_diffusion_positions(ids)
+        tables = jax.jit(lambda pos: lm_blocks._rotary_tables(
+            SEQ, D, theta, pos, sections))
+        cos, sin = tables(positions)
+        print("caller %s: the tables from the operand %.3f ms, once a step"
+              % (args.caller, device_ms(tables, positions)), flush=True)
+    else:
+        cos, sin = lm_blocks._rotary_tables(SEQ, D, theta, None, sections)
 
     tiles = lm_blocks.HEADROPE_TILES
     for heads in (32, 4):
