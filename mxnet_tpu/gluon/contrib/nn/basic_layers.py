@@ -248,25 +248,55 @@ class GroupedQueryAttention(HybridBlock):
     ``positions`` ``(1, batch, seq)``, gives the rotary positions (both
     copies of a token stand at the same one); without it they are ``0 ..
     seq - 1``.  In a compiled graph the projections then lie under device
-    scope ``mx.bd.project`` and the attention under ``mx.bd.attention``."""
+    scope ``mx.bd.project`` and the attention under ``mx.bd.attention``.
+
+    With *window* a query sees that many keys, its own the last
+    (`ops/attention.py` `Window`: the flash kernels' loops skip the tiles the
+    window leaves dark on both sides).  With *gate* one more projection to
+    ``num_heads`` numbers a token goes through a sigmoid and multiplies each
+    head's output in front of the output projection (the headwise output
+    gate of arXiv:2505.06708).  *rope* is one layer kind's entry of a
+    published ``rope_parameters`` mapping (`ops/lm_blocks.py`
+    `rope_frequencies`: ``rope_theta``, ``partial_rotary_factor``, YaRN's
+    keys) in *rope_theta*'s place; a part of a head turned, or YaRN's
+    frequencies, keep the q and k passes on ``contrib.HeadNormRotary``'s
+    `jax.numpy` body.  Device scopes ``mx.swa.project``,
+    ``mx.swa.attention`` and ``mx.swa.out`` for a layer with a window,
+    ``mx.gqa.project``, ``mx.gqa.attention`` and ``mx.gqa.out`` for one
+    with a gate and none.  Without the three this is the block it was."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
                  rope_theta=10000.0, epsilon=1e-5, weight_initializer=None,
-                 diffusion_block=None, **kwargs):
+                 diffusion_block=None, window=None, gate=False, rope=None,
+                 **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("num_heads (%d) must be a multiple of "
                              "num_kv_heads (%d)" % (num_heads, num_kv_heads))
+        if window and diffusion_block:
+            raise ValueError("a window goes with a causal mask, not with "
+                             "the block-diffusion one")
         head_dim = head_dim or units // num_heads
         self._units = units
         self._heads, self._kv_heads = num_heads, num_kv_heads
         self._head_dim, self._theta = head_dim, float(rope_theta)
         self._eps = epsilon
+        self._rotary = {"theta": self._theta}
+        if rope:
+            from ....ops.lm_blocks import rope_frequencies
+            self._rotary = rope_frequencies(rope, head_dim)
         self._mask = {"causal": True} if not diffusion_block else {
             "mask": "block_diffusion", "mask_block": int(diffusion_block)}
-        # the projections' nodes as one named group of the compiled graph
-        self._group = {"__scope__": "mx.bd.project"} if diffusion_block \
-            else {}
+        if window:
+            self._mask["window"] = int(window)
+        # the block's nodes as named groups of the compiled graph
+        scope = "mx.bd" if diffusion_block else "mx.swa" if window \
+            else "mx.gqa" if gate else None
+        self._group = {"__scope__": scope + ".project"} if scope else {}
+        # (under the block-diffusion mask the op names its own scope)
+        self._after = {} if scope in (None, "mx.bd") else {
+            part: {"__scope__": "%s.%s" % (scope, part)}
+            for part in ("attention", "out")}
         with self.name_scope():
             def weight(name, rows, cols):
                 return self.params.get(name, shape=(rows, cols),
@@ -283,10 +313,12 @@ class GroupedQueryAttention(HybridBlock):
                 "query_norm_gamma", shape=(head_dim,), init="ones")
             self.k_gamma = self.params.get(
                 "key_norm_gamma", shape=(head_dim,), init="ones")
+            if gate:
+                self.gate_weight = weight("gate_weight", num_heads, units)
 
     def hybrid_forward(self, F, x, positions=None, q_weight=None,
                        k_weight=None, v_weight=None, out_weight=None,
-                       q_gamma=None, k_gamma=None):
+                       q_gamma=None, k_gamma=None, gate_weight=None):
         def heads(w, n, gamma=None):
             # (B, S, U) -> (B, S, n * d) -> (B, n, S, d); q and k normed
             # over d and turned on the way
@@ -297,8 +329,8 @@ class GroupedQueryAttention(HybridBlock):
                                    axes=(0, 2, 1, 3))
             where = () if positions is None else (positions,)
             return F.contrib.HeadNormRotary(
-                h, gamma, *where, num_heads=n, theta=self._theta,
-                eps=self._eps, use_positions=bool(where))
+                h, gamma, *where, num_heads=n, eps=self._eps,
+                use_positions=bool(where), **self._rotary)
 
         from .... import symbol
         with symbol.AttrScope(**self._group):
@@ -309,12 +341,21 @@ class GroupedQueryAttention(HybridBlock):
             if group > 1:
                 k = F.repeat(k, repeats=group, axis=1)
                 v = F.repeat(v, repeats=group, axis=1)
-        att = F.contrib.DotProductAttention(
-            q, k, v, sm_scale=self._head_dim ** -0.5, **self._mask)
-        att = F.Reshape(F.transpose(att, axes=(0, 2, 1, 3)),
-                        shape=(0, 0, -1))
-        return F.FullyConnected(att, out_weight, no_bias=True,
-                                flatten=False, num_hidden=self._units)
+            if gate_weight is not None:
+                # (B, S, H) -> (B, H, S, 1): one number a head and token
+                gate = F.expand_dims(F.transpose(F.sigmoid(F.FullyConnected(
+                    x, gate_weight, no_bias=True, flatten=False,
+                    num_hidden=self._heads)), axes=(0, 2, 1)), axis=3)
+        with symbol.AttrScope(**self._after.get("attention", {})):
+            att = F.contrib.DotProductAttention(
+                q, k, v, sm_scale=self._head_dim ** -0.5, **self._mask)
+        with symbol.AttrScope(**self._after.get("out", {})):
+            if gate_weight is not None:
+                att = F.broadcast_mul(att, gate)
+            att = F.Reshape(F.transpose(att, axes=(0, 2, 1, 3)),
+                            shape=(0, 0, -1))
+            return F.FullyConnected(att, out_weight, no_bias=True,
+                                    flatten=False, num_hidden=self._units)
 
 
 class SparseAttention(HybridBlock):

@@ -4,12 +4,17 @@ added to the residual:
 
     h = h + operator(rms(h));  h = h + feed_forward(rms(h))
 
-then a final RMS norm and a head.  The operator is one of five kinds
+then a final RMS norm and a head.  The operator is one of six kinds
 (`OPERATOR_KINDS`):
 
 - ``"conv"``: a gated short causal convolution;
 - ``"full_attention"``: grouped-query attention with per-head RMS norm
   and rotary positions;
+- ``"sliding_attention"``: the same block through a causal window of
+  ``sliding_window`` keys, the query's own among them (`ops/attention.py`
+  `Window`: the flash kernels skip the tiles the window leaves dark on both
+  sides); device scopes ``mx.swa.project``, ``mx.swa.attention``,
+  ``mx.swa.out``;
 - ``"latent_attention"``: multi-head latent attention, the expanded form:
   keys and values from one low-rank latent, one rotary key for all heads,
   keys wider than values (``kv_lora_rank``, ``qk_nope_head_dim``,
@@ -36,6 +41,18 @@ then a final RMS norm and a head.  The operator is one of five kinds
   label's second plane.  Device scopes ``mx.bd.project`` and
   ``mx.bd.attention``.
 
+What is a property of the layer and not of the net: ``heads`` may be a
+list, one count a layer (a model whose window layers have more query heads
+than its full ones); ``rope_parameters`` maps a layer kind to its rotary
+settings, shaped as the published key of that name (``{"full_attention":
+{"rope_type": "yarn", "rope_theta": ..., "factor": ...,
+"partial_rotary_factor": 0.5, ...}, "sliding_attention": {"rope_type":
+"default", "rope_theta": ...}}``, `ops/lm_blocks.py` `rope_frequencies`), a
+kind it does not name keeping ``rope_theta``; ``attention_gate`` gives the
+full_attention and sliding_attention layers a per-head sigmoid gate on the
+attention's output (then under device scopes ``mx.gqa.*`` and
+``mx.swa.*``).
+
 The feed-forward is a dense gated MLP in the first ``num_dense_layers``
 layers and dropless top-k routed experts in the others, to which
 ``shared_hidden`` > 0 adds shared experts: one gated MLP of that width
@@ -51,10 +68,13 @@ and of the ``deepseek_v3`` family (latent attention, shared experts, an
 untied head), and of Kwai-Keye's ``KeyeVL2`` language model (sparse
 attention, a softmax router, three-axis rotary positions), and of JetLM's
 ``sdar_moe`` models (block_diffusion_attention layers, a softmax router, an
-untied head), whose published ``config.json`` keys the arguments follow;
+untied head), and of poolside's ``laguna`` models (sliding_attention and
+full_attention layers mixed, head counts by layer, an output gate, rotary
+settings by layer kind, a sigmoid router with a shared expert), whose
+published ``config.json`` keys the arguments follow;
 `benchmarks/models/lfm2_moe.py`, `benchmarks/models/deepseek_v3.py`,
-`benchmarks/models/keye_vl2.py` and `benchmarks/models/sdar_moe.py` build one
-from such a file.
+`benchmarks/models/keye_vl2.py`, `benchmarks/models/sdar_moe.py` and
+`benchmarks/models/laguna.py` build one from such a file.
 
 The routed layers hold ONE CHIP'S SHARE of their experts
 (`gluon.contrib.nn.RoutedExperts`): ``experts_held`` of ``num_experts``
@@ -80,7 +100,8 @@ __all__ = ["AlignedLoss", "BlockDiffusionLoss", "DecoderLayer", "DecoderLM",
            "get_decoder_lm", "OPERATOR_KINDS"]
 
 OPERATOR_KINDS = ("conv", "full_attention", "latent_attention",
-                  "sparse_attention", "block_diffusion_attention")
+                  "sparse_attention", "block_diffusion_attention",
+                  "sliding_attention")
 
 
 class SharedAndRouted(HybridBlock):
@@ -100,21 +121,24 @@ class SharedAndRouted(HybridBlock):
 
 class DecoderLayer(HybridBlock):
     """One pre-norm layer: *operator* is built by kind (*latent* holds
-    `LatentAttention`'s own widths, *sparse* `SparseAttention`'s),
-    *feed_forward* is handed in.  A ``positions`` input goes to a
+    `LatentAttention`'s own widths, *sparse* `SparseAttention`'s, *grouped*
+    what `GroupedQueryAttention` takes beside its head counts: ``window``,
+    ``gate``, ``rope``), *feed_forward* is handed in.  A ``positions`` input goes to a
     sparse_attention or block_diffusion_attention operator and to no other;
     a sparse_attention layer returns ``(output, alignment term)``."""
 
     def __init__(self, dim, kind, feed_forward, heads, kv_heads, head_dim,
                  rope_theta, conv_kernel, eps, init, latent=None,
-                 sparse=None, diffusion_block=None, **kwargs):
+                 sparse=None, diffusion_block=None, grouped=None, **kwargs):
         super().__init__(**kwargs)
         if kind not in OPERATOR_KINDS:
             raise ValueError(
                 "layer kind %r is not one of %s (a gated short convolution, "
                 "grouped-query attention, multi-head latent attention, "
                 "learned sparse attention, grouped-query attention under "
-                "the block-diffusion mask)" % (kind, OPERATOR_KINDS))
+                "the block-diffusion mask, grouped-query attention through "
+                "a causal window)" % (kind, OPERATOR_KINDS))
+        grouped = dict(grouped or {})
         self._takes_positions = kind in ("sparse_attention",
                                          "block_diffusion_attention")
         self._returns_term = kind == "sparse_attention"
@@ -125,10 +149,13 @@ class DecoderLayer(HybridBlock):
                 self.operator = GatedShortConv(
                     dim, conv_kernel, weight_initializer=init,
                     prefix="conv_")
-            elif kind == "full_attention":
+            elif kind in ("full_attention", "sliding_attention"):
+                if kind == "sliding_attention" and not grouped.get("window"):
+                    raise ValueError("a sliding_attention layer needs "
+                                     "sliding_window")
                 self.operator = GroupedQueryAttention(
                     dim, heads, kv_heads, head_dim, rope_theta, eps,
-                    weight_initializer=init, prefix="attn_")
+                    weight_initializer=init, prefix="attn_", **grouped)
             elif kind == "block_diffusion_attention":
                 if not diffusion_block:
                     raise ValueError("a block_diffusion_attention layer "
@@ -182,7 +209,11 @@ class DecoderLM(HybridBlock):
     block_diffusion_attention layers (blocks of *diffusion_block*) the input
     is ``(batch, 2 * seq)``, a clean copy then a noised copy, the positions
     are ``j mod seq`` where none are given, and the logits are the noised
-    half's: ``(batch, seq, vocab)``."""
+    half's: ``(batch, seq, vocab)``.  *heads* is one count or a list of one
+    a layer; *rope_parameters* maps a layer kind to its rotary settings (a
+    published ``rope_parameters``); *sliding_window* is the
+    sliding_attention layers' window and *attention_gate* the per-head
+    output gate of those and of the full_attention layers."""
 
     def __init__(self, vocab, dim, layer_types, num_dense_layers,
                  dense_hidden, expert_hidden, num_experts,
@@ -196,9 +227,17 @@ class DecoderLM(HybridBlock):
                  rope_interleave=True, scoring_func="sigmoid",
                  index_heads=None, index_head_dim=None, index_topk=None,
                  mrope_section=(), alignment_weight=1.0,
-                 diffusion_block=None, **kwargs):
+                 diffusion_block=None, rope_parameters=None,
+                 sliding_window=None, attention_gate=False, **kwargs):
         super().__init__(**kwargs)
         self._vocab, self._dim = vocab, dim
+        layer_types = list(layer_types)
+        if isinstance(heads, int):
+            heads = [heads] * len(layer_types)
+        if len(heads) != len(layer_types):
+            raise ValueError("%d head counts for %d layers"
+                             % (len(heads), len(layer_types)))
+        rope_parameters = dict(rope_parameters or {})
         self._two_copies = "block_diffusion_attention" in layer_types
         init = weight_initializer
         latent = kv_lora_rank and {
@@ -237,11 +276,17 @@ class DecoderLM(HybridBlock):
                 "embed_weight", shape=(vocab, dim), init=init)
             self.layers = []
             for i, kind in enumerate(layer_types):
+                grouped = None
+                if kind in ("full_attention", "sliding_attention"):
+                    grouped = {"gate": bool(attention_gate),
+                               "rope": rope_parameters.get(kind)}
+                    if kind == "sliding_attention":
+                        grouped["window"] = sliding_window
                 layer = DecoderLayer(
                     dim, kind, dense if i < num_dense_layers else sparse,
-                    heads, kv_heads or heads, head_dim, rope_theta,
+                    heads[i], kv_heads or heads[i], head_dim, rope_theta,
                     conv_kernel, eps, init, latent, indexer,
-                    diffusion_block, prefix="l%d_" % i)
+                    diffusion_block, grouped, prefix="l%d_" % i)
                 setattr(self, "l%d" % i, layer)
                 self.layers.append(layer)
             self.final_norm = nn.RMSNorm(dim, eps, prefix="final_norm_")

@@ -40,6 +40,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -48,7 +49,7 @@ from ._precision import matmul_precision
 from .registry import register_op
 
 __all__ = ["flash_attention", "attention_reference", "BlockDiffusion",
-           "block_diffusion_visible"]
+           "Window", "block_diffusion_visible"]
 
 _NEG_INF = -1e30
 # Inside a kernel the per-row softmax state (running max, denominator)
@@ -92,12 +93,28 @@ _UNROLL_WHOLE = 8
 #: Every query sees a key; ``half * (half + block)`` pairs are visible.
 BlockDiffusion = collections.namedtuple("BlockDiffusion", "block half")
 
+#: a causal window, a static description that goes WITH ``causal``: query
+#: ``t`` sees the *keys* keys that end at its own position, ``t - keys < s
+#: <= t`` (sequence ends aligned, as `causal` alone has them).  A row of
+#: ``keys`` or more positions sees ``keys`` pairs, an earlier one all it
+#: has: ``keys * S - keys * (keys - 1) / 2`` pairs over ``S`` positions.
+Window = collections.namedtuple("Window", "keys")
+
 
 def _checked_mask(mask, causal, sq, sk):
-    """*mask* as a `BlockDiffusion` of ints, or None; raises where it does
-    not describe these sequences."""
+    """*mask* as a `BlockDiffusion` or a `Window` of ints, or None (no mask
+    beside `causal`, which a window that leaves every causal key visible
+    is); raises where it does not describe these sequences."""
     if mask is None:
         return None
+    if isinstance(mask, Window):
+        keys = int(mask.keys)
+        if not causal or keys < 1:
+            raise ValueError(
+                "a window bounds a causal query's keys from below: pass "
+                "causal=True and at least one key (got causal=%r, %d keys)"
+                % (bool(causal), keys))
+        return None if keys >= sk else Window(keys)
     mask = BlockDiffusion(int(mask[0]), int(mask[1]))
     if causal:
         raise ValueError("a block-diffusion mask is not causal: pass one "
@@ -122,9 +139,25 @@ def block_diffusion_visible(q_pos, k_pos, mask):
         | (kn & qn & (kb == qb))
 
 
+def window_visible(q_pos, k_pos, keys):
+    """Whether causal query position *q_pos* sees key position *k_pos*
+    through a window of *keys* keys, from its definition; the two
+    broadcast."""
+    return (k_pos <= q_pos) & (k_pos > q_pos - keys)
+
+
+def window_pairs(sq, sk, keys):
+    """Visible query-key pairs a head of *sq* causal queries over *sk*
+    keys (ends aligned) through a window of *keys* keys."""
+    pos = np.arange(sq, dtype=np.int64) + (sk - sq)
+    return int(np.maximum(
+        np.minimum(pos, sk - 1) - np.maximum(pos - keys + 1, 0) + 1, 0).sum())
+
+
 def attention_reference(q, k, v, causal=False, sm_scale=None, mask=None):
     """O(S^2)-memory einsum attention — the numeric oracle for tests.
-    *mask* is a `BlockDiffusion` (every row then sees a key).
+    *mask* is a `BlockDiffusion` (every row then sees a key), or with
+    `causal` a `Window`.
 
     Degenerate-row convention (shared by all paths in this module): a
     causal query row that can see NO keys (seq_q > seq_k under the
@@ -139,7 +172,14 @@ def attention_reference(q, k, v, causal=False, sm_scale=None, mask=None):
                    precision=matmul_precision(q.dtype, k.dtype)) \
         * sm_scale
     mask = _checked_mask(mask, causal, s.shape[-2], s.shape[-1])
-    if mask is not None:
+    if isinstance(mask, Window):
+        qlen, klen = s.shape[-2], s.shape[-1]
+        seen = window_visible(
+            jnp.arange(qlen)[:, None] + (klen - qlen),
+            jnp.arange(klen)[None, :], mask.keys)
+        p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
+        p = p * seen.any(-1)[:, None]  # zero fully-masked rows
+    elif mask is not None:
         seen = block_diffusion_visible(jnp.arange(s.shape[-2])[:, None],
                                        jnp.arange(s.shape[-1])[None, :], mask)
         p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
@@ -194,7 +234,7 @@ def _finalize_softmax(o, m, l):
 def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512,
                        mask=None):
     """Blockwise attention with online softmax over K chunks; *mask* a
-    `BlockDiffusion` in `causal`'s place.
+    `BlockDiffusion` in `causal`'s place, or a `Window` beside it.
 
     Memory is O(S_q * chunk) instead of O(S_q * S_k); the scan body is
     rematerialized on backward (jax.checkpoint), which is exactly the
@@ -227,7 +267,11 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512,
                        preferred_element_type=jnp.float32) * sm_scale
         k_pos = ci * chunk + jnp.arange(chunk)
         valid = k_pos < sk
-        if mask is not None:
+        if isinstance(mask, Window):
+            valid = valid[None, :] & window_visible(
+                q_pos[:, None], k_pos[None, :], mask.keys)
+            s = jnp.where(valid[None, None], s, _NEG_INF)
+        elif mask is not None:
             valid = valid[None, :] & block_diffusion_visible(
                 q_pos[:, None], k_pos[None, :], mask)
             s = jnp.where(valid[None, None], s, _NEG_INF)
@@ -658,8 +702,70 @@ def _bd_q_blocks(ik, t, hv):
 
 
 def _halves_of(mask, sq_p, sk_p):
-    return None if mask is None else _Halves(mask.block, mask.half,
-                                             sq_p // 2, sk_p // 2)
+    return _Halves(mask.block, mask.half, sq_p // 2, sk_p // 2) \
+        if isinstance(mask, BlockDiffusion) else None
+
+
+def _parts(mask):
+    """Equal parts the sequence is padded in: a block-diffusion mask's two
+    copies, else one."""
+    return 2 if isinstance(mask, BlockDiffusion) else 1
+
+
+def _window_of(mask):
+    """The keys of a `Window`, or None."""
+    return mask.keys if isinstance(mask, Window) else None
+
+
+# Under a causal window of `w` keys a query's keys are bounded from below
+# as well: the forward's loop over key sub-tiles starts where the window
+# does and the backward's loop over query sub-tiles ends where the last
+# query that sees the key tile stands, so a tile with no visible pair is
+# not visited on either side.  The segments are `_run_segments`': the
+# tiles the window's lower edge crosses, those every pair of which is
+# visible, those the diagonal (or the padding) crosses.  A window narrower
+# than a tile's two edges together leaves no tile whole: one masked loop.
+
+def _window_k_segments(row0, k0, n, t, off, seq_k, w):
+    """The forward's loops for the query sub-tile whose first row is
+    *row0* over the *n* key sub-tiles of the resident block at column
+    *k0*, as ``(lo, hi, masked)``."""
+    n_full, n_vis = _k_tiles(row0, k0, n, t, off, seq_k, True)
+    lo = _imin(n_vis, _idiv(_imax(row0 + off - w + 1 - k0, 0), t.sub_k))
+    if w < t.sub_q + t.sub_k - 1:
+        return ((lo, n_vis, True),)
+    a = _imin(n_vis, _imax(lo, _idiv(
+        _imax(row0 + t.sub_q + off - w - k0, 0) + t.sub_k - 1, t.sub_k)))
+    b = _imax(n_full, a)
+    return ((lo, a, True), (a, b, None), (b, n_vis, True))
+
+
+def _window_q_segments(col0, q0, n, t, off, seq_k, w):
+    """The backward's loops for the key sub-tile whose first column is
+    *col0* over the *n* query sub-tiles of the resident block at row
+    *q0*."""
+    j_first, j_full = _q_tiles(col0, q0, n, t, off, seq_k, True)
+    # the last query that sees the tile's last key stands w - 1 after it
+    j_end = _imax(j_first, _imin(n, _idiv(
+        _imax(col0 + t.sub_k + w - 2 - off - q0 + t.sub_q, 0), t.sub_q)))
+    if w < t.sub_q + t.sub_k - 1:
+        return ((j_first, j_end, True),)
+    a = _imin(j_full, j_end)
+    b = _imax(a, _imin(j_end, _idiv(_imax(col0 + w - off - q0, 0), t.sub_q)))
+    return ((j_first, a, True), (a, b, None), (b, j_end, True))
+
+
+def _first_k_block(iq, t, off, w):
+    """The first resident key block a query block *iq* sees through a
+    window of *w* keys."""
+    return _idiv(_imax(iq * t.res_q + off - w + 1, 0), t.res_k)
+
+
+def _last_q_block(ik, t, nqr, off, w):
+    """The last resident query block that sees key block *ik* through a
+    window of *w* keys."""
+    return _imin(nqr - 1, _idiv(
+        _imax(ik * t.res_k + t.res_k + w - 2 - off, 0), t.res_q))
 
 
 def _last_k_block(iq, t, nkr, off):
@@ -683,20 +789,26 @@ def _tile_counts(kernel, plan, sq, sk, causal, mask=None):
     sq_p, sk_p = (plan.sq_fwd, plan.sk_fwd) if kernel == "fwd" \
         else (plan.sq_bwd, plan.sk_bwd)
     off = sk - sq
-    hv = _halves_of(mask, sq_p, sk_p)
+    hv, w = _halves_of(mask, sq_p, sk_p), _window_of(mask)
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
     visited = masked = 0
     for q0 in range(0, sq_p, t.res_q):
         for k0 in range(0, sk_p, t.res_k):
-            if hv is not None:
-                segments = [
-                    seg for j in range(nks if kernel == "bwd" else nqs)
-                    for seg in (
-                        _bd_q_segments(k0 + j * t.sub_k, q0, nqs, t, hv)
-                        if kernel == "bwd" else
-                        _bd_k_segments(q0 + j * t.sub_q, k0, nks, t, hv))]
-                visited += sum(hi - lo for lo, hi, _ in segments)
-                masked += sum(hi - lo for lo, hi, m in segments if m)
+            if hv is not None or w:
+                def of(j):
+                    if kernel == "bwd":
+                        at = (k0 + j * t.sub_k, q0, nqs, t)
+                        return _window_q_segments(*at, off, sk, w) if w \
+                            else _bd_q_segments(*at, hv)
+                    at = (q0 + j * t.sub_q, k0, nks, t)
+                    return _window_k_segments(*at, off, sk, w) if w \
+                        else _bd_k_segments(*at, hv)
+
+                segments = [seg for j in range(
+                    nks if kernel == "bwd" else nqs) for seg in of(j)]
+                visited += sum(max(hi - lo, 0) for lo, hi, _ in segments)
+                masked += sum(max(hi - lo, 0) for lo, hi, m in segments
+                              if m)
             elif kernel == "bwd":
                 for jk in range(nks):
                     first, full = _q_tiles(k0 + jk * t.sub_k, q0, nqs, t,
@@ -709,6 +821,8 @@ def _tile_counts(kernel, plan, sq, sk, causal, mask=None):
                                          off, sk, causal)
                     visited += vis
                     masked += vis - full
+    if w:
+        return _window_counts(t, sq, sk, w, visited, masked)
     if mask is not None:
         scores = mask.half * (mask.half + mask.block)
     elif causal:
@@ -721,13 +835,33 @@ def _tile_counts(kernel, plan, sq, sk, causal, mask=None):
             "tiles_ideal": round(scores / (t.sub_q * t.sub_k), 3)}
 
 
+def _window_counts(t, sq, sk, w, visited, masked):
+    """`_tile_counts`' record under a window of *w* keys: beside what the
+    loops visit, the tiles of the real sequence that hold a visible pair
+    (`tiles_needed`: a correct schedule visits those and no other), from
+    the window's definition a row of tiles at a time."""
+    off = sk - sq
+    needed = 0
+    for r0 in range(0, sq, t.sub_q):
+        lo = max(0, r0 + off - w + 1)
+        hi = min(min(r0 + t.sub_q, sq) - 1 + off, sk - 1)
+        if hi >= lo:
+            needed += hi // t.sub_k - lo // t.sub_k + 1
+    scores = window_pairs(sq, sk, w)
+    return {"tiles_visited": visited, "tiles_masked": masked,
+            "tiles_needed": needed,
+            "tiles_ideal": round(scores / (t.sub_q * t.sub_k), 3)}
+
+
 def _plan_args(plan, sq, sk, d, dtype, causal, d_v=None, mask=None):
     """The plan as the `mx.flash.plan` span carries it: static per shape,
     so recorded where the call is traced, not where it runs."""
     rec = {"sq": sq, "sk": sk, "d": d, "dtype": jnp.dtype(dtype).name,
            "causal": bool(causal), "d_block": plan.d_block,
            "d_v": d if d_v is None else d_v, "dv_block": plan.dv_block}
-    if mask is not None:
+    if isinstance(mask, Window):
+        rec.update(mask="window", window=mask.keys)
+    elif mask is not None:
         rec.update(mask="block_diffusion", block=mask.block, half=mask.half)
     itemsize = jnp.dtype(dtype).itemsize
     for kernel in _KERNELS:
@@ -747,13 +881,14 @@ def _record_plan(q, k, v, causal, selected=False, mask=None):
     a step.  With a selection operand (*selected*) the span says so: every
     visited tile is then computed under the mask, and the forward asks
     Mosaic for its own `vmem_limit_bytes` too.  Under a block-diffusion
-    *mask* it carries the mask's kind, block and half."""
+    *mask* it carries the mask's kind, block and half, under a `Window`
+    the kind, the window's keys and each kernel's `tiles_needed`."""
     from .. import profiler
     sq, sk, d, d_v = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     with profiler.scope(  # graftlint: disable=JG003
             "mx.flash.plan", "flash") as span:
         plan = _flash_plan(sq, sk, d, q.dtype, d_v=d_v,
-                           halves=1 if mask is None else 2)
+                           halves=_parts(mask))
         span.args = _plan_args(plan, sq, sk, d, q.dtype, causal, d_v, mask)
         if selected:
             itemsize = jnp.dtype(q.dtype).itemsize
@@ -809,17 +944,21 @@ def _sub(j, size, count):
     return pl.ds(pl.multiple_of(j * size, size), size)
 
 
-def _tile_mask(shape, q_axis, row0, col0, off, seq_k, causal, padded_k):
+def _tile_mask(shape, q_axis, row0, col0, off, seq_k, causal, padded_k,
+               window=None):
     """Visibility of the score tile whose first query row is *row0* and
     first key column *col0*; query rows run along *q_axis*.  Sequence
     ends aligned (decode-style cross-length causal), the convention of
-    attention_reference and _chunked_attention."""
+    attention_reference and _chunked_attention.  With *window* a causal
+    query sees that many keys, its own the last."""
     k_pos = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     mask = k_pos < seq_k if padded_k else None
     if causal:
         q_pos = row0 + off + jax.lax.broadcasted_iota(jnp.int32, shape,
                                                       q_axis)
         mask = k_pos <= q_pos if mask is None else mask & (k_pos <= q_pos)
+        if window:
+            mask = mask & (k_pos > q_pos - window)
     return mask
 
 
@@ -908,7 +1047,7 @@ def _run_segments(segments, tile):
             for lo2, hi2, m2 in group[1:]:
                 later = _below(end - 1, j)
                 at = _sel(later, lo2 + j - end, at)
-                if masked:
+                if isinstance(m, _BdMask):
                     m = _BdMask(_sel(later, m2.noised, m.noised),
                                 _sel(later, m2.extra, m.extra))
                 end = end + hi2 - lo2
@@ -931,9 +1070,12 @@ def _traced_inline(kernel):
 
 @_traced_inline
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
-                      seq_q, seq_k, padded_k, selected=False, hv=None):
+                      seq_q, seq_k, padded_k, selected=False, hv=None,
+                      window=None):
     # with *hv* (`_Halves`) the mask is the block-diffusion one: the loops'
     # bounds and the mask bodies come from its geometry, not from `causal`
+    # with *window* a causal query sees that many keys: the loop over key
+    # tiles starts where the window does (`_window_k_segments`)
     # with *selected* a selection block follows v: (1, res_q / 32, res_k)
     # words, bit r % 32 of word r // 32 saying whether query row r of the
     # block sees the key; every visited tile is then a masked one
@@ -971,7 +1113,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
             if masked:
                 col0 = ik * t.res_k + jk * t.sub_k
                 mask = _tile_mask(
-                    s.shape, 0, row0, col0, off, seq_k, causal, padded_k) \
+                    s.shape, 0, row0, col0, off, seq_k, causal, padded_k,
+                    window) \
                     if hv is None else _bd_tile_mask(
                         s.shape, 0, row0, col0, hv, masked)
                 if selected:
@@ -995,6 +1138,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
         if hv is not None:
             _run_segments(_bd_k_segments(row0, ik * t.res_k, nks, t, hv),
                           tile)
+        elif window:
+            _run_segments(_window_k_segments(
+                row0, ik * t.res_k, nks, t, off, seq_k, window), tile)
         else:
             n_full, n_vis = _k_tiles(row0, ik * t.res_k, nks, t, off, seq_k,
                                      causal)
@@ -1080,13 +1226,19 @@ def _unpad_rows(x, s, halves=1):
         ..., :s // halves].reshape(bh, 1, s)
 
 
-def _k_index(t, nkr, off, causal, hv=None):
+def _k_index(t, nkr, off, causal, hv=None, window=None):
     """Index map of the key-side blocks on a grid (bh, iq, ik).  Causal,
     a step above the diagonal is empty (its loops run no tile): its
     index is clamped to the last block the query block sees, so the
     empty step refetches nothing.  Under a block-diffusion mask (*hv*) a
     step past the query block's last clean block takes the index of the
-    nearest noised block it visits (a clean query block's: none)."""
+    nearest noised block it visits (a clean query block's: none).  Through
+    a *window* the blocks start where the window does: a step below it
+    takes the first block the query block sees."""
+    if window:
+        return lambda bh_, iq, ik: jnp.clip(
+            ik, _first_k_block(iq, t, off, window),
+            _last_k_block(iq, t, nkr, off))
     if hv is not None:
         def index(bh_, iq, ik):
             c_last, n_first, n_last = _bd_k_blocks(iq, t, hv)
@@ -1134,15 +1286,15 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
     (`pack_selection` of a ``(B, seq_q, seq_k)`` mask, one for all the
     heads of a batch row): a query then sees a key only where its bit is
     set, besides `causal` and the padding.  *mask* is a `BlockDiffusion`
-    in `causal`'s place."""
+    in `causal`'s place, or a `Window` beside it."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
-    halves = 1 if mask is None else 2
+    halves = _parts(mask)
     plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v,
                        halves)
     t, dp, dvp = plan.fwd, plan.d_block, plan.dv_block
     sq_p, sk_p = plan.sq_fwd, plan.sk_fwd
-    hv = _halves_of(mask, sq_p, sk_p)
+    hv, window = _halves_of(mask, sq_p, sk_p), _window_of(mask)
     qp = _pad_bh(q, sq_p, dp, halves)
     kp = _pad_bh(k, sk_p, dp, halves)
     vp = _pad_bh(v, sk_p, dvp, halves)
@@ -1151,10 +1303,11 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
 
     q_spec, k_spec, o_spec, v_spec, row_spec = _block_specs(
         t, dp, dvp, lambda bh_, iq, ik: iq,
-        _k_index(t, nkr, sk - sq, causal, hv))
+        _k_index(t, nkr, sk - sq, causal, hv, window))
     kernel = functools.partial(
         _flash_fwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
-        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk, hv=hv)
+        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk, hv=hv,
+        window=window)
     in_specs, operands, params = [q_spec, k_spec, v_spec], (qp, kp, vp), {}
     if sel is not None:
         k_index = _k_index(t, nkr, sk - sq, causal)
@@ -1235,13 +1388,14 @@ def _wide(x, width):
 @_traced_inline
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *rest, t, grid, sm_scale, causal, seq_q, seq_k,
-                      padded_k, selected=False, hv=None):
+                      padded_k, selected=False, hv=None, window=None):
     """K/V block resident, Q/dO sub-tiles from the first visible row on;
     *dq_acc* spans the head's sequence, and without it this step's share
     of dq accumulates in its f32 output block.  With *selected* a
     selection block follows delta, the scores' way round: (1, res_k / 32,
     res_q) words, bit c % 32 of word c // 32 saying whether the query sees
-    key row c of the block."""
+    key row c of the block.  With *window* the loop over query tiles ends
+    with the last query that sees the key tile (`_window_q_segments`)."""
     sel_ref = rest[0] if selected else None
     dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *maybe_dq_acc = \
         rest[1:] if selected else rest
@@ -1288,7 +1442,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             if masked:
                 row0 = iq * t.res_q + jq * t.sub_q
                 mask = _tile_mask(
-                    s.shape, 1, row0, col0, off, seq_k, causal, padded_k) \
+                    s.shape, 1, row0, col0, off, seq_k, causal, padded_k,
+                    window) \
                     if hv is None else _bd_tile_mask(
                         s.shape, 1, row0, col0, hv, masked)
                 if selected:
@@ -1312,6 +1467,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if hv is not None:
             _run_segments(_bd_q_segments(col0, iq * t.res_q, nqs, t, hv),
                           tile)
+        elif window:
+            _run_segments(_window_q_segments(
+                col0, iq * t.res_q, nqs, t, off, seq_k, window), tile)
         else:
             j_first, j_full = _q_tiles(col0, iq * t.res_q, nqs, t, off,
                                        seq_k, causal)
@@ -1355,15 +1513,16 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
     logsumexp: grid (B*H, resident k blocks, resident q blocks), K/V
     resident and Q/dO streamed.  *sel* is the forward's selection the
     scores' way round here: `pack_selection` of the ``(B, seq_k, seq_q)``
-    transposed mask.  *mask* is a `BlockDiffusion` in `causal`'s place."""
+    transposed mask.  *mask* is a `BlockDiffusion` in `causal`'s place, or
+    a `Window` beside it."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
-    halves = 1 if mask is None else 2
+    halves = _parts(mask)
     plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v,
                        halves)
     t, dp, dvp = plan.bwd, plan.d_block, plan.dv_block
     sq_p, sk_p = plan.sq_bwd, plan.sk_bwd
-    hv = _halves_of(mask, sq_p, sk_p)
+    hv, window = _halves_of(mask, sq_p, sk_p), _window_of(mask)
     # the accumulators' lanes
     wide, wide_v = _round_up(dp, _LANES), _round_up(dvp, _LANES)
     qp = _pad_bh(q, sq_p, dp, halves)
@@ -1380,8 +1539,11 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
 
     def q_index(bh_, ik, iq):
         # an empty step (before the first visible row; under a
-        # block-diffusion mask, outside the rows that see the key block)
-        # refetches nothing
+        # block-diffusion mask, outside the rows that see the key block;
+        # through a window, past the last row that does) refetches nothing
+        if window:
+            return jnp.clip(iq, _first_q_block(ik, t, nqr, sk - sq),
+                            _last_q_block(ik, t, nqr, sk - sq, window))
         if hv is not None:
             a_first, n_first, n_last = _bd_q_blocks(ik, t, hv)
             return jnp.where((iq < nqr // 2) & (a_first < nqr // 2),
@@ -1410,7 +1572,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
 
     kernel = functools.partial(
         _flash_bwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
-        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk, hv=hv)
+        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk, hv=hv,
+        window=window)
     in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
     limit = _vmem_limit(plan, q.dtype.itemsize)
     if sel is not None:
@@ -1487,16 +1650,18 @@ def _flash_vjp_bwd(causal, sm_scale, interpret, mask, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+
 def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False,
                     chunk=512, mask=None):
     """Blockwise (flash) attention, (B, H, S, D) layout.
 
     Pallas MXU kernel on TPU; chunked-scan XLA path elsewhere (*chunk*
     is its block length).  Both have O(S * block) activation memory;
-    grads flow through either.  *mask* is a static description of a mask
-    that is not causal, a `BlockDiffusion` ``(block, half)``: the kernels'
-    loops then visit the tiles that hold a visible pair and no other, and
-    no mask is an operand.
+    grads flow through either.  *mask* is a static description: of a mask
+    that is not causal, a `BlockDiffusion` ``(block, half)``, or beside
+    ``causal=True`` a `Window` ``(keys,)`` that bounds a query's keys from
+    below as well.  Either way the kernels' loops visit the tiles that hold
+    a visible pair and no other, and no mask is an operand.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
@@ -1670,23 +1835,33 @@ def selected_attention(q, k, v, sel_q, sel_k, sm_scale=None,
 @register_op("_contrib_DotProductAttention",
              input_names=("query", "key", "value"))
 def _dot_product_attention(query, key, value, causal=False, sm_scale=None,
-                           chunk=512, mask=None, mask_block=1):
+                           chunk=512, mask=None, mask_block=1, window=0):
     """Fused scaled-dot-product attention (TPU-native; no reference
     counterpart — the reference predates Transformers, SURVEY §5.7).
     *mask* ``"block_diffusion"`` with *mask_block* puts the sequence under
     the block-diffusion mask (`ops/attention.py` `BlockDiffusion`: a clean
     copy then a noised copy of half the sequence each), under device scope
-    ``mx.bd.attention``."""
-    if mask is None:
+    ``mx.bd.attention``.  *window* > 0 with ``causal`` bounds a query's
+    keys to that many, its own the last (`Window`); the pairs that leaves
+    visible go out as step stat ``swa_visible_pairs``."""
+    if mask is None and not window:
         return flash_attention(query, key, value, causal=bool(causal),
                                sm_scale=sm_scale, chunk=chunk)
+    from .. import profiler
+    if mask is None:
+        keys = min(int(window), key.shape[2])
+        # at trace time on purpose (as the routed op's counts); a float
+        profiler.emit_step_stat(  # graftlint: disable=JG003
+            "swa_visible_pairs", jnp.float32(query.shape[0] * window_pairs(
+                query.shape[2], key.shape[2], keys)))
+        return flash_attention(query, key, value, causal=bool(causal),
+                               sm_scale=sm_scale, chunk=chunk,
+                               mask=Window(int(window)))
     if mask != "block_diffusion":
         raise ValueError("mask %r is not built (block_diffusion is)"
                          % (mask,))
-    from .. import profiler
     half = query.shape[2] // 2
-    # at trace time on purpose (as the routed op's counts); a float: a
-    # batch's pairs pass 2^31
+    # a float: a batch's pairs pass 2^31
     profiler.emit_step_stat(  # graftlint: disable=JG003
         "bd_visible_pairs", jnp.float32(
             query.shape[0] * half * (half + int(mask_block))))

@@ -73,6 +73,85 @@ def _rms_norm(data, gamma, eps=1e-5):
     return (y * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
+def rope_frequencies(parameters, head_dim):
+    """A layer kind's rotary settings, shaped as one entry of a published
+    ``rope_parameters`` mapping (``rope_type`` ``default`` or ``yarn``,
+    ``rope_theta``, ``partial_rotary_factor``, and for YaRN ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``), as `_contrib_HeadNormRotary`'s attributes:
+    ``theta`` alone where one theta turns the whole head (today's op), else
+    ``rotary_dim``, the ``inv_freq`` of its ``rotary_dim / 2`` pairs and the
+    ``table_scale`` that multiplies cos and sin.
+
+    YaRN (arXiv:2309.00071, as the public code applies it) over ``dim =
+    rotary_dim``: ``e_i = theta^(-2i/dim)``, ``n_i = e_i / factor``, ``c(r)
+    = dim ln(original / (2 pi r)) / (2 ln theta)``, ``low = max(floor(
+    c(beta_fast)), 0)``, ``high = min(ceil(c(beta_slow)), dim - 1)``,
+    ``ramp_i = clip((i - low) / (high - low), 0, 1)``, ``inv_freq_i = n_i
+    ramp_i + e_i (1 - ramp_i)``; the scale is ``attention_factor`` (``0.1
+    ln(factor) + 1`` where not given).  In float64."""
+    kind = parameters.get("rope_type", "default")
+    theta = float(parameters.get("rope_theta", 10000.0))
+    dim = int(head_dim * float(parameters.get("partial_rotary_factor", 1)))
+    if dim < 2 or dim % 2 or dim > head_dim:
+        raise ValueError("partial_rotary_factor %r leaves %d of a head's %d"
+                         % (parameters.get("partial_rotary_factor"), dim,
+                            head_dim))
+    e = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if kind == "default":
+        if dim == head_dim:
+            return {"theta": theta}
+        inv, scale = e, 1.0
+    elif kind == "yarn":
+        factor = float(parameters["factor"])
+        original = float(parameters["original_max_position_embeddings"])
+
+        def turns(r):
+            return dim * math.log(original / (2 * math.pi * r)) \
+                / (2 * math.log(theta))
+
+        low = max(math.floor(turns(float(parameters.get("beta_fast", 32)))),
+                  0)
+        high = min(math.ceil(turns(float(parameters.get("beta_slow", 1)))),
+                   dim - 1)
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        inv = e / factor * ramp + e * (1.0 - ramp)
+        scale = float(parameters.get("attention_factor")
+                      or 0.1 * math.log(factor) + 1.0)
+    else:
+        raise ValueError("rope_type %r is not built (default and yarn are)"
+                         % (kind,))
+    return {"theta": theta, "rotary_dim": dim,
+            "inv_freq": tuple(float(f) for f in inv),
+            "table_scale": scale}
+
+
+def _rotary_given(data, rotary_dim, inv_freq, scale):
+    """Rotary positions ``0 .. seq - 1`` on the first *rotary_dim* of each
+    head of ``(batch, heads, seq, d)`` at the given frequencies, one a
+    pair, the pairs split by halves of the rotated part (``(x[i], x[i +
+    rotary_dim / 2])``); cos and sin times *scale*; the other dims pass
+    through untouched.  Angles in float64 rounded once, the turn in
+    float32, as `_rotary`."""
+    s, d = data.shape[-2], data.shape[-1]
+    half = rotary_dim // 2
+    if len(inv_freq) != half or rotary_dim > d:
+        raise ValueError("%d frequencies do not turn %d of a %d-wide head"
+                         % (len(inv_freq), rotary_dim, d))
+    ang = np.arange(s, dtype=np.float64)[:, None] \
+        * np.asarray(inv_freq, np.float64)[None, :]
+    cos = jnp.asarray(np.concatenate([np.cos(ang)] * 2, -1) * scale,
+                      jnp.float32)
+    sin = jnp.asarray(np.concatenate([np.sin(ang)] * 2, -1) * scale,
+                      jnp.float32)
+    x = data.astype(jnp.float32)
+    turned, kept = x[..., :rotary_dim], x[..., rotary_dim:]
+    rot = jnp.concatenate([-turned[..., half:], turned[..., :half]], -1)
+    return jnp.concatenate([turned * cos + rot * sin, kept],
+                           -1).astype(data.dtype)
+
+
 def _rotary(data, theta=10000.0, interleaved=False, positions=None,
             mrope_section=()):
     """Rotary positions on ``(batch, heads, seq, d)``: positions ``0 ..
@@ -476,6 +555,14 @@ def _block_diffusion_loss(data, label):
 profiler.register_step_stat("bd_position_counts", _fold_bd_counts)
 profiler.register_step_stat("bd_loss", _fold_bd_loss)
 profiler.register_step_stat("bd_visible_pairs", _fold_bd_pairs)
+
+
+def _fold_swa_pairs(values):
+    profiler.bump_counter("swa_visible_pairs_total",
+                          int(np.asarray(values, np.int64).sum()))
+
+
+profiler.register_step_stat("swa_visible_pairs", _fold_swa_pairs)
 
 
 # ---------------------------------------------------------------------------
@@ -1069,7 +1156,7 @@ _head_norm_rotary.defvjp(
 
 
 def _norm_turn_by_head(y, heads, gamma, positions, sections, theta, eps,
-                       tables=None):
+                       tables=None, given=None):
     """A projection's output ``(batch, seq, heads x d)`` to ``(batch,
     heads, seq, d)``, each head normed by *gamma* and turned by *positions*
     (``(axes, batch, seq)`` dealt by *sections*; a text's where None): where
@@ -1077,10 +1164,23 @@ def _norm_turn_by_head(y, heads, gamma, positions, sections, theta, eps,
     `_rotary` over `_rms_norm`; `mx.headrope.plan` says which, and why.  A
     caller that turns several projections by the same angles (an op's q and
     k) hands each call the same list *tables*: the first that takes the
-    kernels builds the pair into it."""
+    kernels builds the pair into it.  *given* ``(rotary_dim, inv_freq,
+    scale)`` turns a part of each head at given frequencies
+    (`_rotary_given`): the kernels roll a whole head by its half, so such a
+    projection keeps the `jax.numpy` body, and the span says so."""
     batch, seq, _ = y.shape
     d = y.shape[-1] // heads
     tables = [] if tables is None else tables
+    if given:
+        if positions is not None:
+            raise ValueError("given frequencies turn by a text's positions; "
+                             "positions as an operand are not built")
+        _record_headrope_plan(
+            y, heads, None, "rotary over %d of a head's %d at given "
+            "frequencies" % (given[0], d), tables)
+        return _rotary_given(_rms_norm(
+            y.reshape(batch, seq, heads, d), gamma, eps).transpose(
+                0, 2, 1, 3), *given)
     plan, why = _headrope_plan(y, heads, positions, sections)
     if plan and not tables:
         tables.extend(jax.lax.stop_gradient(t) for t in _rotary_tables(
@@ -1096,16 +1196,26 @@ def _norm_turn_by_head(y, heads, gamma, positions, sections, theta, eps,
 @register_op("_contrib_HeadNormRotary", aliases=("HeadNormRotary",),
              input_names=("data", "gamma", "positions"))
 def _head_norm_rotary_op(data, gamma, *positions, num_heads=1,
-                         theta=10000.0, eps=1e-5, use_positions=False):
+                         theta=10000.0, eps=1e-5, use_positions=False,
+                         rotary_dim=0, inv_freq=(), table_scale=1.0):
     """A q or k projection's output ``(batch, seq, num_heads x d)`` to the
     attention's ``(batch, num_heads, seq, d)``: ``_contrib_RMSNorm`` over
     each head by *gamma* ``(d,)``, then ``_contrib_RotaryEmbedding``
     (rotate-half; *positions* ``(1, batch, seq)`` is an input with
     ``use_positions``, and the positions are ``0 .. seq - 1`` without), as
-    `_norm_turn_by_head` computes them."""
+    `_norm_turn_by_head` computes them.  With *inv_freq* the rotary
+    positions turn the first *rotary_dim* of each head alone (all of it
+    where 0), at those frequencies and not *theta*'s, cos and sin times
+    *table_scale* (`rope_frequencies` makes the three from a published
+    ``rope_parameters`` entry); without it this is the op it was."""
+    given = None
+    if inv_freq:
+        d = data.shape[-1] // int(num_heads)
+        given = (int(rotary_dim) or d, tuple(float(f) for f in inv_freq),
+                 float(table_scale))
     return _norm_turn_by_head(data, int(num_heads), gamma,
                               positions[0] if positions else None, (),
-                              theta, eps)
+                              theta, eps, given=given)
 
 
 # ---------------------------------------------------------------------------

@@ -457,7 +457,7 @@ def test_the_layer_lowered_for_the_tpu_holds_the_pair_where_the_plan_gives_it(
 def test_a_decoder_layer_of_the_kind_needs_its_block_length():
     from mxnet_tpu.gluon.model_zoo.decoder import (OPERATOR_KINDS,
                                                    get_decoder_lm)
-    assert OPERATOR_KINDS[-1] == "block_diffusion_attention"
+    assert OPERATOR_KINDS[4] == "block_diffusion_attention"
     with pytest.raises(ValueError, match="diffusion_block"):
         get_decoder_lm(vocab=32, dim=64,
                        layer_types=["block_diffusion_attention"],
