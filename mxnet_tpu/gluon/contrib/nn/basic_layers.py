@@ -259,8 +259,9 @@ class GroupedQueryAttention(HybridBlock):
     published ``rope_parameters`` mapping (`ops/lm_blocks.py`
     `rope_frequencies`: ``rope_theta``, ``partial_rotary_factor``, YaRN's
     keys) in *rope_theta*'s place; a part of a head turned, or YaRN's
-    frequencies, keep the q and k passes on ``contrib.HeadNormRotary``'s
-    `jax.numpy` body.  Device scopes ``mx.swa.project``,
+    frequencies, take the same kernels on the same terms (a part by two
+    rolls and a third table; ``mx.headrope.plan`` carries ``rotary_dim``).
+    Device scopes ``mx.swa.project``,
     ``mx.swa.attention`` and ``mx.swa.out`` for a layer with a window,
     ``mx.gqa.project``, ``mx.gqa.attention`` and ``mx.gqa.out`` for one
     with a gate and none.  Without the three this is the block it was."""

@@ -25,10 +25,11 @@ block's, and the operator ``_contrib_HeadNormRotary`` that gluon.contrib.nn
 ``GroupedQueryAttention`` puts behind its q and k products) are
 `_head_norm_rotary`, the Mosaic pair ``mx_headrope_fwd`` and
 ``mx_headrope_bwd``, on the same terms (`_headrope_plan`: one device, heads
-of whole 128-lane tiles, the sequence in whole tiles), and `_rotary` over
-`_rms_norm` at every other shape.  All are decided by what the code sees in
-its input and by the platform it is lowered for: no argument, environment
-variable or switch.
+of whole 128-lane tiles, the sequence in whole tiles; a part of a head
+turned at given frequencies by two rolls and a third table), and `_rotary`
+or `_rotary_given` over `_rms_norm` at every other shape.  All are decided
+by what the code sees in its input and by the platform it is lowered for:
+no argument, environment variable or switch.
 The latent attention block's core is `ops/attention.py` `flash_attention`
 at two head widths (the Mosaic kernels on the TPU, the chunked scan
 elsewhere); its projections and its assembly of q and k are XLA's.
@@ -127,6 +128,19 @@ def rope_frequencies(parameters, head_dim):
             "table_scale": scale}
 
 
+def _given_cos_sin(seq, d, rotary_dim, inv_freq, scale):
+    """``(cos, sin)`` ``(seq, rotary_dim)`` in float64 for positions ``0 ..
+    seq - 1`` at the given frequencies, one a pair of the first *rotary_dim*
+    of a *d*-wide head, each laid twice side by side and times *scale*."""
+    if len(inv_freq) != rotary_dim // 2 or rotary_dim > d:
+        raise ValueError("%d frequencies do not turn %d of a %d-wide head"
+                         % (len(inv_freq), rotary_dim, d))
+    ang = np.arange(seq, dtype=np.float64)[:, None] \
+        * np.asarray(inv_freq, np.float64)[None, :]
+    return (np.concatenate([np.cos(ang)] * 2, -1) * scale,
+            np.concatenate([np.sin(ang)] * 2, -1) * scale)
+
+
 def _rotary_given(data, rotary_dim, inv_freq, scale):
     """Rotary positions ``0 .. seq - 1`` on the first *rotary_dim* of each
     head of ``(batch, heads, seq, d)`` at the given frequencies, one a
@@ -134,17 +148,9 @@ def _rotary_given(data, rotary_dim, inv_freq, scale):
     rotary_dim / 2])``); cos and sin times *scale*; the other dims pass
     through untouched.  Angles in float64 rounded once, the turn in
     float32, as `_rotary`."""
-    s, d = data.shape[-2], data.shape[-1]
     half = rotary_dim // 2
-    if len(inv_freq) != half or rotary_dim > d:
-        raise ValueError("%d frequencies do not turn %d of a %d-wide head"
-                         % (len(inv_freq), rotary_dim, d))
-    ang = np.arange(s, dtype=np.float64)[:, None] \
-        * np.asarray(inv_freq, np.float64)[None, :]
-    cos = jnp.asarray(np.concatenate([np.cos(ang)] * 2, -1) * scale,
-                      jnp.float32)
-    sin = jnp.asarray(np.concatenate([np.sin(ang)] * 2, -1) * scale,
-                      jnp.float32)
+    cos, sin = (jnp.asarray(t, jnp.float32) for t in _given_cos_sin(
+        data.shape[-2], data.shape[-1], rotary_dim, inv_freq, scale))
     x = data.astype(jnp.float32)
     turned, kept = x[..., :rotary_dim], x[..., rotary_dim:]
     rot = jnp.concatenate([-turned[..., half:], turned[..., :half]], -1)
@@ -901,14 +907,36 @@ HEADROPE_TILES = {"fwd": 256, "bwd": 256, "heads": 32}
 _HEADROPE_VMEM = 15 << 20
 
 
-def _rotary_tables(seq, d, theta, positions, sections):
+def _rotary_tables(seq, d, theta, positions, sections, given=None):
     """``(cos, sin)`` ``(1 or batch, seq, d)`` in float32 from `_rotary`'s
     own arithmetic for rotate-half pairs (float64 angles of ``0 .. seq - 1``
     rounded once; with *positions* ``(axes, batch, seq)`` the operand's, in
     float32, each frequency beside its own axis's position), the sign of
     ``rot = [-x2, x1]`` folded into the sine's first half: ``x * cos +
     roll(x, d / 2) * sin`` is `_rotary`'s ``x * cos + rot * sin`` bit for
-    bit."""
+    bit.
+
+    With *given* ``(rotary_dim, inv_freq, scale)`` the tables are
+    `_rotary_given`'s (`_given_cos_sin`), which turns the first
+    ``rotary_dim`` lanes by pairs ``h = rotary_dim / 2`` apart: ``cos`` is 1
+    on the lanes that pass through, and where ``rotary_dim < d`` the sine
+    comes as two tables, one a direction: ``sin_up`` (``+sin`` on lanes ``h
+    .. rotary_dim - 1``, beside ``roll(x, h)``, which brings ``x[j - h]``)
+    and ``sin_down`` (``-sin`` on lanes ``0 .. h - 1``, beside ``roll(x, d -
+    h)``, which brings ``x[j + h]``), 0 elsewhere.  A turned lane has one
+    sine term that is not 0 and a kept lane none: ``x * cos + roll(x, h) *
+    sin_up + roll(x, d - h) * sin_down`` is `_rotary_given`, lane for lane.
+    Where ``rotary_dim == d`` the two rolls are one and so are their
+    tables: the pair above."""
+    if given:
+        rotary_dim = given[0]
+        h, kept = rotary_dim // 2, np.zeros((seq, d - rotary_dim))
+        cos, sin = _given_cos_sin(seq, d, *given)
+        none = np.zeros((seq, h))
+        sines = ([-sin[:, :h], sin[:, h:]],) if rotary_dim == d else (
+            [none, sin[:, h:], kept], [-sin[:, :h], none, kept])
+        return tuple(jnp.asarray(np.concatenate(t, -1)[None], jnp.float32)
+                     for t in ([cos, kept + 1.0], *sines))
     half = d // 2
     sections = tuple(int(n) for n in sections) or (half,)
     inv = 1.0 / (float(theta) ** (np.arange(half, dtype=np.float64) / half))
@@ -926,15 +954,30 @@ def _rotary_tables(seq, d, theta, positions, sections):
             jnp.concatenate([-sin, sin], -1))
 
 
-def _headrope_body(y, gamma, cos, sin, heads, eps):
+def _headrope_rolls(d, rotary_dim):
+    """The lane distances that bring a pair's other half beside it, one a
+    sine table of `_rotary_tables`: a whole head's pairs lie ``d / 2`` apart
+    either way round, a part's ``rotary_dim / 2`` up and the rest of ``d``
+    down."""
+    h = (rotary_dim or d) // 2
+    return (h,) if 2 * h == d else (h, d - h)
+
+
+def _headrope_body(y, gamma, tables, heads, eps, rotary_dim=None):
     """``_rotary(_rms_norm(y by head, gamma).transpose(0, 2, 1, 3))`` from
-    the tables, to the bit: the norm rounded to the input's dtype, then the
-    rotation rounded again."""
+    the tables (`_rotary_given` over the norm from a part's three), to the
+    bit: the norm rounded to the input's dtype, then the rotation rounded
+    again."""
     batch, seq, _ = y.shape
     x = _rms_norm(y.reshape(batch, seq, heads, -1), gamma, eps).transpose(
         0, 2, 1, 3).astype(jnp.float32)
-    rolled = jnp.roll(x, x.shape[-1] // 2, -1)
-    return (x * cos[:, None] + rolled * sin[:, None]).astype(y.dtype)
+    cos, *sines = tables
+    rolled = [jnp.roll(x, r, -1)
+              for r in _headrope_rolls(x.shape[-1], rotary_dim)]
+    out = x * cos[:, None]
+    for brought, sin in zip(rolled, sines):
+        out = out + brought * sin[:, None]
+    return out.astype(y.dtype)
 
 
 def _one_device():
@@ -946,25 +989,27 @@ def _one_device():
         jax.sharding.get_abstract_mesh().manual_axes) == set(mesh.axis_names)
 
 
-def _headrope_blocks(kernel, rows, heads, d, dtype):
+def _headrope_blocks(kernel, rows, heads, d, dtype, tables=2):
     """Bytes of VMEM one grid step of *kernel* takes: its blocks, each held
     twice (the flat and the head-major one, in the backward kernel ``dy``
-    too, and the two tables' rows), and a head's float32 temporaries (ten
-    of them live in the backward kernel)."""
+    too, and the rows of the two or three *tables*), and a head's float32
+    temporaries (ten of them live in the backward kernel)."""
     wide = {"fwd": 2, "bwd": 3}[kernel]
-    return rows * d * (2 * (wide * heads * jnp.dtype(dtype).itemsize + 2 * 4)
-                       + 10 * 4)
+    return rows * d * (2 * (wide * heads * jnp.dtype(dtype).itemsize
+                            + tables * 4) + 10 * 4)
 
 
-def _headrope_plan(y, heads, positions=None, sections=()):
+def _headrope_plan(y, heads, positions=None, sections=(), rotary_dim=None):
     """``(tiles, None)`` where `_head_norm_rotary` takes this projection,
     ``(None, why not)`` where the caller keeps `_rotary` over `_rms_norm`
     (which also says what is wrong with positions or sections it cannot
     turn by).  The kernels take ``(batch, seq, heads x d)`` on one device:
     ``d`` whole 128-lane tiles, the sequence in whole tiles of both
-    kernels, a grid step within `_HEADROPE_VMEM`."""
+    kernels, a grid step within `_HEADROPE_VMEM` (its tables one more where
+    *rotary_dim* is a part of ``d``)."""
     tiles = HEADROPE_TILES
     d = y.shape[-1] // heads
+    tables = 1 + len(_headrope_rolls(d, rotary_dim))
     n = len(sections) or 1
     if y.ndim != 3 or jnp.dtype(y.dtype).itemsize not in (2, 4):
         return None, "not (batch, seq, width) in 2 or 4 bytes"
@@ -980,7 +1025,7 @@ def _headrope_plan(y, heads, positions=None, sections=()):
     # sweep's cap and, with both kernels' blocks, within the VMEM budget
     at_once = next((h for h in range(min(heads, tiles["heads"]), 0, -1)
                     if heads % h == 0 and all(
-                        _headrope_blocks(k, tiles[k], h, d, y.dtype)
+                        _headrope_blocks(k, tiles[k], h, d, y.dtype, tables)
                         <= _HEADROPE_VMEM for k in ("fwd", "bwd"))), None)
     if at_once is None:
         return None, "blocks over the VMEM budget"
@@ -991,7 +1036,7 @@ def _headrope_plan(y, heads, positions=None, sections=()):
     return at, None
 
 
-def _record_headrope_plan(y, heads, plan, why, tables):
+def _record_headrope_plan(y, heads, plan, why, tables, rotary_dim):
     """One `mx.headrope.plan` span each time a projection is handed over
     (as `mx.flash.plan`: the plan is a fact of the compiled program)."""
     with profiler.scope(  # graftlint: disable=JG003
@@ -999,9 +1044,14 @@ def _record_headrope_plan(y, heads, plan, why, tables):
         span.args = {
             "shape": list(y.shape), "dtype": jnp.dtype(y.dtype).name,
             "heads": heads, "head_dim": y.shape[-1] // heads,
+            # the lanes of a head that turn: all of them but at given
+            # frequencies over a part
+            "rotary_dim": rotary_dim,
             "path": "xla" if plan is None else "kernel", "why": why,
             "seq_tile": plan and {k: plan[k] for k in ("fwd", "bwd")},
             "head_tile": plan and plan["heads"],
+            # cos and one sine a roll: two, or three where a part turns
+            "tables": plan and len(tables),
             "table_bytes": plan and sum(t.size * t.dtype.itemsize
                                         for t in tables),
             # what `_head_norm_rotary` keeps for the backward pass: the
@@ -1010,33 +1060,40 @@ def _record_headrope_plan(y, heads, plan, why, tables):
             + y.shape[-1] // heads * 4}
 
 
-def _headrope_fwd_kernel(y_ref, g_ref, cos_ref, sin_ref, out_ref, *, d, eps):
+def _headrope_fwd_kernel(y_ref, g_ref, *refs, d, eps, rolls):
     """A ``(rows, heads x d)`` block of the projection to ``(heads, rows,
     d)`` of the head-major array, a head at a time: all of it in float32,
-    rounded once."""
-    gamma, cos, sin = g_ref[...], cos_ref[0], sin_ref[0]
+    rounded once.  *refs*: cos, a sine table a roll, the block out."""
+    *tables, out_ref = refs
+    gamma, cos, *sines = [g_ref[...]] + [t[0] for t in tables]
     for h in range(out_ref.shape[1]):
         x = y_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
         n = x * jax.lax.rsqrt(
             jnp.sum(x * x, -1, keepdims=True) * (1.0 / d) + eps) * gamma
-        out_ref[0, h] = (n * cos + pltpu.roll(n, d // 2, 1) * sin).astype(
-            out_ref.dtype)
+        out = n * cos
+        for r, sin in zip(rolls, sines):
+            out = out + pltpu.roll(n, r, 1) * sin
+        out_ref[0, h] = out.astype(out_ref.dtype)
 
 
-def _headrope_bwd_kernel(y_ref, g_ref, cos_ref, sin_ref, dout_ref, dy_ref,
-                         dg_ref, *, d, eps):
+def _headrope_bwd_kernel(y_ref, g_ref, *refs, d, eps, rolls):
     """The block's ``dy`` into the flat layout and its part of the scale's
-    gradient.  The rotation is orthogonal, so the cotangent turns back by
-    the transposed roll (a roll by ``d / 2`` again); the head's ``rsqrt``
-    is computed again from the kept projection."""
-    gamma, cos, sin = g_ref[...], cos_ref[0], sin_ref[0]
+    gradient.  The rotation is linear in the normed head, so the cotangent
+    turns back by the transposed rolls (``d`` less each distance: a roll by
+    ``d / 2`` is its own); the head's ``rsqrt`` is computed again from the
+    kept projection.  *refs*: cos, a sine table a roll, ``dout``, then the
+    two blocks out."""
+    *tables, dout_ref, dy_ref, dg_ref = refs
+    gamma, cos, *sines = [g_ref[...]] + [t[0] for t in tables]
     dgamma = jnp.zeros_like(gamma)
     for h in range(dout_ref.shape[1]):
         x = y_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
         dout = dout_ref[0, h].astype(jnp.float32)
         r = jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) * (1.0 / d) + eps)
         xh = x * r
-        dn = dout * cos + pltpu.roll(dout * sin, d // 2, 1)
+        dn = dout * cos
+        for distance, sin in zip(rolls, sines):
+            dn = dn + pltpu.roll(dout * sin, d - distance, 1)
         dgamma += jnp.sum(dn * xh, 0, keepdims=True)
         dxh = dn * gamma
         dy_ref[0, :, h * d:(h + 1) * d] = (r * (dxh - xh * (
@@ -1045,54 +1102,58 @@ def _headrope_bwd_kernel(y_ref, g_ref, cos_ref, sin_ref, dout_ref, dy_ref,
     dg_ref[0] = dgamma
 
 
-def _headrope_specs(y, cos, heads, rows, at_once):
+def _headrope_specs(y, tables, heads, rows, at_once):
     """The grid (batch, sequence tile, group of heads, the heads innermost
     so that a tile's rows of the tables are fetched once) and the block of
-    each operand: flat, head-major, the scale, a table."""
+    each operand: flat, head-major, the scale, each table."""
     batch, seq, width = y.shape
     d = width // heads
-    per_row = cos.shape[0] > 1
+    per_row = tables[0].shape[0] > 1
     return (batch, seq // rows, heads // at_once), d, (
         pl.BlockSpec((1, rows, at_once * d), lambda b, s, h: (b, s, h)),
         pl.BlockSpec((1, at_once, rows, d), lambda b, s, h: (b, h, s, 0)),
         pl.BlockSpec((1, d), lambda b, s, h: (0, 0)),
-        pl.BlockSpec((1, rows, d),
-                     lambda b, s, h: (b if per_row else 0, s, 0)))
+        [pl.BlockSpec((1, rows, d),
+                      lambda b, s, h: (b if per_row else 0, s, 0))
+         for _ in tables])
 
 
-_HEADROPE_STATIC = ("heads", "eps", "rows", "at_once", "interpret")
+_HEADROPE_STATIC = ("heads", "eps", "rows", "at_once", "rotary_dim",
+                    "interpret")
 
 
 # jitted, so q's and k's calls of a step's four layers share two traces
 # and two Mosaic programs of each kernel
 
 @functools.partial(jax.jit, static_argnames=_HEADROPE_STATIC)
-def _headrope_fwd_pallas(y, gamma, cos, sin, heads, eps, rows, at_once,
-                         interpret=False):
+def _headrope_fwd_pallas(y, gamma, tables, heads, eps, rows, at_once,
+                         rotary_dim=None, interpret=False):
     grid, d, (flat, by_head, scale, table) = _headrope_specs(
-        y, cos, heads, rows, at_once)
+        y, tables, heads, rows, at_once)
     with jax.named_scope("mx.headrope"):
         return pl.pallas_call(
-            functools.partial(_headrope_fwd_kernel, d=d, eps=eps),
-            grid=grid, in_specs=[flat, scale, table, table],
+            functools.partial(_headrope_fwd_kernel, d=d, eps=eps,
+                              rolls=_headrope_rolls(d, rotary_dim)),
+            grid=grid, in_specs=[flat, scale, *table],
             out_specs=by_head,
             out_shape=jax.ShapeDtypeStruct(
                 (y.shape[0], heads, y.shape[1], d), y.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",) * 3),
             interpret=interpret, name="mx_headrope_fwd",
-        )(y, gamma.astype(jnp.float32).reshape(1, d), cos, sin)
+        )(y, gamma.astype(jnp.float32).reshape(1, d), *tables)
 
 
 @functools.partial(jax.jit, static_argnames=_HEADROPE_STATIC)
-def _headrope_bwd_pallas(y, gamma, cos, sin, dout, heads, eps, rows, at_once,
-                         interpret=False):
+def _headrope_bwd_pallas(y, gamma, tables, dout, heads, eps, rows, at_once,
+                         rotary_dim=None, interpret=False):
     grid, d, (flat, by_head, scale, table) = _headrope_specs(
-        y, cos, heads, rows, at_once)
+        y, tables, heads, rows, at_once)
     with jax.named_scope("mx.headrope"):
         dy, dgamma = pl.pallas_call(
-            functools.partial(_headrope_bwd_kernel, d=d, eps=eps),
-            grid=grid, in_specs=[flat, scale, table, table, by_head],
+            functools.partial(_headrope_bwd_kernel, d=d, eps=eps,
+                              rolls=_headrope_rolls(d, rotary_dim)),
+            grid=grid, in_specs=[flat, scale, *table, by_head],
             out_specs=[flat, pl.BlockSpec(
                 (1, 1, d), lambda b, s, h: (
                     (b * grid[1] + s) * grid[2] + h, 0, 0))],
@@ -1102,56 +1163,61 @@ def _headrope_bwd_pallas(y, gamma, cos, sin, dout, heads, eps, rows, at_once,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",) * 3),
             interpret=interpret, name="mx_headrope_bwd",
-        )(y, gamma.astype(jnp.float32).reshape(1, d), cos, sin, dout)
+        )(y, gamma.astype(jnp.float32).reshape(1, d), *tables, dout)
         return dy, jnp.sum(dgamma, (0, 1)).astype(gamma.dtype)
 
 
-def _headrope_body_backward(y, gamma, cos, sin, dout, heads, eps):
-    return jax.vjp(functools.partial(_headrope_body, heads=heads, eps=eps),
-                   y, gamma, cos, sin)[1](dout)[:2]
+def _headrope_body_backward(y, gamma, tables, dout, heads, eps,
+                            rotary_dim=None):
+    return jax.vjp(functools.partial(_headrope_body, heads=heads, eps=eps,
+                                     rotary_dim=rotary_dim),
+                   y, gamma, tables)[1](dout)[:2]
 
 
-def _headrope_forward(y, gamma, cos, sin, heads, eps):
-    tiles, _ = _headrope_plan(y, heads)
+def _headrope_forward(y, gamma, tables, heads, eps, rotary_dim):
+    tiles, _ = _headrope_plan(y, heads, rotary_dim=rotary_dim)
     return jax.lax.platform_dependent(
-        y, gamma, cos, sin,
-        default=functools.partial(_headrope_body, heads=heads, eps=eps),
+        y, gamma, tables,
+        default=functools.partial(_headrope_body, heads=heads, eps=eps,
+                                  rotary_dim=rotary_dim),
         tpu=functools.partial(_headrope_fwd_pallas, heads=heads, eps=eps,
-                              rows=tiles["fwd"], at_once=tiles["heads"]))
+                              rows=tiles["fwd"], at_once=tiles["heads"],
+                              rotary_dim=rotary_dim))
 
 
-def _headrope_backward(heads, eps, kept, dout):
-    y, gamma, cos, sin = kept
-    tiles, _ = _headrope_plan(y, heads)
+def _headrope_backward(heads, eps, rotary_dim, kept, dout):
+    y, gamma, tables = kept
+    tiles, _ = _headrope_plan(y, heads, rotary_dim=rotary_dim)
     dy, dgamma = jax.lax.platform_dependent(
-        y, gamma, cos, sin, dout,
+        y, gamma, tables, dout,
         default=functools.partial(_headrope_body_backward, heads=heads,
-                                  eps=eps),
+                                  eps=eps, rotary_dim=rotary_dim),
         tpu=functools.partial(_headrope_bwd_pallas, heads=heads, eps=eps,
-                              rows=tiles["bwd"], at_once=tiles["heads"]))
+                              rows=tiles["bwd"], at_once=tiles["heads"],
+                              rotary_dim=rotary_dim))
     # the tables are positions, which are data: nothing flows back to them
-    return dy, dgamma, jnp.zeros_like(cos), jnp.zeros_like(sin)
+    return dy, dgamma, tuple(jnp.zeros_like(t) for t in tables)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _head_norm_rotary(y, gamma, cos, sin, heads, eps):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _head_norm_rotary(y, gamma, tables, heads, eps, rotary_dim=None):
     """A projection's output ``(batch, seq, heads x d)`` as the product
     wrote it to ``(batch, heads, seq, d)``, each head normed (RMS over
-    ``d``, scaled by *gamma*) and turned by the tables of `_rotary_tables`,
-    at a shape `_headrope_plan` gives tiles for.  Where the program is
-    lowered for the TPU the kernels ``mx_headrope_fwd`` and
-    ``mx_headrope_bwd`` read every element once and write it once each way:
-    nothing in float32 leaves the chip, the result is rounded once, and the
-    projection alone is kept for the backward pass.  Lowered for anything
-    else it is `_headrope_body` and JAX's derivative of it (from ``y``
-    again)."""
-    return _headrope_forward(y, gamma, cos, sin, heads, eps)
+    ``d``, scaled by *gamma*) and turned by the *tables* of `_rotary_tables`
+    (two, or the three that turn *rotary_dim* of ``d``), at a shape
+    `_headrope_plan` gives tiles for.  Where the program is lowered for the
+    TPU the kernels ``mx_headrope_fwd`` and ``mx_headrope_bwd`` read every
+    element once and write it once each way: nothing in float32 leaves the
+    chip, the result is rounded once, and the projection alone is kept for
+    the backward pass.  Lowered for anything else it is `_headrope_body`
+    and JAX's derivative of it (from ``y`` again)."""
+    return _headrope_forward(y, gamma, tables, heads, eps, rotary_dim)
 
 
 _head_norm_rotary.defvjp(
-    lambda y, gamma, cos, sin, heads, eps: (
-        _headrope_forward(y, gamma, cos, sin, heads, eps),
-        (y, gamma, cos, sin)),
+    lambda y, gamma, tables, heads, eps, rotary_dim: (
+        _headrope_forward(y, gamma, tables, heads, eps, rotary_dim),
+        (y, gamma, tables)),
     _headrope_backward)
 
 
@@ -1164,33 +1230,33 @@ def _norm_turn_by_head(y, heads, gamma, positions, sections, theta, eps,
     `_rotary` over `_rms_norm`; `mx.headrope.plan` says which, and why.  A
     caller that turns several projections by the same angles (an op's q and
     k) hands each call the same list *tables*: the first that takes the
-    kernels builds the pair into it.  *given* ``(rotary_dim, inv_freq,
-    scale)`` turns a part of each head at given frequencies
-    (`_rotary_given`): the kernels roll a whole head by its half, so such a
-    projection keeps the `jax.numpy` body, and the span says so."""
+    kernels builds them into it.  *given* ``(rotary_dim, inv_freq, scale)``
+    turns a part of each head at given frequencies: the same kernels by two
+    rolls and a third table where the part is less than the head, and
+    `_rotary_given` over `_rms_norm` where the plan refuses."""
     batch, seq, _ = y.shape
     d = y.shape[-1] // heads
     tables = [] if tables is None else tables
-    if given:
-        if positions is not None:
-            raise ValueError("given frequencies turn by a text's positions; "
-                             "positions as an operand are not built")
-        _record_headrope_plan(
-            y, heads, None, "rotary over %d of a head's %d at given "
-            "frequencies" % (given[0], d), tables)
-        return _rotary_given(_rms_norm(
-            y.reshape(batch, seq, heads, d), gamma, eps).transpose(
-                0, 2, 1, 3), *given)
-    plan, why = _headrope_plan(y, heads, positions, sections)
+    if given and positions is not None:
+        raise ValueError("given frequencies turn by a text's positions; "
+                         "positions as an operand are not built")
+    rotary_dim = given[0] if given else d
+    plan, why = _headrope_plan(y, heads, positions, sections, rotary_dim)
     if plan and not tables:
         tables.extend(jax.lax.stop_gradient(t) for t in _rotary_tables(
-            seq, d, theta, positions, sections))
-    _record_headrope_plan(y, heads, plan, why, tables)
+            seq, d, theta, positions, sections, given))
+    if given and not plan:
+        why = "rotary over %d of a head's %d at given frequencies: %s" % (
+            rotary_dim, d, why)
+    _record_headrope_plan(y, heads, plan, why, tables, rotary_dim)
     if plan:
-        return _head_norm_rotary(y, gamma, *tables, heads, float(eps))
-    return _rotary(_rms_norm(y.reshape(batch, seq, heads, d), gamma,
-                             eps).transpose(0, 2, 1, 3),
-                   float(theta), False, positions, sections)
+        return _head_norm_rotary(y, gamma, tuple(tables), heads, float(eps),
+                                 rotary_dim)
+    normed = _rms_norm(y.reshape(batch, seq, heads, d), gamma,
+                       eps).transpose(0, 2, 1, 3)
+    if given:
+        return _rotary_given(normed, *given)
+    return _rotary(normed, float(theta), False, positions, sections)
 
 
 @register_op("_contrib_HeadNormRotary", aliases=("HeadNormRotary",),

@@ -95,3 +95,30 @@ def dq_accumulates_in():
     yield place
     patch.undo()
     attention._flash_bwd_pallas.clear_cache()
+
+
+@pytest.fixture
+def interpreted_headrope(monkeypatch):
+    """Steers `ops/lm_blocks.py` `_head_norm_rotary` onto its TPU branch on
+    this CPU host, the two kernels interpreted, at tiles small enough for a
+    test (32 rows forward, 16 backward, 8 heads a grid step); every other
+    choice by platform (the attention's, the selection's) stays the
+    CPU's."""
+    import jax
+    from mxnet_tpu.ops import lm_blocks
+    real = jax.lax.platform_dependent
+    mine = (lm_blocks._headrope_fwd_pallas, lm_blocks._headrope_bwd_pallas)
+
+    def choose(*args, tpu, default):
+        if getattr(tpu, "func", None) in mine:
+            return tpu(*args, interpret=True)
+        return real(*args, tpu=tpu, default=default)
+
+    monkeypatch.setattr(lm_blocks, "HEADROPE_TILES",
+                        {"fwd": 32, "bwd": 16, "heads": 8})
+    monkeypatch.setattr(jax.lax, "platform_dependent", choose)
+    # an operator traced before is not traced again, and one traced here
+    # must not serve a later test
+    jax.clear_caches()
+    yield
+    jax.clear_caches()

@@ -551,6 +551,43 @@ def test_logits_loss_and_every_gradient_agree_with_the_reference(kind):
         assert np.abs(w).max() > 0, ref_name
 
 
+def test_heads_of_128_through_the_head_rope_pair_agree_with_the_reference(
+        interpreted_headrope):
+    """A full layer and a window layer at the published head width, where
+    the plan gives tiles: the full layer's q and k (4 and 2 heads, half a
+    head at YaRN's frequencies) go through the pair by three tables, the
+    window layer's (6 and 2, the whole head by one theta) by two, and the
+    logits, the loss and every leaf's gradient are the reference's to the
+    tolerance the body holds."""
+    import mxnet_tpu as mx
+    cfg = cut(2, head_dim=128)
+    # whole tiles of the interpreted kernels' 32 rows
+    cfg["train"] = dict(cfg["train"], sequence_length=64)
+    net, loss, names, params = seeded(cfg)
+    (x, y), = family.batches(cfg, SEED, 1, 2)
+    since = max([s.id for s in profiler.spans()] or [0])
+    got = net(mx.nd.array(x, dtype="int32")).asnumpy()
+    want = highest(lambda p: reference.logits(p, cfg, x), params)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-4, atol=2e-6)
+    trainer = models_common.make_trainer(net, loss, cfg["train"],
+                                         jax.devices()[:1])
+    got_loss = float(trainer.fit_batch(x, y))
+    value, grads = highest(jax.value_and_grad(
+        lambda p: reference.loss_sum(p, cfg, x, y)), params)
+    assert got_loss == pytest.approx(float(value) / 2, rel=1e-5)
+    lr = cfg["train"]["lr"]
+    for ref_name, prog_name in names.items():
+        g = -np.asarray(trainer._opt_state[prog_name][0]) / lr
+        w = np.asarray(grads[ref_name]) / 2
+        assert np.abs(g - w).max() <= 2e-4 * max(np.abs(w).max(), 1e-12), \
+            ref_name
+    plans = [s.args for s in profiler.spans()
+             if s.name == "mx.headrope.plan" and s.id > since]
+    assert {(p["heads"], p["path"], p["rotary_dim"], p["tables"])
+            for p in plans} == {(4, "kernel", 64, 3), (2, "kernel", 64, 3),
+                                (6, "kernel", 128, 2), (2, "kernel", 128, 2)}
+
+
 def test_three_trainer_steps_follow_the_reference():
     cfg = config()
     train = cfg["train"]
@@ -697,20 +734,16 @@ def no_cache():
     compilation_cache.reset_cache()
 
 
-def test_a_sliding_layer_compiles_for_the_described_chip_with_no_square_array(
-        one_chip, no_cache):
-    """The cell's sliding layer, forward and backward, at 4096 positions, 72
-    and 8 heads of 128 in bf16 through 512 keys: both kernels compile for a
-    v5e, the q and k passes take the head-rope pair, and no array of the
-    compiled program has two axes of the sequence."""
+def compiled_layer(one_chip, heads, rope, **kind):
+    """``(optimized text, spans)`` of one of the cell's attention layers,
+    forward and backward, at 4096 positions, *heads* and 8 heads of 128 in
+    bf16, compiled for a v5e."""
     import mxnet_tpu as mx
     from mxnet_tpu import executor
     from mxnet_tpu.gluon.contrib.nn import GroupedQueryAttention
     seq, dim = 4096, 3072
-    block = GroupedQueryAttention(dim, 72, 8, 128, epsilon=1e-6, window=512,
-                                  gate=True, rope={"rope_type": "default",
-                                                   "rope_theta": 10000,
-                                                   "partial_rotary_factor": 1})
+    block = GroupedQueryAttention(dim, heads, 8, 128, epsilon=1e-6,
+                                  gate=True, rope=rope, **kind)
     graph = executor._build_eval(block(mx.sym.var("x")), True)
     bf = jnp.bfloat16
     avals = {p.name: jax.ShapeDtypeStruct(p.shape, bf, sharding=one_chip)
@@ -726,18 +759,40 @@ def test_a_sliding_layer_compiles_for_the_described_chip_with_no_square_array(
 
     compiled = jax.jit(step).lower(avals, jax.ShapeDtypeStruct(
         (1, seq, dim), jnp.float32, sharding=one_chip)).compile()
-    text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') >= 6
-    for name in ("mx_flash_fwd", "mx_flash_bwd", "mx_headrope_fwd",
-                 "mx_headrope_bwd"):
-        assert name in text, name
-    assert not re.search(r"\[(\d+,)*%d,(\d+,)*%d[,\]]" % (seq, seq), text)
-    spans = [s for s in profiler.spans() if s.id > since]
+    return compiled.as_text(), [s for s in profiler.spans() if s.id > since]
+
+
+def test_a_sliding_layer_compiles_for_the_described_chip_with_no_square_array(
+        one_chip, no_cache):
+    """The cell's sliding layer, forward and backward, at 4096 positions, 72
+    and 8 heads of 128 in bf16 through 512 keys, and its full layer at 48
+    and 8: both kernels compile for a v5e, the q and k passes take the
+    head-rope pair (the full layer's over half a head: two rolls and three
+    tables), and no array of the compiled program has two axes of the
+    sequence."""
+    seq = 4096
+    text, spans = compiled_layer(
+        one_chip, 72, {"rope_type": "default", "rope_theta": 10000,
+                       "partial_rotary_factor": 1}, window=512)
+    full_text, full_spans = compiled_layer(one_chip, 48, YARN)
+    for t in (text, full_text):
+        assert t.count('custom_call_target="tpu_custom_call"') >= 6
+        for name in ("mx_flash_fwd", "mx_flash_bwd", "mx_headrope_fwd",
+                     "mx_headrope_bwd"):
+            assert name in t, name
+        assert not re.search(r"\[(\d+,)*%d,(\d+,)*%d[,\]]" % (seq, seq), t)
     plans = [s.args for s in spans if s.name == "mx.flash.plan"]
     assert plans and all(p["mask"] == "window" and p["window"] == 512
                          for p in plans)
     assert plans[0]["fwd"]["tiles_visited"] == plans[0]["fwd"][
         "tiles_needed"] == swa_counts.tiles(seq, 512, 256, 512)[0] == 30
-    ropes = [s.args for s in spans if s.name == "mx.headrope.plan"]
-    assert {(p["heads"], p["path"]) for p in ropes} == {(72, "kernel"),
-                                                        (8, "kernel")}
+    ropes = [s.args for s in spans + full_spans
+             if s.name == "mx.headrope.plan"]
+    assert {(p["heads"], p["path"]) for p in ropes} == {
+        (72, "kernel"), (8, "kernel"), (48, "kernel")}
+    assert {(p["heads"], p["rotary_dim"], p["tables"], p["head_tile"])
+            for p in ropes} == {(72, 128, 2, 24), (8, 128, 2, 8),
+                                (48, 64, 3, 24), (8, 64, 3, 8)}
+    # three float32 tables over the sequence where two were
+    assert {p["table_bytes"] for p in ropes} == {2 * seq * 128 * 4,
+                                                 3 * seq * 128 * 4}
