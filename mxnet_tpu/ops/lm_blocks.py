@@ -12,7 +12,13 @@ their own (docs/observability.md "Spans and device scopes").
 
 Which path runs where.  The routed layer's grouped products are the
 installed JAX's megablox kernels where the program is lowered for the TPU
-and XLA's ragged dot on every other platform (`_product`).  The gated short
+and XLA's ragged dot on every other platform (`_product`); its combine, the
+sum of each token's pairs, is one pass over the pair buffer's rows in token
+order (`_combine_rows`: a row gather, then the Mosaic kernel
+``mx_moe_combine``) where the program is lowered for the TPU and
+`_combine_plan` gives tiles (one device, 2-byte rows of whole 128-lane
+tiles, and a bounded buffer that `_sum_pairs` would gather `COMBINE_RATIO`
+times over), and `_sum_pairs`, a row gather a choice, everywhere else.  The gated short
 convolution's middle (`_gate`: the gates and the depthwise causal taps
 between its two projections) is a pair of Mosaic kernels of this module,
 ``mx_shortconv_fwd`` and ``mx_shortconv_bwd``, where the program is lowered
@@ -1471,14 +1477,19 @@ def _expert_hidden(grouped, xs, w1, w3, sizes):
 # Rows of the pair buffer beyond the pairs that exist belong to no group.
 # The grouped products neither read nor write them (the kernels leave
 # them uninitialised, XLA's ragged dot zeroes them), so nothing below may
-# use such a row as a number: whatever is gathered back per token is
-# selected, not multiplied, by whether the pair exists.
+# use such a row as a number.  `_sum_pairs` gathers a row a choice and
+# selects it by whether the pair exists.  `_combine_rows` multiplies: a
+# token's row of its 0/1 (or weight) matrix times a chunk of rows, and 0
+# times what is not a number is not 0, so the kernel first selects every
+# row of a chunk past the last existing pair to 0, and only then takes
+# the product.
 
 def _sum_pairs(rows, place, exists, weights=None):
     """For each token, the sum over its pairs that exist of ``rows[place
     of the pair]`` (times the pair's weight), in float32.  One row gather
     a choice: a ``(tokens, k, d)`` array would be laid out with its k
-    padded to a whole sublane tile."""
+    padded to a whole sublane tile.  The body every platform but the TPU
+    takes, the worst-case buffer's, and what `_combine_rows` is held to."""
     total = 0.0
     for k in range(place.shape[1]):
         row = jnp.where(exists[:, k, None],
@@ -1497,6 +1508,208 @@ def _places(inverse, sizes, top_k):
     return jnp.where(exists, place, 0), exists
 
 
+#: the combine's pass over the pairs that exist (`_combine_rows`): (tokens
+#: a tile, buffer rows a chunk), and the least ``tokens x top_k /
+#: buffer_rows`` (the rows `_sum_pairs` gathers over the rows that can
+#: hold a pair) from which the pass is taken; from `tools/moe_sweep.py
+#: --combine` on the v5e (docs/PERF_NOTES.md, PR 46): a layer's two
+#: directions take 2.28 ms where the gathers take 3.61 at 5.33 (16384
+#: tokens x 8 over 24576 rows), 0.49 for 1.42 at 8192 x 6 over 9216, 0.13
+#: for 1.49 at 20, and 2.26 for 2.05 at 2.67 (16384 x 4 over the same
+#: 24576 rows, which the gathers read from on-chip memory).  Not options:
+#: the sweep sets them to compare
+COMBINE_TILES = (128, 128)
+COMBINE_RATIO = 4.0
+
+#: a token-ordered row's code is its token times this, plus its choice
+_COMBINE_CHOICES = 16
+
+
+def _combine_plan(tokens, top_k, rows, d, dtype):
+    """``(tiles, None)`` where the combine takes `_combine_rows` at a pair
+    buffer of *rows* rows, ``(None, why not)`` where it stays `_sum_pairs`:
+    the kernel takes 2-byte rows of whole 128-lane tiles on one device,
+    the tokens and the buffer in whole tiles, and pays where `_sum_pairs`
+    gathers `COMBINE_RATIO` rows or more for each row that can hold a
+    pair (at the worst case's buffer that is 1: every pair can exist)."""
+    tile, chunk = COMBINE_TILES
+    if tokens * top_k < COMBINE_RATIO * rows:
+        return None, "%d x %d pairs gathered for %d rows is %.2f, under " \
+            "%g: the gather a choice reads no more" % (
+                tokens, top_k, rows, tokens * top_k / rows, COMBINE_RATIO)
+    if jnp.dtype(dtype).itemsize != 2 or d % 128:
+        return None, "not 2-byte rows of whole 128-lane tiles"
+    if top_k > _COMBINE_CHOICES:
+        return None, "%d experts a token, over the %d a row's code holds" % (
+            top_k, _COMBINE_CHOICES)
+    if tokens % tile or rows % chunk:
+        return None, "%d tokens and %d rows are not whole tiles of %d " \
+            "and %d" % (tokens, rows, tile, chunk)
+    if not _one_device():
+        # XLA does not partition a Mosaic kernel, and no cell spans chips
+        return None, "a mesh of several devices"
+    return COMBINE_TILES, None
+
+
+def _token_order(order, inverse, sizes, top_k, rows, tiles):
+    """The pair buffer's *rows* rows in token order, and the work list of
+    `_combine_rows` over them, as int32 arrays: integer work on ``tokens x
+    top_k`` elements, once a layer for both directions.
+
+    A pair's id is ``token * top_k + choice``, so the pairs that exist,
+    sorted by id, are sorted by token: ``perm`` is the buffer row of the
+    j-th of them (a permutation of the buffer's rows: those that hold no
+    pair come last) and ``code`` says whose it is, ``token *
+    _COMBINE_CHOICES + choice``, a chunk a row of it; a row with no pair
+    has the token after the last, which no tile holds.  A tile of tokens
+    owns a contiguous run of the existing pairs; it reads the whole chunks
+    that cover its run, one work item a chunk and one for a tile with no
+    pair: ``(tile, chunk, flags)`` of each item, tiles in order (flags: 1
+    the tile's first item, 2 a chunk to add, 4 its last), the items past
+    the last tile's doing nothing.  At most a chunk a tile is read twice,
+    so tiles + chunks items always do.  Last, the buffer rows those chunks
+    cover (`moe_combine_rows_read_total`)."""
+    tile, chunk = tiles
+    pairs = inverse.shape[0]
+    n_tiles, n_chunks = pairs // top_k // tile, rows // chunk
+    total = jnp.sum(sizes).astype(jnp.int32)
+    at = jnp.arange(rows, dtype=jnp.int32)
+    pair, perm = jax.lax.sort(
+        (jnp.where(at < total, order[:rows], pairs), at), num_keys=1)
+    code = (pair // top_k * _COMBINE_CHOICES + pair % top_k).reshape(
+        n_chunks, 1, chunk)
+    held = jnp.sum((inverse < total).reshape(n_tiles, -1), axis=1,
+                   dtype=jnp.int32)
+    end = jnp.cumsum(held)
+    first = (end - held) // chunk
+    count = jnp.where(held > 0, -(-end // chunk) - first, 0)
+    items = jnp.maximum(count, 1)
+    before = jnp.cumsum(items) - items
+    k = jnp.arange(n_tiles + n_chunks, dtype=jnp.int32)
+    of = jnp.sum(before[None, :] <= k[:, None], axis=1, dtype=jnp.int32) - 1
+    nth = k - before[of]
+    live = nth < count[of]
+    flags = (nth == 0) + 2 * live + 4 * (nth == items[of] - 1)
+    # an item that adds nothing stays on the chunk before it: no fetch
+    reads = jax.lax.cummax(jnp.where(live, first[of] + nth, 0))
+    return (perm, code, of, reads, flags.astype(jnp.int32),
+            total[None]), chunk * jnp.sum(count)
+
+
+def _combine_kernel(tile_ref, chunk_ref, flag_ref, total_ref, code_ref,
+                    *refs):
+    """One work item of `_token_order`: a chunk of the token-ordered rows
+    into its tile's float32 accumulator, as (token of the tile x row of
+    the chunk) times the chunk on the MXU.  The matrix holds 1 where the
+    row is that token's, or with *weights* (the tile's ``(tokens, k)``)
+    the weight of the token's choice that the row is, as three bfloat16
+    pieces in three passes: a piece times a bfloat16 row is exact in
+    float32, so the weight is applied and the sum held in float32.  Rows
+    past the last existing pair are selected to 0 first (they are not
+    numbers).  *refs*: the weights (forward), the chunk, the tile out, the
+    accumulator."""
+    *weights, rows_ref, out_ref, acc_ref = refs
+    k = pl.program_id(0)
+    flags = flag_ref[k]
+    tile, chunk = out_ref.shape[0], rows_ref.shape[0]
+
+    @pl.when(flags & 1 != 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def add(past):
+        """The chunk into the accumulator, its last *past* rows (those
+        beyond the last existing pair) selected to 0 first."""
+        code = code_ref[0]
+        mine = code // _COMBINE_CHOICES - tile_ref[k] * tile == \
+            jax.lax.broadcasted_iota(jnp.int32, (tile, chunk), 0)
+        rows = rows_ref[...]
+        if past is not None:
+            row = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+            rows = jnp.where(row < chunk - past, rows, jnp.zeros_like(rows))
+        if not weights:
+            acc_ref[...] += jnp.dot(mine.astype(rows.dtype), rows,
+                                    preferred_element_type=jnp.float32)
+            return
+        choice, rest = code % _COMBINE_CHOICES, 0.0
+        for c in range(weights[0].shape[1]):
+            rest = rest + jnp.where(mine & (choice == c),
+                                    weights[0][:, c:c + 1], 0.0)
+        pieces = []
+        for _ in range(3):
+            pieces.append(rest.astype(rows.dtype))
+            rest = rest - pieces[-1].astype(jnp.float32)
+        # one on top of the other: the MXU loads a block of the chunk
+        # once for the three
+        part = jnp.dot(jnp.concatenate(pieces, axis=0), rows,
+                       preferred_element_type=jnp.float32)
+        acc_ref[...] += part[:tile] + part[tile:2 * tile] + part[2 * tile:]
+
+    # rows of this chunk past the last existing pair (one chunk has any)
+    past = (chunk_ref[k] + 1) * chunk - total_ref[0]
+
+    @pl.when((flags & 2 != 0) & (past <= 0))
+    def _():
+        add(None)
+
+    @pl.when((flags & 2 != 0) & (past > 0))
+    def _():
+        add(past)
+
+    @pl.when(flags & 4 != 0)
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _combine_rows(rows, run, *weights, tokens, tiles, interpret=False):
+    """`_sum_pairs` over the pairs that exist, each read once: the buffer's
+    *rows* gathered into token order (one row gather; *run* is
+    `_token_order`'s), then the Mosaic kernel ``mx_moe_combine`` over the
+    work list, each tile of tokens written once, in the rows' dtype.  With
+    *weights* ``(tokens, top_k)`` in float32 a pair's row times its weight
+    (the forward pass); without, the plain sum (the backward pass)."""
+    perm, code, of, reads, flags, total = run
+    (tile, chunk), d = tiles, rows.shape[1]
+    by_tile = [pl.BlockSpec((tile, w.shape[1]), lambda k, of, *_: (of[k], 0))
+               for w in weights]
+    return pl.pallas_call(
+        _combine_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(of.shape[0],),
+            in_specs=[pl.BlockSpec((1, 1, chunk), lambda k, of, reads, *_: (
+                reads[k], 0, 0))] + by_tile + [pl.BlockSpec(
+                    (chunk, d), lambda k, of, reads, *_: (reads[k], 0))],
+            out_specs=pl.BlockSpec(
+                (tile, d), lambda k, of, *_: (of[k], 0)),
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="mx_moe_combine",
+    )(of, reads, flags, total, code, *weights,
+      # a permutation: every index is a row of the buffer, once
+      rows.at[perm].get(mode="promise_in_bounds", unique_indices=True))
+
+
+def _combine(combine, rows, top_k, inverse, sizes, run, *weights):
+    """For each token the sum over its pairs that exist of their *rows*
+    (times the pair's weight with *weights*), held in float32 and rounded
+    once to the rows' dtype: `_combine_rows` at the tiles *combine* where
+    the program is lowered for the TPU, `_sum_pairs` on every other
+    platform, and where `_combine_plan` gave no tiles."""
+    def gathers(rows, *weights):
+        return _sum_pairs(rows, *_places(inverse, sizes, top_k),
+                          *weights).astype(rows.dtype)
+
+    if combine is None:
+        return gathers(rows, *weights)
+    return jax.lax.platform_dependent(
+        rows, run, *weights,
+        tpu=functools.partial(_combine_rows, tiles=combine,
+                              tokens=inverse.shape[0] // top_k),
+        default=lambda rows, run, *weights: gathers(rows, *weights))
+
+
 def _buffer_rows(tokens, top_k, held, router_experts):
     """Rows of the pair buffer: `BUFFER_FACTOR` times the pairs uniform
     routing lands on the held experts, in whole row tiles, and never more
@@ -1508,14 +1721,14 @@ def _buffer_rows(tokens, top_k, held, router_experts):
     return min(worst, -(-expected // tile) * tile)
 
 
-def _at_buffer(rows, body, order, sizes, *rest):
-    """``body(n, grouped, order, sizes, *rest)`` at a pair buffer of ``n``
-    = *rows* rows where the pairs that exist fit them, and at the worst
-    case's where they do not: the same pairs in the same groups from row
-    0 either way, so nothing is dropped.  Where *rows* is the worst case
-    there is one path and no `cond`.  Called from inside the custom VJP's
-    two sides, so JAX differentiates neither branch and each keeps its
-    temporaries to itself.
+def _at_buffer(rows, combine, body, order, sizes, *rest):
+    """``body(n, grouped, combine, order, sizes, *rest)`` at a pair buffer
+    of ``n`` = *rows* rows where the pairs that exist fit them, and at the
+    worst case's where they do not: the same pairs in the same groups from
+    row 0 either way, so nothing is dropped.  Where *rows* is the worst
+    case there is one path and no `cond`.  Called from inside the custom
+    VJP's two sides, so JAX differentiates neither branch and each keeps
+    its temporaries to itself.
 
     The worst-case branch of a `cond` takes XLA's ragged dot on every
     platform: with the kernels in both branches a step holds twice the
@@ -1523,25 +1736,31 @@ def _at_buffer(rows, body, order, sizes, *rest):
     cost a set-up 3 s of 31 where no step of the benchmark ever runs
     them (PERF.md section 6, PR 27).  A step that overflows takes half
     as long again in this layer and sums in XLA's order, not the
-    kernels'."""
+    kernels'.  For the same reason, and because at the worst case every
+    pair can exist, that branch combines by `_sum_pairs` (*combine*, the
+    tiles of `_combine_plan` at *rows*, is the bounded branch's alone)."""
     worst, grouped = order.shape[0], (GROUPED_PATH, GROUPED_TILES)
     if rows == worst:
-        return body(worst, grouped, order, sizes, *rest)
+        return body(worst, grouped, combine, order, sizes, *rest)
     return jax.lax.cond(
-        jnp.sum(sizes) <= rows, functools.partial(body, rows, grouped),
-        functools.partial(body, worst, ("ragged", None)), order, sizes,
-        *rest)
+        jnp.sum(sizes) <= rows,
+        functools.partial(body, rows, grouped, combine),
+        functools.partial(body, worst, ("ragged", None), None), order,
+        sizes, *rest)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _experts(rows, x, weights, order, inverse, sizes, w1, w3, w2):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts(rows, combine, x, weights, order, inverse, sizes, run, w1, w3,
+             w2):
     """The held experts' part of the result for tokens *x* ``(N, d)``
     with pair weights *weights* ``(N, k)``: *order* lists the pairs
     (token * k + choice) sorted by held expert, those on no held expert
     last; *inverse* is where each pair landed; *sizes* the pairs of each
-    held expert; *rows* the pair buffer's rows (`_buffer_rows`)."""
-    return _experts_fwd(rows, x, weights, order, inverse, sizes, w1, w3,
-                        w2)[0]
+    held expert; *rows* the pair buffer's rows (`_buffer_rows`); *combine*
+    the tiles of `_combine_plan` with *run* from `_token_order`, or None
+    and ()."""
+    return _experts_fwd(rows, combine, x, weights, order, inverse, sizes,
+                        run, w1, w3, w2)[0]
 
 
 # The two bodies are jitted, so a step's routed layers of one shape share
@@ -1549,8 +1768,9 @@ def _experts(rows, x, weights, order, inverse, sizes, w1, w3, w2):
 # 3.7% faster: XLA places the same operations otherwise (PERF.md section
 # 6, PR 27)
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _forward_at(n, grouped, order, sizes, x, weights, inverse, w1, w3, w2):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _forward_at(n, grouped, combine, order, sizes, x, weights, inverse, run,
+                w1, w3, w2):
     top_k = weights.shape[1]
     with jax.named_scope("mx.moe.dispatch"):
         xs = jnp.take(x, order[:n] // top_k, axis=0)
@@ -1558,20 +1778,19 @@ def _forward_at(n, grouped, order, sizes, x, weights, inverse, w1, w3, w2):
         a = _expert_hidden(grouped, xs, w1, w3, sizes)[2]
         y = _product(grouped, a, w2, sizes)
     with jax.named_scope("mx.moe.combine"):
-        place, exists = _places(inverse, sizes, top_k)
-        out = _sum_pairs(y, place, exists, weights)
-    return out.astype(x.dtype)
+        return _combine(combine, y, top_k, inverse, sizes, run, weights)
 
 
-def _experts_fwd(rows, x, weights, order, inverse, sizes, w1, w3, w2):
-    out = _at_buffer(rows, _forward_at, order, sizes, x, weights, inverse,
-                     w1, w3, w2)
-    return out, (x, weights, order, inverse, sizes, w1, w3, w2)
+def _experts_fwd(rows, combine, x, weights, order, inverse, sizes, run, w1,
+                 w3, w2):
+    out = _at_buffer(rows, combine, _forward_at, order, sizes, x, weights,
+                     inverse, run, w1, w3, w2)
+    return out, (x, weights, order, inverse, sizes, run, w1, w3, w2)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _backward_at(n, grouped, order, sizes, x, weights, inverse, w1, w3, w2,
-                 dout):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _backward_at(n, grouped, combine, order, sizes, x, weights, inverse,
+                 run, w1, w3, w2, dout):
     top_k = weights.shape[1]
     order = order[:n]
     w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
@@ -1594,24 +1813,27 @@ def _backward_at(n, grouped, order, sizes, x, weights, inverse, w1, w3, w2,
         dg = (da * h32 * sig).astype(x.dtype)
         dw1 = _product_t(grouped, xs, dh, sizes, w1.dtype)
         dw3 = _product_t(grouped, xs, dg, sizes, w3.dtype)
-        dxs = (_product(grouped, dh, w1, sizes, True).astype(jnp.float32)
-               + _product(grouped, dg, w3, sizes, True).astype(jnp.float32)
-               ).astype(x.dtype)
+        by_w1 = _product(grouped, dh, w1, sizes, True)
+        by_w3 = _product(grouped, dg, w3, sizes, True)
     with jax.named_scope("mx.moe.combine"):
+        # the two products' sum, rounded once: formed here, a row once,
+        # whichever way the rows then reach their tokens
+        dxs = (by_w1.astype(jnp.float32) + by_w3.astype(jnp.float32)
+               ).astype(x.dtype)
+        dx = _combine(combine, dxs, top_k, inverse, sizes, run)
         place, exists = _places(inverse, sizes, top_k)
-        dx = _sum_pairs(dxs, place, exists).astype(x.dtype)
         dweights = jnp.where(exists, jnp.take(dw_sorted[:, 0], place), 0)
     return dx, dweights.astype(weights.dtype), dw1, dw3, dw2
 
 
-def _experts_bwd(rows, res, dout):
+def _experts_bwd(rows, combine, res, dout):
     """The experts' hidden states are computed again and not kept: they
     are the layer's largest arrays."""
-    x, weights, order, inverse, sizes, w1, w3, w2 = res
+    x, weights, order, inverse, sizes, run, w1, w3, w2 = res
     dx, dweights, dw1, dw3, dw2 = _at_buffer(
-        rows, _backward_at, order, sizes, x, weights, inverse, w1, w3, w2,
-        dout)
-    return dx, dweights, None, None, None, dw1, dw3, dw2
+        rows, combine, _backward_at, order, sizes, x, weights, inverse, run,
+        w1, w3, w2, dout)
+    return dx, dweights, None, None, None, None, dw1, dw3, dw2
 
 
 _experts.defvjp(_experts_fwd, _experts_bwd)
@@ -1635,9 +1857,10 @@ def _sort_pairs(chosen, first_expert, held):
 
 
 def _record_moe_plan(tokens, router_experts, top_k, held, first_expert,
-                     rows, dtype, hidden, expert_hidden):
+                     rows, dtype, hidden, expert_hidden, combine, why):
     """One `mx.moe.plan` span each time the op is traced (as
-    `mx.flash.plan`: the plan is a fact of the compiled program)."""
+    `mx.flash.plan`: the plan is a fact of the compiled program).
+    *combine* and *why* are `_combine_plan`'s."""
     with profiler.scope(  # graftlint: disable=JG003
             "mx.moe.plan", "moe") as span:
         span.args = {
@@ -1655,7 +1878,13 @@ def _record_moe_plan(tokens, router_experts, top_k, held, first_expert,
             "dtype": jnp.dtype(dtype).name, "path": GROUPED_PATH,
             "tiles": None if GROUPED_PATH != "megablox" else {
                 "up": _tiles(GROUPED_TILES, rows, hidden, expert_hidden),
-                "down": _tiles(GROUPED_TILES, rows, expert_hidden, hidden)}}
+                "down": _tiles(GROUPED_TILES, rows, expert_hidden, hidden)},
+            # how a token's pairs come back to it at the bounded buffer
+            # (the worst case's branch gathers a choice at a time)
+            "combine": {
+                "path": "xla" if combine is None else "kernel", "why": why,
+                "token_tile": combine and combine[0],
+                "chunk_rows": combine and combine[1]}}
 
 
 @register_op("_contrib_RoutedExperts", aliases=("RoutedExperts",))
@@ -1680,11 +1909,21 @@ def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
     experts would add is left out.  No capacity and no dropped token:
     token-expert pairs are sorted by expert into a buffer of
     `_buffer_rows` rows, the three products run over the groups that
-    exist, and each token gathers its pairs back; a step with more pairs
-    than rows takes the worst case's buffer (every choice of every token
-    held here) through the same code (`_at_buffer`).  The counts of
-    `routed_expert_counts` leave through
-    `profiler.emit_step_stat` under ``moe_expert_counts``.
+    exist, and each token's pairs come back to it summed (`_combine`):
+    where `_combine_plan` gives tiles and the program is lowered for the
+    TPU, the pairs that exist are read once each, the buffer's rows
+    gathered into token order (a pair's id is token x k + choice, so the
+    existing pairs sorted by id are sorted by token: `_token_order`) and
+    each tile of tokens summing its contiguous run of them on the MXU
+    (`_combine_rows`); everywhere else each token gathers a row a choice
+    and selects the ones that exist (`_sum_pairs`).  A buffer row past the
+    last pair is not a number, so it is selected away before either sum.
+    A step with more pairs than rows takes the worst case's buffer (every
+    choice of every token held here) through the same code, with the
+    ragged dot and the gather a choice (`_at_buffer`).  The counts of
+    `routed_expert_counts` leave through `profiler.emit_step_stat` under
+    ``moe_expert_counts``, the buffer rows the combine read under
+    ``moe_combine_rows``.
     """
     lead, d = data.shape[:-1], data.shape[-1]
     x = data.reshape(-1, d)
@@ -1692,8 +1931,9 @@ def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
     top_k, first = int(num_experts_per_tok), int(first_expert)
     bias = tuple(expert_bias) or (0.0,) * router_experts
     rows = _buffer_rows(x.shape[0], top_k, held, router_experts)
+    combine, why = _combine_plan(x.shape[0], top_k, rows, d, data.dtype)
     _record_moe_plan(x.shape[0], router_experts, top_k, held, first, rows,
-                     data.dtype, d, w1.shape[2])
+                     data.dtype, d, w1.shape[2], combine, why)
     with jax.named_scope("mx.moe.route"):
         chosen, weights = _route(x, router_weight, bias, top_k,
                                  bool(norm_topk_prob),
@@ -1705,11 +1945,29 @@ def _routed_experts(data, router_weight, w1, w3, w2, expert_bias=(),
             "moe_expert_counts",
             routed_expert_counts(chosen, router_experts, first, held, rows))
         order, inverse, sizes = _sort_pairs(chosen, first, held)
-    out = _experts(rows, x, weights, order, inverse, sizes, w1, w3, w2)
+    run, read = (), chosen.size
+    if combine:
+        with jax.named_scope("mx.moe.combine"):
+            run, read = _token_order(order, inverse, sizes, top_k, rows,
+                                     combine)
+            # a step with more pairs than rows gathers a choice at a time
+            read = jnp.where(jnp.sum(sizes) <= rows, read, chosen.size)
+    profiler.emit_step_stat(  # graftlint: disable=JG003
+        "moe_combine_rows", jnp.asarray(read, jnp.int32))
+    out = _experts(rows, combine, x, weights, order, inverse, sizes, run, w1,
+                   w3, w2)
     return out.reshape(*lead, d)
 
 
+def _fold_combine_rows(values):
+    """One step's values (one per routed layer): the buffer rows the
+    combine read, to set beside `moe_local_assignments_total`."""
+    profiler.bump_counter("moe_combine_rows_read_total",
+                          int(np.asarray(values, np.int64).sum()))
+
+
 profiler.register_step_stat("moe_expert_counts", _fold_expert_counts)
+profiler.register_step_stat("moe_combine_rows", _fold_combine_rows)
 
 
 from .registry import get_op as _get_op  # noqa: E402

@@ -18,8 +18,19 @@ to 6e-6 of its norm (XLA tiles a float32 row reduction by the buffer's
 shape); the overflowing call agrees as the ragged dot does with the
 kernels, to some 0.5% of a norm.
 
+With ``--combine`` it times the combine alone instead (PERF.md section 6,
+PR 46), both directions, at the five routed cells' (tokens, top-k, width,
+pair buffer) under random even routing: `_sum_pairs`' gather a choice
+against `_combine_rows`' one pass over the rows in token order (with and
+without `_token_order`'s integer work, which a layer does once for both
+directions) at several (token tile, chunk rows), every row of the buffer
+past the last pair set to NaN, each result checked against `_sum_pairs`'
+in float32.
+`COMBINE_TILES` and `COMBINE_RATIO` in ops/lm_blocks.py, and the table in
+docs/PERF_NOTES.md, come from it.
+
     python tools/moe_sweep.py [--tokens 16384] [--iters 5]
-                              [--default-only | --buffer-only]
+                              [--default-only | --buffer-only | --combine]
 
 Timing: each call is jitted, run once to compile, then *iters* times to a
 `block_until_ready`; the best and the median are printed.  Needs the chip
@@ -43,6 +54,180 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 TILINGS = ((512, 1024), (256, 1024), (512, 512), (128, 128))
 
 
+#: the routed cells' (tokens a step, experts a token, width, experts held,
+#: router's experts): SDAR's is Keye's too; then LFM2's with a third and a
+#: half of the experts held, between its ratio and the worst case's 1, to
+#: place `COMBINE_RATIO`
+COMBINE_CELLS = {"laguna": (4096, 10, 3072, 8, 256),
+                 "sdar": (16384, 8, 2048, 16, 128),
+                 "kanana": (8192, 6, 2048, 16, 128),
+                 "lfm2": (16384, 4, 2048, 8, 32),
+                 "a-third-held": (16384, 4, 2048, 8, 24),
+                 "a-half-held": (16384, 4, 2048, 8, 16)}
+#: (token tile, chunk rows) of `_combine_rows`
+COMBINE_TILINGS = ((128, 128), (256, 128), (256, 256))
+
+
+def traced(calls, iters):
+    """``{label: (result, device ms a call, [[operation family, ms a
+    call], ... the six longest])}`` for *calls* ``{label: (fn, args)}``,
+    each compiled and run once, then *iters* times under one profiler
+    trace."""
+    import tempfile
+
+    import jax
+    from benchmarks import trace
+
+    fns = {k: jax.jit(f) for k, (f, _) in calls.items()}
+    outs = {k: jax.block_until_ready(fns[k](*calls[k][1]))
+            for k in calls}
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp)
+        for k, (_, a) in calls.items():
+            with jax.profiler.TraceAnnotation(trace.SPAN_PREFIX + k):
+                for _ in range(iters):
+                    last = fns[k](*a)
+                jax.block_until_ready(last)
+        jax.profiler.stop_trace()
+        events = trace.load_events(trace.find_xplane(tmp))
+    ops = [e for e in events if trace.DEVICE_PLANE.match(e["plane"])
+           and not trace._WRAPPERS.match(trace.op_family(e["name"]))]
+    found = {}
+    for e in events:
+        if not e["name"].startswith(trace.SPAN_PREFIX):
+            continue
+        lo, hi = e["start_ns"], e["start_ns"] + e["dur_ns"]
+        by = {}
+        for op in ops:
+            if lo <= op["start_ns"] < hi:
+                fam = trace.op_family(op["name"])
+                by[fam] = by.get(fam, 0) + op["dur_ns"]
+        k = 1e-6 / iters
+        found[e["name"][len(trace.SPAN_PREFIX):]] = (
+            round(sum(by.values()) * k, 4),
+            [[f, round(v * k, 4)] for f, v in sorted(
+                by.items(), key=lambda kv: -kv[1])[:6]])
+    return {k: (outs[k],) + found[k] for k in calls}
+
+
+def combine_sweep(args):
+    """The combine alone at `COMBINE_CELLS`: one JSON row a cell, direction
+    and body, device ms a call from a profiler trace of *iters* calls (the
+    operations that ran between a call group's first dispatch and its last
+    result; a host clock around calls of under a millisecond reads the
+    dispatch), and the operations that took most of it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.ops import lm_blocks as lb
+
+    def off(got, want, scale):
+        """The largest error over what a rounding of the result's dtype
+        and a float32 rounding of a token's summed magnitudes allow (1 or
+        less is equal but for the addends' order), NaN counted as 1e9."""
+        got = np.asarray(got.astype(jnp.float32))
+        bound = 2.0 ** -8 * np.abs(want) + 2.0 ** -21 * scale + 1e-30
+        return float(np.nan_to_num(np.abs(got - want) / bound,
+                                   nan=1e9).max())
+
+    dev = jax.devices()[0]
+    print("moe_sweep: platform=%s kind=%r" % (dev.platform, dev.device_kind),
+          flush=True)
+    bf, rows_out = jnp.bfloat16, []
+    for cell, (tokens, top_k, d, held, router) in COMBINE_CELLS.items():
+        rng = np.random.default_rng(0)
+        chosen = jnp.asarray(rng.random((tokens, router)).argsort(
+            1)[:, :top_k], jnp.int32)
+        order, inverse, sizes = lb._sort_pairs(chosen, 0, held)
+        n = lb._buffer_rows(tokens, top_k, held, router)
+        pairs = int(jnp.sum(sizes))
+        assert pairs <= n
+        past = jnp.arange(n)[:, None] >= pairs
+
+        def buffer(seed):
+            return jnp.where(past, jnp.nan, jax.random.normal(
+                jax.random.PRNGKey(seed), (n, d), jnp.float32)).astype(bf)
+
+        y, by_w1, by_w3 = buffer(1), buffer(2), buffer(3)
+        weights = jax.random.uniform(jax.random.PRNGKey(4), (tokens, top_k),
+                                     jnp.float32, 0.05, 1.0)
+        head = {"cell": cell, "tokens": tokens, "top_k": top_k, "d": d,
+                "buffer_rows": n, "pairs": pairs,
+                "gathered_per_buffer_row": round(tokens * top_k / n, 2)}
+
+        def summed(by_w1, by_w3):
+            return (by_w1.astype(jnp.float32) + by_w3.astype(jnp.float32)
+                    ).astype(bf)
+
+        def places():
+            return lb._places(inverse, sizes, top_k)
+
+        def old_fwd(y, weights):
+            return lb._sum_pairs(y, *places(), weights).astype(bf)
+
+        def old_bwd(by_w1, by_w3):
+            return lb._sum_pairs(summed(by_w1, by_w3), *places()).astype(bf)
+
+        # float32 references, and each token's summed magnitudes
+        want = {"fwd": jax.jit(lambda: (
+            lb._sum_pairs(y, *places(), weights),
+            lb._sum_pairs(jnp.abs(y), *places(), weights)))(),
+            "bwd": jax.jit(lambda: (
+                lb._sum_pairs(summed(by_w1, by_w3), *places()),
+                lb._sum_pairs(jnp.abs(summed(by_w1, by_w3)), *places())))()}
+        want = {k: [np.asarray(a) for a in v] for k, v in want.items()}
+        calls = {"fwd sum_pairs": (old_fwd, (y, weights)),
+                 "bwd sum_pairs": (old_bwd, (by_w1, by_w3))}
+        reads = {}
+        for tile, chunk in COMBINE_TILINGS:
+            if tokens % tile or n % chunk:
+                continue
+            tiles = (tile, chunk)
+            # a rehearsal off the chip runs the kernel interpreted
+            kw = dict(tokens=tokens, tiles=tiles,
+                      interpret=dev.platform != "tpu")
+
+            def plan(order, inverse, sizes, tiles=tiles):
+                return lb._token_order(order, inverse, sizes, top_k, n,
+                                       tiles)
+
+            def new_fwd(run, y, weights, kw=kw):
+                return lb._combine_rows(y, run, weights, **kw)
+
+            def new_bwd(run, by_w1, by_w3, kw=kw):
+                return lb._combine_rows(summed(by_w1, by_w3), run, **kw)
+
+            def layer(order, inverse, sizes, y, weights, by_w1, by_w3,
+                      plan=plan, new_fwd=new_fwd, new_bwd=new_bwd):
+                # what a layer pays: the integer work once, a pass each way
+                run, _ = plan(order, inverse, sizes)
+                return new_fwd(run, y, weights), new_bwd(run, by_w1, by_w3)
+
+            run, read = jax.jit(plan)(order, inverse, sizes)
+            name = "combine_rows %dx%d" % (tile, chunk)
+            reads[name] = round(int(read) / pairs, 3)
+            calls.update({
+                "order " + name: (plan, (order, inverse, sizes)),
+                "fwd " + name: (new_fwd, (run, y, weights)),
+                "bwd " + name: (new_bwd, (run, by_w1, by_w3)),
+                "order+fwd+bwd " + name: (layer, (
+                    order, inverse, sizes, y, weights, by_w1, by_w3))})
+        for label, (got, ms, ops) in traced(calls, args.iters).items():
+            way, body = label.split(" ", 1)
+            row = dict(head, way=way, body=body, device_ms=ms, ops=ops)
+            if body in reads:
+                row["rows_read_per_pair"] = reads[body]
+                if way in want:
+                    row["off"] = round(off(got, *want[way]), 3)
+            rows_out.append(row)
+            print("moe_sweep: combine " + json.dumps(row), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join("chiprun_out", "moe_combine_sweep.json"),
+              "w") as fh:
+        json.dump(rows_out, fh, indent=1)
+    return 0
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--tokens", type=int, default=16384)
@@ -57,7 +242,12 @@ def main(argv=None):
                     help="the ragged dot and GROUPED_TILES as they stand")
     ap.add_argument("--buffer-only", action="store_true",
                     help="the pair buffer's four rows and no tiling")
+    ap.add_argument("--combine", action="store_true",
+                    help="the combine alone at the five routed cells' "
+                    "shapes, and nothing else")
     args = ap.parse_args(argv)
+    if args.combine:
+        return combine_sweep(args)
 
     import jax
     import jax.numpy as jnp
