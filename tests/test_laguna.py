@@ -295,9 +295,10 @@ def test_the_plan_span_carries_the_window():
         assert args[kernel]["tiles_visited"] == args[kernel]["tiles_needed"]
 
 
-#: commit d96fd39's kernels at the five language cells' shapes, as text
+#: the parent's kernels at the six language cells' shapes, as text
 #: (`jaxpr_sha`), forward then backward: causal (OPT, LFM2, Kanana), with a
-#: selection operand (Keye), under the block-diffusion mask (SDAR)
+#: selection operand (Keye), under the block-diffusion mask (SDAR), all five
+#: commit d96fd39's, and through a window (Laguna), commit c1cc16f's
 PARENT_KERNELS = {
     "opt-1.3b_train_1chip": (
         "c90fe3b230b0e563445ae24e8e0b1cb912c1359ba1d4c947bb5f6fa50e8d19b0",
@@ -314,12 +315,16 @@ PARENT_KERNELS = {
     "sdar-30b-a3b-chat_train_ep8share": (
         "3e1fe5d3cf180766c1f59efde4a8cb60631cc621b615c4c8a8d16bfb188dc0e2",
         "089ec1634b8d48e9a02d3127156436715c85f7cdc1707b2f90f7c547eac9aa5e"),
+    "laguna-s-2.1_train_ep32share": (
+        "86abead6355761858da04e2575e36b9c0123d0a3223c2765b7b002b143e25346",
+        "3e6ba351529d227cfe1370eb803efe6330eef81bfc0fddeca79b45660136614d"),
 }
 SHAPES = {"opt-1.3b_train_1chip": (2048, 64, 64),
           "lfm2-8b-a1b_train_ep4share": (8192, 64, 64),
           "kanana-2-30b-a3b_train_ep8share": (8192, 192, 128),
           "keye-vl-2.0-30b-a3b_train_ep8share": (16384, 128, 128),
-          "sdar-30b-a3b-chat_train_ep8share": (16384, 128, 128)}
+          "sdar-30b-a3b-chat_train_ep8share": (16384, 128, 128),
+          "laguna-s-2.1_train_ep32share": (4096, 128, 128)}
 
 
 def jaxpr_sha(fn, *avals):
@@ -330,10 +335,10 @@ def jaxpr_sha(fn, *avals):
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 @pytest.mark.parametrize("cell", sorted(PARENT_KERNELS))
 def test_the_language_cells_kernels_trace_as_the_parent_s(cell, kernel):
-    """The window is one more static description: the causal, selected and
-    block-diffusion calls at the five language cells' shapes are commit
-    d96fd39's to the letter (a JAX that prints jaxprs another way re-pins
-    them)."""
+    """A change to how a mask is described reorders Python, not the program:
+    the causal, selected, block-diffusion and window calls at the six
+    language cells' shapes are the parent's to the letter (a JAX that prints
+    jaxprs another way re-pins them)."""
     s, d, d_v = SHAPES[cell]
     q = jax.ShapeDtypeStruct((1, 2, s, d), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((1, 2, s, d_v), jnp.bfloat16)
@@ -344,6 +349,8 @@ def test_the_language_cells_kernels_trace_as_the_parent_s(cell, kernel):
         avals = (jax.ShapeDtypeStruct((1, s // 32, s), jnp.int32),)
     elif cell.startswith("sdar"):
         causal, extra = False, {"mask": attention.BlockDiffusion(4, s // 2)}
+    elif cell.startswith("laguna"):
+        extra = {"mask": attention.Window(512)}
 
     def sel(rest):
         return dict(extra, sel=rest[0]) if rest else extra
