@@ -21,7 +21,7 @@ mesh (``mxnet_tpu.parallel.sequence``).  This module provides:
   spans one head's whole sequence (f32 partials a K/V block in HBM where
   that would not fit).  Both kernels share one tile plan
   (``_flash_plan``): resident blocks of Q and K/V a grid step, score
-  sub-tiles walked in loops bounded by the causal limit.
+  sub-tiles walked in the loops the call's mask description gives.
 - ``_contrib_DotProductAttention`` / ``_contrib_div_sqrt_dim`` registered
   operators, so the op is reachable from mx.nd / mx.sym like any other.
 
@@ -35,6 +35,8 @@ from the one width to the other.
 from __future__ import annotations
 
 import collections
+import contextlib
+import dataclasses
 import functools
 import math
 
@@ -49,7 +51,7 @@ from ._precision import matmul_precision
 from .registry import register_op
 
 __all__ = ["flash_attention", "attention_reference", "BlockDiffusion",
-           "Window", "block_diffusion_visible"]
+           "Window"]
 
 _NEG_INF = -1e30
 # Inside a kernel the per-row softmax state (running max, denominator)
@@ -84,80 +86,529 @@ _UNROLL = 4
 _UNROLL_WHOLE = 8
 
 
-#: the block-diffusion mask, a static description: the sequence is two
-#: copies of *half* positions, a clean one then a noised one, in blocks of
-#: *block*.  With ``n(j) = j >= half`` and ``b(j) = (j mod half) // block``
-#: query ``j`` sees key ``s`` where both are clean and ``b(s) <= b(j)``,
-#: or the query is noised and the key clean and ``b(s) < b(j)``, or both
-#: are noised and ``b(s) == b(j)`` (BD3-LM, arXiv:2503.09573, section 5).
-#: Every query sees a key; ``half * (half + block)`` pairs are visible.
-BlockDiffusion = collections.namedtuple("BlockDiffusion", "block half")
+# ---------------------------------------------------------------------------
+# Mask descriptions.  Which query sees which key is one static object, a
+# frozen dataclass that compares and hashes by value: it is a static
+# argument of the two jitted wrappers and of `_flash`, so every layer of a
+# model that gives the same description shares one traced kernel body.
+# `_described` turns what a caller gives (`causal`, and a `Window` beside it
+# or a `BlockDiffusion` in its place) into one of `Full`, `Causal`, `Window`,
+# `BlockDiffusion`; everything below this section calls the description and
+# never asks which one it holds.
+#
+# What an instance supplies (`Full` has every one; a kind overrides what it
+# changes):
+#   checked       whether it describes these sequences, and as what
+#   visible       whether a pair is visible, from positions, by definition:
+#                 `attention_reference`, `_chunked_attention` and the tests'
+#                 brute force read this and nothing else
+#   pairs         how many pairs are visible (`tiles_ideal`, the op's stat)
+#   parts         the equal parts the sequence is padded in
+#   k_runs/q_runs the forward's loops for a query sub-tile over a resident
+#                 key block and the backward's for a key sub-tile over a
+#                 resident query block, as runs ``(lo, hi, body)`` of tiles:
+#                 *body* None where every pair of every tile is visible, else
+#                 the terms `tile_mask` takes (`_PLAIN`: none of its own)
+#   end_to_end    which of `_run`'s two ways the runs go (the programs
+#                 differ, and only the chip can judge one for all: ROADMAP
+#                 D16)
+#   tile_mask     a masked tile's body, from iotas
+#   k_block/q_block  the resident block an index map takes at a grid step:
+#                 the step's own where it holds a visible pair, else the
+#                 nearest that does, so that an empty step refetches nothing
+#   plan_says/plan_counts  what `mx.flash.plan` says of it
+#   stat/scope    the op's step stat and device scope
+# What it never touches: the kernels, the wrappers, the plan, the block
+# specs, the counts, the two `jax.numpy` bodies, the op.  A selection (the
+# operand of `selected_attention`) is no description: it goes beside a
+# `Causal` one, the runs are told once (`_Frame.selected`) that every
+# visited tile then runs a body, and the bits are ANDed there.
+# ---------------------------------------------------------------------------
 
-#: a causal window, a static description that goes WITH ``causal``: query
-#: ``t`` sees the *keys* keys that end at its own position, ``t - keys < s
-#: <= t`` (sequence ends aligned, as `causal` alone has them).  A row of
-#: ``keys`` or more positions sees ``keys`` pairs, an earlier one all it
-#: has: ``keys * S - keys * (keys - 1) / 2`` pairs over ``S`` positions.
-Window = collections.namedtuple("Window", "keys")
+# The loop bounds below run on Python ints (the plan's counts) and on the
+# kernels' traced scalars alike; numerators are clamped at 0 first, so
+# the division is the same truncating one on both.
+def _imax(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
 
 
-def _checked_mask(mask, causal, sq, sk):
-    """*mask* as a `BlockDiffusion` or a `Window` of ints, or None (no mask
-    beside `causal`, which a window that leaves every causal key visible
-    is); raises where it does not describe these sequences."""
-    if mask is None:
-        return None
-    if isinstance(mask, Window):
-        keys = int(mask.keys)
-        if not causal or keys < 1:
+def _imin(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.minimum(a, b)
+
+
+def _idiv(a, b):
+    return a // b if isinstance(a, int) else jax.lax.div(a, jnp.int32(b))
+
+
+def _sel(which, a, b):
+    """*a* where *which* is 1, *b* where it is 0 (ints or traced)."""
+    return which * a + (1 - which) * b
+
+
+def _below(a, b):
+    """1 where *a* < *b*, else 0 (ints or traced)."""
+    if isinstance(a, int) and isinstance(b, int):
+        return int(a < b)
+    return (a < b).astype(jnp.int32)
+
+
+def _and(mask, other):
+    return other if mask is None else mask & other
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+class _Frame(collections.namedtuple(
+        "_Frame", "t seq_q seq_k pad_q pad_k selected")):
+    """What a description's loops and bodies are asked about: one kernel's
+    tiles *t* over *seq_q* queries and *seq_k* keys padded to *pad_q* and
+    *pad_k*, sequence ends aligned (decode-style cross-length causal).
+    With a selection operand (*selected*) every visited tile runs a body."""
+    __slots__ = ()
+
+    @property
+    def off(self):
+        return self.seq_k - self.seq_q
+
+    @property
+    def padded_k(self):
+        return self.pad_k != self.seq_k
+
+
+#: the terms of a mask body that takes none of its own
+_PLAIN = ()
+
+
+@dataclasses.dataclass(frozen=True)
+class Full:
+    """Every query sees every key: the rectangle, which only the padding
+    crosses."""
+    causal = False
+    parts = 1
+    end_to_end = False
+    stat = scope = None
+
+    def checked(self, causal, sq, sk):
+        """This description as it stands over *sq* queries and *sk* keys;
+        raises where it describes no such sequences."""
+        return self
+
+    def visible(self, q_pos, k_pos):
+        """Whether query position *q_pos* (in the keys' positions: ends
+        aligned) sees key position *k_pos*; the two broadcast."""
+        return True
+
+    def pairs(self, sq, sk):
+        """Visible query-key pairs of a head."""
+        return sq * sk
+
+    def plan_says(self):
+        return {}
+
+    def plan_counts(self, t, sq, sk):
+        """What a kernel's record in the span holds beside its visits."""
+        return {}
+
+    def _k_bounds(self, g, row0, k0, n):
+        return n, n
+
+    def _q_bounds(self, g, col0, q0, n):
+        return 0, 0
+
+    def _k_tiles(self, g, row0, k0, n):
+        """``(n_full, n_vis)`` for the query sub-tile whose first row is
+        *row0*, over the *n* key sub-tiles of the resident block that
+        starts at column *k0*: tiles ``[0, n_vis)`` hold a visible score,
+        and the first ``n_full`` of them nothing else (neither the diagonal
+        nor the padding crosses them, and no selection is given)."""
+        n_full, n_vis = self._k_bounds(g, row0, k0, n)
+        n_real = _idiv(_imax(g.seq_k - k0, 0), g.t.sub_k)
+        n_full = _imin(_imin(n_full, n_real), n_vis)
+        return (0 if g.selected else n_full), n_vis
+
+    def _q_tiles(self, g, col0, q0, n):
+        """``(j_first, j_full)`` for the key sub-tile whose first column is
+        *col0*, over the *n* query sub-tiles of the resident block that
+        starts at row *q0*: tiles ``[j_first, n)`` hold a visible score, and
+        from ``j_full`` on nothing else."""
+        j_first, j_full = self._q_bounds(g, col0, q0, n)
+        padded = col0 + g.t.sub_k > g.seq_k
+        if isinstance(padded, bool):
+            j_full = n if padded else j_full
+        else:
+            j_full = jnp.where(padded, n, j_full)
+        return j_first, (n if g.selected else j_full)
+
+    def _bodied(self, g, runs):
+        """*runs* without those under a body where no tile can need one: no
+        diagonal, no padding, no selection."""
+        return runs if self.causal or g.padded_k or g.selected else tuple(
+            run for run in runs if run[2] is None)
+
+    def k_runs(self, g, row0, k0, n):
+        n_full, n_vis = self._k_tiles(g, row0, k0, n)
+        return self._bodied(g, ((0, n_full, None), (n_full, n_vis, _PLAIN)))
+
+    def q_runs(self, g, col0, q0, n):
+        j_first, j_full = self._q_tiles(g, col0, q0, n)
+        return self._bodied(g, ((j_first, j_full, _PLAIN), (j_full, n, None)))
+
+    def tile_mask(self, g, shape, q_axis, row0, col0, body):
+        """Visibility of the score tile whose first query row is *row0* and
+        first key column *col0* (query rows along *q_axis*) in a run under
+        *body*: the padding's term and the definition's."""
+        k_pos = col0 + _iota(shape, 1 - q_axis)
+        seen = k_pos < g.seq_k if g.padded_k else None
+        if not self.causal:
+            return seen
+        return _and(seen, self.visible(
+            row0 + g.off + _iota(shape, q_axis), k_pos))
+
+    def k_block(self, g, nkr, iq, ik):
+        """The key-side block of forward grid step (*iq*, *ik*) of *nkr*."""
+        return ik
+
+    def q_block(self, g, nqr, ik, iq):
+        """The query-side block of backward grid step (*ik*, *iq*)."""
+        return iq
+
+
+@dataclasses.dataclass(frozen=True)
+class Causal(Full):
+    """Query ``t`` sees the keys up to its own position, ``s <= t``."""
+    causal = True
+
+    def visible(self, q_pos, k_pos):
+        return k_pos <= q_pos
+
+    def pairs(self, sq, sk):
+        off = sk - sq
+        lo, hi = max(0, -off), sq            # rows that see a key
+        return (hi - lo) * (lo + off + hi + off + 1) // 2 if hi > lo else 0
+
+    def _k_bounds(self, g, row0, k0, n):
+        t = g.t
+        n_vis = _imin(n, _idiv(
+            _imax(row0 + t.sub_q + g.off - k0, 0) + t.sub_k - 1, t.sub_k))
+        return _idiv(_imax(row0 + g.off + 1 - k0, 0), t.sub_k), n_vis
+
+    def _q_bounds(self, g, col0, q0, n):
+        t = g.t
+        return (_imin(n, _idiv(_imax(col0 - g.off - q0, 0), t.sub_q)),
+                _imin(n, _idiv(
+                    _imax(col0 + t.sub_k - 1 - g.off - q0, 0) + t.sub_q - 1,
+                    t.sub_q)))
+
+    def _last_k_block(self, g, nkr, iq):
+        """The last resident key block a query block *iq* sees."""
+        t = g.t
+        return _imin(nkr - 1, _idiv(
+            _imax(iq * t.res_q + t.res_q - 1 + g.off, 0), t.res_k))
+
+    def _first_q_block(self, g, nqr, ik):
+        """The first resident query block that sees key block *ik*."""
+        return _imin(nqr - 1, _idiv(
+            _imax(ik * g.t.res_k - g.off, 0), g.t.res_q))
+
+    # a step above the diagonal is empty (its loops run no tile)
+    def k_block(self, g, nkr, iq, ik):
+        return jnp.minimum(ik, self._last_k_block(g, nkr, iq)) \
+            if nkr > 1 else ik
+
+    def q_block(self, g, nqr, ik, iq):
+        return jnp.maximum(iq, self._first_q_block(g, nqr, ik)) \
+            if nqr > 1 else iq
+
+
+@dataclasses.dataclass(frozen=True)
+class Window(Causal):
+    """A causal window, given WITH ``causal``: query ``t`` sees the *keys*
+    keys that end at its own position, ``t - keys < s <= t``.  A row of
+    ``keys`` or more positions sees ``keys`` pairs, an earlier one all it
+    has: ``keys * S - keys * (keys - 1) / 2`` pairs over ``S`` positions.
+
+    A query's keys are bounded from below as well: the forward's loop over
+    key sub-tiles starts where the window does and the backward's loop over
+    query sub-tiles ends where the last query that sees the key tile
+    stands, so a tile with no visible pair is not visited on either side.
+    The runs: the tiles the window's lower edge crosses, those every pair
+    of which is visible, those the diagonal (or the padding) crosses.  A
+    window narrower than a tile's two edges together leaves no tile whole:
+    one masked loop."""
+    keys: int
+    end_to_end = True
+    stat = "swa_visible_pairs"
+
+    def checked(self, causal, sq, sk):
+        if not causal or self.keys < 1:
             raise ValueError(
                 "a window bounds a causal query's keys from below: pass "
                 "causal=True and at least one key (got causal=%r, %d keys)"
-                % (bool(causal), keys))
-        return None if keys >= sk else Window(keys)
-    mask = BlockDiffusion(int(mask[0]), int(mask[1]))
-    if causal:
-        raise ValueError("a block-diffusion mask is not causal: pass one "
-                         "or the other")
-    if sq != sk or sq != 2 * mask.half or mask.block < 1 \
-            or mask.half % mask.block:
-        raise ValueError(
-            "a block-diffusion mask of two halves of %d in blocks of %d "
-            "does not describe %d queries and %d keys"
-            % (mask.half, mask.block, sq, sk))
-    return mask
+                % (bool(causal), self.keys))
+        # a window that leaves every causal key visible is no window
+        return Causal() if self.keys >= sk else self
+
+    def visible(self, q_pos, k_pos):
+        return super().visible(q_pos, k_pos) & (k_pos > q_pos - self.keys)
+
+    def pairs(self, sq, sk):
+        pos = np.arange(sq, dtype=np.int64) + (sk - sq)
+        return int(np.maximum(np.minimum(pos, sk - 1) - np.maximum(
+            pos - self.keys + 1, 0) + 1, 0).sum())
+
+    def plan_says(self):
+        return {"mask": "window", "window": self.keys}
+
+    def plan_counts(self, t, sq, sk):
+        """`tiles_needed`: the tiles of the real sequence that hold a
+        visible pair (a correct schedule visits those and no other), from
+        the window's definition a row of tiles at a time."""
+        off, needed = sk - sq, 0
+        for r0 in range(0, sq, t.sub_q):
+            lo = max(0, r0 + off - self.keys + 1)
+            hi = min(min(r0 + t.sub_q, sq) - 1 + off, sk - 1)
+            if hi >= lo:
+                needed += hi // t.sub_k - lo // t.sub_k + 1
+        return {"tiles_needed": needed}
+
+    def k_runs(self, g, row0, k0, n):
+        t, w = g.t, self.keys
+        n_full, n_vis = self._k_tiles(g, row0, k0, n)
+        lo = _imin(n_vis, _idiv(
+            _imax(row0 + g.off - w + 1 - k0, 0), t.sub_k))
+        if w < t.sub_q + t.sub_k - 1:
+            return ((lo, n_vis, _PLAIN),)
+        a = _imin(n_vis, _imax(lo, _idiv(
+            _imax(row0 + t.sub_q + g.off - w - k0, 0) + t.sub_k - 1,
+            t.sub_k)))
+        b = _imax(n_full, a)
+        return ((lo, a, _PLAIN), (a, b, None), (b, n_vis, _PLAIN))
+
+    def q_runs(self, g, col0, q0, n):
+        t, w = g.t, self.keys
+        j_first, j_full = self._q_tiles(g, col0, q0, n)
+        # the last query that sees the tile's last key stands w - 1 after it
+        j_end = _imax(j_first, _imin(n, _idiv(
+            _imax(col0 + t.sub_k + w - 2 - g.off - q0 + t.sub_q, 0),
+            t.sub_q)))
+        if w < t.sub_q + t.sub_k - 1:
+            return ((j_first, j_end, _PLAIN),)
+        a = _imin(j_full, j_end)
+        b = _imax(a, _imin(j_end, _idiv(
+            _imax(col0 + w - g.off - q0, 0), t.sub_q)))
+        return ((j_first, a, _PLAIN), (a, b, None), (b, j_end, _PLAIN))
+
+    # a step below the window takes the first block the query block sees, a
+    # step past the last row that sees the key block the last that does
+    def k_block(self, g, nkr, iq, ik):
+        first = _idiv(_imax(iq * g.t.res_q + g.off - self.keys + 1, 0),
+                      g.t.res_k)
+        return jnp.clip(ik, first, self._last_k_block(g, nkr, iq))
+
+    def q_block(self, g, nqr, ik, iq):
+        t = g.t
+        return jnp.clip(iq, self._first_q_block(g, nqr, ik), _imin(
+            nqr - 1, _idiv(_imax(
+                ik * t.res_k + t.res_k + self.keys - 2 - g.off, 0), t.res_q)))
 
 
-def block_diffusion_visible(q_pos, k_pos, mask):
-    """Whether query position *q_pos* sees key position *k_pos* under
-    *mask* (a `BlockDiffusion`), from its definition; the two broadcast."""
-    block, half = mask
-    qn, kn = q_pos >= half, k_pos >= half
-    qb = (q_pos - qn * half) // block
-    kb = (k_pos - kn * half) // block
-    return (~kn & ~qn & (kb <= qb)) | (~kn & qn & (kb < qb)) \
-        | (kn & qn & (kb == qb))
+# Under a `BlockDiffusion` both kernels see the sequence as two halves, each
+# padded to whole resident blocks: `_Halves` holds the block length, a
+# half's real length and its padded lengths along the queries and along the
+# keys.  Positions below are LOCAL to their half.  A real query never sees a
+# padded key, so the padding needs no term of its own; a padded query sees
+# what the last real one does.
+_Halves = collections.namedtuple("_Halves", "block real pad_q pad_k")
+#: a tile's mask body: the tile's keys are clean ones (*noised* 0), visible
+#: below the query's block start plus *extra* (the block length for a clean
+#: query, 0 for a noised one), or noised ones (*noised* 1), visible inside
+#: the query's block.  Ints, or traced where one loop runs tiles of several
+#: kinds (`_run`)
+_BdMask = collections.namedtuple("_BdMask", "noised extra")
 
 
-def window_visible(q_pos, k_pos, keys):
-    """Whether causal query position *q_pos* sees key position *k_pos*
-    through a window of *keys* keys, from its definition; the two
-    broadcast."""
-    return (k_pos <= q_pos) & (k_pos > q_pos - keys)
+def _bd_start(x, block):
+    """First position of the *block*-long block that holds position *x*."""
+    if block & (block - 1) == 0:
+        return x & -block
+    return _idiv(x, block) * block
 
 
-def window_pairs(sq, sk, keys):
-    """Visible query-key pairs a head of *sq* causal queries over *sk*
-    keys (ends aligned) through a window of *keys* keys."""
-    pos = np.arange(sq, dtype=np.int64) + (sk - sq)
-    return int(np.maximum(
-        np.minimum(pos, sk - 1) - np.maximum(pos - keys + 1, 0) + 1, 0).sum())
+def _bd_blocks(x0, n, hv):
+    """Block starts of the first and of the last real position among the
+    *n* from *x0* on (of the last real one of the half where all *n* are
+    padding)."""
+    return (_bd_start(_imin(x0, hv.real - 1), hv.block),
+            _bd_start(_imin(x0 + n, hv.real) - 1, hv.block))
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion(Full):
+    """The block-diffusion mask, given in ``causal``'s place: the sequence
+    is two copies of *half* positions, a clean one then a noised one, in
+    blocks of *block*.  With ``n(j) = j >= half`` and ``b(j) = (j mod half)
+    // block`` query ``j`` sees key ``s`` where both are clean and ``b(s) <=
+    b(j)``, or the query is noised and the key clean and ``b(s) < b(j)``, or
+    both are noised and ``b(s) == b(j)`` (BD3-LM, arXiv:2503.09573, section
+    5).  Every query sees a key; ``half * (half + block)`` pairs are
+    visible."""
+    block: int
+    half: int
+    parts = 2
+    end_to_end = True
+    stat = "bd_visible_pairs"
+    scope = "mx.bd.attention"
+
+    def checked(self, causal, sq, sk):
+        if causal:
+            raise ValueError("a block-diffusion mask is not causal: pass one "
+                             "or the other")
+        if sq != sk or sq != 2 * self.half or self.block < 1 \
+                or self.half % self.block:
+            raise ValueError(
+                "a block-diffusion mask of two halves of %d in blocks of %d "
+                "does not describe %d queries and %d keys"
+                % (self.half, self.block, sq, sk))
+        return self
+
+    def visible(self, q_pos, k_pos):
+        qn, kn = q_pos >= self.half, k_pos >= self.half
+        qb = (q_pos - qn * self.half) // self.block
+        kb = (k_pos - kn * self.half) // self.block
+        return (~kn & ~qn & (kb <= qb)) | (~kn & qn & (kb < qb)) \
+            | (kn & qn & (kb == qb))
+
+    def pairs(self, sq, sk):
+        return self.half * (self.half + self.block)
+
+    def plan_says(self):
+        return {"mask": "block_diffusion", "block": self.block,
+                "half": self.half}
+
+    def _halves(self, g):
+        return _Halves(self.block, self.half, g.pad_q // 2, g.pad_k // 2)
+
+    def k_runs(self, g, row0, k0, n):
+        """The clean keys every row of the tile sees, those a block
+        boundary crosses, and (a noised tile) the noised keys of its own
+        blocks."""
+        t, hv = g.t, self._halves(g)
+        qh = _idiv(row0, hv.pad_q)          # 0: clean queries, 1: noised ones
+        first, last = _bd_blocks(row0 - qh * hv.pad_q, t.sub_q, hv)
+        live = _below(row0 - qh * hv.pad_q, hv.real)    # 0: a tile of padding
+        extra = hv.block * (1 - qh)
+        base, half = _idiv(k0, t.sub_k), hv.pad_k // t.sub_k
+
+        def local(x):
+            return _imin(_imax(x - base, 0), n)
+
+        def up(x):
+            return _idiv(x + t.sub_k - 1, t.sub_k)
+
+        full = live * local(_idiv(first + extra, t.sub_k))
+        vis = live * local(up(last + extra))
+        lo = local(half + _idiv(first, t.sub_k))
+        hi = _sel(qh * live, local(half + up(last + hv.block)), lo)
+        return ((0, full, None), (full, vis, _BdMask(0, extra)),
+                (lo, hi, _BdMask(1, 0)))
+
+    def q_runs(self, g, col0, q0, n):
+        """Clean keys: the clean rows from the keys' first block on and the
+        noised rows from the block after, each masked until the keys' last
+        block is passed; noised keys: the noised rows of their own
+        blocks."""
+        t, hv = g.t, self._halves(g)
+        kh = _idiv(col0, hv.pad_k)          # 0: clean keys, 1: noised ones
+        first, last = _bd_blocks(col0 - kh * hv.pad_k, t.sub_k, hv)
+        live = _below(col0 - kh * hv.pad_k, hv.real)    # 0: a tile of padding
+        base, half = _idiv(q0, t.sub_q), hv.pad_q // t.sub_q
+        rows = -(-hv.real // t.sub_q)   # a half's tiles that hold a real row
+
+        def local(x):
+            return _imin(_imax(x - base, 0), n)
+
+        def up(x):
+            return _imin(_idiv(x + t.sub_q - 1, t.sub_q), rows)
+
+        clean, noised = (1 - kh) * live, kh * live
+        a_lo, a_full = _idiv(first, t.sub_q), up(last)
+        # (the keys' first block may be the half's last: no noised row then)
+        b_lo = _sel(_below(first + hv.block, hv.real),
+                    _idiv(first + hv.block, t.sub_q), rows)
+        b_full = _imax(up(last + hv.block), b_lo)
+        # on noised keys the first four are empty, on clean keys the last
+        return ((local(_sel(clean, a_lo, rows)),
+                 local(_sel(clean, a_full, rows)), _BdMask(0, hv.block)),
+                (local(_sel(clean, a_full, rows)), local(rows), None),
+                (local(half + _sel(clean, b_lo, rows)),
+                 local(half + _sel(clean, b_full, rows)), _BdMask(0, 0)),
+                (local(half + _sel(clean, b_full, rows)), local(half + rows),
+                 None),
+                (local(half + _sel(noised, a_lo, rows)),
+                 local(half + _sel(noised, b_full, rows)), _BdMask(1, 0)))
+
+    def tile_mask(self, g, shape, q_axis, row0, col0, body):
+        """From iotas, no operand: *row0* and *col0* are whole-sequence
+        positions, the tile's keys of the half and with the bound that
+        *body* (a `_BdMask`'s terms) names."""
+        hv, (noised, extra) = self._halves(g), body
+        q_loc = row0 - _idiv(row0, hv.pad_q) * hv.pad_q + _iota(shape, q_axis)
+        k_loc = col0 - noised * hv.pad_k + _iota(shape, 1 - q_axis)
+        start = _bd_start(q_loc, hv.block)
+        # clean keys: below the start plus `extra`; noised keys: from the
+        # start on, one block long
+        return (k_loc >= noised * start) \
+            & (k_loc < start + extra + noised * hv.block)
+
+    def k_block(self, g, nkr, iq, ik):
+        """A step past the query block's last clean block takes the nearest
+        noised block it visits (a clean query block's: its last clean block
+        again)."""
+        t, hv = g.t, self._halves(g)
+        qh = _idiv(iq * t.res_q, hv.pad_q)
+        first, last = _bd_blocks(iq * t.res_q - qh * hv.pad_q, t.res_q, hv)
+        c_last = _idiv(_imax(last + hv.block * (1 - qh) - 1, 0), t.res_k)
+        half = hv.pad_k // t.res_k
+        n_first = _sel(qh, half + _idiv(first, t.res_k), c_last)
+        n_last = _sel(qh, half + _idiv(last + hv.block - 1, t.res_k), c_last)
+        return jnp.where(ik <= c_last, ik, jnp.clip(ik, n_first, n_last))
+
+    def q_block(self, g, nqr, ik, iq):
+        """A step outside the rows that see the key block takes the nearest
+        inside: from the first clean one on, and from the first to the last
+        noised one."""
+        t, hv = g.t, self._halves(g)
+        kh = _idiv(ik * t.res_k, hv.pad_k)
+        first, last = _bd_blocks(ik * t.res_k - kh * hv.pad_k, t.res_k, hv)
+        half = hv.pad_q // t.res_q
+        a_lo = _idiv(first, t.res_q)
+        b_lo = _imin(_idiv(first + hv.block, t.res_q), half - 1)
+        a_first = _sel(kh, half, a_lo)
+        n_first = half + _sel(kh, a_lo, b_lo)
+        n_last = half + _sel(
+            kh, _idiv(last + hv.block - 1, t.res_q), half - 1)
+        return jnp.where((iq < nqr // 2) & (a_first < nqr // 2),
+                         jnp.maximum(iq, a_first),
+                         jnp.clip(iq, n_first, n_last))
+
+
+def _described(causal, mask, sq, sk):
+    """The description that `causal` and *mask* (None, a `Window` beside
+    `causal`, a `BlockDiffusion` in its place, or what this function
+    returned) make of *sq* queries over *sk* keys."""
+    if mask is None:
+        mask = Causal() if causal else Full()
+    return mask.checked(causal, sq, sk)
 
 
 def attention_reference(q, k, v, causal=False, sm_scale=None, mask=None):
-    """O(S^2)-memory einsum attention — the numeric oracle for tests.
-    *mask* is a `BlockDiffusion` (every row then sees a key), or with
-    `causal` a `Window`.
+    """O(S^2)-memory einsum attention — the numeric oracle for tests:
+    the softmax over the pairs that `causal` and *mask* (`_described`) call
+    visible.
 
     Degenerate-row convention (shared by all paths in this module): a
     causal query row that can see NO keys (seq_q > seq_k under the
@@ -171,26 +622,12 @@ def attention_reference(q, k, v, causal=False, sm_scale=None, mask=None):
                    k.astype(jnp.float32),
                    precision=matmul_precision(q.dtype, k.dtype)) \
         * sm_scale
-    mask = _checked_mask(mask, causal, s.shape[-2], s.shape[-1])
-    if isinstance(mask, Window):
-        qlen, klen = s.shape[-2], s.shape[-1]
-        seen = window_visible(
-            jnp.arange(qlen)[:, None] + (klen - qlen),
-            jnp.arange(klen)[None, :], mask.keys)
-        p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
-        p = p * seen.any(-1)[:, None]  # zero fully-masked rows
-    elif mask is not None:
-        seen = block_diffusion_visible(jnp.arange(s.shape[-2])[:, None],
-                                       jnp.arange(s.shape[-1])[None, :], mask)
-        p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
-    elif causal:
-        qlen, klen = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((qlen, klen), bool), klen - qlen)
-        s = jnp.where(mask, s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        p = p * mask.any(-1)[:, None]  # zero fully-masked rows
-    else:
-        p = jax.nn.softmax(s, axis=-1)
+    qlen, klen = s.shape[-2:]
+    seen = jnp.broadcast_to(_described(causal, mask, qlen, klen).visible(
+        jnp.arange(qlen)[:, None] + (klen - qlen),   # sequence ends aligned
+        jnp.arange(klen)[None, :]), (qlen, klen))
+    p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
+    p = p * seen.any(-1)[:, None]  # zero fully-masked rows
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32),
                       precision=matmul_precision(q.dtype, v.dtype)
                       ).astype(q.dtype)
@@ -233,8 +670,8 @@ def _finalize_softmax(o, m, l):
 
 def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512,
                        mask=None):
-    """Blockwise attention with online softmax over K chunks; *mask* a
-    `BlockDiffusion` in `causal`'s place, or a `Window` beside it.
+    """Blockwise attention with online softmax over K chunks, under the
+    description `causal` and *mask* make (`_described`).
 
     Memory is O(S_q * chunk) instead of O(S_q * S_k); the scan body is
     rematerialized on backward (jax.checkpoint), which is exactly the
@@ -244,6 +681,7 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512,
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
+    mask = _described(causal, mask, sq, sk)
     chunk = min(chunk, sk)
     nchunk = -(-sk // chunk)
     pad = nchunk * chunk - sk
@@ -266,20 +704,9 @@ def _chunked_attention(q, k, v, causal=False, sm_scale=None, chunk=512,
                        precision=matmul_precision(q.dtype, kb.dtype),
                        preferred_element_type=jnp.float32) * sm_scale
         k_pos = ci * chunk + jnp.arange(chunk)
-        valid = k_pos < sk
-        if isinstance(mask, Window):
-            valid = valid[None, :] & window_visible(
-                q_pos[:, None], k_pos[None, :], mask.keys)
-            s = jnp.where(valid[None, None], s, _NEG_INF)
-        elif mask is not None:
-            valid = valid[None, :] & block_diffusion_visible(
-                q_pos[:, None], k_pos[None, :], mask)
-            s = jnp.where(valid[None, None], s, _NEG_INF)
-        elif causal:
-            valid = valid[None, :] & (k_pos[None, :] <= q_pos[:, None])
-            s = jnp.where(valid[None, None], s, _NEG_INF)
-        else:
-            s = jnp.where(valid[None, None, None, :], s, _NEG_INF)
+        valid = (k_pos < sk)[None, :] & mask.visible(
+            q_pos[:, None], k_pos[None, :])
+        s = jnp.where(valid[None, None], s, _NEG_INF)
         o, m, l = _online_softmax_update(o, m, l, s, vb)
         return (o, m, l), None
 
@@ -524,391 +951,86 @@ def _flash_plan(sq, sk, d, dtype, blk_q=None, blk_k=None, res_q=None,
                  dq_accumulator)
 
 
-# The loop bounds below run on Python ints (the plan's counts) and on the
-# kernels' traced scalars alike; numerators are clamped at 0 first, so
-# the division is the same truncating one on both.
-def _imax(a, b):
-    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
-        else jnp.maximum(a, b)
-
-
-def _imin(a, b):
-    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
-        else jnp.minimum(a, b)
-
-
-def _idiv(a, b):
-    return a // b if isinstance(a, int) else jax.lax.div(a, jnp.int32(b))
-
-
-def _k_tiles(row0, k0, n, t, off, seq_k, causal):
-    """``(n_full, n_vis)`` for the query sub-tile whose first row is
-    *row0*, over the *n* key sub-tiles of the resident block that starts
-    at column *k0*: tiles ``[0, n_vis)`` hold a visible score, and the
-    first ``n_full`` of them nothing else (neither the diagonal nor the
-    padding crosses them).  *off* is ``seq_k - seq_q``: ends aligned."""
-    n_full = n_vis = n
-    if causal:
-        n_vis = _imin(n, _idiv(
-            _imax(row0 + t.sub_q + off - k0, 0) + t.sub_k - 1, t.sub_k))
-        n_full = _idiv(_imax(row0 + off + 1 - k0, 0), t.sub_k)
-    n_real = _idiv(_imax(seq_k - k0, 0), t.sub_k)
-    return _imin(_imin(n_full, n_real), n_vis), n_vis
-
-
-def _q_tiles(col0, q0, n, t, off, seq_k, causal):
-    """``(j_first, j_full)`` for the key sub-tile whose first column is
-    *col0*, over the *n* query sub-tiles of the resident block that
-    starts at row *q0*: tiles ``[j_first, n)`` hold a visible score, and
-    from ``j_full`` on nothing else."""
-    j_first = j_full = 0
-    if causal:
-        j_first = _imin(n, _idiv(_imax(col0 - off - q0, 0), t.sub_q))
-        j_full = _imin(n, _idiv(
-            _imax(col0 + t.sub_k - 1 - off - q0, 0) + t.sub_q - 1,
-            t.sub_q))
-    padded = col0 + t.sub_k > seq_k
-    if isinstance(padded, bool):
-        return j_first, (n if padded else j_full)
-    return j_first, jnp.where(padded, n, j_full)
-
-
-# Under a block-diffusion mask (`BlockDiffusion`) both kernels see the
-# sequence as two halves, each padded to whole resident blocks: `_Halves`
-# holds the block length, a half's real length and its padded lengths
-# along the queries and along the keys.  Positions below are LOCAL to
-# their half.  A real query never sees a padded key, so the padding needs
-# no term of its own; a padded query sees what the last real one does.
-_Halves = collections.namedtuple("_Halves", "block real pad_q pad_k")
-#: a tile's mask body: the tile's keys are clean ones (*noised* 0), visible
-#: below the query's block start plus *extra* (the block length for a clean
-#: query, 0 for a noised one), or noised ones (*noised* 1), visible inside
-#: the query's block.  Ints, or traced where one loop runs tiles of several
-#: kinds (`_run_segments`)
-_BdMask = collections.namedtuple("_BdMask", "noised extra")
-
-
-def _sel(which, a, b):
-    """*a* where *which* is 1, *b* where it is 0 (ints or traced)."""
-    return which * a + (1 - which) * b
-
-
-def _below(a, b):
-    """1 where *a* < *b*, else 0 (ints or traced)."""
-    if isinstance(a, int) and isinstance(b, int):
-        return int(a < b)
-    return (a < b).astype(jnp.int32)
-
-
-def _bd_start(x, block):
-    """First position of the *block*-long block that holds position *x*."""
-    if block & (block - 1) == 0:
-        return x & -block
-    return _idiv(x, block) * block
-
-
-def _bd_blocks(x0, n, hv):
-    """Block starts of the first and of the last real position among the
-    *n* from *x0* on (of the last real one of the half where all *n* are
-    padding)."""
-    return (_bd_start(_imin(x0, hv.real - 1), hv.block),
-            _bd_start(_imin(x0 + n, hv.real) - 1, hv.block))
-
-
-def _bd_k_segments(row0, k0, n, t, hv):
-    """The forward's loops for the query sub-tile whose first row is
-    *row0* over the *n* key sub-tiles of the resident block at column
-    *k0*, as ``(lo, hi, mask)``: the clean keys every row of the tile
-    sees, those a block boundary crosses, and (a noised tile) the noised
-    keys of its own blocks."""
-    qh = _idiv(row0, hv.pad_q)          # 0: clean queries, 1: noised ones
-    first, last = _bd_blocks(row0 - qh * hv.pad_q, t.sub_q, hv)
-    live = _below(row0 - qh * hv.pad_q, hv.real)    # 0: a tile of padding
-    extra = hv.block * (1 - qh)
-    base, half = _idiv(k0, t.sub_k), hv.pad_k // t.sub_k
-
-    def local(g):
-        return _imin(_imax(g - base, 0), n)
-
-    def up(x):
-        return _idiv(x + t.sub_k - 1, t.sub_k)
-
-    full = live * local(_idiv(first + extra, t.sub_k))
-    vis = live * local(up(last + extra))
-    lo = local(half + _idiv(first, t.sub_k))
-    hi = _sel(qh * live, local(half + up(last + hv.block)), lo)
-    return ((0, full, None), (full, vis, _BdMask(0, extra)),
-            (lo, hi, _BdMask(1, 0)))
-
-
-def _bd_q_segments(col0, q0, n, t, hv):
-    """The backward's loops for the key sub-tile whose first column is
-    *col0* over the *n* query sub-tiles of the resident block at row *q0*.
-    Clean keys: the clean rows from the keys' first block on and the
-    noised rows from the block after, each masked until the keys' last
-    block is passed; noised keys: the noised rows of their own blocks."""
-    kh = _idiv(col0, hv.pad_k)          # 0: clean keys, 1: noised ones
-    first, last = _bd_blocks(col0 - kh * hv.pad_k, t.sub_k, hv)
-    live = _below(col0 - kh * hv.pad_k, hv.real)    # 0: a tile of padding
-    base, half = _idiv(q0, t.sub_q), hv.pad_q // t.sub_q
-    rows = -(-hv.real // t.sub_q)       # a half's tiles that hold a real row
-
-    def local(g):
-        return _imin(_imax(g - base, 0), n)
-
-    def up(x):
-        return _imin(_idiv(x + t.sub_q - 1, t.sub_q), rows)
-
-    clean, noised = (1 - kh) * live, kh * live
-    a_lo, a_full = _idiv(first, t.sub_q), up(last)
-    # (the keys' first block may be the half's last: no noised row then)
-    b_lo = _sel(_below(first + hv.block, hv.real),
-                _idiv(first + hv.block, t.sub_q), rows)
-    b_full = _imax(up(last + hv.block), b_lo)
-    # on noised keys the first four are empty, on clean keys the last
-    return ((local(_sel(clean, a_lo, rows)), local(_sel(clean, a_full, rows)),
-             _BdMask(0, hv.block)),
-            (local(_sel(clean, a_full, rows)), local(rows), None),
-            (local(half + _sel(clean, b_lo, rows)),
-             local(half + _sel(clean, b_full, rows)), _BdMask(0, 0)),
-            (local(half + _sel(clean, b_full, rows)), local(half + rows),
-             None),
-            (local(half + _sel(noised, a_lo, rows)),
-             local(half + _sel(noised, b_full, rows)), _BdMask(1, 0)))
-
-
-def _bd_k_blocks(iq, t, hv):
-    """Resident key blocks a query block *iq* visits under the mask:
-    ``(last clean one, first noised one, last noised one)``; a clean
-    query block's noised pair is its last clean block twice."""
-    qh = _idiv(iq * t.res_q, hv.pad_q)
-    first, last = _bd_blocks(iq * t.res_q - qh * hv.pad_q, t.res_q, hv)
-    c_last = _idiv(_imax(last + hv.block * (1 - qh) - 1, 0), t.res_k)
-    half = hv.pad_k // t.res_k
-    return (c_last, _sel(qh, half + _idiv(first, t.res_k), c_last),
-            _sel(qh, half + _idiv(last + hv.block - 1, t.res_k), c_last))
-
-
-def _bd_q_blocks(ik, t, hv):
-    """Resident query blocks a key block *ik* is seen by: ``(first clean
-    one, first noised one, last noised one)``."""
-    kh = _idiv(ik * t.res_k, hv.pad_k)
-    first, last = _bd_blocks(ik * t.res_k - kh * hv.pad_k, t.res_k, hv)
-    half = hv.pad_q // t.res_q
-    a_lo = _idiv(first, t.res_q)
-    b_lo = _imin(_idiv(first + hv.block, t.res_q), half - 1)
-    return (_sel(kh, half, a_lo), half + _sel(kh, a_lo, b_lo),
-            half + _sel(kh, _idiv(last + hv.block - 1, t.res_q), half - 1))
-
-
-def _halves_of(mask, sq_p, sk_p):
-    return _Halves(mask.block, mask.half, sq_p // 2, sk_p // 2) \
-        if isinstance(mask, BlockDiffusion) else None
-
-
-def _parts(mask):
-    """Equal parts the sequence is padded in: a block-diffusion mask's two
-    copies, else one."""
-    return 2 if isinstance(mask, BlockDiffusion) else 1
-
-
-def _window_of(mask):
-    """The keys of a `Window`, or None."""
-    return mask.keys if isinstance(mask, Window) else None
-
-
-# Under a causal window of `w` keys a query's keys are bounded from below
-# as well: the forward's loop over key sub-tiles starts where the window
-# does and the backward's loop over query sub-tiles ends where the last
-# query that sees the key tile stands, so a tile with no visible pair is
-# not visited on either side.  The segments are `_run_segments`': the
-# tiles the window's lower edge crosses, those every pair of which is
-# visible, those the diagonal (or the padding) crosses.  A window narrower
-# than a tile's two edges together leaves no tile whole: one masked loop.
-
-def _window_k_segments(row0, k0, n, t, off, seq_k, w):
-    """The forward's loops for the query sub-tile whose first row is
-    *row0* over the *n* key sub-tiles of the resident block at column
-    *k0*, as ``(lo, hi, masked)``."""
-    n_full, n_vis = _k_tiles(row0, k0, n, t, off, seq_k, True)
-    lo = _imin(n_vis, _idiv(_imax(row0 + off - w + 1 - k0, 0), t.sub_k))
-    if w < t.sub_q + t.sub_k - 1:
-        return ((lo, n_vis, True),)
-    a = _imin(n_vis, _imax(lo, _idiv(
-        _imax(row0 + t.sub_q + off - w - k0, 0) + t.sub_k - 1, t.sub_k)))
-    b = _imax(n_full, a)
-    return ((lo, a, True), (a, b, None), (b, n_vis, True))
-
-
-def _window_q_segments(col0, q0, n, t, off, seq_k, w):
-    """The backward's loops for the key sub-tile whose first column is
-    *col0* over the *n* query sub-tiles of the resident block at row
-    *q0*."""
-    j_first, j_full = _q_tiles(col0, q0, n, t, off, seq_k, True)
-    # the last query that sees the tile's last key stands w - 1 after it
-    j_end = _imax(j_first, _imin(n, _idiv(
-        _imax(col0 + t.sub_k + w - 2 - off - q0 + t.sub_q, 0), t.sub_q)))
-    if w < t.sub_q + t.sub_k - 1:
-        return ((j_first, j_end, True),)
-    a = _imin(j_full, j_end)
-    b = _imax(a, _imin(j_end, _idiv(_imax(col0 + w - off - q0, 0), t.sub_q)))
-    return ((j_first, a, True), (a, b, None), (b, j_end, True))
-
-
-def _first_k_block(iq, t, off, w):
-    """The first resident key block a query block *iq* sees through a
-    window of *w* keys."""
-    return _idiv(_imax(iq * t.res_q + off - w + 1, 0), t.res_k)
-
-
-def _last_q_block(ik, t, nqr, off, w):
-    """The last resident query block that sees key block *ik* through a
-    window of *w* keys."""
-    return _imin(nqr - 1, _idiv(
-        _imax(ik * t.res_k + t.res_k + w - 2 - off, 0), t.res_q))
-
-
-def _last_k_block(iq, t, nkr, off):
-    """The last resident key block a causal query block *iq* sees."""
-    return _imin(nkr - 1, _idiv(
-        _imax(iq * t.res_q + t.res_q - 1 + off, 0), t.res_k))
-
-
-def _first_q_block(ik, t, nqr, off):
-    """The first resident query block that sees causal key block *ik*."""
-    return _imin(nqr - 1, _idiv(_imax(ik * t.res_k - off, 0), t.res_q))
-
-
-def _tile_counts(kernel, plan, sq, sk, causal, mask=None):
+def _tile_counts(kernel, plan, sq, sk, causal, mask=None, selected=False):
     """What the schedule of *kernel* visits, for one head: score tiles
-    computed, those of them computed under the mask, and the visible
-    scores in tiles (`tiles_ideal`: the causal triangle, the rectangle, or
-    what a block-diffusion *mask* leaves).  The same bound functions as the
-    kernels, on ints."""
+    computed, those of them computed under a mask body, and the visible
+    scores in tiles (`tiles_ideal`), with what the description adds
+    (`plan_counts`).  The kernels' own runs, on ints."""
+    mask = _described(causal, mask, sq, sk)
     t = getattr(plan, kernel)
-    sq_p, sk_p = (plan.sq_fwd, plan.sk_fwd) if kernel == "fwd" \
-        else (plan.sq_bwd, plan.sk_bwd)
-    off = sk - sq
-    hv, w = _halves_of(mask, sq_p, sk_p), _window_of(mask)
+    g = _Frame(t, sq, sk, *((plan.sq_fwd, plan.sk_fwd) if kernel == "fwd"
+                            else (plan.sq_bwd, plan.sk_bwd)), selected)
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
     visited = masked = 0
-    for q0 in range(0, sq_p, t.res_q):
-        for k0 in range(0, sk_p, t.res_k):
-            if hv is not None or w:
-                def of(j):
-                    if kernel == "bwd":
-                        at = (k0 + j * t.sub_k, q0, nqs, t)
-                        return _window_q_segments(*at, off, sk, w) if w \
-                            else _bd_q_segments(*at, hv)
-                    at = (q0 + j * t.sub_q, k0, nks, t)
-                    return _window_k_segments(*at, off, sk, w) if w \
-                        else _bd_k_segments(*at, hv)
-
-                segments = [seg for j in range(
-                    nks if kernel == "bwd" else nqs) for seg in of(j)]
-                visited += sum(max(hi - lo, 0) for lo, hi, _ in segments)
-                masked += sum(max(hi - lo, 0) for lo, hi, m in segments
-                              if m)
-            elif kernel == "bwd":
-                for jk in range(nks):
-                    first, full = _q_tiles(k0 + jk * t.sub_k, q0, nqs, t,
-                                           off, sk, causal)
-                    visited += nqs - first
-                    masked += full - first
+    for q0 in range(0, g.pad_q, t.res_q):
+        for k0 in range(0, g.pad_k, t.res_k):
+            if kernel == "bwd":
+                runs = [run for j in range(nks) for run in mask.q_runs(
+                    g, k0 + j * t.sub_k, q0, nqs)]
             else:
-                for jq in range(nqs):
-                    full, vis = _k_tiles(q0 + jq * t.sub_q, k0, nks, t,
-                                         off, sk, causal)
-                    visited += vis
-                    masked += vis - full
-    if w:
-        return _window_counts(t, sq, sk, w, visited, masked)
-    if mask is not None:
-        scores = mask.half * (mask.half + mask.block)
-    elif causal:
-        lo, hi = max(0, -off), sq            # rows that see a key
-        scores = (hi - lo) * (lo + off + hi + off + 1) // 2 if hi > lo \
-            else 0
-    else:
-        scores = sq * sk
-    return {"tiles_visited": visited, "tiles_masked": masked,
-            "tiles_ideal": round(scores / (t.sub_q * t.sub_k), 3)}
+                runs = [run for j in range(nqs) for run in mask.k_runs(
+                    g, q0 + j * t.sub_q, k0, nks)]
+            visited += sum(max(hi - lo, 0) for lo, hi, _ in runs)
+            masked += sum(max(hi - lo, 0) for lo, hi, body in runs
+                          if body is not None)
+    return dict(
+        tiles_visited=visited, tiles_masked=masked,
+        **mask.plan_counts(t, sq, sk),
+        tiles_ideal=round(mask.pairs(sq, sk) / (t.sub_q * t.sub_k), 3))
 
 
-def _window_counts(t, sq, sk, w, visited, masked):
-    """`_tile_counts`' record under a window of *w* keys: beside what the
-    loops visit, the tiles of the real sequence that hold a visible pair
-    (`tiles_needed`: a correct schedule visits those and no other), from
-    the window's definition a row of tiles at a time."""
-    off = sk - sq
-    needed = 0
-    for r0 in range(0, sq, t.sub_q):
-        lo = max(0, r0 + off - w + 1)
-        hi = min(min(r0 + t.sub_q, sq) - 1 + off, sk - 1)
-        if hi >= lo:
-            needed += hi // t.sub_k - lo // t.sub_k + 1
-    scores = window_pairs(sq, sk, w)
-    return {"tiles_visited": visited, "tiles_masked": masked,
-            "tiles_needed": needed,
-            "tiles_ideal": round(scores / (t.sub_q * t.sub_k), 3)}
-
-
-def _plan_args(plan, sq, sk, d, dtype, causal, d_v=None, mask=None):
+def _plan_args(plan, sq, sk, d, dtype, causal, d_v=None, mask=None,
+               selected=False):
     """The plan as the `mx.flash.plan` span carries it: static per shape,
-    so recorded where the call is traced, not where it runs."""
+    so recorded where the call is traced, not where it runs.  With a
+    selection operand (*selected*) the span says so: every visited tile is
+    then computed under a mask body, and the forward asks Mosaic for its
+    own `vmem_limit_bytes` too."""
+    mask = _described(causal, mask, sq, sk)
     rec = {"sq": sq, "sk": sk, "d": d, "dtype": jnp.dtype(dtype).name,
            "causal": bool(causal), "d_block": plan.d_block,
-           "d_v": d if d_v is None else d_v, "dv_block": plan.dv_block}
-    if isinstance(mask, Window):
-        rec.update(mask="window", window=mask.keys)
-    elif mask is not None:
-        rec.update(mask="block_diffusion", block=mask.block, half=mask.half)
+           "d_v": d if d_v is None else d_v, "dv_block": plan.dv_block,
+           **mask.plan_says()}
     itemsize = jnp.dtype(dtype).itemsize
     for kernel in _KERNELS:
         t = getattr(plan, kernel)
         rec[kernel] = dict(
-            _tile_counts(kernel, plan, sq, sk, causal, mask),
+            _tile_counts(kernel, plan, sq, sk, causal, mask, selected),
             resident=[t.res_q, t.res_k], sub_tile=[t.sub_q, t.sub_k],
             vmem_bytes=_vmem_bytes(kernel, plan, itemsize))
     rec["bwd"].update(dq_accumulator=plan.dq_accumulator,
                       vmem_limit_bytes=_vmem_limit(plan, itemsize))
+    if selected:
+        rec["selection"] = "bits"
+        for kernel in _KERNELS:
+            rec[kernel].update(
+                selection_block_bytes=_selection_bytes(getattr(plan, kernel)),
+                vmem_limit_bytes=_selected_vmem_limit(kernel, plan, itemsize))
     return rec
 
 
 def _record_plan(q, k, v, causal, selected=False, mask=None):
     """One `mx.flash.plan` span each time the op is traced.  At trace
     time on purpose: the plan is a fact of the compiled program, not of
-    a step.  With a selection operand (*selected*) the span says so: every
-    visited tile is then computed under the mask, and the forward asks
-    Mosaic for its own `vmem_limit_bytes` too.  Under a block-diffusion
-    *mask* it carries the mask's kind, block and half, under a `Window`
-    the kind, the window's keys and each kernel's `tiles_needed`."""
+    a step.  It carries what the description says of itself
+    (`plan_says`, `plan_counts`)."""
     from .. import profiler
     sq, sk, d, d_v = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     with profiler.scope(  # graftlint: disable=JG003
             "mx.flash.plan", "flash") as span:
-        plan = _flash_plan(sq, sk, d, q.dtype, d_v=d_v,
-                           halves=_parts(mask))
-        span.args = _plan_args(plan, sq, sk, d, q.dtype, causal, d_v, mask)
-        if selected:
-            itemsize = jnp.dtype(q.dtype).itemsize
-            span.args["selection"] = "bits"
-            for kernel in _KERNELS:
-                rec = span.args[kernel]
-                rec["tiles_masked"] = rec["tiles_visited"]
-                rec["selection_block_bytes"] = _selection_bytes(
-                    getattr(plan, kernel))
-                rec["vmem_limit_bytes"] = _selected_vmem_limit(
-                    kernel, plan, itemsize)
+        plan = _flash_plan(sq, sk, d, q.dtype, d_v=d_v, halves=_described(
+            causal, mask, sq, sk).parts)
+        span.args = _plan_args(plan, sq, sk, d, q.dtype, causal, d_v, mask,
+                               selected)
 
 
 # ---------------------------------------------------------------------------
 # Pallas flash kernels.  Shared conventions: operands enter the MXU in
 # their storage dtype and accumulate f32; the softmax state is f32; a
 # grid step holds resident blocks of Q and of K/V and loops over score
-# sub-tiles between bounds that come from the causal limit, so a tile
-# above the diagonal costs neither a grid step, a DMA nor a branch, and
-# only the tiles the diagonal or the padding crosses pay for the mask.
+# sub-tiles in the runs the mask description gives, so a tile with no
+# visible pair (above the diagonal) costs neither a grid step, a DMA nor a
+# branch, and only the tiles the mask or the padding crosses pay for it.
 # ---------------------------------------------------------------------------
 
 def _mxu_dot(a, b, contract):
@@ -942,44 +1064,6 @@ def _sub(j, size, count):
     if count == 1:
         return slice(0, size)
     return pl.ds(pl.multiple_of(j * size, size), size)
-
-
-def _tile_mask(shape, q_axis, row0, col0, off, seq_k, causal, padded_k,
-               window=None):
-    """Visibility of the score tile whose first query row is *row0* and
-    first key column *col0*; query rows run along *q_axis*.  Sequence
-    ends aligned (decode-style cross-length causal), the convention of
-    attention_reference and _chunked_attention.  With *window* a causal
-    query sees that many keys, its own the last."""
-    k_pos = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    mask = k_pos < seq_k if padded_k else None
-    if causal:
-        q_pos = row0 + off + jax.lax.broadcasted_iota(jnp.int32, shape,
-                                                      q_axis)
-        mask = k_pos <= q_pos if mask is None else mask & (k_pos <= q_pos)
-        if window:
-            mask = mask & (k_pos > q_pos - window)
-    return mask
-
-
-def _bd_tile_mask(shape, q_axis, row0, col0, hv, m):
-    """Visibility under a block-diffusion mask of the score tile at query
-    row *row0* and key column *col0* (whole-sequence positions; query rows
-    along *q_axis*), its keys of the half and with the bound *m* (a
-    `_BdMask`) names: from iotas, no operand."""
-    q_loc = row0 - _idiv(row0, hv.pad_q) * hv.pad_q \
-        + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
-    k_loc = col0 - m.noised * hv.pad_k \
-        + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    start = _bd_start(q_loc, hv.block)
-    # clean keys: below the start plus `extra`; noised keys: from the
-    # start on, one block long
-    return (k_loc >= m.noised * start) \
-        & (k_loc < start + m.extra + m.noised * hv.block)
-
-
-def _and(mask, other):
-    return other if mask is None else mask & other
 
 
 def _grid_pos(axis, n):
@@ -1016,40 +1100,34 @@ def _loop(lo, hi, body):
     jax.lax.fori_loop(lo + chunks * _UNROLL, hi, one, 0)
 
 
-def _two_loops(bounds, tile, any_masked):
-    """Run *tile* over ``[lo, mid)`` and ``[mid, hi)`` with the mask on
-    in the halves that *bounds* ``(lo, mid, hi, masked_first)`` says."""
-    lo, mid, hi, masked_first = bounds
-    for a, b, masked in ((lo, mid, masked_first),
-                         (mid, hi, not masked_first)):
-        if masked and not any_masked:
-            continue            # no diagonal and no padding: never runs
-        _loop(a, b, functools.partial(tile, masked=masked))
-
-
-def _run_segments(segments, tile):
-    """Run *tile* over each ``(lo, hi, mask)`` of *segments*, in two loops:
-    the tiles that need no mask body, then those that do, each loop over
-    its segments laid end to end with the tile's index and the mask's
-    bounds chosen by where the count stands.  A loop a segment is five
-    copies of the backward's tile body in each of ten loops a key
-    sub-tile, 71085 bundles where the causal kernel has 29182, and a tile
-    then took 3.6 times as long on the chip (PERF.md section 6, PR 39)."""
+def _run(runs, tile, end_to_end):
+    """Run *tile* over each ``(lo, hi, body)`` of *runs* (a description's
+    `k_runs` or `q_runs`), the way the description says: a loop a run, in
+    order; or (*end_to_end*) in two loops, the tiles that need no mask body,
+    then those that do, each loop over its runs laid end to end with the
+    tile's index and the body's terms chosen by where the count stands.  A
+    loop a run is five copies of the backward's tile body in each of ten
+    loops a key sub-tile under a block-diffusion mask, 71085 bundles where
+    the causal kernel has 29182, and a tile then took 3.6 times as long on
+    the chip (PERF.md section 6, PR 39)."""
+    if not end_to_end:
+        for lo, hi, body in runs:
+            _loop(lo, hi, functools.partial(tile, masked=body))
+        return
     for masked in (False, True):
-        group = [(lo, _imax(hi, lo), m) for lo, hi, m in segments
+        group = [(lo, _imax(hi, lo), m) for lo, hi, m in runs
                  if (m is not None) == masked]
         if not group:
             continue
 
-        def body(j, group=group, masked=masked):
+        def body(j, group=group):
             lo, end, m = group[0]
             at, end = lo + j, end - lo      # `end`: tiles up to here
             for lo2, hi2, m2 in group[1:]:
                 later = _below(end - 1, j)
                 at = _sel(later, lo2 + j - end, at)
-                if isinstance(m, _BdMask):
-                    m = _BdMask(_sel(later, m2.noised, m.noised),
-                                _sel(later, m2.extra, m.extra))
+                if m:
+                    m = tuple(_sel(later, b, a) for a, b in zip(m, m2))
                 end = end + hi2 - lo2
             tile(at, masked=m)
 
@@ -1069,27 +1147,23 @@ def _traced_inline(kernel):
 
 
 @_traced_inline
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
-                      seq_q, seq_k, padded_k, selected=False, hv=None,
-                      window=None):
-    # with *hv* (`_Halves`) the mask is the block-diffusion one: the loops'
-    # bounds and the mask bodies come from its geometry, not from `causal`
-    # with *window* a causal query sees that many keys: the loop over key
-    # tiles starts where the window does (`_window_k_segments`)
-    # with *selected* a selection block follows v: (1, res_q / 32, res_k)
-    # words, bit r % 32 of word r // 32 saying whether query row r of the
-    # block sees the key; every visited tile is then a masked one
-    sel_ref = rest[0] if selected else None
-    o_ref, *maybe_lse_and_scratch = rest[1:] if selected else rest
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, mask, g, grid, sm_scale):
+    # *mask* is the call's description and *g* its `_Frame`: the loops'
+    # runs and the mask bodies are the description's
+    # with a selection (`g.selected`) a selection block follows v: (1,
+    # res_q / 32, res_k) words, bit r % 32 of word r // 32 saying whether
+    # query row r of the block sees the key; every visited tile is then a
+    # masked one
+    sel_ref = rest[0] if g.selected else None
+    o_ref, *maybe_lse_and_scratch = rest[1:] if g.selected else rest
     if len(maybe_lse_and_scratch) == 4:
         lse_ref, acc_ref, m_ref, l_ref = maybe_lse_and_scratch
     else:  # inference path: no logsumexp output allocated
         lse_ref = None
         acc_ref, m_ref, l_ref = maybe_lse_and_scratch
-    nkr = grid[1]
+    t, nkr = g.t, grid[1]
     iq, ik = _grid_pos(1, grid[0]), _grid_pos(2, nkr)
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
-    off = seq_k - seq_q
     d = acc_ref.shape[1]                # the values' width, and the output's
 
     # a query tile's first and last steps sit in its own loop body, not
@@ -1110,17 +1184,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
             k = k_ref[0, ks, :]
             v = v_ref[0, ks, :]
             s = _mxu_dot(q, k, _NT) * sm_scale      # (sub_q, sub_k)
-            if masked:
+            if masked is not None:
                 col0 = ik * t.res_k + jk * t.sub_k
-                mask = _tile_mask(
-                    s.shape, 0, row0, col0, off, seq_k, causal, padded_k,
-                    window) \
-                    if hv is None else _bd_tile_mask(
-                        s.shape, 0, row0, col0, hv, masked)
-                if selected:
-                    mask = _and(mask, unpack_selection(sel_ref[
+                seen = mask.tile_mask(g, s.shape, 0, row0, col0, masked)
+                if sel_ref is not None:
+                    seen = _and(seen, unpack_selection(sel_ref[
                         0, _sub(jq, t.sub_q // _SEL_BITS, nqs), ks]))
-                s = jnp.where(mask, s, _NEG_INF)
+                s = jnp.where(seen, s, _NEG_INF)
             m_prev = m_ref[qs, :]                   # (sub_q, _LANES)
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -1135,19 +1205,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, t, grid, sm_scale, causal,
             acc_ref[qs, :] = acc_ref[qs, :] * _lanes_to(alpha, d) + \
                 _mxu_dot(p.astype(v.dtype), v, _NN)
 
-        if hv is not None:
-            _run_segments(_bd_k_segments(row0, ik * t.res_k, nks, t, hv),
-                          tile)
-        elif window:
-            _run_segments(_window_k_segments(
-                row0, ik * t.res_k, nks, t, off, seq_k, window), tile)
-        else:
-            n_full, n_vis = _k_tiles(row0, ik * t.res_k, nks, t, off, seq_k,
-                                     causal)
-            if selected:
-                n_full = 0
-            _two_loops((0, n_full, n_vis, False), tile,
-                       causal or padded_k or selected)
+        _run(mask.k_runs(g, row0, ik * t.res_k, nks), tile, mask.end_to_end)
 
         @pl.when(ik == nkr - 1)
         def _finish():
@@ -1226,30 +1284,6 @@ def _unpad_rows(x, s, halves=1):
         ..., :s // halves].reshape(bh, 1, s)
 
 
-def _k_index(t, nkr, off, causal, hv=None, window=None):
-    """Index map of the key-side blocks on a grid (bh, iq, ik).  Causal,
-    a step above the diagonal is empty (its loops run no tile): its
-    index is clamped to the last block the query block sees, so the
-    empty step refetches nothing.  Under a block-diffusion mask (*hv*) a
-    step past the query block's last clean block takes the index of the
-    nearest noised block it visits (a clean query block's: none).  Through
-    a *window* the blocks start where the window does: a step below it
-    takes the first block the query block sees."""
-    if window:
-        return lambda bh_, iq, ik: jnp.clip(
-            ik, _first_k_block(iq, t, off, window),
-            _last_k_block(iq, t, nkr, off))
-    if hv is not None:
-        def index(bh_, iq, ik):
-            c_last, n_first, n_last = _bd_k_blocks(iq, t, hv)
-            return jnp.where(ik <= c_last, ik, jnp.clip(ik, n_first, n_last))
-        return index
-    if causal and nkr > 1:
-        return lambda bh_, iq, ik: jnp.minimum(
-            ik, _last_k_block(iq, t, nkr, off))
-    return lambda bh_, iq, ik: ik
-
-
 def _block_specs(t, d_block, dv_block, q_index, k_index):
     """BlockSpecs of a resident query-side block and a key-side block at
     q's and k's width (q, dq; k, dk), of the same two at v's width (o, dO;
@@ -1285,33 +1319,32 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, blk_q=None, blk_k=None,
     as ``(B*H, 1, seq_q)``.  *sel* is a selection operand
     (`pack_selection` of a ``(B, seq_q, seq_k)`` mask, one for all the
     heads of a batch row): a query then sees a key only where its bit is
-    set, besides `causal` and the padding.  *mask* is a `BlockDiffusion`
-    in `causal`'s place, or a `Window` beside it."""
+    set, besides what `causal` and *mask* describe (`_described`) and the
+    padding."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
-    halves = _parts(mask)
+    mask = _described(causal, mask, sq, sk)
+    halves = mask.parts
     plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v,
                        halves)
     t, dp, dvp = plan.fwd, plan.d_block, plan.dv_block
     sq_p, sk_p = plan.sq_fwd, plan.sk_fwd
-    hv, window = _halves_of(mask, sq_p, sk_p), _window_of(mask)
+    g = _Frame(t, sq, sk, sq_p, sk_p, sel is not None)
     qp = _pad_bh(q, sq_p, dp, halves)
     kp = _pad_bh(k, sk_p, dp, halves)
     vp = _pad_bh(v, sk_p, dvp, halves)
     bh = b * h
     nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
 
+    def k_index(bh_, iq, ik):
+        return mask.k_block(g, nkr, iq, ik)
+
     q_spec, k_spec, o_spec, v_spec, row_spec = _block_specs(
-        t, dp, dvp, lambda bh_, iq, ik: iq,
-        _k_index(t, nkr, sk - sq, causal, hv, window))
-    kernel = functools.partial(
-        _flash_fwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
-        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk, hv=hv,
-        window=window)
+        t, dp, dvp, lambda bh_, iq, ik: iq, k_index)
+    kernel = functools.partial(_flash_fwd_kernel, mask=mask, g=g,
+                               grid=(nqr, nkr), sm_scale=sm_scale)
     in_specs, operands, params = [q_spec, k_spec, v_spec], (qp, kp, vp), {}
     if sel is not None:
-        k_index = _k_index(t, nkr, sk - sq, causal)
-        kernel = functools.partial(kernel, selected=True)
         in_specs.append(pl.BlockSpec(
             (1, t.res_q // _SEL_BITS, t.res_k),
             lambda bh_, iq, ik: (bh_ // h, iq, k_index(bh_, iq, ik))))
@@ -1387,23 +1420,21 @@ def _wide(x, width):
 
 @_traced_inline
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      *rest, t, grid, sm_scale, causal, seq_q, seq_k,
-                      padded_k, selected=False, hv=None, window=None):
-    """K/V block resident, Q/dO sub-tiles from the first visible row on;
-    *dq_acc* spans the head's sequence, and without it this step's share
-    of dq accumulates in its f32 output block.  With *selected* a
-    selection block follows delta, the scores' way round: (1, res_k / 32,
-    res_q) words, bit c % 32 of word c // 32 saying whether the query sees
-    key row c of the block.  With *window* the loop over query tiles ends
-    with the last query that sees the key tile (`_window_q_segments`)."""
-    sel_ref = rest[0] if selected else None
+                      *rest, mask, g, grid, sm_scale):
+    """K/V block resident, Q/dO sub-tiles over the runs the description
+    *mask* gives of the `_Frame` *g* (causal: from the first visible row
+    on); *dq_acc* spans the head's sequence, and without it this step's
+    share of dq accumulates in its f32 output block.  With a selection
+    (`g.selected`) a selection block follows delta, the scores' way round:
+    (1, res_k / 32, res_q) words, bit c % 32 of word c // 32 saying whether
+    the query sees key row c of the block."""
+    sel_ref = rest[0] if g.selected else None
     dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *maybe_dq_acc = \
-        rest[1:] if selected else rest
+        rest[1:] if g.selected else rest
     dq_acc = maybe_dq_acc[0] if maybe_dq_acc else None
-    nqr, nkr = grid
+    t, (nqr, nkr) = g.t, grid
     ik, iq = _grid_pos(1, nkr), _grid_pos(2, nqr)
     nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
-    off = seq_k - seq_q
     d, wide = dk_ref.shape[2], dk_acc.shape[1]
     d_v, wide_v = dv_ref.shape[2], dv_acc.shape[1]
 
@@ -1439,17 +1470,13 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q = _wide(q_ref[0, qs, :], wide)
             do = _wide(do_ref[0, qs, :], wide_v)
             s = _mxu_dot(k, q, _NT) * sm_scale      # (sub_k, sub_q)
-            if masked:
+            if masked is not None:
                 row0 = iq * t.res_q + jq * t.sub_q
-                mask = _tile_mask(
-                    s.shape, 1, row0, col0, off, seq_k, causal, padded_k,
-                    window) \
-                    if hv is None else _bd_tile_mask(
-                        s.shape, 1, row0, col0, hv, masked)
-                if selected:
-                    mask = _and(mask, unpack_selection(sel_ref[
+                seen = mask.tile_mask(g, s.shape, 1, row0, col0, masked)
+                if sel_ref is not None:
+                    seen = _and(seen, unpack_selection(sel_ref[
                         0, _sub(jk, t.sub_k // _SEL_BITS, nks), qs]))
-                s = jnp.where(mask, s, _NEG_INF)
+                s = jnp.where(seen, s, _NEG_INF)
             p = jnp.exp(s - lse_ref[0, :, qs])
             # dv += p dO (the tile is p^T as the forward knew it) — p cast
             # to the storage dtype for a full-rate MXU dot; accumulators
@@ -1464,19 +1491,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             # 8% slower at S = 2048, tools/flash_sweep.py)
             dq_add(jq, _mxu_dot(ds.T.astype(k.dtype), k, _NN))
 
-        if hv is not None:
-            _run_segments(_bd_q_segments(col0, iq * t.res_q, nqs, t, hv),
-                          tile)
-        elif window:
-            _run_segments(_window_q_segments(
-                col0, iq * t.res_q, nqs, t, off, seq_k, window), tile)
-        else:
-            j_first, j_full = _q_tiles(col0, iq * t.res_q, nqs, t, off,
-                                       seq_k, causal)
-            if selected:
-                j_full = nqs
-            _two_loops((j_first, j_full, nqs, True), tile,
-                       causal or padded_k or selected)
+        _run(mask.q_runs(g, col0, iq * t.res_q, nqs), tile, mask.end_to_end)
 
         @pl.when(iq == nqr - 1)
         def _finish():
@@ -1513,16 +1528,16 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
     logsumexp: grid (B*H, resident k blocks, resident q blocks), K/V
     resident and Q/dO streamed.  *sel* is the forward's selection the
     scores' way round here: `pack_selection` of the ``(B, seq_k, seq_q)``
-    transposed mask.  *mask* is a `BlockDiffusion` in `causal`'s place, or
-    a `Window` beside it."""
+    transposed mask."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
-    halves = _parts(mask)
+    mask = _described(causal, mask, sq, sk)
+    halves = mask.parts
     plan = _flash_plan(sq, sk, d, q.dtype, blk_q, blk_k, res_q, res_k, d_v,
                        halves)
     t, dp, dvp = plan.bwd, plan.d_block, plan.dv_block
     sq_p, sk_p = plan.sq_bwd, plan.sk_bwd
-    hv, window = _halves_of(mask, sq_p, sk_p), _window_of(mask)
+    g = _Frame(t, sq, sk, sq_p, sk_p, sel is not None)
     # the accumulators' lanes
     wide, wide_v = _round_up(dp, _LANES), _round_up(dvp, _LANES)
     qp = _pad_bh(q, sq_p, dp, halves)
@@ -1538,19 +1553,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
     nqr, nkr = sq_p // t.res_q, sk_p // t.res_k
 
     def q_index(bh_, ik, iq):
-        # an empty step (before the first visible row; under a
-        # block-diffusion mask, outside the rows that see the key block;
-        # through a window, past the last row that does) refetches nothing
-        if window:
-            return jnp.clip(iq, _first_q_block(ik, t, nqr, sk - sq),
-                            _last_q_block(ik, t, nqr, sk - sq, window))
-        if hv is not None:
-            a_first, n_first, n_last = _bd_q_blocks(ik, t, hv)
-            return jnp.where((iq < nqr // 2) & (a_first < nqr // 2),
-                             jnp.maximum(iq, a_first),
-                             jnp.clip(iq, n_first, n_last))
-        return jnp.maximum(iq, _first_q_block(ik, t, nqr, sk - sq)) \
-            if causal and nqr > 1 else iq
+        return mask.q_block(g, nqr, ik, iq)
 
     q_spec, k_spec, do_spec, v_spec, row_spec = _block_specs(
         t, dp, dvp, q_index, lambda bh_, ik, iq: ik)
@@ -1570,10 +1573,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
                                lambda bh_, ik, iq: (bh_, ik, iq, 0))
         dq_shape = jax.ShapeDtypeStruct((heads, nkr, sq_p, dp), jnp.float32)
 
-    kernel = functools.partial(
-        _flash_bwd_kernel, t=t, grid=(nqr, nkr), sm_scale=sm_scale,
-        causal=causal, seq_q=sq, seq_k=sk, padded_k=sk_p != sk, hv=hv,
-        window=window)
+    kernel = functools.partial(_flash_bwd_kernel, mask=mask, g=g,
+                               grid=(nqr, nkr), sm_scale=sm_scale)
     in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
     limit = _vmem_limit(plan, q.dtype.itemsize)
     if sel is not None:
@@ -1581,7 +1582,6 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, causal, sm_scale,
             raise ValueError(
                 "flash attention backward: a selection operand with dq's "
                 "partials in HBM (a query sequence of %d) is not built" % sq)
-        kernel = functools.partial(kernel, selected=True)
         in_specs.append(pl.BlockSpec(
             (1, t.res_k // _SEL_BITS, t.res_q),
             lambda bh_, ik, iq: (bh_ // h, ik, q_index(bh_, ik, iq))))
@@ -1652,20 +1652,20 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False,
-                    chunk=512, mask=None):
+                    mask=None):
     """Blockwise (flash) attention, (B, H, S, D) layout.
 
-    Pallas MXU kernel on TPU; chunked-scan XLA path elsewhere (*chunk*
-    is its block length).  Both have O(S * block) activation memory;
-    grads flow through either.  *mask* is a static description: of a mask
-    that is not causal, a `BlockDiffusion` ``(block, half)``, or beside
-    ``causal=True`` a `Window` ``(keys,)`` that bounds a query's keys from
-    below as well.  Either way the kernels' loops visit the tiles that hold
-    a visible pair and no other, and no mask is an operand.
+    Pallas MXU kernel on TPU; chunked-scan XLA path elsewhere.  Both have
+    O(S * block) activation memory; grads flow through either.  *mask* is
+    a static description: of a mask that is not causal, a `BlockDiffusion`
+    ``(block, half)``, or beside ``causal=True`` a `Window` ``(keys,)``
+    that bounds a query's keys from below as well.  Either way the kernels'
+    loops visit the tiles that hold a visible pair and no other, and no
+    mask is an operand.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    mask = _checked_mask(mask, causal, q.shape[2], k.shape[2])
+    mask = _described(causal, mask, q.shape[2], k.shape[2])
     if interpret:
         dt = jnp.result_type(q.dtype, k.dtype, v.dtype)
         return _flash(q.astype(dt), k.astype(dt), v.astype(dt),
@@ -1700,8 +1700,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False,
                              out_specs=spec, check_vma=False)(q, k, v)
 
     def _other(q, k, v):
-        return _chunked_attention(q, k, v, causal, sm_scale, int(chunk),
-                                  mask).astype(q.dtype)
+        return _chunked_attention(q, k, v, causal, sm_scale,
+                                  mask=mask).astype(q.dtype)
 
     # decided at LOWERING time per platform, not by which devices this
     # process happens to see: only the target platform's branch is
@@ -1835,38 +1835,33 @@ def selected_attention(q, k, v, sel_q, sel_k, sm_scale=None,
 @register_op("_contrib_DotProductAttention",
              input_names=("query", "key", "value"))
 def _dot_product_attention(query, key, value, causal=False, sm_scale=None,
-                           chunk=512, mask=None, mask_block=1, window=0):
+                           mask=None, mask_block=1, window=0):
     """Fused scaled-dot-product attention (TPU-native; no reference
     counterpart — the reference predates Transformers, SURVEY §5.7).
     *mask* ``"block_diffusion"`` with *mask_block* puts the sequence under
     the block-diffusion mask (`ops/attention.py` `BlockDiffusion`: a clean
     copy then a noised copy of half the sequence each), under device scope
     ``mx.bd.attention``.  *window* > 0 with ``causal`` bounds a query's
-    keys to that many, its own the last (`Window`); the pairs that leaves
-    visible go out as step stat ``swa_visible_pairs``."""
-    if mask is None and not window:
-        return flash_attention(query, key, value, causal=bool(causal),
-                               sm_scale=sm_scale, chunk=chunk)
-    from .. import profiler
-    if mask is None:
-        keys = min(int(window), key.shape[2])
-        # at trace time on purpose (as the routed op's counts); a float
-        profiler.emit_step_stat(  # graftlint: disable=JG003
-            "swa_visible_pairs", jnp.float32(query.shape[0] * window_pairs(
-                query.shape[2], key.shape[2], keys)))
-        return flash_attention(query, key, value, causal=bool(causal),
-                               sm_scale=sm_scale, chunk=chunk,
-                               mask=Window(int(window)))
-    if mask != "block_diffusion":
+    keys to that many, its own the last (`Window`).  The pairs either
+    leaves visible go out as step stat ``bd_visible_pairs`` or
+    ``swa_visible_pairs``."""
+    if mask == "block_diffusion":
+        mask = BlockDiffusion(int(mask_block), query.shape[2] // 2)
+    elif mask is not None:
         raise ValueError("mask %r is not built (block_diffusion is)"
                          % (mask,))
-    half = query.shape[2] // 2
-    # a float: a batch's pairs pass 2^31
-    profiler.emit_step_stat(  # graftlint: disable=JG003
-        "bd_visible_pairs", jnp.float32(
-            query.shape[0] * half * (half + int(mask_block))))
-    with jax.named_scope("mx.bd.attention"):
-        return flash_attention(
-            query, key, value, causal=bool(causal), sm_scale=sm_scale,
-            chunk=chunk,
-            mask=BlockDiffusion(int(mask_block), half))
+    elif window:
+        mask = Window(int(window))
+    scope = contextlib.nullcontext()
+    if mask is not None:
+        from .. import profiler
+        # at trace time on purpose (as the routed op's counts); a float: a
+        # batch's pairs pass 2^31
+        profiler.emit_step_stat(  # graftlint: disable=JG003
+            mask.stat, jnp.float32(query.shape[0] * mask.pairs(
+                query.shape[2], key.shape[2])))
+        if mask.scope:
+            scope = jax.named_scope(mask.scope)
+    with scope:
+        return flash_attention(query, key, value, causal=bool(causal),
+                               sm_scale=sm_scale, mask=mask)

@@ -1,10 +1,17 @@
 """Attention stack tests: chunked/flash attention vs the einsum oracle,
 ring attention on the virtual 8-device mesh (SURVEY §5.7 TPU stance)."""
 
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)                    # `benchmarks`' counts
 
 import mxnet_tpu as mx
 from mxnet_tpu.ops.attention import (attention_reference, _chunked_attention,
@@ -386,49 +393,154 @@ def test_pallas_flash_default_plan_short_sequence_interpret():
     _check_flash_against_reference(q, k, v, True, 2e-5, 2e-4)
 
 
-def _brute_force_tile_counts(sq, sk, causal, sq_p, sk_p, sub_q, sub_k):
-    """(visited, masked, scores) from the visibility matrix itself."""
-    rows = np.arange(sq_p)[:, None]
-    cols = np.arange(sk_p)[None, :]
-    real = (cols < sk) & (rows >= 0)
-    seen = real & (cols <= rows + (sk - sq)) if causal else real
-    scores = int(seen[:sq].sum())
-    # a padded query row is computed like a real one (its dO is zero):
-    # it never makes a tile masked, the padded key columns do
-    seen = seen.reshape(sq_p // sub_q, sub_q, sk_p // sub_k, sub_k)
-    real_rows = (np.arange(sq_p) < sq).reshape(-1, sub_q)[:, :, None, None]
-    any_ = (seen & real_rows).any(axis=(1, 3))
-    all_ = (seen | ~real_rows).all(axis=(1, 3))
-    return int(any_.sum()), int((any_ & ~all_).sum()), scores
+def _padded_positions(n, n_p, parts):
+    """``(position, real)`` of each of the *n_p* padded indices of *n*
+    positions in *parts* equal parts, each padded on its own (a padded one
+    takes its part's last position)."""
+    per_p, per = n_p // parts, n // parts
+    part, local = np.divmod(np.arange(n_p), per_p)
+    return part * per + np.minimum(local, per - 1), local < per
 
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("sq,sk,tiles", _GEOMETRIES + [
-    (2048, 2048, {}), (1000, 1000, {}), (300, 2048, {}), (2048, 300, {}),
-    (4096, 4096, dict(blk_q=512, blk_k=256, res_q=1024, res_k=2048)),
-])
-def test_flash_plan_visits_what_the_mask_leaves_visible(causal, sq, sk,
-                                                        tiles):
-    """The loops' bounds against the visibility matrix: every tile that
-    holds a visible score is visited and no other (a tile all of whose
-    real rows are hidden costs nothing), and the mask runs exactly on
-    the tiles that the diagonal or the key padding crosses."""
-    from mxnet_tpu.ops.attention import (_KERNELS, _flash_plan,
+def _tiles_by_definition(mask, g):
+    """``(any, all, pairs)`` from the description's own `visible`, a row of
+    sub-tiles at a time: which sub-tiles hold a visible pair, in which every
+    real query sees every key (and every key is real), and the visible
+    pairs.  A padded query row is computed like a real one (its dO is
+    zero): it never makes a tile masked, the padded key columns do."""
+    t = g.t
+    q_pos, q_real = _padded_positions(g.seq_q, g.pad_q, mask.parts)
+    k_pos, k_real = _padded_positions(g.seq_k, g.pad_k, mask.parts)
+    shape = (g.pad_q // t.sub_q, g.pad_k // t.sub_k)
+    any_, all_, pairs = np.zeros(shape, bool), np.zeros(shape, bool), 0
+    for band in range(shape[0]):
+        rows = slice(band * t.sub_q, (band + 1) * t.sub_q)
+        seen = k_real & np.broadcast_to(mask.visible(
+            q_pos[rows, None] + g.off, k_pos[None, :]), (t.sub_q, g.pad_k))
+        seen = seen.reshape(t.sub_q, shape[1], t.sub_k)
+        real = q_real[rows, None, None]
+        any_[band] = (seen & real).any(axis=(0, 2))
+        all_[band] = (seen | ~real).all(axis=(0, 2))
+        pairs += int((seen & real).sum())
+    return any_, all_, pairs
+
+
+def _tiles_by_the_loops(mask, kernel, g):
+    """``(visits, bodies)`` a sub-tile: how often the kernel's loops run it
+    and how often under a mask body, from the description's runs at every
+    grid step."""
+    t = g.t
+    nqs, nks = t.res_q // t.sub_q, t.res_k // t.sub_k
+    visits = np.zeros((g.pad_q // t.sub_q, g.pad_k // t.sub_k), int)
+    bodies = np.zeros_like(visits)
+    for q0 in range(0, g.pad_q, t.res_q):
+        for k0 in range(0, g.pad_k, t.res_k):
+            iq, ik = q0 // t.sub_q, k0 // t.sub_k
+            for j in range(nqs if kernel == "fwd" else nks):
+                if kernel == "fwd":
+                    runs = mask.k_runs(g, q0 + j * t.sub_q, k0, nks)
+                    at = [(iq + j, slice(ik + lo, ik + max(lo, hi)), body)
+                          for lo, hi, body in runs]
+                else:
+                    runs = mask.q_runs(g, k0 + j * t.sub_k, q0, nqs)
+                    at = [(slice(iq + lo, iq + max(lo, hi)), ik + j, body)
+                          for lo, hi, body in runs]
+                for rows, cols, body in at:
+                    visits[rows, cols] += 1
+                    bodies[rows, cols] += body is not None
+    return visits, bodies
+
+
+def _bd(half, block, d, dtype, **tiles):
+    from benchmarks import bd_counts
+    from mxnet_tpu.ops.attention import BlockDiffusion
+    # a mask body wherever a boundary crosses, and on few tiles more (the
+    # loops' bounds are whole blocks of the mask, not rows)
+    return (BlockDiffusion(block, half), 2 * half, 2 * half, d, dtype, tiles,
+            lambda t: bd_counts.tiles(half, block, t.sub_q, t.sub_k), True)
+
+
+def _swa(seq, window, d, dtype):
+    from benchmarks import swa_counts
+    from mxnet_tpu.ops.attention import Window
+    return (Window(window), seq, seq, d, dtype, {},
+            lambda t: swa_counts.tiles(seq, window, t.sub_q, t.sub_k), False)
+
+
+def _description_cases():
+    """``(description, sq, sk, d, dtype, tile overrides, the benchmark's own
+    count of (needed, crossed) tiles or None, whether a wholly visible tile
+    may run a body)``"""
+    from mxnet_tpu.ops.attention import Causal, Full
+    cases = {}
+    for sq, sk, tiles in _GEOMETRIES + [
+            (2048, 2048, {}), (1000, 1000, {}), (300, 2048, {}),
+            (2048, 300, {}),
+            (4096, 4096, dict(blk_q=512, blk_k=256, res_q=1024,
+                              res_k=2048))]:
+        for mask in (Full(), Causal()):
+            name = "%s-%dx%d-%s" % (type(mask).__name__, sq, sk, "-".join(
+                "%s%d" % kv for kv in sorted(tiles.items())))
+            cases[name] = (mask, sq, sk, 64, jnp.bfloat16, tiles, None, False)
+    for half, block, d, dtype in [
+            (8192, 4, 128, jnp.bfloat16), (8192, 32, 128, jnp.bfloat16),
+            (6144, 4, 128, jnp.bfloat16), (2048, 4, 64, jnp.bfloat16),
+            (1000, 8, 64, jnp.float32), (6144, 6, 128, jnp.bfloat16)]:
+        cases["BlockDiffusion-%d-%d" % (half, block)] = _bd(
+            half, block, d, dtype)
+    for seq, window, d, dtype in [
+            (8192, 512, 128, jnp.bfloat16), (6144, 512, 128, jnp.bfloat16),
+            (4096, 512, 128, jnp.bfloat16), (8192, 1024, 128, jnp.bfloat16),
+            (8192, 2000, 64, jnp.bfloat16), (2048, 100, 64, jnp.bfloat16),
+            (1000, 77, 64, jnp.float32), (4096, 4095, 128, jnp.bfloat16)]:
+        cases["Window-%d-%d" % (seq, window)] = _swa(seq, window, d, dtype)
+    return cases
+
+
+_DESCRIPTIONS = _description_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_DESCRIPTIONS))
+def test_the_loops_visit_what_the_description_calls_visible(case):
+    """Both kernels' loops against the description's own `visible`: every
+    sub-tile that holds a visible pair is run once and no other is run (a
+    tile all of whose real rows are hidden costs nothing); a tile run
+    without a mask body is wholly visible, and a tile the mask or the key
+    padding crosses runs one (where the description's runs are whole blocks
+    of its own, a few more do); the pair count is the definition's sum;
+    `_tile_counts` is the loops' count; and the benchmark's own count of
+    the tiles (`bd_counts`, `swa_counts`), where it has one, agrees."""
+    from mxnet_tpu.ops.attention import (_KERNELS, _Frame, _flash_plan,
                                          _tile_counts)
-    plan = _flash_plan(sq, sk, 64, jnp.bfloat16, **tiles)
+    mask, sq, sk, d, dtype, tiles, yardstick, slack = _DESCRIPTIONS[case]
+    assert mask.checked(mask.causal, sq, sk) == mask
+    plan = _flash_plan(sq, sk, d, dtype, halves=mask.parts, **tiles)
     for kernel in _KERNELS:
         t = getattr(plan, kernel)
         sq_p, sk_p = (plan.sq_fwd, plan.sk_fwd) if kernel == "fwd" \
             else (plan.sq_bwd, plan.sk_bwd)
         assert sq_p % t.res_q == 0 and t.res_q % t.sub_q == 0
         assert sk_p % t.res_k == 0 and t.res_k % t.sub_k == 0
-        visited, masked, scores = _brute_force_tile_counts(
-            sq, sk, causal, sq_p, sk_p, t.sub_q, t.sub_k)
-        got = _tile_counts(kernel, plan, sq, sk, causal)
-        assert got["tiles_visited"] == visited, kernel
-        assert got["tiles_masked"] == masked, kernel
+        g = _Frame(t, sq, sk, sq_p, sk_p, False)
+        any_, all_, pairs = _tiles_by_definition(mask, g)
+        visits, bodies = _tiles_by_the_loops(mask, kernel, g)
+        assert (visits == any_).all(), kernel
+        assert all_[(visits > 0) & (bodies == 0)].all(), kernel
+        crossed = int((any_ & ~all_).sum())
+        if slack:
+            assert crossed <= bodies.sum() <= 1.5 * crossed + 2, kernel
+        else:
+            assert ((bodies > 0) == (any_ & ~all_)).all(), kernel
+        assert pairs == mask.pairs(sq, sk)
+        got = _tile_counts(kernel, plan, sq, sk, mask.causal, mask)
+        assert got["tiles_visited"] == visits.sum(), kernel
+        assert got["tiles_masked"] == bodies.sum(), kernel
+        assert got.get("tiles_needed", visits.sum()) == visits.sum(), kernel
         assert got["tiles_ideal"] == pytest.approx(
-            scores / (t.sub_q * t.sub_k), abs=1e-3)
+            pairs / (t.sub_q * t.sub_k), abs=1e-3)
+        if yardstick:
+            needed, edge = yardstick(t)
+            assert (needed, edge) == (visits.sum(), crossed), kernel
 
 
 @pytest.mark.parametrize("sq,sk,d,dtype", [
@@ -467,6 +579,24 @@ def test_flash_plan_stays_inside_vmem_and_near_the_triangle(sq, sk, d,
         assert _tile_counts(kernel, _flash_plan(sq, sk, d, dtype), sq, sk,
                             True) == _tile_counts(kernel, plan, sq, sk,
                                                   True)
+
+
+def test_equal_descriptions_share_one_traced_kernel():
+    """A description hashes by value: every layer of a model that gives an
+    equal one (a fresh object each) finds the wrappers' trace cache, so a
+    kernel body is traced once a shape and not once a layer (`setup_s`)."""
+    from mxnet_tpu.ops import attention as A
+    q, _, _ = _rand_qkv(b=1, h=2, sq=128, sk=128, d=16)
+    cases = ((True, lambda: A.Window(40)), (True, lambda: None),
+             (False, lambda: A.BlockDiffusion(4, 64)))
+    before = A._flash_fwd_pallas._cache_size()
+    for _ in range(3):
+        for causal, mask in cases:
+            flash_attention(q, q, q, causal=causal, interpret=True,
+                            mask=mask())
+    assert A._flash_fwd_pallas._cache_size() - before == len(cases)
+    assert A.Window(40) == A.Window(40) != A.Window(41)
+    assert A.Full() != A.Causal() and len({A.Causal(), A.Causal()}) == 1
 
 
 def test_flash_plan_is_recorded_once_per_traced_call():

@@ -497,9 +497,13 @@ def test_flash_attention_s_paths_agree_at_two_widths(d, d_v):
     with jax.default_matmul_precision("highest"):
         want = out_and_grads(lambda *a: attention.attention_reference(
             *a, causal=True), q, k, v, w)
-        for path in (dict(interpret=True), dict(chunk=16)):
-            got = out_and_grads(lambda *a: attention.flash_attention(
-                *a, causal=True, **path), q, k, v, w)
+        for path in (
+                lambda *a: attention.flash_attention(*a, causal=True,
+                                                     interpret=True),
+                lambda *a: attention.flash_attention(*a, causal=True),
+                lambda *a: attention._chunked_attention(*a, causal=True,
+                                                        chunk=16)):
+            got = out_and_grads(path, q, k, v, w)
             for a, b in zip(jax.tree_util.tree_leaves(got),
                             jax.tree_util.tree_leaves(want)):
                 np.testing.assert_allclose(np.asarray(a), np.asarray(b),

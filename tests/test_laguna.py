@@ -120,14 +120,14 @@ def test_the_window_is_its_definition_and_leaves_what_the_count_says(
         seq, window):
     pos = np.arange(seq)
     want = swa_counts.visible(pos[:, None], pos[None, :], window)
-    got = np.asarray(attention.window_visible(
-        jnp.arange(seq)[:, None], jnp.arange(seq)[None, :], window))
+    got = np.asarray(attention.Window(window).visible(
+        jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]))
     assert (got == want).all()
     # a row of `window` or more positions sees `window` keys, its own the
     # last; an earlier row all it has
     assert want.sum(-1).tolist() == [min(t + 1, window) for t in range(seq)]
     assert want.sum() == swa_counts.visible_pairs(seq, window) \
-        == attention.window_pairs(seq, seq, window)
+        == attention.Window(window).pairs(seq, seq)
     assert reference.sees(pos[:, None], pos[None, :], window).sum() \
         == want.sum()
 
@@ -150,8 +150,9 @@ def test_a_window_that_is_not_one_is_refused():
         attention.flash_attention(q, q, q, causal=True,
                                   mask=attention.Window(0))
     # a window that leaves every causal key visible is no mask at all
-    assert attention._checked_mask(attention.Window(32), True, 32, 32) is None
-    assert attention._checked_mask(attention.Window(31), True, 32, 32) \
+    assert attention._described(True, attention.Window(32), 32, 32) \
+        == attention._described(True, None, 32, 32) == attention.Causal()
+    assert attention._described(True, attention.Window(31), 32, 32) \
         == attention.Window(31)
 
 
@@ -234,28 +235,9 @@ def test_the_public_op_looks_through_the_window_and_counts_its_pairs():
 
 
 # -- the plan -------------------------------------------------------------------
-@pytest.mark.parametrize("seq,window,d,dtype", [
-    (8192, 512, 128, jnp.bfloat16), (6144, 512, 128, jnp.bfloat16),
-    (4096, 512, 128, jnp.bfloat16), (8192, 1024, 128, jnp.bfloat16),
-    (8192, 2000, 64, jnp.bfloat16), (2048, 100, 64, jnp.bfloat16),
-    (1000, 77, 64, jnp.float32), (4096, 4095, 128, jnp.bfloat16)])
-def test_the_plan_visits_every_tile_with_a_visible_pair_and_no_other(
-        seq, window, d, dtype):
-    mask = attention.Window(window)
-    plan = attention._flash_plan(seq, seq, d, dtype)
-    for kernel in ("fwd", "bwd"):
-        t = getattr(plan, kernel)
-        counts = attention._tile_counts(kernel, plan, seq, seq, True, mask)
-        needed, crossed = swa_counts.tiles(seq, window, t.sub_q, t.sub_k)
-        assert counts["tiles_visited"] == counts["tiles_needed"] == needed, \
-            kernel
-        # a mask body wherever an edge crosses, and on no tile more
-        assert counts["tiles_masked"] == crossed, kernel
-        assert counts["tiles_ideal"] == pytest.approx(
-            swa_counts.visible_pairs(seq, window) / (t.sub_q * t.sub_k),
-            abs=1e-3)
-
-
+# (the loops against the window's definition at eight shapes, the cell's among
+# them, and against `swa_counts.tiles`: cases `Window-*` of
+# tests/test_attention.py's one test over descriptions)
 def test_the_plan_at_8192_through_512_is_62_tiles_of_272():
     """At `_SUB_LOOPED` (256 queries by 512 keys) and 8192 positions: the
     first two rows of tiles see one tile, the other thirty two, every one

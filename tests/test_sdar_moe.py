@@ -82,9 +82,8 @@ def test_the_mask_is_its_definition_and_leaves_half_times_half_plus_block(
     B``: a quarter of the square where ``B`` is small beside ``L``."""
     pos = np.arange(2 * half)
     want = bd_counts.visible(pos[:, None], pos[None, :], half, block)
-    got = attention.block_diffusion_visible(
-        jnp.asarray(pos)[:, None], jnp.asarray(pos)[None, :],
-        attention.BlockDiffusion(block, half))
+    got = attention.BlockDiffusion(block, half).visible(
+        jnp.asarray(pos)[:, None], jnp.asarray(pos)[None, :])
     assert np.array_equal(np.asarray(got), want)
     assert np.array_equal(np.asarray(reference.sees(
         jnp.asarray(pos)[:, None], jnp.asarray(pos)[None, :], half, block)),
@@ -118,12 +117,13 @@ def test_the_cell_s_mask_shows_a_quarter_of_the_square():
 
 def test_a_mask_that_does_not_describe_the_sequence_is_refused():
     q = rand(1, 1, 2, 32, 8)
+    bd = attention.BlockDiffusion
     with pytest.raises(ValueError, match="does not describe"):
-        attention.flash_attention(q, q, q, mask=(4, 12))
+        attention.flash_attention(q, q, q, mask=bd(4, 12))
     with pytest.raises(ValueError, match="does not describe"):
-        attention.flash_attention(q, q, q, mask=(3, 16))
+        attention.flash_attention(q, q, q, mask=bd(3, 16))
     with pytest.raises(ValueError, match="not causal"):
-        attention.flash_attention(q, q, q, causal=True, mask=(4, 16))
+        attention.flash_attention(q, q, q, causal=True, mask=bd(4, 16))
     with pytest.raises(ValueError, match="is not built"):
         get_op("_contrib_DotProductAttention").fn(q, q, q, mask="sliding")
 
@@ -210,28 +210,9 @@ def test_the_public_op_runs_the_kernels_under_the_mask():
 
 
 # -- the plan -----------------------------------------------------------------
-@pytest.mark.parametrize("half,block,d,dtype", [
-    (8192, 4, 128, jnp.bfloat16), (8192, 32, 128, jnp.bfloat16),
-    (6144, 4, 128, jnp.bfloat16), (2048, 4, 64, jnp.bfloat16),
-    (1000, 8, 64, jnp.float32), (6144, 6, 128, jnp.bfloat16)])
-def test_the_plan_visits_every_tile_with_a_visible_pair_and_no_other(
-        half, block, d, dtype):
-    mask = attention.BlockDiffusion(block, half)
-    plan = attention._flash_plan(2 * half, 2 * half, d, dtype, halves=2)
-    for kernel in ("fwd", "bwd"):
-        t = getattr(plan, kernel)
-        counts = attention._tile_counts(kernel, plan, 2 * half, 2 * half,
-                                        False, mask)
-        needed, crossed = bd_counts.tiles(half, block, t.sub_q, t.sub_k)
-        assert counts["tiles_visited"] == needed, kernel
-        # a mask body wherever a boundary crosses, and on few tiles more
-        # (the loops' bounds are whole blocks of the mask, not rows)
-        assert crossed <= counts["tiles_masked"] <= 1.5 * crossed + 2, kernel
-        assert counts["tiles_ideal"] == pytest.approx(
-            bd_counts.visible_pairs(half, block) / (t.sub_q * t.sub_k),
-            abs=1e-3)
-
-
+# (the loops against the mask's definition at six shapes, the cell's among
+# them, and against `bd_counts.tiles`: cases `BlockDiffusion-*` of
+# tests/test_attention.py's one test over descriptions)
 def test_the_cell_s_plan_is_576_tiles_of_1056():
     """At `_SUB_LOOPED` (256 queries by 512 keys) and ``L`` 8192: 272
     (clean on clean) + 272 (noised on clean) + 32 (noised on its own
@@ -303,7 +284,8 @@ def test_the_selected_plan_masks_every_tile_it_visits_as_before():
 def test_the_plan_span_carries_the_mask():
     q = rand(21, 1, 2, 128, 16)
     since = profiler.spans()[-1].id if profiler.spans() else -1
-    attention.flash_attention(q, q, q, interpret=True, mask=(4, 64))
+    attention.flash_attention(q, q, q, interpret=True,
+                              mask=attention.BlockDiffusion(4, 64))
     args = [s for s in profiler.spans()
             if s.name == "mx.flash.plan" and s.id > since][-1].args
     assert (args["mask"], args["block"], args["half"]) == (

@@ -97,8 +97,7 @@ def times(A, half, block, heads, d, iters, masks):
             else bd_counts.causal_pairs(s)
         for kernel, fn in calls.items():
             ms, kernel_ms = _time_scan(fn, (q, k, v, out, lse, do), iters)
-            counts = A._tile_counts(kernel, plan, s, s, causal,
-                                    *((mask,) if mask is not None else ()))
+            counts = A._tile_counts(kernel, plan, s, s, causal, mask)
             flops = 2 * heads * pairs * 2 * d * (1 if kernel == "fwd" else 2)
             rows.append({"mask": name, "kernel": kernel, "ms": ms,
                          "kernel_ms": kernel_ms, **counts,
