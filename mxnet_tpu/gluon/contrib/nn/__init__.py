@@ -1,7 +1,7 @@
 """Contrib layers (reference: gluon/contrib/nn/basic_layers.py)."""
 
-from .basic_layers import (Concurrent, GatedMLP, GatedShortConv,  # noqa
-                           GroupedQueryAttention, HybridConcurrent, Identity,
+from .basic_layers import (Concurrent, GatedDeltaNet, GatedMLP,  # noqa
+                           GatedShortConv, GroupedQueryAttention, HybridConcurrent, Identity,
                            LatentAttention, MoEFFN, MultiHeadAttention,
                            RoutedExperts, SharedExperts, SparseAttention,
                            SparseEmbedding, SyncBatchNorm)

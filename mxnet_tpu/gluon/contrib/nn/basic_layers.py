@@ -227,6 +227,95 @@ class GatedShortConv(HybridBlock):
                                         out_weight)
 
 
+class GatedDeltaNet(HybridBlock):
+    """A linear-attention operator on (batch, seq, units): the gated delta
+    rule (arXiv:2412.06464; with *allow_neg_eigval* the write strength in
+    (0, 2) of arXiv:2411.12537) in the block form whose published keys are
+    ``linear_num_key_heads``, ``linear_key_head_dim``,
+    ``linear_value_head_dim``, ``linear_conv_kernel_dim`` and
+    ``linear_allow_neg_eigval``.  *num_heads* heads carry a float32 state of
+    *key_dim* x *value_dim* each along the sequence; no biases but the
+    decay's:
+
+    - six products of the input: q and k (*num_heads* x *key_dim* each) and v
+      (*num_heads* x *value_dim*) as the row blocks of one matrix, the
+      output's gate z (as wide as v), and one number a head and token each
+      for the decay and for the write strength;
+    - q, k and v through one depthwise causal convolution of *conv_kernel*
+      taps over their concatenated channels, then silu; q and k L2-normed
+      by head, q scaled by ``key_dim ** -0.5`` (``contrib.ShortConvHeads``);
+    - ``g = -exp(A_log) softplus(a + dt_bias)`` (float32) and ``b =
+      sigmoid(.)``, doubled with *allow_neg_eigval*
+      (``contrib.DeltaRuleGates``);
+    - the rule itself, ``contrib.GatedDeltaRule``, chunkwise in the op's own
+      chunks of 64 tokens (the sequence has to be whole chunks);
+    - ``rms(o, gamma) * silu(z)`` by head with one *value_dim*-wide gamma
+      (``contrib.GatedRMSNorm``), and the output product.
+
+    Device scopes ``mx.gdn.project`` (the six products), ``mx.gdn.conv``
+    (convolution, silu, the two L2 norms, the gates' activations),
+    ``mx.gdn.scan`` (the rule, forward and backward) and ``mx.gdn.out`` (the
+    gated norm and the output product); span ``mx.gdn.plan`` a traced
+    call."""
+
+    def __init__(self, units, num_heads, key_dim, value_dim, conv_kernel=4,
+                 allow_neg_eigval=False, epsilon=1e-6,
+                 weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        self._units, self._heads = units, int(num_heads)
+        self._dk, self._dv = int(key_dim), int(value_dim)
+        self._eps = float(epsilon)
+        self._neg = bool(allow_neg_eigval)
+        keys, values = self._heads * self._dk, self._heads * self._dv
+        self._channels = 2 * keys + values
+        with self.name_scope():
+            def weight(name, rows, cols):
+                return self.params.get(name, shape=(rows, cols),
+                                       init=weight_initializer)
+            self.qkv_weight = weight("qkv_weight", self._channels, units)
+            self.conv_weight = weight("conv_weight", self._channels,
+                                      conv_kernel)
+            self.decay_weight = weight("decay_weight", self._heads, units)
+            self.beta_weight = weight("beta_weight", self._heads, units)
+            self.a_log = self.params.get("a_log", shape=(self._heads,),
+                                         init="zeros")
+            self.dt_bias = self.params.get("dt_bias", shape=(self._heads,),
+                                           init="zeros")
+            self.gate_weight = weight("gate_weight", values, units)
+            self.norm_gamma = self.params.get(
+                "norm_gamma", shape=(self._dv,), init="ones")
+            self.out_weight = weight("out_weight", units, values)
+
+    def hybrid_forward(self, F, x, qkv_weight, conv_weight, decay_weight,
+                       beta_weight, a_log, dt_bias, gate_weight, norm_gamma,
+                       out_weight):
+        from .... import symbol
+
+        def product(w, n):
+            return F.FullyConnected(x, w, no_bias=True, flatten=False,
+                                    num_hidden=n)
+
+        with symbol.AttrScope(__scope__="mx.gdn.project"):
+            qkv = product(qkv_weight, self._channels)
+            z = product(gate_weight, self._heads * self._dv)
+            a = product(decay_weight, self._heads)
+            b = product(beta_weight, self._heads)
+        with symbol.AttrScope(__scope__="mx.gdn.conv"):
+            heads = F.contrib.ShortConvHeads(
+                qkv, conv_weight, num_heads=self._heads, key_dim=self._dk,
+                eps=self._eps)
+            gates = F.contrib.DeltaRuleGates(a, b, a_log, dt_bias,
+                                             allow_neg_eigval=self._neg)
+        with symbol.AttrScope(__scope__="mx.gdn.scan"):
+            o = F.contrib.GatedDeltaRule(heads[0], heads[1], heads[2],
+                                         gates[0], gates[1])
+        with symbol.AttrScope(__scope__="mx.gdn.out"):
+            return F.FullyConnected(
+                F.contrib.GatedRMSNorm(o, z, norm_gamma, eps=self._eps),
+                out_weight, no_bias=True, flatten=False,
+                num_hidden=self._units)
+
+
 class GroupedQueryAttention(HybridBlock):
     """Causal self-attention with fewer key/value heads than query
     heads, RMS norm over each query and key head, and rotary positions;
@@ -264,12 +353,24 @@ class GroupedQueryAttention(HybridBlock):
     Device scopes ``mx.swa.project``,
     ``mx.swa.attention`` and ``mx.swa.out`` for a layer with a window,
     ``mx.gqa.project``, ``mx.gqa.attention`` and ``mx.gqa.out`` for one
-    with a gate and none.  Without the three this is the block it was."""
+    with a gate and none.  Without the three this is the block it was.
+
+    Two departures a layer may ask for beside them.  *qk_norm* ``"width"``
+    puts one RMS norm over the whole width of the q product and one over the
+    k product's (scales ``(num_heads * head_dim,)`` and ``(num_kv_heads *
+    head_dim,)``: the norm runs over all the heads' numbers at once,
+    arXiv:2501.00656 section 3) in place of ``"head"``'s norm over each
+    head.  A *rope* entry whose ``rope_theta`` is null switches the rotary
+    positions off: q and k go to the attention normed and not turned (a
+    model whose other layers carry the order).  With either, the q and k
+    products do not take the head-rope kernels (``mx.headrope.plan`` says
+    ``path`` ``xla`` and why), and the layer takes the scopes ``mx.gqa.*``
+    as a gated one does."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
                  rope_theta=10000.0, epsilon=1e-5, weight_initializer=None,
                  diffusion_block=None, window=None, gate=False, rope=None,
-                 **kwargs):
+                 qk_norm="head", **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("num_heads (%d) must be a multiple of "
@@ -277,22 +378,34 @@ class GroupedQueryAttention(HybridBlock):
         if window and diffusion_block:
             raise ValueError("a window goes with a causal mask, not with "
                              "the block-diffusion one")
+        if qk_norm not in ("head", "width"):
+            raise ValueError("qk_norm %r is neither \"head\" (a norm over "
+                             "each head) nor \"width\" (one over all of "
+                             "them)" % (qk_norm,))
         head_dim = head_dim or units // num_heads
         self._units = units
         self._heads, self._kv_heads = num_heads, num_kv_heads
         self._head_dim, self._theta = head_dim, float(rope_theta)
         self._eps = epsilon
         self._rotary = {"theta": self._theta}
-        if rope:
+        # a published entry whose rope_theta is null: no rotary positions
+        unturned = bool(rope) and "rope_theta" in rope \
+            and rope["rope_theta"] is None
+        if unturned:
+            self._rotary = {"rotary": False}
+        elif rope:
             from ....ops.lm_blocks import rope_frequencies
             self._rotary = rope_frequencies(rope, head_dim)
+        if qk_norm == "width":
+            self._rotary["norm_over"] = "width"
+        departs = unturned or qk_norm == "width"
         self._mask = {"causal": True} if not diffusion_block else {
             "mask": "block_diffusion", "mask_block": int(diffusion_block)}
         if window:
             self._mask["window"] = int(window)
         # the block's nodes as named groups of the compiled graph
         scope = "mx.bd" if diffusion_block else "mx.swa" if window \
-            else "mx.gqa" if gate else None
+            else "mx.gqa" if gate or departs else None
         self._group = {"__scope__": scope + ".project"} if scope else {}
         # (under the block-diffusion mask the op names its own scope)
         self._after = {} if scope in (None, "mx.bd") else {
@@ -310,10 +423,13 @@ class GroupedQueryAttention(HybridBlock):
                                    units)
             self.out_weight = weight("out_weight", units,
                                      num_heads * head_dim)
+            wide = qk_norm == "width"
             self.q_gamma = self.params.get(
-                "query_norm_gamma", shape=(head_dim,), init="ones")
+                "query_norm_gamma", init="ones",
+                shape=(num_heads * head_dim if wide else head_dim,))
             self.k_gamma = self.params.get(
-                "key_norm_gamma", shape=(head_dim,), init="ones")
+                "key_norm_gamma", init="ones",
+                shape=(num_kv_heads * head_dim if wide else head_dim,))
             if gate:
                 self.gate_weight = weight("gate_weight", num_heads, units)
 

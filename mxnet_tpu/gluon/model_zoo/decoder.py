@@ -1,10 +1,16 @@
 """A decoder-only language model built from a list of layer kinds: each
-layer has an operator and a feed-forward, pre-normed with RMS norm and
-added to the residual:
+layer has an operator and a feed-forward, each with an RMS norm and added
+to the residual.  The norm sits on the input (pre-norm, the default):
 
     h = h + operator(rms(h));  h = h + feed_forward(rms(h))
 
-then a final RMS norm and a head.  The operator is one of six kinds
+or, for the layer kinds that ``norm_place`` maps to ``"output"``, on what
+the operator and the feed-forward give back (the reordered norm of
+arXiv:2501.00656 section 3):
+
+    h = h + rms(operator(h));  h = h + rms(feed_forward(h))
+
+then a final RMS norm and a head.  The operator is one of seven kinds
 (`OPERATOR_KINDS`):
 
 - ``"conv"``: a gated short causal convolution;
@@ -39,7 +45,16 @@ then a final RMS norm and a head.  The operator is one of six kinds
   logits, and `BlockDiffusionLoss` is its objective: the cross-entropy of
   the clean token at each masked position (no shift), weighted by the
   label's second plane.  Device scopes ``mx.bd.project`` and
-  ``mx.bd.attention``.
+  ``mx.bd.attention``;
+- ``"linear_attention"``: the gated delta rule (`contrib.nn.GatedDeltaNet`,
+  `ops/delta_rule.py`): a float32 state a head carried along the sequence in
+  chunks of 64 tokens in place of attention over it, behind one short causal
+  convolution of q, k and v; its own widths come as the mapping ``linear``
+  (``num_key_heads``, ``num_value_heads``, ``key_head_dim``,
+  ``value_head_dim``, ``conv_kernel_dim``, ``allow_neg_eigval``: the
+  published ``linear_*`` keys without the prefix).  The sequence has to be
+  whole chunks.  Device scopes ``mx.gdn.project``, ``mx.gdn.conv``,
+  ``mx.gdn.scan`` and ``mx.gdn.out``.
 
 What is a property of the layer and not of the net: ``heads`` may be a
 list, one count a layer (a model whose window layers have more query heads
@@ -48,10 +63,15 @@ settings, shaped as the published key of that name (``{"full_attention":
 {"rope_type": "yarn", "rope_theta": ..., "factor": ...,
 "partial_rotary_factor": 0.5, ...}, "sliding_attention": {"rope_type":
 "default", "rope_theta": ...}}``, `ops/lm_blocks.py` `rope_frequencies`), a
-kind it does not name keeping ``rope_theta``; ``attention_gate`` gives the
+kind it does not name keeping ``rope_theta``, and an entry whose
+``rope_theta`` is null switching the rotary positions of that kind off;
+``attention_gate`` gives the
 full_attention and sliding_attention layers a per-head sigmoid gate on the
 attention's output (then under device scopes ``mx.gqa.*`` and
-``mx.swa.*``).
+``mx.swa.*``); ``norm_place`` maps a layer kind to ``"input"`` or
+``"output"`` (a kind it does not name is pre-normed); ``qk_norm`` maps a
+layer kind to ``"head"`` (the default: an RMS norm over each query and key
+head) or ``"width"`` (one over all the heads' numbers at once).
 
 The feed-forward is a dense gated MLP in the first ``num_dense_layers``
 layers and dropless top-k routed experts in the others, to which
@@ -70,11 +90,15 @@ attention, a softmax router, three-axis rotary positions), and of JetLM's
 ``sdar_moe`` models (block_diffusion_attention layers, a softmax router, an
 untied head), and of poolside's ``laguna`` models (sliding_attention and
 full_attention layers mixed, head counts by layer, an output gate, rotary
-settings by layer kind, a sigmoid router with a shared expert), whose
+settings by layer kind, a sigmoid router with a shared expert), and of
+Ai2's ``olmo_hybrid`` models (linear_attention and full_attention layers
+mixed, the full ones with the norm on the output, a norm over the width of
+q and k and no rotary positions, a dense feed-forward everywhere), whose
 published ``config.json`` keys the arguments follow;
 `benchmarks/models/lfm2_moe.py`, `benchmarks/models/deepseek_v3.py`,
-`benchmarks/models/keye_vl2.py`, `benchmarks/models/sdar_moe.py` and
-`benchmarks/models/laguna.py` build one from such a file.
+`benchmarks/models/keye_vl2.py`, `benchmarks/models/sdar_moe.py`,
+`benchmarks/models/laguna.py` and `benchmarks/models/olmo_hybrid.py` build
+one from such a file.
 
 The routed layers hold ONE CHIP'S SHARE of their experts
 (`gluon.contrib.nn.RoutedExperts`): ``experts_held`` of ``num_experts``
@@ -92,16 +116,17 @@ from __future__ import annotations
 
 from ..block import HybridBlock
 from .. import nn
-from ..contrib.nn import (GatedMLP, GatedShortConv, GroupedQueryAttention,
-                          LatentAttention, RoutedExperts, SharedExperts,
-                          SparseAttention)
+from ..contrib.nn import (GatedDeltaNet, GatedMLP, GatedShortConv,
+                          GroupedQueryAttention, LatentAttention,
+                          RoutedExperts, SharedExperts, SparseAttention)
 
 __all__ = ["AlignedLoss", "BlockDiffusionLoss", "DecoderLayer", "DecoderLM",
            "get_decoder_lm", "OPERATOR_KINDS"]
 
 OPERATOR_KINDS = ("conv", "full_attention", "latent_attention",
                   "sparse_attention", "block_diffusion_attention",
-                  "sliding_attention")
+                  "sliding_attention", "linear_attention")
+NORM_PLACES = ("input", "output")
 
 
 class SharedAndRouted(HybridBlock):
@@ -120,16 +145,20 @@ class SharedAndRouted(HybridBlock):
 
 
 class DecoderLayer(HybridBlock):
-    """One pre-norm layer: *operator* is built by kind (*latent* holds
-    `LatentAttention`'s own widths, *sparse* `SparseAttention`'s, *grouped*
-    what `GroupedQueryAttention` takes beside its head counts: ``window``,
-    ``gate``, ``rope``), *feed_forward* is handed in.  A ``positions`` input goes to a
+    """One layer: *operator* is built by kind (*latent* holds
+    `LatentAttention`'s own widths, *sparse* `SparseAttention`'s, *linear*
+    `GatedDeltaNet`'s, *grouped* what `GroupedQueryAttention` takes beside
+    its head counts: ``window``, ``gate``, ``rope``, ``qk_norm``),
+    *feed_forward* is handed in.  *norm_place* ``"input"`` norms what the
+    operator and the feed-forward read (pre-norm), ``"output"`` what they
+    give back.  A ``positions`` input goes to a
     sparse_attention or block_diffusion_attention operator and to no other;
     a sparse_attention layer returns ``(output, alignment term)``."""
 
     def __init__(self, dim, kind, feed_forward, heads, kv_heads, head_dim,
                  rope_theta, conv_kernel, eps, init, latent=None,
-                 sparse=None, diffusion_block=None, grouped=None, **kwargs):
+                 sparse=None, diffusion_block=None, grouped=None,
+                 linear=None, norm_place="input", **kwargs):
         super().__init__(**kwargs)
         if kind not in OPERATOR_KINDS:
             raise ValueError(
@@ -137,7 +166,13 @@ class DecoderLayer(HybridBlock):
                 "grouped-query attention, multi-head latent attention, "
                 "learned sparse attention, grouped-query attention under "
                 "the block-diffusion mask, grouped-query attention through "
-                "a causal window)" % (kind, OPERATOR_KINDS))
+                "a causal window, the gated delta rule's linear attention)"
+                % (kind, OPERATOR_KINDS))
+        if norm_place not in NORM_PLACES:
+            raise ValueError("a layer's norms sit on the %s or on the %s of "
+                             "its operator and feed-forward, not %r"
+                             % (NORM_PLACES + (norm_place,)))
+        self._norm_output = norm_place == "output"
         grouped = dict(grouped or {})
         self._takes_positions = kind in ("sparse_attention",
                                          "block_diffusion_attention")
@@ -164,6 +199,24 @@ class DecoderLayer(HybridBlock):
                     dim, heads, kv_heads, head_dim, rope_theta, eps,
                     weight_initializer=init, prefix="attn_",
                     diffusion_block=diffusion_block)
+            elif kind == "linear_attention":
+                if not linear:
+                    raise ValueError(
+                        "a linear_attention layer needs linear: "
+                        "num_key_heads, num_value_heads, key_head_dim, "
+                        "value_head_dim, conv_kernel_dim")
+                if linear["num_key_heads"] != linear["num_value_heads"]:
+                    raise ValueError(
+                        "a linear_attention layer with %d key heads for %d "
+                        "value heads is not built: one state a head"
+                        % (linear["num_key_heads"],
+                           linear["num_value_heads"]))
+                self.operator = GatedDeltaNet(
+                    dim, linear["num_value_heads"], linear["key_head_dim"],
+                    linear["value_head_dim"],
+                    conv_kernel=linear["conv_kernel_dim"],
+                    allow_neg_eigval=linear.get("allow_neg_eigval", False),
+                    epsilon=eps, weight_initializer=init, prefix="gdn_")
             elif kind == "sparse_attention":
                 if not sparse:
                     raise ValueError(
@@ -185,14 +238,18 @@ class DecoderLayer(HybridBlock):
             self.feed_forward = feed_forward()
 
     def hybrid_forward(self, F, x, positions=None):
-        h = self.operator_norm(x)
+        h = x if self._norm_output else self.operator_norm(x)
         out = self.operator(h, positions) \
             if self._takes_positions and positions is not None \
             else self.operator(h)
         if self._returns_term:
             out, term = out
-        x = x + out
-        x = x + self.feed_forward(self.ffn_norm(x))
+        if self._norm_output:
+            x = x + self.operator_norm(out)
+            x = x + self.ffn_norm(self.feed_forward(x))
+        else:
+            x = x + out
+            x = x + self.feed_forward(self.ffn_norm(x))
         return (x, term) if self._returns_term else x
 
 
@@ -213,7 +270,11 @@ class DecoderLM(HybridBlock):
     a layer; *rope_parameters* maps a layer kind to its rotary settings (a
     published ``rope_parameters``); *sliding_window* is the
     sliding_attention layers' window and *attention_gate* the per-head
-    output gate of those and of the full_attention layers."""
+    output gate of those and of the full_attention layers; *linear* holds
+    the linear_attention layers' widths; *norm_place* maps a layer kind to
+    where its norms sit (``"input"`` or ``"output"``) and *qk_norm* a
+    full_attention or sliding_attention kind to ``"head"`` or
+    ``"width"``."""
 
     def __init__(self, vocab, dim, layer_types, num_dense_layers,
                  dense_hidden, expert_hidden, num_experts,
@@ -228,7 +289,8 @@ class DecoderLM(HybridBlock):
                  index_heads=None, index_head_dim=None, index_topk=None,
                  mrope_section=(), alignment_weight=1.0,
                  diffusion_block=None, rope_parameters=None,
-                 sliding_window=None, attention_gate=False, **kwargs):
+                 sliding_window=None, attention_gate=False, linear=None,
+                 norm_place=None, qk_norm=None, **kwargs):
         super().__init__(**kwargs)
         self._vocab, self._dim = vocab, dim
         layer_types = list(layer_types)
@@ -238,6 +300,7 @@ class DecoderLM(HybridBlock):
             raise ValueError("%d head counts for %d layers"
                              % (len(heads), len(layer_types)))
         rope_parameters = dict(rope_parameters or {})
+        norm_place, qk_norm = dict(norm_place or {}), dict(qk_norm or {})
         self._two_copies = "block_diffusion_attention" in layer_types
         init = weight_initializer
         latent = kv_lora_rank and {
@@ -282,11 +345,14 @@ class DecoderLM(HybridBlock):
                                "rope": rope_parameters.get(kind)}
                     if kind == "sliding_attention":
                         grouped["window"] = sliding_window
+                    if kind in qk_norm:
+                        grouped["qk_norm"] = qk_norm[kind]
                 layer = DecoderLayer(
                     dim, kind, dense if i < num_dense_layers else sparse,
                     heads[i], kv_heads or heads[i], head_dim, rope_theta,
                     conv_kernel, eps, init, latent, indexer,
-                    diffusion_block, grouped, prefix="l%d_" % i)
+                    diffusion_block, grouped, linear,
+                    norm_place.get(kind, "input"), prefix="l%d_" % i)
                 setattr(self, "l%d" % i, layer)
                 self.layers.append(layer)
             self.final_norm = nn.RMSNorm(dim, eps, prefix="final_norm_")

@@ -601,17 +601,26 @@ SHORTCONV_TILES = {"fwd": 256, "bwd": 128, "channels": 512}
 _SHORTCONV_VMEM = 10 << 20
 
 
+def causal_taps(u, conv_weight):
+    """The depthwise causal convolution ``c_t = sum_j w_j u_(t-L+1+j)`` over
+    ``(..., seq, d)`` in float32, ``u`` zero before the sequence of every
+    batch row; *conv_weight* is ``(d, L)``.  The gated short convolution's
+    and the linear-attention block's (`ops/delta_rule.py`)."""
+    taps, seq = conv_weight.shape[1], u.shape[-2]
+    u = u.astype(jnp.float32)
+    w = conv_weight.astype(jnp.float32)
+    padded = jnp.pad(u, [(0, 0)] * (u.ndim - 2) + [(taps - 1, 0), (0, 0)])
+    return sum(padded[..., j:j + seq, :] * w[:, j] for j in range(taps))
+
+
 def _gate_body(bcx, conv_weight):
     """``C * conv(B * X)`` in `jax.numpy`, the middle's definition: ``u``
     and the taps in float32 (a product of two bf16 numbers is exact there),
     the result rounded to the input's dtype once."""
-    d, taps = conv_weight.shape
+    d = conv_weight.shape[0]
     b, c, x = bcx[..., :d], bcx[..., d:2 * d], bcx[..., 2 * d:]
     u = b.astype(jnp.float32) * x.astype(jnp.float32)
-    w = conv_weight.astype(jnp.float32)
-    seq = bcx.shape[-2]
-    padded = jnp.pad(u, [(0, 0)] * (u.ndim - 2) + [(taps - 1, 0), (0, 0)])
-    conv = sum(padded[..., j:j + seq, :] * w[:, j] for j in range(taps))
+    conv = causal_taps(u, conv_weight)
     return (c.astype(jnp.float32) * conv).astype(bcx.dtype)
 
 
@@ -1265,11 +1274,37 @@ def _norm_turn_by_head(y, heads, gamma, positions, sections, theta, eps,
     return _rotary(normed, float(theta), False, positions, sections)
 
 
+def _norm_by_width_or_unturned(y, heads, gamma, norm_over, rotary, positions,
+                               theta, eps, given):
+    """`_contrib_HeadNormRotary`'s two departures, in XLA: an RMS norm over
+    each head (*gamma* ``(d,)``) or over the whole width (*gamma* ``(heads x
+    d,)``), the move to ``(batch, heads, seq, d)``, and the rotary positions
+    where the layer has them."""
+    batch, seq, width = y.shape
+    d = width // heads
+    why = " and ".join(
+        ["one norm over the whole width of %d, not a head's %d" % (width, d)]
+        * (norm_over == "width") + ["no rotary positions: nothing to turn"]
+        * (not rotary))
+    _record_headrope_plan(y, heads, None, why, (), d if rotary else 0)
+    if norm_over == "width":
+        normed = _rms_norm(y, gamma, eps).reshape(batch, seq, heads, d)
+    else:
+        normed = _rms_norm(y.reshape(batch, seq, heads, d), gamma, eps)
+    normed = normed.transpose(0, 2, 1, 3)
+    if not rotary:
+        return normed
+    if given:
+        return _rotary_given(normed, *given)
+    return _rotary(normed, float(theta), False, positions, ())
+
+
 @register_op("_contrib_HeadNormRotary", aliases=("HeadNormRotary",),
              input_names=("data", "gamma", "positions"))
 def _head_norm_rotary_op(data, gamma, *positions, num_heads=1,
                          theta=10000.0, eps=1e-5, use_positions=False,
-                         rotary_dim=0, inv_freq=(), table_scale=1.0):
+                         rotary_dim=0, inv_freq=(), table_scale=1.0,
+                         norm_over="head", rotary=True):
     """A q or k projection's output ``(batch, seq, num_heads x d)`` to the
     attention's ``(batch, num_heads, seq, d)``: ``_contrib_RMSNorm`` over
     each head by *gamma* ``(d,)``, then ``_contrib_RotaryEmbedding``
@@ -1279,14 +1314,23 @@ def _head_norm_rotary_op(data, gamma, *positions, num_heads=1,
     positions turn the first *rotary_dim* of each head alone (all of it
     where 0), at those frequencies and not *theta*'s, cos and sin times
     *table_scale* (`rope_frequencies` makes the three from a published
-    ``rope_parameters`` entry); without it this is the op it was."""
+    ``rope_parameters`` entry); without it this is the op it was.
+
+    Two departures, neither of which the kernels serve (`mx.headrope.plan`
+    says ``path`` ``xla`` and why): *norm_over* ``"width"`` norms the whole
+    ``num_heads x d`` of a token at once by a *gamma* that wide, and
+    *rotary* false leaves the heads unturned (no positions at all)."""
     given = None
     if inv_freq:
         d = data.shape[-1] // int(num_heads)
         given = (int(rotary_dim) or d, tuple(float(f) for f in inv_freq),
                  float(table_scale))
-    return _norm_turn_by_head(data, int(num_heads), gamma,
-                              positions[0] if positions else None, (),
+    positions = positions[0] if positions else None
+    if norm_over == "width" or not rotary:
+        return _norm_by_width_or_unturned(
+            data, int(num_heads), gamma, norm_over, bool(rotary), positions,
+            theta, eps, given)
+    return _norm_turn_by_head(data, int(num_heads), gamma, positions, (),
                               theta, eps, given=given)
 
 

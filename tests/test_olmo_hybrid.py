@@ -1,0 +1,792 @@
+"""Linear and full attention mixed by layer: the gated delta rule in its
+chunkwise form (`ops/delta_rule.py`: `_contrib_GatedDeltaRule` with a
+backward of its own, the short convolution with its norms, the gates, the
+gated norm), `gluon.contrib.nn.GatedDeltaNet`, `GroupedQueryAttention`'s
+norm over the width and its switched-off rotary positions, the decoder kind
+`linear_attention` and the norm's place by layer kind, against the
+recurrence token by token in float64 numpy (`benchmarks/gdn_counts.py`) and
+the plain float32 reference `benchmarks/reference/olmo_hybrid.py`, at a
+small size on the CPU with seeded weights: float32 on both sides, so only
+the order of the arithmetic differs."""
+
+import hashlib
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmarks import compare, gdn_counts  # noqa: E402
+from benchmarks.models import common as models_common  # noqa: E402
+from benchmarks.models import olmo_hybrid as family  # noqa: E402
+from benchmarks.reference import common as ref_common  # noqa: E402
+from benchmarks.reference import olmo_hybrid as reference  # noqa: E402
+from mxnet_tpu import profiler  # noqa: E402
+from mxnet_tpu.ops import attention, delta_rule, lm_blocks  # noqa: E402
+
+SEED = 2 ** 31 + 11
+
+
+def config(**changes):
+    """The cell's shapes, small: both kinds of layer, a state that is not
+    square (dk != dv), 3 heads, 4 taps, two chunks of 64."""
+    cfg = {"family": "olmo_hybrid", "model_type": "olmo_hybrid",
+           "hidden_size": 48, "intermediate_size": 80,
+           "num_attention_heads": 3, "num_key_value_heads": 3,
+           "hidden_act": "silu", "attention_bias": False,
+           "linear_num_key_heads": 3, "linear_num_value_heads": 3,
+           "linear_key_head_dim": 8, "linear_value_head_dim": 16,
+           "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
+           "rope_parameters": {"rope_theta": None},
+           "layer_types": ["linear_attention", "linear_attention",
+                           "full_attention"],
+           "rms_norm_eps": 1e-6, "tie_word_embeddings": False,
+           "num_hidden_layers": 3, "vocab_size": 96,
+           "initializer_range": 0.02, "embedding_initializer_range": 1.0,
+           "conv_initializer_range": 0.2887, "gate_init_seed": 0,
+           "train": {"optimizer": "sgd", "lr": 0.01, "momentum": 0.9,
+                     "wd": 0.0, "multi_precision": False,
+                     "sequence_length": 128, "per_chip_batch": 2}}
+    cfg.update(changes)
+    return cfg
+
+
+def cut(kinds, seq=128, **changes):
+    cfg = config(layer_types=list(kinds), num_hidden_layers=len(kinds),
+                 **changes)
+    cfg["train"] = dict(cfg["train"], sequence_length=seq)
+    return cfg
+
+
+def seeded(cfg, seed=SEED):
+    """``(net, loss, names, reference parameters)`` from one seed."""
+    table = reference.param_table(cfg)
+    net, loss = family.build(cfg)
+    names = models_common.seeded_net(
+        net, table, ref_common.init_params(table, seed))
+    return net, loss, names, ref_common.init_params(table, seed)
+
+
+def highest(fn, *args):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(fn)(*args)
+
+
+# -- the rule against the recurrence in float64 ---------------------------------
+def recurrence_backward(q, k, v, g, b, dout):
+    """The five gradients of `gdn_counts.recurrence`'s output against
+    *dout*, by its own reverse walk in float64 numpy: every state kept, each
+    line of the forward step undone in turn."""
+    q, k, v, g, b, dout = (np.asarray(x, np.float64)
+                           for x in (q, k, v, g, b, dout))
+    batch, seq, heads, dk = q.shape
+    dv = v.shape[-1]
+    grads = [np.zeros_like(x) for x in (q, k, v, g, b)]
+    dq, dk_, dv_, dg, db = grads
+    for i in range(batch):
+        for h in range(heads):
+            states = [np.zeros((dk, dv))]
+            for t in range(seq):
+                decayed = np.exp(g[i, t, h]) * states[-1]
+                states.append(decayed + np.outer(
+                    b[i, t, h] * k[i, t, h],
+                    v[i, t, h] - decayed.T @ k[i, t, h]))
+            dstate = np.zeros((dk, dv))
+            for t in reversed(range(seq)):
+                a, kt, bt = np.exp(g[i, t, h]), k[i, t, h], b[i, t, h]
+                before, after = states[t], states[t + 1]
+                decayed = a * before
+                held = v[i, t, h] - decayed.T @ kt
+                dq[i, t, h] = after @ dout[i, t, h]
+                dstate = dstate + np.outer(q[i, t, h], dout[i, t, h])
+                db[i, t, h] = kt @ dstate @ held
+                dk_[i, t, h] = bt * (dstate @ held)
+                dheld = bt * (dstate.T @ kt)
+                dv_[i, t, h] = dheld
+                dk_[i, t, h] -= decayed @ dheld
+                ddecayed = dstate - np.outer(kt, dheld)
+                dg[i, t, h] = a * np.sum(ddecayed * before)
+                dstate = a * ddecayed
+    return grads
+
+
+def rule_inputs(regime, batch=2, seq=128, heads=2, dk=8, dv=16, seed=0):
+    rng = np.random.default_rng(seed)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = unit(rng.normal(size=(batch, seq, heads, dk))) / np.sqrt(dk)
+    k = unit(rng.normal(size=(batch, seq, heads, dk)))
+    v = rng.normal(size=(batch, seq, heads, dv))
+    g = -rng.uniform(0.0, 1.6, (batch, seq, heads))
+    b = rng.uniform(0.0, 2.0, (batch, seq, heads))
+    if regime == "decays-near-0":
+        g = -rng.uniform(5.0, 30.0, (batch, seq, heads))
+    elif regime == "decays-near-1":
+        g = -rng.uniform(0.0, 1e-4, (batch, seq, heads))
+    elif regime == "b-near-2":
+        b = rng.uniform(1.9, 2.0, (batch, seq, heads))
+        g = -rng.uniform(0.0, 0.05, (batch, seq, heads))
+    elif regime == "keys-nearly-alike":
+        # the triangular system at its hardest: every A[t, j] near b_t
+        k = unit(k[:, :1] + 0.05 * rng.normal(size=k.shape))
+        g = -rng.uniform(0.0, 0.02, (batch, seq, heads))
+    return q, k, v, g, b
+
+
+REGIMES = ("usual", "decays-near-0", "decays-near-1", "b-near-2",
+           "keys-nearly-alike")
+#: float32 in chunks against float64 token by token, of the largest entry
+RULE_TOLERANCE = 2e-5
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 64])
+@pytest.mark.parametrize("regime", REGIMES)
+def test_the_rule_and_its_five_gradients_are_the_recurrence_s(regime, chunk):
+    q, k, v, g, b = rule_inputs(regime)
+    want, _ = gdn_counts.recurrence(q, k, v, g, b)
+    dout = np.random.default_rng(7).normal(size=want.shape)
+    f32 = [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, b)]
+    got, pull = jax.vjp(lambda *a: delta_rule.gated_delta_rule(*a, chunk),
+                        *f32)
+    scale = np.abs(want).max()
+    assert np.abs(np.asarray(got) - want).max() <= RULE_TOLERANCE * scale
+    grads = pull(jnp.asarray(dout, jnp.float32))
+    for name, mine, theirs in zip("qkvgb", grads, recurrence_backward(
+            q, k, v, g, b, dout)):
+        scale = max(np.abs(theirs).max(), 1e-30)
+        assert np.abs(np.asarray(mine) - theirs).max() \
+            <= 10 * RULE_TOLERANCE * scale, (name, regime, chunk)
+
+
+@pytest.mark.parametrize("regime", REGIMES)
+def test_the_result_does_not_depend_on_the_chunk(regime):
+    """The chunk is a property of the algorithm: 16, 32 and 64 agree to
+    float32 rounding, forward and backward."""
+    f32 = [jnp.asarray(x, jnp.float32) for x in rule_inputs(regime)]
+    dout = jnp.asarray(np.random.default_rng(8).normal(
+        size=f32[2].shape), jnp.float32)
+    outs = {}
+    for chunk in (16, 32, 64):
+        got, pull = jax.vjp(
+            lambda *a: delta_rule.gated_delta_rule(*a, chunk), *f32)
+        outs[chunk] = (np.asarray(got),) + tuple(
+            np.asarray(x) for x in pull(dout))
+    for chunk in (16, 32):
+        for i, (mine, theirs) in enumerate(zip(outs[chunk], outs[64])):
+            scale = max(np.abs(theirs).max(), 1e-30)
+            # the gradients are sums of products that cancel (the decay's,
+            # where the decay is all but zero, is 1e-3 of its terms)
+            assert np.abs(mine - theirs).max() <= (
+                10 if i else 1) * RULE_TOLERANCE * scale
+
+
+def test_a_sequence_that_is_not_whole_chunks_is_refused():
+    q = jnp.zeros((1, 100, 2, 8))
+    with pytest.raises(ValueError, match="100 .*not a multiple of the "
+                                         "chunk of 64"):
+        delta_rule._gated_delta_rule_op(q, q, jnp.zeros((1, 100, 2, 16)),
+                                        jnp.zeros((1, 100, 2)),
+                                        jnp.zeros((1, 100, 2)))
+    with pytest.raises(ValueError, match="query and key"):
+        delta_rule._gated_delta_rule_op(q[:, :64], q[:, :64, :1],
+                                        jnp.zeros((1, 64, 2, 16)),
+                                        jnp.zeros((1, 64, 2)),
+                                        jnp.zeros((1, 64, 2)))
+
+
+def test_bf16_inputs_come_back_in_bf16_and_the_state_stays_float32():
+    q, k, v, g, b = rule_inputs("usual")
+    want, _ = gdn_counts.recurrence(q, k, v, g, b)
+    bf = jnp.bfloat16
+    got = delta_rule.gated_delta_rule(
+        jnp.asarray(q, bf), jnp.asarray(k, bf), jnp.asarray(v, bf),
+        jnp.asarray(g, jnp.float32), jnp.asarray(b, bf), 64)
+    assert got.dtype == bf
+    # inputs rounded to 3 digits, nothing else: far inside a percent
+    assert np.abs(np.asarray(got, np.float64) - want).max() \
+        <= 2e-2 * np.abs(want).max()
+    jaxpr = str(jax.make_jaxpr(lambda *a: delta_rule.gated_delta_rule(
+        *a, 64))(*(jnp.asarray(x, bf) for x in (q, k, v)),
+                 jnp.asarray(g, jnp.float32), jnp.asarray(b, bf)))
+    assert "f32[2,2,8,16]" in jaxpr and "bf16[2,2,8,16]" not in jaxpr
+
+
+def test_the_plan_span_and_the_step_stat_say_what_a_call_keeps():
+    since = max([s.id for s in profiler.spans()] or [0])
+    q = jnp.zeros((1, 4096, 30, 96), jnp.bfloat16)
+    with profiler.collect_step_stats() as stats:
+        jax.eval_shape(
+            lambda *a: delta_rule._gated_delta_rule_op(*a), q, q,
+            jnp.zeros((1, 4096, 30, 192), jnp.bfloat16),
+            jnp.zeros((1, 4096, 30), jnp.float32),
+            jnp.zeros((1, 4096, 30), jnp.bfloat16))
+    plan, = [s.args for s in profiler.spans()
+             if s.name == "mx.gdn.plan" and s.id > since]
+    assert plan["path"] == "xla" and plan["chunk"] == 64 \
+        and plan["chunks"] == 64 and plan["heads"] == 30
+    assert plan["state_kept_bytes"] == 64 * 30 * 96 * 192 * 4 \
+        == gdn_counts.state_kept_bytes(1, 4096, 30, 96, 192, 64)
+    assert plan["per_token_state_bytes"] == 64 * plan["state_kept_bytes"]
+    assert len(stats["gdn_state_kept_bytes"]) == 1      # one a traced call
+    profiler.fold_step_stats(
+        {"gdn_state_kept_bytes": np.asarray([plan["state_kept_bytes"]] * 3,
+                                            np.float32)})
+    from mxnet_tpu.observability import metrics
+    assert "mxnet_gdn_state_kept_bytes 424673280.0" in metrics.exposition()
+
+
+# -- the operators around the rule -----------------------------------------------
+def test_the_convolution_its_silu_and_the_norms_are_the_reference_s():
+    rng = np.random.default_rng(3)
+    heads, dk, dv, seq = 3, 8, 16, 40
+    x = jnp.asarray(rng.normal(size=(2, seq, heads * (2 * dk + dv))),
+                    jnp.float32)
+    w = jnp.asarray(rng.normal(size=(x.shape[-1], 4)) * 0.3, jnp.float32)
+
+    def theirs(x, w):
+        y = reference.conv_silu(x, w)
+
+        def unit(z):
+            z = z.reshape(2, seq, heads, dk)
+            return z / jnp.sqrt(jnp.sum(z * z, -1, keepdims=True) + 1e-6)
+
+        return (unit(y[..., :heads * dk]) / np.sqrt(dk),
+                unit(y[..., heads * dk:2 * heads * dk]),
+                y[..., 2 * heads * dk:].reshape(2, seq, heads, dv))
+
+    def mine(x, w):
+        return delta_rule._short_conv_heads(x, w, num_heads=heads,
+                                            key_dim=dk, eps=1e-6)
+
+    got, pull = jax.vjp(mine, x, w)
+    want, pull_want = jax.vjp(theirs, x, w)
+    douts = tuple(jnp.asarray(rng.normal(size=o.shape), jnp.float32)
+                  for o in want)
+    for a, b in zip(got + pull(douts), want + pull_want(douts)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-6)
+    # position 0 sees zeros before it: its convolution is the last tap's
+    first = jax.nn.silu(x[:, 0] * w[:, 3])
+    np.testing.assert_allclose(
+        np.asarray(got[2][:, 0]).reshape(2, -1),
+        np.asarray(first[:, 2 * heads * dk:]), rtol=1e-6)
+    with pytest.raises(ValueError, match="channels"):
+        delta_rule._short_conv_heads(x[..., :-1], w[:-1], num_heads=heads,
+                                     key_dim=dk)
+
+
+def test_the_taps_are_the_gated_short_convolution_s():
+    """One definition of the depthwise causal taps, in `ops/lm_blocks.py`:
+    `causal_taps` over ``B * X`` is what `_gate_body` multiplies by ``C``,
+    and what the linear-attention block's convolution calls."""
+    assert delta_rule.causal_taps is lm_blocks.causal_taps
+    rng = np.random.default_rng(4)
+    bcx = jnp.asarray(rng.normal(size=(2, 24, 3 * 16)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(16, 3)), jnp.float32)
+    b, c, x = bcx[..., :16], bcx[..., 16:32], bcx[..., 32:]
+    np.testing.assert_array_equal(
+        np.asarray(c * lm_blocks.causal_taps(b * x, w)),
+        np.asarray(lm_blocks._gate_body(bcx, w)))
+
+
+def test_the_gates_are_float32_and_the_doubling_is_a_flag():
+    a = jnp.asarray([[[0.3, -2.0]]], jnp.bfloat16)
+    b = jnp.asarray([[[0.0, 5.0]]], jnp.bfloat16)
+    a_log, dt = jnp.asarray([0.5, 2.0]), jnp.asarray([-1.0, 0.2])
+    g, beta = delta_rule._delta_rule_gates(a, b, a_log, dt,
+                                           allow_neg_eigval=True)
+    assert g.dtype == jnp.float32 and beta.dtype == jnp.bfloat16
+    want = -np.exp([0.5, 2.0]) * np.log1p(np.exp(
+        np.asarray(a, np.float64)[0, 0] + [-1.0, 0.2]))
+    np.testing.assert_allclose(np.asarray(g)[0, 0], want, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(beta, np.float32)[0, 0],
+                               [1.0, 2 / (1 + np.exp(-5.0))], rtol=1e-2)
+    _, single = delta_rule._delta_rule_gates(a, b, a_log, dt)
+    np.testing.assert_allclose(np.asarray(single, np.float32) * 2,
+                               np.asarray(beta, np.float32), rtol=1e-2)
+
+
+def test_the_gated_norm_is_the_reference_s():
+    rng = np.random.default_rng(5)
+    o = jnp.asarray(rng.normal(size=(2, 10, 3, 16)), jnp.float32)
+    z = jnp.asarray(rng.normal(size=(2, 10, 48)), jnp.float32)
+    gamma = jnp.asarray(rng.normal(size=(16,)), jnp.float32)
+
+    def theirs(o, z, gamma):
+        return (reference.rms(o, gamma, 1e-6) * jax.nn.silu(
+            z.reshape(2, 10, 3, 16))).reshape(2, 10, 48)
+
+    got, pull = jax.vjp(lambda *a: delta_rule._gated_rms_norm(*a, eps=1e-6),
+                        o, z, gamma)
+    want, pull_want = jax.vjp(theirs, o, z, gamma)
+    dout = jnp.asarray(rng.normal(size=want.shape), jnp.float32)
+    for a, b in zip((got,) + pull(dout), (want,) + pull_want(dout)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-6)
+
+
+# -- what the accepted cells run is what they ran --------------------------------
+def jaxpr_sha(fn, *avals):
+    text = re.sub(r" at \S+:\d+", "", str(jax.make_jaxpr(fn)(*avals)))
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+YARN = {"rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+        "original_max_position_embeddings": 8192, "beta_slow": 1,
+        "beta_fast": 32, "attention_factor": 1.4852030263919618,
+        "partial_rotary_factor": 0.5}
+#: `GroupedQueryAttention` forward and backward at 512 positions in bf16 at
+#: the arguments the accepted cells build it with, traced on the parent tree
+#: (commit 186ea64)
+PARENT_BLOCKS = {
+    "lfm2-plain": (
+        (2048, 32, 8, 64, 1000000.0, 1e-5), {},
+        "60a577acfa1b821a600607e2205a171f7c89a35b2c9cca9a7d6cb238fc2e74b6"),
+    "sdar-block-diffusion": (
+        (2048, 32, 4, 128, 1000000.0, 1e-6), {"diffusion_block": 4},
+        "7379895a85ffea5bddc00d92cfe68eb893d1347ab1637aeefc9695c6da1e321a"),
+    "laguna-window-gate": (
+        (3072, 72, 8, 128), {
+            "epsilon": 1e-6, "gate": True, "window": 128,
+            "rope": {"rope_type": "default", "rope_theta": 10000,
+                     "partial_rotary_factor": 1}},
+        "8edcf6de1172a5cdc172dca60a478a938fbab74376b779dc45f876423504c7d5"),
+    "laguna-full-gate-yarn": (
+        (3072, 48, 8, 128), {"epsilon": 1e-6, "gate": True, "rope": YARN},
+        "08942b09294a13d698fe9a51e77cb4ef01ace12848c24d8556f3e1b16b22e14f"),
+}
+
+
+def block_step(block, seq, dim):
+    """``(step, avals)``: a Gluon block's graph, forward and backward in
+    bf16, as a function of its arguments."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import executor
+    graph = executor._build_eval(block(mx.sym.var("x")), True)
+    bf = jnp.bfloat16
+    avals = {p.name: jax.ShapeDtypeStruct(p.shape, bf)
+             for p in block.collect_params().values()}
+    avals["x"] = jax.ShapeDtypeStruct((1, seq, dim), bf)
+
+    def step(args, dout):
+        def objective(args):
+            out, = graph(args, {}, None)[0]
+            return jnp.sum(out.astype(jnp.float32) * dout)
+        return jax.grad(objective)(args)
+
+    return step, (avals, jax.ShapeDtypeStruct((1, seq, dim), jnp.float32))
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_BLOCKS))
+def test_grouped_query_attention_at_today_s_arguments_is_the_parent_s(case):
+    from mxnet_tpu.gluon.contrib.nn import GroupedQueryAttention
+    args, kwargs, want = PARENT_BLOCKS[case]
+    step, avals = block_step(
+        GroupedQueryAttention(*args, prefix="attn_", **kwargs), 512,
+        args[0])
+    assert jaxpr_sha(step, *avals) == want
+
+
+def test_the_gated_short_convolution_is_the_parent_s():
+    bf = jnp.bfloat16
+    avals = (jax.ShapeDtypeStruct((1, 512, 2048), bf),
+             jax.ShapeDtypeStruct((3 * 2048, 2048), bf),
+             jax.ShapeDtypeStruct((2048, 3), bf),
+             jax.ShapeDtypeStruct((2048, 2048), bf))
+
+    def conv(*a):
+        return jax.grad(lambda *a: jnp.sum(lm_blocks._gated_short_conv(
+            *a).astype(jnp.float32)), argnums=(0, 1, 2, 3))(*a)
+
+    assert jaxpr_sha(conv, *avals) == \
+        "2535d566acaa8484993f379e0d0228ba28d62f935b9912dbff8fcdc60a24dcdf"
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_the_new_cell_s_flash_kernels_trace_as_the_parent_s(kernel):
+    """The six language cells' kernels are pinned by `tests/test_laguna.py`;
+    this cell's full layer calls the causal pair at 3072 positions and heads
+    of 128, which is the parent's program to the letter."""
+    s, d = 3072, 128
+    q = jax.ShapeDtypeStruct((1, 2, s, d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((2, 1, s), jnp.float32)
+    if kernel == "fwd":
+        got = jaxpr_sha(lambda q, k, v: attention._flash_fwd_pallas(
+            q, k, v, True, d ** -0.5, with_lse=True), q, q, q)
+    else:
+        got = jaxpr_sha(
+            lambda q, k, v, o, l, do: attention._flash_bwd_pallas(
+                q, k, v, o, l, do, True, d ** -0.5), q, q, q, q, lse, q)
+    assert got == {
+        "fwd": "28971073db70a223f8416226abeeb7d4268ac412ce40cd52a17ca3c"
+               "98c59052d",
+        "bwd": "a6413071d686a237f491346c282e6c0468aea9b706ccf95c67cdc99"
+               "facf3bc90"}[kernel]
+
+
+# -- the blocks and the decoder ---------------------------------------------------
+def test_the_attention_block_s_two_departures():
+    from mxnet_tpu.gluon.contrib.nn import GroupedQueryAttention
+    plain = GroupedQueryAttention(48, 3, 3, 16)
+    assert plain._group == {} and plain.q_gamma.shape == (16,)
+    wide = GroupedQueryAttention(48, 3, 3, 16, qk_norm="width",
+                                 rope={"rope_theta": None})
+    assert wide.q_gamma.shape == (48,) and wide.k_gamma.shape == (48,)
+    assert wide._rotary == {"rotary": False, "norm_over": "width"}
+    assert wide._group == {"__scope__": "mx.gqa.project"}
+    assert wide._after["out"] == {"__scope__": "mx.gqa.out"}
+    # either departure alone takes the scopes; a theta keeps the positions
+    assert GroupedQueryAttention(48, 3, 3, 16, rope={"rope_theta": None}
+                                 )._group
+    turned = GroupedQueryAttention(48, 3, 3, 16, qk_norm="width",
+                                   rope={"rope_theta": 10000.0})
+    assert "rotary" not in turned._rotary \
+        and turned._rotary["norm_over"] == "width"
+    with pytest.raises(ValueError, match="qk_norm 'rows' is neither"):
+        GroupedQueryAttention(48, 3, 3, 16, qk_norm="rows")
+
+
+@pytest.mark.parametrize("norm_over,rotary", [("width", False),
+                                              ("width", True),
+                                              ("head", False)])
+def test_the_head_norm_op_s_departures_say_why_they_stay_in_xla(norm_over,
+                                                                rotary):
+    rng = np.random.default_rng(6)
+    y = jnp.asarray(rng.normal(size=(2, 12, 3 * 16)), jnp.float32)
+    gamma = jnp.asarray(rng.normal(size=(48 if norm_over == "width"
+                                         else 16,)), jnp.float32)
+    since = max([s.id for s in profiler.spans()] or [0])
+    got = lm_blocks._head_norm_rotary_op(
+        y, gamma, num_heads=3, theta=10000.0, eps=1e-6, norm_over=norm_over,
+        rotary=rotary)
+    if norm_over == "width":
+        normed = reference.rms(y, gamma, 1e-6).reshape(2, 12, 3, 16)
+    else:
+        normed = reference.rms(y.reshape(2, 12, 3, 16), gamma, 1e-6)
+    want = normed.transpose(0, 2, 1, 3)
+    if rotary:
+        want = lm_blocks._rotary(want, 10000.0, False, None, ())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+    plan, = [s.args for s in profiler.spans()
+             if s.name == "mx.headrope.plan" and s.id > since]
+    assert plan["path"] == "xla"
+    assert ("whole width of 48" in plan["why"]) == (norm_over == "width")
+    assert ("no rotary positions" in plan["why"]) == (not rotary)
+    assert plan["rotary_dim"] == (16 if rotary else 0)
+
+
+def test_a_decoder_layer_of_the_kind_needs_its_widths():
+    from mxnet_tpu.gluon.model_zoo import decoder
+    assert decoder.OPERATOR_KINDS[-1] == "linear_attention"
+    with pytest.raises(ValueError, match="needs linear: num_key_heads"):
+        decoder.get_decoder_lm(
+            vocab=32, dim=48, layer_types=["linear_attention"],
+            num_dense_layers=1, dense_hidden=64, expert_hidden=0,
+            num_experts=0, num_experts_per_tok=0)
+    with pytest.raises(ValueError, match="one state a head"):
+        decoder.get_decoder_lm(
+            vocab=32, dim=48, layer_types=["linear_attention"],
+            num_dense_layers=1, dense_hidden=64, expert_hidden=0,
+            num_experts=0, num_experts_per_tok=0,
+            linear={"num_key_heads": 2, "num_value_heads": 4,
+                    "key_head_dim": 8, "value_head_dim": 16,
+                    "conv_kernel_dim": 4})
+    with pytest.raises(ValueError, match="the gated delta rule's linear "
+                                         "attention"):
+        decoder.get_decoder_lm(
+            vocab=32, dim=48, layer_types=["recurrent"], num_dense_layers=1,
+            dense_hidden=64, expert_hidden=0, num_experts=0,
+            num_experts_per_tok=0)
+    with pytest.raises(ValueError, match="on the input or on the output"):
+        decoder.get_decoder_lm(
+            vocab=32, dim=48, layer_types=["full_attention"],
+            num_dense_layers=1, dense_hidden=64, expert_hidden=0,
+            num_experts=0, num_experts_per_tok=0, heads=3,
+            norm_place={"full_attention": "middle"})
+
+
+def test_the_family_builds_the_norms_places_by_kind():
+    net, _ = family.build(config())
+    kinds = [type(layer.operator).__name__ for layer in net.layers]
+    assert kinds == ["GatedDeltaNet", "GatedDeltaNet",
+                     "GroupedQueryAttention"]
+    assert [layer._norm_output for layer in net.layers] == [False, False,
+                                                            True]
+    gdn, full = net.layers[0].operator, net.layers[2].operator
+    assert (gdn._heads, gdn._dk, gdn._dv, gdn._neg) == (3, 8, 16, True)
+    assert gdn.qkv_weight.shape == (3 * (8 + 8 + 16), 48)
+    assert gdn.conv_weight.shape == (96, 4)
+    assert full._rotary == {"rotary": False, "norm_over": "width"}
+    # pre-norm stays the default: a net built without the mapping has it
+    from mxnet_tpu.gluon.model_zoo import decoder
+    net = decoder.get_decoder_lm(
+        vocab=32, dim=48, layer_types=["full_attention"],
+        num_dense_layers=1, dense_hidden=64, expert_hidden=0, num_experts=0,
+        num_experts_per_tok=0, heads=3)
+    assert not net.layers[0]._norm_output
+
+
+def test_the_compiled_layers_lie_under_their_scopes():
+    """The lowered step names the four groups of a linear layer and the
+    three of a full one, and the plan spans say which path each took."""
+    cfg = cut(["linear_attention", "full_attention"])
+    net, loss, _, _ = seeded(cfg)
+    (x, y), = family.batches(cfg, SEED, 1, 2)
+    trainer = models_common.make_trainer(net, loss, cfg["train"],
+                                         jax.devices()[:1])
+    since = max([s.id for s in profiler.spans()] or [0])
+    trainer.fit_batch(x, y)
+    names = [n for n in profiler.scope_map("parallel_step").values() if n]
+    for scope in ("mx.gdn.project", "mx.gdn.conv", "mx.gdn.scan",
+                  "mx.gdn.out", "mx.gqa.project", "mx.gqa.attention",
+                  "mx.gqa.out"):
+        found = [n for n in names
+                 if re.search(r"[/(]%s/" % re.escape(scope), n)]
+        assert found, scope
+        # forward and backward both: the rule's own backward keeps the name
+        assert any("transpose(" in n for n in found), scope
+        assert any("transpose(" not in n for n in found), scope
+    spans = [s for s in profiler.spans() if s.id > since]
+    assert {s.args["path"] for s in spans if s.name == "mx.gdn.plan"} == \
+        {"xla"}
+    whys = {s.args["why"] for s in spans if s.name == "mx.headrope.plan"}
+    assert whys == {"one norm over the whole width of 48, not a head's 16 "
+                    "and no rotary positions: nothing to turn"}
+
+
+# -- the whole model ----------------------------------------------------------
+KINDS = {"a-linear-layer": cut(["linear_attention"]),
+         "a-full-layer": cut(["full_attention"]),
+         "all-at-two-chunks": config(),
+         "all-at-three-chunks": cut(config()["layer_types"], seq=192),
+         "b-not-doubled": config(linear_allow_neg_eigval=False)}
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_logits_loss_and_every_gradient_agree_with_the_reference(kind):
+    """Through `ParallelTrainer.fit_batch`: the loss a step reports is the
+    mean next-token cross-entropy, and every leaf's gradient is the
+    reference's.  Tolerances: float32 on both sides, summed in another
+    order (2e-4 of a leaf's largest entry, as the other families')."""
+    import mxnet_tpu as mx
+    cfg = KINDS[kind]
+    seq = cfg["train"]["sequence_length"]
+    net, loss, names, params = seeded(cfg)
+    (x, y), = family.batches(cfg, SEED, 1, 2)
+    got = net(mx.nd.array(x, dtype="int32")).asnumpy()
+    assert got.shape == (2, seq, 96)
+    want = highest(lambda p: reference.logits(p, cfg, x), params)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-4, atol=2e-6)
+
+    trainer = models_common.make_trainer(net, loss, cfg["train"],
+                                         jax.devices()[:1])
+    got_loss = float(trainer.fit_batch(x, y))
+    value, grads = highest(jax.value_and_grad(
+        lambda p: reference.loss_sum(p, cfg, x, y)), params)
+    assert got_loss == pytest.approx(float(value) / 2, rel=1e-5)
+    assert set(names) == set(grads)
+    lr = cfg["train"]["lr"]
+    for ref_name, prog_name in names.items():
+        g = -np.asarray(trainer._opt_state[prog_name][0]) / lr
+        w = np.asarray(grads[ref_name]) / 2
+        scale = max(np.abs(w).max(), 1e-12)
+        assert np.abs(g - w).max() <= 2e-4 * scale, ref_name
+        assert np.abs(w).max() > 0, ref_name
+
+
+def test_a_net_refuses_a_sequence_that_is_not_whole_chunks():
+    import mxnet_tpu as mx
+    cfg = cut(["linear_attention"], seq=100)
+    net, _, _, _ = seeded(cfg)
+    (x, _), = family.batches(cfg, SEED, 1, 2)
+    with pytest.raises(Exception, match="not a multiple of the chunk of 64"):
+        net(mx.nd.array(x, dtype="int32")).asnumpy()
+
+
+def test_three_trainer_steps_follow_the_reference():
+    cfg = config()
+    train = cfg["train"]
+    table = reference.param_table(cfg)
+    net, loss, names, params = seeded(cfg)
+    batches = family.batches(cfg, SEED, 3, 2)
+    trainer = models_common.make_trainer(net, loss, train, jax.devices()[:1])
+    to_ref = {prog: ref for ref, prog in names.items()}
+    got = {"losses": []}
+    for i, (x, y) in enumerate(batches):
+        got["losses"].append(float(trainer.fit_batch(x, y)))
+        if i == 0:
+            mom = {n: trainer._opt_state[n][0] for n in trainer.param_names}
+            got["first_update_norms"] = ref_common.leaf_norms(mom)
+            first = {to_ref[n]: np.asarray(a) for n, a in mom.items()}
+    dist = ref_common.distance_from_init(
+        table, SEED, {to_ref[n]: trainer._params[n]
+                      for n in trainer.param_names})
+    got["total_update_norms"] = {names[r]: v for r, v in dist.items()}
+    with jax.default_matmul_precision("highest"):
+        ref = ref_common.follow_steps(
+            lambda p, x, y: reference.loss_sum(p, cfg, x, y), params,
+            batches, {"lr": train["lr"], "momentum": train["momentum"],
+                      "wd": train["wd"]},
+            lambda p: ref_common.distance_from_init(table, SEED, p),
+            rows_per_block=1, first_update=first)
+    for name, (value, detail) in compare.training_numbers(
+            got, ref, names).items():
+        assert value <= 1e-4, (name, value, detail)
+
+
+@pytest.mark.parametrize("sight", ["no_erase", "single_b"])
+def test_the_rule_s_controls_move_the_reference(sight):
+    """With the erase term left out, or ``b`` not doubled, the reference is
+    another model: its gradients move, the linear layers' most."""
+    cfg = config()
+    _, _, _, params = seeded(cfg)
+    (x, y), = family.batches(cfg, SEED, 1, 2)
+    own, grads = highest(jax.value_and_grad(
+        lambda p: reference.loss_sum(p, cfg, x, y)), params)
+    other, moved = highest(jax.value_and_grad(
+        lambda p: reference.loss_sum(p, cfg, x, y, sight=sight)), params)
+    # (at seeded weights the loss is the logarithm of the rows held whatever
+    # the layers compute: it moves in the sixth digit, the gradients do not)
+    assert float(own) != float(other)
+    gap = {n: float(jnp.linalg.norm(moved[n] - grads[n])
+                    / jnp.linalg.norm(grads[n])) for n in grads}
+    assert gap["l0.wqkv"] > 0.05 and gap["l1.wo"] > 0.02
+    with pytest.raises(ValueError, match="sight"):
+        reference.linear_attention(params, 0, cfg, jnp.zeros((1, 64, 48)),
+                                   sight="causal")
+
+
+def test_the_vocabulary_s_slice_is_the_uncut_model_s_first_columns():
+    """Logits over the rows held are the uncut reference's first columns:
+    the embedding's and the head's rows are what a share of the vocabulary
+    cuts, and nothing else."""
+    whole = config(vocab_size=8 * 96)
+    table = reference.param_table(whole)
+    params = ref_common.init_params(table, SEED)
+    held = dict(params, embed=params["embed"][:96], head=params["head"][:96])
+    cfg = config()
+    net, _ = family.build(cfg)
+    models_common.seeded_net(net, reference.param_table(cfg), held)
+    (x, _), = family.batches(cfg, SEED, 1, 2)       # ids from the rows held
+    import mxnet_tpu as mx
+    got = net(mx.nd.array(x, dtype="int32")).asnumpy()
+    want = highest(lambda p: reference.logits(p, whole, x), params)
+    assert want.shape == (2, 128, 768)
+    np.testing.assert_allclose(got, np.asarray(want)[..., :96], rtol=2e-4,
+                               atol=2e-6)
+
+
+def test_the_step_counts_the_state_it_keeps():
+    cfg = cut(["linear_attention", "linear_attention"])
+    net, loss, _, _ = seeded(cfg)
+    (x, y), = family.batches(cfg, SEED, 1, 2)
+    trainer = models_common.make_trainer(net, loss, cfg["train"],
+                                         jax.devices()[:1])
+    trainer.fit_batch(x, y)
+    trainer.flush_step_stats()
+    from mxnet_tpu.observability import metrics
+    kept = 2 * gdn_counts.state_kept_bytes(2, 128, 3, 8, 16, 64)
+    assert "mxnet_gdn_state_kept_bytes %s" % float(kept) \
+        in metrics.exposition()
+
+
+# -- compiled for the described chip --------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """A chipless compile cannot be read back from the persistent cache:
+    off around these tests, so that they stay silent."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def scan_lengths(jaxpr):
+    """The trip counts of every loop of a jaxpr, nested ones too (a
+    `while` that is no scan counts as -1)."""
+    found = []
+    for eqn in jaxpr.eqns if hasattr(jaxpr, "eqns") else jaxpr.jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(eqn.params["length"])
+        elif eqn.primitive.name == "while":
+            found.append(-1)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) \
+                    else [value]:
+                if hasattr(inner, "eqns") or hasattr(inner, "jaxpr"):
+                    found.extend(scan_lengths(inner))
+    return found
+
+
+def test_a_linear_layer_compiles_for_the_described_chip_with_no_square_array(
+        one_chip, no_cache):
+    """The cell's linear layer, forward and backward, at 4096 positions and
+    30 heads of 96 x 192 in bf16, compiled for a v5e: no array of the
+    compiled program has two axes of the sequence, none is a state a token
+    ``(S, H, dk, dv)`` in any order of its axes, the loops are the chunk
+    scans (64 steps) and the solve's (a chunk's rows), and what the forward
+    keeps is the plan's."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import executor
+    from mxnet_tpu.gluon.contrib.nn import GatedDeltaNet
+    seq, dim, heads, dk, dv = 4096, 3840, 30, 96, 192
+    block = GatedDeltaNet(dim, heads, dk, dv, conv_kernel=4,
+                          allow_neg_eigval=True, epsilon=1e-6)
+    graph = executor._build_eval(block(mx.sym.var("x")), True)
+    bf = jnp.bfloat16
+    avals = {p.name: jax.ShapeDtypeStruct(p.shape, bf, sharding=one_chip)
+             for p in block.collect_params().values()}
+    avals["x"] = jax.ShapeDtypeStruct((1, seq, dim), bf, sharding=one_chip)
+    since = max([s.id for s in profiler.spans()] or [0])
+
+    def step(args, dout):
+        def objective(args):
+            out, = graph(args, {}, None)[0]
+            return jnp.sum(out.astype(jnp.float32) * dout)
+        return jax.grad(objective)(args)
+
+    compiled = jax.jit(step).lower(avals, jax.ShapeDtypeStruct(
+        (1, seq, dim), jnp.float32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\[(\d+,)*%d,(\d+,)*%d[,\]]" % (seq, seq), text)
+    shapes = {tuple(int(n) for n in m.split(","))
+              for m in re.findall(r"\[((?:\d+,)+\d+)\]", text)}
+    per_token = seq * heads * dk * dv
+    assert not [s for s in shapes if int(np.prod(s)) >= per_token]
+    # the largest arrays are the chunk-boundary states: S / C a head
+    assert (seq // 64, 1, heads, dk, dv) in shapes
+    # two loops, the scan over the chunks and its reverse walk: 64 steps
+    # each (`scan_lengths` reads them where they are written, in the jaxpr)
+    assert text.count(" while(") == 2
+    assert scan_lengths(jax.make_jaxpr(step)(avals, jax.ShapeDtypeStruct(
+        (1, seq, dim), jnp.float32))) == [seq // 64, seq // 64]
+    plan, = {tuple(sorted(s.args.items())) for s in profiler.spans()
+             if s.name == "mx.gdn.plan" and s.id > since}
+    assert dict(plan)["state_kept_bytes"] == 141557760
+    mem = compiled.memory_analysis()
+    # the step's temporaries: far from a state a token (9 GB a layer)
+    assert mem.temp_size_in_bytes < 2.5e9
