@@ -254,9 +254,10 @@ class GatedDeltaNet(HybridBlock):
 
     Device scopes ``mx.gdn.project`` (the six products), ``mx.gdn.conv``
     (convolution, silu, the two L2 norms, the gates' activations),
-    ``mx.gdn.scan`` (the rule, forward and backward) and ``mx.gdn.out`` (the
-    gated norm and the output product); span ``mx.gdn.plan`` a traced
-    call."""
+    ``mx.gdn.scan`` (the rule, forward and backward: on the TPU the kernels
+    ``mx_gdn_fwd`` and ``mx_gdn_bwd`` with the moves to head-major and back
+    around them) and ``mx.gdn.out`` (the gated norm and the output product);
+    span ``mx.gdn.plan`` a traced call, with the rule's ``path``."""
 
     def __init__(self, units, num_heads, key_dim, value_dim, conv_kernel=4,
                  allow_neg_eigval=False, epsilon=1e-6,

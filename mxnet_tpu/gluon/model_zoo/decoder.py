@@ -54,7 +54,8 @@ then a final RMS norm and a head.  The operator is one of seven kinds
   ``value_head_dim``, ``conv_kernel_dim``, ``allow_neg_eigval``: the
   published ``linear_*`` keys without the prefix).  The sequence has to be
   whole chunks.  Device scopes ``mx.gdn.project``, ``mx.gdn.conv``,
-  ``mx.gdn.scan`` and ``mx.gdn.out``.
+  ``mx.gdn.scan`` (two Mosaic kernels on the TPU, `jax.numpy` elsewhere:
+  span ``mx.gdn.plan``) and ``mx.gdn.out``.
 
 What is a property of the layer and not of the net: ``heads`` may be a
 list, one count a layer (a model whose window layers have more query heads

@@ -23,17 +23,35 @@ arXiv:2406.06484 section 3 with the decays of arXiv:2412.06464 section 3.3):
 
 Every decay ratio is ``exp`` of a difference of cumulative sums (never a
 quotient of two exponentials), and only of differences that are not positive.
-What does not depend on the state (``A``, the solve's two right-hand sides,
-``P`` and the decayed q and k) is computed for all chunks at once; what does
-is one `lax.scan` over the ``S / C`` chunks, three products a step.  The
-forward keeps its five inputs and the ``S / C`` chunk-boundary states
-(float32) and nothing per token of size ``dk x dv``; the backward is the op's
-own (`jax.custom_vjp`): it rebuilds every chunk's system, walks the chunks in
-reverse with the state's gradient as the carry, and hands what that collects
-to the derivative of the all-chunks part.  No array has two axes of the
-sequence, none is ``(S, H, dk, dv)``, and no loop runs a token at a time.
-The state, the cumulative sums, the solve and every product here are float32
-(q, k, v and b arrive in the block's dtype, g in float32).
+That is the op's definition, and it is `jax.numpy` (`_forward`,
+`_backward`): what does not depend on the state (``A``, the solve's two
+right-hand sides, ``P`` and the decayed q and k) is computed for all chunks
+at once; what does is one `lax.scan` over the ``S / C`` chunks, three
+products a step.  The forward keeps its five inputs and the ``S / C``
+chunk-boundary states (float32) and nothing per token of size ``dk x dv``;
+the backward is the op's own (`jax.custom_vjp`): it rebuilds every chunk's
+system, walks the chunks in reverse with the state's gradient as the carry,
+and hands what that collects to the derivative of the all-chunks part.  No
+array has two axes of the sequence, none is ``(S, H, dk, dv)``, and no loop
+runs a token at a time.  The state, the cumulative sums, the solve and every
+product here are float32 (q, k, v and b arrive in the block's dtype, g in
+float32).
+
+Where the program is lowered for the TPU on one device and the shape tiles
+(`_gdn_plan`), the same runs as one Mosaic kernel each way, ``mx_gdn_fwd``
+and ``mx_gdn_bwd``: a grid over (batch, head, chunk) with the chunks in turn,
+the state (backward: its gradient) in VMEM for a head's whole walk, a chunk's
+whole algebra on chip (the inverse of ``I + A`` by blocks,
+`_unit_lower_inverse`, in place of `triangular_solve`; the derivative of the
+chunk's system written out, `_chunk_backward`), and nothing between the five
+inputs, the output and the kept chunk-start states in HBM.  Every product
+with a float32 operand is float32 at `HIGHEST` there too; ``K K^T`` and ``Q
+K^T``, whose operands are the bf16 inputs themselves, are exact at one pass.
+`jax.lax.platform_dependent` chooses, as for `_gate` and `_head_norm_rotary`
+in `ops/lm_blocks.py`; on every other platform, under a mesh of several
+devices and at a shape that does not tile, the `jax.numpy` form runs.  Span
+``mx.gdn.plan`` says which (``path`` ``kernel``, or ``xla`` and ``why``)
+beside the sizes.
 
 Around it `gluon.contrib.nn.GatedDeltaNet` uses ``_contrib_ShortConvHeads``
 (one depthwise causal convolution of a few taps over the concatenated q, k, v
@@ -42,8 +60,7 @@ channels, silu, the per-head L2 norms of q and k, the move to heads),
 from their two projections) and ``_contrib_GatedRMSNorm`` (the RMS norm of
 each head's output times ``silu`` of a gate).  The first and the last keep
 their inputs alone for the backward pass and compute their float32
-intermediates again there.  All of it is `jax.numpy` on every platform;
-span ``mx.gdn.plan`` says so (``path`` ``xla``) beside the sizes.
+intermediates again there; these three are `jax.numpy` on every platform.
 """
 
 from __future__ import annotations
@@ -53,9 +70,11 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import profiler
-from .lm_blocks import causal_taps
+from .lm_blocks import _one_device, causal_taps
 from .registry import register_op
 
 #: tokens a chunk where the caller names none
@@ -152,19 +171,6 @@ def _forward(q, k, v, g, b, chunk):
     return _from_chunks(out).astype(v.dtype), starts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def gated_delta_rule(q, k, v, g, b, chunk=DEFAULT_CHUNK):
-    """``o`` ``(B, S, H, dv)`` of the recurrence above from ``q, k (B, S, H,
-    dk)`` (normalised by the caller), ``v (B, S, H, dv)`` and ``g, b (B, S,
-    H)``, in chunks of *chunk* tokens."""
-    return _forward(q, k, v, g, b, chunk)[0]
-
-
-def _rule_fwd(q, k, v, g, b, chunk):
-    out, starts = _forward(q, k, v, g, b, chunk)
-    return out, (q, k, v, g, b, starts)
-
-
 def _again(kept, dout):
     """What a backward pass computes again, it computes from these: behind a
     barrier with the gradient that starts it, or XLA merges the second
@@ -173,8 +179,8 @@ def _again(kept, dout):
     return jax.lax.optimization_barrier((kept, dout))
 
 
-def _rule_bwd(chunk, kept, dout):
-    (q, k, v, g, b, starts), dout = _again(kept, dout)
+def _backward(q, k, v, g, b, starts, dout, chunk):
+    (q, k, v, g, b, starts), dout = _again((q, k, v, g, b, starts), dout)
     systems, pull = jax.vjp(
         _chunk_systems, *(_to_chunks(x, chunk) for x in (q, k, v, g, b)))
     _, dsystems = jax.lax.scan(
@@ -184,7 +190,389 @@ def _rule_bwd(chunk, kept, dout):
                  for d, x in zip(pull(dsystems), (q, k, v, g, b)))
 
 
-gated_delta_rule.defvjp(_rule_fwd, _rule_bwd)
+# ---------------------------------------------------------------------------
+# The same on the TPU: one Mosaic kernel each way.  The grid is (batch, head,
+# chunk), the chunk axis last and sequential; a head's float32 state (forward)
+# or the state's gradient (backward) lives in a VMEM scratch for its whole
+# walk, and nothing of a chunk's algebra (the ratios, A, its inverse, P, the
+# decayed q and k, U) goes to HBM.  What the forward keeps is what `_forward`
+# keeps: the five inputs and the chunk-start states.
+# ---------------------------------------------------------------------------
+
+#: rows of the diagonal blocks of ``I + A`` that the in-chunk solve inverts by
+#: substitution on the VPU before products on the MXU pair them
+#: (`_unit_lower_inverse`); a chunk no longer than that is one block, and
+#: substitution alone.  From `tools/gdn_sweep.py` on the v5e at (1, 3072, 30,
+#: 96 | 192) bf16, device ms a call, forward + backward (PERF.md section 6,
+#: PR 49): 3.404 + 4.904 at 8 rows, 2.934 + 4.385 at 16, 2.657 + 3.902 at 32,
+#: **2.473 + 3.857 at 64** (`jax.numpy`: 6.164 + 13.551); at 4096 positions
+#: the same order, 3.306 + 5.174 at 64.  A pairing is two dependent products
+#: with nothing of the chunk to run beside them; the substitution's steps run
+#: beside the products that do not wait for the inverse.  A grid step walks
+#: one chunk of one head: 2, 5 and 15 heads a step (a loop inside the step)
+#: read within 1% of one head's times, so the step's own cost is not what
+#: the time is.  Not an option: the sweep sets it to compare
+GDN_TILES = {"solve": 64}
+
+#: what a kernel's blocks (each held twice), its scratch and a head's
+#: float32 temporaries may take of the 16 MiB of VMEM that a Mosaic kernel is
+#: given on the v5e
+_GDN_VMEM = 12 << 20
+
+_NN = ((1,), (0,))      # a @ b
+_NT = ((1,), (1,))      # a @ b.T
+_TN = ((0,), (0,))      # a.T @ b
+
+
+def _dot(a, b, contract):
+    """An MXU product summed in float32.  Both operands bf16 (the inputs as
+    they arrived: ``K K^T``, ``Q K^T``): one pass, every product exact in
+    float32.  Anything else is float32 at `HIGHEST`."""
+    if not a.dtype == b.dtype == jnp.bfloat16:
+        a, b = a.astype(_F32), b.astype(_F32)
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), preferred_element_type=_F32,
+        precision=None if a.dtype == jnp.bfloat16 else _HIGHEST)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _unit_lower_inverse(a, block):
+    """``(I + a)^-1`` for a strictly lower-triangular ``(n, n)`` *a* of whole
+    blocks of *block* rows.  The diagonal blocks by forward substitution
+    (``X <- X - a[:, j] X[j, :]`` inside each, ``block - 1`` steps on the
+    VPU, a step touching the sublane tiles from row ``j``'s down); then
+    blocks are paired, ``[[L, 0], [M, N]]^-1 = [[L^-1, 0],
+    [-N^-1 M L^-1, N^-1]]``, by two products a doubling.  Not the nilpotent
+    series ``(I - a)(I + a^2)(I + a^4)...``: with entries near 2 its factors
+    grow by orders of magnitude before they cancel.
+
+    The steps keep the XLU busy (a column's lane broadcast each) and leave
+    the MXU idle: products that do not wait for the inverse belong before it
+    in the kernel's text, where Mosaic's scheduler runs them beside the
+    steps (and no product may stand between, or the MXU's order holds the
+    steps back)."""
+    n = a.shape[0]
+    x = []
+    for at in range(0, n, block):
+        column = a[at:at + block, at:at + block]
+        # the block's rows of the identity; the sublane tiles (8 rows) above
+        # row j's are final, the rest take the step as one array
+        rest = (_iota((block, n), 0) + at == _iota((block, n), 1)).astype(
+            _F32)
+        for j in range(block - 1):
+            if j and j % 8 == 0:
+                x.append(rest[:8])
+                rest = rest[8:]
+            top = j - j % 8
+            rest = rest - column[top:, j:j + 1] * rest[j - top:j - top + 1]
+        x.append(rest)
+    x = jnp.concatenate(x, 0)
+    rows, cols = _iota((n, n), 0), _iota((n, n), 1)
+    size = block
+    while size < n:
+        # M of every pair: the lower of its two blocks against the upper
+        below = functools.reduce(jnp.logical_or, (
+            (rows >= at + size) & (rows < at + 2 * size)
+            & (cols >= at) & (cols < at + size)
+            for at in range(0, n, 2 * size)))
+        x = x - _dot(x, _dot(jnp.where(below, a, 0.0), x, _NN), _NN)
+        size *= 2
+    return x
+
+
+def _chunk_algebra(q, k, v, g, b):
+    """What both kernels compute of one chunk and head from its inputs alone
+    (q, k ``(C, dk)``, v ``(C, dv)``, g and b as rows ``(1, C)``):
+    `_chunk_systems`' quantities before the solve, by name."""
+    c = q.shape[0]
+    rows, cols = _iota((c, c), 0), _iota((c, c), 1)
+    eye, seen, below = rows == cols, rows >= cols, rows > cols
+    at = {"eye": eye, "seen": seen, "below": below}
+    # the cumulative sums as a column and, the same numbers, as a row
+    cum = jnp.sum(jnp.where(seen, g, 0.0), 1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(eye, cum, 0.0), 0, keepdims=True)
+    last = cum[c - 1:]
+    # exp of differences that are not positive, and of no other
+    ratio = jnp.where(seen, jnp.exp(jnp.where(seen, cum - cum_row, 0.0)), 0.0)
+    grow, fade = jnp.exp(cum), jnp.exp(last - cum)
+    # b, a row as it arrives, as a column
+    beta = jnp.sum(jnp.where(eye, b.astype(_F32), 0.0), 1, keepdims=True)
+    below_ratio, kk = jnp.where(below, ratio, 0.0), _dot(k, k, _NT)
+    qf, kf, vf = (x.astype(_F32) for x in (q, k, v))
+    at.update(
+        grow=grow, fade=fade, glast=jnp.exp(last), b=beta, ratio=ratio,
+        below_ratio=below_ratio, kk=kk, a=beta * below_ratio * kk,
+        p=ratio * _dot(q, k, _NT), q=qf, k=kf, v=vf,
+        kb=beta * grow * kf, qg=grow * qf, kd=fade * kf)
+    return at
+
+
+def _chunk_solve(at, held, solve):
+    """``(T, U)``: ``T = (I + A)^-1`` and ``U = T (b V - (b exp(G) K) S_0)``,
+    *held* the product against the state."""
+    t = _unit_lower_inverse(at["a"], solve)
+    return t, _dot(t, at["b"] * at["v"] - held, _NN)
+
+
+def _chunk_forward(q, k, v, g, b, state, solve):
+    """``(the chunk's output, the state after it)``."""
+    at, c = _chunk_algebra(q, k, v, g, b), q.shape[0]
+    # (b exp(G) K) S_0 and (exp(G) Q) S_0 as one product of 2C rows, before
+    # the solve, which it does not wait for
+    held = _dot(jnp.concatenate([at["kb"], at["qg"]], 0), state, _NN)
+    _, u = _chunk_solve(at, held[:c], solve)
+    return (held[c:] + _dot(at["p"], u, _NN),
+            at["glast"] * state + _dot(at["kd"], u, _TN))
+
+
+def _chunk_backward(q, k, v, g, b, state, dout, dstate, solve):
+    """One chunk's part of the backward walk, the derivative of
+    `_chunk_forward` written out: from the chunk's inputs, the state it
+    started from, its output's gradient and the gradient of the state after
+    it, ``(dq, dk, dv, dg and db as rows, the gradient of the state before
+    it)``."""
+    at, c = _chunk_algebra(q, k, v, g, b), q.shape[0]
+    eye, seen, below = at["eye"], at["seen"], at["below"]
+    beta, grow, fade, glast = at["b"], at["grow"], at["fade"], at["glast"]
+    qf, kf, vf, kb, qg, kd, p, a = (at[n] for n in (
+        "q", "k", "v", "kb", "qg", "kd", "p", "a"))
+    dout = dout.astype(_F32)
+    # the four products that do not wait for the solve, before it
+    held = _dot(kb, state, _NN)
+    du = _dot(p, dout, _TN) + _dot(kd, dstate, _NN)
+    dqg = _dot(dout, state, _NT)
+    t, u = _chunk_solve(at, held, solve)
+    dr = _dot(t, du, _TN)             # the transposed system: T^T is at hand
+    both = jnp.concatenate([dout, dr], 0)
+    scores = _dot(both, u, _NT)                         # (2C, C)
+    dp = jnp.where(seen, scores[:c], 0.0)
+    da = -jnp.where(below, scores[c:], 0.0)
+    dkb = -_dot(dr, state, _NT)
+    dkd = _dot(u, dstate, _NT)
+    before = glast * dstate + _dot(jnp.concatenate([qg, -kb], 0), both, _TN)
+    # through P = ratio * Q K^T and A = b * ratio * K K^T
+    dscores = jnp.concatenate(
+        [at["ratio"] * dp, beta * at["below_ratio"] * da], 0)   # (2C, C)
+    right = _dot(dscores, kf, _NN)                      # (2C, dk)
+    dq = grow * dqg + right[:c]
+    dk = beta * grow * dkb + fade * dkd + right[c:] + _dot(
+        dscores, jnp.concatenate([qf, kf], 0), _TN)
+    dv = beta * dr
+    # the decays: every ratio is exp of a difference of cumulative sums
+    e = jnp.where(below, dp * p + da * a, 0.0)
+    to_last = jnp.sum(dkd * kd, 1, keepdims=True)
+    dcum = jnp.sum(e, 1, keepdims=True) \
+        - jnp.sum(jnp.where(eye, jnp.sum(e, 0, keepdims=True), 0.0), 1,
+                  keepdims=True) \
+        + jnp.sum(dqg * qg + dkb * kb, 1, keepdims=True) - to_last
+    dlast = jnp.sum(to_last, 0, keepdims=True) + glast * jnp.sum(
+        jnp.sum(state * dstate, 1, keepdims=True), 0, keepdims=True)
+    dcum = dcum + jnp.where(_iota((c, 1), 0) == c - 1, dlast, 0.0)
+    # g's gradient is the reverse cumulative sum inside the chunk
+    dg = jnp.sum(jnp.where(seen, dcum, 0.0), 0, keepdims=True)
+    db = sum(jnp.sum(x, 1, keepdims=True) for x in (
+        dr * vf, dkb * (grow * kf), da * (at["below_ratio"] * at["kk"])))
+    return dq, dk, dv, dg, jnp.sum(jnp.where(eye, db, 0.0), 0,
+                                   keepdims=True), before
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, out_ref, starts_ref,
+                    state, *, solve):
+    """One chunk of one head: the state it starts the chunk from to the
+    kept array, the chunk's output, the state on to the next chunk."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    start = state[...]
+    starts_ref[0, 0, 0] = start
+    out, state[...] = _chunk_forward(
+        q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], g_ref[0, 0, 0],
+        b_ref[0, 0, 0], start, solve)
+    out_ref[0, 0] = out.astype(out_ref.dtype)
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, starts_ref, dout_ref,
+                    dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate, *, solve):
+    """The same chunk on the way back (the grid walks the chunks from the
+    last): the carry is the gradient of the state after the chunk."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    dq, dk, dv, dg, db, dstate[...] = _chunk_backward(
+        q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], g_ref[0, 0, 0],
+        b_ref[0, 0, 0], starts_ref[0, 0, 0], dout_ref[0, 0], dstate[...],
+        solve)
+    for ref, value in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv)):
+        ref[0, 0] = value.astype(ref.dtype)
+    dg_ref[0, 0, 0] = dg
+    db_ref[0, 0, 0] = db.astype(db_ref.dtype)
+
+
+def _head_major(x, chunk):
+    """``(B, S, H, d)`` -> ``(B, H, S, d)`` as the kernels take q, k and v;
+    ``(B, S, H)`` -> ``(B, H, N, 1, C)``, a chunk's gates one row."""
+    x = jnp.swapaxes(x, 1, 2)
+    if x.ndim == 4:
+        return x
+    return x.reshape(x.shape[:2] + (x.shape[2] // chunk, 1, chunk))
+
+
+def _token_major(x):
+    """`_head_major` undone."""
+    if x.ndim == 5:
+        x = x.reshape(x.shape[:2] + (-1,))
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _gdn_specs(q, v, chunk, reverse):
+    """The grid (batch, head, chunk: the chunks last and in turn) and the
+    blocks: a chunk of one head's q or k, of its v or o, of a gate, and the
+    head's state at one chunk boundary."""
+    (batch, heads, seq, dk), dv = q.shape, v.shape[-1]
+    n = seq // chunk
+    at = (lambda c: n - 1 - c) if reverse else (lambda c: c)
+    return (batch, heads, n), (
+        pl.BlockSpec((1, 1, chunk, dk), lambda i, h, c: (i, h, at(c), 0)),
+        pl.BlockSpec((1, 1, chunk, dv), lambda i, h, c: (i, h, at(c), 0)),
+        pl.BlockSpec((1, 1, 1, 1, chunk),
+                     lambda i, h, c: (i, h, at(c), 0, 0)),
+        pl.BlockSpec((1, 1, 1, dk, dv), lambda i, h, c: (at(c), i, h, 0, 0)))
+
+
+_GDN_STATIC = ("chunk", "solve", "interpret")
+_GDN_WALK = ("parallel", "parallel", "arbitrary")
+
+
+# jitted, so a step's linear layers share one trace and one Mosaic program of
+# each kernel (as the flash wrappers)
+
+@functools.partial(jax.jit, static_argnames=_GDN_STATIC)
+def _gdn_fwd_pallas(q, k, v, g, b, chunk, solve, interpret=False):
+    """`_forward` by ``mx_gdn_fwd``: ``(o, the chunk-start states)``."""
+    with jax.named_scope("mx.gdn.rule"):
+        q, k, v, g, b = (_head_major(x, chunk) for x in (q, k, v, g, b))
+        (batch, heads, seq, dk), dv = q.shape, v.shape[-1]
+        grid, (keys, values, gate, states) = _gdn_specs(q, v, chunk, False)
+        out, starts = pl.pallas_call(
+            functools.partial(_gdn_fwd_kernel, solve=solve),
+            grid=grid, in_specs=[keys, keys, values, gate, gate],
+            out_specs=[values, states],
+            out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                       jax.ShapeDtypeStruct(
+                           (seq // chunk, batch, heads, dk, dv), _F32)],
+            scratch_shapes=[pltpu.VMEM((dk, dv), _F32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=_GDN_WALK),
+            interpret=interpret, name="mx_gdn_fwd",
+        )(q, k, v, g, b)
+        return _token_major(out), starts
+
+
+@functools.partial(jax.jit, static_argnames=_GDN_STATIC)
+def _gdn_bwd_pallas(q, k, v, g, b, starts, dout, chunk, solve,
+                    interpret=False):
+    """`_backward` by ``mx_gdn_bwd``: the five gradients."""
+    with jax.named_scope("mx.gdn.rule"):
+        q, k, v, g, b, dout = (_head_major(x, chunk)
+                               for x in (q, k, v, g, b, dout))
+        grid, (keys, values, gate, states) = _gdn_specs(q, v, chunk, True)
+        grads = pl.pallas_call(
+            functools.partial(_gdn_bwd_kernel, solve=solve),
+            grid=grid,
+            in_specs=[keys, keys, values, gate, gate, states, values],
+            out_specs=[keys, keys, values, gate, gate],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                       for x in (q, k, v, g, b)],
+            scratch_shapes=[pltpu.VMEM(starts.shape[-2:], _F32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=_GDN_WALK),
+            interpret=interpret, name="mx_gdn_bwd",
+        )(q, k, v, g, b, starts, dout)
+        return tuple(_token_major(d) for d in grads)
+
+
+def _padded(rows, cols, itemsize):
+    """Bytes of a ``(rows, cols)`` array as the VMEM tiles it."""
+    sub = 32 // itemsize
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
+
+
+def _gdn_blocks(kernel, chunk, dk, dv, itemsize):
+    """Bytes of VMEM one grid step of *kernel* takes: its blocks, each held
+    twice (q and k, v and o or their gradients and the output's, the gates'
+    rows, the head's state at a boundary), the carried state, and some
+    forty float32 temporaries the size of a chunk's v."""
+    keys, values, gates = {"fwd": (2, 2, 2), "bwd": (4, 3, 4)}[kernel]
+    state = _padded(dk, dv, 4)
+    blocks = keys * _padded(chunk, dk, itemsize) \
+        + values * _padded(chunk, dv, itemsize) \
+        + gates * _padded(1, chunk, 4) + state
+    return 2 * blocks + state + 40 * _padded(chunk, max(chunk, dk, dv), 4)
+
+
+def _gdn_plan(q, v, chunk):
+    """``(tiles, None)`` where the kernels take this call, ``(None, why
+    not)`` where it stays `_forward` and `_backward`.  From what the input
+    shows alone: q and v ``(B, S, H, d)`` in 2 or 4 bytes, the chunk in
+    whole blocks of the solve and whole sublane tiles, a grid step within
+    `_GDN_VMEM`, one device."""
+    solve = min(chunk, GDN_TILES["solve"])
+    dk, dv = q.shape[-1], v.shape[-1]
+    itemsize = jnp.dtype(v.dtype).itemsize
+    if itemsize not in (2, 4) or q.dtype != v.dtype:
+        return None, "q and v not of one dtype of 2 or 4 bytes"
+    if chunk % solve or chunk % (32 // itemsize):
+        return None, "a chunk of %d is not whole blocks of %d of the solve " \
+            "and whole tiles of %d rows" % (chunk, solve, 32 // itemsize)
+    if any(_gdn_blocks(kernel, chunk, dk, dv, itemsize) > _GDN_VMEM
+           for kernel in ("fwd", "bwd")):
+        return None, "a state of %d x %d with its blocks over the VMEM " \
+            "budget" % (dk, dv)
+    if not _one_device():
+        # XLA does not partition a Mosaic kernel, and no cell spans chips
+        return None, "a mesh of several devices"
+    return {"solve": solve}, None
+
+
+def _rule_forward(q, k, v, g, b, chunk):
+    tiles, _ = _gdn_plan(q, v, chunk)
+    if tiles is None:
+        return _forward(q, k, v, g, b, chunk)
+    return jax.lax.platform_dependent(
+        q, k, v, g, b, default=functools.partial(_forward, chunk=chunk),
+        tpu=functools.partial(_gdn_fwd_pallas, chunk=chunk, **tiles))
+
+
+def _rule_backward(chunk, kept, dout):
+    tiles, _ = _gdn_plan(kept[0], kept[2], chunk)
+    if tiles is None:
+        return _backward(*kept, dout, chunk)
+    return jax.lax.platform_dependent(
+        *kept, dout, default=functools.partial(_backward, chunk=chunk),
+        tpu=functools.partial(_gdn_bwd_pallas, chunk=chunk, **tiles))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def gated_delta_rule(q, k, v, g, b, chunk=DEFAULT_CHUNK):
+    """``o`` ``(B, S, H, dv)`` of the recurrence above from ``q, k (B, S, H,
+    dk)`` (normalised by the caller), ``v (B, S, H, dv)`` and ``g, b (B, S,
+    H)``, in chunks of *chunk* tokens: the kernels where `_gdn_plan` gives
+    tiles and the program is lowered for the TPU, `_forward` and `_backward`
+    everywhere else."""
+    return _rule_forward(q, k, v, g, b, chunk)[0]
+
+
+def _rule_fwd(q, k, v, g, b, chunk):
+    out, starts = _rule_forward(q, k, v, g, b, chunk)
+    return out, (q, k, v, g, b, starts)
+
+
+gated_delta_rule.defvjp(_rule_fwd, _rule_backward)
 
 
 def state_kept_bytes(batch, seq, heads, dk, dv, chunk=DEFAULT_CHUNK):
@@ -217,9 +605,11 @@ def _gated_delta_rule_op(query, key, value, decay, beta,
 
     in the chunkwise-parallel form, *chunk* tokens a chunk (static; the
     sequence has to be whole chunks), with a backward of its own that keeps
-    the inputs and the chunk-boundary states alone (`gated_delta_rule`).
-    q and k arrive normalised.  Span ``mx.gdn.plan`` and step stat
-    ``gdn_state_kept_bytes`` say what a call keeps."""
+    the inputs and the chunk-boundary states alone (`gated_delta_rule`:
+    the kernels ``mx_gdn_fwd`` and ``mx_gdn_bwd`` on the TPU where
+    `_gdn_plan` gives tiles, `jax.numpy` elsewhere).  q and k arrive
+    normalised.  Span ``mx.gdn.plan`` says which path a call takes and, with
+    step stat ``gdn_state_kept_bytes``, what it keeps."""
     chunk = int(chunk)
     batch, seq, heads, dk = query.shape
     dv = value.shape[-1]
@@ -236,15 +626,19 @@ def _gated_delta_rule_op(query, key, value, decay, beta,
                 query.shape, key.shape, value.shape, decay.shape,
                 beta.shape))
     kept = state_kept_bytes(batch, seq, heads, dk, dv, chunk)
+    tiles, why = _gdn_plan(query, value, chunk)
     with profiler.scope(  # graftlint: disable=JG003
             "mx.gdn.plan", "gdn") as span:
         span.args = {
             "batch": batch, "tokens": seq, "heads": heads, "key_dim": dk,
             "value_dim": dv, "chunk": chunk, "chunks": seq // chunk,
             "dtype": jnp.dtype(value.dtype).name,
-            # every platform runs the same jax.numpy: the systems of all
-            # chunks at once, then a scan over the chunks
-            "path": "xla",
+            # `kernel`: mx_gdn_fwd and mx_gdn_bwd where the program is
+            # lowered for the TPU (the same `jax.numpy` as `xla` where it
+            # is lowered for anything else); `xla`: the systems of all
+            # chunks at once, then a scan over the chunks, and why
+            "path": "xla" if tiles is None else "kernel", "why": why,
+            "solve_block": tiles and tiles["solve"],
             "state_kept_bytes": kept,
             # what a state kept at every token would be
             "per_token_state_bytes": 4 * batch * seq * heads * dk * dv}

@@ -148,23 +148,93 @@ REGIMES = ("usual", "decays-near-0", "decays-near-1", "b-near-2",
 RULE_TOLERANCE = 2e-5
 
 
-@pytest.mark.parametrize("chunk", [16, 32, 64])
-@pytest.mark.parametrize("regime", REGIMES)
-def test_the_rule_and_its_five_gradients_are_the_recurrence_s(regime, chunk):
-    q, k, v, g, b = rule_inputs(regime)
+def rule_and_gradients(path, f32, dout, chunk):
+    """``(o, the five gradients)`` by the op as it runs here (``xla``: the
+    `jax.numpy` path, which is what `gated_delta_rule` lowers to on the
+    CPU) or by the kernel pair, interpreted: at the plan's block of the
+    solve (``kernel``: a chunk of 64 is one block, substitution alone) or at
+    blocks of 16 that products pair (``kernel-16``)."""
+    if path == "xla":
+        got, pull = jax.vjp(lambda *a: delta_rule.gated_delta_rule(
+            *a, chunk), *f32)
+        return got, pull(dout)
+    solve = 16 if path == "kernel-16" else delta_rule._gdn_plan(
+        f32[0], f32[2], chunk)[0]["solve"]
+    tiles = dict(chunk=chunk, solve=solve, interpret=True)
+    got, starts = delta_rule._gdn_fwd_pallas(*f32, **tiles)
+    return got, delta_rule._gdn_bwd_pallas(*f32, starts, dout, **tiles)
+
+
+#: the five regimes at every chunk that tiles, and the usual one at the
+#: cell's widths, which the lanes pad (96 to 128, 192 to 256): two heads,
+#: three chunks
+RULE_CASES = [(regime, chunk, {}) for regime in REGIMES
+              for chunk in (16, 32, 64)] + [
+    ("usual", 64, dict(batch=1, seq=192, heads=2, dk=96, dv=192))]
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel", "kernel-16"])
+@pytest.mark.parametrize(
+    "regime,chunk,shape", RULE_CASES,
+    ids=["%s-%d%s" % (r, c, "-96x192" if s else "") for r, c, s in RULE_CASES])
+def test_the_rule_and_its_five_gradients_are_the_recurrence_s(regime, chunk,
+                                                              shape, path):
+    q, k, v, g, b = rule_inputs(regime, **shape)
     want, _ = gdn_counts.recurrence(q, k, v, g, b)
     dout = np.random.default_rng(7).normal(size=want.shape)
     f32 = [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, b)]
-    got, pull = jax.vjp(lambda *a: delta_rule.gated_delta_rule(*a, chunk),
-                        *f32)
+    got, grads = rule_and_gradients(path, f32, jnp.asarray(dout, jnp.float32),
+                                    chunk)
     scale = np.abs(want).max()
     assert np.abs(np.asarray(got) - want).max() <= RULE_TOLERANCE * scale
-    grads = pull(jnp.asarray(dout, jnp.float32))
     for name, mine, theirs in zip("qkvgb", grads, recurrence_backward(
             q, k, v, g, b, dout)):
         scale = max(np.abs(theirs).max(), 1e-30)
         assert np.abs(np.asarray(mine) - theirs).max() \
             <= 10 * RULE_TOLERANCE * scale, (name, regime, chunk)
+
+
+def test_the_kernels_and_the_jax_numpy_path_agree_on_bf16_inputs():
+    """The cell's dtypes (q, k, v, b in bf16, g in float32): the same dtypes
+    out of both paths, and the same numbers to float32's rounding (then
+    rounded once to bf16: an entry or two may land on the neighbour)."""
+    bf = jnp.bfloat16
+    q, k, v, g, b = rule_inputs("usual", dk=32, dv=64)
+    args = [jnp.asarray(x, t) for x, t in zip(
+        (q, k, v, g, b), (bf, bf, bf, jnp.float32, bf))]
+    dout = jnp.asarray(np.random.default_rng(9).normal(size=v.shape), bf)
+    want, want_grads = rule_and_gradients("xla", args, dout, 64)
+    got, grads = rule_and_gradients("kernel", args, dout, 64)
+    assert got.dtype == want.dtype == bf
+    for mine, theirs, x in zip((got,) + tuple(grads),
+                               (want,) + tuple(want_grads),
+                               [args[2]] + args):
+        assert mine.dtype == theirs.dtype == x.dtype
+        mine, theirs = (np.asarray(a, np.float64) for a in (mine, theirs))
+        # one bf16 step of the largest entry, where the two roundings part
+        assert np.abs(mine - theirs).max() <= 2.0 ** -7 * np.abs(theirs).max()
+        assert np.linalg.norm(mine - theirs) <= 1e-3 * np.linalg.norm(theirs)
+
+
+@pytest.mark.parametrize("block", [8, 16, 32, 64])
+@pytest.mark.parametrize("regime", ["b-near-2", "keys-nearly-alike"])
+def test_the_block_inverse_is_the_inverse(regime, block):
+    """``(I + A)^-1`` of a chunk's system where it is hardest (entries of
+    ``A`` near ``b``, near 2, where the nilpotent series' terms grow before
+    they cancel): substitution in the diagonal blocks and products to pair
+    them, against `numpy.linalg.inv` in float64."""
+    q, k, v, g, b = rule_inputs(regime, batch=1, seq=64, heads=1)
+    k, g, b = k[0, :, 0], g[0, :, 0], b[0, :, 0]
+    cum = np.cumsum(g)
+    a = np.tril(b[:, None] * np.exp(cum[:, None] - cum[None, :]) * (k @ k.T),
+                -1)
+    assert np.abs(a).max() > 1.5
+    want = np.linalg.inv(np.eye(64) + a)
+    got = jax.jit(lambda a: delta_rule._unit_lower_inverse(a, block))(
+        jnp.asarray(a, jnp.float32))
+    assert np.abs(np.asarray(got) - want).max() \
+        <= RULE_TOLERANCE * np.abs(want).max()
+    assert np.abs(np.triu(np.asarray(got), 1)).max() == 0.0
 
 
 @pytest.mark.parametrize("regime", REGIMES)
@@ -220,19 +290,32 @@ def test_bf16_inputs_come_back_in_bf16_and_the_state_stays_float32():
     assert "f32[2,2,8,16]" in jaxpr and "bf16[2,2,8,16]" not in jaxpr
 
 
-def test_the_plan_span_and_the_step_stat_say_what_a_call_keeps():
+def plan_of(q, v, chunk=64, devices=1):
+    """The `mx.gdn.plan` span of one traced call at these shapes."""
+    from jax.sharding import Mesh
+    from mxnet_tpu.parallel import mesh as mesh_mod
+    gate = q.shape[:3]
     since = max([s.id for s in profiler.spans()] or [0])
-    q = jnp.zeros((1, 4096, 30, 96), jnp.bfloat16)
-    with profiler.collect_step_stats() as stats:
+    with mesh_mod.use_mesh(Mesh(np.array(jax.devices()[:devices]), ("dp",))):
         jax.eval_shape(
-            lambda *a: delta_rule._gated_delta_rule_op(*a), q, q,
-            jnp.zeros((1, 4096, 30, 192), jnp.bfloat16),
-            jnp.zeros((1, 4096, 30), jnp.float32),
-            jnp.zeros((1, 4096, 30), jnp.bfloat16))
+            lambda *a: delta_rule._gated_delta_rule_op(*a, chunk=chunk),
+            q, q, v, jax.ShapeDtypeStruct(gate, jnp.float32),
+            jax.ShapeDtypeStruct(gate, v.dtype))
     plan, = [s.args for s in profiler.spans()
              if s.name == "mx.gdn.plan" and s.id > since]
-    assert plan["path"] == "xla" and plan["chunk"] == 64 \
-        and plan["chunks"] == 64 and plan["heads"] == 30
+    return plan
+
+
+def test_the_plan_span_and_the_step_stat_say_what_a_call_keeps():
+    q = jax.ShapeDtypeStruct((1, 4096, 30, 96), jnp.bfloat16)
+    with profiler.collect_step_stats() as stats:
+        plan = plan_of(q, jax.ShapeDtypeStruct((1, 4096, 30, 192),
+                                               jnp.bfloat16))
+    assert plan["path"] == "kernel" and plan["why"] is None \
+        and plan["chunk"] == 64 and plan["chunks"] == 64 \
+        and plan["heads"] == 30
+    assert plan["solve_block"] == min(64, delta_rule.GDN_TILES["solve"])
+    # the kernels keep what the `jax.numpy` path keeps
     assert plan["state_kept_bytes"] == 64 * 30 * 96 * 192 * 4 \
         == gdn_counts.state_kept_bytes(1, 4096, 30, 96, 192, 64)
     assert plan["per_token_state_bytes"] == 64 * plan["state_kept_bytes"]
@@ -242,6 +325,45 @@ def test_the_plan_span_and_the_step_stat_say_what_a_call_keeps():
                                             np.float32)})
     from mxnet_tpu.observability import metrics
     assert "mxnet_gdn_state_kept_bytes 424673280.0" in metrics.exposition()
+
+
+REFUSALS = {
+    # (sequence, heads, dk, dv, dtype, chunk, devices) -> why not
+    "a-mesh-of-two": ((3072, 30, 96, 192, "bfloat16", 64, 2),
+                      "a mesh of several devices"),
+    "a-chunk-the-solve-s-block-does-not-divide": (
+        (3072, 30, 96, 192, "float32", 96, 1),
+        "a chunk of 96 is not whole blocks of %d of the solve"
+        % delta_rule.GDN_TILES["solve"]),
+    "a-chunk-of-half-a-bf16-tile": (
+        (3072, 30, 96, 192, "bfloat16", 8, 1), "a chunk of 8 is not whole"),
+    "a-state-over-the-vmem-budget": (
+        (3072, 4, 1024, 1024, "bfloat16", 64, 1),
+        "a state of 1024 x 1024 with its blocks over the VMEM budget"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSALS))
+def test_the_plan_says_why_a_call_stays_jax_numpy(case):
+    (seq, heads, dk, dv, dtype, chunk, devices), why = REFUSALS[case]
+    plan = plan_of(jax.ShapeDtypeStruct((1, seq, heads, dk), dtype),
+                   jax.ShapeDtypeStruct((1, seq, heads, dv), dtype),
+                   chunk, devices)
+    assert plan["path"] == "xla" and plan["why"].startswith(why)
+    assert plan["solve_block"] is None
+    assert plan["state_kept_bytes"] == 4 * (seq // chunk) * heads * dk * dv
+
+
+def test_a_wide_state_that_fits_takes_the_kernels():
+    """The budget is the grid step's: the blocks of one chunk of one head,
+    each held twice, the carried state and the temporaries."""
+    wide = plan_of(jax.ShapeDtypeStruct((1, 128, 16, 256), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((1, 128, 16, 512), jnp.bfloat16))
+    assert wide["path"] == "kernel"
+    at = dict(chunk=64, itemsize=2)
+    assert delta_rule._gdn_blocks("bwd", dk=256, dv=512, **at) \
+        <= delta_rule._GDN_VMEM < delta_rule._gdn_blocks(
+            "bwd", dk=1024, dv=1024, **at)
 
 
 # -- the operators around the rule -----------------------------------------------
@@ -556,8 +678,11 @@ def test_the_compiled_layers_lie_under_their_scopes():
         assert any("transpose(" in n for n in found), scope
         assert any("transpose(" not in n for n in found), scope
     spans = [s for s in profiler.spans() if s.id > since]
+    # two heads of 8 x 16 at chunks of 64 tile: on the TPU this is the kernel
+    # pair; lowered for the CPU, as here, the same plan runs the `jax.numpy`
+    # path, under the same scope
     assert {s.args["path"] for s in spans if s.name == "mx.gdn.plan"} == \
-        {"xla"}
+        {"kernel"}
     whys = {s.args["why"] for s in spans if s.name == "mx.headrope.plan"}
     assert whys == {"one norm over the whole width of 48, not a head's 16 "
                     "and no rotary positions: nothing to turn"}
@@ -745,11 +870,11 @@ def scan_lengths(jaxpr):
 def test_a_linear_layer_compiles_for_the_described_chip_with_no_square_array(
         one_chip, no_cache):
     """The cell's linear layer, forward and backward, at 4096 positions and
-    30 heads of 96 x 192 in bf16, compiled for a v5e: no array of the
-    compiled program has two axes of the sequence, none is a state a token
-    ``(S, H, dk, dv)`` in any order of its axes, the loops are the chunk
-    scans (64 steps) and the solve's (a chunk's rows), and what the forward
-    keeps is the plan's."""
+    30 heads of 96 x 192 in bf16, compiled for a v5e: the rule is the two
+    Mosaic kernels and no loop of XLA's, no array of the compiled program
+    has two axes of the sequence, none is a state a token ``(S, H, dk,
+    dv)`` in any order of its axes, no chunk's solve ``(N, B, H, C, dk +
+    dv)`` leaves the kernels, and what the forward keeps is the plan's."""
     import mxnet_tpu as mx
     from mxnet_tpu import executor
     from mxnet_tpu.gluon.contrib.nn import GatedDeltaNet
@@ -772,6 +897,11 @@ def test_a_linear_layer_compiles_for_the_described_chip_with_no_square_array(
     compiled = jax.jit(step).lower(avals, jax.ShapeDtypeStruct(
         (1, seq, dim), jnp.float32, sharding=one_chip)).compile()
     text = compiled.as_text()
+    for kernel in ("mx_gdn_fwd", "mx_gdn_bwd"):
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and kernel + "/" in line]
+        assert len(calls) == 1 and "mx.gdn.scan/" in calls[0], kernel
+    assert " while(" not in text
     assert not re.search(r"\[(\d+,)*%d,(\d+,)*%d[,\]]" % (seq, seq), text)
     shapes = {tuple(int(n) for n in m.split(","))
               for m in re.findall(r"\[((?:\d+,)+\d+)\]", text)}
@@ -779,14 +909,19 @@ def test_a_linear_layer_compiles_for_the_described_chip_with_no_square_array(
     assert not [s for s in shapes if int(np.prod(s)) >= per_token]
     # the largest arrays are the chunk-boundary states: S / C a head
     assert (seq // 64, 1, heads, dk, dv) in shapes
-    # two loops, the scan over the chunks and its reverse walk: 64 steps
-    # each (`scan_lengths` reads them where they are written, in the jaxpr)
-    assert text.count(" while(") == 2
+    # the solves of all chunks at once were `f32[64,1,30,64,288]`
+    assert not [s for s in shapes if s[-2:] == (64, dk + dv)]
+    # the `jax.numpy` path is still the other branch of the traced program:
+    # its two scans over the chunks, 64 steps each
     assert scan_lengths(jax.make_jaxpr(step)(avals, jax.ShapeDtypeStruct(
         (1, seq, dim), jnp.float32))) == [seq // 64, seq // 64]
-    plan, = {tuple(sorted(s.args.items())) for s in profiler.spans()
+    plan, = {tuple(sorted((k, v) for k, v in s.args.items()))
+             for s in profiler.spans()
              if s.name == "mx.gdn.plan" and s.id > since}
     assert dict(plan)["state_kept_bytes"] == 141557760
+    assert dict(plan)["path"] == "kernel"
     mem = compiled.memory_analysis()
-    # the step's temporaries: far from a state a token (9 GB a layer)
-    assert mem.temp_size_in_bytes < 2.5e9
+    # the step's temporaries: the projections, the kept states and the
+    # head-major copies around the kernels, 1.40 GB (2.5e9 bounded the
+    # `jax.numpy` path's; a state a token is 9 GB a layer)
+    assert mem.temp_size_in_bytes < 1.6e9
