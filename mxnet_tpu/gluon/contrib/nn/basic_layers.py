@@ -243,7 +243,13 @@ class GatedDeltaNet(HybridBlock):
       for the decay and for the write strength;
     - q, k and v through one depthwise causal convolution of *conv_kernel*
       taps over their concatenated channels, then silu; q and k L2-normed
-      by head, q scaled by ``key_dim ** -0.5`` (``contrib.ShortConvHeads``);
+      by head, q scaled by ``key_dim ** -0.5`` (``contrib.ShortConvHeads``:
+      the kernels ``mx_gdnconv_fwd`` and ``mx_gdnconv_bwd``, one pass over
+      the channels each way, where the program is lowered for the TPU on
+      one device at widths of whole groups of ``lcm(key_dim, 128)``
+      channels and a sequence of whole pieces of 64 rows; the same
+      arithmetic in `jax.numpy` at every other input; span
+      ``mx.gdnconv.plan`` says which);
     - ``g = -exp(A_log) softplus(a + dt_bias)`` (float32) and ``b =
       sigmoid(.)``, doubled with *allow_neg_eigval*
       (``contrib.DeltaRuleGates``);
@@ -253,11 +259,13 @@ class GatedDeltaNet(HybridBlock):
       (``contrib.GatedRMSNorm``), and the output product.
 
     Device scopes ``mx.gdn.project`` (the six products), ``mx.gdn.conv``
-    (convolution, silu, the two L2 norms, the gates' activations),
+    (convolution, silu and the two L2 norms, on the TPU the kernels
+    ``mx_gdnconv_fwd`` and ``mx_gdnconv_bwd``; the gates' activations),
     ``mx.gdn.scan`` (the rule, forward and backward: on the TPU the kernels
     ``mx_gdn_fwd`` and ``mx_gdn_bwd`` with the moves to head-major and back
     around them) and ``mx.gdn.out`` (the gated norm and the output product);
-    span ``mx.gdn.plan`` a traced call, with the rule's ``path``."""
+    spans ``mx.gdn.plan`` and ``mx.gdnconv.plan`` a traced call, with the
+    rule's and the convolution's ``path``."""
 
     def __init__(self, units, num_heads, key_dim, value_dim, conv_kernel=4,
                  allow_neg_eigval=False, epsilon=1e-6,

@@ -60,7 +60,11 @@ channels, silu, the per-head L2 norms of q and k, the move to heads),
 from their two projections) and ``_contrib_GatedRMSNorm`` (the RMS norm of
 each head's output times ``silu`` of a gate).  The first and the last keep
 their inputs alone for the backward pass and compute their float32
-intermediates again there; these three are `jax.numpy` on every platform.
+intermediates again there.  The first is a Mosaic pair of its own where the
+program is lowered for the TPU on one device and `_gdnconv_plan` gives tiles,
+``mx_gdnconv_fwd`` and ``mx_gdnconv_bwd``, one pass over the channels each
+way (span ``mx.gdnconv.plan`` says which path a call takes); the other two
+are `jax.numpy` on every platform.
 """
 
 from __future__ import annotations
@@ -74,7 +78,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import profiler
-from .lm_blocks import _one_device, causal_taps
+from .lm_blocks import _halo_rows, _one_device, causal_taps
 from .registry import register_op
 
 #: tokens a chunk where the caller names none
@@ -666,22 +670,510 @@ def _conv_heads_body(data, conv_weight, heads, dk, eps):
         _unit(q, eps) * dk ** -0.5, _unit(k, eps), v))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _conv_heads(data, conv_weight, heads, dk, eps):
-    return _conv_heads_body(data, conv_weight, heads, dk, eps)
-
-
-def _conv_heads_bwd(heads, dk, eps, kept, dout):
+def _conv_heads_body_backward(data, conv_weight, dout, heads, dk, eps):
     # the float32 intermediates are computed again from the two inputs
-    kept, dout = _again(kept, dout)
+    kept, dout = _again((data, conv_weight), dout)
     return jax.vjp(functools.partial(_conv_heads_body, heads=heads, dk=dk,
                                      eps=eps), *kept)[1](dout)
 
 
+# ---------------------------------------------------------------------------
+# The same on the TPU: one Mosaic kernel each way, ``mx_gdnconv_fwd`` and
+# ``mx_gdnconv_bwd``, one pass over the channels forward and one backward.
+# A grid step holds a tile of rows of one block of channels; the channels
+# come in groups of ``lcm(dk, 128)`` (whole heads AND whole lane tiles: 384
+# at keys of 96), a block is a few groups, the blocks of q and k take the
+# norms and the blocks of v do not.  Inside a step the rows go by in pieces
+# that stay in registers from the load to the store (whole tiles an
+# operation spill every intermediate, and the store slot bounds the kernel).
+# A head of 96 straddles lane tiles, so a head's sum is a masked lane sum for
+# each tile it has lanes in (`_head_sums`; the same sums as a product with a
+# 0/1 matrix on the MXU were 1.4 times slower a call).  The kernels write q,
+# k and v head-major, ``(batch, heads, seq, d)``, and read their gradients
+# so: that is what the rule's kernels read and write, XLA folds the
+# operator's token-major outputs' swap into the rule's own, and no copy moves
+# a head of 96 to a lane tile's start (the flat outputs' reshape to heads was
+# a split and two transposing copies a tensor each way).  Nothing float32
+# goes to HBM either way, and the backward keeps the two inputs alone.
+# ---------------------------------------------------------------------------
+
+#: ``(rows of the sequence a grid step holds, rows of a piece inside it)`` of
+#: each kernel, and the most channels of a block (cut to whole groups that
+#: divide q and k's width and v's).  From `tools/gdnconv_sweep.py` on the v5e
+#: at (1, 3072, 11520) bf16, 30 heads of 96 | 192, 4 taps, device ms a call
+#: with the wrapper's moves from and to token-major, which fold away in a
+#: step (docs/PERF_NOTES.md, PR 51): forward **0.466 at 1024 rows in pieces
+#: of 128**, 0.560 in pieces of 64, 0.773 of 32, 0.446 of 256; 0.473 at 512
+#: rows, 0.445 at 3072; 0.463 at 512 rows x 1152 channels; `jax.numpy` 1.891.
+#: Backward **0.700 at 1024 rows in pieces of 64**, 0.805 of 32, 0.757 of
+#: 128; 0.732 at 512 rows, 0.664 at 3072; 0.707 at 512 x 1152; `jax.numpy`
+#: 5.332.  A head's sums as a product on the MXU (the piece's float32 numbers
+#: in three bf16 parts against the 0/1 matrix of a group's heads) read 0.633
+#: and 1.011 where the masked lane sums read 0.467 and 0.701 (PR 50's
+#: builder's sweep), and left the module.  In the cell's step the kernels
+#: alone read 0.361 and 0.555 ms a call.  Not options: the sweep sets them to
+#: compare
+GDNCONV_TILES = {"fwd": (1024, 128), "bwd": (1024, 64), "channels": 384}
+
+#: what a kernel's blocks (each held twice) and a group's float32
+#: temporaries may take of the 16 MiB of VMEM that a Mosaic kernel is given
+#: on the v5e
+_GDNCONV_VMEM = 14 << 20
+
+#: the row of the taps' ``(8, channels)`` array that holds each lane's scale
+#: (``dk ** -0.5`` on q's lanes, 1 on the others: the border between q and k
+#: need not be a lane tile's)
+_SCALE_ROW = 7
+
+
+def _group(dk):
+    """Channels of the least run that is whole heads of *dk* and whole lane
+    tiles."""
+    return int(np.lcm(dk, 128))
+
+
+def _taps_and_scales(conv_weight, heads, dk):
+    """The taps as the kernels read them, ``(8, channels)`` float32, row j
+    the tap of ``x_(t-L+1+j)``, and in row `_SCALE_ROW` each lane's scale."""
+    width, taps = conv_weight.shape
+    scale = jnp.where(jnp.arange(width) < heads * dk, dk ** -0.5, 1.0)
+    return jnp.zeros((8, width), _F32).at[:taps].set(
+        conv_weight.astype(_F32).T).at[_SCALE_ROW].set(scale)
+
+
+def _head_sums(a, dk):
+    """``(rows, G)`` float32 -> at every lane the sum of *a* over the lane's
+    head: a lane tile at a time, a masked lane sum for each head that has
+    lanes in it (a head of 96 has them in two tiles), the heads' sums
+    chosen back by lane."""
+    lane, total, cut = _iota((1, 128), 1), {}, []
+    for t in range(a.shape[1] // 128):
+        tile = a[:, 128 * t:128 * (t + 1)]
+        cut.append([(h, max(h * dk - 128 * t, 0),
+                     min((h + 1) * dk - 128 * t, 128))
+                    for h in range(128 * t // dk, (128 * t + 127) // dk + 1)])
+        for h, lo, hi in cut[-1]:
+            part = jnp.sum(tile if hi - lo == 128 else jnp.where(
+                (lane >= lo) & (lane < hi), tile, 0.0), 1, keepdims=True)
+            total[h] = total[h] + part if h in total else part
+    out = []
+    for heads in cut:
+        chosen = total[heads[-1][0]]
+        for h, _, hi in heads[-2::-1]:
+            chosen = jnp.where(lane < hi, total[h], chosen)
+        out.append(jnp.broadcast_to(chosen, (a.shape[0], 128)))
+    return jnp.concatenate(out, 1)
+
+
+def _sigmoid(x):
+    """``1 / (1 + exp(-x))`` in float32 by the EUP's approximate reciprocal
+    and two Newton steps of it, float32 from eight good bits on (a true
+    division is a dozen VALU operations a number where this is six).
+    ``-x`` is held under 80, so that a step never meets an infinity: ``x
+    sigmoid(x)`` is under 1e-32 there either way."""
+    d = 1.0 + jnp.exp(jnp.minimum(-x, 80.0))
+    r = pl.reciprocal(d, approx=True)
+    r = r * (2.0 - d * r)
+    return r * (2.0 - d * r)
+
+
+def _conv_silu(ext, w, lead):
+    """From rows of the input in float32, *lead* rows of halo first, and the
+    taps as rows *w*: the taps' shifted inputs ``u[j]`` = ``x_(t-L+1+j)`` of
+    the rows after the halo, the sigmoid of their sum (in `causal_taps`'
+    order) and its silu.  A shifted input is a sublane roll of all the rows
+    and an aligned slice of it: the rows that wrap land in the halo's part
+    and are cut off."""
+    u = [pltpu.roll(ext, back, 0)[lead:] for back in range(len(w) - 1, 0, -1)]
+    u.append(ext[lead:])
+    conv = sum(x * tap for x, tap in zip(u, w))
+    gate = _sigmoid(conv)
+    return u, gate, conv * gate
+
+
+def _rows_f32(ref, at, start=0, size=None):
+    return ref[0, pl.ds(start, size or ref.shape[1]), at].astype(_F32)
+
+
+def _by_kind(walk, qk_blocks):
+    """*walk* over the block's groups, with the norms in q and k's blocks
+    and without in v's."""
+    normed = pl.program_id(0) < qk_blocks
+    pl.when(normed)(functools.partial(walk, True))
+    pl.when(jnp.logical_not(normed))(functools.partial(walk, False))
+
+
+def _gdnconv_fwd_kernel(x_ref, x_before, w_ref, qk_ref, v_ref, *, group,
+                        taps, dk, eps, qk_blocks, piece):
+    """One ``(rows, channels)`` tile of the input to the same rows of its
+    heads in q and k's head-major array (a block of q and k's channels) or
+    in v's (a block of v's): a group of channels at a time, and in it
+    *piece* rows at a time, which stay in registers from the load to the
+    store; the rows a piece's taps look back at are the piece's before it,
+    carried (the tile's first: the halo block's, zero before position 0 of
+    every batch row, so packed rows never see each other)."""
+    lead, rows = x_before.shape[1], x_ref.shape[1]
+    first = pl.program_id(2) == 0
+
+    def walk(normed):
+        out_ref = qk_ref if normed else v_ref
+        d = out_ref.shape[3]
+        for lo in range(0, x_ref.shape[2], group):
+            at = slice(lo, lo + group)
+            w = [w_ref[j:j + 1, at] for j in range(taps)]
+
+            def step(i, before):
+                start = pl.multiple_of(i * piece, piece)
+                own = _rows_f32(x_ref, at, start, piece)
+                _, _, y = _conv_silu(jnp.concatenate([before, own], 0), w,
+                                     lead)
+                if normed:
+                    y = y * jax.lax.rsqrt(_head_sums(y * y, dk) + eps) \
+                        * w_ref[_SCALE_ROW:_SCALE_ROW + 1, at]
+                y = y.astype(out_ref.dtype)
+                for h in range(group // d):
+                    out_ref[0, lo // d + h, pl.ds(start, piece)] = \
+                        y[:, h * d:(h + 1) * d]
+                return own[piece - lead:]
+
+            jax.lax.fori_loop(0, rows // piece, step, jnp.where(
+                first, 0.0, _rows_f32(x_before, at)))
+
+    _by_kind(walk, qk_blocks)
+
+
+def _heads_f32(ref, lo, group, start, size):
+    """Channels *lo* .. *lo* + *group* of *size* rows of a head-major block
+    ``(1, heads, rows, d)``, side by side in float32."""
+    d = ref.shape[3]
+    return jnp.concatenate(
+        [ref[0, lo // d + h, pl.ds(start, size)].astype(_F32)
+         for h in range(group // d)], 1)
+
+
+def _gdnconv_bwd_kernel(x_ref, x_before, x_after, gqk_ref, gqk_after, gv_ref,
+                        gv_after, w_ref, dx_ref, dw_ref, *, group, taps, dk,
+                        eps, qk_blocks, piece):
+    """The tile's part of the input's gradient, and of the taps' gradient
+    added to *dw_ref*, which stays in VMEM over a channel block's whole
+    walk of the rows.  The convolution, silu and the norms are computed
+    again in float32, *piece* rows at a time from the tile's last to its
+    first: the transpose of the taps looks ahead, ``dx_t = sum_j w_j
+    dconv_(t+L-1-j)``, so a piece carries its first rows of ``dconv`` to the
+    piece before it, and the walk starts from the halo block's after the
+    tile (zero past the sequence's end)."""
+    lead, rows = x_before.shape[1], x_ref.shape[1]
+    first = pl.program_id(2) == 0
+    last = pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when((pl.program_id(1) == 0) & first)
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def walk(normed):
+        g_ref, g_after = (gqk_ref, gqk_after) if normed else (gv_ref,
+                                                              gv_after)
+        for lo in range(0, x_ref.shape[2], group):
+            at = slice(lo, lo + group)
+            w = [w_ref[j:j + 1, at] for j in range(taps)]
+
+            def dconv_of(ext, dy):
+                """``(u, dconv)`` of the rows of *ext* after its halo."""
+                u, gate, y = _conv_silu(ext, w, lead)
+                if normed:
+                    dy = dy * w_ref[_SCALE_ROW:_SCALE_ROW + 1, at]
+                    n = dy.shape[0]
+                    both = _head_sums(jnp.concatenate([y * y, dy * y], 0),
+                                      dk)
+                    r = jax.lax.rsqrt(both[:n] + eps)
+                    dy = r * (dy - y * (r * r) * both[n:])
+                # silu's derivative
+                return u, dy * (gate + y * (1.0 - gate))
+
+            def step(i, carry):
+                ahead, sums_w = carry
+                i = rows // piece - 1 - i
+                start = pl.multiple_of(i * piece, piece)
+                before = jnp.where(
+                    i == 0, jnp.where(first, 0.0, _rows_f32(x_before, at)),
+                    _rows_f32(x_ref, at, pl.multiple_of(
+                        jnp.maximum(start - lead, 0), lead), lead))
+                u, dconv = dconv_of(
+                    jnp.concatenate(
+                        [before, _rows_f32(x_ref, at, start, piece)], 0),
+                    _heads_f32(g_ref, lo, group, start, piece))
+                ext = jnp.concatenate([dconv, ahead], 0)
+                dx = dconv * w[taps - 1] + sum(
+                    pltpu.roll(ext, piece + lead - k, 0)[:piece]
+                    * w[taps - 1 - k] for k in range(1, taps))
+                dx_ref[0, pl.ds(start, piece), at] = dx.astype(dx_ref.dtype)
+                # the taps' gradient a sublane: summed over the sublanes once
+                return dconv[:lead], tuple(
+                    s + jnp.sum((dconv * x).reshape(piece // 8, 8, group), 0)
+                    for s, x in zip(sums_w, u))
+
+            _, after = dconv_of(
+                jnp.concatenate([_rows_f32(x_ref, at, rows - lead, lead),
+                                 _rows_f32(x_after, at)], 0),
+                _heads_f32(g_after, lo, group, 0, lead))
+            _, sums_w = jax.lax.fori_loop(
+                0, rows // piece, step,
+                (jnp.where(last, 0.0, after),
+                 (jnp.zeros((8, group), _F32),) * taps))
+            for j, s in enumerate(sums_w):
+                dw_ref[j:j + 1, at] += jnp.sum(s, 0, keepdims=True)
+
+    _by_kind(walk, qk_blocks)
+
+
+def _gdnconv_call(kernel, data, conv_weight, heads, dk, eps, rows, channels,
+                  piece):
+    """What both `pallas_call`s share: the kernel with its static numbers,
+    the grid (channel block, batch, tile of rows: a block's tiles in turn, so
+    the taps' gradient stays where it is summed), the blocks and the taps'
+    operand.  The blocks of the flat input: a tile, the halo block
+    just before it and just after it (the sequence's ends clamp to a block
+    that is there and the kernels zero it).  The blocks of the head-major
+    arrays ``(batch, heads, seq, d)``, q and k's 2 H heads in one array and
+    v's H in another: the tile's rows of the channel block's heads and the
+    halo block after them; while the other array's blocks go by, an array's
+    index is held where it was last written (q and k's) or will first be
+    (v's), so that nothing is copied out that the kernel did not write.  A
+    channel block's rows of the taps."""
+    batch, seq, width = data.shape
+    halo = _halo_rows(data.dtype)
+    per, end = rows // halo, seq // halo - 1
+    qk_blocks, dv = 2 * heads * dk // channels, width // heads - 2 * dk
+    tiles = seq // rows
+
+    def before(s):
+        return jnp.maximum(s * per - 1, 0)
+
+    def after(s):
+        return jnp.minimum((s + 1) * per, end)
+
+    def head_major(rows_of, where):
+        def qk(c, i, s):
+            on = c < qk_blocks
+            return (jnp.where(on, i, batch - 1),
+                    jnp.where(on, c, qk_blocks - 1),
+                    where(jnp.where(on, s, tiles - 1)), 0)
+
+        def v(c, i, s):
+            on = c >= qk_blocks
+            return (jnp.where(on, i, 0), jnp.where(on, c - qk_blocks, 0),
+                    where(jnp.where(on, s, 0)), 0)
+
+        return (pl.BlockSpec((1, channels // dk, rows_of, dk), qk),
+                pl.BlockSpec((1, channels // dv, rows_of, dv), v))
+
+    specs = dict(
+        tile=pl.BlockSpec((1, rows, channels), lambda c, i, s: (i, s, c)),
+        before=pl.BlockSpec((1, halo, channels),
+                            lambda c, i, s: (i, before(s), c)),
+        after=pl.BlockSpec((1, halo, channels),
+                           lambda c, i, s: (i, after(s), c)),
+        heads=head_major(rows, lambda s: s),
+        heads_after=head_major(halo, after),
+        taps=pl.BlockSpec((8, channels), lambda c, i, s: (0, c)))
+    kernel = functools.partial(
+        kernel, group=_group(dk), taps=conv_weight.shape[1], dk=dk, eps=eps,
+        qk_blocks=qk_blocks, piece=piece)
+    return kernel, (width // channels, batch, tiles), specs, \
+        _taps_and_scales(conv_weight, heads, dk)
+
+
+_GDNCONV_STATIC = ("heads", "dk", "eps", "rows", "channels", "piece",
+                   "interpret")
+# the head-major arrays are revisited across the channel axis
+_GDNCONV_WALK = ("arbitrary",) * 3
+
+
+# jitted, so a step's linear layers share one trace and one Mosaic program of
+# each kernel (as the rule's wrappers)
+
+@functools.partial(jax.jit, static_argnames=_GDNCONV_STATIC)
+def _gdnconv_fwd_pallas(data, conv_weight, heads, dk, eps, rows, channels,
+                        piece, interpret=False):
+    """`_conv_heads_body` by ``mx_gdnconv_fwd``.  The kernel writes q, k
+    and v head-major, ``(batch, heads, seq, d)``, as the rule's kernels read
+    them; the outputs are those with the two axes swapped back, which XLA
+    folds into the rule's own swap."""
+    batch, seq, width = data.shape
+    kernel, grid, specs, taps = _gdnconv_call(
+        _gdnconv_fwd_kernel, data, conv_weight, heads, dk, eps, rows,
+        channels, piece)
+    qk, v = pl.pallas_call(
+        kernel, grid=grid,
+        in_specs=[specs["tile"], specs["before"], specs["taps"]],
+        out_specs=list(specs["heads"]),
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, 2 * heads, seq, dk), data.dtype),
+            jax.ShapeDtypeStruct(
+                (batch, heads, seq, width // heads - 2 * dk), data.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_GDNCONV_WALK),
+        interpret=interpret, name="mx_gdnconv_fwd",
+    )(data, data, taps)
+    return tuple(jnp.swapaxes(x, 1, 2)
+                 for x in (qk[:, :heads], qk[:, heads:], v))
+
+
+@functools.partial(jax.jit, static_argnames=_GDNCONV_STATIC)
+def _gdnconv_bwd_pallas(data, conv_weight, dout, heads, dk, eps, rows,
+                        channels, piece, interpret=False):
+    """`_conv_heads_body`'s derivative by ``mx_gdnconv_bwd``: the gradients
+    of the input and of the taps from the three outputs' gradients, which
+    the kernel reads head-major (as the rule's kernel wrote them)."""
+    dq, dk_, dv = (jnp.swapaxes(d, 1, 2) for d in dout)
+    dqk = jnp.concatenate([dq, dk_], 1)
+    kernel, grid, specs, taps = _gdnconv_call(
+        _gdnconv_bwd_kernel, data, conv_weight, heads, dk, eps, rows,
+        channels, piece)
+    (gqk, gv), (gqk_after, gv_after) = specs["heads"], specs["heads_after"]
+    ddata, dw = pl.pallas_call(
+        kernel, grid=grid,
+        in_specs=[specs["tile"], specs["before"], specs["after"], gqk,
+                  gqk_after, gv, gv_after, specs["taps"]],
+        out_specs=[specs["tile"], specs["taps"]],
+        out_shape=[jax.ShapeDtypeStruct(data.shape, data.dtype),
+                   jax.ShapeDtypeStruct((8, data.shape[2]), _F32)],
+        # the taps' gradient is summed over a channel block's tiles
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_GDNCONV_WALK),
+        interpret=interpret, name="mx_gdnconv_bwd",
+    )(data, data, data, dqk, dqk, dv, dv, taps)
+    return ddata, dw[:conv_weight.shape[1]].T.astype(conv_weight.dtype)
+
+
+def _gdnconv_blocks(kernel, rows, channels, group, piece, dk, dv, dtype):
+    """Bytes of VMEM one grid step of *kernel* takes: its blocks, each held
+    twice (the flat tile of the input, in the backward kernel of its
+    gradient too; the same rows of the block's heads in q and k's array and
+    in v's, as the lanes pad them; the halo blocks; the taps' 8 float32
+    rows, with their gradient's in the backward kernel), and two dozen
+    float32 temporaries of a piece of a group's columns."""
+    flat, halos, taps = {"fwd": (1, 1, 1), "bwd": (2, 3, 2)}[kernel]
+    item, halo = jnp.dtype(dtype).itemsize, _halo_rows(dtype)
+    heads = sum(channels // d * _padded(r, d, item) for d in (dk, dv)
+                for r in ((rows,) if kernel == "fwd" else (rows, halo)))
+    blocks = channels * item * (flat * rows + halos * halo) + heads \
+        + taps * 8 * channels * 4
+    return 2 * blocks + 24 * _padded(piece + 2 * halo, group, 4)
+
+
+def _gdnconv_plan(data, conv_weight, heads, dk):
+    """``(tiles, None)`` where the kernels take this call, ``(None, why
+    not)`` where it stays `_conv_heads_body`.  From what the input shows
+    alone: ``(batch, seq, channels)`` in 2 or 4 bytes, q and k's width and
+    v's in whole groups of ``lcm(dk, 128)`` channels and a block of them in
+    whole heads of v, the taps within a halo block and the rows their
+    gradient is summed in, the sequence in whole pieces of both kernels, a
+    grid step within `_GDNCONV_VMEM`, one device."""
+    tiles, (width, taps) = GDNCONV_TILES, conv_weight.shape
+    keys, group = 2 * heads * dk, _group(dk)
+    if data.ndim != 3 or jnp.dtype(data.dtype).itemsize not in (2, 4):
+        return None, "not (batch, seq, channels) in a dtype of 2 or 4 bytes"
+    if keys % group or (width - keys) % group:
+        return None, "%d channels of q and k and %d of v are not whole " \
+            "groups of %d (whole heads of %d and whole lane tiles)" % (
+                keys, width - keys, group, dk)
+    most = min(_SCALE_ROW, _halo_rows(data.dtype) + 1)
+    if not 2 <= taps <= most:
+        return None, "%d taps are not 2 to %d" % (taps, most)
+    rows = {k: _fit_rows(data.shape[1], *tiles[k]) for k in ("fwd", "bwd")}
+    if None in rows.values():
+        return None, "a sequence of %d is not whole pieces of %d and %d " \
+            "rows" % (data.shape[1], tiles["fwd"][1], tiles["bwd"][1])
+    # the most whole groups within the tile that divide both widths
+    both = int(np.gcd(keys, width - keys)) // group
+    channels = group * max(n for n in range(1, both + 1) if both % n == 0
+                           and (n == 1 or n * group <= tiles["channels"]))
+    dv = (width - keys) // heads
+    if channels % dv:
+        return None, "v's heads of %d do not make whole blocks of %d " \
+            "channels" % (dv, channels)
+    if any(_gdnconv_blocks(k, r, channels, group, tiles[k][1], dk, dv,
+                           data.dtype)
+           > _GDNCONV_VMEM for k, r in rows.items()):
+        return None, "groups of %d channels with their blocks over the " \
+            "VMEM budget" % group
+    if not _one_device():
+        # XLA does not partition a Mosaic kernel, and no cell spans chips
+        return None, "a mesh of several devices"
+    return dict({k: dict(rows=r, piece=tiles[k][1]) for k, r in rows.items()},
+                channels=channels), None
+
+
+def _fit_rows(seq, most, piece):
+    """The most rows up to *most* that are whole pieces and divide *seq*
+    (None: no such number)."""
+    return next((rows for rows in range(min(most, seq) // piece * piece, 0,
+                                        -piece) if seq % rows == 0), None)
+
+
+def _conv_heads_forward(data, conv_weight, heads, dk, eps):
+    at = dict(heads=heads, dk=dk, eps=eps)
+    tiles, _ = _gdnconv_plan(data, conv_weight, heads, dk)
+    if tiles is None:
+        return _conv_heads_body(data, conv_weight, **at)
+    return jax.lax.platform_dependent(
+        data, conv_weight,
+        default=functools.partial(_conv_heads_body, **at),
+        tpu=functools.partial(
+            _gdnconv_fwd_pallas, **tiles["fwd"], channels=tiles["channels"],
+            **at))
+
+
+def _conv_heads_backward(heads, dk, eps, kept, dout):
+    at = dict(heads=heads, dk=dk, eps=eps)
+    tiles, _ = _gdnconv_plan(*kept, heads, dk)
+    if tiles is None:
+        return _conv_heads_body_backward(*kept, dout, **at)
+    return jax.lax.platform_dependent(
+        *kept, dout,
+        default=functools.partial(_conv_heads_body_backward, **at),
+        tpu=functools.partial(
+            _gdnconv_bwd_pallas, **tiles["bwd"], channels=tiles["channels"],
+            **at))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _conv_heads(data, conv_weight, heads, dk, eps):
+    """q, k and v from their concatenated projections: the kernels where
+    `_gdnconv_plan` gives tiles and the program is lowered for the TPU,
+    `_conv_heads_body` (and JAX's derivative of it, from the two inputs
+    again) everywhere else."""
+    return _conv_heads_forward(data, conv_weight, heads, dk, eps)
+
+
 _conv_heads.defvjp(
-    lambda data, w, heads, dk, eps: (_conv_heads_body(data, w, heads, dk,
-                                                       eps), (data, w)),
-    _conv_heads_bwd)
+    lambda data, w, heads, dk, eps: (
+        _conv_heads_forward(data, w, heads, dk, eps), (data, w)),
+    _conv_heads_backward)
+
+
+def _record_gdnconv_plan(data, conv_weight, tiles, why):
+    """One `mx.gdnconv.plan` span each time the op is traced (as
+    `mx.gdn.plan`: the plan is a fact of the compiled program)."""
+    with profiler.scope(  # graftlint: disable=JG003
+            "mx.gdnconv.plan", "gdn") as span:
+        span.args = {
+            "shape": list(data.shape), "dtype": jnp.dtype(data.dtype).name,
+            "taps": conv_weight.shape[1],
+            # `kernel`: mx_gdnconv_fwd and mx_gdnconv_bwd where the program
+            # is lowered for the TPU (`_conv_heads_body` where it is
+            # lowered for anything else); `xla`: the body, and why
+            "path": "xla" if tiles is None else "kernel", "why": why,
+            "seq_tile": tiles and {k: tiles[k]["rows"]
+                                   for k in ("fwd", "bwd")},
+            "piece_rows": tiles and {k: tiles[k]["piece"]
+                                     for k in ("fwd", "bwd")},
+            "channel_tile": tiles and tiles["channels"],
+            "halo_rows": tiles and _halo_rows(data.dtype),
+            # what either path keeps for the backward pass: the two inputs
+            "residual_bytes": data.size * data.dtype.itemsize
+            + conv_weight.size * conv_weight.dtype.itemsize}
 
 
 @register_op("_contrib_ShortConvHeads", aliases=("ShortConvHeads",),
@@ -694,7 +1186,15 @@ def _short_conv_heads(data, conv_weight, num_heads=1, key_dim=1, eps=1e-6):
     + eps) / sqrt(dk)``, ``k / sqrt(sum(k^2) + eps)`` and ``v`` as it is:
     ``(B, S, H, dk)`` twice and ``(B, S, H, dv)``.  The convolution, the
     silu and the norms are float32 and rounded once; the backward pass keeps
-    the two inputs and computes them again."""
+    the two inputs and computes them again.
+
+    Where the program is lowered for the TPU on one device, at widths that
+    are whole groups of ``lcm(dk, 128)`` channels and a sequence in whole
+    pieces (`GDNCONV_TILES`), it is the kernels ``mx_gdnconv_fwd`` and
+    ``mx_gdnconv_bwd``, one pass over the channels each way
+    (`mx.gdnconv.plan` says ``path: kernel``); on every other platform and
+    at every other shape it is `_conv_heads_body`, the same arithmetic in
+    `jax.numpy`, and the span says why."""
     heads, dk = int(num_heads), int(key_dim)
     if conv_weight.shape[0] != data.shape[-1] \
             or (data.shape[-1] - 2 * heads * dk) % heads \
@@ -703,6 +1203,8 @@ def _short_conv_heads(data, conv_weight, num_heads=1, key_dim=1, eps=1e-6):
             "%d channels are not %d heads of two %d-wide keys and a value "
             "under taps %s" % (data.shape[-1], heads, dk,
                                conv_weight.shape))
+    _record_gdnconv_plan(data, conv_weight,
+                         *_gdnconv_plan(data, conv_weight, heads, dk))
     return _conv_heads(data, conv_weight, heads, dk, float(eps))
 
 

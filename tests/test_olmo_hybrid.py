@@ -290,20 +290,27 @@ def test_bf16_inputs_come_back_in_bf16_and_the_state_stays_float32():
     assert "f32[2,2,8,16]" in jaxpr and "bf16[2,2,8,16]" not in jaxpr
 
 
-def plan_of(q, v, chunk=64, devices=1):
-    """The `mx.gdn.plan` span of one traced call at these shapes."""
+def traced_plan(name, op, avals, devices=1):
+    """The arguments of the one span *name* that tracing *op* at *avals*
+    records, under a mesh of *devices* devices."""
     from jax.sharding import Mesh
     from mxnet_tpu.parallel import mesh as mesh_mod
-    gate = q.shape[:3]
     since = max([s.id for s in profiler.spans()] or [0])
     with mesh_mod.use_mesh(Mesh(np.array(jax.devices()[:devices]), ("dp",))):
-        jax.eval_shape(
-            lambda *a: delta_rule._gated_delta_rule_op(*a, chunk=chunk),
-            q, q, v, jax.ShapeDtypeStruct(gate, jnp.float32),
-            jax.ShapeDtypeStruct(gate, v.dtype))
+        jax.eval_shape(op, *avals)
     plan, = [s.args for s in profiler.spans()
-             if s.name == "mx.gdn.plan" and s.id > since]
+             if s.name == name and s.id > since]
     return plan
+
+
+def plan_of(q, v, chunk=64, devices=1):
+    """The `mx.gdn.plan` span of one traced call at these shapes."""
+    gate = q.shape[:3]
+    return traced_plan(
+        "mx.gdn.plan",
+        lambda *a: delta_rule._gated_delta_rule_op(*a, chunk=chunk),
+        (q, q, v, jax.ShapeDtypeStruct(gate, jnp.float32),
+         jax.ShapeDtypeStruct(gate, v.dtype)), devices)
 
 
 def test_the_plan_span_and_the_step_stat_say_what_a_call_keeps():
@@ -404,6 +411,222 @@ def test_the_convolution_its_silu_and_the_norms_are_the_reference_s():
     with pytest.raises(ValueError, match="channels"):
         delta_rule._short_conv_heads(x[..., :-1], w[:-1], num_heads=heads,
                                      key_dim=dk)
+
+
+# -- the short convolution's kernels, interpreted ---------------------------------
+#: the cell's widths: 30 heads of 96 | 192 (11520 channels, q's border with k
+#: at channel 2880 in the middle of a lane tile), 4 taps
+CELL = dict(heads=30, dk=96, dv=192)
+#: two heads: one group of q and k and one of v, the border at channel 192
+NARROW = dict(heads=2, dk=96, dv=192)
+
+
+def conv_inputs(heads, dk, dv, seq, dtype, batch=1, taps=4, seed=6):
+    rng = np.random.default_rng(seed)
+    width = heads * (2 * dk + dv)
+    x = jnp.asarray(rng.normal(size=(batch, seq, width)), dtype)
+    w = jnp.asarray(rng.normal(size=(width, taps)) * 0.3, dtype)
+    douts = tuple(jnp.asarray(rng.normal(size=(batch, seq, heads, d)), dtype)
+                  for d in (dk, dk, dv))
+    return x, w, douts
+
+
+def conv_pair(path, x, w, douts, heads, dk, rows=32, channels=384, piece=16,
+              **_):
+    """``(q, k, v, d data, d taps)`` by `_conv_heads_body` and JAX's
+    derivative of it (``xla``) or by the kernel pair, interpreted, at tiles
+    of *rows* rows in pieces of *piece* (``kernel``)."""
+    at = dict(heads=heads, dk=dk, eps=1e-6)
+    if path == "xla":
+        out, pull = jax.vjp(lambda x, w: delta_rule._conv_heads_body(
+            x, w, **at), x, w)
+        return out + pull(douts)
+    tiles = dict(at, rows=rows, channels=channels, piece=piece,
+                 interpret=True)
+    return delta_rule._gdnconv_fwd_pallas(x, w, **tiles) \
+        + delta_rule._gdnconv_bwd_pallas(x, w, douts, **tiles)
+
+
+def assert_close(mine, theirs, dtype, what):
+    """float32: the two orders of the same float32 arithmetic; bf16: one
+    bf16 rounding of the largest entry, where the two roundings part."""
+    assert mine.dtype == theirs.dtype == dtype and mine.shape == theirs.shape
+    mine, theirs = (np.asarray(a, np.float64) for a in (mine, theirs))
+    most = np.abs(theirs).max()
+    if dtype == jnp.float32:
+        assert np.abs(mine - theirs).max() <= 2e-6 * most, what
+    else:
+        assert np.abs(mine - theirs).max() <= 2.0 ** -7 * most, what
+        assert np.linalg.norm(mine - theirs) \
+            <= 2e-3 * np.linalg.norm(theirs), what
+
+
+CONV_NAMES = ("q", "k", "v", "d data", "d taps")
+
+
+@pytest.mark.parametrize("piece", [16, 32])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_convolution_s_kernels_are_the_body_at_the_cell_s_widths(dtype,
+                                                                     piece):
+    """Forward, the input's gradient and the taps' at 30 heads of 96 | 192
+    over three tiles of rows, two pieces or one a tile."""
+    dtype = jnp.dtype(dtype)
+    x, w, douts = conv_inputs(seq=96, dtype=dtype, **CELL)
+    want = conv_pair("xla", x, w, douts, **CELL)
+    got = conv_pair("kernel", x, w, douts, piece=piece, **CELL)
+    for name, mine, theirs in zip(CONV_NAMES, got, want):
+        assert_close(mine, theirs, dtype, (name, piece))
+    # the head on each side of channel 2880: q's last takes dk ** -0.5, k's
+    # first does not, and both are unit vectors before it
+    q, k = (np.asarray(a, np.float64) for a in got[:2])
+    np.testing.assert_allclose(np.linalg.norm(q[0, :, -1], axis=-1),
+                               96 ** -0.5, rtol=1e-2)
+    np.testing.assert_allclose(np.linalg.norm(k[0, :, 0], axis=-1), 1.0,
+                               rtol=1e-2)
+
+
+@pytest.mark.parametrize("only", [0, 1, 2])
+def test_each_output_s_gradient_reaches_the_input_and_the_taps(only):
+    """The gradient of q alone, of k alone and of v alone (the other two
+    zero), in float32 at the cell's widths."""
+    x, w, douts = conv_inputs(seq=64, dtype=jnp.float32, **CELL)
+    douts = tuple(d if i == only else jnp.zeros_like(d)
+                  for i, d in enumerate(douts))
+    want = conv_pair("xla", x, w, douts, **CELL)
+    got = conv_pair("kernel", x, w, douts, **CELL)
+    for name, mine, theirs in zip(CONV_NAMES[3:], got[3:], want[3:]):
+        assert_close(mine, theirs, jnp.float32, (name, only))
+        assert np.abs(np.asarray(theirs)).max() > 0
+
+
+def test_a_packed_row_does_not_see_the_row_before_it():
+    """A batch of two rows: the second row's outputs and gradients are what
+    they are alone, whatever the first row holds, and its first ``taps -
+    1`` positions see zeros (position 0 is the last tap's)."""
+    x, w, douts = conv_inputs(seq=64, dtype=jnp.float32, batch=2, **NARROW)
+    got = conv_pair("kernel", x, w, douts, **NARROW)
+    for name, mine, theirs in zip(CONV_NAMES, got, conv_pair(
+            "xla", x, w, douts, **NARROW)):
+        assert_close(mine, theirs, jnp.float32, name)
+    other = conv_pair("kernel", x.at[0].set(7.0), w, douts, **NARROW)
+    alone = conv_pair("kernel", x[1:], w, tuple(d[1:] for d in douts),
+                      **NARROW)
+    for name, a, b, c in zip(CONV_NAMES[:4], got, other, alone):
+        np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]),
+                                      err_msg=name)
+        np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(c[0]),
+                                      err_msg=name)
+    first = jax.nn.silu(x[:, 0] * w[:, 3])
+    np.testing.assert_allclose(
+        np.asarray(got[2][:, 0]).reshape(2, -1),
+        np.asarray(first[:, 2 * 2 * 96:]), rtol=1e-6)
+
+
+@pytest.mark.parametrize("at", [31, 32, 34, 63])
+def test_a_gradient_at_a_tile_s_edge_crosses_it_both_ways(at):
+    """One position's gradient, at a tile's last row, at the next tile's
+    first rows and at the sequence's end, reaches the ``taps - 1`` positions
+    before it through the taps (the backward kernel's rows after the tile)
+    and its own head through the norm, and no other."""
+    x, w, douts = conv_inputs(seq=64, dtype=jnp.float32, **NARROW)
+    douts = tuple(jnp.zeros_like(d).at[:, at].set(d[:, at]) for d in douts)
+    want = conv_pair("xla", x, w, douts, **NARROW)
+    got = conv_pair("kernel", x, w, douts, **NARROW)
+    for name, mine, theirs in zip(CONV_NAMES[3:], got[3:], want[3:]):
+        assert_close(mine, theirs, jnp.float32, (name, at))
+    reached = np.flatnonzero(np.abs(np.asarray(got[3][0])).max(-1))
+    assert reached.tolist() == list(range(at - 3, at + 1))
+
+
+def conv_plan_of(shape, dtype, taps=4, heads=30, dk=96, devices=1):
+    """The arguments of the one `mx.gdnconv.plan` span that tracing the op
+    at these shapes records."""
+    return traced_plan(
+        "mx.gdnconv.plan",
+        lambda x, w: delta_rule._short_conv_heads(
+            x, w, num_heads=heads, key_dim=dk),
+        (jax.ShapeDtypeStruct(shape, dtype),
+         jax.ShapeDtypeStruct((shape[-1], taps), dtype)), devices)
+
+
+def test_the_convolution_s_plan_span_says_what_the_cell_runs():
+    plan = conv_plan_of((1, 3072, 11520), jnp.bfloat16)
+    tiles = delta_rule.GDNCONV_TILES
+    assert plan == {
+        "shape": [1, 3072, 11520], "dtype": "bfloat16", "taps": 4,
+        "path": "kernel", "why": None,
+        "seq_tile": {k: min(3072, tiles[k][0]) for k in ("fwd", "bwd")},
+        "piece_rows": {k: tiles[k][1] for k in ("fwd", "bwd")},
+        "channel_tile": plan["channel_tile"], "halo_rows": 16,
+        # the two inputs and nothing float32
+        "residual_bytes": 2 * (3072 * 11520 + 11520 * 4)}
+    # whole groups of lcm(96, 128) that divide q and k's 5760 and v's 5760
+    assert plan["channel_tile"] % 384 == 0 and 5760 % plan["channel_tile"] == 0
+    assert plan["channel_tile"] <= max(384, tiles["channels"])
+
+
+CONV_REFUSALS = {
+    # (shape, dtype, taps, heads, dk, devices) -> why not
+    "a-mesh-of-two": (((1, 3072, 11520), "bfloat16", 4, 30, 96, 2),
+                      "a mesh of several devices"),
+    "one-byte-numbers": (((1, 3072, 11520), "float8_e4m3fn", 4, 30, 96, 1),
+                         "not (batch, seq, channels) in a dtype of 2 or 4"),
+    "heads-that-make-no-whole-group": (
+        ((1, 3072, 3 * 384), "bfloat16", 4, 3, 96, 1),
+        "576 channels of q and k and 576 of v are not whole groups of 384"),
+    "keys-of-eight": (((2, 128, 96), "float32", 4, 3, 8, 1),
+                      "48 channels of q and k and 48 of v are not whole "
+                      "groups of 128"),
+    "values-whose-heads-straddle-a-block": (
+        ((1, 3072, 12 * (2 * 96 + 160)), "bfloat16", 4, 12, 96, 1),
+        "v's heads of 160 do not make whole blocks of 384 channels"),
+    "one-tap": (((1, 3072, 11520), "bfloat16", 1, 30, 96, 1),
+                "1 taps are not 2 to 7"),
+    "taps-over-the-rows-their-gradient-is-summed-in": (
+        ((1, 3072, 11520), "bfloat16", 8, 30, 96, 1),
+        "8 taps are not 2 to 7"),
+    "a-sequence-of-part-pieces": (
+        ((1, 3072 + 8, 11520), "bfloat16", 4, 30, 96, 1),
+        "a sequence of 3080 is not whole pieces of"),
+    "a-group-over-the-vmem-budget": (
+        ((1, 3072, 4 * 97 * 128), "bfloat16", 4, 128, 97, 1),
+        "groups of 12416 channels with their blocks over the VMEM budget"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CONV_REFUSALS))
+def test_the_convolution_s_plan_says_why_a_call_stays_jax_numpy(case):
+    (shape, dtype, taps, heads, dk, devices), why = CONV_REFUSALS[case]
+    plan = conv_plan_of(shape, jnp.dtype(dtype), taps, heads, dk, devices)
+    assert plan["path"] == "xla" and plan["why"].startswith(why)
+    assert plan["seq_tile"] is None and plan["channel_tile"] is None \
+        and plan["piece_rows"] is None and plan["halo_rows"] is None
+    assert plan["shape"] == list(shape) and plan["taps"] == taps
+    assert plan["residual_bytes"] == jnp.dtype(dtype).itemsize * (
+        int(np.prod(shape)) + shape[-1] * taps)
+
+
+def test_a_refused_call_runs_the_body_and_a_planned_one_the_body_off_the_tpu():
+    """On the CPU both are `_conv_heads_body` to the bit: the kernels are
+    the other branch of `platform_dependent`."""
+    for shape in ((1, 512, 2 * 384), (1, 40, 2 * 384)):
+        x, w, douts = conv_inputs(seq=shape[1], dtype=jnp.float32, **NARROW)
+        assert (delta_rule._gdnconv_plan(x, w, 2, 96)[0] is None) \
+            == (shape[1] == 40)
+        def op(x, w):
+            return delta_rule._short_conv_heads(x, w, num_heads=2, key_dim=96)
+
+        got, pull = jax.vjp(op, x, w)
+        for mine, theirs in zip(got + pull(douts), conv_pair(
+                "xla", x, w, douts, **NARROW)):
+            np.testing.assert_array_equal(np.asarray(mine),
+                                          np.asarray(theirs))
+        # the planned call's trace names both kernels (the other branch),
+        # the refused call's neither
+        text = str(jax.make_jaxpr(
+            lambda x, w, d: jax.vjp(op, x, w)[1](d))(x, w, douts))
+        for kernel in ("mx_gdnconv_fwd", "mx_gdnconv_bwd"):
+            assert (kernel in text) == (shape[1] == 512), (kernel, shape)
 
 
 def test_the_taps_are_the_gated_short_convolution_s():
@@ -555,6 +778,32 @@ def test_the_new_cell_s_flash_kernels_trace_as_the_parent_s(kernel):
                "facf3bc90"}[kernel]
 
 
+#: the short convolution's pair at the cell's arguments and `GDNCONV_TILES`,
+#: traced on this tree (PR 51): what the Olmo cell runs is what it ran
+CONV_PINS = {
+    "fwd": "f3d8af547074872aa2b9fd759471d4a537492f2d18ac18c948aa8ae9fd1f9c1d",
+    "bwd": "96b03f8a4522c98cfff1730e49f53b8bbda688a284ac9a3fe9caf3a4a6a5e16b"}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_the_cell_s_short_convolution_kernels_trace_as_pinned(kernel):
+    bf = jnp.bfloat16
+    x = jax.ShapeDtypeStruct((1, 3072, 11520), bf)
+    w = jax.ShapeDtypeStruct((11520, 4), bf)
+    douts = tuple(jax.ShapeDtypeStruct((1, 3072, 30, d), bf)
+                  for d in (96, 96, 192))
+    tiles, _ = delta_rule._gdnconv_plan(x, w, 30, 96)
+    at = dict(heads=30, dk=96, eps=1e-6, channels=tiles["channels"],
+              **tiles[kernel])
+    if kernel == "fwd":
+        got = jaxpr_sha(lambda x, w: delta_rule._gdnconv_fwd_pallas(
+            x, w, **at), x, w)
+    else:
+        got = jaxpr_sha(lambda x, w, d: delta_rule._gdnconv_bwd_pallas(
+            x, w, d, **at), x, w, douts)
+    assert got == CONV_PINS[kernel]
+
+
 # -- the blocks and the decoder ---------------------------------------------------
 def test_the_attention_block_s_two_departures():
     from mxnet_tpu.gluon.contrib.nn import GroupedQueryAttention
@@ -686,6 +935,49 @@ def test_the_compiled_layers_lie_under_their_scopes():
     whys = {s.args["why"] for s in spans if s.name == "mx.headrope.plan"}
     assert whys == {"one norm over the whole width of 48, not a head's 16 "
                     "and no rotary positions: nothing to turn"}
+
+
+def traced_step(family, cfg, seeded_net, seed):
+    """``(the graph's operators, the training step's jaxpr as text, the
+    names of the spans its tracing recorded)`` of a small decoder: the step
+    is traced as `fit_batch` would trace it, and neither compiled nor
+    run.  The jaxpr holds both branches of every `platform_dependent`, so
+    a Mosaic kernel shows in it by name on the CPU too."""
+    net, loss = seeded_net(cfg)[:2]
+    (x, y), = family.batches(cfg, seed, 1, cfg["train"]["per_chip_batch"])
+    trainer = models_common.make_trainer(net, loss, cfg["train"],
+                                         jax.devices()[:1])
+    since = max([s.id for s in profiler.spans()] or [0])
+    trainer._ensure_built(x, y)
+    trainer._refresh_frozen(x.shape, y.shape)
+    text = str(jax.make_jaxpr(trainer._step_fn)(*trainer._step_args(x, y)))
+    return ({n.op.name for n in trainer._graph._topo() if not n.is_var},
+            text, {s.name for s in profiler.spans() if s.id > since})
+
+
+@pytest.mark.parametrize("other", ["sdar_moe", "lfm2_moe", "laguna",
+                                   "olmo_hybrid"])
+def test_no_other_family_s_step_runs_the_short_convolution_over_heads(other):
+    """`_contrib_ShortConvHeads` has one caller, `GatedDeltaNet`, and one
+    family builds it: the traced step of a small SDAR, LFM2 and Laguna
+    decoder (their own tests' configurations) has no such node, no
+    ``mx_gdnconv_*`` call in either branch and no `mx.gdnconv.plan` span,
+    so a change to the operator cannot reach their cells.  Olmo's own small
+    decoder, traced the same way, has the node and the span (its heads of 8
+    make no whole group, so its plan says ``xla`` and no kernel is traced:
+    the kernels' own tests above trace them at the cell's widths)."""
+    import importlib
+    theirs = importlib.import_module("test_" + other)
+    cfg = {"sdar_moe": lambda: theirs.config(),
+           "lfm2_moe": lambda: theirs.config(*theirs.KINDS["all"]),
+           "laguna": lambda: theirs.config(),
+           "olmo_hybrid": lambda: config()}[other]()
+    ops, text, spans = traced_step(theirs.family, cfg, theirs.seeded,
+                                   theirs.SEED)
+    assert "dot_general" in text and "mx_gdnconv" not in text
+    assert ("_contrib_ShortConvHeads" in ops) == (other == "olmo_hybrid")
+    assert ("mx.gdnconv.plan" in spans) == (other == "olmo_hybrid")
+    assert ("mx.gdn.plan" in spans) == (other == "olmo_hybrid")
 
 
 # -- the whole model ----------------------------------------------------------
@@ -912,9 +1204,21 @@ def test_a_linear_layer_compiles_for_the_described_chip_with_no_square_array(
     # the solves of all chunks at once were `f32[64,1,30,64,288]`
     assert not [s for s in shapes if s[-2:] == (64, dk + dv)]
     # the `jax.numpy` path is still the other branch of the traced program:
-    # its two scans over the chunks, 64 steps each
-    assert scan_lengths(jax.make_jaxpr(step)(avals, jax.ShapeDtypeStruct(
-        (1, seq, dim), jnp.float32))) == [seq // 64, seq // 64]
+    # its two scans over the chunks, 64 steps each; the other loops are the
+    # short convolution's kernels' walks over the pieces of a tile's rows,
+    # q and k's and v's, forward and backward
+    fwd, bwd = (rows // piece for rows, piece in (
+        delta_rule.GDNCONV_TILES[k] for k in ("fwd", "bwd")))
+    assert sorted(scan_lengths(jax.make_jaxpr(step)(
+        avals, jax.ShapeDtypeStruct((1, seq, dim), jnp.float32)))) \
+        == sorted([seq // 64] * 2 + [fwd] * 2 + [bwd] * 2)
+    for kernel in ("mx_gdnconv_fwd", "mx_gdnconv_bwd"):
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and kernel + "/" in line]
+        assert len(calls) == 1 and "mx.gdn.conv/" in calls[0], kernel
+    # q, k and v reach the rule's kernels as the convolution's wrote them:
+    # no copy turns heads of 96 around
+    assert not re.search(r"copy\(.*mx\.gdn\.(conv|scan)", text)
     plan, = {tuple(sorted((k, v) for k, v in s.args.items()))
              for s in profiler.spans()
              if s.name == "mx.gdn.plan" and s.id > since}
