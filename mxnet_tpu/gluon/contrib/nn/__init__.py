@@ -4,4 +4,4 @@ from .basic_layers import (Concurrent, GatedDeltaNet, GatedMLP,  # noqa
                            GatedShortConv, GroupedQueryAttention, HybridConcurrent, Identity,
                            LatentAttention, MoEFFN, MultiHeadAttention,
                            RoutedExperts, SharedExperts, SparseAttention,
-                           SparseEmbedding, SyncBatchNorm)
+                           SparseEmbedding, StateSpaceMixer, SyncBatchNorm)

@@ -325,6 +325,99 @@ class GatedDeltaNet(HybridBlock):
                 num_hidden=self._units)
 
 
+class StateSpaceMixer(HybridBlock):
+    """A state-space layer's mixer (Mamba-2, arXiv:2405.21060, as the
+    ``bamba`` / ``granitemoehybrid`` models publish it): a float32 state of
+    *head_dim* x *state* a head carried along the sequence under a scalar
+    decay a head and token, in place of attention over it.  For ``x (batch,
+    seq, units)`` with ``I = num_heads * head_dim``:
+
+    - one product of the input, the row blocks of one stored matrix: the
+      output's gate z (``I``), the channels that go through the convolution
+      (x: ``I``, then the input and output maps B and C of *groups* groups,
+      ``groups * state`` each) and one step size a head;
+    - those channels through one depthwise causal convolution of
+      *conv_kernel* taps with a bias, then silu (``contrib.ShortConvSilu``);
+    - ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` in float32
+      (``contrib.StateSpaceGates``);
+    - the recurrence itself, ``contrib.StateSpaceScan``, chunkwise in chunks
+      of *chunk* tokens (the sequence has to be whole chunks), with the skip
+      ``D x``;
+    - ``rms(y * silu(z), gamma)``, the gate in front of the norm and the
+      norm over a group's whole width (``contrib.GatedRMSNorm`` with
+      ``gate_first``), and the output product.
+
+    No biases but the convolution's.  Device scopes ``mx.ssm.project`` (the
+    product and its split), ``mx.ssm.conv`` (convolution, bias and silu, the
+    gates' activations), ``mx.ssm.scan`` (the recurrence, forward and
+    backward) and ``mx.ssm.out`` (the gated norm and the output product);
+    spans ``mx.ssm.plan`` and ``mx.ssmconv.plan`` a traced call."""
+
+    def __init__(self, units, num_heads, head_dim, state, groups=1,
+                 conv_kernel=4, chunk=256, epsilon=1e-5,
+                 weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % groups:
+            raise ValueError("%d heads do not make %d groups"
+                             % (num_heads, groups))
+        self._units, self._heads = units, int(num_heads)
+        self._groups, self._state = int(groups), int(state)
+        self._chunk, self._eps = int(chunk), float(epsilon)
+        self._inner = self._heads * int(head_dim)
+        self._conv = self._inner + 2 * self._groups * self._state
+        self._rows = self._inner + self._conv + self._heads
+        with self.name_scope():
+            def vector(name, size, init):
+                return self.params.get(name, shape=(size,), init=init)
+            self.in_weight = self.params.get(
+                "in_weight", shape=(self._rows, units),
+                init=weight_initializer)
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(self._conv, conv_kernel),
+                init=weight_initializer)
+            self.conv_bias = vector("conv_bias", self._conv, "zeros")
+            self.a_log = vector("a_log", self._heads, "zeros")
+            self.dt_bias = vector("dt_bias", self._heads, "zeros")
+            self.skip = vector("skip", self._heads, "ones")
+            self.norm_gamma = vector("norm_gamma", self._inner, "ones")
+            self.out_weight = self.params.get(
+                "out_weight", shape=(units, self._inner),
+                init=weight_initializer)
+
+    def hybrid_forward(self, F, x, in_weight, conv_weight, conv_bias, a_log,
+                       dt_bias, skip, norm_gamma, out_weight):
+        from .... import symbol
+        inner, maps = self._inner, self._groups * self._state
+
+        def part(y, lo, hi):
+            return F.slice_axis(y, axis=-1, begin=lo, end=hi)
+
+        with symbol.AttrScope(__scope__="mx.ssm.project"):
+            zxd = F.FullyConnected(x, in_weight, no_bias=True, flatten=False,
+                                   num_hidden=self._rows)
+            z = part(zxd, 0, inner)
+            xbc = part(zxd, inner, inner + self._conv)
+            dt = part(zxd, inner + self._conv, self._rows)
+        with symbol.AttrScope(__scope__="mx.ssm.conv"):
+            xbc = F.contrib.ShortConvSilu(xbc, conv_weight, conv_bias)
+            gates = F.contrib.StateSpaceGates(dt, a_log, dt_bias)
+            by_head = F.Reshape(part(xbc, 0, inner),
+                                shape=(0, 0, self._heads, -1))
+            b, c = (F.Reshape(part(xbc, lo, lo + maps),
+                              shape=(0, 0, self._groups, -1))
+                    for lo in (inner, inner + maps))
+        with symbol.AttrScope(__scope__="mx.ssm.scan"):
+            y = F.contrib.StateSpaceScan(by_head, gates[0], gates[1], b, c,
+                                         skip, chunk=self._chunk)
+        with symbol.AttrScope(__scope__="mx.ssm.out"):
+            y = F.Reshape(y, shape=(0, 0, self._groups, -1))
+            return F.FullyConnected(
+                F.contrib.GatedRMSNorm(y, z, norm_gamma, eps=self._eps,
+                                       gate_first=True),
+                out_weight, no_bias=True, flatten=False,
+                num_hidden=self._units)
+
+
 class GroupedQueryAttention(HybridBlock):
     """Causal self-attention with fewer key/value heads than query
     heads, RMS norm over each query and key head, and rotary positions;
@@ -374,12 +467,18 @@ class GroupedQueryAttention(HybridBlock):
     model whose other layers carry the order).  With either, the q and k
     products do not take the head-rope kernels (``mx.headrope.plan`` says
     ``path`` ``xla`` and why), and the layer takes the scopes ``mx.gqa.*``
-    as a gated one does."""
+    as a gated one does.  *qk_norm* None leaves q and k as projected: no
+    norm parameters are created, and with a null ``rope_theta`` beside it
+    (the only way it is built: a norm-less q and k with rotary positions has
+    no caller) the two go to the attention by a reshape and a transpose, as
+    v does.  *scale* is the softmax's scale in place of ``head_dim ** -0.5``
+    (a model that publishes an ``attention_multiplier``); a layer with
+    either takes the scopes ``mx.gqa.*`` too."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
                  rope_theta=10000.0, epsilon=1e-5, weight_initializer=None,
                  diffusion_block=None, window=None, gate=False, rope=None,
-                 qk_norm="head", **kwargs):
+                 qk_norm="head", scale=None, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("num_heads (%d) must be a multiple of "
@@ -387,11 +486,12 @@ class GroupedQueryAttention(HybridBlock):
         if window and diffusion_block:
             raise ValueError("a window goes with a causal mask, not with "
                              "the block-diffusion one")
-        if qk_norm not in ("head", "width"):
+        if qk_norm not in ("head", "width", None):
             raise ValueError("qk_norm %r is neither \"head\" (a norm over "
                              "each head) nor \"width\" (one over all of "
                              "them)" % (qk_norm,))
         head_dim = head_dim or units // num_heads
+        self._scale = head_dim ** -0.5 if scale is None else float(scale)
         self._units = units
         self._heads, self._kv_heads = num_heads, num_kv_heads
         self._head_dim, self._theta = head_dim, float(rope_theta)
@@ -407,7 +507,11 @@ class GroupedQueryAttention(HybridBlock):
             self._rotary = rope_frequencies(rope, head_dim)
         if qk_norm == "width":
             self._rotary["norm_over"] = "width"
-        departs = unturned or qk_norm == "width"
+        if qk_norm is None and not unturned:
+            raise ValueError("q and k with no norm and rotary positions are "
+                             "not built: qk_norm None goes with a rope "
+                             "entry whose rope_theta is null")
+        departs = unturned or qk_norm != "head" or scale is not None
         self._mask = {"causal": True} if not diffusion_block else {
             "mask": "block_diffusion", "mask_block": int(diffusion_block)}
         if window:
@@ -433,12 +537,13 @@ class GroupedQueryAttention(HybridBlock):
             self.out_weight = weight("out_weight", units,
                                      num_heads * head_dim)
             wide = qk_norm == "width"
-            self.q_gamma = self.params.get(
-                "query_norm_gamma", init="ones",
-                shape=(num_heads * head_dim if wide else head_dim,))
-            self.k_gamma = self.params.get(
-                "key_norm_gamma", init="ones",
-                shape=(num_kv_heads * head_dim if wide else head_dim,))
+            if qk_norm:
+                self.q_gamma = self.params.get(
+                    "query_norm_gamma", init="ones",
+                    shape=(num_heads * head_dim if wide else head_dim,))
+                self.k_gamma = self.params.get(
+                    "key_norm_gamma", init="ones",
+                    shape=(num_kv_heads * head_dim if wide else head_dim,))
             if gate:
                 self.gate_weight = weight("gate_weight", num_heads, units)
 
@@ -474,7 +579,7 @@ class GroupedQueryAttention(HybridBlock):
                     num_hidden=self._heads)), axes=(0, 2, 1)), axis=3)
         with symbol.AttrScope(**self._after.get("attention", {})):
             att = F.contrib.DotProductAttention(
-                q, k, v, sm_scale=self._head_dim ** -0.5, **self._mask)
+                q, k, v, sm_scale=self._scale, **self._mask)
         with symbol.AttrScope(**self._after.get("out", {})):
             if gate_weight is not None:
                 att = F.broadcast_mul(att, gate)

@@ -10,7 +10,7 @@ arXiv:2501.00656 section 3):
 
     h = h + rms(operator(h));  h = h + rms(feed_forward(h))
 
-then a final RMS norm and a head.  The operator is one of seven kinds
+then a final RMS norm and a head.  The operator is one of eight kinds
 (`OPERATOR_KINDS`):
 
 - ``"conv"``: a gated short causal convolution;
@@ -55,7 +55,17 @@ then a final RMS norm and a head.  The operator is one of seven kinds
   published ``linear_*`` keys without the prefix).  The sequence has to be
   whole chunks.  Device scopes ``mx.gdn.project``, ``mx.gdn.conv``,
   ``mx.gdn.scan`` (two Mosaic kernels on the TPU, `jax.numpy` elsewhere:
-  span ``mx.gdn.plan``) and ``mx.gdn.out``.
+  span ``mx.gdn.plan``) and ``mx.gdn.out``;
+- ``"mamba"`` (the published word): a Mamba-2 state-space mixer
+  (`contrib.nn.StateSpaceMixer`, `ops/state_space.py`): a float32 state of
+  ``d_head x d_state`` a head under a scalar decay a head and token, in
+  chunks of ``chunk_size`` tokens, behind one short causal convolution with
+  a bias and in front of a norm whose gate comes first; its widths come as
+  the mapping ``state_space`` (``n_heads``, ``d_head``, ``d_state``,
+  ``n_groups``, ``d_conv``, ``chunk_size``: the published ``mamba_*`` keys
+  without the prefix).  The sequence has to be whole chunks.  Device scopes
+  ``mx.ssm.project``, ``mx.ssm.conv``, ``mx.ssm.scan`` (span
+  ``mx.ssm.plan``) and ``mx.ssm.out``.
 
 What is a property of the layer and not of the net: ``heads`` may be a
 list, one count a layer (a model whose window layers have more query heads
@@ -72,7 +82,17 @@ attention's output (then under device scopes ``mx.gqa.*`` and
 ``mx.swa.*``); ``norm_place`` maps a layer kind to ``"input"`` or
 ``"output"`` (a kind it does not name is pre-normed); ``qk_norm`` maps a
 layer kind to ``"head"`` (the default: an RMS norm over each query and key
-head) or ``"width"`` (one over all the heads' numbers at once).
+head), ``"width"`` (one over all the heads' numbers at once) or None (none:
+with a null ``rope_theta`` q and k go to the attention as projected);
+``attention_scale`` is the softmax's scale in the full_attention and
+sliding_attention layers in place of ``head_dim ** -0.5`` (a published
+``attention_multiplier``).
+
+Three multipliers of the net, each 1 by default and then absent from the
+graph: ``residual_multiplier`` scales what the operator and the feed-forward
+add to the residual (``h = h + m operator(rms(h))``),
+``embedding_multiplier`` the embedding's rows as they enter, and the logits
+are divided by ``logits_scaling``.
 
 The feed-forward is a dense gated MLP in the first ``num_dense_layers``
 layers and dropless top-k routed experts in the others, to which
@@ -94,12 +114,15 @@ full_attention layers mixed, head counts by layer, an output gate, rotary
 settings by layer kind, a sigmoid router with a shared expert), and of
 Ai2's ``olmo_hybrid`` models (linear_attention and full_attention layers
 mixed, the full ones with the norm on the output, a norm over the width of
-q and k and no rotary positions, a dense feed-forward everywhere), whose
+q and k and no rotary positions, a dense feed-forward everywhere), and of
+IBM's ``granitemoehybrid`` models (mamba and full_attention layers mixed,
+the attention with no positions, no q/k norm and a scale of its own, the
+three multipliers, a dense feed-forward everywhere, a tied head), whose
 published ``config.json`` keys the arguments follow;
 `benchmarks/models/lfm2_moe.py`, `benchmarks/models/deepseek_v3.py`,
 `benchmarks/models/keye_vl2.py`, `benchmarks/models/sdar_moe.py`,
-`benchmarks/models/laguna.py` and `benchmarks/models/olmo_hybrid.py` build
-one from such a file.
+`benchmarks/models/laguna.py`, `benchmarks/models/olmo_hybrid.py` and
+`benchmarks/models/granitemoehybrid.py` build one from such a file.
 
 The routed layers hold ONE CHIP'S SHARE of their experts
 (`gluon.contrib.nn.RoutedExperts`): ``experts_held`` of ``num_experts``
@@ -119,14 +142,15 @@ from ..block import HybridBlock
 from .. import nn
 from ..contrib.nn import (GatedDeltaNet, GatedMLP, GatedShortConv,
                           GroupedQueryAttention, LatentAttention,
-                          RoutedExperts, SharedExperts, SparseAttention)
+                          RoutedExperts, SharedExperts, SparseAttention,
+                          StateSpaceMixer)
 
 __all__ = ["AlignedLoss", "BlockDiffusionLoss", "DecoderLayer", "DecoderLM",
            "get_decoder_lm", "OPERATOR_KINDS"]
 
 OPERATOR_KINDS = ("conv", "full_attention", "latent_attention",
                   "sparse_attention", "block_diffusion_attention",
-                  "sliding_attention", "linear_attention")
+                  "sliding_attention", "linear_attention", "mamba")
 NORM_PLACES = ("input", "output")
 
 
@@ -148,27 +172,30 @@ class SharedAndRouted(HybridBlock):
 class DecoderLayer(HybridBlock):
     """One layer: *operator* is built by kind (*latent* holds
     `LatentAttention`'s own widths, *sparse* `SparseAttention`'s, *linear*
-    `GatedDeltaNet`'s, *grouped* what `GroupedQueryAttention` takes beside
-    its head counts: ``window``, ``gate``, ``rope``, ``qk_norm``),
-    *feed_forward* is handed in.  *norm_place* ``"input"`` norms what the
-    operator and the feed-forward read (pre-norm), ``"output"`` what they
-    give back.  A ``positions`` input goes to a
+    `GatedDeltaNet`'s, *state_space* `StateSpaceMixer`'s, *grouped* what
+    `GroupedQueryAttention` takes beside its head counts: ``window``,
+    ``gate``, ``rope``, ``qk_norm``, ``scale``), *feed_forward* is handed in.
+    *norm_place* ``"input"`` norms what the operator and the feed-forward
+    read (pre-norm), ``"output"`` what they give back; *residual_multiplier*
+    scales what the two add to the residual.  A ``positions`` input goes to a
     sparse_attention or block_diffusion_attention operator and to no other;
     a sparse_attention layer returns ``(output, alignment term)``."""
 
     def __init__(self, dim, kind, feed_forward, heads, kv_heads, head_dim,
                  rope_theta, conv_kernel, eps, init, latent=None,
                  sparse=None, diffusion_block=None, grouped=None,
-                 linear=None, norm_place="input", **kwargs):
+                 linear=None, norm_place="input", state_space=None,
+                 residual_multiplier=1.0, **kwargs):
         super().__init__(**kwargs)
+        self._residual = float(residual_multiplier)
         if kind not in OPERATOR_KINDS:
             raise ValueError(
                 "layer kind %r is not one of %s (a gated short convolution, "
                 "grouped-query attention, multi-head latent attention, "
                 "learned sparse attention, grouped-query attention under "
                 "the block-diffusion mask, grouped-query attention through "
-                "a causal window, the gated delta rule's linear attention)"
-                % (kind, OPERATOR_KINDS))
+                "a causal window, the gated delta rule's linear attention, a "
+                "Mamba-2 state-space mixer)" % (kind, OPERATOR_KINDS))
         if norm_place not in NORM_PLACES:
             raise ValueError("a layer's norms sit on the %s or on the %s of "
                              "its operator and feed-forward, not %r"
@@ -218,6 +245,17 @@ class DecoderLayer(HybridBlock):
                     conv_kernel=linear["conv_kernel_dim"],
                     allow_neg_eigval=linear.get("allow_neg_eigval", False),
                     epsilon=eps, weight_initializer=init, prefix="gdn_")
+            elif kind == "mamba":
+                if not state_space:
+                    raise ValueError(
+                        "a mamba layer needs state_space: n_heads, d_head, "
+                        "d_state, n_groups, d_conv, chunk_size")
+                self.operator = StateSpaceMixer(
+                    dim, state_space["n_heads"], state_space["d_head"],
+                    state_space["d_state"], state_space.get("n_groups", 1),
+                    state_space.get("d_conv", 4),
+                    state_space.get("chunk_size", 256), epsilon=eps,
+                    weight_initializer=init, prefix="ssm_")
             elif kind == "sparse_attention":
                 if not sparse:
                     raise ValueError(
@@ -245,12 +283,16 @@ class DecoderLayer(HybridBlock):
             else self.operator(h)
         if self._returns_term:
             out, term = out
+
+        def add(x, y):
+            return x + (y if self._residual == 1.0 else y * self._residual)
+
         if self._norm_output:
-            x = x + self.operator_norm(out)
-            x = x + self.ffn_norm(self.feed_forward(x))
+            x = add(x, self.operator_norm(out))
+            x = add(x, self.ffn_norm(self.feed_forward(x)))
         else:
-            x = x + out
-            x = x + self.feed_forward(self.ffn_norm(x))
+            x = add(x, out)
+            x = add(x, self.feed_forward(self.ffn_norm(x)))
         return (x, term) if self._returns_term else x
 
 
@@ -274,8 +316,12 @@ class DecoderLM(HybridBlock):
     output gate of those and of the full_attention layers; *linear* holds
     the linear_attention layers' widths; *norm_place* maps a layer kind to
     where its norms sit (``"input"`` or ``"output"``) and *qk_norm* a
-    full_attention or sliding_attention kind to ``"head"`` or
-    ``"width"``."""
+    full_attention or sliding_attention kind to ``"head"``, ``"width"`` or
+    None; *state_space* holds the mamba layers' widths; *attention_scale* is
+    the softmax's scale of the full_attention and sliding_attention layers;
+    *residual_multiplier*, *embedding_multiplier* and *logits_scaling* scale
+    what a layer adds to the residual, the embedding as it enters, and (by
+    division) the logits."""
 
     def __init__(self, vocab, dim, layer_types, num_dense_layers,
                  dense_hidden, expert_hidden, num_experts,
@@ -291,9 +337,13 @@ class DecoderLM(HybridBlock):
                  mrope_section=(), alignment_weight=1.0,
                  diffusion_block=None, rope_parameters=None,
                  sliding_window=None, attention_gate=False, linear=None,
-                 norm_place=None, qk_norm=None, **kwargs):
+                 norm_place=None, qk_norm=None, state_space=None,
+                 attention_scale=None, residual_multiplier=1.0,
+                 embedding_multiplier=1.0, logits_scaling=1.0, **kwargs):
         super().__init__(**kwargs)
         self._vocab, self._dim = vocab, dim
+        self._embedding_multiplier = float(embedding_multiplier)
+        self._logits_scaling = float(logits_scaling)
         layer_types = list(layer_types)
         if isinstance(heads, int):
             heads = [heads] * len(layer_types)
@@ -348,12 +398,15 @@ class DecoderLM(HybridBlock):
                         grouped["window"] = sliding_window
                     if kind in qk_norm:
                         grouped["qk_norm"] = qk_norm[kind]
+                    if attention_scale is not None:
+                        grouped["scale"] = attention_scale
                 layer = DecoderLayer(
                     dim, kind, dense if i < num_dense_layers else sparse,
                     heads[i], kv_heads or heads[i], head_dim, rope_theta,
                     conv_kernel, eps, init, latent, indexer,
                     diffusion_block, grouped, linear,
-                    norm_place.get(kind, "input"), prefix="l%d_" % i)
+                    norm_place.get(kind, "input"), state_space,
+                    residual_multiplier, prefix="l%d_" % i)
                 setattr(self, "l%d" % i, layer)
                 self.layers.append(layer)
             self.final_norm = nn.RMSNorm(dim, eps, prefix="final_norm_")
@@ -364,6 +417,8 @@ class DecoderLM(HybridBlock):
                        head_weight=None):
         h = F.Embedding(x, embed_weight, input_dim=self._vocab,
                         output_dim=self._dim)
+        if self._embedding_multiplier != 1.0:
+            h = h * self._embedding_multiplier
         if self._two_copies and positions is None:
             positions = F.contrib.BlockDiffusionPositions(x)
         terms = []
@@ -379,6 +434,8 @@ class DecoderLM(HybridBlock):
             self.final_norm(h),
             embed_weight if head_weight is None else head_weight,
             no_bias=True, flatten=False, num_hidden=self._vocab)
+        if self._logits_scaling != 1.0:
+            logits = logits / self._logits_scaling
         if not terms:
             return logits
         total = terms[0]

@@ -23,4 +23,5 @@ from . import spatial       # noqa: F401
 from . import attention     # noqa: F401
 from . import lm_blocks     # noqa: F401
 from . import delta_rule    # noqa: F401
+from . import state_space   # noqa: F401
 from . import parity        # noqa: F401  (must come last: aliases)

@@ -65,6 +65,13 @@ program is lowered for the TPU on one device and `_gdnconv_plan` gives tiles,
 ``mx_gdnconv_fwd`` and ``mx_gdnconv_bwd``, one pass over the channels each
 way (span ``mx.gdnconv.plan`` says which path a call takes); the other two
 are `jax.numpy` on every platform.
+
+A state-space block (`gluon.contrib.nn.StateSpaceMixer`, `ops/state_space.py`)
+shares two of them in another form: ``_contrib_ShortConvSilu`` is the same
+taps and silu (`_taps_silu`) with a bias a channel and no heads to norm
+(`jax.numpy` on every platform; span ``mx.ssmconv.plan``), and
+``_contrib_GatedRMSNorm`` with ``gate_first`` puts the gate in front of the
+norm, ``rms(x * silu(z))``.
 """
 
 from __future__ import annotations
@@ -661,9 +668,16 @@ def _unit(x, eps):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + eps)
 
 
+def _taps_silu(data, conv_weight, bias=None):
+    """The depthwise causal taps over ``(B, S, channels)``, a *bias* a
+    channel where one is given, silu: float32."""
+    y = causal_taps(data, conv_weight)
+    return jax.nn.silu(y if bias is None else y + bias.astype(_F32))
+
+
 def _conv_heads_body(data, conv_weight, heads, dk, eps):
     batch, seq, width = data.shape
-    y = jax.nn.silu(causal_taps(data, conv_weight))
+    y = _taps_silu(data, conv_weight)
     q, k, v = (part.reshape(batch, seq, heads, -1) for part in
                jnp.split(y, [heads * dk, 2 * heads * dk], -1))
     return tuple(part.astype(data.dtype) for part in (
@@ -1208,6 +1222,52 @@ def _short_conv_heads(data, conv_weight, num_heads=1, key_dim=1, eps=1e-6):
     return _conv_heads(data, conv_weight, heads, dk, float(eps))
 
 
+def _conv_silu_body(data, conv_weight, bias):
+    return _taps_silu(data, conv_weight, bias).astype(data.dtype)
+
+
+@jax.custom_vjp
+def _conv_silu_kept(data, conv_weight, bias):
+    return _conv_silu_body(data, conv_weight, bias)
+
+
+def _conv_silu_bwd(kept, dout):
+    # the float32 intermediates are computed again from the three inputs
+    kept, dout = _again(kept, dout)
+    return jax.vjp(_conv_silu_body, *kept)[1](dout)
+
+
+_conv_silu_kept.defvjp(
+    lambda *kept: (_conv_silu_body(*kept), kept), _conv_silu_bwd)
+
+
+@register_op("_contrib_ShortConvSilu", aliases=("ShortConvSilu",))
+def _short_conv_silu(data, conv_weight, bias):
+    """`_contrib_ShortConvHeads`' convolution with a bias and no heads to
+    norm, a state-space block's: ``silu(taps(data) + bias)`` over ``(B, S,
+    channels)``, *conv_weight* ``(channels, taps)`` depthwise and causal
+    (zeros before position 0), *bias* ``(channels,)``.  Float32, rounded
+    once; the backward pass keeps the three inputs and computes it again.
+    `jax.numpy` on every platform: the kernels ``mx_gdnconv_*`` norm q and k
+    by head and carry no bias, and span ``mx.ssmconv.plan`` says so."""
+    if conv_weight.shape[0] != data.shape[-1] \
+            or bias.shape != data.shape[-1:]:
+        raise ValueError("%d channels under taps %s and a bias %s" % (
+            data.shape[-1], conv_weight.shape, bias.shape))
+    with profiler.scope(  # graftlint: disable=JG003
+            "mx.ssmconv.plan", "ssm") as span:
+        span.args = {
+            "shape": list(data.shape), "dtype": jnp.dtype(data.dtype).name,
+            "taps": conv_weight.shape[1], "path": "xla",
+            "why": "taps, a bias and silu with no heads to norm: the kernels "
+                   "mx_gdnconv_fwd and mx_gdnconv_bwd norm q and k by head "
+                   "and carry no bias",
+            # what is kept for the backward pass: the three inputs
+            "residual_bytes": sum(x.size * x.dtype.itemsize
+                                  for x in (data, conv_weight, bias))}
+    return _conv_silu_kept(data, conv_weight, bias)
+
+
 @register_op("_contrib_DeltaRuleGates", aliases=("DeltaRuleGates",),
              num_outputs=2)
 def _delta_rule_gates(decay, beta, a_log, dt_bias, allow_neg_eigval=False):
@@ -1223,37 +1283,45 @@ def _delta_rule_gates(decay, beta, a_log, dt_bias, allow_neg_eigval=False):
     return g, ((2.0 * b) if allow_neg_eigval else b).astype(beta.dtype)
 
 
-def _gated_norm_body(data, gate, gamma, eps):
+def _gated_norm_body(data, gate, gamma, eps, gate_first=False):
     batch, seq, heads, dv = data.shape
     x = data.astype(_F32)
+    if gate_first:
+        x = x * jax.nn.silu(gate.astype(_F32).reshape(data.shape))
+        gamma = gamma.reshape(-1, dv)       # a scale a head, or one for all
     y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps) \
         * gamma.astype(_F32)
-    z = gate.astype(_F32).reshape(batch, seq, heads, dv)
-    return (y * jax.nn.silu(z)).reshape(batch, seq, heads * dv).astype(
-        data.dtype)
+    if not gate_first:
+        z = gate.astype(_F32).reshape(batch, seq, heads, dv)
+        y = y * jax.nn.silu(z)
+    return y.reshape(batch, seq, heads * dv).astype(data.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gated_norm(data, gate, gamma, eps):
-    return _gated_norm_body(data, gate, gamma, eps)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gated_norm(data, gate, gamma, eps, gate_first):
+    return _gated_norm_body(data, gate, gamma, eps, gate_first)
 
 
-def _gated_norm_bwd(eps, kept, dout):
+def _gated_norm_bwd(eps, gate_first, kept, dout):
     kept, dout = _again(kept, dout)
-    return jax.vjp(functools.partial(_gated_norm_body, eps=eps),
-                   *kept)[1](dout)
+    return jax.vjp(functools.partial(_gated_norm_body, eps=eps,
+                                     gate_first=gate_first), *kept)[1](dout)
 
 
 _gated_norm.defvjp(
-    lambda data, gate, gamma, eps: (_gated_norm_body(data, gate, gamma, eps),
-                                    (data, gate, gamma)),
+    lambda data, gate, gamma, eps, gate_first: (
+        _gated_norm_body(data, gate, gamma, eps, gate_first),
+        (data, gate, gamma)),
     _gated_norm_bwd)
 
 
 @register_op("_contrib_GatedRMSNorm", aliases=("GatedRMSNorm",))
-def _gated_rms_norm(data, gate, gamma, eps=1e-6):
+def _gated_rms_norm(data, gate, gamma, eps=1e-6, gate_first=False):
     """``rms(o, gamma) * silu(z)`` for ``o (B, S, H, dv)`` and ``z (B, S, H
     dv)``: the RMS norm over each head's ``dv`` numbers by one *gamma*
     ``(dv,)`` for all heads, gated, as ``(B, S, H dv)``; float32, rounded
-    once; the backward pass keeps the three inputs."""
-    return _gated_norm(data, gate, gamma, float(eps))
+    once; the backward pass keeps the three inputs.  With *gate_first* the
+    gate stands in front of the norm, ``rms(o * silu(z), gamma)`` (a
+    state-space block's: its "heads" are the groups the norm runs over, and
+    *gamma* may be ``(H dv,)``, a scale a channel)."""
+    return _gated_norm(data, gate, gamma, float(eps), bool(gate_first))
